@@ -1,14 +1,15 @@
 #!/usr/bin/env python
 """Serving latency benchmark: p50/p99 of POST /invocations + restart churn.
 
-BASELINE.md's second metric ("p50 serve-predict latency"). Runs the real
+The serving-side metric ("p50 serve-predict latency"). Runs the real
 threaded WSGI server in-process against a trained abalone-sized model and
 measures end-to-end HTTP latency for single-row csv payloads, then a batch
 payload, then a **churn leg**: a rolling SIGTERM-restart cycle (graceful
 drain via serving/lifecycle.py) under continuous client load, reporting the
 p95 and error rate a fleet would see across deploys. Prints one JSON line
-(not the driver contract — bench.py is that; this is the measurement tool
-for serving work).
+naming the platform, device kind and device count it ran on. A latency is a
+device number: the run fails (exit 2) when jax finds no accelerator, unless
+a CPU run was asked for by name with ``JAX_PLATFORMS=cpu``.
 """
 
 import json
@@ -217,10 +218,22 @@ def main():
     from sagemaker_xgboost_container_tpu.data.matrix import DataMatrix
     from sagemaker_xgboost_container_tpu.models import train
     from sagemaker_xgboost_container_tpu.serving.app import ScoringService, make_app
+    from sagemaker_xgboost_container_tpu.serving.serve_utils import (
+        join_predict_warmup,
+    )
     from sagemaker_xgboost_container_tpu.serving.server import (
         _QuietHandler,
         _ThreadedWSGIServer,
     )
+    from sagemaker_xgboost_container_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+    from sagemaker_xgboost_container_tpu.utils.device_runtime import (
+        require_accelerator,
+    )
+
+    device = require_accelerator("bench_serve.py")
+    enable_compile_cache()
 
     rng = np.random.RandomState(0)
     X = rng.rand(4000, 8).astype(np.float32)
@@ -251,8 +264,6 @@ def main():
             resp.read()
         return time.perf_counter() - t0
 
-    import jax
-
     single = ",".join("%.4f" % v for v in X[0]).encode()
     batch = "\n".join(
         ",".join("%.4f" % v for v in row) for row in X[:256]
@@ -261,9 +272,7 @@ def main():
     # trigger the model load, then let its background bucket warmup finish
     # BEFORE timing — an in-flight compile would pollute the first leg
     post(single)
-    for t in threading.enumerate():
-        if t.name == "predict-warmup":
-            t.join(timeout=300)
+    join_predict_warmup(300)
 
     # A/B the small-payload strategy: host numpy traversal (pinned to a
     # cutover that definitely includes 1 row) vs forcing the compiled device
@@ -300,9 +309,8 @@ def main():
     print(
         json.dumps(
             {
-                "metric": "serve /invocations latency (100-tree depth-6 model) [backend={}]".format(
-                    jax.default_backend()
-                ),
+                "metric": "serve /invocations latency (100-tree depth-6 model)",
+                "device": device,
                 **results,
                 "p50_batch256_ms": round(blat[len(blat) // 2] * 1000, 2),
                 "steady_rps": steady_rps,

@@ -33,7 +33,7 @@ def _packaged_extension():
 
     setup.py builds native/fastdata.cpp into
     ``sagemaker_xgboost_container_tpu/_fastdata*.so`` so installed images get
-    the C++ parser without a compiler (VERDICT r1 weak #8). It is a plain
+    the C++ parser without a compiler. It is a plain
     C-ABI object — loaded with ctypes, never imported.
     """
     import glob
@@ -140,8 +140,12 @@ def _load():
             except AttributeError:  # stale cached pre-r5 .so
                 lib.forest_leaf_values = None
             _lib = lib
+            logger.info("native data plane loaded from %s (%s)", lib_path, kind)
         except Exception as e:  # no compiler / load failure -> python fallback
-            logger.info("native libsvm parser unavailable (%s); using python parser", e)
+            logger.warning(
+                "native data plane unavailable (%s); using the python "
+                "parser and the numpy forest traversal", e
+            )
             _lib = None
         finally:
             # set only AFTER the attempt: the unlocked fast path above must

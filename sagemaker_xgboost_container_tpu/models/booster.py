@@ -52,15 +52,6 @@ from . import eval_metrics
 from . import objectives as objectives_mod
 from .forest import Forest, compact_padded_tree
 
-try:
-    from jax import shard_map
-
-    _SHARD_MAP_REP_KW = {"check_vma": False}
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
-
-    _SHARD_MAP_REP_KW = {"check_rep": False}  # pre-0.6 kwarg name
-
 logger = logging.getLogger(__name__)
 
 # objective hyperparameters carried into the saved model / objective
@@ -124,7 +115,7 @@ class TrainConfig:
         else:
             self.max_bin = 256
         if p.get("tree_method") == "approx":
-            # r5 (VERDICT r4 #8): approx now matches libxgboost's candidate
+            # r5: approx now matches libxgboost's candidate
             # refresh — a hessian-weighted re-sketch before every dispatch
             # (_TrainingSession._resketch_bins). GRAFT_APPROX_RESKETCH=0
             # restores the single global sketch (hist semantics) for A/Bs.
@@ -321,12 +312,12 @@ def _calibrated_comm_ms(mesh, hist_comm, plan_key):
                 fn, out_spec = psum_fn, P()
             # graftlint: disable=trace-uncached-jit — calibration-scope: lru_cached module factory, one standalone collective timing per distinct (mesh, plan shape, impl) per process, off the round path
             mapped = jax.jit(
-                shard_map(
+                jax.shard_map(
                     fn,
                     mesh=mesh,
                     in_specs=(P(),),
                     out_specs=out_spec,
-                    **_SHARD_MAP_REP_KW,
+                    check_vma=False,
                 )
             )
             x = jnp.zeros(shape, jnp.float32)
@@ -394,12 +385,6 @@ class _TrainingSession:
         has_feval=False,
         hist_knobs=None,
     ):
-        # persistent XLA compile cache (GRAFT_COMPILE_CACHE_DIR): armed
-        # before anything in this session can trigger a compile, resolved
-        # once per process like every other session knob
-        from ..utils.compile_cache import maybe_enable_compile_cache
-
-        maybe_enable_compile_cache()
         self.config = config
         self.objective = forest.objective()
         self.num_group = self.objective.num_output_group
@@ -462,7 +447,7 @@ class _TrainingSession:
         # a dedicated global-rows path — all_gather over the data axis on
         # device (device_metrics needs_global_rows) or process_allgather on
         # the host evaluate() path — the same way the Cox gradients gather
-        # global risk sets (r3 parity debt, VERDICT #4).
+        # global risk sets (r3 parity debt).
         # ranking layouts: single device keeps the [G, M] global layout;
         # on a mesh, rows are re-partitioned BY GROUP (groups never straddle
         # shards, so intra-group pairwise gradients stay shard-exact — the
@@ -478,7 +463,7 @@ class _TrainingSession:
             # "feature") as usual, rank_index replicates over the feature
             # axis, and the builder's cross-shard split combine + owner/psum
             # routing (ops/tree_build, ops/lossguide) do the column work
-            # (r3 parity debt, VERDICT #4)
+            # (r3 parity debt)
             if dtrain.groups is None:
                 # xgboost convention: absent group info = one group per dataset
                 groups = np.asarray([dtrain.num_row], np.int64)
@@ -1209,12 +1194,12 @@ class _TrainingSession:
             in_specs = base_specs + (eval_specs, eval_blw_specs)
             out_specs = (P(), P(), margin_spec, eval_specs) + stats_specs
             donate = (1, 9)
-        mapped = shard_map(
+        mapped = jax.shard_map(
             fn,
             mesh=self.mesh,
             in_specs=in_specs,
             out_specs=out_specs,
-            **_SHARD_MAP_REP_KW,
+            check_vma=False,
         )
         # graftlint: disable=trace-uncached-jit — session-scope construction: built once per training session, not per call (one session = one round closure = its own jit cache)
         return jax.jit(mapped, donate_argnums=donate)
@@ -1237,12 +1222,12 @@ class _TrainingSession:
             # graftlint: disable=trace-uncached-jit — session-scope construction: _make_apply_fn runs once per session
             return jax.jit(apply_tree, donate_argnums=(2,))
         margin_spec = P("data") if num_group == 1 else P("data", None)
-        mapped = shard_map(
+        mapped = jax.shard_map(
             apply_tree,
             mesh=self.mesh,
             in_specs=(P(), P("data", None), margin_spec),
             out_specs=margin_spec,
-            **_SHARD_MAP_REP_KW,
+            check_vma=False,
         )
         # graftlint: disable=trace-uncached-jit — session-scope construction: _make_apply_fn runs once per session
         return jax.jit(mapped, donate_argnums=(2,))
@@ -1478,8 +1463,8 @@ class _TrainingSession:
         ``cost_analysis``/``memory_analysis`` into the device-window plane
         (``training.compiled`` record + flops/HBM gauges). Gated on
         ``SM_DEVICE_TELEMETRY`` because the AOT compile is real work (the
-        jit path's own compile is served from the persistent cache when
-        ``GRAFT_COMPILE_CACHE_DIR`` is armed); lowering never *executes*,
+        jit path's own compile is served from the persistent compile
+        cache, utils/compile_cache.py); lowering never *executes*,
         so donated buffers are not consumed. Diagnostics only — any
         failure is one warning, never a failed session."""
         from ..telemetry import device as device_telemetry
@@ -2006,11 +1991,6 @@ def train(
     snapshot so the rebuilt (smaller-mesh) session trains under identical
     kernel choices.
     """
-    from ..utils.compile_cache import maybe_enable_compile_cache
-
-    # armed here too so every booster path (gblinear, dart, update) gets
-    # the persistent compile cache, not just _TrainingSession builders
-    maybe_enable_compile_cache()
     config = TrainConfig(params)
     callbacks = list(callbacks or [])
 

@@ -309,19 +309,10 @@ def train_linear(
     if axis is not None:
         from jax.sharding import PartitionSpec as P
 
-        try:
-            from jax import shard_map
-
-            rep_kw = {"check_vma": False}
-        except ImportError:  # pragma: no cover - older jax
-            from jax.experimental.shard_map import shard_map
-
-            rep_kw = {"check_rep": False}  # pre-0.6 kwarg name
-
         lab_spec = P("data") if labels.ndim == 1 else P("data", None)
         # graftlint: disable=trace-uncached-jit — session-scope construction: one linear round program per train call
         one_round_sharded = jax.jit(
-            shard_map(
+            jax.shard_map(
                 _round_body,
                 mesh=mesh,
                 in_specs=(
@@ -329,7 +320,7 @@ def train_linear(
                     lab_spec, P("data"), P(None, None), P(None),
                 ),
                 out_specs=(P(None, None), P(None)),
-                **rep_kw,
+                check_vma=False,
             )
         )
 

@@ -85,10 +85,10 @@ def resolve_hist_knobs():
 
 
 def _impl():
-    """Backend-aware default: the pallas one-hot matmul kernel is the
-    measured TPU winner (BASELINE.md round-2 probes: pallas 3.15 r/s vs
-    flat 0.265 on the bench config); the flat segment-sum wins on CPU.
-    GRAFT_HIST_IMPL overrides either way."""
+    """Backend-aware default: the pallas one-hot matmul kernel on TPU
+    (pallas 3.15 r/s vs flat 0.265 on the bench config — builders'
+    self-report, one v5e, round 2, not the driver's); the flat segment-sum
+    wins on CPU. GRAFT_HIST_IMPL overrides either way."""
     # graftlint: disable=trace-env-read — direct-caller fallback only;
     # sessions snapshot this via resolve_hist_knobs() at build time
     v = os.environ.get("GRAFT_HIST_IMPL")
@@ -488,8 +488,13 @@ def _totals_onehot(grad, hess, node_local, num_nodes, knobs=None):
         h_c = jax.lax.dynamic_slice(h, (sl,), (chunk,))
         oh = (node_c[:, None] == iota_w[None, :]).astype(jnp.float32)  # [c, W]
         gh = jnp.stack([g_c, h_c])  # [2, c]
+        # HIGHEST: a TPU runs a default-precision f32 dot as one bf16 pass,
+        # which rounds every gradient to 8 mantissa bits before it is summed
+        # into a leaf weight; the dot is tiny ([2, c] @ [c, W])
         P = jax.lax.dot_general(
-            gh, oh, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            gh, oh, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
         )
         return carry + P, None
 
@@ -508,13 +513,7 @@ def _totals_pallas_fn(n, W, block, interpret):
     (segment_sum) and no matmul (the [2, c] @ [c, W] onehot dot pads M=2 to
     a 128 tile). The last tree level runs this over every row."""
     import jax.experimental.pallas as pl
-
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        vmem = pltpu.VMEM
-    except ImportError:  # pragma: no cover
-        vmem = None
+    from jax.experimental.pallas import tpu as pltpu
 
     def kernel(gh_ref, node_ref, out_ref):
         step = pl.program_id(0)
@@ -532,7 +531,7 @@ def _totals_pallas_fn(n, W, block, interpret):
         out_ref[:] += jnp.sum(A, axis=0, keepdims=True)
 
     steps = n // block
-    in_space = dict(memory_space=vmem) if vmem is not None and not interpret else {}
+    in_space = {} if interpret else dict(memory_space=pltpu.VMEM)
     return pl.pallas_call(
         kernel,
         grid=(steps,),
@@ -553,7 +552,7 @@ def _totals_pallas(grad, hess, node_local, num_nodes, knobs=None):
         z = jnp.zeros(W, jnp.float32)
         return z, z
     block = knobs.pallas_block if knobs is not None else _pallas_block()
-    interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret()
     active = node_local >= 0
     g = jnp.where(active, grad, 0.0)
     h = jnp.where(active, hess, 0.0)
@@ -780,100 +779,138 @@ def _vnode_factor(W, block, d, B, knobs=None):
     return max(1, v)
 
 
+def pallas_interpret():
+    """Pallas kernels are interpreted on the CPU backend only (tests, CPU
+    rehearsals). Any accelerator, whatever its platform is called, compiles
+    them or fails loudly — never a silent interpreter run on a device."""
+    return jax.default_backend() == "cpu"
+
+
+def _round_up(x, m):
+    return -(-x // m) * m
+
+
+# most feature rows per grid step of the pallas histogram: one packed
+# sublane tile of the narrowest bin dtype (u8 tiles are 32 x 128), so a
+# block of transposed bins is tile-aligned for u8, u16 and i32 alike
+_PALLAS_FEATURE_GROUP = 32
+
+
+def _pallas_feature_group(d, bins_dtype):
+    """Feature rows per grid step: _PALLAS_FEATURE_GROUP, or for a narrower
+    matrix its width rounded up to whole sublane tiles of the bin dtype
+    (32 rows of u8, 16 of u16) and of the bf16 missing-bin operand (16) —
+    narrow data is not padded to 32 features of MXU work."""
+    tile = max(16, 32 // jnp.dtype(bins_dtype).itemsize)
+    return min(_PALLAS_FEATURE_GROUP, _round_up(d, tile))
+
+
 @functools.lru_cache(maxsize=None)
-def _pallas_hist_fn(n, d, W, B, block, prec, interpret, split_missing, v):
-    """Compiled pallas histogram: (bins int [n,d] — any integer storage
-    dtype, widened per block in VMEM, so u8/u16 bins move half the HBM
-    bytes — gh f32 [n,2], node i32 [n,1]) -> [2*W*v, d, B] f32 with the g
-    histograms in rows [:W*v] and h in [W*v:], v sub-group copies each
-    (see _vnode_factor; the caller reduces them). Grid over row blocks;
-    VMEM-resident accumulator. split_missing: see _mxu_split_missing
-    (part of the cache key because the kernel body changes with it)."""
+def _pallas_hist_fn(n, d_pad, fg, W, B, block, prec, interpret, split_missing, v):
+    """Compiled pallas histogram over ROW-ON-LANES operands: (bins int
+    [d_pad, n] — any integer storage dtype, widened per block in VMEM so
+    u8/u16 bins move fewer HBM bytes — gh f32 [2, n], node i32 [1, n]) ->
+    (main f32 [d_pad, 2*M, Bp], miss f32 [d_pad, 2*M]) with the g rows in
+    [:M] and h in [M:], M = W*v rounded up to a sublane tile and v
+    sub-group copies each (see _vnode_factor; the caller reduces them).
+
+    Every operand keeps rows on the lane axis, so the kernel has no
+    lane-sparse [block, 1] blocks, no in-kernel transposes and no strided
+    stores: the node and bin one-hots are sublane-broadcast compares, both
+    dots contract the lane axis of both operands (the A @ B^T form the MXU
+    takes natively), and each feature's [2*M, Bp] product lands on a whole
+    tile-aligned slab of the accumulator. Bp is the bin axis padded to a
+    lane multiple; split_missing (see _mxu_split_missing) moves the missing
+    bin out of it into the second output. Grid = (feature groups of fg
+    rows, row blocks): the accumulator block for one feature group stays
+    resident in VMEM across the row axis, so VMEM use is bounded by the
+    group size, not by the matrix width."""
     import jax.experimental.pallas as pl
-
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        vmem = pltpu.VMEM
-    except ImportError:  # pragma: no cover
-        pltpu = None
-        vmem = None
+    from jax.experimental.pallas import tpu as pltpu
 
     Bm = B - 1 if split_missing else B
+    Bp = _round_up(Bm, 128)
     Wv = W * v
+    M = _round_up(Wv, 8)
 
-    def kernel(bins_ref, gh_ref, node_ref, out_ref):
-        step = pl.program_id(0)
-
-        @pl.when(step == 0)
+    def kernel(bins_ref, gh_ref, node_ref, out_ref, miss_ref):
+        @pl.when(pl.program_id(1) == 0)
         def _():
-            out_ref[:] = jnp.zeros_like(out_ref)
+            out_ref[...] = jnp.zeros_like(out_ref)
+            miss_ref[...] = jnp.zeros_like(miss_ref)
 
-        node = node_ref[:, 0]                          # [blk]
+        node = node_ref[...]                           # [1, blk]
+        dead = node >= W
         if v > 1:
-            # row i -> virtual node range (i % v); dead rows (node == W)
-            # must stay out of EVERY range, not collide with range (s+1)
-            s = jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)[:, 0] % v
-            node = jnp.where(node >= W, Wv, node + s * W)
-        onehot_w = (node[:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (block, Wv), 1)).astype(jnp.float32)
-        g = gh_ref[:, 0]
-        h = gh_ref[:, 1]
+            # row i -> virtual node range (i % v); v is a power of two
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+            node = node + (lane & (v - 1)) * W
+        # dead rows must stay out of EVERY range, not collide with the next
+        node = jnp.where(dead, -1, node)
+        hit = jax.lax.broadcasted_iota(jnp.int32, (M, block), 0) == node
         A = jnp.concatenate(
-            [onehot_w * g[:, None], onehot_w * h[:, None]], axis=1
-        )  # [blk, 2*Wv]
+            [
+                jnp.where(hit, gh_ref[0:1, :], 0.0),
+                jnp.where(hit, gh_ref[1:2, :], 0.0),
+            ],
+            axis=0,
+        )  # [2*M, blk]
         if prec == "bf16x2":
-            A_hi, A_lo = _split_bf16(A)
+            parts = _split_bf16(A)
         elif prec == "bf16":
-            A_hi = A.astype(jnp.bfloat16)
-            A_lo = None
+            parts = (A.astype(jnp.bfloat16),)
         else:
-            A_hi, A_lo = A, None
-        bw = bins_ref[:].astype(jnp.int32)             # widen in VMEM
-        iota_b = jax.lax.broadcasted_iota(jnp.int32, (block, Bm), 1)
-        for f in range(d):
-            ob = (bw[:, f][:, None] == iota_b)
-            ob = ob.astype(A_hi.dtype)
-            P = jax.lax.dot_general(
-                A_hi, ob, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            if A_lo is not None:
-                P = P + jax.lax.dot_general(
-                    A_lo, ob.astype(A_lo.dtype), (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
+            parts = (A,)
+        op_dtype = parts[0].dtype
+        lanes = (((1,), (1,)), ((), ()))               # contract rows
+
+        bw = bins_ref[...].astype(jnp.int32)           # widen in VMEM
+        iota_b = jax.lax.broadcasted_iota(jnp.int32, (Bp, block), 0)
+        for f in range(fg):
+            ob = (iota_b == bw[f:f + 1, :]).astype(op_dtype)   # [Bp, blk]
+            P = sum(
+                jax.lax.dot_general(
+                    a, ob, lanes, preferred_element_type=jnp.float32
                 )
-            out_ref[:, f, :Bm] += P
+                for a in parts
+            )
+            out_ref[f] += P
         if split_missing:
-            miss = (bw == (B - 1)).astype(A_hi.dtype)  # [blk, d]
-            Pm = jax.lax.dot_general(
-                A_hi, miss, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            if A_lo is not None:
-                Pm = Pm + jax.lax.dot_general(
-                    A_lo, miss.astype(A_lo.dtype), (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
+            miss = (bw == (B - 1)).astype(op_dtype)    # [fg, blk]
+            miss_ref[...] += sum(
+                jax.lax.dot_general(
+                    miss, a, lanes, preferred_element_type=jnp.float32
                 )
-            out_ref[:, :, Bm:Bm + 1] += Pm[:, :, None]
+                for a in parts
+            )
 
-    steps = n // block
-    if vmem is not None and not interpret:
-        in_space = dict(memory_space=vmem)
-    else:
-        in_space = {}
-
+    # accumulator blocks (main + the lane-padded missing-bin block) are
+    # double-buffered by the pipeline; operand blocks and the per-feature
+    # one-hot temporaries are small next to them
+    acc_bytes = fg * 2 * M * (Bp + 128) * 4
+    vmem_limit = min(2 * acc_bytes + 16 * 1024 * 1024, 100 * 1024 * 1024)
     return pl.pallas_call(
         kernel,
-        grid=(steps,),
+        grid=(d_pad // fg, n // block),
         in_specs=[
-            pl.BlockSpec((block, d), lambda i: (i, 0), **in_space),
-            pl.BlockSpec((block, 2), lambda i: (i, 0), **in_space),
-            pl.BlockSpec((block, 1), lambda i: (i, 0), **in_space),
+            pl.BlockSpec((fg, block), lambda j, i: (j, i)),
+            pl.BlockSpec((2, block), lambda j, i: (0, i)),
+            pl.BlockSpec((1, block), lambda j, i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((2 * Wv, d, B), lambda i: (0, 0, 0), **in_space),
-        out_shape=jax.ShapeDtypeStruct((2 * Wv, d, B), jnp.float32),
+        out_specs=[
+            pl.BlockSpec((fg, 2 * M, Bp), lambda j, i: (j, 0, 0)),
+            pl.BlockSpec((fg, 2 * M), lambda j, i: (j, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((d_pad, 2 * M, Bp), jnp.float32),
+            jax.ShapeDtypeStruct((d_pad, 2 * M), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit,
+        ),
         interpret=interpret,
+        name="graft_level_histogram",
     )
 
 
@@ -882,36 +919,45 @@ def _hist_pallas(bins, grad, hess, node_local, num_nodes, num_bins, knobs=None):
     W = num_nodes
     B = num_bins
     if n == 0:
-        # grid would be (0,): the step-0 out_ref init never runs and the
+        # grid would be (.., 0): the step-0 out_ref init never runs and the
         # kernel would return an uninitialized buffer
         zeros = jnp.zeros((W, d, B), jnp.float32)
         return zeros, zeros
-    block = knobs.pallas_block if knobs is not None else _pallas_block()
+    # row blocks sit on the lane axis: whole 128-lane tiles only
+    block = _round_up(
+        knobs.pallas_block if knobs is not None else _pallas_block(), 128
+    )
     prec = knobs.precision if knobs is not None else _matmul_precision()
-    interpret = jax.default_backend() != "tpu"
 
     active = node_local >= 0
     g = jnp.where(active, grad, 0.0)
     h = jnp.where(active, hess, 0.0)
     node = jnp.where(active, node_local, jnp.int32(W))
 
-    n_pad = -(-n // block) * block
-    if n_pad != n:
-        pad = [(0, n_pad - n)]
-        g = jnp.pad(g, pad)
-        h = jnp.pad(h, pad)
-        node = jnp.pad(node, pad, constant_values=W)
-        bins = jnp.pad(bins, pad + [(0, 0)])
+    n_pad = _round_up(n, block)
+    fg = _pallas_feature_group(d, bins.dtype)
+    d_pad = _round_up(d, fg)
+    # rows onto the lane axis (see _pallas_hist_fn); the padding rows are
+    # dead (node == W) and the padding features are sliced off below
+    bins_t = jnp.pad(bins.T, [(0, d_pad - d), (0, n_pad - n)])
+    gh = jnp.pad(jnp.stack([g, h]), [(0, 0), (0, n_pad - n)])
+    node = jnp.pad(node, [(0, n_pad - n)], constant_values=W)
 
-    gh = jnp.stack([g, h], axis=1)                     # [n, 2]
-    v = _vnode_factor(W, block, d, B, knobs=knobs)
+    split_missing = _mxu_split_missing(B, knobs=knobs)
+    # the VMEM-resident accumulator spans one feature group, not all of d
+    v = _vnode_factor(W, block, fg, B, knobs=knobs)
     fn = _pallas_hist_fn(
-        n_pad, d, W, B, block, prec, interpret, _mxu_split_missing(B, knobs=knobs), v
+        n_pad, d_pad, fg, W, B, block, prec, pallas_interpret(), split_missing, v
     )
-    GH = fn(bins, gh, node[:, None].astype(jnp.int32))
-    if v > 1:
-        Wv = W * v
-        G = GH[:Wv].reshape(v, W, d, B).sum(axis=0)
-        H = GH[Wv:].reshape(v, W, d, B).sum(axis=0)
-        return G, H
-    return GH[:W], GH[W:]
+    main, miss = fn(bins_t, gh, node[None, :].astype(jnp.int32))
+
+    M = main.shape[1] // 2
+    Bm = B - 1 if split_missing else B
+    GH = main[:d, :, :Bm]                              # [d, 2*M, Bm]
+    if split_missing:
+        GH = jnp.concatenate([GH, miss[:d, :, None]], axis=2)
+
+    def _half(x):                                      # [d, M, B] -> [W, d, B]
+        return x[:, : W * v].reshape(d, v, W, B).sum(axis=1).transpose(1, 0, 2)
+
+    return _half(GH[:, :M]), _half(GH[:, M:])

@@ -20,6 +20,8 @@ import io
 import json
 import logging
 import os
+import threading
+import time
 
 import numpy as np
 from scipy import stats
@@ -287,6 +289,9 @@ def best_iteration_range(forest):
     return (0, int(best_iteration) + 1)
 
 
+WARMUP_THREAD_NAME = "predict-warmup"
+
+
 def warmup_predict_async(model):
     """Pre-compile the first device predict buckets in the background.
 
@@ -295,7 +300,8 @@ def warmup_predict_async(model):
     row bucket — tens of seconds on a TPU endpoint, easily tripping client
     timeouts right after deploy. Warming the smallest device bucket plus a
     representative batch bucket at model-load time moves that cost off the
-    request path. Fire-and-forget daemon thread; failures only log.
+    request path. Fire-and-forget daemon thread; a failure is logged at
+    ERROR with its traceback and serving continues.
     GRAFT_PREDICT_WARMUP=0 disables (any other value, including typos,
     degrades to the default: enabled)."""
     if os.getenv("GRAFT_PREDICT_WARMUP", "1").lower() in ("0", "false", "off", "no"):
@@ -324,12 +330,24 @@ def warmup_predict_async(model):
                         np.zeros((n, d), np.float32),
                         iteration_range=best_iteration_range(m),
                     )
-        except Exception as e:  # a failed warmup must never break serving
-            logging.getLogger(__name__).info("predict warmup skipped: %s", e)
+        except Exception:
+            # a failed warmup must never break serving, but on a device it
+            # is the first compile of the traversal kernel: the requests
+            # that follow will hit the same failure, so say it loudly
+            logging.getLogger(__name__).exception("predict warmup failed")
 
-    import threading
+    threading.Thread(target=_warm, daemon=True, name=WARMUP_THREAD_NAME).start()
 
-    threading.Thread(target=_warm, daemon=True, name="predict-warmup").start()
+
+def join_predict_warmup(timeout):
+    """Wait (bounded) for in-flight warm-up compiles. Shutdown calls this
+    before the interpreter exits: tearing the process down under a daemon
+    thread that is inside an XLA compile aborts it (SIGABRT) instead of
+    exiting 0."""
+    deadline = time.monotonic() + timeout
+    for t in threading.enumerate():
+        if t.name == WARMUP_THREAD_NAME:
+            t.join(max(0.0, deadline - time.monotonic()))
 
 
 def predict(model, model_format, dtest, input_content_type, objective=None):

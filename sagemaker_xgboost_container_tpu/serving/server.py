@@ -28,9 +28,11 @@ from wsgiref.simple_server import WSGIRequestHandler, WSGIServer, make_server
 from .. import constants
 from .. import telemetry
 from ..constants import EXIT_DRAIN_TIMEOUT
+from ..utils.device_runtime import start_device_runtime
 from ..utils.envconfig import env_float
 from ..utils.logging_config import setup_main_logger
 from . import lifecycle as lifecycle_mod
+from . import serve_utils
 from .app import ScoringService, make_app
 from .mme import make_mme_app
 
@@ -246,6 +248,10 @@ def serving_entrypoint(port=None, block=True):
     set_default_serving_env_if_unspecified()
     setup_main_logger(__name__)
     port = int(port or os.getenv("SAGEMAKER_BIND_TO_PORT", 8080))
+    # before the first compile: arm the compile cache, say what we run on.
+    # Initializes the backend here, so a missing device fails the start
+    # instead of the first request
+    start_device_runtime("serve")
     # device-runtime gauges (XLA compile count/seconds, RSS, live device
     # bytes) feed /metrics and the snapshot records from serving startup on
     telemetry.register_runtime_gauges()
@@ -309,6 +315,7 @@ def serving_entrypoint(port=None, block=True):
             drainer = shutdown_state["thread"]
         if drainer is not None:
             drainer.join(timeout=lifecycle.drain_timeout_s + 10.0)
+        serve_utils.join_predict_warmup(lifecycle.drain_timeout_s)
     return httpd
 
 

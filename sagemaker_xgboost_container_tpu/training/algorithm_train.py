@@ -382,13 +382,7 @@ def _accelerator_runtime_present():
     try:
         from importlib.metadata import entry_points
 
-        eps = entry_points()
-        group = (
-            eps.select(group="jax_plugins")
-            if hasattr(eps, "select")
-            else eps.get("jax_plugins", [])
-        )
-        if len(list(group)):
+        if len(list(entry_points(group="jax_plugins"))):
             return True
     except Exception:  # metadata backends vary; absence of evidence -> no accel
         pass
@@ -433,7 +427,7 @@ def maybe_init_jax_distributed(sm_hosts, sm_current_host, port=12355):
     # plugin) instead of initializing one.
     platforms = (
         os.environ.get("JAX_PLATFORMS")
-        or getattr(jax.config, "jax_platforms", None)
+        or jax.config.jax_platforms
         or ""
     )
     if platforms:
@@ -448,22 +442,13 @@ def maybe_init_jax_distributed(sm_hosts, sm_current_host, port=12355):
         return False
     hosts = sorted(sm_hosts)
     try:
-        import inspect
-
-        kwargs = {}
-        # older jax (the >=0.4.30 contract floor) has no heartbeat kwarg;
-        # there the runtime's built-in default applies
-        if "heartbeat_timeout_seconds" in inspect.signature(
-            jax.distributed.initialize
-        ).parameters:
-            kwargs["heartbeat_timeout_seconds"] = int(
-                os.environ.get("GRAFT_HEARTBEAT_TIMEOUT_S", "100")
-            )
         jax.distributed.initialize(
             coordinator_address="{}:{}".format(hosts[0], port),
             num_processes=len(hosts),
             process_id=hosts.index(sm_current_host),
-            **kwargs,
+            heartbeat_timeout_seconds=int(
+                os.environ.get("GRAFT_HEARTBEAT_TIMEOUT_S", "100")
+            ),
         )
         logger.info(
             "jax.distributed up: %d processes, %d global devices",
@@ -494,8 +479,7 @@ def _reinit_jax_distributed(sm_hosts, sm_current_host):
     import jax
 
     try:
-        state = getattr(jax.distributed, "global_state", None)
-        if state is not None and getattr(state, "client", None) is not None:
+        if jax.distributed.is_initialized():
             jax.distributed.shutdown()
     except Exception as e:
         # a coordination client wedged on the dead host may refuse a clean
@@ -521,9 +505,24 @@ def train_job(
     aggregator can propose a shrink — a dead master is not survivable (the
     legacy jax heartbeat timeout applies) and is documented as such.
     """
+    from ..data.binning import BinnedMatrix
+    from ..ops.histogram import resolve_hist_knobs
+    from ..utils.device_runtime import start_device_runtime
+
     train_cfg = dict(train_cfg)
     num_devices_cap = train_cfg.pop("_num_devices", None)
     mesh = _training_mesh(num_devices_cap)
+    # one knob snapshot for the whole job: every generation the reform loop
+    # rebuilds the session with, so a shrink can never pick up mid-job env
+    # drift — and what the device-runtime line reports is what trains
+    hist_knobs = resolve_hist_knobs()
+    # before the first compile: arm the compile cache, say what we run on.
+    # Chunked ingest hands over matrices it sketched and binned on the host;
+    # the sketch lowering named in the line is the whole-file path's
+    start_device_runtime(
+        "train", mesh=mesh, knobs=hist_knobs,
+        ingest="chunked" if isinstance(train_dmatrix, BinnedMatrix) else "whole",
+    )
     # r2: ranking objectives shard rows by group and survival:cox gathers
     # global risk sets inside the jitted round, so every objective trains on
     # a data-parallel mesh
@@ -590,13 +589,8 @@ def train_job(
         from .profiling import xla_trace
 
         if kfold is None:
-            from ..ops.histogram import resolve_hist_knobs
             from . import elastic
 
-            # one knob snapshot for the whole job: every generation the
-            # reform loop rebuilds the session with, so a shrink can never
-            # pick up mid-job env drift
-            hist_knobs = resolve_hist_knobs()
             mesh_box = {"mesh": mesh}
 
             def _train_once():

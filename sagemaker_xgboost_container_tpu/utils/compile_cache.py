@@ -1,24 +1,27 @@
-"""Persistent XLA compilation cache (``GRAFT_COMPILE_CACHE_DIR``).
+"""Persistent XLA compilation cache, placed from outside the program.
 
 First-round XLA compile is the dominant fixed cost of every short job: a
-repeat training run, a CV fold sweep re-entering the same program shapes in
-a fresh process, and every ``bench.py`` probe child all pay it again.
-jax ships a persistent on-disk compilation cache
-(``jax_compilation_cache_dir``) keyed by the serialized HLO + compile
-options + backend version; arming it turns those repeat compiles into disk
-reads (ROADMAP item 4a: first-round compile stops polluting short probes).
+repeat training run, a server restart, each process of one chip-tool call.
+jax ships a persistent on-disk compilation cache keyed by the serialized
+HLO + compile options + backend version; the cache directory is part of
+how an entry is found again, so it must not move between processes.
 
-One knob: ``GRAFT_COMPILE_CACHE_DIR`` names the cache directory (created if
-missing). Resolved ONCE per process at training-session build time — the
-same host-side-snapshot discipline as the histogram knobs (the traced round
-path never reads env), and jax reads the config at compile time, so arming
-must happen before the first dispatch, never mid-job. Unset (the default)
-leaves jax's in-memory jit cache as the only cache — bit-for-bit today's
-behavior.
+Resolution order, once per process:
+
+* ``JAX_COMPILATION_CACHE_DIR`` set: jax itself already reads it — this
+  module sets no directory, so whoever runs the program (an operator, the
+  chip tool) decides where entries live and finds them again next time.
+* unset: one fixed directory, ``DEFAULT_CACHE_DIR`` (``.jax_cache`` beside
+  the package — inside the checkout, listed in ``.gitignore``). Never a
+  temp name, a pid or a time: a directory that moves never hits.
+
+Either way the write thresholds are zeroed so small programs cache too.
+``train`` and ``serve`` both arm it through
+``utils/device_runtime.start_device_runtime`` before their first compile.
 
 Cache correctness is jax's own contract (the key covers the executable's
-identity including backend/toolchain versions); a corrupt or unwritable
-directory degrades to a logged warning, never a failed job.
+identity including backend/toolchain versions); an unwritable directory
+degrades to a logged warning, never a failed job.
 """
 
 import logging
@@ -27,42 +30,42 @@ import threading
 
 logger = logging.getLogger(__name__)
 
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
+
 _lock = threading.Lock()
 _resolved = None  # None = not yet resolved; "" = resolved to disabled
 
 
-def maybe_enable_compile_cache():
-    """Arm jax's persistent compilation cache if the knob is set.
-
-    Returns the armed directory path, or None when the knob is unset or
-    arming failed. Idempotent and process-once: the first call resolves
-    ``GRAFT_COMPILE_CACHE_DIR`` and every later call returns the same
-    answer (flipping the env mid-process has no effect — sessions must see
-    one consistent compile-cache state, like every other session knob).
-    """
+def enable_compile_cache():
+    """Arm jax's persistent compilation cache; returns its directory, or
+    None when arming failed. Idempotent and process-once: the first call
+    resolves the directory and every later call returns the same answer
+    (sessions must see one consistent compile-cache state)."""
     global _resolved
     with _lock:
         if _resolved is not None:
             return _resolved or None
-        path = os.environ.get("GRAFT_COMPILE_CACHE_DIR", "").strip()
-        if not path:
-            _resolved = ""
-            return None
         import jax
 
-        prev_dir = jax.config.jax_compilation_cache_dir
+        from_env = os.environ.get(CACHE_DIR_ENV, "").strip()
+        path = from_env or DEFAULT_CACHE_DIR
         try:
             os.makedirs(path, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", path)
-            # cache every executable: short probes and repeat jobs pay many
-            # small compiles, which the default write thresholds would skip
+            if not from_env:
+                jax.config.update("jax_compilation_cache_dir", path)
+            # cache every executable: short jobs pay many small compiles,
+            # which the default write thresholds would skip
             jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
             jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
             # jax latches its cache state at the FIRST compile of the
-            # process: if anything jitted before this call (a model-load
-            # predict warmup, preprocessing), the new dir would silently
-            # never be read or written — clear the latch so arming works
-            # regardless of prior compiles
+            # process: if anything jitted before this call, the directory
+            # would silently never be read or written — clear the latch
             from jax.experimental.compilation_cache import (
                 compilation_cache as _cc,
             )
@@ -70,15 +73,14 @@ def maybe_enable_compile_cache():
             _cc.reset_cache()
         except Exception as e:  # arming is an optimization, never an outage
             logger.warning(
-                "GRAFT_COMPILE_CACHE_DIR=%r could not be armed: %s", path, e
+                "persistent XLA compilation cache at %r could not be armed: %s",
+                path, e,
             )
-            try:
-                # don't leave the cache half-armed while reporting disabled
-                jax.config.update("jax_compilation_cache_dir", prev_dir)
-            except Exception:
-                pass
             _resolved = ""
             return None
         _resolved = path
-        logger.info("persistent XLA compilation cache armed at %s", path)
+        logger.info(
+            "persistent XLA compilation cache at %s (%s)",
+            path, CACHE_DIR_ENV if from_env else "default",
+        )
         return path

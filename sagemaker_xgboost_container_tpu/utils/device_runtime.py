@@ -1,0 +1,109 @@
+"""What this process runs on, said once and out loud.
+
+The histogram kernel, the node-totals lowering and the quantile sketch all
+pick their implementation from ``jax.default_backend()``, and Pallas kernels
+are interpreted on the CPU. None of that is visible in a job log unless the
+program prints it, so a run that quietly landed on the wrong device looks
+like a slow run on the right one. ``start_device_runtime`` is the one place
+``train`` (training/algorithm_train.train_job) and ``serve``
+(serving/server.serving_entrypoint) pass through before their first compile:
+it arms the persistent compile cache and logs one ``device runtime:`` line
+with the backend, the device kind and count, the mesh, every
+backend-selected lowering and whether Pallas is interpreted.
+"""
+
+import json
+import logging
+import os
+import sys
+
+from .compile_cache import enable_compile_cache
+
+logger = logging.getLogger(__name__)
+
+RUNTIME_LINE_PREFIX = "device runtime: "
+
+
+def device_summary():
+    """{"platform", "kind", "count"} as jax reports the default backend's
+    devices. Initializes the backend — call after jax.distributed is up."""
+    import jax
+
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+
+
+def require_accelerator(who):
+    """Exit 2 when jax's default backend is the CPU and the CPU was not
+    asked for by name (``JAX_PLATFORMS=cpu``): a rate measured on the CPU
+    must never be printed by a run that was looking for a chip. Returns
+    :func:`device_summary` for the caller's result line."""
+    import jax
+
+    try:
+        found = jax.default_backend() != "cpu"
+        reason = "default backend is 'cpu'"
+    except RuntimeError as e:  # the requested platform failed to initialize
+        found, reason = False, str(e)
+    if not found and os.environ.get("JAX_PLATFORMS") != "cpu":
+        sys.stderr.write(
+            "{}: jax found no accelerator ({}); set JAX_PLATFORMS=cpu to ask "
+            "for a CPU run by name\n".format(who, reason)
+        )
+        sys.exit(2)
+    return device_summary()
+
+
+def _libtpu_version():
+    from importlib.metadata import PackageNotFoundError, version
+
+    try:
+        return version("libtpu")
+    except PackageNotFoundError:  # CPU-only image: nothing to report
+        return None
+
+
+def start_device_runtime(role, mesh=None, knobs=None, **facts):
+    """Arm the compile cache and log the ``device runtime:`` line.
+
+    ``role``: "train" | "serve". ``mesh``: the training mesh (None on one
+    device). ``knobs``: the session's HistKnobs snapshot; resolved here when
+    the caller has none (the server — it reports what a session in this
+    process would pick). ``facts``: what else only the caller knows about
+    the path taken (the trainer's ingest mode). Returns the logged fields.
+    """
+    import jax
+    import jaxlib
+
+    from ..data.binning import _sketch_impl
+    from ..ops.histogram import pallas_interpret, resolve_hist_knobs
+
+    cache_dir = enable_compile_cache()
+    if knobs is None:
+        knobs = resolve_hist_knobs()
+    fields = dict(device_summary())
+    fields.update(
+        role=role,
+        backend=jax.default_backend(),
+        mesh=(
+            {name: int(size) for name, size in mesh.shape.items()}
+            if mesh is not None
+            else None
+        ),
+        hist_impl=knobs.impl,
+        totals_impl=knobs.totals_impl,
+        route_impl=knobs.route_impl,
+        sketch_impl=_sketch_impl(),
+        pallas_interpret=pallas_interpret(),
+        compile_cache_dir=cache_dir,
+        jax=jax.__version__,
+        jaxlib=jaxlib.__version__,
+        libtpu=_libtpu_version(),
+        **facts,
+    )
+    logger.info("%s%s", RUNTIME_LINE_PREFIX, json.dumps(fields, sort_keys=True))
+    return fields

@@ -17,20 +17,23 @@ installed (setup.py loads it before they exist) — stdlib imports only at
 module level.
 """
 
-# floors, chosen at the versions the framework is developed/tested against;
-# no upper bounds (jax moves fast and upper-pinning a container base image
-# causes more breakage than it prevents — widen deliberately, with tests)
+# floors = the one installation the framework is built, tested and run on
+# the chip against (libtpu 0.0.34 rides the TPU image's jax[tpu] extra and
+# is absent from CPU images, so it is not an install requirement); no upper
+# bounds (jax moves fast and upper-pinning a container base image causes
+# more breakage than it prevents — raise deliberately, with tests)
 SUPPORTED = {
-    "jax": ">=0.4.30",
-    "numpy": ">=1.24",
-    "scipy": ">=1.10",
-    "pandas": ">=1.5",
-    "pyarrow": ">=10.0",
-    "scikit-learn": ">=1.2",
-    "protobuf": ">=3.20",
+    "jax": ">=0.9.0",
+    "jaxlib": ">=0.9.0",
+    "numpy": ">=2.0.2",
+    "scipy": ">=1.17.0",
+    "pandas": ">=3.0.3",
+    "pyarrow": ">=25.0.0",
+    "scikit-learn": ">=1.9.0",
+    "protobuf": ">=6.33.5",
     # violations() itself needs it, and python:…-slim images don't ship it
     # (pip only vendors a private copy)
-    "packaging": ">=21.0",
+    "packaging": ">=26.0",
 }
 
 

@@ -15,7 +15,7 @@ export XLA_FLAGS="--xla_force_host_platform_device_count=8"
 # The 60% coverage gate (reference: tox.ini:29-30) is MANDATORY in the full
 # tier: pytest-cov when installed, else the stdlib PEP 669 gate
 # (scripts/covgate.py, py3.12+). If neither can arm, the tier FAILS —
-# a gate that silently disarms is documentation, not CI (VERDICT r3).
+# a gate that silently disarms is documentation, not CI.
 COV_ARGS=()
 if [ "$TIER" = "full" ]; then
   if python -c "import pytest_cov" 2>/dev/null; then
@@ -142,20 +142,6 @@ if [ $rc -eq 0 ] && [ "$TIER" = "full" ]; then
   else
     rc=1
     echo "CI $TIER TIER FAILED (fleet smoke; see $ARTIFACT_DIR/traces)"
-  fi
-fi
-
-# bench trajectory (full): fold the per-PR BENCH_*/MULTICHIP_* snapshots at
-# the repo root into one trend report so a perf regression reads as a bend
-# in the curve; archived next to the bench-smoke artifact. Reporting-only
-# here (no --gate) — the snapshots are driver-owned history, not this run.
-if [ $rc -eq 0 ] && [ "$TIER" = "full" ]; then
-  if python "$REPO/scripts/bench_trend.py" --dir "$REPO" \
-      --out "$ARTIFACT_DIR/bench/bench_trend.json"; then
-    echo "bench trend: OK (artifact: $ARTIFACT_DIR/bench/bench_trend.json)"
-  else
-    rc=1
-    echo "CI $TIER TIER FAILED (bench trend; see $ARTIFACT_DIR/bench)"
   fi
 fi
 

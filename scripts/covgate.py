@@ -28,7 +28,7 @@ import sys
 
 PKG = "sagemaker_xgboost_container_tpu"
 
-# Known blind spots (VERDICT r4 weak #7): modules whose tests drive them OUT
+# Known blind spots: modules whose tests drive them OUT
 # of process, which sys.monitoring cannot see — their in-process percentages
 # under-report real coverage. Enumerated here so the artifact carries its own
 # exclusions; PARITY.md's gate section mirrors this list.

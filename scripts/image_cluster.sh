@@ -1,5 +1,5 @@
 #!/bin/bash
-# Multi-host built-image cluster tier (VERDICT r3 missing #1): run the
+# Multi-host built-image cluster tier: run the
 # shipping image as a 2-host docker-compose cluster against the fabricated
 # SageMaker filesystem — the repo analog of the reference's local_mode
 # compose harness (reference test/utils/local_mode.py:477-557) and its
@@ -16,7 +16,7 @@
 # Usage: scripts/image_cluster.sh [cluster|kill|mme|all|dry]
 # cluster/kill/mme/all need Docker + compose (v2 `docker compose` or v1
 # `docker-compose`) and network for the image build; exit 75 = environment
-# cannot run them (SKIP). `dry` (VERDICT r4 #5) needs NEITHER: it validates
+# cannot run them (SKIP). `dry` needs NEITHER: it validates
 # everything checkable without a docker daemon — Dockerfile structure and
 # COPY sources, the version contract + native-parser gates the build RUNs,
 # compose-file syntax, and console-script entrypoint wiring — so hosts

@@ -365,7 +365,7 @@ def test_tree_method_binning_map():
 
 
 def test_approx_resketch_matches_hist_quality(monkeypatch):
-    """tree_method=approx (r5: VERDICT r4 #8): per-dispatch hessian-weighted
+    """tree_method=approx (r5): per-dispatch hessian-weighted
     re-sketch, matching libxgboost's approx candidate refresh. Contract:
     (a) with GRAFT_APPROX_RESKETCH=0 the old single-sketch behavior is
     bit-identical to hist at the same candidate budget; (b) the default

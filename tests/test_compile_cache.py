@@ -1,18 +1,19 @@
-"""GRAFT_COMPILE_CACHE_DIR: the persistent XLA compilation cache.
+"""The persistent XLA compilation cache is placed from outside.
 
-The knob arms jax's on-disk compilation cache at training-session build
-(``utils/compile_cache.maybe_enable_compile_cache``), so repeat jobs and
-short bench probes stop paying first-round compile. The contract proven
-here: (1) the knob resolves once per process and never breaks a session;
-(2) a cold train run with the knob set populates the cache directory
-(cache-hit evidence for every later process); (3) a repeat run in a fresh
-process records materially less backend-compile time than the cold run.
+Resolution order (utils/compile_cache.py): ``JAX_COMPILATION_CACHE_DIR`` set
+-> jax already uses it and the code sets no directory; unset -> one fixed
+directory inside the checkout, never a temp name, a pid or a time. That
+``train`` and ``serve`` both arm it before their first compile, through the
+entry points, is asserted on the CPU rehearsal of ``chip_smoke.py``
+(tests/test_chip_smoke.py: trainer and server entries land under the
+directory the environment names); cold versus warm compile seconds are a chip
+observation (CHANGES.md PR 21).
 """
 
-import json
 import os
+import re
 import subprocess
-import sys
+import tempfile
 
 import pytest
 
@@ -20,102 +21,75 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture
-def fresh_cache_module(monkeypatch):
-    """The compile_cache module with its process-once latch reset (and
-    restored afterwards, so this test cannot re-arm jax config for the
-    rest of the suite)."""
+def cache_module(monkeypatch):
+    """compile_cache with its process-once latch reset, and jax's cache
+    config restored afterwards (the suite runs with the cache disabled)."""
+    import jax
+
     from sagemaker_xgboost_container_tpu.utils import compile_cache
 
     monkeypatch.setattr(compile_cache, "_resolved", None)
-    return compile_cache
+    saved = {
+        name: getattr(jax.config, name)
+        for name in (
+            "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes",
+        )
+    }
+    yield compile_cache
+    for name, value in saved.items():
+        jax.config.update(name, value)
 
 
-def test_unset_knob_resolves_disabled_once(fresh_cache_module, monkeypatch, tmp_path):
-    monkeypatch.delenv("GRAFT_COMPILE_CACHE_DIR", raising=False)
-    assert fresh_cache_module.maybe_enable_compile_cache() is None
-    # resolved once per process: a later env flip must not re-arm mid-job
-    monkeypatch.setenv("GRAFT_COMPILE_CACHE_DIR", str(tmp_path))
-    assert fresh_cache_module.maybe_enable_compile_cache() is None
-
-
-def test_set_knob_arms_jax_cache_dir(fresh_cache_module, monkeypatch, tmp_path):
+def test_env_dir_means_the_code_sets_no_directory(cache_module, monkeypatch, tmp_path):
     import jax
 
-    cache_dir = tmp_path / "xla-cache"
-    monkeypatch.setenv("GRAFT_COMPILE_CACHE_DIR", str(cache_dir))
-    prev = jax.config.jax_compilation_cache_dir
-    try:
-        armed = fresh_cache_module.maybe_enable_compile_cache()
-        assert armed == str(cache_dir)
-        assert cache_dir.is_dir()
-        assert jax.config.jax_compilation_cache_dir == str(cache_dir)
-        # idempotent: the second call returns the same resolution
-        assert fresh_cache_module.maybe_enable_compile_cache() == str(cache_dir)
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "outside"))
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda name, value: (updates.append(name), real_update(name, value))[1],
+    )
+    assert cache_module.enable_compile_cache() == str(tmp_path / "outside")
+    assert "jax_compilation_cache_dir" not in updates
+    # the zero write thresholds are still applied: small programs cache too
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+    assert jax.config.jax_persistent_cache_min_entry_size_bytes == -1
+    # resolved once per process: a later env flip must not re-arm mid-job
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "other"))
+    assert cache_module.enable_compile_cache() == str(tmp_path / "outside")
 
 
-def test_unwritable_dir_degrades_not_fails(fresh_cache_module, monkeypatch, tmp_path):
+def test_unset_env_uses_one_fixed_directory_inside_the_checkout(
+    cache_module, monkeypatch
+):
+    import jax
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    path = cache_module.enable_compile_cache()
+    assert path == cache_module.DEFAULT_CACHE_DIR == os.path.join(REPO_ROOT, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == path
+    # a directory that moves never hits: no temp name, pid or time in it
+    assert not path.startswith(tempfile.gettempdir())
+    assert str(os.getpid()) not in path
+    assert not re.search(r"\d{6,}", path)
+
+
+def test_unwritable_dir_degrades_not_fails(cache_module, monkeypatch, tmp_path):
     blocker = tmp_path / "blocker"
     blocker.write_text("not a directory")
-    monkeypatch.setenv("GRAFT_COMPILE_CACHE_DIR", str(blocker / "cache"))
-    assert fresh_cache_module.maybe_enable_compile_cache() is None
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(blocker / "cache"))
+    assert cache_module.enable_compile_cache() is None
 
 
-_CHILD = r"""
-import json, os, sys
-sys.path.insert(0, {repo!r})
-from sagemaker_xgboost_container_tpu.telemetry import register_runtime_gauges
-from sagemaker_xgboost_container_tpu.telemetry.cluster import compile_stats
-
-register_runtime_gauges()
-
-import numpy as np
-from sagemaker_xgboost_container_tpu.data.matrix import DataMatrix
-from sagemaker_xgboost_container_tpu.models import train
-
-rng = np.random.RandomState(0)
-X = rng.rand(200, 5).astype(np.float32)
-y = (X[:, 0] > 0.5).astype(np.float32)
-train(
-    {{"objective": "binary:logistic", "max_depth": 3, "max_bin": 32}},
-    DataMatrix(X, labels=y),
-    num_boost_round=2,
-)
-print(json.dumps({{"compile_s": compile_stats()["seconds"]}}))
-"""
-
-
-def _train_child(cache_dir):
-    env = dict(os.environ)
-    env.update(
-        JAX_PLATFORMS="cpu",
-        GRAFT_COMPILE_CACHE_DIR=str(cache_dir),
-        XLA_FLAGS="",  # no forced multi-device: one tiny single-chip child
-    )
-    out = subprocess.run(
-        [sys.executable, "-c", _CHILD.format(repo=REPO_ROOT)],
-        env=env,
-        capture_output=True,
-        text=True,
-        timeout=300,
-    )
-    assert out.returncode == 0, out.stderr[-2000:]
-    return json.loads(out.stdout.strip().splitlines()[-1])
-
-
-def test_repeat_train_run_hits_persistent_cache(tmp_path):
-    """Cold run populates GRAFT_COMPILE_CACHE_DIR; the repeat run (fresh
-    process, same program shapes) serves its executables from disk —
-    cache entries exist and backend-compile seconds drop vs the cold run
-    (the acceptance proof for the phases_ms["compile"] ~0 claim)."""
-    cache_dir = tmp_path / "xla-cache"
-    cold = _train_child(cache_dir)
-    entries = [f for f in os.listdir(cache_dir) if f.endswith("-cache")]
-    assert entries, "cold run left no persistent cache entries"
-    warm = _train_child(cache_dir)
-    # the cache-entry assertion above is the functional proof; the timing
-    # check stays deliberately loose (measured ~0.25x on the dev box, but a
-    # loaded CI worker adds fixed per-process overhead the cache can't
-    # remove) — strictly-less is regression evidence without flake risk
-    assert warm["compile_s"] < cold["compile_s"], (cold, warm)
+def test_old_private_knob_is_gone():
+    old_knob = "GRAFT_COMPILE" + "_CACHE_DIR"  # replaced, not kept beside
+    hits = subprocess.run(
+        ["grep", "-rl", old_knob, "--exclude=CHANGES.md", "--exclude=ISSUE.md",
+         "--exclude=PERF_LEDGER.jsonl", "--exclude-dir=.git",
+         "--exclude-dir=.chipwork", "--exclude-dir=chiprun_out", REPO_ROOT],
+        capture_output=True, text=True,
+    ).stdout.split()
+    assert hits == []

@@ -1,6 +1,6 @@
 """Contract tests for the stdlib coverage gate (scripts/covgate.py) — the
 reference's --cov-fail-under=60 (tox.ini:29-30) must actually evaluate, not
-silently disarm (VERDICT r3 missing #2)."""
+silently disarm."""
 
 import json
 import os

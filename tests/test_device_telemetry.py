@@ -344,37 +344,3 @@ def test_debug_profile_capture_and_404(device_env, tmp_path):
         assert err.value.code == 400
     finally:
         server.stop()
-
-
-# ------------------------------------------------------------- bench_trend
-def test_bench_trend_report_and_gate(tmp_path):
-    from scripts.bench_trend import build_report, gate
-
-    def snap(n, value, fallback=False):
-        metric = "rounds/sec" + (" [CPU FALLBACK - x]" if fallback else "")
-        (tmp_path / "BENCH_r{:02d}.json".format(n)).write_text(
-            json.dumps(
-                {
-                    "n": n,
-                    "rc": 0,
-                    "parsed": {"metric": metric, "value": value, "unit": "rounds/sec"},
-                }
-            )
-        )
-
-    snap(1, 1.0, fallback=True)
-    snap(2, 2.0)
-    snap(3, 1.0)
-    (tmp_path / "MULTICHIP_r03.json").write_text(
-        json.dumps({"n_devices": 8, "rc": 0, "ok": True, "skipped": False})
-    )
-    report = build_report(str(tmp_path))
-    assert [p["n"] for p in report["bench"]] == [1, 2, 3]
-    assert report["summary"]["best_value"] == 2.0
-    assert report["multichip"][0]["ok"] is True
-    # newest (1.0) is 50% below best same-family prior (2.0): gate at 15% fails
-    ok, message = gate(report, 0.15)
-    assert not ok and "REGRESSION" in message
-    # generous tolerance passes; the CPU-fallback r01 never enters the compare
-    ok, _ = gate(report, 0.6)
-    assert ok

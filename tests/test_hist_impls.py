@@ -191,7 +191,7 @@ def test_lossguide_subtraction_matches_direct(rand_problem):
 def test_lossguide_predict_depth_adaptive():
     """In-training eval of a lossguide tree iterates only to the true depth
     (while_loop early exit), and leaf routing matches the reference direct
-    traversal (VERDICT r1 weak #6)."""
+    traversal."""
     from sagemaker_xgboost_container_tpu.data.matrix import DataMatrix
     from sagemaker_xgboost_container_tpu.models import train
 
@@ -449,3 +449,42 @@ def test_multiclass_vmap_over_pallas():
     np.testing.assert_allclose(
         np.asarray(f1.predict(X)), np.asarray(f0.predict(X)), atol=1e-4
     )
+
+
+@pytest.mark.parametrize(
+    "backend, interpreted",
+    [("cpu", True), ("tpu", False), ("gpu", False), ("some_new_accelerator", False)],
+)
+def test_pallas_interpreted_only_on_the_cpu_backend(monkeypatch, backend, interpreted):
+    """An accelerator under any name compiles the Pallas kernels or fails
+    loudly; only the CPU backend (tests, rehearsals) interprets them."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert hist_mod.pallas_interpret() is interpreted
+
+
+def test_pallas_feature_group_is_tile_aligned_and_bounded():
+    """The accumulator block spans one feature group: whole sublane tiles
+    of the bin dtype, at most 32 features, whatever the matrix width."""
+    fg = hist_mod._pallas_feature_group
+    assert fg(28, np.uint16) == 32 and fg(28, np.uint8) == 32
+    assert fg(8, np.uint16) == 16 and fg(8, np.uint8) == 32
+    assert fg(9, np.int32) == 16
+    assert fg(136, np.uint16) == 32 and fg(5000, np.uint8) == 32
+
+
+def test_pallas_hist_wider_than_one_feature_group():
+    """More features than one group: the grid's feature axis runs several
+    accumulator blocks, each equal to the flat reference's columns."""
+    rng = np.random.RandomState(3)
+    n, d, num_bins, W = 400, 70, 17, 2
+    bins = rng.randint(0, num_bins, size=(n, d)).astype(np.uint8)
+    grad = rng.randn(n).astype(np.float32)
+    hess = rng.rand(n).astype(np.float32)
+    node = rng.randint(-1, W, size=n).astype(np.int32)
+    args = (jnp.asarray(bins), jnp.asarray(grad), jnp.asarray(hess), jnp.asarray(node))
+    Gp, Hp = hist_mod._hist_pallas(*args, W, num_bins)
+    Gf, Hf = hist_mod._hist_flat(*args, W, num_bins)
+    np.testing.assert_allclose(np.asarray(Gp), np.asarray(Gf), atol=2e-4)
+    np.testing.assert_allclose(np.asarray(Hp), np.asarray(Hf), atol=2e-4)

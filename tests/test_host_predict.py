@@ -1,7 +1,7 @@
 """Host (numpy) small-payload predictor == compiled device predictor.
 
-Serving's small-batch strategy (BASELINE.md serving metric; reference C++
-predictor at serve_utils.py:244-250 has no dispatch floor): payloads at or
+Serving's small-batch strategy (the reference C++ predictor at
+serve_utils.py:244-250 has no dispatch floor): payloads at or
 below GRAFT_HOST_PREDICT_ROWS run a vectorized numpy traversal that must be
 bit-identical to the XLA kernel on every routing rule — numeric splits,
 NaN-missing default directions, categorical set-membership, invalid

@@ -1,4 +1,4 @@
-"""Built-image integration tier (VERDICT r2 missing #1).
+"""Built-image integration tier.
 
 Runs scripts/image_smoke.sh: builds docker/Dockerfile.tpu (CPU variant via
 the JAX_SPEC build-arg), fabricates the SageMaker /opt/ml filesystem the

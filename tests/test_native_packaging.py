@@ -1,6 +1,6 @@
 """Packaging test: a built wheel ships the compiled native data plane.
 
-VERDICT r1 weak #8: `native/fastdata.cpp` was only compiled for whoever ran
+`native/fastdata.cpp` was only compiled for whoever ran
 a compiler manually; `pip install .` silently fell back to the Python
 parser. The wheel must now contain the `_fastdata` shared object, and the
 object must expose the C ABI the ctypes binding drives.

@@ -5,7 +5,7 @@ disabled fast path), Chrome-trace export validity, the end-to-end training
 tree (round -> {collective, checkpoint, compile}), the flight-recorder dump
 on a watchdog abort (exit 79), correlation-id -> trace-id propagation
 across the serving batcher's worker thread, device-sync attribution
-(SM_TRACE_DEVICE_SYNC), and the bench backend-probe error capture.
+(SM_TRACE_DEVICE_SYNC), and the bench result line.
 """
 
 import json
@@ -541,52 +541,11 @@ def test_device_sync_off_adds_no_phase_keys(monkeypatch, capsys):
 
 
 # ------------------------------------------------------------ bench satellite
-class TestBenchBackendProbe:
-    def test_backend_healthy_captures_timeout(self, monkeypatch):
-        import subprocess
-
-        import bench
-
-        def fake_run(*args, **kwargs):
-            raise subprocess.TimeoutExpired(cmd="probe", timeout=1)
-
-        monkeypatch.setattr(bench.subprocess, "run", fake_run)
-        ok, n_devices, err = bench._backend_healthy(1)
-        assert ok is False and n_devices == 0
-        assert "timed out" in err["error"]
-        assert err["elapsed_s"] >= 0.0
-
-    def test_backend_healthy_captures_stderr_tail(self, monkeypatch):
-        import bench
-
-        class _Result:
-            returncode = 1
-            stdout = "DEVICES 4\n"
-            stderr = "boot log\nRuntimeError: tunnel wedged at init\n"
-
-        monkeypatch.setattr(
-            bench.subprocess, "run", lambda *a, **k: _Result()
-        )
-        ok, n_devices, err = bench._backend_healthy(5)
-        assert ok is False and n_devices == 4
-        assert "tunnel wedged at init" in err["error"]
-
-    def test_emit_injects_backend_init_error(self, monkeypatch, capsys):
-        import bench
-
-        monkeypatch.setattr(
-            bench,
-            "_backend_init_error",
-            {"error": "probe timed out", "elapsed_s": 90.0},
-        )
-        bench._emit({"metric": "m", "value": 0.0, "unit": "rounds/sec"})
-        doc = json.loads(capsys.readouterr().out.strip())
-        assert doc["backend_init_error"]["error"] == "probe timed out"
-        assert doc["backend_init_error"]["elapsed_s"] == 90.0
-
+class TestBenchResultLine:
     def test_bench_final_line_carries_attribution(self, monkeypatch, capsys):
-        """The acceptance contract: the child's final JSON line has the
-        compile/host/device/collective attribution section."""
+        """The acceptance contract: the final JSON line has the
+        compile/host/device/collective attribution section and names the
+        device it ran on."""
         import bench
 
         monkeypatch.setattr(bench, "N_ROWS", 400)
@@ -607,3 +566,17 @@ class TestBenchBackendProbe:
             assert key in attribution, key
             assert attribution[key] >= 0.0
         assert attribution["host_ms"] > 0.0  # sync sampling was armed
+        assert doc["device"] == {"platform": "cpu", "kind": "cpu", "count": 8}
+
+    def test_bench_refuses_a_cpu_nobody_asked_for(self, monkeypatch, capsys):
+        """No chip and no explicit JAX_PLATFORMS=cpu: exit 2, no result line
+        (a CPU timing is never printed under the device metric's name)."""
+        import bench
+
+        monkeypatch.delenv("JAX_PLATFORMS")
+        with pytest.raises(SystemExit) as exit_info:
+            bench.main()
+        assert exit_info.value.code == 2
+        captured = capsys.readouterr()
+        assert "no accelerator" in captured.err
+        assert not [l for l in captured.out.splitlines() if l.startswith("{")]

@@ -1,4 +1,4 @@
-"""Dependency-version contract (VERDICT r2 missing #2).
+"""Dependency-version contract.
 
 The reference asserts its pinned dependency set from inside the built image
 (reference test/integration/local/test_versions.py runs

@@ -44,7 +44,7 @@ def distributed_train_worker(rank, world, port, q):
 
 def distributed_metrics_worker(rank, world, port, q):
     """2-process pod: device metrics must be globally exact and identical on
-    every host (VERDICT r1 missing #1); feval rides the host weighted-mean
+    every host; feval rides the host weighted-mean
     combine and must also agree across hosts."""
     import os
 
@@ -410,7 +410,7 @@ def update_worker(rank, world, port, q):
 
 def host_loss_worker(rank, world, port, q):
     """2-process pod where rank 1 dies mid-train (simulated host loss /
-    preemption). Contract under test (VERDICT r2 missing #5): the SURVIVOR
+    preemption). Contract under test: the SURVIVOR
     must terminate with an error within ~heartbeat_timeout — the job fails
     loudly instead of hanging in the psum or continuing on partial data.
     Recovery is restart + checkpoint resume (test_resume_from_checkpoint)."""
@@ -475,7 +475,7 @@ def host_loss_worker(rank, world, port, q):
 
 def distributed_2d_mesh_worker(rank, world, port, q):
     """2 processes x (2 data x 2 feature) mesh: the data axis spans hosts,
-    the feature axis stays within each host (VERDICT r1 item 4). Trains with
+    the feature axis stays within each host. Trains with
     colsample + monotone active."""
     import os
 

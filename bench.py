@@ -119,12 +119,12 @@ def main():
     enable_compile_cache()
 
     # attribution plumbing: the jax.monitoring compile listener feeds
-    # compile_stats, and SM_TRACE_DEVICE_SYNC=1 makes the session fence
-    # every dispatch so host_dispatch/device_sync phases are measured (the
-    # bench loop blocks per dispatch anyway, so the fence costs nothing)
+    # compile_stats; every dispatch records host_dispatch/device_sync, and
+    # SM_TRACE_DEVICE_SYNC=1 adds the fence on everything a dispatch put in
+    # flight (the bench loop blocks per dispatch anyway, so it costs nothing)
     os.environ.setdefault("SM_TRACE_DEVICE_SYNC", "1")
     # arm the device window too: the session's compiled-cost introspection
-    # (training.compiled) plus the roofline stamp below ride the same gate
+    # (training.compiled, with the stage table's summary)
     os.environ.setdefault("SM_DEVICE_TELEMETRY", "1")
     # and the model window: the final JSON stamps a train metric + the last
     # round's learning stats so the result tracks model quality next to
@@ -294,18 +294,6 @@ def main():
         "phases_ms": phases_ms,
         "attribution": attribution,
     }
-    # roofline stamp for the measured window: achieved FLOPs/s and bytes/s
-    # against the compiled cost captured at session build (device window)
-    from sagemaker_xgboost_container_tpu.telemetry import device as device_telemetry
-
-    device_ms = _delta("device_sync") * 1000
-    source = "device_sync"
-    if device_ms <= 0.0:
-        device_ms = max(elapsed * 1000.0 - compile_ms - host_ms, 0.0)
-        source = "residual"
-    roofline = device_telemetry.maybe_roofline(device_ms, done, source)
-    if roofline is not None:
-        doc["roofline"] = roofline
     # model-quality stamp (SM_MODEL_TELEMETRY): the final train metric plus
     # the last dispatch's on-device learning stats: quality next to
     # throughput

@@ -27,6 +27,7 @@ import os
 
 import numpy as np
 
+from ..telemetry.spans import span
 from ..toolkit import exceptions as exc
 
 
@@ -292,6 +293,14 @@ def _device_cut_points(features, w, max_cuts):
     return [mids[f, : int(counts[f])].copy() for f in range(d)]
 
 
+def _host_bytes(*arrays):
+    """Bytes of those arrays that are host (numpy) arrays: what handing them
+    to a device kernel uploads, as float32."""
+    return sum(
+        int(a.size) * 4 for a in arrays if isinstance(a, np.ndarray)
+    )
+
+
 def compute_cut_points(features, weights=None, max_bin=256):
     """Per-feature cut thresholds via weighted quantiles. NaN = missing.
 
@@ -305,16 +314,27 @@ def compute_cut_points(features, weights=None, max_bin=256):
         raise exc.UserError("max_bin must be at least 2")
     w = np.ones(n, dtype=np.float32) if weights is None else weights
     max_cuts = n if max_bin is None else max_bin - 1
-    if max_bin is not None and n > 0 and _sketch_impl() == "device":
-        return _device_cut_points(features, w, max_cuts)
-    cuts = []
-    order = np.argsort(features, axis=0, kind="stable")
-    for f in range(d):
-        col = features[order[:, f], f]
-        colw = w[order[:, f]]
-        valid = ~np.isnan(col)
-        cuts.append(_select_cuts(col[valid], colw[valid], max_cuts))
-    return cuts
+    on_device = max_bin is not None and n > 0 and _sketch_impl() == "device"
+    attributes = {
+        "rows": n,
+        "columns": d,
+        "impl": "device" if on_device else "host",
+        # the device kernel takes the float matrix and the weights; what is
+        # on the device already (the approx re-sketch stages it) moves nothing
+        "bytes_up": _host_bytes(features, w) if on_device else 0,
+    }
+    # the span ends where the cuts are on the host (np.asarray(mids))
+    with span("setup.sketch", attributes=attributes):
+        if on_device:
+            return _device_cut_points(features, w, max_cuts)
+        cuts = []
+        order = np.argsort(features, axis=0, kind="stable")
+        for f in range(d):
+            col = features[order[:, f], f]
+            colw = w[order[:, f]]
+            valid = ~np.isnan(col)
+            cuts.append(_select_cuts(col[valid], colw[valid], max_cuts))
+        return cuts
 
 
 def cuts_from_summaries(summaries, max_bin):
@@ -347,19 +367,34 @@ def cuts_from_summaries(summaries, max_bin):
     ]
 
 
-def apply_cut_points(features, cut_points, max_bin):
-    """Map float features to bin indices; NaN -> missing bin (== max_bin)."""
+def apply_cut_points(features, cut_points, max_bin, name=None):
+    """Map float features to bin indices; NaN -> missing bin (== max_bin).
+    ``name`` says which matrix it is (``train``, an evaluation set's name)
+    on the ``setup.bin_apply`` span."""
     n, d = features.shape
     dtype = np.uint8 if max_bin + 1 <= 256 else np.uint16
-    if n > 0 and d > 0 and _sketch_impl() == "device":
-        return _device_apply(features, cut_points, max_bin, dtype)
-    bins = np.empty((n, d), dtype=dtype)
-    for f in range(d):
-        col = features[:, f]
-        idx = np.searchsorted(cut_points[f], col, side="right")
-        idx[np.isnan(col)] = max_bin
-        bins[:, f] = idx.astype(dtype)
-    return bins
+    on_device = n > 0 and d > 0 and _sketch_impl() == "device"
+    attributes = {
+        "rows": n,
+        "columns": d,
+        "set": name or "",
+        "impl": "device" if on_device else "host",
+        # up: the float matrix; down: the kernel's int32 bin indices, which
+        # are narrowed to ``dtype`` on the host
+        "bytes_up": _host_bytes(features) if on_device else 0,
+        "bytes_down": n * d * 4 if on_device else 0,
+    }
+    # the span ends where the bins are on the host (np.asarray(out))
+    with span("setup.bin_apply", attributes=attributes):
+        if on_device:
+            return _device_apply(features, cut_points, max_bin, dtype)
+        bins = np.empty((n, d), dtype=dtype)
+        for f in range(d):
+            col = features[:, f]
+            idx = np.searchsorted(cut_points[f], col, side="right")
+            idx[np.isnan(col)] = max_bin
+            bins[:, f] = idx.astype(dtype)
+        return bins
 
 
 @functools.lru_cache(maxsize=None)
@@ -406,8 +441,9 @@ def _device_apply(features, cut_points, max_bin, dtype):
     return np.asarray(out).astype(dtype)
 
 
-def bin_matrix(dmatrix, max_bin=256, cut_points=None, exact_cap=None):
-    """DataMatrix -> BinnedMatrix (computing cuts unless provided).
+def bin_matrix(dmatrix, max_bin=256, cut_points=None, exact_cap=None, name=None):
+    """DataMatrix -> BinnedMatrix (computing cuts unless provided). ``name``
+    labels the matrix on its ``setup.bin_apply`` span.
 
     ``max_bin=None`` = exact-greedy binning: cuts at every adjacent-distinct
     midpoint, and the bin width sized by the data (see compute_cut_points).
@@ -438,7 +474,7 @@ def bin_matrix(dmatrix, max_bin=256, cut_points=None, exact_cap=None):
         raise exc.AlgorithmError(
             "cut selection produced {} cuts for max_bin {}".format(longest, max_bin)
         )
-    bins = apply_cut_points(dmatrix.features, cut_points, max_bin)
+    bins = apply_cut_points(dmatrix.features, cut_points, max_bin, name=name)
     return BinnedMatrix(
         bins,
         cut_points,

@@ -21,6 +21,7 @@ bool stop / after_training) so the orchestration layer's checkpoint, early
 stop, and monitor callbacks port naturally.
 """
 
+import contextlib
 import functools
 import logging
 import os
@@ -46,6 +47,17 @@ from ..ops.tree_build import (
     tree_from_packed,
     unpack_tree,
 )
+from ..telemetry import device as device_telemetry
+from ..telemetry.cluster import compile_stats, install_program_listener
+from ..telemetry.device import (
+    STAGE_EVAL_APPLY,
+    STAGE_EVAL_METRIC,
+    STAGE_GRAD,
+    STAGE_LEAF_MARGIN,
+    STAGE_PACK,
+    stage,
+)
+from ..telemetry.spans import active_recorder, begin_span, end_span, span
 from ..toolkit import exceptions as exc
 from ..utils.faults import fault_point
 from . import eval_metrics
@@ -246,23 +258,24 @@ def _apply_packed_tree(packed, bins, margins, num_group, num_parallel, depth,
     routing knob must arrive as ``route_impl`` — the session's
     ``hist_knobs.route_impl`` snapshot, never a trace-time env read.
     """
-    tree = tree_from_packed(packed)
 
     def one(t):
         return predict_binned(t, bins, depth, num_bins, route_impl=route_impl)
 
-    if num_group == 1:
+    with stage(STAGE_EVAL_APPLY):
+        tree = tree_from_packed(packed)
+        if num_group == 1:
+            if num_parallel > 1:
+                delta = jax.vmap(one)(tree).sum(axis=0)
+            else:
+                delta = one(tree)
+            return margins + delta
         if num_parallel > 1:
-            delta = jax.vmap(one)(tree).sum(axis=0)
+            # packed [P, C, ...]: sum the bagged parallel trees per class
+            deltas = jax.vmap(jax.vmap(one))(tree).sum(axis=0)
         else:
-            delta = one(tree)
-        return margins + delta
-    if num_parallel > 1:
-        # packed [P, C, ...]: sum the bagged parallel trees per class
-        deltas = jax.vmap(jax.vmap(one))(tree).sum(axis=0)
-    else:
-        deltas = jax.vmap(one)(tree)
-    return margins + deltas.T
+            deltas = jax.vmap(one)(tree)
+        return margins + deltas.T
 
 
 @functools.lru_cache(maxsize=None)
@@ -385,6 +398,10 @@ class _TrainingSession:
         has_feval=False,
         hist_knobs=None,
     ):
+        # the one jax.monitoring listener: program loads (trace, lower,
+        # compile, cache load) count under the span that is open, whoever
+        # called train() (algorithm_train and serve install it as well)
+        install_program_listener()
         self.config = config
         self.objective = forest.objective()
         self.num_group = self.objective.num_output_group
@@ -544,6 +561,7 @@ class _TrainingSession:
                 config.max_bin,
                 cut_points=shared_cuts,
                 exact_cap=config.exact_bin_cap,
+                name="train",
             )
         self.cuts = self.train_binned.cut_points
         self.eval_sets = []
@@ -570,7 +588,9 @@ class _TrainingSession:
                     )
                 binned = dm
             else:
-                binned = bin_matrix(dm, config.max_bin, cut_points=self.cuts)
+                binned = bin_matrix(
+                    dm, config.max_bin, cut_points=self.cuts, name=name
+                )
             self.eval_sets.append((name, dm, binned))
 
         def _agreed_pad(num_row):
@@ -614,8 +634,11 @@ class _TrainingSession:
         d_pad = padded_feature_width(d_real, self.n_feature_shards)
         self.d_pad = d_pad
 
+        self._bytes_put = 0  # host bytes handed to the device by _put
+
         def _put(local_np, spec):
             """Local host array -> placed device array (global across procs)."""
+            self._bytes_put += int(local_np.nbytes)
             if self.mesh is None:
                 return jnp.asarray(local_np)
             from jax.sharding import NamedSharding
@@ -663,27 +686,28 @@ class _TrainingSession:
                 "support per-iteration re-binning)."
             )
             self.approx_resketch = False
-        self.labels = _put(_layout_rows(labels, 0.0), P("data"))
-        self.weights = _put(_layout_rows(dtrain.get_weight(), 0.0), P("data"))
-        self.groups = dtrain.groups
-        if self._rank_index_np is not None:
-            self.rank_index_dev = _put(self._rank_index_np, P("data", None, None))
-        elif self.row_index is not None:
-            self.rank_index_dev = self.row_index
-        else:
-            self.rank_index_dev = jnp.zeros((1, 1), jnp.int32)  # inert dummy
+        with self._upload_span("labels_weights_margins"):
+            self.labels = _put(_layout_rows(labels, 0.0), P("data"))
+            self.weights = _put(_layout_rows(dtrain.get_weight(), 0.0), P("data"))
+            self.groups = dtrain.groups
+            if self._rank_index_np is not None:
+                self.rank_index_dev = _put(self._rank_index_np, P("data", None, None))
+            elif self.row_index is not None:
+                self.rank_index_dev = self.row_index
+            else:
+                self.rank_index_dev = jnp.zeros((1, 1), jnp.int32)  # inert dummy
 
-        base = self.objective.base_margin(forest.base_score)
-        shape = (n_pad,) if self.num_group == 1 else (n_pad, self.num_group)
-        if forest.trees:
-            margin = _predict_margin_rows(forest, dtrain).reshape(
-                (self.n,) if self.num_group == 1 else (self.n, self.num_group)
-            )
-            self.margins = _put(
-                _layout_rows(margin.astype(np.float32), base), margin_spec
-            )
-        else:
-            self.margins = _put(np.full(shape, base, np.float32), margin_spec)
+            base = self.objective.base_margin(forest.base_score)
+            shape = (n_pad,) if self.num_group == 1 else (n_pad, self.num_group)
+            if forest.trees:
+                margin = _predict_margin_rows(forest, dtrain).reshape(
+                    (self.n,) if self.num_group == 1 else (self.n, self.num_group)
+                )
+                self.margins = _put(
+                    _layout_rows(margin.astype(np.float32), base), margin_spec
+                )
+            else:
+                self.margins = _put(np.full(shape, base, np.float32), margin_spec)
 
         # eval-set device state: bins cached once, margins incremental;
         # labels/weights kept on device for batched device-side metrics
@@ -692,33 +716,34 @@ class _TrainingSession:
         self.eval_labels = []
         self.eval_weights = []
         self._eval_pads = []  # per eval set: padded row count (None = shared)
-        for name, dm, binned in self.eval_sets:
-            if binned is self.train_binned:
-                self.eval_bins.append(None)     # shares training margins
-                self.eval_margins.append(None)
-                self.eval_labels.append(self.labels)
-                self.eval_weights.append(self.weights)
-                self._eval_pads.append(None)
-                continue
-            m_pad = _agreed_pad(dm.num_row)
-            self._eval_pads.append(m_pad)
-            self.eval_bins.append(
-                _put(_pad_rows(binned.bins, m_pad, binned.max_bin), P("data", None))
-            )
-            self.eval_labels.append(_put(_pad_rows(dm.labels, m_pad, 0.0), P("data")))
-            self.eval_weights.append(
-                _put(_pad_rows(dm.get_weight(), m_pad, 0.0), P("data"))
-            )
-            eshape = (m_pad,) if self.num_group == 1 else (m_pad, self.num_group)
-            if forest.trees:
-                em = _predict_margin_rows(forest, dm).reshape(
-                    (dm.num_row,) if self.num_group == 1 else (dm.num_row, self.num_group)
+        with self._upload_span("eval_sets"):
+            for name, dm, binned in self.eval_sets:
+                if binned is self.train_binned:
+                    self.eval_bins.append(None)     # shares training margins
+                    self.eval_margins.append(None)
+                    self.eval_labels.append(self.labels)
+                    self.eval_weights.append(self.weights)
+                    self._eval_pads.append(None)
+                    continue
+                m_pad = _agreed_pad(dm.num_row)
+                self._eval_pads.append(m_pad)
+                self.eval_bins.append(
+                    _put(_pad_rows(binned.bins, m_pad, binned.max_bin), P("data", None))
                 )
-                self.eval_margins.append(
-                    _put(_pad_rows(em.astype(np.float32), m_pad, base), margin_spec)
+                self.eval_labels.append(_put(_pad_rows(dm.labels, m_pad, 0.0), P("data")))
+                self.eval_weights.append(
+                    _put(_pad_rows(dm.get_weight(), m_pad, 0.0), P("data"))
                 )
-            else:
-                self.eval_margins.append(_put(np.full(eshape, base, np.float32), margin_spec))
+                eshape = (m_pad,) if self.num_group == 1 else (m_pad, self.num_group)
+                if forest.trees:
+                    em = _predict_margin_rows(forest, dm).reshape(
+                        (dm.num_row,) if self.num_group == 1 else (dm.num_row, self.num_group)
+                    )
+                    self.eval_margins.append(
+                        _put(_pad_rows(em.astype(np.float32), m_pad, base), margin_spec)
+                    )
+                else:
+                    self.eval_margins.append(_put(np.full(eshape, base, np.float32), margin_spec))
 
         self.rng = jax.random.PRNGKey(config.seed)
 
@@ -799,18 +824,21 @@ class _TrainingSession:
         self._hist_comm_ms = None  # lazily calibrated at the first dispatch
         self._set_comm_round_fields()
 
-        # device-sync attribution sampling (SM_TRACE_DEVICE_SYNC = N):
-        # every Nth dispatch is split by a block_until_ready fence into a
-        # `host_dispatch` span (python + XLA dispatch until the async call
-        # returns) and a `device_sync` span (waiting on device compute) —
-        # the host/device split the flat round record can't see. Resolved
-        # ONCE here, host-side, like the hist knobs: the traced round path
-        # never reads env. 0 (default) means no fences, no spans.
+        # every dispatch records a `host_dispatch` span (python + XLA
+        # dispatch until the async call returns) and a `device_sync` span
+        # (the transfer of the packed trees, which blocks on the round
+        # program and is there anyway). SM_TRACE_DEVICE_SYNC = N adds a
+        # block_until_ready fence on every Nth dispatch inside `device_sync`:
+        # the K = 1 path's eval-apply programs are separate dispatches that
+        # the tree transfer does not wait for. Resolved ONCE here, host-side,
+        # like the hist knobs: the traced round path never reads env.
         from ..telemetry.tracing import DEVICE_SYNC_ENV
         from ..utils.envconfig import env_int
 
         self._device_sync_every = env_int(DEVICE_SYNC_ENV, 0, minimum=0)
         self._dispatch_index = 0
+        self._turnaround = None  # the open `host_turnaround` span
+        self._first_dispatch = None  # the open `setup.first_dispatch` span
 
         # model-quality plane (SM_MODEL_TELEMETRY): resolved ONCE here,
         # host-side, like the hist knobs — unset traces exactly the pre-PR
@@ -826,9 +854,10 @@ class _TrainingSession:
         if self.learning_stats:
             model_telemetry.capture_drift_baseline(self.train_binned)
 
-        self._round_fn = self._make_round_fn()
-        self._apply_fn = self._make_apply_fn()
-        self._introspect_compiled_cost()
+        with span("setup.program_build"):
+            self._round_fn = self._make_round_fn()
+            self._apply_fn = self._make_apply_fn()
+        self._introspect_compiled_cost(self._register_round_program())
 
     # ------------------------------------------------------------------ jit
     def _grad_hess_fn(self):
@@ -980,20 +1009,22 @@ class _TrainingSession:
                 shard_rng = jax.random.fold_in(rng, jax.lax.axis_index(axis_name))
             else:
                 shard_rng = rng
-            if ranking_grads is not None:
-                g, h = ranking_grads(margins, labels, weights, rank_index)
-            else:
-                g, h = grad_hess(margins, labels, weights)
+            with stage(STAGE_GRAD):
+                if ranking_grads is not None:
+                    g, h = ranking_grads(margins, labels, weights, rank_index)
+                else:
+                    g, h = grad_hess(margins, labels, weights)
 
             def sampled(rng_k, gc, hc):
                 if subsample >= 1.0:
                     return gc, hc
-                keep = (
-                    jax.random.uniform(rng_k, (bins.shape[0],)) < subsample
-                ).astype(jnp.float32)
-                if gc.ndim == 1:
-                    return gc * keep, hc * keep
-                return gc * keep[:, None], hc * keep[:, None]
+                with stage(STAGE_GRAD):
+                    keep = (
+                        jax.random.uniform(rng_k, (bins.shape[0],)) < subsample
+                    ).astype(jnp.float32)
+                    if gc.ndim == 1:
+                        return gc * keep, hc * keep
+                    return gc * keep[:, None], hc * keep[:, None]
 
             trees = []
             if num_group == 1:
@@ -1006,8 +1037,10 @@ class _TrainingSession:
                         feature_mask=feature_mask, monotone=mono, rng=rng_k,
                     )
                     trees.append(tree)
-                    total_out = total_out + row_out
-                margins = margins + total_out
+                    with stage(STAGE_LEAF_MARGIN):
+                        total_out = total_out + row_out
+                with stage(STAGE_LEAF_MARGIN):
+                    margins = margins + total_out
             else:
                 # multi-class: vmap the builder over the class axis; with
                 # num_parallel_tree=P the class-vmap runs P times on P row
@@ -1024,15 +1057,19 @@ class _TrainingSession:
                         )
                     )(gk.T, hk.T)
                     trees.append(tree)
-                    total_out = total_out + row_out.T
-                margins = margins + total_out
-            stacked = jax.tree_util.tree_map(
-                lambda *leaves: jnp.stack(leaves), *trees
-            ) if num_parallel > 1 else trees[0]
+                    with stage(STAGE_LEAF_MARGIN):
+                        total_out = total_out + row_out.T
+                with stage(STAGE_LEAF_MARGIN):
+                    margins = margins + total_out
             # pack inside the program: the host pulls ONE array per dispatch
+            with stage(STAGE_PACK):
+                stacked = jax.tree_util.tree_map(
+                    lambda *leaves: jnp.stack(leaves), *trees
+                ) if num_parallel > 1 else trees[0]
+                packed = pack_tree(stacked)
             if not collect_stats:
-                return pack_tree(stacked), margins
-            return pack_tree(stacked), margins, _learning_stats(g, h, margins)
+                return packed, margins
+            return packed, margins, _learning_stats(g, h, margins)
 
         K = self.rounds_per_dispatch
         colsample = cfg.colsample_bytree
@@ -1042,6 +1079,11 @@ class _TrainingSession:
         shared_flags = [b is None for b in self.eval_bins]
         predict_depth = cfg.predict_depth
         n_data_shards = self.n_data_shards
+        # locals, not ``self``: the jitted closure must not keep the session
+        # (and with it every device buffer) alive
+        d_pad = self.d_pad
+        n_fs = self.n_feature_shards
+        route_impl = self.hist_knobs.route_impl
 
         def multi_round(
             bins, margins, labels, weights, num_cuts, rng, feature_mask, monotone,
@@ -1052,8 +1094,6 @@ class _TrainingSession:
             # under shard_map and mismatch the per-shard margins)
             # lax.scan so the round body is compiled ONCE regardless of K
             k_features = max(1, int(round(colsample * d)))
-            d_pad = self.d_pad
-            n_fs = self.n_feature_shards
 
             def body(carry, j):
                 margins_c, extra = carry
@@ -1102,7 +1142,7 @@ class _TrainingSession:
                         m_e = _apply_packed_tree(
                             packed, b_e, extra[ei],
                             num_group, num_parallel, predict_depth, num_bins,
-                            route_impl=self.hist_knobs.route_impl,
+                            route_impl=route_impl,
                         )
                         new_extra.append(m_e)
                         ei += 1
@@ -1123,20 +1163,24 @@ class _TrainingSession:
                             return fn.partial(m_g, y_g, w_g) / n_data_shards
                         return fn.partial(m_s, y_s, w_s)
 
-                    stats = jnp.concatenate(
-                        [_stats_for(fn, m_e, y_e, w_e) for fn in metric_fns]
-                    )
-                    if axis_name is not None:
-                        stats = jax.lax.psum(stats, axis_name)
-                    scalars_set = []
-                    off = 0
-                    for fn in metric_fns:
-                        scalars_set.append(fn.finalize(stats[off : off + fn.size]))
-                        off += fn.size
-                    per_set.append(jnp.stack(scalars_set))
+                    with stage(STAGE_EVAL_METRIC):
+                        stats = jnp.concatenate(
+                            [_stats_for(fn, m_e, y_e, w_e) for fn in metric_fns]
+                        )
+                        if axis_name is not None:
+                            stats = jax.lax.psum(stats, axis_name)
+                        scalars_set = []
+                        off = 0
+                        for fn in metric_fns:
+                            scalars_set.append(
+                                fn.finalize(stats[off : off + fn.size])
+                            )
+                            off += fn.size
+                        per_set.append(jnp.stack(scalars_set))
                 extra = tuple(new_extra)
                 if metric_fns:
-                    scalars = jnp.stack(per_set)          # [n_sets, n_metrics]
+                    with stage(STAGE_EVAL_METRIC):
+                        scalars = jnp.stack(per_set)      # [n_sets, n_metrics]
                 else:
                     # non-empty dummy: zero-sized scan outputs are a
                     # lowering hazard on some backends
@@ -1361,6 +1405,19 @@ class _TrainingSession:
                 },
             )
 
+    @contextlib.contextmanager
+    def _upload_span(self, what):
+        """A `setup.upload` span around host staging (padding, layout) and
+        the `_put` calls that follow, with the bytes they handed over. The
+        transfers return before the bytes are on the device: the tail of an
+        upload is asynchronous (`async_tail`) and ends under a later span."""
+        with span(
+            "setup.upload", attributes={"what": what, "async_tail": True}
+        ) as upload:
+            before = self._bytes_put
+            yield
+            upload.add_bytes(up=self._bytes_put - before)
+
     # ------------------------------------------------------------- resketch
     def _stage_train_bins(self, raw_bins, cuts, max_bin):
         """Stage [n_local, d_real] bin indices + per-feature cuts as the
@@ -1373,24 +1430,25 @@ class _TrainingSession:
                 np.zeros(0, np.float32)
                 for _ in range(self.d_pad - self._d_real)
             ]
-        bins_np = self._layout_rows(np.asarray(raw_bins), max_bin)
-        if self.d_pad != self._d_real:
-            bins_np = np.concatenate(
-                [
-                    bins_np,
-                    np.full(
-                        (bins_np.shape[0], self.d_pad - self._d_real),
-                        max_bin,
-                        bins_np.dtype,
-                    ),
-                ],
-                axis=1,
+        with self._upload_span("train_bins"):
+            bins_np = self._layout_rows(np.asarray(raw_bins), max_bin)
+            if self.d_pad != self._d_real:
+                bins_np = np.concatenate(
+                    [
+                        bins_np,
+                        np.full(
+                            (bins_np.shape[0], self.d_pad - self._d_real),
+                            max_bin,
+                            bins_np.dtype,
+                        ),
+                    ],
+                    axis=1,
+                )
+            self.cuts = cuts
+            self.num_cuts = self._put(
+                np.array([len(c) for c in cuts], np.int32), self.feat_spec
             )
-        self.cuts = cuts
-        self.num_cuts = self._put(
-            np.array([len(c) for c in cuts], np.int32), self.feat_spec
-        )
-        self.bins = self._put(bins_np, self.bins_spec)
+            self.bins = self._put(bins_np, self.bins_spec)
 
     def _resketch_bins(self):
         """Per-dispatch candidate re-sketch for tree_method='approx'.
@@ -1440,7 +1498,7 @@ class _TrainingSession:
             feats = self._dtrain.features
             cuts = compute_cut_points(feats, h_host, max_bin)
         self._stage_train_bins(
-            apply_cut_points(feats, cuts, max_bin), cuts, max_bin
+            apply_cut_points(feats, cuts, max_bin, name="train"), cuts, max_bin
         )
         # cached eval bins were built with the old cuts; the incremental
         # eval-margin apply reads bin indices, so they must re-bin too
@@ -1452,62 +1510,79 @@ class _TrainingSession:
                 if i not in self._eval_feats_dev:
                     self._eval_feats_dev[i] = jnp.asarray(efeats, jnp.float32)
                 efeats = self._eval_feats_dev[i]
-            eb = np.asarray(apply_cut_points(efeats, cuts, max_bin))
+            eb = np.asarray(apply_cut_points(efeats, cuts, max_bin, name=name))
             self.eval_bins[i] = self._put(
                 _pad_rows(eb, self._eval_pads[i], max_bin), P("data", None)
             )
 
     # ------------------------------------------------------- device window
-    def _introspect_compiled_cost(self):
-        """AOT-lower the fused round dispatch and feed its XLA
-        ``cost_analysis``/``memory_analysis`` into the device-window plane
-        (``training.compiled`` record + flops/HBM gauges). Gated on
-        ``SM_DEVICE_TELEMETRY`` because the AOT compile is real work (the
-        jit path's own compile is served from the persistent compile
-        cache, utils/compile_cache.py); lowering never *executes*,
-        so donated buffers are not consumed. Diagnostics only — any
-        failure is one warning, never a failed session."""
-        from ..telemetry import device as device_telemetry
+    def _register_round_program(self):
+        """Hand the device plane a closure that lowers and compiles this
+        session's round program from shapes, dtypes and shardings alone
+        (``jax.ShapeDtypeStruct``): it keeps the jitted function and no
+        device buffer, and nothing runs until ``round_program_stages()`` or
+        the gated introspection below asks. Lowering never *executes*, so
+        donated buffers are not consumed and the rng stream is untouched."""
+        def aval(a):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding)
 
+        mask_sharding = None
+        if self.has_feature_axis:
+            from jax.sharding import NamedSharding
+
+            mask_sharding = NamedSharding(self.mesh, self.feat_spec)
+        args = (
+            self.bins,
+            self.margins,
+            self.labels,
+            self.weights,
+            self.num_cuts,
+            self.rng,
+            # the per-dispatch feature mask: no array of it exists yet
+            jax.ShapeDtypeStruct(
+                (self.bins.shape[1],), jnp.float32, sharding=mask_sharding
+            ),
+            self.monotone,
+            self.rank_index_dev,
+        )
+        if self.use_scan_rounds:
+            eval_m = tuple(m for m in self.eval_margins if m is not None)
+            eval_blw = tuple(
+                (self.eval_bins[i], self.eval_labels[i], self.eval_weights[i])
+                for i in range(len(self.eval_bins))
+                if self.eval_bins[i] is not None
+            )
+            args += (eval_m, eval_blw)
+        avals = jax.tree_util.tree_map(aval, args)
+        round_fn = self._round_fn
+
+        def compile_round_program():
+            return round_fn.lower(*avals).compile()
+
+        device_telemetry.register_round_program(compile_round_program)
+        return compile_round_program
+
+    def _introspect_compiled_cost(self, compile_round_program):
+        """AOT-lower the fused round dispatch and feed its XLA
+        ``cost_analysis``/``memory_analysis`` and its stage table into the
+        device-window plane (``training.compiled`` record + flops/HBM
+        gauges). Gated on ``SM_DEVICE_TELEMETRY`` because the AOT compile
+        is real work (the jit path's own compile is served from the
+        persistent compile cache, utils/compile_cache.py). Diagnostics only
+        — any failure is one warning, never a failed session."""
         if not device_telemetry.enabled():
             return
         try:
-            d_pad = self.bins.shape[1]
-            mask_np = np.ones(d_pad, np.float32)
-            if self.has_feature_axis:
-                feature_mask = self._put(mask_np, self.feat_spec)
-            else:
-                feature_mask = jnp.asarray(mask_np)
-            # self.rng is key-shaped and is NOT consumed here — lowering
-            # only reads avals, so the training stream stays bit-identical
-            args = (
-                self.bins,
-                self.margins,
-                self.labels,
-                self.weights,
-                self.num_cuts,
-                self.rng,
-                feature_mask,
-                self.monotone,
-                self.rank_index_dev,
-            )
-            if self.use_scan_rounds:
-                eval_m = tuple(m for m in self.eval_margins if m is not None)
-                eval_blw = tuple(
-                    (self.eval_bins[i], self.eval_labels[i], self.eval_weights[i])
-                    for i in range(len(self.eval_bins))
-                    if self.eval_bins[i] is not None
-                )
-                lowered = self._round_fn.lower(*args, eval_m, eval_blw)
-            else:
-                lowered = self._round_fn.lower(*args)
-            cost = device_telemetry.cost_from_compiled(lowered.compile())
+            compiled = compile_round_program()
+            cost = device_telemetry.cost_from_compiled(compiled)
+            _table, stages = device_telemetry.note_stage_table(compiled)
             mesh_shape = dict(self.mesh.shape) if self.mesh is not None else None
             device_telemetry.note_compiled(
                 cost,
                 mesh_shape=mesh_shape,
                 rounds_per_dispatch=self.rounds_per_dispatch,
                 backend=jax.default_backend(),
+                stages=stages,
             )
         except Exception as e:
             logger.warning(
@@ -1536,40 +1611,32 @@ class _TrainingSession:
         )
 
     # ---------------------------------------------------------------- round
-    def _maybe_fenced_dispatch(self, dispatch):
-        """Run one round dispatch, attribution-fenced on every Nth call
-        (SM_TRACE_DEVICE_SYNC): the async XLA dispatch is timed as a
-        `host_dispatch` span and the wait on its outputs as `device_sync`.
-        The fence serializes host/device overlap, which is why it is
-        sampled, never always-on. Unsampled calls run ``dispatch`` as-is."""
-        sampled = (
-            self._device_sync_every > 0
-            and self._dispatch_index % self._device_sync_every == 0
-        )
-        self._dispatch_index += 1
-        if not sampled:
-            return dispatch()
-        from ..telemetry import active_recorder, compile_stats, span
-
+    def _timed_dispatch(self, dispatch, attributes):
+        """Run one round dispatch under a `host_dispatch` span: python and
+        the asynchronous XLA call, up to its return. The return also ends
+        the `host_turnaround` that the previous dispatch's `device_sync`
+        began: from there on the device has work again."""
         pre_compile = compile_stats()["seconds"]
-        with span("host_dispatch"):
+        with span("host_dispatch", attributes=attributes):
             out = dispatch()
         # an XLA compile that completed inside THIS dispatch is wall time
         # the host_dispatch span already contains; RoundTimer reports it
         # under the round's `compile` key, so remove exactly the measured
-        # overlap from the phase accumulator (and only then — a compile on
-        # an unfenced dispatch must not erode the sampled host time)
+        # overlap from the phase accumulator
         overlap = compile_stats()["seconds"] - pre_compile
         if overlap > 0:
             recorder = active_recorder()
             if recorder is not None:
                 recorder.add("host_dispatch", -overlap)
-        with span("device_sync"):
-            # dispatch callables return every output they put in flight
-            # (round program + any separate eval-apply programs), so
-            # blocking the returned pytree fences the whole device step
-            jax.block_until_ready(out)
+        self.end_turnaround()
         return out
+
+    def end_turnaround(self):
+        """Close the open `host_turnaround` span, if any: ``train()`` calls
+        it after the last dispatch, which no `host_dispatch` follows."""
+        if self._turnaround is not None:
+            end_span(self._turnaround)
+            self._turnaround = None
 
     def run_rounds(self):
         """One device dispatch -> (list of host tree dicts, metrics or None).
@@ -1611,9 +1678,48 @@ class _TrainingSession:
         ]
 
     def _run_rounds_inner(self):
+        index = self._dispatch_index
+        self._dispatch_index += 1
+        attributes = {"k": self.rounds_per_dispatch, "dispatch": index}
+        # every Nth dispatch (SM_TRACE_DEVICE_SYNC) fences all it put in flight
+        fenced = (
+            self._device_sync_every > 0 and index % self._device_sync_every == 0
+        )
+        if index == 0:
+            # the first dispatch is set-up: program load and K rounds of
+            # warm-up, up to the first packed trees on the host
+            self._first_dispatch = begin_span(
+                "setup.first_dispatch", covering=True, attributes=attributes
+            )
+        try:
+            return self._dispatch_rounds(attributes, fenced)
+        finally:
+            self._end_first_dispatch()  # still open only if the dispatch raised
+
+    def _end_first_dispatch(self):
+        if self._first_dispatch is not None:
+            end_span(self._first_dispatch)
+            self._first_dispatch = None
+
+    def _device_sync(self, packed, out, attributes, fenced):
+        """The packed trees on the host, under a `device_sync` span: the
+        transfer blocks on the round program and is there anyway. ``fenced``
+        first blocks on everything the dispatch put in flight (``out``): the
+        K = 1 path's eval-apply programs are separate dispatches. Where the
+        span ends the device has nothing queued, so `host_turnaround`
+        begins (and `setup.first_dispatch` ends)."""
+        with span("device_sync", attributes=attributes):
+            if fenced:
+                jax.block_until_ready(out)
+            packed_np = np.asarray(packed)
+        self._end_first_dispatch()
+        self._turnaround = begin_span("host_turnaround", covering=True)
+        return packed_np
+
+    def _dispatch_rounds(self, attributes, fenced):
         if self.approx_resketch:
             self._resketch_bins()
-        if fault_point("train.gradient_poison", dispatch=self._dispatch_index):
+        if fault_point("train.gradient_poison", dispatch=attributes["dispatch"]):
             # numeric-poison drill: corrupt the live margins so the next
             # round's gradients genuinely go NaN through the real device
             # pipeline (the learning-telemetry guard must catch it there)
@@ -1666,18 +1772,20 @@ class _TrainingSession:
                 # into build_eval / the next round's host_dispatch
                 return packed, lstats, [m for m in self.eval_margins if m is not None]
 
-            packed, lstats, _fenced_evals = self._maybe_fenced_dispatch(_dispatch_single)
+            out = self._timed_dispatch(_dispatch_single, attributes)
+            packed, lstats, _fenced_evals = out
+            packed_np = self._device_sync(packed, out, attributes, fenced)
             self._note_comm_dispatch(1)
             self._stash_learning_stats(lstats)
-            return [unpack_tree(np.asarray(packed))], None
+            return [unpack_tree(packed_np)], None
         eval_m = tuple(m for m in self.eval_margins if m is not None)
         eval_blw = tuple(
             (self.eval_bins[i], self.eval_labels[i], self.eval_weights[i])
             for i in range(len(self.eval_bins))
             if self.eval_bins[i] is not None
         )
-        out = self._maybe_fenced_dispatch(
-            lambda: self._round_fn(*args, eval_m, eval_blw)
+        out = self._timed_dispatch(
+            lambda: self._round_fn(*args, eval_m, eval_blw), attributes
         )
         if self.learning_stats:
             packed, metrics, self.margins, eval_m_out, lstats = out
@@ -1689,7 +1797,8 @@ class _TrainingSession:
             if self.eval_margins[i] is not None:
                 self.eval_margins[i] = eval_m_out[ei]
                 ei += 1
-        packed_np = np.asarray(packed)  # ONE transfer for K rounds
+        # ONE transfer for K rounds
+        packed_np = self._device_sync(packed, out, attributes, fenced)
         self._note_comm_dispatch(packed_np.shape[0])
         self._stash_learning_stats(lstats)
         metrics_np = np.asarray(metrics) if self.device_metric_fns else None
@@ -2139,11 +2248,14 @@ def train(
     stop = False
     while rnd < end_round and not stop:
         trees_batch, batch_metrics = session.run_rounds()
+        # what follows, up to the return of the next dispatch, is the
+        # session's `host_turnaround`; its parts are spans of their own
         for j, tree_np in enumerate(trees_batch):
             if rnd >= end_round:
                 break  # trees past the requested count are discarded
-            trees, info = _trees_for_round(tree_np)
-            forest.append_round(trees, info)
+            with span("commit", attributes={"round": rnd}):
+                trees, info = _trees_for_round(tree_np)
+                forest.append_round(trees, info)
 
             if j < len(session.last_learning_stats):
                 # model-quality plane: device reductions + committed-tree
@@ -2158,42 +2270,47 @@ def train(
                 if model_telemetry.first_poisoned_round([stats], rnd) is not None:
                     _abort_numeric_poison(rnd)
 
-            if batch_metrics is not None:
-                # device-computed per-round metrics: [K, n_sets, n_metrics]
-                results = [
-                    (name, metric_name, float(batch_metrics[j, si, i]))
-                    for si, (name, _dm, _b) in enumerate(session.eval_sets)
-                    for i, metric_name in enumerate(session.device_metric_names)
-                ]
-            elif not session.eval_sets:
-                results = []
-            elif not session.host_eval_batched:
-                results = session.evaluate(metric_names, feval=feval)
-            elif j == len(trees_batch) - 1:
-                # host-fallback cadence: the fused K-round dispatch finished
-                # and the device margins cover exactly the committed trees —
-                # one host evaluation per dispatch, attributed to the
-                # batch-end round.
-                results = session.evaluate(metric_names, feval=feval)
-            elif rnd == end_round - 1:
-                # final round lands mid-batch (num_boost_round % K != 0):
-                # the device margins include the over-built, discarded trees
-                # — evaluate the committed forest so the last metric line
-                # (the one HPO reads) is exact.
-                results = session.evaluate(metric_names, feval=feval, forest=forest)
-            else:
-                results = []  # stale round inside the fused batch
-            for data_name, metric_name, value in results:
-                evals_log.setdefault(data_name, {}).setdefault(metric_name, []).append(value)
+            with span("eval_log", attributes={"round": rnd}):
+                if batch_metrics is not None:
+                    # device-computed per-round metrics: [K, n_sets, n_metrics]
+                    results = [
+                        (name, metric_name, float(batch_metrics[j, si, i]))
+                        for si, (name, _dm, _b) in enumerate(session.eval_sets)
+                        for i, metric_name in enumerate(session.device_metric_names)
+                    ]
+                elif not session.eval_sets:
+                    results = []
+                elif not session.host_eval_batched:
+                    results = session.evaluate(metric_names, feval=feval)
+                elif j == len(trees_batch) - 1:
+                    # host-fallback cadence: the fused K-round dispatch finished
+                    # and the device margins cover exactly the committed trees —
+                    # one host evaluation per dispatch, attributed to the
+                    # batch-end round.
+                    results = session.evaluate(metric_names, feval=feval)
+                elif rnd == end_round - 1:
+                    # final round lands mid-batch (num_boost_round % K != 0):
+                    # the device margins include the over-built, discarded trees
+                    # — evaluate the committed forest so the last metric line
+                    # (the one HPO reads) is exact.
+                    results = session.evaluate(metric_names, feval=feval, forest=forest)
+                else:
+                    results = []  # stale round inside the fused batch
+                for data_name, metric_name, value in results:
+                    evals_log.setdefault(data_name, {}).setdefault(metric_name, []).append(value)
 
-            for cb in callbacks:
-                if hasattr(cb, "after_iteration") and cb.after_iteration(
-                    forest, rnd, evals_log
-                ):
-                    stop = True
+            # covering: checkpoint, eval-monitor and RoundTimer spans lie inside
+            with span("callbacks", covering=True, attributes={"round": rnd}):
+                for cb in callbacks:
+                    if hasattr(cb, "after_iteration") and cb.after_iteration(
+                        forest, rnd, evals_log
+                    ):
+                        stop = True
             rnd += 1
             if stop:
                 break
+
+    session.end_turnaround()
 
     for cb in callbacks:
         if hasattr(cb, "after_training"):

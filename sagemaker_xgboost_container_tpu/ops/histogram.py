@@ -33,6 +33,7 @@ import os
 import jax
 import jax.numpy as jnp
 
+from ..telemetry.device import STAGE_HIST_ALLREDUCE, stage
 from ..utils.envconfig import env_int
 
 # Session-build-time snapshot of every histogram/scan/routing tuning knob.
@@ -185,9 +186,10 @@ def apply_hist_collective(G, H, axis_name, comm, axis_size):
     """
     if axis_name is None:
         return G, H
-    if comm == "reduce_scatter":
-        return scatter_histograms(G, H, axis_name, axis_size)
-    return jax.lax.psum(G, axis_name), jax.lax.psum(H, axis_name)
+    with stage(STAGE_HIST_ALLREDUCE):
+        if comm == "reduce_scatter":
+            return scatter_histograms(G, H, axis_name, axis_size)
+        return jax.lax.psum(G, axis_name), jax.lax.psum(H, axis_name)
 
 
 def hist_comm_impl():

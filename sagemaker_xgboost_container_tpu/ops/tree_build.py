@@ -27,6 +27,15 @@ from .histogram import (
     padded_feature_width,
     subtraction_enabled,
 )
+from ..telemetry.device import (
+    STAGE_HIST,
+    STAGE_LEAF_MARGIN,
+    STAGE_NODE_TOTALS,
+    STAGE_PACK,
+    STAGE_ROUTE_ROWS,
+    STAGE_SPLIT_SCAN,
+    stage,
+)
 from .split import (
     broadcast_node_totals,
     column_shard_helpers,
@@ -219,234 +228,240 @@ def build_tree(
             # Last level: every surviving node becomes a leaf, and leaf
             # weights only need per-node g/h totals — skip the full (widest,
             # most expensive) [W, d, B] histogram of the tree entirely.
-            g_tot, h_tot = node_totals(
-                grad, hess, node_local, width, axis_name=axis_name, knobs=knobs
-            )
-            weight = leaf_weight(
-                g_tot, h_tot,
-                reg_lambda=reg_lambda, alpha=alpha, max_delta_step=max_delta_step,
-            )
-            sl = slice(first, first + width)
-            tree["is_leaf"] = tree["is_leaf"].at[sl].set(True)
-            tree["leaf_value"] = tree["leaf_value"].at[sl].set(eta * weight)
-            tree["base_weight"] = tree["base_weight"].at[sl].set(weight)
-            tree["sum_hess"] = tree["sum_hess"].at[sl].set(h_tot)
-            at_level = node_local >= 0
-            local_safe = jnp.clip(node_local, 0, width - 1)
-            row_out = jnp.where(at_level, eta * weight[local_safe], row_out)
+            with stage(STAGE_NODE_TOTALS):
+                g_tot, h_tot = node_totals(
+                    grad, hess, node_local, width, axis_name=axis_name, knobs=knobs
+                )
+            with stage(STAGE_LEAF_MARGIN):
+                weight = leaf_weight(
+                    g_tot, h_tot,
+                    reg_lambda=reg_lambda, alpha=alpha, max_delta_step=max_delta_step,
+                )
+                sl = slice(first, first + width)
+                tree["is_leaf"] = tree["is_leaf"].at[sl].set(True)
+                tree["leaf_value"] = tree["leaf_value"].at[sl].set(eta * weight)
+                tree["base_weight"] = tree["base_weight"].at[sl].set(weight)
+                tree["sum_hess"] = tree["sum_hess"].at[sl].set(h_tot)
+                at_level = node_local >= 0
+                local_safe = jnp.clip(node_local, 0, width - 1)
+                row_out = jnp.where(at_level, eta * weight[local_safe], row_out)
             break
 
-        if subtract and level > 0:
-            # histogram only the LEFT child of each sibling pair; the right
-            # one is parent - left. Parents that leafed routed no rows to
-            # their children, so their pair contribution is zeroed. The
-            # local accumulation runs ONCE over the rows; the collective is
-            # issued per node batch (overlap schedule) on slices of it.
-            active = node_local >= 0
-            is_left = (node_local % 2) == 0
-            left_local = jnp.where(active & is_left, node_local // 2, -1)
-            Gl_loc, Hl_loc = level_histogram(
-                bins, grad, hess, left_local, width // 2, num_bins,
-                knobs=knobs,
-            )
-            keep = ~parent_leaf
+        with stage(STAGE_HIST):
+            if subtract and level > 0:
+                # histogram only the LEFT child of each sibling pair; the right
+                # one is parent - left. Parents that leafed routed no rows to
+                # their children, so their pair contribution is zeroed. The
+                # local accumulation runs ONCE over the rows; the collective is
+                # issued per node batch (overlap schedule) on slices of it.
+                active = node_local >= 0
+                is_left = (node_local % 2) == 0
+                left_local = jnp.where(active & is_left, node_local // 2, -1)
+                Gl_loc, Hl_loc = level_histogram(
+                    bins, grad, hess, left_local, width // 2, num_bins,
+                    knobs=knobs,
+                )
+                keep = ~parent_leaf
 
-            def _batch_hists(psl):
-                # parent slice [a, b) -> level nodes [2a, 2b), interleaved
-                # (left child 2i, right child 2i+1) from the reduced left
-                # histograms + the cached (already reduced) parent slice
-                Gl, Hl = apply_hist_collective(
-                    Gl_loc[psl], Hl_loc[psl], axis_name, hist_comm,
-                    n_data_shards,
-                )
-                kp = keep[psl]
-                Gp = jnp.where(kp[:, None, None], G_cache[psl], 0.0)
-                Hp = jnp.where(kp[:, None, None], H_cache[psl], 0.0)
-                Gr = Gp - Gl
-                Hr = Hp - Hl
-                Gb = jnp.stack([Gl, Gr], axis=1).reshape(
-                    2 * Gl.shape[0], Gl.shape[1], -1
-                )
-                Hb = jnp.stack([Hl, Hr], axis=1).reshape(
-                    2 * Hl.shape[0], Hl.shape[1], -1
-                )
-                return Gb, Hb
-
-            batch_hists = [
-                (slice(psl.start * 2, psl.stop * 2),) + _batch_hists(psl)
-                for psl in overlap_node_batches(width // 2, overlap)
-            ]
-        else:
-            G_loc, H_loc = level_histogram(
-                bins, grad, hess, node_local, width, num_bins, knobs=knobs,
-            )
-            batch_hists = [
-                (nsl,)
-                + apply_hist_collective(
-                    G_loc[nsl], H_loc[nsl], axis_name, hist_comm,
-                    n_data_shards,
-                )
-                for nsl in overlap_node_batches(width, overlap)
-            ]
-        if subtract:
-            if len(batch_hists) == 1:
-                G_cache, H_cache = batch_hists[0][1], batch_hists[0][2]
-            else:
-                G_cache = jnp.concatenate([b[1] for b in batch_hists], axis=0)
-                H_cache = jnp.concatenate([b[2] for b in batch_hists], axis=0)
-        # shared column-draw convention (ops/split.py): draws over the REAL
-        # global feature count, padded then sliced per shard
-        d_draw, _pad_cols, _local_cols = column_shard_helpers(
-            feat_shard, d, n_feature_shards, d_global
-        )
-
-        level_mask = feature_mask
-        if colsample_bylevel < 1.0 and rng is not None:
-            draw = jax.random.uniform(jax.random.fold_in(rng, level), (d_draw,))
-            sampled = _local_cols(
-                _pad_cols((draw < colsample_bylevel).astype(jnp.float32))
-            )
-            level_mask = sampled if level_mask is None else level_mask * sampled
-        if colsample_bynode < 1.0 and rng is not None:
-            # fresh per-node feature subset (xgboost colsample_bynode)
-            node_draw = jax.random.uniform(
-                jax.random.fold_in(rng, 7919 + level), (width, d_draw)
-            )
-            node_mask = _local_cols(
-                _pad_cols((node_draw < colsample_bynode).astype(jnp.float32))
-            )
-            if level_mask is None:
-                level_mask = node_mask
-            elif level_mask.ndim == 1:
-                level_mask = node_mask * level_mask[None, :]
-            else:
-                level_mask = node_mask * level_mask
-        if alive_sets is not None:
-            # [W, S] @ [S, d_total] -> per-node allowed-feature mask over
-            # global columns, sliced to this shard
-            node_allowed = (
-                alive_sets.astype(jnp.float32) @ interaction_sets.astype(jnp.float32)
-            ) > 0
-            per_node = _local_cols(node_allowed.astype(jnp.float32))
-            level_mask = per_node if level_mask is None else per_node * level_mask[None, :]
-        def _scan_batch(nsl, Gb, Hb):
-            """Gain-scan one node batch of the level (per-node independent,
-            so batches concatenate bit-identically — concat_node_splits)."""
-            scan_cuts, scan_mask, scan_mono, scan_totals = (
-                num_cuts, level_mask, monotone, None,
-            )
-            if scan_mask is not None and scan_mask.ndim == 2:
-                scan_mask = scan_mask[nsl]  # per-node mask rows
-            if reduce_scatter:
-                # the scan sees only this shard's globally-summed feature
-                # slice; its per-feature inputs must slice exactly like the
-                # histograms, and node totals broadcast from shard 0 BEFORE
-                # the scan so every shard's gains use bit-identical totals
-                scan_cuts = shard_feature_slice(
-                    num_cuts, data_shard, d_scan, n_data_shards
-                )
-                if scan_mask is not None:
-                    scan_mask = shard_feature_slice(
-                        scan_mask, data_shard, d_scan, n_data_shards
+                def _batch_hists(psl):
+                    # parent slice [a, b) -> level nodes [2a, 2b), interleaved
+                    # (left child 2i, right child 2i+1) from the reduced left
+                    # histograms + the cached (already reduced) parent slice
+                    Gl, Hl = apply_hist_collective(
+                        Gl_loc[psl], Hl_loc[psl], axis_name, hist_comm,
+                        n_data_shards,
                     )
-                if scan_mono is not None:
-                    scan_mono = shard_feature_slice(
-                        scan_mono, data_shard, d_scan, n_data_shards
+                    kp = keep[psl]
+                    Gp = jnp.where(kp[:, None, None], G_cache[psl], 0.0)
+                    Hp = jnp.where(kp[:, None, None], H_cache[psl], 0.0)
+                    Gr = Gp - Gl
+                    Hr = Hp - Hl
+                    Gb = jnp.stack([Gl, Gr], axis=1).reshape(
+                        2 * Gl.shape[0], Gl.shape[1], -1
                     )
-                scan_totals = broadcast_node_totals(
-                    Gb, Hb, data_shard, axis_name
+                    Hb = jnp.stack([Hl, Hr], axis=1).reshape(
+                        2 * Hl.shape[0], Hl.shape[1], -1
+                    )
+                    return Gb, Hb
+
+                batch_hists = [
+                    (slice(psl.start * 2, psl.stop * 2),) + _batch_hists(psl)
+                    for psl in overlap_node_batches(width // 2, overlap)
+                ]
+            else:
+                G_loc, H_loc = level_histogram(
+                    bins, grad, hess, node_local, width, num_bins, knobs=knobs,
                 )
-            s = find_best_splits(
-                Gb,
-                Hb,
-                scan_cuts,
-                reg_lambda=reg_lambda,
-                alpha=alpha,
-                gamma=gamma,
-                min_child_weight=min_child_weight,
-                feature_mask=scan_mask,
-                monotone=scan_mono,
-                totals=scan_totals,
+                batch_hists = [
+                    (nsl,)
+                    + apply_hist_collective(
+                        G_loc[nsl], H_loc[nsl], axis_name, hist_comm,
+                        n_data_shards,
+                    )
+                    for nsl in overlap_node_batches(width, overlap)
+                ]
+            if subtract:
+                if len(batch_hists) == 1:
+                    G_cache, H_cache = batch_hists[0][1], batch_hists[0][2]
+                else:
+                    G_cache = jnp.concatenate([b[1] for b in batch_hists], axis=0)
+                    H_cache = jnp.concatenate([b[2] for b in batch_hists], axis=0)
+        with stage(STAGE_SPLIT_SCAN):
+            # shared column-draw convention (ops/split.py): draws over the REAL
+            # global feature count, padded then sliced per shard
+            d_draw, _pad_cols, _local_cols = column_shard_helpers(
+                feat_shard, d, n_feature_shards, d_global
             )
-            if reduce_scatter:
-                # the data axis is a feature axis for the duration of the
-                # scan: the same winner merge (totals pass through —
-                # already broadcast)
-                s = combine_splits_across_shards(
-                    s, data_shard, d_scan, axis_name
+
+            level_mask = feature_mask
+            if colsample_bylevel < 1.0 and rng is not None:
+                draw = jax.random.uniform(jax.random.fold_in(rng, level), (d_draw,))
+                sampled = _local_cols(
+                    _pad_cols((draw < colsample_bylevel).astype(jnp.float32))
                 )
-            if feature_axis_name is not None:
-                s = combine_splits_across_shards(
-                    s, feat_shard, d, feature_axis_name
+                level_mask = sampled if level_mask is None else level_mask * sampled
+            if colsample_bynode < 1.0 and rng is not None:
+                # fresh per-node feature subset (xgboost colsample_bynode)
+                node_draw = jax.random.uniform(
+                    jax.random.fold_in(rng, 7919 + level), (width, d_draw)
                 )
-            return s
+                node_mask = _local_cols(
+                    _pad_cols((node_draw < colsample_bynode).astype(jnp.float32))
+                )
+                if level_mask is None:
+                    level_mask = node_mask
+                elif level_mask.ndim == 1:
+                    level_mask = node_mask * level_mask[None, :]
+                else:
+                    level_mask = node_mask * level_mask
+            if alive_sets is not None:
+                # [W, S] @ [S, d_total] -> per-node allowed-feature mask over
+                # global columns, sliced to this shard
+                node_allowed = (
+                    alive_sets.astype(jnp.float32) @ interaction_sets.astype(jnp.float32)
+                ) > 0
+                per_node = _local_cols(node_allowed.astype(jnp.float32))
+                level_mask = per_node if level_mask is None else per_node * level_mask[None, :]
+            def _scan_batch(nsl, Gb, Hb):
+                """Gain-scan one node batch of the level (per-node independent,
+                so batches concatenate bit-identically — concat_node_splits)."""
+                scan_cuts, scan_mask, scan_mono, scan_totals = (
+                    num_cuts, level_mask, monotone, None,
+                )
+                if scan_mask is not None and scan_mask.ndim == 2:
+                    scan_mask = scan_mask[nsl]  # per-node mask rows
+                if reduce_scatter:
+                    # the scan sees only this shard's globally-summed feature
+                    # slice; its per-feature inputs must slice exactly like the
+                    # histograms, and node totals broadcast from shard 0 BEFORE
+                    # the scan so every shard's gains use bit-identical totals
+                    scan_cuts = shard_feature_slice(
+                        num_cuts, data_shard, d_scan, n_data_shards
+                    )
+                    if scan_mask is not None:
+                        scan_mask = shard_feature_slice(
+                            scan_mask, data_shard, d_scan, n_data_shards
+                        )
+                    if scan_mono is not None:
+                        scan_mono = shard_feature_slice(
+                            scan_mono, data_shard, d_scan, n_data_shards
+                        )
+                    scan_totals = broadcast_node_totals(
+                        Gb, Hb, data_shard, axis_name
+                    )
+                s = find_best_splits(
+                    Gb,
+                    Hb,
+                    scan_cuts,
+                    reg_lambda=reg_lambda,
+                    alpha=alpha,
+                    gamma=gamma,
+                    min_child_weight=min_child_weight,
+                    feature_mask=scan_mask,
+                    monotone=scan_mono,
+                    totals=scan_totals,
+                )
+                if reduce_scatter:
+                    # the data axis is a feature axis for the duration of the
+                    # scan: the same winner merge (totals pass through —
+                    # already broadcast)
+                    s = combine_splits_across_shards(
+                        s, data_shard, d_scan, axis_name
+                    )
+                if feature_axis_name is not None:
+                    s = combine_splits_across_shards(
+                        s, feat_shard, d, feature_axis_name
+                    )
+                return s
 
-        splits = concat_node_splits(
-            [_scan_batch(nsl, Gb, Hb) for nsl, Gb, Hb in batch_hists]
-        )
+            splits = concat_node_splits(
+                [_scan_batch(nsl, Gb, Hb) for nsl, Gb, Hb in batch_hists]
+            )
 
-        g_tot, h_tot = splits["g_total"], splits["h_total"]
-        weight = leaf_weight(
-            g_tot, h_tot, reg_lambda=reg_lambda, alpha=alpha, max_delta_step=max_delta_step
-        )
+            g_tot, h_tot = splits["g_total"], splits["h_total"]
+            weight = leaf_weight(
+                g_tot, h_tot, reg_lambda=reg_lambda, alpha=alpha, max_delta_step=max_delta_step
+            )
 
-        can_split = splits["gain"] > MIN_SPLIT_LOSS
-        becomes_leaf = ~can_split
-        parent_leaf = becomes_leaf
+            can_split = splits["gain"] > MIN_SPLIT_LOSS
+            becomes_leaf = ~can_split
+            parent_leaf = becomes_leaf
 
-        sl = slice(first, first + width)
-        tree["feature"] = tree["feature"].at[sl].set(splits["feature"])
-        tree["bin"] = tree["bin"].at[sl].set(splits["bin"])
-        tree["default_left"] = tree["default_left"].at[sl].set(splits["default_left"])
-        tree["is_leaf"] = tree["is_leaf"].at[sl].set(becomes_leaf)
-        tree["leaf_value"] = tree["leaf_value"].at[sl].set(
-            jnp.where(becomes_leaf, eta * weight, 0.0)
-        )
-        tree["base_weight"] = tree["base_weight"].at[sl].set(weight)
-        tree["gain"] = tree["gain"].at[sl].set(
-            jnp.where(can_split, splits["gain"], 0.0)
-        )
-        tree["sum_hess"] = tree["sum_hess"].at[sl].set(h_tot)
+        with stage(STAGE_LEAF_MARGIN):
+            sl = slice(first, first + width)
+            tree["feature"] = tree["feature"].at[sl].set(splits["feature"])
+            tree["bin"] = tree["bin"].at[sl].set(splits["bin"])
+            tree["default_left"] = tree["default_left"].at[sl].set(splits["default_left"])
+            tree["is_leaf"] = tree["is_leaf"].at[sl].set(becomes_leaf)
+            tree["leaf_value"] = tree["leaf_value"].at[sl].set(
+                jnp.where(becomes_leaf, eta * weight, 0.0)
+            )
+            tree["base_weight"] = tree["base_weight"].at[sl].set(weight)
+            tree["gain"] = tree["gain"].at[sl].set(
+                jnp.where(can_split, splits["gain"], 0.0)
+            )
+            tree["sum_hess"] = tree["sum_hess"].at[sl].set(h_tot)
+
+            at_level = node_local >= 0
+            local_safe = jnp.clip(node_local, 0, width - 1)
+            row_leafed = at_level & becomes_leaf[local_safe]
+            row_out = jnp.where(row_leafed, eta * weight[local_safe], row_out)
 
         # --- route rows ----------------------------------------------------
-        at_level = node_local >= 0
-        local_safe = jnp.clip(node_local, 0, width - 1)
-        row_leafed = at_level & becomes_leaf[local_safe]
-        row_out = jnp.where(row_leafed, eta * weight[local_safe], row_out)
-
-        split_feat = splits["feature"][local_safe]
-        split_bin = splits["bin"][local_safe]
-        if feature_axis_name is None:
-            row_bin = row_bin_lookup(
-                bins, split_feat, impl=knobs.route_impl if knobs else None
-            )
-            is_missing = row_bin == (num_bins - 1)
-            go_right = jnp.where(
-                is_missing, ~splits["default_left"][local_safe], row_bin > split_bin
-            )
-        else:
-            # only the shard owning a node's split feature can decide its
-            # rows; decisions psum-broadcast along the feature axis
-            owner = (split_feat // d) == feat_shard
-            local_idx = jnp.clip(split_feat - feat_shard * d, 0, d - 1)
-            row_bin = row_bin_lookup(
-                bins, local_idx, impl=knobs.route_impl if knobs else None
-            )
-            is_missing = row_bin == (num_bins - 1)
-            decision = jnp.where(
-                is_missing, ~splits["default_left"][local_safe], row_bin > split_bin
-            )
-            go_right = (
-                jax.lax.psum(
-                    jnp.where(owner, decision, False).astype(jnp.int32),
-                    feature_axis_name,
+        with stage(STAGE_ROUTE_ROWS):
+            split_feat = splits["feature"][local_safe]
+            split_bin = splits["bin"][local_safe]
+            if feature_axis_name is None:
+                row_bin = row_bin_lookup(
+                    bins, split_feat, impl=knobs.route_impl if knobs else None
                 )
-                > 0
+                is_missing = row_bin == (num_bins - 1)
+                go_right = jnp.where(
+                    is_missing, ~splits["default_left"][local_safe], row_bin > split_bin
+                )
+            else:
+                # only the shard owning a node's split feature can decide its
+                # rows; decisions psum-broadcast along the feature axis
+                owner = (split_feat // d) == feat_shard
+                local_idx = jnp.clip(split_feat - feat_shard * d, 0, d - 1)
+                row_bin = row_bin_lookup(
+                    bins, local_idx, impl=knobs.route_impl if knobs else None
+                )
+                is_missing = row_bin == (num_bins - 1)
+                decision = jnp.where(
+                    is_missing, ~splits["default_left"][local_safe], row_bin > split_bin
+                )
+                go_right = (
+                    jax.lax.psum(
+                        jnp.where(owner, decision, False).astype(jnp.int32),
+                        feature_axis_name,
+                    )
+                    > 0
+                )
+            child = node_of_row * 2 + 1 + go_right.astype(jnp.int32)
+            node_of_row = jnp.where(
+                row_leafed, -1, jnp.where(at_level, child, node_of_row)
             )
-        child = node_of_row * 2 + 1 + go_right.astype(jnp.int32)
-        node_of_row = jnp.where(
-            row_leafed, -1, jnp.where(at_level, child, node_of_row)
-        )
 
         if alive_sets is not None and level < max_depth:
             feat_sets = interaction_sets[:, splits["feature"]].T  # [W, S]
@@ -455,9 +470,10 @@ def build_tree(
 
     # explicit child indices (leaves self-loop), so depthwise and lossguide
     # trees share one predict/compact layout
-    ids = jnp.arange(max_nodes, dtype=jnp.int32)
-    tree["left"] = jnp.where(tree["is_leaf"], ids, 2 * ids + 1)
-    tree["right"] = jnp.where(tree["is_leaf"], ids, 2 * ids + 2)
+    with stage(STAGE_PACK):
+        ids = jnp.arange(max_nodes, dtype=jnp.int32)
+        tree["left"] = jnp.where(tree["is_leaf"], ids, 2 * ids + 1)
+        tree["right"] = jnp.where(tree["is_leaf"], ids, 2 * ids + 2)
     return tree, row_out
 
 

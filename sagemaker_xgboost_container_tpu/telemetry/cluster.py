@@ -46,6 +46,7 @@ from ..utils.envconfig import env_float, env_int, env_port
 from . import tracing
 from .emit import emit_metric
 from .registry import REGISTRY, percentile
+from .spans import current_phase
 
 logger = logging.getLogger(__name__)
 
@@ -151,13 +152,83 @@ ROUND_STATE = RoundState()
 _runtime_lock = threading.Lock()
 _compile_listener_installed = False
 _compile_stats = {"count": 0, "seconds": 0.0}
+_listener_tls = threading.local()
+
+#: program-load stage of each ``jax.monitoring`` duration event: a jitted
+#: function is traced to a jaxpr, lowered to an MLIR module, and then
+#: either compiled by the backend or loaded from the persistent cache
+_PROGRAM_STAGE_OF_EVENT = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+}
+_CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+#: the last program loads, newest last: (stage, phase, fun_name, seconds)
+_program_events = collections.deque(maxlen=512)
 
 
-def _on_jax_duration_event(event, duration, **_kwargs):
+def _count_program(stage, duration, fun_name):
+    phase = current_phase()
+    labels = {"stage": stage, "phase": phase}
+    REGISTRY.counter(
+        "xla_program_seconds_total",
+        help="Seconds spent tracing, lowering, compiling and cache-loading "
+        "XLA programs, by stage and by the span (phase) that was open",
+        labels=labels,
+    ).inc(float(duration))
+    REGISTRY.counter(
+        "xla_programs_total",
+        help="XLA program-load events, by stage and open span (phase)",
+        labels=labels,
+    ).inc()
+    if stage != "trace":  # one line a program: every inner jit traces too
+        _program_events.append((stage, phase, fun_name or "", float(duration)))
+
+
+def program_events():
+    """The last program-load events, oldest first: ``(stage, phase,
+    fun_name, seconds)`` — which programs a phase lowered, compiled or
+    loaded from the cache (the counters keep only the sums)."""
+    return list(_program_events)
+
+
+def _own_trace_seconds(duration):
+    """A trace event's seconds less the traces nested in it. Every inner
+    jitted function fires an event of its own when its trace ends, before
+    the outer one's: summing them as they come would count the inner
+    seconds twice."""
+    end = time.perf_counter()
+    start = end - duration
+    recent = getattr(_listener_tls, "traces", None)
+    if recent is None:
+        recent = _listener_tls.traces = collections.deque(maxlen=64)
+    nested = 0.0
+    while recent and recent[-1][0] >= start:
+        nested += recent.pop()[1]
+    recent.append((start, duration))
+    return max(duration - nested, 0.0)
+
+
+def _on_jax_duration_event(event, duration, **kwargs):
+    stage = _PROGRAM_STAGE_OF_EVENT.get(event)
+    if stage is not None:
+        if stage == "trace":
+            duration = _own_trace_seconds(float(duration))
+        _count_program(stage, duration, kwargs.get("fun_name"))
+        return
+    if event == _CACHE_RETRIEVAL_EVENT:
+        # a persistent-cache hit: the backend_compile event that follows on
+        # this thread times the load of that entry, not a compilation
+        _listener_tls.cache_hit = True
+        return
     # backend_compile_duration is the actual XLA compile; the other
     # /jax/core/compile/* events (tracing, MLIR lowering) are host-side prep
     if not event.endswith("backend_compile_duration"):
         return
+    cache_hit = getattr(_listener_tls, "cache_hit", False)
+    _listener_tls.cache_hit = False
+    _count_program(
+        "cache_load" if cache_hit else "compile", duration, kwargs.get("fun_name")
+    )
     with _runtime_lock:
         _compile_stats["count"] += 1
         _compile_stats["seconds"] += float(duration)
@@ -173,11 +244,11 @@ def _on_jax_duration_event(event, duration, **_kwargs):
     tracing.record_compile(float(duration))
 
 
-def register_runtime_gauges():
-    """Install the ``jax.monitoring`` compile listener (idempotent, and a
-    no-op when jax is absent — CPU-only paths keep working) and prime the
-    process gauges. Adds zero threads; call at training and serving startup.
-    """
+def install_program_listener():
+    """Install the one ``jax.monitoring`` listener (idempotent; a no-op
+    when jax is absent). ``algorithm_train`` and ``serve`` install it
+    through :func:`register_runtime_gauges`; a ``_TrainingSession`` installs
+    it too, so that ``models.train()`` alone counts its program loads."""
     global _compile_listener_installed
     with _runtime_lock:
         already = _compile_listener_installed
@@ -189,6 +260,14 @@ def register_runtime_gauges():
             monitoring.register_event_duration_secs_listener(_on_jax_duration_event)
         except Exception:  # jax absent or monitoring API unavailable: no-op
             logger.debug("jax.monitoring unavailable; compile gauges disabled")
+
+
+def register_runtime_gauges():
+    """Install the ``jax.monitoring`` compile listener (idempotent, and a
+    no-op when jax is absent — CPU-only paths keep working) and prime the
+    process gauges. Adds zero threads; call at training and serving startup.
+    """
+    install_program_listener()
     refresh_runtime_gauges()
 
 

@@ -22,35 +22,34 @@ zero records, zero registry series when ``SM_DEVICE_TELEMETRY`` is unset):
   sampling never pay it twice per interval. Watermarks ride the fleet
   span shipper to rank 0, where ``/status`` renders a memory section and
   names a *memory*-skewed rank.
-* **Roofline attribution** — :func:`roofline_fields` combines measured
-  device time with the compiled cost into achieved FLOPs/s, bytes/s, and
-  the binding resource (compute / memory / latency); RoundTimer emits one
-  ``training.roofline`` record and mirrors it into
-  ``training.attribution``, ``/status``, and bench.py's final JSON.
+* **Stage table** — the round program names its own work: every stage of
+  a boosting round is traced under a ``jax.named_scope`` (:data:`STAGES`),
+  which XLA keeps as ``op_name`` metadata on every instruction, fusions
+  included. :func:`round_program_stages` reads the optimised HLO of the
+  session's round program into ``{instruction name: stage}``; a profiler
+  trace names each device event by its instruction, so the join gives
+  device time by stage. Lazy: the session registers a closure over shapes
+  only (:func:`register_round_program`); nothing is lowered until somebody
+  asks, and the ``training.compiled`` record carries the table's summary.
 * **OOM forensics** — :func:`dump_oom_forensics` writes
   ``hbm-forensics-rank<r>.json`` (top live buffers by shape/size,
   allocator stats, the compiled memory analysis, the last watermark) on
   the booster's ``RESOURCE_EXHAUSTED`` path before the watchdog abort
   (exit 86, ``EXIT_DEVICE_OOM``). The forensics path is robustness, not
   telemetry: like exits 79-85 it fires regardless of the gate.
-
-Binding-resource heuristic (deterministic, no hardware database): a round
-whose device time sits under ``LATENCY_FLOOR_MS`` is dispatch-floor bound
-("latency"); otherwise operational intensity (flops / bytes accessed)
-against ``DEFAULT_RIDGE_FLOPS_PER_BYTE`` splits "compute" from "memory".
-The ridge is a documented constant carried in every record, so a reader
-can re-judge against their hardware's real ridge point.
 """
 
 import json
 import logging
 import os
+import re
 import threading
 import time
 
 from ..utils.envconfig import env_bool, env_int
 from .emit import emit_metric
 from .registry import REGISTRY
+from .spans import span
 
 logger = logging.getLogger(__name__)
 
@@ -60,14 +59,32 @@ DEVICE_TELEMETRY_ENV = "SM_DEVICE_TELEMETRY"
 HBM_SAMPLE_EVERY_ENV = "SM_HBM_SAMPLE_EVERY"
 DEFAULT_HBM_SAMPLE_EVERY = 8
 
-#: operational-intensity ridge (flops per HBM byte) splitting compute- from
-#: memory-bound; stamped into every roofline record so the verdict can be
-#: re-judged against real hardware (v5p HBM ridge is far higher — a program
-#: memory-bound at 10 is memory-bound everywhere that matters)
-DEFAULT_RIDGE_FLOPS_PER_BYTE = 10.0
-#: per-round device time under this is dominated by the per-dispatch floor
-#: (host->device transfer, dispatch latency), not by the program itself
-LATENCY_FLOOR_MS = 0.5
+#: stage names of the round program, in the order a round runs them. Each is
+#: a ``jax.named_scope`` where the work is traced (models/booster.py,
+#: ops/tree_build.py, ops/histogram.py) and so the ``op_name`` metadata of
+#: every HLO instruction under it; ``hist_allreduce`` exists on a mesh only.
+STAGE_GRAD = "grad"
+STAGE_HIST = "hist"
+STAGE_HIST_ALLREDUCE = "hist_allreduce"
+STAGE_NODE_TOTALS = "node_totals"
+STAGE_SPLIT_SCAN = "split_scan"
+STAGE_ROUTE_ROWS = "route_rows"
+STAGE_LEAF_MARGIN = "leaf_margin"
+STAGE_EVAL_APPLY = "eval_apply"
+STAGE_EVAL_METRIC = "eval_metric"
+STAGE_PACK = "pack"
+STAGES = (
+    STAGE_GRAD,
+    STAGE_HIST,
+    STAGE_HIST_ALLREDUCE,
+    STAGE_NODE_TOTALS,
+    STAGE_SPLIT_SCAN,
+    STAGE_ROUTE_ROWS,
+    STAGE_LEAF_MARGIN,
+    STAGE_EVAL_APPLY,
+    STAGE_EVAL_METRIC,
+    STAGE_PACK,
+)
 
 #: one cached device-memory walk serves every consumer inside this window
 SAMPLE_MAX_AGE_S = 1.0
@@ -75,6 +92,8 @@ SAMPLE_MAX_AGE_S = 1.0
 _state_lock = threading.Lock()
 _last_compiled = None  # the note_compiled record (train round program)
 _last_watermark = None  # the last sample_watermark result
+_round_program = None  # () -> jax Compiled, registered by the live session
+_stage_table = None  # round_program_stages() of _round_program, once asked
 _watermark_high = 0  # high-water bytes_in_use across watermark samples
 
 _sample_lock = threading.Lock()
@@ -206,10 +225,11 @@ def note_compiled(
     backend=None,
     kind="train_round",
     registry=None,
+    stages=None,
 ):
     """Fold one program's cost dict (:func:`cost_from_compiled`) into the
     plane: emit the ``training.compiled`` record, set the gauges, and keep
-    the record for roofline math, ``/status``, and OOM forensics. The
+    the record for ``/status`` and OOM forensics. The
     caller gates on :func:`enabled` — this function assumes the plane is
     armed. Returns the record."""
     k = max(int(rounds_per_dispatch or 1), 1)
@@ -231,6 +251,10 @@ def note_compiled(
         record["mesh_shape"] = {str(a): int(n) for a, n in dict(mesh_shape).items()}
     if backend:
         record["backend"] = backend
+    if stages:
+        # the stage table's summary (stages_from_hlo_text): instructions and
+        # result bytes per stage, "" for what was traced under none
+        record["stages"] = stages
     global _last_compiled
     with _state_lock:
         if kind == "train_round" or _last_compiled is None:
@@ -312,70 +336,132 @@ def memory_status():
     return doc
 
 
-# ----------------------------------------------------------------- roofline
-def roofline_fields(
-    compiled,
-    device_ms,
-    rounds,
-    source="residual",
-    ridge=DEFAULT_RIDGE_FLOPS_PER_BYTE,
-    latency_floor_ms=LATENCY_FLOOR_MS,
-):
-    """Pure roofline math -> the ``training.roofline`` field dict.
+# -------------------------------------------------------------- stage table
+def stage(name):
+    """The ``jax.named_scope`` of one round-program stage (trace time only:
+    a scope is HLO metadata and costs nothing at run time)."""
+    import jax
 
-    ``compiled`` is a :func:`note_compiled`-shaped dict (tests inject their
-    own); ``device_ms`` is the measured device-window time covering
-    ``rounds`` rounds, with ``source`` naming how it was measured
-    (``device_sync`` fence spans, or the ``residual`` of the round total
-    minus instrumented host phases)."""
-    rounds = max(int(rounds), 1)
-    flops_per_round = float(compiled.get("flops_per_round", 0.0) or 0.0)
-    bytes_per_round = float(compiled.get("bytes_per_round", 0.0) or 0.0)
-    seconds = max(float(device_ms), 0.0) / 1000.0
-    per_round_ms = device_ms / rounds if rounds else 0.0
-    achieved_flops = flops_per_round * rounds / seconds if seconds > 0 else 0.0
-    achieved_bytes = bytes_per_round * rounds / seconds if seconds > 0 else 0.0
-    intensity = flops_per_round / bytes_per_round if bytes_per_round > 0 else 0.0
-    if per_round_ms < latency_floor_ms:
-        binding = "latency"
-    elif intensity >= ridge:
-        binding = "compute"
+    return jax.named_scope(name)
+
+
+def stage_of_op_name(op_name):
+    """The innermost stage among the ``/``-separated scopes of an HLO
+    ``op_name`` (``jit(multi_round)/while/body/route_rows/gather``), or
+    None where the instruction was traced under no stage."""
+    for part in reversed(op_name.split("/")):
+        if part in STAGES:
+            return part
+    return None
+
+
+# "  ROOT %fusion.12 = (u32[1]{0}, u32[1]{0}) fusion(...), ..., metadata={op_name="..." ...}"
+_HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (.*)$")
+_HLO_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_HLO_CALLED = re.compile(r"(?:calls|to_apply)=%?([\w.\-]+)")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+) \(.*\{\s*$")
+_HLO_ARRAY = re.compile(r"\b(pred|[suf]\d+|bf16|c\d+)\[([\d,]*)\]")
+_DTYPE_BYTES = {"pred": 1, "bf16": 2}
+
+
+def _result_bytes(rest):
+    """Bytes of an instruction's result, from the shape in front of its
+    opcode (``f32[32,64,256]{2,1,0} fusion(...)``; a tuple sums its parts)."""
+    if rest.startswith("("):
+        depth = 0
+        for end, ch in enumerate(rest):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                break
+        shape = rest[: end + 1]
     else:
-        binding = "memory"
-    return {
-        "rounds": rounds,
-        "device_ms": round(float(device_ms), 3),
-        "device_ms_per_round": round(per_round_ms, 3),
-        "device_time_source": source,
-        "flops_per_round": round(flops_per_round, 1),
-        "bytes_per_round": round(bytes_per_round, 1),
-        "achieved_flops_per_sec": round(achieved_flops, 1),
-        "achieved_bytes_per_sec": round(achieved_bytes, 1),
-        "operational_intensity": round(intensity, 3),
-        "ridge_flops_per_byte": ridge,
-        "binding": binding,
-    }
+        shape = rest.split(" ", 1)[0]
+    total = 0
+    for dtype, dims in _HLO_ARRAY.findall(shape):
+        width = _DTYPE_BYTES.get(dtype) or max(int(dtype.lstrip("sufc")) // 8, 1)
+        count = 1
+        for dim in dims.split(","):
+            count *= int(dim) if dim else 1
+        total += width * count
+    return total
 
 
-def maybe_roofline(device_ms, rounds, source, emit=False, extra=None):
-    """The gated roofline entrypoint: None when the plane is unarmed or no
-    compiled cost was introspected; otherwise the field dict, optionally
-    emitted as one ``training.roofline`` record and mirrored into
-    ``/status``."""
-    if not enabled():
-        return None
-    compiled = last_compiled()
-    if compiled is None or rounds <= 0:
-        return None
-    fields = roofline_fields(compiled, device_ms, rounds, source)
-    if extra:
-        fields.update(extra)
-    if emit:
-        emit_metric("training.roofline", **fields)
-        from . import fleet
+def stages_from_hlo_text(text):
+    """Optimised HLO text -> (``{instruction name: stage}``, summary).
 
-        fleet.note_status(roofline=fields)
-    return fields
+    Every instruction whose ``op_name`` lies under a stage scope is in the
+    table, whichever computation holds it: a profiler trace names a device
+    event by the instruction that ran (``%fusion.12 = ...``), and a fusion
+    carries the ``op_name`` of its root. The summary counts, per stage (and
+    under ``""`` for what has none), the instructions that run as
+    operations of their own (not the bodies of fusions and reducers) and
+    the bytes of their results."""
+    table = {}
+    rows = []  # (computation, instruction, stage or "", result bytes)
+    inlined = set()  # computations that are the body of a fusion or reducer
+    computation = ""
+    for line in text.splitlines():
+        header = _HLO_COMPUTATION.match(line)
+        if header:
+            computation = header.group(1)
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if not m:
+            continue
+        name, rest = m.groups()
+        inlined.update(_HLO_CALLED.findall(rest))
+        op = _HLO_OP_NAME.search(rest)
+        found = stage_of_op_name(op.group(1)) if op else None
+        if found is not None:
+            table[name] = found
+        rows.append((computation, name, found or "", _result_bytes(rest)))
+    summary = {}
+    for comp, _name, found, nbytes in rows:
+        if comp in inlined:
+            continue
+        entry = summary.setdefault(found, {"instructions": 0, "result_bytes": 0})
+        entry["instructions"] += 1
+        entry["result_bytes"] += nbytes
+    return table, summary
+
+
+def register_round_program(compile_fn):
+    """The live session's round program, as a closure that lowers and
+    compiles it from shapes and dtypes alone (``jax.ShapeDtypeStruct``: it
+    keeps no device buffer alive). Registering costs nothing; the closure
+    runs when :func:`round_program_stages` is first asked."""
+    global _round_program, _stage_table
+    with _state_lock:
+        _round_program = compile_fn
+        _stage_table = None
+
+
+def note_stage_table(compiled):
+    """Read and keep the stage table of a compiled round program; returns
+    (table, summary). The session's gated introspection calls it with the
+    executable it compiled for ``cost_analysis`` anyway."""
+    global _stage_table
+    parsed = stages_from_hlo_text(compiled.as_text())
+    with _state_lock:
+        _stage_table = parsed
+    return parsed
+
+
+def round_program_stages():
+    """``{instruction name: stage}`` for the session's round program, from
+    the optimised HLO of its executable. Lowers and compiles on the first
+    call (the jit path's own compile is served from the persistent cache,
+    so this is a second one); ``{}`` when no session has registered."""
+    with _state_lock:
+        parsed, compile_fn = _stage_table, _round_program
+    if parsed is None:
+        if compile_fn is None:
+            return {}
+        # a span of its own: the table's cost, and the program load it
+        # causes, are named in the registry and kept apart from set-up's
+        with span("stage_table"):
+            parsed = note_stage_table(compile_fn())
+    return dict(parsed[0])
 
 
 # ------------------------------------------------------------ OOM forensics
@@ -483,7 +569,10 @@ def dump_oom_forensics(exc, default_dir=None, top_n=32):
 
 def _reset_for_tests():
     global _last_compiled, _last_watermark, _watermark_high, _sample_cache
+    global _round_program, _stage_table
     with _state_lock:
+        _round_program = None
+        _stage_table = None
         _last_compiled = None
         _last_watermark = None
         _watermark_high = 0

@@ -108,6 +108,11 @@ _PHASE_SPANS = {
     "collective.dispatch": "collective",
 }
 _COMPONENTS = ("host", "device", "collective")
+#: an XLA compile ends inside the `host_dispatch` that triggered it and is
+#: not dispatch work: its span is taken off the host component again (the
+#: correction RoundTimer makes on the flat phases), so a first round's
+#: compile stays in the remainder, as before every dispatch recorded spans
+_COMPILE_SPAN = "xla.compile"
 
 
 def fleet_enabled():
@@ -477,6 +482,9 @@ class FleetCollector:
         if component is not None:
             self._running[rank][component] += dur_ms
             return None
+        if name == _COMPILE_SPAN:
+            self._running[rank]["host"] -= dur_ms
+            return None
         if name != "round":
             return None
         attrs = wire.get("attributes") or {}
@@ -489,6 +497,7 @@ class FleetCollector:
             return None  # the post-training tail span has no round index
         entry = {"total": dur_ms}
         entry.update(running)
+        entry["host"] = max(entry["host"], 0.0)  # compiles outside any dispatch
         per_rank = self._rounds.setdefault(round_index, {})
         per_rank[rank] = entry
         if len(per_rank) >= self.num_ranks:

@@ -32,6 +32,7 @@ import contextlib
 import json
 import logging
 import os
+import sys
 import threading
 import time
 import uuid
@@ -77,6 +78,7 @@ class Span:
         "tid",
         "thread_name",
         "seq",
+        "annotation",
     )
 
     def __init__(self, name, trace_id, parent_id, attributes=None):
@@ -93,6 +95,8 @@ class Span:
         # the flight recorder — the fleet shipper's drain watermark
         # (telemetry/fleet.py ships spans with seq > last-shipped)
         self.seq = None
+        # the entered jax.profiler.TraceAnnotation of the same name (or None)
+        self.annotation = None
 
     def context(self):
         return (self.trace_id, self.span_id)
@@ -197,6 +201,30 @@ def _resolve_parent(parent, trace_id, root):
     return trace_id or new_id(), None
 
 
+# ------------------------------------------------- profiler annotations
+_annotation_cls = None  # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def annotate(name, attributes=None):
+    """Enter a ``jax.profiler.TraceAnnotation`` named ``name`` and return it
+    (leave it with ``__exit__``), or None while jax is not loaded. While a
+    profiler session runs (``jax.profiler.start_trace``, ``xla_trace``,
+    ``/debug/profile``) the annotation is an event on the host plane of the
+    same ``.xplane.pb`` as the device operations, on the profiler's clock;
+    with no session it is a flag test. jax is looked up, never imported: a
+    process that has not imported it has no session to write into."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        cls = _annotation_cls = jax.profiler.TraceAnnotation
+    annotation = cls(name, **attributes) if attributes else cls(name)
+    annotation.__enter__()
+    return annotation
+
+
 # ----------------------------------------------------------------- span API
 def start_span(name, attributes=None, parent=None, trace_id=None, root=False):
     """Open a span (None when tracing is disabled). ``parent`` overrides the
@@ -209,6 +237,7 @@ def start_span(name, attributes=None, parent=None, trace_id=None, root=False):
     _stack().append(span)
     with _state_lock:
         _live[span.span_id] = span
+    span.annotation = annotate(name, attributes)
     return span
 
 
@@ -217,6 +246,9 @@ def finish_span(span, **attributes):
     to the flight recorder."""
     if span is None:
         return
+    if span.annotation is not None:
+        span.annotation.__exit__(None, None, None)
+        span.annotation = None
     span.dur_us = max(_now_us() - span.start_us, 0.0)
     if attributes:
         span.attributes.update(attributes)

@@ -126,9 +126,9 @@ class RoundTimer:
             )
             self._compile_base = compile_now
             self._compile_total_s += compile_delta
-            # NOTE: a compile that completes inside a fenced dispatch is
-            # already subtracted from the host_dispatch phase at the source
-            # (booster._maybe_fenced_dispatch measures the exact overlap),
+            # NOTE: a compile that completes inside a dispatch is already
+            # subtracted from the host_dispatch phase at the source
+            # (booster._timed_dispatch measures the exact overlap),
             # so compile + host_dispatch + build_eval sum without double
             # counting; values only clamp here against float noise
             for name, seconds in phases.items():
@@ -223,35 +223,15 @@ class RoundTimer:
                     fields["fold"] = self.fold
                 emit_metric("training.summary", **fields)
                 self._emit_attribution(total)
-                # roofline record (device plane): the measured device window
-                # against the compiled cost — one record per training run
-                device_ms, source = self._device_window_ms(total)
-                extra = {"fold": self.fold} if self.fold is not None else None
-                device_telemetry.maybe_roofline(
-                    device_ms, len(self._times), source, emit=True, extra=extra
-                )
         return model
-
-    def _device_window_ms(self, total_s):
-        """-> (device-window ms, source): the fenced ``device_sync`` span
-        totals when SM_TRACE_DEVICE_SYNC was armed, else the residual of
-        the round totals minus every instrumented host phase and compile —
-        the same remainder the round records call ``build_eval``."""
-        device_s = self._phase_totals.get("device_sync", 0.0)
-        if device_s > 0:
-            return device_s * 1000.0, "device_sync"
-        residual = max(
-            total_s - sum(self._phase_totals.values()) - self._compile_total_s,
-            0.0,
-        )
-        return residual * 1000.0, "residual"
 
     def _emit_attribution(self, total_s, rolling=False, round_index=None):
         """One ``training.attribution`` record: where the run's wall time
         went — XLA compile (the jax.monitoring listener), host dispatch /
-        device compute (the SM_TRACE_DEVICE_SYNC sampling spans), and the
-        calibrated histogram collectives. Fields are 0.0 when the matching
-        instrumentation wasn't armed, so the record shape is stable.
+        device compute (the `host_dispatch` / `device_sync` spans every
+        dispatch records), and the calibrated histogram collectives. Fields
+        are 0.0 when the matching instrumentation wasn't armed, so the
+        record shape is stable.
 
         ``rolling=True`` marks the SM_ATTRIBUTION_EVERY mid-job emissions
         (cumulative since the start of training — same shape, plus the
@@ -267,20 +247,6 @@ class RoundTimer:
             collective_ms=float(comm_per_round) * len(self._times),
         )
         fields["rounds"] = len(self._times)
-        # mirror the roofline verdict (device plane; None when unarmed or
-        # nothing introspected) so attribution says WHY the device share is
-        # what it is, not just how big it is
-        device_ms, source = self._device_window_ms(total_s)
-        roofline = device_telemetry.maybe_roofline(
-            device_ms, len(self._times), source
-        )
-        if roofline is not None:
-            fields["roofline"] = {
-                "binding": roofline["binding"],
-                "achieved_flops_per_sec": roofline["achieved_flops_per_sec"],
-                "achieved_bytes_per_sec": roofline["achieved_bytes_per_sec"],
-                "operational_intensity": roofline["operational_intensity"],
-            }
         if rolling:
             fields["rolling"] = True
         if round_index is not None:

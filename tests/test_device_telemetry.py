@@ -2,8 +2,8 @@
 
 Covers the unset-gate guard (no records, no threads, bit-identical trees vs
 an armed run — AOT lowering must not consume the RNG stream), the
-``training.compiled`` record shape on a tiny mesh train, the roofline math
-with injected costs (compute / memory / latency binding), the HBM watermark
+``training.compiled`` record shape on a tiny mesh train (with the stage
+table's summary), the stage table from HLO text, the HBM watermark
 cadence (SM_HBM_SAMPLE_EVERY) and wire shape, the shared cached sampler the
 heartbeat plane delegates to, the OOM forensics drill (injected
 RESOURCE_EXHAUSTED -> hbm-forensics-rank0.json + exit 86), the /status
@@ -64,7 +64,7 @@ def _tiny_data(n=256, d=5):
 def _train_tiny(mesh=None, rounds=4, timer=False):
     X, y = _tiny_data()
     # the entrypoint layer installs RoundTimer (training/callbacks.py); tests
-    # that assert the roofline/watermark path add it explicitly
+    # that assert the watermark path add it explicitly
     callbacks = [RoundTimer(log_every=0)] if timer else None
     return train(
         {"max_depth": 3, "objective": "binary:logistic"},
@@ -82,12 +82,12 @@ def test_gate_off_no_records_no_threads(device_env, capsys):
     _train_tiny(timer=True)
     out = capsys.readouterr().out
     assert _records(out, "training.compiled") == []
-    assert _records(out, "training.roofline") == []
     assert set(threading.enumerate()) == before
     assert device.sample_cadence() == 0
     assert device.watermark_wire() is None
     assert device.memory_status() is None
-    assert device.maybe_roofline(100.0, 4, "residual") is None
+    # the stage table is lazy: registered, never computed on this path
+    assert device._round_program is not None and device._stage_table is None
 
 
 def test_gate_does_not_change_trees(device_env, tmp_path, capsys):
@@ -125,44 +125,66 @@ def test_compiled_record_on_tiny_mesh_train(device_env, capsys):
     assert rec["rounds_per_dispatch"] >= 1
     assert rec["mesh_shape"] == {"data": 2}
     assert rec["backend"] == "cpu"
-    # the roofline record rode the same run
-    rooflines = _records(out, "training.roofline")
-    assert len(rooflines) == 1
-    roof = rooflines[0]
-    assert roof["binding"] in ("compute", "memory", "latency")
-    assert roof["device_time_source"] in ("device_sync", "residual")
-    assert roof["rounds"] == 4
-    assert roof["achieved_flops_per_sec"] >= 0
+    # the stage table's summary rode the same record: every stage of a
+    # one-tree round, the collective's among them on a mesh
+    for name in ("grad", "hist", "hist_allreduce", "split_scan", "route_rows"):
+        assert rec["stages"][name]["instructions"] > 0, name
     # and the record survived for /status + forensics
     last = device.last_compiled()
     assert last is not None and last["flops"] == rec["flops"]
 
 
-# ------------------------------------------------------------- roofline math
-def test_roofline_compute_bound_units():
-    compiled = {"flops_per_round": 1e6, "bytes_per_round": 1e4}
-    fields = device.roofline_fields(compiled, device_ms=1000.0, rounds=10)
-    # 1e6 flops x 10 rounds over 1 second
-    assert fields["achieved_flops_per_sec"] == pytest.approx(1e7)
-    assert fields["achieved_bytes_per_sec"] == pytest.approx(1e5)
-    assert fields["operational_intensity"] == pytest.approx(100.0)
-    assert fields["binding"] == "compute"
-    assert fields["device_ms_per_round"] == pytest.approx(100.0)
-    assert fields["ridge_flops_per_byte"] == device.DEFAULT_RIDGE_FLOPS_PER_BYTE
+# --------------------------------------------------------------- stage table
+_HLO = """HloModule jit_multi_round
+
+%fused_computation.3 (p0: f32[8]) -> f32[8] {
+  %p0 = f32[8]{0} parameter(0)
+  ROOT %mul.9 = f32[8]{0} multiply(%p0, %p0), metadata={op_name="jit(multi_round)/while/body/closed_call/grad/mul"}
+}
+
+%body.1 (arg: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %arg = (s32[], f32[8]{0}) parameter(0)
+  %gte.2 = f32[8]{0} get-tuple-element(%arg), index=1
+  %fusion.12 = f32[8]{0} fusion(%gte.2), kind=kLoop, calls=%fused_computation.3, metadata={op_name="jit(multi_round)/while/body/closed_call/grad/mul" source_file="booster.py" source_line=9}
+  %gather.4 = (u16[16,4]{1,0}, pred[4]{0}) gather(%fusion.12), metadata={op_name="jit(multi_round)/while/body/closed_call/hist/hist_allreduce/psum"}
+  ROOT %copy.7 = f32[8]{0} copy(%fusion.12), metadata={op_name="reduce_window_sum"}
+}
+"""
 
 
-def test_roofline_memory_bound():
-    compiled = {"flops_per_round": 1e4, "bytes_per_round": 1e4}
-    fields = device.roofline_fields(compiled, device_ms=1000.0, rounds=10)
-    assert fields["operational_intensity"] == pytest.approx(1.0)
-    assert fields["binding"] == "memory"
+def test_stage_of_op_name_takes_the_innermost_scope():
+    assert device.stage_of_op_name("jit(f)/while/body/closed_call/route_rows/gather") == "route_rows"
+    assert device.stage_of_op_name("jit(f)/while/body/hist/hist_allreduce/psum") == "hist_allreduce"
+    assert device.stage_of_op_name("jit(f)/eval_apply/while/body/jit(take_along_axis)/gather") == "eval_apply"
+    assert device.stage_of_op_name("reduce_window_sum") is None
+    assert set(device.STAGES) >= {"grad", "hist", "node_totals", "split_scan", "pack"}
 
 
-def test_roofline_latency_floor():
-    # 0.1 ms/round of device time: the dispatch floor, not the program
-    compiled = {"flops_per_round": 1e9, "bytes_per_round": 1.0}
-    fields = device.roofline_fields(compiled, device_ms=1.0, rounds=10)
-    assert fields["binding"] == "latency"
+def test_stages_from_hlo_text_names_fusions_and_counts_what_runs():
+    table, summary = device.stages_from_hlo_text(_HLO)
+    # a fusion takes the stage of its own op_name; its body is named too
+    assert table == {"mul.9": "grad", "fusion.12": "grad", "gather.4": "hist_allreduce"}
+    # the summary counts what runs as an operation of its own: the fusion's
+    # body does not, and what was traced under no stage goes under ""
+    assert summary["grad"] == {"instructions": 1, "result_bytes": 32}
+    assert summary["hist_allreduce"] == {"instructions": 1, "result_bytes": 16 * 4 * 2 + 4}
+    assert summary[""]["instructions"] == 3  # parameter, get-tuple-element, copy
+
+
+def test_round_program_stages_is_lazy_and_cached(device_env):
+    class Compiled:
+        def as_text(self):
+            return _HLO
+
+    calls = []
+    assert device.round_program_stages() == {}  # no session has registered
+    device.register_round_program(lambda: calls.append(1) or Compiled())
+    assert calls == []  # registering lowers nothing
+    assert device.round_program_stages()["fusion.12"] == "grad"
+    assert device.round_program_stages()["gather.4"] == "hist_allreduce"
+    assert calls == [1]  # compiled once, when somebody asked
+    device.register_round_program(lambda: calls.append(2) or Compiled())
+    assert device._stage_table is None  # a new session drops the old table
 
 
 # ------------------------------------------------------------ HBM watermarks

@@ -525,7 +525,11 @@ def smoke(rehearsal, work):
     # 1. fused dispatch (no checkpoint directory)
     out, text, model_dir, wall = run_trainer("train_fused", work, platform, ROUNDS_FUSED)
     fields = runtime_line(text, "train_fused")
-    check_runtime(fields, "train_fused", device, platform, "train")
+    check_runtime(
+        fields, "train_fused", device, platform, "train",
+        route_impl="dense" if platform == "tpu" else "gather",
+        route_width=NUM_FEATURES,
+    )
     cache_dir = fields.get("compile_cache_dir")
     losses, compile_s, rounds_s = check_training(
         "train_fused", out, model_dir, ROUNDS_FUSED, LOGLOSS_BOUND[mode]
@@ -534,10 +538,11 @@ def smoke(rehearsal, work):
         "train_fused: {} rounds, wall {:.1f}s (smoke observation), rounds "
         "{:.1f}s of which compile {:.1f}s as the child reports; "
         "validation-logloss {:.4f} -> {:.4f}; hist={} (interpreted: {}) "
-        "totals={} ingest={} mesh={}".format(
+        "totals={} route={} ingest={} mesh={}".format(
             ROUNDS_FUSED, wall, rounds_s, compile_s, losses[0], losses[-1],
             fields["hist_impl"], fields["pallas_interpret"],
-            fields["totals_impl"], fields["ingest"], fields["mesh"],
+            fields["totals_impl"], fields["route_impl"], fields["ingest"],
+            fields["mesh"],
         )
     )
 

@@ -42,6 +42,7 @@ from ..ops.histogram import (
 from ..ops.ranking import build_group_layout, lambdarank_grad_hess
 from ..ops.tree_build import (
     build_tree,
+    choose_route_impl,
     pack_tree,
     predict_binned,
     tree_from_packed,
@@ -251,13 +252,15 @@ def _merged_distributed_cuts(dtrain, max_bin, weights=None):
 
 
 def _apply_packed_tree(packed, bins, margins, num_group, num_parallel, depth,
-                       num_bins, route_impl=None):
+                       num_bins, route_backend):
     """margins += the packed tree's (or tree stack's) outputs on ``bins``.
 
     Runs under trace (the round fn and the session apply fn), so the
-    routing knob must arrive as ``route_impl`` — the session's
-    ``hist_knobs.route_impl`` snapshot, never a trace-time env read.
+    backend that decides the bin fetch's lowering must arrive as
+    ``route_backend`` — the session's ``hist_knobs.route_backend``
+    snapshot, never a trace-time read.
     """
+    route_impl = choose_route_impl(route_backend, bins.shape[1])
 
     def one(t):
         return predict_binned(t, bins, depth, num_bins, route_impl=route_impl)
@@ -1083,7 +1086,7 @@ class _TrainingSession:
         # (and with it every device buffer) alive
         d_pad = self.d_pad
         n_fs = self.n_feature_shards
-        route_impl = self.hist_knobs.route_impl
+        route_backend = self.hist_knobs.route_backend
 
         def multi_round(
             bins, margins, labels, weights, num_cuts, rng, feature_mask, monotone,
@@ -1142,7 +1145,7 @@ class _TrainingSession:
                         m_e = _apply_packed_tree(
                             packed, b_e, extra[ei],
                             num_group, num_parallel, predict_depth, num_bins,
-                            route_impl=route_impl,
+                            route_backend=route_backend,
                         )
                         new_extra.append(m_e)
                         ei += 1
@@ -1254,12 +1257,12 @@ class _TrainingSession:
         num_group = self.num_group
         num_parallel = cfg.num_parallel_tree
 
-        route_impl = self.hist_knobs.route_impl
+        route_backend = self.hist_knobs.route_backend
 
         def apply_tree(packed, bins, margins):
             return _apply_packed_tree(
                 packed, bins, margins, num_group, num_parallel,
-                cfg.predict_depth, num_bins, route_impl=route_impl,
+                cfg.predict_depth, num_bins, route_backend=route_backend,
             )
 
         if self.mesh is None:

@@ -48,7 +48,7 @@ HistKnobs = collections.namedtuple(
     [
         "impl",          # GRAFT_HIST_IMPL (backend-aware default)
         "totals_impl",   # GRAFT_TOTALS_IMPL (backend-aware default)
-        "route_impl",    # GRAFT_ROUTE_IMPL (ops/tree_build.row_bin_lookup)
+        "route_backend", # jax.default_backend() (ops/tree_build.choose_route_impl)
         "matmul_chunk",  # GRAFT_HIST_CHUNK
         "pallas_block",  # GRAFT_HIST_BLOCK
         "precision",     # GRAFT_HIST_MM_PREC
@@ -72,7 +72,7 @@ def resolve_hist_knobs():
     return HistKnobs(
         impl=_impl(),
         totals_impl=_totals_impl(),
-        route_impl=os.environ.get("GRAFT_ROUTE_IMPL", "gather"),
+        route_backend=jax.default_backend(),
         matmul_chunk=_matmul_chunk(),
         pallas_block=_pallas_block(),
         precision=_matmul_precision(),

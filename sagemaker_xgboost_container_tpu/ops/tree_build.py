@@ -13,8 +13,6 @@ finalized rows hold -1 and accumulate their leaf value into ``row_out``, so
 the booster updates margins without re-predicting the train set.
 """
 
-import os
-
 import jax
 import jax.numpy as jnp
 
@@ -49,28 +47,60 @@ from .split import (
 MIN_SPLIT_LOSS = 1e-6  # xgboost kRtEps
 
 
+# Widest bins matrix whose split bins the TPU fetches with the dense pass: the
+# widest probed at which dense beats gather for both storage dtypes. ns a row
+# and call on one v5e (scripts/dissect.py --route-widths, 1,048,576 rows up to
+# width 1,024, then 2**30 / width; PR 27, PERF.md section 5):
+#
+#   width    gather u8 / u16    dense u8 / u16
+#      28     10.80 / 10.82      0.21 /  0.21
+#      54     10.81 / 12.50      0.19 /  0.20
+#     136     12.76 / 13.47      0.26 /  0.44
+#     512     12.40 / 12.56      0.98 /  1.44
+#   1,024     12.35 / 12.46      1.60 /  2.80
+#   2,048     12.39 / 12.48      2.87 /  5.59
+#   4,096     12.56 / 12.64      5.75 / 11.17
+#   8,192     12.87 / 12.88     11.46 / 22.36   <- u16 loses
+#
+# The gather costs the same at every width; the dense pass grows with the
+# bytes it reads (1.4 ns a row per 1,000 u8 columns, 2.7 per 1,000 u16).
+ROUTE_DENSE_MAX_WIDTH = 4096
+
+
+def choose_route_impl(backend, width):
+    """The lowering ``row_bin_lookup`` takes for a bins matrix of ``width``
+    columns (static at trace time; the shard-local width on a feature-sharded
+    mesh) on ``backend``. The dense pass costs n * width and the gather n
+    times a constant, so dense wins up to a width, and only where gathers
+    serialize: on the CPU the gather is the fast one."""
+    if backend == "tpu" and width <= ROUTE_DENSE_MAX_WIDTH:
+        return "dense"
+    return "gather"
+
+
 def row_bin_lookup(bins, feat_idx, impl=None):
     """Per-row bin of a per-row feature: ``bins[i, feat_idx[i]]``.
 
-    Two lowerings, A/B-able on hardware via ``GRAFT_ROUTE_IMPL``:
+    Two lowerings, the same integers bit for bit:
 
-    * ``gather`` (default): ``take_along_axis`` — a [n] gather over the lane
-      dimension.
-    * ``onehot``: masked sum over the feature axis — n*d VPU multiply-adds,
-      no gather; can win on TPU where cross-lane gathers serialize.
+    * ``gather``: ``take_along_axis`` — one dynamic gather per row.
+    * ``dense``: compare ``feat_idx`` with an iota over the feature axis,
+      select, reduce over features — one fusion that reads every bin once in
+      its storage dtype and writes no [n, d] intermediate.
 
-    Both used by level routing here and binned eval prediction. ``impl``:
-    the session's ``HistKnobs.route_impl`` (env fallback for direct
-    callers).
+    Used by level routing here and by binned eval prediction. ``impl``: a
+    lowering by name (traced callers resolve it from the session's
+    ``HistKnobs.route_backend`` through ``choose_route_impl``); None chooses
+    from the process's backend, for direct callers only.
     """
     if impl is None:
-        # graftlint: disable=trace-env-read — direct-caller fallback only;
-        # sessions snapshot this via resolve_hist_knobs() at build time
-        impl = os.environ.get("GRAFT_ROUTE_IMPL", "gather")
-    if impl == "onehot":
+        impl = choose_route_impl(jax.default_backend(), bins.shape[1])
+    if impl == "dense":
         d = bins.shape[1]
-        oh = feat_idx[:, None] == jnp.arange(d, dtype=jnp.int32)[None, :]
-        return jnp.sum(jnp.where(oh, bins, 0).astype(jnp.int32), axis=1)
+        wanted = feat_idx[:, None] == jnp.arange(d, dtype=jnp.int32)[None, :]
+        return jnp.sum(jnp.where(wanted, bins, 0).astype(jnp.int32), axis=1)
+    if impl != "gather":
+        raise ValueError("unknown row_bin_lookup lowering: {!r}".format(impl))
     return jnp.take_along_axis(bins, feat_idx[:, None], axis=1)[:, 0].astype(jnp.int32)
 
 
@@ -166,6 +196,9 @@ def build_tree(
     d_scan = padded_feature_width(d, n_data_shards) // n_data_shards if reduce_scatter else d
     data_shard = jax.lax.axis_index(axis_name) if reduce_scatter else None
     max_nodes = max_nodes_for_depth(max_depth)
+    route_impl = (
+        choose_route_impl(knobs.route_backend, d) if knobs is not None else None
+    )
     # bins stay in their storage dtype (u8/u16 from binning) end to end:
     # every consumer widens inside a fused op, so no [n, d] i32 copy is ever
     # materialized in HBM and the hot-loop bin reads move half the bytes
@@ -432,9 +465,7 @@ def build_tree(
             split_feat = splits["feature"][local_safe]
             split_bin = splits["bin"][local_safe]
             if feature_axis_name is None:
-                row_bin = row_bin_lookup(
-                    bins, split_feat, impl=knobs.route_impl if knobs else None
-                )
+                row_bin = row_bin_lookup(bins, split_feat, impl=route_impl)
                 is_missing = row_bin == (num_bins - 1)
                 go_right = jnp.where(
                     is_missing, ~splits["default_left"][local_safe], row_bin > split_bin
@@ -444,9 +475,7 @@ def build_tree(
                 # rows; decisions psum-broadcast along the feature axis
                 owner = (split_feat // d) == feat_shard
                 local_idx = jnp.clip(split_feat - feat_shard * d, 0, d - 1)
-                row_bin = row_bin_lookup(
-                    bins, local_idx, impl=knobs.route_impl if knobs else None
-                )
+                row_bin = row_bin_lookup(bins, local_idx, impl=route_impl)
                 is_missing = row_bin == (num_bins - 1)
                 decision = jnp.where(
                     is_missing, ~splits["default_left"][local_safe], row_bin > split_bin
@@ -538,9 +567,9 @@ def predict_binned(tree, bins, max_depth, num_bins, route_impl=None):
     tree of actual depth ~8 costs ~8 gather rounds, not 255. Used for
     validation-set evaluation during training (validation is binned with the
     training cuts, so bin comparison == float comparison). ``route_impl``:
-    the session's ``HistKnobs.route_impl`` — traced callers must thread it
-    (trace-safety; None falls back to an env read for direct unit-test
-    callers only).
+    ``row_bin_lookup``'s lowering — traced callers resolve it from the
+    session's ``HistKnobs.route_backend`` (trace-safety; None chooses from
+    the process's backend, for direct callers only).
     """
     n = bins.shape[0]
 

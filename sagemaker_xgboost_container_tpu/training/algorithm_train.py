@@ -520,7 +520,7 @@ def train_job(
     # Chunked ingest hands over matrices it sketched and binned on the host;
     # the sketch lowering named in the line is the whole-file path's
     start_device_runtime(
-        "train", mesh=mesh, knobs=hist_knobs,
+        "train", mesh=mesh, knobs=hist_knobs, route_width=train_dmatrix.num_col,
         ingest="chunked" if isinstance(train_dmatrix, BinnedMatrix) else "whole",
     )
     # r2: ranking objectives shard rows by group and survival:cox gathers

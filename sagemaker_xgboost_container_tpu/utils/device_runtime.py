@@ -67,13 +67,15 @@ def _libtpu_version():
         return None
 
 
-def start_device_runtime(role, mesh=None, knobs=None, **facts):
+def start_device_runtime(role, mesh=None, knobs=None, route_width=None, **facts):
     """Arm the compile cache and log the ``device runtime:`` line.
 
     ``role``: "train" | "serve". ``mesh``: the training mesh (None on one
     device). ``knobs``: the session's HistKnobs snapshot; resolved here when
     the caller has none (the server — it reports what a session in this
-    process would pick). ``facts``: what else only the caller knows about
+    process would pick). ``route_width``: the feature width of the train bins,
+    for which the line names the bin fetch's lowering (the trainer; a server
+    routes no binned rows). ``facts``: what else only the caller knows about
     the path taken (the trainer's ingest mode). Returns the logged fields.
     """
     import jax
@@ -81,6 +83,7 @@ def start_device_runtime(role, mesh=None, knobs=None, **facts):
 
     from ..data.binning import _sketch_impl
     from ..ops.histogram import pallas_interpret, resolve_hist_knobs
+    from ..ops.tree_build import choose_route_impl
 
     cache_dir = enable_compile_cache()
     if knobs is None:
@@ -96,7 +99,12 @@ def start_device_runtime(role, mesh=None, knobs=None, **facts):
         ),
         hist_impl=knobs.impl,
         totals_impl=knobs.totals_impl,
-        route_impl=knobs.route_impl,
+        route_impl=(
+            choose_route_impl(knobs.route_backend, route_width)
+            if route_width is not None
+            else None
+        ),
+        route_width=route_width,
         sketch_impl=_sketch_impl(),
         pallas_interpret=pallas_interpret(),
         compile_cache_dir=cache_dir,

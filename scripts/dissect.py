@@ -4,14 +4,19 @@
 Times each stage of the bench configuration (bench.py: 1M x 28, depth 8,
 max_bin 256, binary:logistic) in isolation under jit, so a round's time on
 the device can be attributed: grad/hess, per-level histograms (with the sibling
-subtraction that the real build does), node totals, split scan, row routing
-(gather vs onehot), eval prediction, and the full fused tree build.
+subtraction that the real build does), node totals, split scan, row routing,
+eval prediction, and the full fused tree build.
 
 Prints one "stage: ms" line per stage plus a JSON summary line at the end.
-Honors GRAFT_HIST_IMPL / GRAFT_HIST_MM_PREC / GRAFT_ROUTE_IMPL. Run under an
-external timeout, like anything that holds a device.
+Honors GRAFT_HIST_IMPL / GRAFT_HIST_MM_PREC. ``--route-impl gather|dense``
+forces the lowering of the routing stage's bin fetch (default: what
+``ops/tree_build.choose_route_impl`` picks here). ``--route-widths`` runs the
+width probe instead: both lowerings of ``row_bin_lookup`` at each feature
+width and bins dtype, the table beside ``ROUTE_DENSE_MAX_WIDTH``. Run under
+an external timeout, like anything that holds a device.
 """
 
+import argparse
 import json
 import os
 import sys
@@ -42,7 +47,79 @@ def _time(fn, *args):
     return best * 1e3
 
 
+ROUTE_PROBE_WIDTHS = (28, 54, 136, 512, 1024, 2048)
+ROUTE_PROBE_CALLS = 8  # one per level: enqueued back to back, one wait
+
+
+def route_width_probe(widths, n_rows):
+    """ms per call of ``row_bin_lookup`` under each lowering, by feature width
+    and bins dtype, as a level of the round program calls it (per-row feature
+    ids, the result compared with a per-row split bin). Bins are made on the
+    device, in the layout a ``device_put`` matrix of that shape has."""
+    import jax
+    import jax.numpy as jnp
+
+    from sagemaker_xgboost_container_tpu.ops import tree_build as TB
+
+    rows = []
+    for width in widths:
+        n = min(n_rows, (1 << 30) // width)  # under 2**30 bins: a few GB at the widest
+        for dtype, num_bins in ((jnp.uint8, 256), (jnp.uint16, 257)):
+            k_bins, k_feat, k_split = jax.random.split(jax.random.PRNGKey(width), 3)
+            bins = jax.jit(
+                lambda k, dtype=dtype, num_bins=num_bins, n=n: (
+                    jax.random.bits(k, (n, width), dtype) % num_bins
+                ).astype(dtype)
+            )(k_bins)
+            feat = jax.random.randint(k_feat, (n,), 0, width, jnp.int32)
+            split_bin = jax.random.randint(k_split, (n,), 0, num_bins - 1, jnp.int32)
+            jax.block_until_ready((bins, feat, split_bin))
+            row = {"width": width, "dtype": jnp.dtype(dtype).name, "rows": n}
+            outs = {}
+            for impl in ("gather", "dense"):
+                fn = jax.jit(
+                    lambda b, f, sb, impl=impl: TB.row_bin_lookup(b, f, impl=impl) > sb
+                )
+
+                def calls(b, f, sb, fn=fn):
+                    out = None
+                    for _ in range(ROUTE_PROBE_CALLS):
+                        out = fn(b, f, sb)
+                    return out
+
+                ms = _time(calls, bins, feat, split_bin) / ROUTE_PROBE_CALLS
+                row[impl + "_ms"] = ms
+                row[impl + "_ns_per_row"] = ms * 1e6 / n
+                outs[impl] = fn(bins, feat, split_bin)
+            row["equal"] = bool(jnp.array_equal(outs["gather"], outs["dense"]))
+            row["chosen"] = TB.choose_route_impl(jax.default_backend(), width)
+            print(json.dumps(row), flush=True)
+            rows.append(row)
+            del bins, outs
+    return rows
+
+
+def _emit(summary, out_path):
+    line = json.dumps(summary)
+    print(line)
+    if out_path:
+        os.makedirs(os.path.dirname(os.path.abspath(out_path)), exist_ok=True)
+        with open(out_path, "w") as f:
+            f.write(line + "\n")
+
+
 def main():
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--route-impl", choices=("gather", "dense"), default=None)
+    ap.add_argument(
+        "--route-widths", nargs="*", type=int, default=None, metavar="D",
+        help="run only the width probe (default widths: {})".format(
+            " ".join(map(str, ROUTE_PROBE_WIDTHS))
+        ),
+    )
+    ap.add_argument("--out", default=None, help="also write the JSON summary here")
+    args = ap.parse_args()
+
     import jax
     import jax.numpy as jnp
 
@@ -51,11 +128,25 @@ def main():
     from sagemaker_xgboost_container_tpu.ops.split import find_best_splits
 
     print("backend:", jax.default_backend(), flush=True)
+    if args.route_widths is not None:
+        summary = {
+            "backend": jax.default_backend(),
+            "device_kind": jax.devices()[0].device_kind,
+            "route_dense_max_width": TB.ROUTE_DENSE_MAX_WIDTH,
+            "route_width_probe": route_width_probe(
+                args.route_widths or ROUTE_PROBE_WIDTHS, N_ROWS
+            ),
+        }
+        _emit(summary, args.out)
+        return
+    route_impl = args.route_impl or TB.choose_route_impl(
+        jax.default_backend(), N_FEATURES
+    )
     print(
         "impl={} prec={} route={}".format(
             os.environ.get("GRAFT_HIST_IMPL", "flat"),
             os.environ.get("GRAFT_HIST_MM_PREC", "bf16x2"),
-            os.environ.get("GRAFT_ROUTE_IMPL", "gather"),
+            route_impl,
         ),
         flush=True,
     )
@@ -130,7 +221,7 @@ def main():
 
     @jax.jit
     def route(b, sf):
-        row_bin = TB.row_bin_lookup(b, sf)
+        row_bin = TB.row_bin_lookup(b, sf, impl=route_impl)
         return row_bin > 128
 
     timings["route_lookup[n]"] = _time(route, bins, split_feat) * MAX_DEPTH
@@ -157,7 +248,10 @@ def main():
 
     for k, v in timings.items():
         print("{:28s} {:9.2f} ms".format(k, v), flush=True)
-    print(json.dumps({"backend": jax.default_backend(), "timings_ms": timings}))
+    _emit(
+        {"backend": jax.default_backend(), "route_impl": route_impl, "timings_ms": timings},
+        args.out,
+    )
 
 
 if __name__ == "__main__":

@@ -243,12 +243,10 @@ def test_colsample_bynode_actually_wired():
 
 
 def test_route_impls_equivalent():
-    """GRAFT_ROUTE_IMPL=onehot must build identical trees to the gather
-    default (both levelwise routing and binned eval prediction use it)."""
-    import os
-
-    import numpy as np
-
+    """train() under the dense bin fetch must build identical trees to the
+    gather (both levelwise routing and binned eval prediction use it, so the
+    logged validation losses must agree too). The lowering follows the
+    session snapshot's backend; tests/test_row_routing.py has the pieces."""
     from sagemaker_xgboost_container_tpu.data.matrix import DataMatrix
     from sagemaker_xgboost_container_tpu.models import train
 
@@ -256,23 +254,29 @@ def test_route_impls_equivalent():
     X = rng.rand(3000, 7).astype(np.float32)
     X[rng.rand(3000, 7) < 0.1] = np.nan
     y = (np.nan_to_num(X[:, 0]) + np.nan_to_num(X[:, 2]) > 1).astype(np.float32)
-    d = DataMatrix(X, labels=y)
-    params = {"objective": "binary:logistic", "max_depth": 5}
+    d = DataMatrix(X[:2400], labels=y[:2400])
+    dval = DataMatrix(X[2400:], labels=y[2400:])
+    params = {"objective": "binary:logistic", "max_depth": 5, "_rounds_per_dispatch": 2}
 
-    prior = os.environ.get("GRAFT_ROUTE_IMPL")
-    try:
-        os.environ["GRAFT_ROUTE_IMPL"] = "gather"
-        f_gather = train(params, d, num_boost_round=4)
-        os.environ["GRAFT_ROUTE_IMPL"] = "onehot"
-        f_onehot = train(params, d, num_boost_round=4)
-    finally:
-        if prior is None:
-            os.environ.pop("GRAFT_ROUTE_IMPL", None)
-        else:
-            os.environ["GRAFT_ROUTE_IMPL"] = prior
+    class KeepLog:
+        def after_iteration(self, forest, rnd, evals_log):
+            self.evals_log = evals_log
+            return False
+
+    forests, logs = {}, {}
+    for backend in ("cpu", "tpu"):  # gather, dense at this width
+        keep = KeepLog()
+        forests[backend] = train(
+            params, d, num_boost_round=4, evals=[(d, "train"), (dval, "validation")],
+            callbacks=[keep], verbose_eval=False,
+            hist_knobs=hist_mod.resolve_hist_knobs()._replace(route_backend=backend),
+        )
+        logs[backend] = {k: dict(v) for k, v in keep.evals_log.items()}
     np.testing.assert_array_equal(
-        np.asarray(f_gather.predict_margin(X)), np.asarray(f_onehot.predict_margin(X))
+        np.asarray(forests["cpu"].predict_margin(X)),
+        np.asarray(forests["tpu"].predict_margin(X)),
     )
+    assert logs["cpu"] == logs["tpu"] and len(logs["cpu"]["validation"]["logloss"]) == 4
 
 
 def test_mxu_aligned_hist_matches_flat():

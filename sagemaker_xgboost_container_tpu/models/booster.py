@@ -13,8 +13,8 @@ topology — SURVEY.md §5). Trees come out bitwise identical on every shard, so
 the "master saves the model" contract is trivially consistent. Rows are
 zero-weight padded to a multiple of the shard count.
 
-Ranking objectives route through ops/ranking's LambdaMART gradients over a
-padded [groups, max_group] layout.
+Ranking objectives route through ops/ranking's LambdaMART gradients over
+query groups bucketed by size.
 
 Callback protocol mirrors xgboost's (before_training / after_iteration ->
 bool stop / after_training) so the orchestration layer's checkpoint, early
@@ -39,7 +39,12 @@ from ..ops.histogram import (
     resolve_hist_knobs,
     round_comm_plan,
 )
-from ..ops.ranking import build_group_layout, lambdarank_grad_hess
+from ..ops.ranking import (
+    GroupLayout,
+    build_group_layout,
+    lambdarank_grad_hess,
+    pair_slots,
+)
 from ..ops.tree_build import (
     build_tree,
     choose_route_impl,
@@ -473,10 +478,9 @@ class _TrainingSession:
         # shards, so intra-group pairwise gradients stay shard-exact — the
         # reference's Rabit ranking path keeps worker groups whole the same
         # way, hyperparameter_validation.py:283-309 trains them under Rabit)
-        self.row_index = None
+        self.rank_layout = None        # GroupLayout (numpy) of the train rows
         self.rank_perm = None          # device-order position -> original row
         self.rank_pos = None           # original (local) row -> device position
-        self._rank_index_np = None     # [local_shards, G_max, M]
         if self.is_ranking:
             # ranking composes with a feature axis: the group-partitioned
             # row layout permutes ROWS only, so bins shard P("data",
@@ -489,41 +493,8 @@ class _TrainingSession:
                 groups = np.asarray([dtrain.num_row], np.int64)
             else:
                 groups = np.asarray(dtrain.groups, np.int64)
-            if mesh is None:
-                self.row_index = jnp.asarray(build_group_layout(groups))
-            else:
-                from ..ops.ranking import build_sharded_group_layout
-
-                # DATA shards only: with a feature axis, local_devices also
-                # counts column shards, which hold the same rows
-                local_shards = (
-                    max(1, int(mesh.local_mesh.shape["data"]))
-                    if self.is_multiprocess
-                    else self.n_data_shards
-                )
-                perm, ri, rps = build_sharded_group_layout(groups, local_shards)
-                if self.is_multiprocess:
-                    # all hosts must agree on padded shapes
-                    from jax.experimental import multihost_utils
-
-                    maxima = np.asarray(
-                        multihost_utils.process_allgather(
-                            np.asarray([rps, ri.shape[1], ri.shape[2]], np.int64)
-                        )
-                    ).max(axis=0)
-                    perm, ri, rps = build_sharded_group_layout(
-                        groups,
-                        local_shards,
-                        rows_per_shard=int(maxima[0]),
-                        max_groups_per_shard=int(maxima[1]),
-                        max_group_size=int(maxima[2]),
-                    )
-                self.rank_perm = perm
-                self._rank_index_np = ri
-                pos = np.full(dtrain.num_row, -1, np.int64)
-                m = perm >= 0
-                pos[perm[m]] = np.nonzero(m)[0]
-                self.rank_pos = pos
+            with span("setup.group_layout", attributes={"what": "train"}):
+                self._build_rank_layout(groups, dtrain.num_row)
 
         pre_binned = isinstance(dtrain, BinnedMatrix)
         shared_cuts = None
@@ -693,12 +664,10 @@ class _TrainingSession:
             self.labels = _put(_layout_rows(labels, 0.0), P("data"))
             self.weights = _put(_layout_rows(dtrain.get_weight(), 0.0), P("data"))
             self.groups = dtrain.groups
-            if self._rank_index_np is not None:
-                self.rank_index_dev = _put(self._rank_index_np, P("data", None, None))
-            elif self.row_index is not None:
-                self.rank_index_dev = self.row_index
-            else:
+            if self.rank_layout is None:
                 self.rank_index_dev = jnp.zeros((1, 1), jnp.int32)  # inert dummy
+            else:
+                self.rank_index_dev = self._put_layout(self.rank_layout, self._rank_specs())
 
             base = self.objective.base_margin(forest.base_score)
             shape = (n_pad,) if self.num_group == 1 else (n_pad, self.num_group)
@@ -762,13 +731,25 @@ class _TrainingSession:
         # lines (reference semantics: metrics allreduced under the
         # communicator, distributed.py:219). They activate when batching is
         # requested (K > 1) or when multi-process exactness needs them.
+        # A ranking job's metrics are per query group (ndcg): on one device
+        # they ride the scan over each evaluation set's bucketed group layout
+        # (device_metrics.grouped_ndcg), so K rounds log K metric lines and
+        # the host pulls no margins. On a mesh the evaluation rows are not
+        # partitioned by group, and a set without groups is one group of all
+        # its rows: both stay on the host path below, as `map` does.
+        grouped_metrics = (
+            self.is_ranking
+            and mesh is None
+            and all(dm.groups is not None for _name, dm, _b in self.eval_sets)
+        )
         want_device_metrics = (
             self.eval_sets
             and metric_names
             and not has_feval
-            and not self.is_ranking
+            and (not self.is_ranking or grouped_metrics)
             and (self.rounds_per_dispatch > 1 or self.is_multiprocess)
         )
+        self.eval_layouts = ()  # a GroupLayout per non-shared evaluation set
         if want_device_metrics:
             from .device_metrics import all_supported
 
@@ -777,9 +758,17 @@ class _TrainingSession:
                 self.objective.name,
                 self.num_group,
                 config.objective_params,
+                grouped=grouped_metrics,
             )
             if self.device_metric_fns is not None:
                 self.device_metric_names = list(metric_names)
+                if any(fn.needs_groups for fn in self.device_metric_fns):
+                    with span("setup.group_layout", attributes={"what": "eval_sets"}):
+                        self.eval_layouts = tuple(
+                            self._put_layout(build_group_layout(dm.groups))
+                            for _name, dm, binned in self.eval_sets
+                            if binned is not self.train_binned
+                        )
         # Metrics outside device_metrics.all_supported (feval, ranking
         # metrics, non-decomposable scalars) no longer force K -> 1: the
         # fused dispatch keeps K, the scan carries every eval set's margins
@@ -862,21 +851,80 @@ class _TrainingSession:
             self._apply_fn = self._make_apply_fn()
         self._introspect_compiled_cost(self._register_round_program())
 
+    def _put_layout(self, layout, specs=None):
+        """A host ``GroupLayout`` as device arrays: whole on one device, by
+        ``specs`` (a ``GroupLayout`` of partition specs) on a mesh."""
+        if specs is None:
+            specs = jax.tree_util.tree_map(lambda _: P(), layout)
+        return jax.tree_util.tree_map(self._put, layout, specs)
+
+    def _rank_specs(self):
+        """How a mesh shards the train rows' ``GroupLayout`` (one index a
+        shard, its rows' slots with the rows); None on one device."""
+        if self.rank_layout is None or self.mesh is None:
+            return None
+        return GroupLayout((P("data", None, None),), P("data"), P())
+
+    def _build_rank_layout(self, groups, num_row):
+        """The train rows' query groups as the round program takes them: on
+        one device bucketed by size (ops/ranking.build_group_layout); on a
+        mesh rows are re-partitioned BY GROUP, one index a shard."""
+        from ..telemetry import REGISTRY
+
+        if self.mesh is None:
+            self.rank_layout = build_group_layout(groups)
+        else:
+            from ..ops.ranking import build_sharded_group_layout
+
+            # DATA shards only: with a feature axis, local_devices also
+            # counts column shards, which hold the same rows
+            local_shards = (
+                max(1, int(self.mesh.local_mesh.shape["data"]))
+                if self.is_multiprocess
+                else self.n_data_shards
+            )
+            perm, layout, rps = build_sharded_group_layout(groups, local_shards)
+            if self.is_multiprocess:
+                # all hosts must agree on padded shapes
+                from jax.experimental import multihost_utils
+
+                ri = layout.indices[0]
+                maxima = np.asarray(
+                    multihost_utils.process_allgather(
+                        np.asarray([rps, ri.shape[1], ri.shape[2]], np.int64)
+                    )
+                ).max(axis=0)
+                perm, layout, rps = build_sharded_group_layout(
+                    groups,
+                    local_shards,
+                    rows_per_shard=int(maxima[0]),
+                    max_groups_per_shard=int(maxima[1]),
+                    max_group_size=int(maxima[2]),
+                )
+            self.rank_perm = perm
+            self.rank_layout = layout
+            pos = np.full(num_row, -1, np.int64)
+            m = perm >= 0
+            pos[perm[m]] = np.nonzero(m)[0]
+            self.rank_pos = pos
+        # what a round computes against what the groups hold: set once
+        REGISTRY.gauge(
+            "rank_pair_slots",
+            "Pair slots one round's LambdaMART pair pass computes over the "
+            "padded group layout (this host's shards)",
+        ).set(pair_slots(self.rank_layout))
+        REGISTRY.gauge(
+            "rank_pairs_real",
+            "Ordered document pairs inside the query groups: the sum of the "
+            "squared group sizes",
+        ).set(float(np.sum(np.square(groups.astype(np.float64)))))
+
     # ------------------------------------------------------------------ jit
     def _grad_hess_fn(self):
         if not self.is_ranking:
             return None
-        scheme = self.objective.scheme
-
-        def ranking_grads(margins, labels, weights, rank_index):
-            if rank_index.ndim == 3:
-                # per-shard [1, G_max, M] slice under shard_map
-                rank_index = rank_index.reshape(rank_index.shape[1:])
-            return lambdarank_grad_hess(
-                margins, labels, weights, rank_index, scheme=scheme
-            )
-
-        return ranking_grads
+        # (margins, labels, weights, layout); a shard's slices under shard_map
+        return partial(lambdarank_grad_hess, scheme=self.objective.scheme)
 
     def _make_round_fn(self):
         cfg = self.config
@@ -1090,11 +1138,13 @@ class _TrainingSession:
 
         def multi_round(
             bins, margins, labels, weights, num_cuts, rng, feature_mask, monotone,
-            rank_index, eval_m, eval_blw,
+            rank_index, eval_m, eval_blw, eval_groups=(),
         ):
             # eval_blw: ((bins, labels, weights), ...) for the non-shared
             # eval sets — passed as sharded args (closures would stay global
-            # under shard_map and mismatch the per-shard margins)
+            # under shard_map and mismatch the per-shard margins);
+            # eval_groups: their GroupLayouts, where a metric is per group
+            # (the shared set's is rank_index)
             # lax.scan so the round body is compiled ONCE regardless of K
             k_features = max(1, int(round(colsample * d)))
 
@@ -1139,9 +1189,10 @@ class _TrainingSession:
                 ei = 0
                 for si, shared in enumerate(shared_flags):
                     if shared:
-                        m_e, y_e, w_e = margins_c, labels, weights
+                        m_e, y_e, w_e, groups_e = margins_c, labels, weights, rank_index
                     else:
                         b_e, y_e, w_e = eval_blw[ei]
+                        groups_e = eval_groups[ei] if eval_groups else None
                         m_e = _apply_packed_tree(
                             packed, b_e, extra[ei],
                             num_group, num_parallel, predict_depth, num_bins,
@@ -1158,7 +1209,9 @@ class _TrainingSession:
                     # the global rows first — its replicated stats are
                     # pre-divided by the axis size so the shared psum
                     # restores the global value.
-                    def _stats_for(fn, m_s, y_s, w_s):
+                    def _stats_for(fn, m_s, y_s, w_s, groups_s=groups_e):
+                        if fn.needs_groups:
+                            return fn.partial(m_s, y_s, w_s, groups_s)
                         if fn.needs_global_rows and axis_name is not None:
                             m_g = jax.lax.all_gather(m_s, axis_name, tiled=True)
                             y_g = jax.lax.all_gather(y_s, axis_name, tiled=True)
@@ -1210,9 +1263,7 @@ class _TrainingSession:
             return jax.jit(fn, donate_argnums=(1, 9))
 
         margin_spec = P("data") if num_group == 1 else P("data", None)
-        rank_spec = (
-            P("data", None, None) if self._rank_index_np is not None else P()
-        )
+        rank_spec = self._rank_specs() or P()
         base_specs = (
             self.bins_spec,    # bins
             margin_spec,       # margins
@@ -1238,7 +1289,7 @@ class _TrainingSession:
                 for b in self.eval_bins
                 if b is not None
             )
-            in_specs = base_specs + (eval_specs, eval_blw_specs)
+            in_specs = base_specs + (eval_specs, eval_blw_specs, ())
             out_specs = (P(), P(), margin_spec, eval_specs) + stats_specs
             donate = (1, 9)
         mapped = jax.shard_map(
@@ -1555,7 +1606,7 @@ class _TrainingSession:
                 for i in range(len(self.eval_bins))
                 if self.eval_bins[i] is not None
             )
-            args += (eval_m, eval_blw)
+            args += (eval_m, eval_blw, self.eval_layouts)
         avals = jax.tree_util.tree_map(aval, args)
         round_fn = self._round_fn
 
@@ -1788,7 +1839,8 @@ class _TrainingSession:
             if self.eval_bins[i] is not None
         )
         out = self._timed_dispatch(
-            lambda: self._round_fn(*args, eval_m, eval_blw), attributes
+            lambda: self._round_fn(*args, eval_m, eval_blw, self.eval_layouts),
+            attributes,
         )
         if self.learning_stats:
             packed, metrics, self.margins, eval_m_out, lstats = out
@@ -1928,6 +1980,8 @@ def evaluate_host_lines(
                 if is_multiprocess
                 else None
             )
+            if dmf is not None and dmf.needs_groups:
+                dmf = None  # per group: the weighted-mean combine below
             if dmf is not None and dmf.needs_global_rows:
                 # non-decomposable (cox-nloglik): gather every host's rows
                 # (padded to the max local length, weight 0) and evaluate on

@@ -33,14 +33,20 @@ class DeviceMetric:
     over the data axis, call ``partial`` on the replicated global arrays,
     and divide by the axis size so the shared downstream psum restores the
     global value (mirroring the booster's Cox gradient path, which gathers
-    global risk sets the same way)."""
+    global risk sets the same way).
 
-    def __init__(self, name, size, partial, finalize, needs_global_rows=False):
+    ``needs_groups`` marks a metric that is a mean over query groups (ndcg):
+    its ``partial`` takes a fourth argument, the ``ops.ranking.GroupLayout``
+    of the rows, and returns (sum of the per-group values, groups)."""
+
+    def __init__(self, name, size, partial, finalize, needs_global_rows=False,
+                 needs_groups=False):
         self.name = name
         self.size = size
         self.partial = partial
         self.finalize = finalize
         self.needs_global_rows = needs_global_rows
+        self.needs_groups = needs_groups
 
     def __call__(self, margins, labels, weights):
         return self.finalize(self.partial(margins, labels, weights))
@@ -77,10 +83,50 @@ def _weighted_mean_metric(name, objective_name, term_fn, post=None):
     return DeviceMetric(name, 2, partial, finalize)
 
 
+def grouped_ndcg(name, k=None):
+    """``ndcg`` / ``ndcg@k`` over a ``GroupLayout``: per group DCG over ideal
+    DCG with gain ``2^label - 1`` and discount ``1 / log2(1 + rank)``, ranks
+    by margin descending with ties broken by position (a stable argsort's
+    order), a group without a relevant document counted as 1 — the semantics
+    of ``eval_metrics.ndcg``, which ignores weights too. Padding slots carry
+    no gain and rank last, so they add nothing."""
+    from ..ops import ranking
+
+    def per_group(S, Y, valid):
+        gains = ranking.dcg_gain(Y, valid)
+        ranks = ranking.rank_descending(S, valid)
+        terms = gains * ranking.dcg_discount(ranks)
+        if k:
+            terms = jnp.where(ranks <= k, terms, 0.0)
+        dcg = terms.sum(axis=1)
+        ideal = ranking.ideal_dcg(Y, gains, valid, k)
+        ndcg = jnp.where(ideal > 0, dcg / jnp.where(ideal > 0, ideal, 1.0), 1.0)
+        held = valid.any(axis=1)  # groups added to fill a chunk hold nothing
+        return jnp.where(held, ndcg, 0.0), held.astype(jnp.float32)
+
+    def partial(m, y, w, layout):
+        total = count = layout.empty_groups  # the host counts an empty group as 1
+        for index in layout.indices:
+            valid, S, Y = ranking.gather_groups(index, (m, y), (0.0, 0.0))
+            ndcg, held = ranking.map_group_chunks(
+                per_group, (S, Y, valid), fills=(0.0, 0.0, False)
+            )
+            total = total + ndcg.sum()
+            count = count + held.sum()
+        return jnp.stack([total, count])
+
+    return DeviceMetric(
+        name, 2, partial, lambda s: s[0] / jnp.maximum(s[1], _EPS), needs_groups=True
+    )
+
+
 def make_device_metric(name, objective_name, num_group=1, params=None):
     """-> DeviceMetric, or None if unsupported on device."""
     params = params or {}
     base, _, suffix = name.partition("@")
+
+    if base == "ndcg":
+        return grouped_ndcg(name, int(float(suffix)) if suffix else None)
 
     if num_group > 1:
         if base == "merror":
@@ -229,8 +275,10 @@ def make_device_metric(name, objective_name, num_group=1, params=None):
     return None
 
 
-def all_supported(names, objective_name, num_group, params=None):
+def all_supported(names, objective_name, num_group, params=None, grouped=False):
+    """The metrics as device functions, or None where one cannot run there.
+    ``grouped``: the caller has a ``GroupLayout`` for every set of rows."""
     fns = [make_device_metric(n, objective_name, num_group, params) for n in names]
-    if any(f is None for f in fns):
+    if any(f is None or (f.needs_groups and not grouped) for f in fns):
         return None
     return fns

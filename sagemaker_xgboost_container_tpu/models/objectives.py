@@ -364,8 +364,9 @@ class LambdaRankObjective(Objective):
     """rank:pairwise / rank:ndcg / rank:map — LambdaMART gradients.
 
     Gradients need the query-group layout, so the booster routes these through
-    ``ops.ranking.lambdarank_grad_hess`` over a padded [groups, max_group]
-    index built once per dataset. This class carries scheme metadata only.
+    ``ops.ranking.lambdarank_grad_hess`` over a ``GroupLayout`` (groups
+    bucketed by size) built once per dataset. This class carries scheme
+    metadata only.
     """
 
     name = "rank:pairwise"

@@ -1,22 +1,68 @@
 """LambdaMART gradients for rank:pairwise / rank:ndcg / rank:map.
 
 The reference delegates ranking to libxgboost's LambdaRank objective (group
-layout carried by the DMatrix). Here query groups are padded into a dense
-[G, M] layout (G groups, M = max group size) once on the host, and each round
-computes all intra-group pairwise RankNet gradients as one XLA program:
-sigmoid on the score-difference matrix, masked by label ordering, optionally
-weighted by |delta NDCG| (LambdaMART), then scattered back to row order.
+layout carried by the DMatrix). Here query groups are laid out once on the
+host as dense ``[G, M]`` row indices with -1 padding, and each round computes
+all intra-group pairwise RankNet gradients as one XLA program: sigmoid on the
+score-difference matrix, masked by label ordering, optionally weighted by
+|delta NDCG| (LambdaMART), then handed back in row order.
 
-O(G * M^2) memory — fine for typical web-ranking group sizes (MSLR ~ 100-1300
-docs/query). Groups larger than ``max_group_size`` are truncated with a
-warning at layout build time (matching common LightGBM/XGBoost practice).
+Group sizes are skewed (MSLR-WEB30K: 1 to 1,251 documents a query, mean
+120), so one layout padded to the largest group would compute fifty times
+the real pairs. ``build_group_layout`` therefore sorts groups into buckets
+by size (widths doubling from 32 to 512, then every 256 up to the largest
+group rounded up to the lane width), each bucket an index of its own, and
+``lambdarank_grad_hess`` runs the pair pass per bucket, ``lax.map`` over
+chunks of groups sized so that one ``[chunk, M, M]`` pair tensor stays
+under ``PAIR_SLOTS_PER_STEP`` elements. No group is truncated. A single
+``[G, M]`` index is the one-bucket case; the mesh path keeps one index a
+shard (``build_sharded_group_layout``).
+
+Every row lies in exactly one slot, so the way back from slots to rows is a
+gather through the inverse index (``row_slot``), not a scatter-add.
 """
+
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..telemetry.device import (
+    STAGE_RANK_GATHER,
+    STAGE_RANK_PAIRS,
+    STAGE_RANK_SCATTER,
+    stage,
+)
+
 _SIGMA = 1.0
+
+#: bucket widths: doubling up to 512, then every 256 (``bucket_widths``)
+_FIRST_WIDTH = 32
+_LAST_DOUBLED_WIDTH = 512
+_WIDTH_STEP = 256
+_LANES = 128
+_SUBLANES = 8
+#: elements of one ``[chunk, M, M]`` pair tensor a ``lax.map`` step may hold
+#: (64 MiB in float32; the pair pass keeps a handful of them alive)
+PAIR_SLOTS_PER_STEP = 1 << 24
+
+
+class GroupLayout(NamedTuple):
+    """Query groups as the round program takes them (a pytree of arrays).
+
+    indices: tuple of int32 ``[G_b, M_b]`` row indices, -1 padding, one a
+      bucket (on a mesh: one ``[shards, G, M]`` array, a ``[1, G, M]`` slice
+      a shard, in shard-local row coordinates).
+    row_slot: int32 ``[n]``, each row's position in the concatenation of the
+      flattened ``indices``; -1 for a row in no group (mesh padding).
+    empty_groups: float32 scalar, the groups that hold no row: they are in
+      no bucket, and a per-group metric counts them as the host does.
+    """
+
+    indices: tuple
+    row_slot: object
+    empty_groups: object
 
 
 def map_exchange_delta(S, Y, valid):
@@ -62,22 +108,90 @@ def map_exchange_delta(S, Y, valid):
     return jnp.abs(core) * differs / R_total
 
 
-def build_group_layout(groups, max_group_size=None):
-    """Group-size array -> (row_index [G, M] int32 with -1 padding).
+def _round_up(value, multiple):
+    return -(-int(value) // multiple) * multiple
 
-    Host-side, once per dataset.
+
+def bucket_widths(max_size):
+    """Bucket widths for groups of up to ``max_size`` rows, read from the
+    sizes alone: 32, 64, ..., 512, then 768, 1024, ... and last the largest
+    group rounded up to the lane width (to the sublane width where it is
+    under a lane's)."""
+    top = _round_up(max(max_size, 1), _LANES if max_size > _LANES else _SUBLANES)
+    widths, w = [], _FIRST_WIDTH
+    while w < top:
+        widths.append(w)
+        w = w * 2 if w < _LAST_DOUBLED_WIDTH else w + _WIDTH_STEP
+    return widths + [top]
+
+
+def _dense_index(starts, sizes, width):
+    """int32 ``[G, width]``: row ``starts[g] + j`` where ``j < sizes[g]``, else -1."""
+    j = np.arange(width, dtype=np.int64)[None, :]
+    index = np.where(j < sizes[:, None], starts[:, None] + j, -1)
+    return index.astype(np.int32)
+
+
+def _row_slots(indices, n_rows):
+    """Inverse of the concatenated flat ``indices``: row -> slot, -1 for none."""
+    row_slot = np.full(n_rows, -1, np.int32)
+    offset = 0
+    for index in indices:
+        flat = index.reshape(-1)
+        at = np.flatnonzero(flat >= 0)
+        row_slot[flat[at]] = offset + at
+        offset += flat.size
+    return row_slot
+
+
+def build_group_layout(groups, widths=None):
+    """Group-size array -> ``GroupLayout`` (numpy), groups bucketed by size.
+
+    Host-side, once per dataset. Rows of a group are contiguous, in the
+    order of ``groups``. ``widths`` overrides the bucket rule (ascending, the
+    last at least the largest group): the tests compare one bucket against
+    several.
     """
     sizes = np.asarray(groups, np.int64)
-    if max_group_size is None:
-        max_group_size = int(sizes.max())
-    G = len(sizes)
-    row_index = np.full((G, max_group_size), -1, np.int32)
-    start = 0
-    for g, size in enumerate(sizes):
-        take = min(int(size), max_group_size)
-        row_index[g, :take] = np.arange(start, start + take, dtype=np.int32)
-        start += int(size)
-    return row_index
+    starts = np.cumsum(sizes) - sizes
+    if widths is None:
+        widths = bucket_widths(int(sizes.max()) if len(sizes) else 1)
+    widths = np.asarray(widths, np.int64)
+    if len(sizes) and sizes.max() > widths[-1]:
+        raise ValueError("the widest bucket is smaller than the largest group")
+    bucket = np.searchsorted(widths, sizes)  # the narrowest that holds the group
+    indices = []
+    for b, width in enumerate(widths):
+        held = np.flatnonzero((bucket == b) & (sizes > 0))
+        if len(held):
+            indices.append(_dense_index(starts[held], sizes[held], int(width)))
+    if not indices:
+        indices.append(np.full((1, int(widths[0])), -1, np.int32))
+    return GroupLayout(
+        tuple(indices),
+        _row_slots(indices, int(sizes.sum())),
+        np.float32(np.count_nonzero(sizes == 0)),
+    )
+
+
+def _chunking(n_groups, width, pair_slots_per_step):
+    """(steps, groups a step) for one bucket: as few steps as keep a
+    ``[chunk, width, width]`` tensor under the budget, the groups spread
+    evenly over them so that at most ``steps - 1`` all-padding groups are added."""
+    steps = max(1, -(-n_groups * width * width // pair_slots_per_step))
+    steps = min(steps, n_groups)
+    return steps, -(-n_groups // steps)
+
+
+def pair_slots(layout, pair_slots_per_step=PAIR_SLOTS_PER_STEP):
+    """Pair slots a round computes over ``layout``, chunk padding included
+    (a mesh's ``[shards, G, M]`` index: over all its shards)."""
+    total = 0
+    for index in layout.indices:
+        n_groups, width = index.shape[-2:]
+        steps, chunk = _chunking(n_groups, width, pair_slots_per_step)
+        total += int(np.prod(index.shape[:-2])) * steps * chunk * width * width
+    return total
 
 
 def build_sharded_group_layout(groups, n_shards, max_group_size=None,
@@ -90,14 +204,19 @@ def build_sharded_group_layout(groups, n_shards, max_group_size=None,
     assignment balances row counts; every shard pads to the same
     ``rows_per_shard`` with -1 (weight-0) rows.
 
-    Returns (perm, row_index, rows_per_shard):
+    Returns (perm, layout, rows_per_shard):
       perm: int64 [n_shards * rows_per_shard] — device-order position ->
         original row id, -1 for padding.
-      row_index: int32 [n_shards, G_max, M] — per-shard group layout in
-        SHARD-LOCAL row coordinates, -1 padding (feed one shard's [G_max, M]
-        slice to lambdarank_grad_hess inside shard_map).
+      layout: ``GroupLayout`` of one index, int32 [n_shards, G_max, M] — the
+        per-shard groups in SHARD-LOCAL row coordinates, -1 padding — and
+        ``row_slot`` [n_shards * rows_per_shard], each shard's rows into its
+        own flattened [G_max, M] (feed one shard's slices to
+        lambdarank_grad_hess inside shard_map). One bucket a shard: the
+        shards must agree on every shape, and a shard's groups are few.
     The ``rows_per_shard`` / ``max_groups_per_shard`` / ``max_group_size``
-    overrides let multi-host runs agree on global maxima.
+    overrides let multi-host runs agree on global maxima; a
+    ``max_group_size`` under the largest local group is refused, never a
+    truncation.
     """
     sizes = np.asarray(groups, np.int64)
     G = len(sizes)
@@ -116,73 +235,150 @@ def build_sharded_group_layout(groups, n_shards, max_group_size=None,
     if max_groups_per_shard is not None:
         G_max = max(G_max, int(max_groups_per_shard))
     M = int(max_group_size if max_group_size is not None else sizes.max())
+    if M < sizes.max():
+        raise ValueError("max_group_size is smaller than the largest group")
     perm = np.full(n_shards * rps, -1, np.int64)
     row_index = np.full((n_shards, G_max, M), -1, np.int32)
+    row_slot = np.full((n_shards, rps), -1, np.int32)
     for s, group_list in enumerate(assign):
         pos = 0
         for gi, g in enumerate(sorted(group_list)):
-            size = min(int(sizes[g]), M)
+            size = int(sizes[g])
             rows = np.arange(starts[g], starts[g] + size, dtype=np.int64)
             perm[s * rps + pos : s * rps + pos + size] = rows
             row_index[s, gi, :size] = np.arange(pos, pos + size, dtype=np.int32)
             pos += size
-    return perm, row_index, rps
+        row_slot[s] = _row_slots([row_index[s]], rps)
+    layout = GroupLayout(
+        (row_index,), row_slot.reshape(-1), np.float32(np.count_nonzero(sizes == 0))
+    )
+    return perm, layout, rps
+
+
+
+def _pad_groups(array, n_groups, fill):
+    extra = n_groups - array.shape[0]
+    if not extra:
+        return array
+    pad = jnp.full((extra,) + array.shape[1:], fill, array.dtype)
+    return jnp.concatenate([array, pad], axis=0)
+
+
+def map_group_chunks(fn, arrays, pair_slots_per_step=PAIR_SLOTS_PER_STEP, fills=None):
+    """``fn(*arrays)`` over the groups of one bucket, ``lax.map`` over chunks
+    of groups sized by ``_chunking`` from the bucket's width (the second
+    axis of ``arrays[0]``, ``[G, M]``). ``fn`` maps ``[g, M]`` arrays to a
+    tuple of arrays with ``g`` leading; groups added to fill the last chunk
+    hold ``fills`` and are cut off the result."""
+    n_groups, width = arrays[0].shape
+    steps, chunk = _chunking(n_groups, width, pair_slots_per_step)
+    if steps == 1:
+        return fn(*arrays)
+    fills = fills or (0,) * len(arrays)
+    chunked = tuple(
+        _pad_groups(a, steps * chunk, fill).reshape((steps, chunk) + a.shape[1:])
+        for a, fill in zip(arrays, fills)
+    )
+    outs = jax.lax.map(lambda xs: fn(*xs), chunked)
+    return tuple(o.reshape((steps * chunk,) + o.shape[2:])[:n_groups] for o in outs)
+
+
+def gather_groups(index, columns, fills):
+    """Rows -> slots: each of ``columns`` ([n]) at ``index`` ([G, M]), the
+    padding slots holding the column's fill. Returns (valid, *gathered)."""
+    valid = index >= 0
+    safe = jnp.where(valid, index, 0)
+    return (valid,) + tuple(
+        jnp.where(valid, col[safe], fill) for col, fill in zip(columns, fills)
+    )
+
+
+def slots_to_rows(slot_values, row_slot):
+    """Slots -> rows: ``slot_values`` (the flattened buckets, concatenated)
+    read through the inverse index; a row in no slot gets 0."""
+    return jnp.where(row_slot >= 0, slot_values[jnp.maximum(row_slot, 0)], 0.0)
+
+
+def rank_descending(key, valid):
+    """1-based rank of each slot within its group by ``key`` descending,
+    ties broken by position, padding last: what ``argsort(argsort(-key))``
+    with a stable sort gives, counted from the ``[G, M, M]`` comparison the
+    pair pass forms anyway (a sort along 1,251 lanes costs far more)."""
+    k = jnp.where(valid, -key, jnp.inf)
+    ki, kj = k[:, :, None], k[:, None, :]
+    pos = jnp.arange(key.shape[1])
+    ahead = (kj < ki) | ((kj == ki) & (pos[None, None, :] < pos[None, :, None]))
+    return ahead.sum(axis=2, dtype=jnp.int32) + 1
+
+
+def dcg_gain(labels, valid):
+    return jnp.where(valid, jnp.exp2(labels) - 1.0, 0.0)
+
+
+def dcg_discount(ranks):
+    return 1.0 / jnp.log2(1.0 + ranks.astype(jnp.float32))
+
+
+def ideal_dcg(labels, gains, valid, k=None):
+    """DCG (at ``k``) of each group's documents in the order of their labels."""
+    ideal_ranks = rank_descending(labels, valid)
+    terms = gains * dcg_discount(ideal_ranks)
+    if k:
+        terms = jnp.where(ideal_ranks <= k, terms, 0.0)
+    return terms.sum(axis=1)
 
 
 def lambdarank_grad_hess(
-    margins, labels, weights, row_index, scheme="pairwise", group_chunk=256
+    margins, labels, weights, layout, scheme="pairwise",
+    pair_slots_per_step=PAIR_SLOTS_PER_STEP,
 ):
     """Per-row (grad, hess) for LambdaMART.
 
-    margins/labels/weights: [n]; row_index: [G, M] with -1 padding;
+    margins/labels/weights: [n]; layout: a ``GroupLayout``;
     scheme: "pairwise" (delta = 1) | "ndcg" (|delta NDCG|) | "map" (exact
     |delta AP| exchange weights, binary relevance = label > 0).
 
-    The O(M^2) pairwise tensors are materialized ``group_chunk`` groups at a
-    time via ``lax.map`` so web-scale group counts (MSLR: ~30k queries x up
-    to ~1300 docs) stay within HBM.
+    Three stages (``telemetry/device.py::STAGES``): ``rank_gather`` (rows to
+    slots), ``rank_pairs`` (ranks, the O(M^2) pair pass and its sums over
+    slots, per bucket, chunks of groups at a time) and ``rank_scatter``
+    (slots back to rows).
     """
-    n = margins.shape[0]
-    G, M = row_index.shape
-    if G > group_chunk:
-        pad_groups = -(-G // group_chunk) * group_chunk
-        padded_index = jnp.concatenate(
-            [row_index, jnp.full((pad_groups - G, M), -1, row_index.dtype)], axis=0
-        )
-        chunks = padded_index.reshape(pad_groups // group_chunk, group_chunk, M)
-
-        def chunk_grads(chunk_index):
-            return _lambdarank_block(
-                margins, labels, weights, chunk_index, scheme
+    grads, hesses = [], []
+    for index in layout.indices:
+        index = index.reshape(index.shape[-2:])  # a shard's [1, G, M] slice
+        with stage(STAGE_RANK_GATHER):
+            # padding is never "preferred": its label is -inf
+            valid, S, Y, W = gather_groups(
+                index, (margins, labels, weights), (0.0, -jnp.inf, 0.0)
             )
+        with stage(STAGE_RANK_PAIRS):
+            g_mat, h_mat = map_group_chunks(
+                lambda s, y, w, v: _lambdarank_block(s, y, w, v, scheme),
+                (S, Y, W, valid),
+                pair_slots_per_step,
+                fills=(0.0, -jnp.inf, 0.0, False),
+            )
+        grads.append(g_mat.reshape(-1))
+        hesses.append(h_mat.reshape(-1))
+    with stage(STAGE_RANK_SCATTER):
+        row_slot = layout.row_slot
+        return (
+            slots_to_rows(jnp.concatenate(grads), row_slot),
+            slots_to_rows(jnp.concatenate(hesses), row_slot),
+        )
 
-        g_blocks, h_blocks = jax.lax.map(chunk_grads, chunks)
-        return g_blocks.sum(axis=0), h_blocks.sum(axis=0)
-    return _lambdarank_block(margins, labels, weights, row_index, scheme)
 
-
-def _lambdarank_block(margins, labels, weights, row_index, scheme):
-    n = margins.shape[0]
-    G, M = row_index.shape
-    valid = row_index >= 0
-    safe = jnp.clip(row_index, 0, n - 1)
-    S = jnp.where(valid, margins[safe], 0.0)
-    Y = jnp.where(valid, labels[safe], -jnp.inf)  # padding never "preferred"
-    W = jnp.where(valid, weights[safe], 0.0)
-
+def _lambdarank_block(S, Y, W, valid, scheme):
+    """(g, h) of every slot of ``[G, M]`` groups: all intra-group pairs."""
     s_diff = S[:, :, None] - S[:, None, :]             # [G, M, M]
     rho = 1.0 / (1.0 + jnp.exp(_SIGMA * s_diff))       # P(swap needed | i>j)
     prefer = (Y[:, :, None] > Y[:, None, :]) & valid[:, :, None] & valid[:, None, :]
 
     if scheme == "ndcg":
-        # ranks by score descending within group (1-based), padding last
-        order_key = jnp.where(valid, -S, jnp.inf)
-        ranks = jnp.argsort(jnp.argsort(order_key, axis=1), axis=1) + 1  # [G, M]
-        gains = jnp.where(valid, jnp.exp2(jnp.where(valid, Y, 0.0)) - 1.0, 0.0)
-        discount = 1.0 / jnp.log2(1.0 + ranks.astype(jnp.float32))
-        ideal_order = jnp.sort(jnp.where(valid, gains, 0.0), axis=1)[:, ::-1]
-        ideal_discount = 1.0 / jnp.log2(2.0 + jnp.arange(M, dtype=jnp.float32))
-        max_dcg = jnp.maximum((ideal_order * ideal_discount[None, :]).sum(axis=1), 1e-12)
+        ranks = rank_descending(S, valid)              # by score, 1-based
+        gains = dcg_gain(Y, valid)
+        discount = dcg_discount(ranks)
+        max_dcg = jnp.maximum(ideal_dcg(Y, gains, valid), 1e-12)
         delta = (
             jnp.abs(gains[:, :, None] - gains[:, None, :])
             * jnp.abs(discount[:, :, None] - discount[:, None, :])
@@ -203,11 +399,4 @@ def _lambdarank_block(margins, labels, weights, row_index, scheme):
     h_mat = hess_pair.sum(axis=2) + hess_pair.sum(axis=1)
     g_mat = g_mat * W
     h_mat = jnp.maximum(h_mat, 1e-16) * W
-
-    grad = jnp.zeros(n, jnp.float32).at[safe.reshape(-1)].add(
-        jnp.where(valid, g_mat, 0.0).reshape(-1)
-    )
-    hess = jnp.zeros(n, jnp.float32).at[safe.reshape(-1)].add(
-        jnp.where(valid, h_mat, 0.0).reshape(-1)
-    )
-    return grad, hess
+    return jnp.where(valid, g_mat, 0.0), jnp.where(valid, h_mat, 0.0)

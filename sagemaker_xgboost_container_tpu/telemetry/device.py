@@ -64,6 +64,11 @@ DEFAULT_HBM_SAMPLE_EVERY = 8
 #: ops/tree_build.py, ops/histogram.py) and so the ``op_name`` metadata of
 #: every HLO instruction under it; ``hist_allreduce`` exists on a mesh only.
 STAGE_GRAD = "grad"
+#: inside ``grad`` for the ranking objectives (ops/ranking.py): rows to group
+#: slots, the pair pass with its ranks and sums, slots back to rows
+STAGE_RANK_GATHER = "rank_gather"
+STAGE_RANK_PAIRS = "rank_pairs"
+STAGE_RANK_SCATTER = "rank_scatter"
 STAGE_HIST = "hist"
 STAGE_HIST_ALLREDUCE = "hist_allreduce"
 STAGE_NODE_TOTALS = "node_totals"
@@ -75,6 +80,9 @@ STAGE_EVAL_METRIC = "eval_metric"
 STAGE_PACK = "pack"
 STAGES = (
     STAGE_GRAD,
+    STAGE_RANK_GATHER,
+    STAGE_RANK_PAIRS,
+    STAGE_RANK_SCATTER,
     STAGE_HIST,
     STAGE_HIST_ALLREDUCE,
     STAGE_NODE_TOTALS,

@@ -462,9 +462,13 @@ def test_ranking_group_chunking_equivalence():
     margins = jnp.asarray(rng.randn(n_groups * m).astype(np.float32))
     labels = jnp.asarray(rng.randint(0, 3, n_groups * m).astype(np.float32))
     weights = jnp.asarray(np.ones(n_groups * m, np.float32))
-    idx = jnp.asarray(build_group_layout(np.full(n_groups, m)))
-    g1, h1 = lambdarank_grad_hess(margins, labels, weights, idx, "ndcg", group_chunk=4)
-    g2, h2 = lambdarank_grad_hess(margins, labels, weights, idx, "ndcg", group_chunk=999)
+    idx = jax.tree_util.tree_map(jnp.asarray, build_group_layout(np.full(n_groups, m)))
+    width = idx.indices[0].shape[1]
+    # a budget of four groups' pair tensors a step against one step for all
+    g1, h1 = lambdarank_grad_hess(
+        margins, labels, weights, idx, "ndcg", pair_slots_per_step=4 * width * width
+    )
+    g2, h2 = lambdarank_grad_hess(margins, labels, weights, idx, "ndcg")
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), rtol=1e-5, atol=1e-6)
 

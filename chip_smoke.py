@@ -528,7 +528,7 @@ def smoke(rehearsal, work):
     check_runtime(
         fields, "train_fused", device, platform, "train",
         route_impl="dense" if platform == "tpu" else "gather",
-        route_width=NUM_FEATURES,
+        route_width=NUM_FEATURES, eval_traversal="level",
     )
     cache_dir = fields.get("compile_cache_dir")
     losses, compile_s, rounds_s = check_training(
@@ -538,11 +538,11 @@ def smoke(rehearsal, work):
         "train_fused: {} rounds, wall {:.1f}s (smoke observation), rounds "
         "{:.1f}s of which compile {:.1f}s as the child reports; "
         "validation-logloss {:.4f} -> {:.4f}; hist={} (interpreted: {}) "
-        "totals={} route={} ingest={} mesh={}".format(
+        "totals={} route={} eval_traversal={} ingest={} mesh={}".format(
             ROUNDS_FUSED, wall, rounds_s, compile_s, losses[0], losses[-1],
             fields["hist_impl"], fields["pallas_interpret"],
-            fields["totals_impl"], fields["route_impl"], fields["ingest"],
-            fields["mesh"],
+            fields["totals_impl"], fields["route_impl"],
+            fields["eval_traversal"], fields["ingest"], fields["mesh"],
         )
     )
 
@@ -554,7 +554,8 @@ def smoke(rehearsal, work):
     )
     fields2 = runtime_line(text2, "train_checkpointed")
     check_runtime(
-        fields2, "train_checkpointed", device, platform, "train", ingest="whole"
+        fields2, "train_checkpointed", device, platform, "train", ingest="whole",
+        eval_traversal="level",
     )
     losses2, compile2_s, rounds2_s = check_training(
         "train_checkpointed", out2, model_dir2, ROUNDS_CHECKPOINTED

@@ -47,9 +47,11 @@ from ..ops.ranking import (
 )
 from ..ops.tree_build import (
     build_tree,
+    choose_eval_traversal,
     choose_route_impl,
     pack_tree,
     predict_binned,
+    predict_binned_levels,
     tree_from_packed,
     unpack_tree,
 )
@@ -173,6 +175,7 @@ class TrainConfig:
             if self.grow_policy == "lossguide"
             else self.max_depth
         )
+        self.eval_traversal = choose_eval_traversal(self.grow_policy)
         self.process_type = p.get("process_type", "default")
         if self.process_type not in ("default", "update"):
             raise exc.UserError(
@@ -257,17 +260,26 @@ def _merged_distributed_cuts(dtrain, max_bin, weights=None):
 
 
 def _apply_packed_tree(packed, bins, margins, num_group, num_parallel, depth,
-                       num_bins, route_backend):
+                       num_bins, route_backend, traversal):
     """margins += the packed tree's (or tree stack's) outputs on ``bins``.
 
     Runs under trace (the round fn and the session apply fn), so the
-    backend that decides the bin fetch's lowering must arrive as
-    ``route_backend`` — the session's ``hist_knobs.route_backend``
-    snapshot, never a trace-time read.
+    backend that decides the lowering of the bin fetch and of the node-table
+    lookups must arrive as ``route_backend`` — the session's
+    ``hist_knobs.route_backend`` snapshot, never a trace-time read.
+    ``traversal``: the layout of the trees this session's builder makes
+    (``TrainConfig.eval_traversal``): ``level`` walks the depth-wise heap
+    level by level, ``pointer`` chases a loss-guided tree's ``left`` /
+    ``right``.
     """
     route_impl = choose_route_impl(route_backend, bins.shape[1])
 
     def one(t):
+        if traversal == "level":
+            return predict_binned_levels(
+                t, bins, depth, num_bins, route_impl=route_impl,
+                table_backend=route_backend,
+            )
         return predict_binned(t, bins, depth, num_bins, route_impl=route_impl)
 
     with stage(STAGE_EVAL_APPLY):
@@ -1129,6 +1141,7 @@ class _TrainingSession:
         metric_fns = self.device_metric_fns
         shared_flags = [b is None for b in self.eval_bins]
         predict_depth = cfg.predict_depth
+        eval_traversal = cfg.eval_traversal
         n_data_shards = self.n_data_shards
         # locals, not ``self``: the jitted closure must not keep the session
         # (and with it every device buffer) alive
@@ -1196,7 +1209,7 @@ class _TrainingSession:
                         m_e = _apply_packed_tree(
                             packed, b_e, extra[ei],
                             num_group, num_parallel, predict_depth, num_bins,
-                            route_backend=route_backend,
+                            route_backend=route_backend, traversal=eval_traversal,
                         )
                         new_extra.append(m_e)
                         ei += 1
@@ -1314,6 +1327,7 @@ class _TrainingSession:
             return _apply_packed_tree(
                 packed, bins, margins, num_group, num_parallel,
                 cfg.predict_depth, num_bins, route_backend=route_backend,
+                traversal=cfg.eval_traversal,
             )
 
         if self.mesh is None:

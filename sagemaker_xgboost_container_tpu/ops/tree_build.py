@@ -104,6 +104,78 @@ def row_bin_lookup(bins, feat_idx, impl=None):
     return jnp.take_along_axis(bins, feat_idx[:, None], axis=1)[:, 0].astype(jnp.int32)
 
 
+# Widest node table the TPU looks up with the select pass: the widest probed
+# at which select beats gather by half in every dtype. ns a row and lookup on
+# one v5e (scripts/dissect.py --node-table-widths, 2,200,000 rows, every pair
+# bit-equal; PR 29, PERF.md section 5):
+#
+#   width    gather i32 / bool / f32    select i32 / bool / f32
+#    1-32     0.09 / 0.09 / 0.09         0.09-0.11 (every dtype)
+#      64     0.09 / 0.10 / 0.09         0.19 / 0.18 / 0.19
+#     128     8.27 / 8.19 / 8.27         0.84 / 0.84 / 0.84
+#     256     8.61 / 8.19 / 8.61         0.84 / 0.84 / 0.84
+#     512     8.61 / 8.19 / 8.61         0.90 / 0.92 / 0.90
+#   1,024     5.64 / 8.55 / 5.64         0.98 / 1.14 / 0.98
+#   2,048     7.53 / 8.56 / 7.53         1.63 / 1.99 / 1.63
+#   4,096     7.53 / 8.56 / 7.52         3.17 / 3.93 / 3.17
+#   8,192     7.53 / 8.56 / 7.52         6.34 / 7.88 / 6.25   <- a tenth apart
+#
+# Up to 64 entries XLA expands the gather into a select itself (why the
+# build's lookups read 1 ns where the finished tree's 511 entries read 7);
+# from 128 it is a real gather, 8 ns a row whatever it fetches. The select
+# pass costs per lookup and not per byte up to 1,024 entries, then 3 operations
+# an entry at the VPU's rate (0.77 ns a row per 1,000 entries).
+NODE_TABLE_SELECT_MAX_WIDTH = 4096
+
+
+def choose_table_impl(backend, width):
+    """The lowering ``node_table_lookup`` takes for a node table ``width``
+    entries long (static at trace time: a level of a depth-wise tree is
+    ``2**level`` wide) on ``backend``, chosen as ``choose_route_impl``
+    chooses: the select pass costs n * width and the gather n times a
+    constant, and only the TPU serializes gathers."""
+    if backend == "tpu" and width <= NODE_TABLE_SELECT_MAX_WIDTH:
+        return "select"
+    return "gather"
+
+
+def node_table_lookup(table, idx, impl):
+    """Per-row entry of a short per-node table: ``table[idx[i]]``, ``idx``
+    inside the table.
+
+    Two lowerings, the same bits (a float travels as its 32 bits, so a
+    ``-0.0`` leaf stays ``-0.0``):
+
+    * ``gather``: the indexed gather.
+    * ``select``: compare ``idx`` with an iota over the table, select, reduce
+      over the table's axis — ``row_bin_lookup``'s dense pass with the table
+      broadcast over the rows, one fusion and no [n, width] intermediate.
+
+    ``impl``: a lowering by name, as ``choose_table_impl`` gives it.
+    """
+    if impl == "gather":
+        return table[idx]
+    if impl != "select":
+        raise ValueError("unknown node_table_lookup lowering: {!r}".format(impl))
+    wanted = idx[:, None] == jnp.arange(table.shape[0], dtype=jnp.int32)[None, :]
+    if table.dtype == jnp.bool_:
+        return jnp.any(wanted & table[None, :], axis=1)
+    if table.dtype == jnp.float32:
+        bits = jax.lax.bitcast_convert_type(table, jnp.int32)
+        return jax.lax.bitcast_convert_type(
+            jnp.sum(jnp.where(wanted, bits[None, :], 0), axis=1), jnp.float32
+        )
+    return jnp.sum(jnp.where(wanted, table[None, :], 0), axis=1)
+
+
+def choose_eval_traversal(grow_policy):
+    """How an evaluation row finds its leaf in a tree this session's builder
+    made: ``level`` (``predict_binned_levels``) for the heap ``build_tree``
+    lays out, ``pointer`` (``predict_binned``) for loss-guided trees, whose
+    nodes are numbered in the order they were split."""
+    return "pointer" if grow_policy == "lossguide" else "level"
+
+
 def max_nodes_for_depth(max_depth):
     return 2 ** (max_depth + 1) - 1
 
@@ -592,3 +664,48 @@ def predict_binned(tree, bins, max_depth, num_bins, route_impl=None):
         cond, body, (jnp.int32(0), jnp.zeros(n, jnp.int32))
     )
     return tree["leaf_value"][node]
+
+
+def predict_binned_levels(tree, bins, max_depth, num_bins, route_impl=None,
+                          table_backend=None):
+    """``predict_binned`` for a tree ``build_tree`` made, bit for bit.
+
+    Such a tree is a heap (children of i at 2i+1 / 2i+2, level L the static
+    slice [2**L - 1, 2**(L+1) - 1)), so rows walk it as the build routes its
+    own: the level loop unrolled at trace time, each level's split fields
+    looked up in that level's own ``2**L``-entry slices, rows that met a leaf
+    above staying on it. No ``while_loop``, no reduction over the rows, no
+    ``left`` / ``right``: no data-dependent control flow, so under ``vmap``
+    over a stack of trees it is the same program with a leading axis. The
+    leaf value is one lookup at the end (on the chip cheaper than the build's
+    one a level: 15.6 against 18.3 ms over 2.2M rows; PERF.md section 5).
+    ``table_backend`` chooses each ``node_table_lookup``'s lowering through
+    ``choose_table_impl`` (traced callers pass the session's
+    ``HistKnobs.route_backend``; None reads the process's backend, for direct
+    callers only).
+    """
+    if table_backend is None:
+        table_backend = jax.default_backend()
+    node = jnp.zeros(bins.shape[0], jnp.int32)
+    for level in range(max_depth):
+        first = 2**level - 1
+        width = 2**level
+        impl = choose_table_impl(table_backend, width)
+        node_local = node - first  # negative: the row met its leaf above
+        local_safe = jnp.clip(node_local, 0, width - 1)
+
+        def at_node(field):
+            return node_table_lookup(
+                tree[field][first:first + width], local_safe, impl=impl
+            )
+
+        row_bin = row_bin_lookup(bins, at_node("feature"), impl=route_impl)
+        go_right = jnp.where(
+            row_bin == (num_bins - 1), ~at_node("default_left"), row_bin > at_node("bin")
+        )
+        child = node * 2 + 1 + go_right.astype(jnp.int32)
+        node = jnp.where((node_local >= 0) & ~at_node("is_leaf"), child, node)
+    leaves = tree["leaf_value"]
+    return node_table_lookup(
+        leaves, node, impl=choose_table_impl(table_backend, leaves.shape[0])
+    )

@@ -521,6 +521,7 @@ def train_job(
     # the sketch lowering named in the line is the whole-file path's
     start_device_runtime(
         "train", mesh=mesh, knobs=hist_knobs, route_width=train_dmatrix.num_col,
+        grow_policy=train_cfg.get("grow_policy", "depthwise"),
         ingest="chunked" if isinstance(train_dmatrix, BinnedMatrix) else "whole",
     )
     # r2: ranking objectives shard rows by group and survival:cox gathers

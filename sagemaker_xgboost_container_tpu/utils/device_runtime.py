@@ -67,7 +67,9 @@ def _libtpu_version():
         return None
 
 
-def start_device_runtime(role, mesh=None, knobs=None, route_width=None, **facts):
+def start_device_runtime(
+    role, mesh=None, knobs=None, route_width=None, grow_policy=None, **facts
+):
     """Arm the compile cache and log the ``device runtime:`` line.
 
     ``role``: "train" | "serve". ``mesh``: the training mesh (None on one
@@ -75,7 +77,9 @@ def start_device_runtime(role, mesh=None, knobs=None, route_width=None, **facts)
     the caller has none (the server — it reports what a session in this
     process would pick). ``route_width``: the feature width of the train bins,
     for which the line names the bin fetch's lowering (the trainer; a server
-    routes no binned rows). ``facts``: what else only the caller knows about
+    routes no binned rows). ``grow_policy``: the trainer's, for which the line
+    names how evaluation rows walk a new tree (``eval_traversal``; a server
+    builds no trees). ``facts``: what else only the caller knows about
     the path taken (the trainer's ingest mode). Returns the logged fields.
     """
     import jax
@@ -83,7 +87,7 @@ def start_device_runtime(role, mesh=None, knobs=None, route_width=None, **facts)
 
     from ..data.binning import _sketch_impl
     from ..ops.histogram import pallas_interpret, resolve_hist_knobs
-    from ..ops.tree_build import choose_route_impl
+    from ..ops.tree_build import choose_eval_traversal, choose_route_impl
 
     cache_dir = enable_compile_cache()
     if knobs is None:
@@ -105,6 +109,9 @@ def start_device_runtime(role, mesh=None, knobs=None, route_width=None, **facts)
             else None
         ),
         route_width=route_width,
+        eval_traversal=(
+            choose_eval_traversal(grow_policy) if grow_policy is not None else None
+        ),
         sketch_impl=_sketch_impl(),
         pallas_interpret=pallas_interpret(),
         compile_cache_dir=cache_dir,

@@ -12,8 +12,12 @@ Honors GRAFT_HIST_IMPL / GRAFT_HIST_MM_PREC. ``--route-impl gather|dense``
 forces the lowering of the routing stage's bin fetch (default: what
 ``ops/tree_build.choose_route_impl`` picks here). ``--route-widths`` runs the
 width probe instead: both lowerings of ``row_bin_lookup`` at each feature
-width and bins dtype, the table beside ``ROUTE_DENSE_MAX_WIDTH``. Run under
-an external timeout, like anything that holds a device.
+width and bins dtype, the table beside ``ROUTE_DENSE_MAX_WIDTH``.
+``--node-table-widths`` runs the node-table probe: both lowerings of
+``node_table_lookup`` at each table width, the table beside
+``NODE_TABLE_SELECT_MAX_WIDTH``, then the two traversals of one depth-8 tree
+over the same rows. Run under an external timeout, like anything that holds a
+device.
 """
 
 import argparse
@@ -99,6 +103,114 @@ def route_width_probe(widths, n_rows):
     return rows
 
 
+NODE_TABLE_PROBE_WIDTHS = tuple(2**k for k in range(14))
+NODE_TABLE_PROBE_ROWS = 2_200_000  # higgs-d8's validation rows
+EVAL_WALK_GAMMA = 4.0  # leafs some of the probe tree's branches above the last level
+
+
+def node_table_probe(widths, n_rows):
+    """ns a row and lookup of ``node_table_lookup`` under each lowering, by
+    table width and dtype, as a level of the evaluation walk calls it (a
+    per-row index into the level's own table)."""
+    import jax
+    import jax.numpy as jnp
+
+    from sagemaker_xgboost_container_tpu.ops import tree_build as TB
+
+    rows = []
+    for width in widths:
+        k_tab, k_idx = jax.random.split(jax.random.PRNGKey(width))
+        idx = jax.random.randint(k_idx, (n_rows,), 0, width, jnp.int32)
+        ints = jax.random.randint(k_tab, (width,), 0, 257, jnp.int32)
+        tables = {
+            "int32": ints,
+            "bool": ints % 2 == 0,
+            "float32": jax.random.normal(k_tab, (width,), jnp.float32),
+        }
+        jax.block_until_ready((idx, tables))
+        for name, table in tables.items():
+            row = {"width": width, "dtype": name, "rows": n_rows}
+            outs = {}
+            for impl in ("gather", "select"):
+                fn = jax.jit(
+                    lambda t, i, impl=impl: TB.node_table_lookup(t, i, impl=impl)
+                )
+
+                def calls(t, i, fn=fn):
+                    out = None
+                    for _ in range(ROUTE_PROBE_CALLS):
+                        out = fn(t, i)
+                    return out
+
+                ms = _time(calls, table, idx) / ROUTE_PROBE_CALLS
+                row[impl + "_ns_per_row"] = ms * 1e6 / n_rows
+                outs[impl] = fn(table, idx)
+            row["equal"] = bool(
+                jnp.array_equal(
+                    *(
+                        jax.lax.bitcast_convert_type(outs[k], jnp.int32)
+                        if name == "float32"
+                        else outs[k]
+                        for k in ("gather", "select")
+                    )
+                )
+            )
+            row["chosen"] = TB.choose_table_impl(jax.default_backend(), width)
+            print(json.dumps(row), flush=True)
+            rows.append(row)
+    return rows
+
+
+def eval_walk_probe(n_rows, d=N_FEATURES, depth=MAX_DEPTH, max_bin=MAX_BIN):
+    """ms of one depth-wise tree applied to ``n_rows`` unseen rows under each
+    traversal and node-table lowering, every result bit-equal to the pointer
+    traversal's. The tree is built here from seeded rows, with a ``gamma``
+    that leafs some branches early."""
+    import jax
+    import jax.numpy as jnp
+
+    from sagemaker_xgboost_container_tpu.ops import tree_build as TB
+
+    B = max_bin + 1
+    dtype = jnp.uint8 if B <= 256 else jnp.uint16
+    k_train, k_eval, k_g = jax.random.split(jax.random.PRNGKey(29), 3)
+    make = jax.jit(
+        lambda k, n: (jax.random.bits(k, (n, d), jnp.uint16) % B).astype(dtype),
+        static_argnums=1,
+    )
+    train, rows = make(k_train, 200_000), make(k_eval, n_rows)
+    grad = jax.random.normal(k_g, (train.shape[0],), jnp.float32) + (
+        train[:, 0] > B // 2
+    ) * 0.5
+    tree, _ = jax.jit(
+        lambda b, g: TB.build_tree(
+            b, g, jnp.ones_like(g), jnp.full((d,), max_bin - 1, jnp.int32), depth, B,
+            gamma=EVAL_WALK_GAMMA,
+        )
+    )(train, grad)
+    route_impl = TB.choose_route_impl(jax.default_backend(), d)
+    fns = {
+        "pointer": lambda t, b: TB.predict_binned(t, b, depth, B, route_impl=route_impl),
+        "level_gather": lambda t, b: TB.predict_binned_levels(
+            t, b, depth, B, route_impl=route_impl, table_backend="cpu"
+        ),
+        "level_select": lambda t, b: TB.predict_binned_levels(
+            t, b, depth, B, route_impl=route_impl, table_backend="tpu"
+        ),
+    }
+    out = {"rows": n_rows, "depth": depth, "width": d}
+    want = None
+    for name, fn in fns.items():
+        fn = jax.jit(fn)
+        out[name + "_ms"] = _time(fn, tree, rows)
+        got = jax.lax.bitcast_convert_type(fn(tree, rows), jnp.int32)
+        want = got if want is None else want
+        out[name + "_equal"] = bool(jnp.array_equal(got, want))
+    out["leaves_reached"] = int(jnp.unique(want).size)
+    print(json.dumps(out), flush=True)
+    return out
+
+
 def _emit(summary, out_path):
     line = json.dumps(summary)
     print(line)
@@ -116,6 +228,10 @@ def main():
         help="run only the width probe (default widths: {})".format(
             " ".join(map(str, ROUTE_PROBE_WIDTHS))
         ),
+    )
+    ap.add_argument(
+        "--node-table-widths", nargs="*", type=int, default=None, metavar="W",
+        help="run only the node-table probe (default widths: 1 to 8192 doubling)",
     )
     ap.add_argument("--out", default=None, help="also write the JSON summary here")
     args = ap.parse_args()
@@ -136,6 +252,19 @@ def main():
             "route_width_probe": route_width_probe(
                 args.route_widths or ROUTE_PROBE_WIDTHS, N_ROWS
             ),
+        }
+        _emit(summary, args.out)
+        return
+    if args.node_table_widths is not None:
+        summary = {
+            "backend": jax.default_backend(),
+            "device_kind": jax.devices()[0].device_kind,
+            "node_table_select_max_width": TB.NODE_TABLE_SELECT_MAX_WIDTH,
+            "node_table_probe": node_table_probe(
+                args.node_table_widths or NODE_TABLE_PROBE_WIDTHS,
+                NODE_TABLE_PROBE_ROWS,
+            ),
+            "eval_walk_probe": eval_walk_probe(NODE_TABLE_PROBE_ROWS),
         }
         _emit(summary, args.out)
         return

@@ -4,7 +4,15 @@ margins under either, and the chooser reads a backend and a width and nothing
 else. Everything runs on the CPU with the lowering forced: through ``impl=`` /
 ``route_impl=`` where the function takes it, through the session snapshot's
 ``route_backend`` where it takes ``knobs``.
+
+The evaluation walk: ``predict_binned_levels`` (depth-wise trees, level by
+level over the level's own node tables), ``predict_binned`` (the pointer
+traversal) and the build's own ``row_out`` agree bit for bit under every
+lowering of the bin fetch and of the node-table lookup, and a session takes
+the walk its ``grow_policy`` names.
 """
+
+import functools
 
 import json
 import logging
@@ -22,17 +30,24 @@ from sagemaker_xgboost_container_tpu.data.binning import (
 )
 from sagemaker_xgboost_container_tpu.ops.histogram import resolve_hist_knobs
 from sagemaker_xgboost_container_tpu.ops.tree_build import (
+    NODE_TABLE_SELECT_MAX_WIDTH,
     ROUTE_DENSE_MAX_WIDTH,
     build_tree,
+    choose_eval_traversal,
     choose_route_impl,
+    choose_table_impl,
+    node_table_lookup,
     pack_tree,
     predict_binned,
+    predict_binned_levels,
     row_bin_lookup,
     tree_from_packed,
 )
 
 # the backend whose chooser picks each lowering at the widths used here
 BACKEND_OF = {"dense": "tpu", "gather": "cpu"}
+# the node-table lowering each backend's chooser picks at a level's width
+BACKEND_OF_TABLE = {"tpu": "select", "cpu": "gather"}
 
 
 @pytest.mark.parametrize("d", [1, 7, 28, 130])
@@ -152,6 +167,233 @@ def test_predict_binned_identical_under_each_lowering():
     assert np.unique(got["dense"]).size > 4
 
 
+# what stops a branch: nothing, a hessian floor that leafs some branches at
+# levels 1-3, a gamma no split clears (the tree is its root), and three class
+# trees side by side under vmap
+GROWTH = {
+    "full": {},
+    "early_leaves": {"min_child_weight": 45.0},
+    "single_leaf": {"gamma": 1e9},
+    "class_stack": {},
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _grown(depth, growth):
+    """(tree dict or stack of three, row_out, train bins, unseen bins, num_bins)
+    for one depth and way of growing, built once for every lowering."""
+    bins, grad, hess, num_cuts, num_bins = _nan_problem()
+    unseen = _nan_problem(n=1111, seed=9)[0]
+
+    @jax.jit
+    def build(g):
+        return build_tree(
+            jnp.asarray(bins), g, jnp.asarray(hess), jnp.asarray(num_cuts),
+            max_depth=depth, num_bins=num_bins, eta=0.3, **GROWTH[growth]
+        )
+
+    if growth != "class_stack":
+        tree, row_out = build(jnp.asarray(grad))
+        return tree, np.asarray(row_out), bins, unseen, num_bins
+    # one-vs-rest gradients of three classes cut from the first column's bins
+    classes = np.digitize(bins[:, 0], [8, 20])
+    built = [build(jnp.asarray((0.3 - (classes == c)).astype(np.float32))) for c in range(3)]
+    stack = {k: jnp.stack([t[k] for t, _ in built]) for k in built[0][0]}
+    return stack, np.stack([np.asarray(r) for _, r in built]), bins, unseen, num_bins
+
+
+def _bits(x):
+    return np.asarray(x, np.float32).view(np.int32)
+
+
+@pytest.mark.parametrize("table_backend", ["cpu", "tpu"])
+@pytest.mark.parametrize("route_impl", ["gather", "dense"])
+@pytest.mark.parametrize(
+    "depth,growth",
+    [(d, g) for d in (1, 5, 8) for g in ("full", "early_leaves", "single_leaf")]
+    + [(5, "class_stack")],
+)
+def test_level_walk_equals_pointer_traversal_and_build(depth, growth, route_impl, table_backend):
+    """Train rows land on the leaf the build routed them to, unseen rows on
+    the pointer traversal's, to the bit (the float's 32 bits, so a signed
+    zero counts)."""
+    tree, row_out, bins, unseen, num_bins = _grown(depth, growth)
+    assert choose_table_impl(table_backend, 2**depth) == BACKEND_OF_TABLE[table_backend]
+
+    def level(t, rows):
+        return predict_binned_levels(
+            t, rows, depth, num_bins, route_impl=route_impl, table_backend=table_backend
+        )
+
+    def pointer(t, rows):
+        return predict_binned(t, rows, depth, num_bins, route_impl=route_impl)
+
+    if growth == "class_stack":
+        level, pointer = (
+            lambda t, rows, f=f: jax.vmap(lambda one: f(one, rows))(t) for f in (level, pointer)
+        )
+    for rows in (unseen, bins):
+        got = _bits(level(tree, jnp.asarray(rows)))
+        np.testing.assert_array_equal(got, _bits(pointer(tree, jnp.asarray(rows))))
+    np.testing.assert_array_equal(got, _bits(row_out))
+    # node axis last: a stack of class trees reads as one tree does
+    split = ~np.asarray(tree["is_leaf"])
+    reached = np.asarray(tree["sum_hess"]) > 0
+    if growth == "single_leaf":
+        assert not split[0] and np.unique(got).size == 1
+    elif depth > 1:
+        assert np.unique(got).size > 4
+        if growth != "class_stack":
+            # both default directions among the splits rows pass through
+            assert set(np.asarray(tree["default_left"])[split & reached]) == {False, True}
+    if growth == "early_leaves" and depth > 1:
+        # a leaf rows reach at levels 1-3, and a split beside it
+        assert (~split & reached)[1:15].any() and (split & reached)[1:15].any()
+
+
+@pytest.mark.parametrize("width", [1, 2, 8, 128, 511])
+@pytest.mark.parametrize("dtype", ["int32", "bool", "float32"])
+def test_node_table_lookup_select_equals_gather(dtype, width):
+    rng = np.random.RandomState(width)
+    n = 1000 + 37
+    table = {
+        "int32": rng.randint(0, 300, size=width).astype(np.int32),
+        "bool": rng.rand(width) < 0.5,
+        # signed zeros and a non-finite entry must come back as they are
+        "float32": np.resize(
+            np.asarray([-0.0, 0.0, -1.5, np.inf, 3e-41], np.float32), width
+        ),
+    }[dtype]
+    idx = rng.randint(0, width, size=n).astype(np.int32)
+    idx[:2] = [0, width - 1]
+    got = {
+        impl: np.asarray(node_table_lookup(jnp.asarray(table), jnp.asarray(idx), impl=impl))
+        for impl in ("gather", "select")
+    }
+    assert got["select"].dtype == table.dtype
+    view = np.int32 if dtype == "float32" else table.dtype
+    np.testing.assert_array_equal(got["gather"].view(view), table[idx].view(view))
+    np.testing.assert_array_equal(got["select"].view(view), table[idx].view(view))
+
+
+def test_node_table_lookup_rejects_unknown_lowering():
+    with pytest.raises(ValueError, match="onehot"):
+        node_table_lookup(jnp.zeros(4, jnp.int32), jnp.zeros(4, jnp.int32), impl="onehot")
+
+
+class NoEnviron:
+    def __getattr__(self, name):
+        raise AssertionError("the chooser read the environment")
+
+    __getitem__ = __contains__ = __getattr__
+
+
+@pytest.mark.parametrize(
+    "backend,width,want",
+    [
+        ("tpu", 1, "select"),
+        ("tpu", 128, "select"),
+        ("tpu", NODE_TABLE_SELECT_MAX_WIDTH, "select"),
+        ("tpu", NODE_TABLE_SELECT_MAX_WIDTH + 1, "gather"),
+        ("cpu", 1, "gather"),
+        ("cpu", 128, "gather"),
+        ("gpu", 128, "gather"),
+    ],
+)
+def test_table_chooser_reads_backend_and_width_only(monkeypatch, backend, width, want):
+    with monkeypatch.context() as during_the_call:
+        during_the_call.setattr(os, "environ", NoEnviron())
+        got = choose_table_impl(backend, width)
+    assert got == want
+
+
+def test_session_walks_as_its_grow_policy_says(monkeypatch):
+    """A loss-guided session keeps the pointer traversal, a depth-wise one
+    takes the level walk; the choice reads the policy and nothing else."""
+    from sagemaker_xgboost_container_tpu.data.matrix import DataMatrix
+    from sagemaker_xgboost_container_tpu.models import booster, train
+
+    with monkeypatch.context() as during_the_call:
+        during_the_call.setattr(os, "environ", NoEnviron())
+        assert choose_eval_traversal("lossguide") == "pointer"
+        assert choose_eval_traversal("depthwise") == "level"
+    lossguide = {"grow_policy": "lossguide", "max_leaves": 8, "max_depth": 0}
+    assert booster.TrainConfig(lossguide).eval_traversal == "pointer"
+    assert booster.TrainConfig({"max_depth": 3}).eval_traversal == "level"
+
+    taken = []
+    for name in ("predict_binned", "predict_binned_levels"):
+        def spy(*args, _name=name, _fn=getattr(booster, name), **kwargs):
+            taken.append(_name)
+            return _fn(*args, **kwargs)
+
+        monkeypatch.setattr(booster, name, spy)
+    rng = np.random.RandomState(3)
+    X = rng.rand(500, 4).astype(np.float32)
+    y = (X[:, 0] + X[:, 1] > 1).astype(np.float32)
+    dtrain, dval = DataMatrix(X[:400], labels=y[:400]), DataMatrix(X[400:], labels=y[400:])
+    for params, want in ((lossguide, "predict_binned"), ({"max_depth": 3}, "predict_binned_levels")):
+        del taken[:]
+        train(
+            dict(params, objective="binary:logistic"), dtrain, num_boost_round=2,
+            evals=[(dval, "validation")], verbose_eval=False,
+        )
+        assert set(taken) == {want}
+
+
+def test_train_logs_the_pointer_traversals_validation_metric(monkeypatch):
+    """End to end: ``train()`` with a validation set logs, every round, the
+    metric that the same forest gives those rows under the pointer traversal,
+    to the bit."""
+    from sagemaker_xgboost_container_tpu.data.matrix import DataMatrix
+    from sagemaker_xgboost_container_tpu.models import booster, train
+
+    rng = np.random.RandomState(5)
+    X = rng.rand(3000, 7).astype(np.float32)
+    X[rng.rand(3000, 7) < 0.1] = np.nan
+    y = (np.nan_to_num(X[:, 0]) + np.nan_to_num(X[:, 2]) > 1).astype(np.float32)
+    dtrain = DataMatrix(X[:2400], labels=y[:2400])
+    dval = DataMatrix(X[2400:], labels=y[2400:])
+    params = {
+        "objective": "binary:logistic", "max_depth": 5, "gamma": 0.5,
+        "_rounds_per_dispatch": 2,
+    }
+
+    def run():
+        log = {}
+
+        class Rec:
+            def after_iteration(self, model, epoch, evals_log):
+                log["logloss"] = list(evals_log["validation"]["logloss"])
+                return False
+
+        forest = train(
+            params, dtrain, num_boost_round=4, evals=[(dval, "validation")],
+            callbacks=[Rec()], verbose_eval=False,
+        )
+        return forest, log["logloss"]
+
+    forest, logged = run()
+    assert len(logged) == 4
+    walked = []
+    monkeypatch.setattr(
+        booster, "predict_binned_levels",
+        lambda t, b, depth, num_bins, route_impl=None, table_backend=None: (
+            walked.append("pointer") or predict_binned(t, b, depth, num_bins, route_impl=route_impl)
+        ),
+    )
+    forest_p, logged_p = run()
+    assert walked
+    assert [float(v).hex() for v in logged] == [float(v).hex() for v in logged_p]
+    for a, b in zip(forest.trees, forest_p.trees):
+        np.testing.assert_array_equal(a.feature, b.feature)
+        np.testing.assert_array_equal(a.value, b.value)
+    # and the host's float traversal of the returned forest agrees with it
+    p = np.clip(forest.predict(X[2400:]), 1e-7, 1 - 1e-7)
+    direct = float(-np.mean(y[2400:] * np.log(p) + (1 - y[2400:]) * np.log(1 - p)))
+    assert abs(direct - logged[-1]) < 1e-5
+
+
 @pytest.mark.parametrize(
     "backend,width,want",
     [
@@ -167,12 +409,6 @@ def test_predict_binned_identical_under_each_lowering():
     ],
 )
 def test_chooser_reads_backend_and_width_only(monkeypatch, backend, width, want):
-    class NoEnviron:
-        def __getattr__(self, name):
-            raise AssertionError("the chooser read the environment")
-
-        __getitem__ = __contains__ = __getattr__
-
     with monkeypatch.context() as during_the_call:
         during_the_call.setattr(os, "environ", NoEnviron())
         got = choose_route_impl(backend, width)
@@ -185,18 +421,27 @@ def test_session_snapshot_holds_the_backend():
 
 
 @pytest.mark.parametrize(
-    "backend,width,want", [("tpu", 28, "dense"), ("cpu", 28, "gather"), ("tpu", None, None)]
+    "backend,width,grow_policy,want",
+    [
+        ("tpu", 28, "depthwise", ("dense", "level")),
+        ("cpu", 28, "depthwise", ("gather", "level")),
+        ("tpu", 28, "lossguide", ("dense", "pointer")),
+        ("tpu", None, None, (None, None)),  # a server: no binned rows, no trees built
+    ],
 )
 def test_device_runtime_line_names_the_resolved_lowering(
-    monkeypatch, caplog, backend, width, want
+    monkeypatch, caplog, backend, width, grow_policy, want
 ):
     from sagemaker_xgboost_container_tpu.utils import device_runtime
 
     monkeypatch.setattr(device_runtime, "enable_compile_cache", lambda: None)
     knobs = resolve_hist_knobs()._replace(route_backend=backend)
     with caplog.at_level(logging.INFO, logger=device_runtime.__name__):
-        fields = device_runtime.start_device_runtime("train", knobs=knobs, route_width=width)
-    assert (fields["route_impl"], fields["route_width"]) == (want, width)
+        fields = device_runtime.start_device_runtime(
+            "train", knobs=knobs, route_width=width, grow_policy=grow_policy
+        )
     line = [r.getMessage() for r in caplog.records if "device runtime: " in r.getMessage()][-1]
     logged = json.loads(line.split("device runtime: ", 1)[1])
-    assert (logged["route_impl"], logged["route_width"]) == (want, width)
+    for said in (fields, logged):
+        assert (said["route_impl"], said["eval_traversal"]) == want
+        assert said["route_width"] == width

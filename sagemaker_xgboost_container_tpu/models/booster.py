@@ -25,6 +25,7 @@ import contextlib
 import functools
 import logging
 import os
+import sys
 from functools import partial
 
 import jax
@@ -260,25 +261,25 @@ def _merged_distributed_cuts(dtrain, max_bin, weights=None):
 
 
 def _apply_packed_tree(packed, bins, margins, num_group, num_parallel, depth,
-                       num_bins, route_backend, traversal):
+                       num_bins, backend, traversal):
     """margins += the packed tree's (or tree stack's) outputs on ``bins``.
 
     Runs under trace (the round fn and the session apply fn), so the
     backend that decides the lowering of the bin fetch and of the node-table
-    lookups must arrive as ``route_backend`` — the session's
-    ``hist_knobs.route_backend`` snapshot, never a trace-time read.
+    lookups must arrive as ``backend`` — the session's
+    ``hist_knobs.backend`` snapshot, never a trace-time read.
     ``traversal``: the layout of the trees this session's builder makes
     (``TrainConfig.eval_traversal``): ``level`` walks the depth-wise heap
     level by level, ``pointer`` chases a loss-guided tree's ``left`` /
     ``right``.
     """
-    route_impl = choose_route_impl(route_backend, bins.shape[1])
+    route_impl = choose_route_impl(backend, bins.shape[1])
 
     def one(t):
         if traversal == "level":
             return predict_binned_levels(
                 t, bins, depth, num_bins, route_impl=route_impl,
-                table_backend=route_backend,
+                table_backend=backend,
             )
         return predict_binned(t, bins, depth, num_bins, route_impl=route_impl)
 
@@ -441,7 +442,8 @@ class _TrainingSession:
         # session, new round-fn closure, hence its own jit cache entry)
         # picks up the new value.
         self.hist_comm = hist_comm_impl() if mesh is not None else "psum"
-        # every other histogram/scan/routing knob, snapshotted host-side for
+        # the backend every kernel chooser reads, the histogram's operand
+        # precision and the collective overlap, snapshotted host-side for
         # the same reason (trace-safety: graftlint trace-env-read forbids
         # env reads in the traced build path) and threaded into the builders.
         # Callers may inject a snapshot: an elastic membership reform rebuilds
@@ -1147,7 +1149,7 @@ class _TrainingSession:
         # (and with it every device buffer) alive
         d_pad = self.d_pad
         n_fs = self.n_feature_shards
-        route_backend = self.hist_knobs.route_backend
+        backend = self.hist_knobs.backend
 
         def multi_round(
             bins, margins, labels, weights, num_cuts, rng, feature_mask, monotone,
@@ -1209,7 +1211,7 @@ class _TrainingSession:
                         m_e = _apply_packed_tree(
                             packed, b_e, extra[ei],
                             num_group, num_parallel, predict_depth, num_bins,
-                            route_backend=route_backend, traversal=eval_traversal,
+                            backend=backend, traversal=eval_traversal,
                         )
                         new_extra.append(m_e)
                         ei += 1
@@ -1321,12 +1323,12 @@ class _TrainingSession:
         num_group = self.num_group
         num_parallel = cfg.num_parallel_tree
 
-        route_backend = self.hist_knobs.route_backend
+        backend = self.hist_knobs.backend
 
         def apply_tree(packed, bins, margins):
             return _apply_packed_tree(
                 packed, bins, margins, num_group, num_parallel,
-                cfg.predict_depth, num_bins, route_backend=route_backend,
+                cfg.predict_depth, num_bins, backend=backend,
                 traversal=cfg.eval_traversal,
             )
 
@@ -1366,15 +1368,11 @@ class _TrainingSession:
         if cfg.grow_policy == "lossguide":
             from ..ops.lossguide import _subtraction_enabled
 
-            subtract = _subtraction_enabled(
-                cfg.max_leaves, d_local, num_bins, knobs=self.hist_knobs
-            )
+            subtract = _subtraction_enabled(cfg.max_leaves, d_local, num_bins)
         else:
             from ..ops.tree_build import _subtraction_enabled
 
-            subtract = _subtraction_enabled(
-                cfg.max_depth, d_local, num_bins, knobs=self.hist_knobs
-            )
+            subtract = _subtraction_enabled(cfg.max_depth, d_local, num_bins)
         return round_comm_plan(
             cfg.grow_policy,
             cfg.max_depth,
@@ -1700,11 +1698,18 @@ class _TrainingSession:
         return out
 
     def end_turnaround(self):
-        """Close the open `host_turnaround` span, if any: ``train()`` calls
-        it after the last dispatch, which no `host_dispatch` follows."""
+        """Close the open `host_turnaround` span, if any. Whoever drives
+        ``run_rounds()`` calls it after the last dispatch, which no
+        `host_dispatch` follows (``train()`` does, in a ``finally``)."""
         if self._turnaround is not None:
             end_span(self._turnaround)
             self._turnaround = None
+
+    def __del__(self):
+        # a session dropped between dispatches takes its open span with it
+        # (one whose construction failed has none)
+        if getattr(self, "_turnaround", None) is not None and not sys.is_finalizing():
+            self.end_turnaround()
 
     def run_rounds(self):
         """One device dispatch -> (list of host tree dicts, metrics or None).
@@ -1723,6 +1728,7 @@ class _TrainingSession:
         except Exception as e:
             from ..telemetry import device as device_telemetry
 
+            self.end_turnaround()  # no dispatch follows a failed one
             if device_telemetry.is_oom_error(e):
                 self._abort_device_oom(e)
             raise
@@ -2317,71 +2323,75 @@ def train(
     end_round = start_round + num_boost_round
     rnd = start_round
     stop = False
-    while rnd < end_round and not stop:
-        trees_batch, batch_metrics = session.run_rounds()
-        # what follows, up to the return of the next dispatch, is the
-        # session's `host_turnaround`; its parts are spans of their own
-        for j, tree_np in enumerate(trees_batch):
-            if rnd >= end_round:
-                break  # trees past the requested count are discarded
-            with span("commit", attributes={"round": rnd}):
-                trees, info = _trees_for_round(tree_np)
-                forest.append_round(trees, info)
+    try:
+        while rnd < end_round and not stop:
+            trees_batch, batch_metrics = session.run_rounds()
+            # what follows, up to the return of the next dispatch, is the
+            # session's `host_turnaround`; its parts are spans of their own
+            for j, tree_np in enumerate(trees_batch):
+                if rnd >= end_round:
+                    break  # trees past the requested count are discarded
+                with span("commit", attributes={"round": rnd}):
+                    trees, info = _trees_for_round(tree_np)
+                    forest.append_round(trees, info)
 
-            if j < len(session.last_learning_stats):
-                # model-quality plane: device reductions + committed-tree
-                # stats -> one training.learning record, then the numeric-
-                # health guard (NaN/Inf counters nonzero -> forensics dump
-                # + exit 87 on every rank, naming this round)
-                from ..telemetry import model as model_telemetry
+                if j < len(session.last_learning_stats):
+                    # model-quality plane: device reductions + committed-tree
+                    # stats -> one training.learning record, then the numeric-
+                    # health guard (NaN/Inf counters nonzero -> forensics dump
+                    # + exit 87 on every rank, naming this round)
+                    from ..telemetry import model as model_telemetry
 
-                stats = dict(session.last_learning_stats[j])
-                stats.update(model_telemetry.tree_stats(trees))
-                model_telemetry.note_learning(rnd, stats)
-                if model_telemetry.first_poisoned_round([stats], rnd) is not None:
-                    _abort_numeric_poison(rnd)
+                    stats = dict(session.last_learning_stats[j])
+                    stats.update(model_telemetry.tree_stats(trees))
+                    model_telemetry.note_learning(rnd, stats)
+                    if model_telemetry.first_poisoned_round([stats], rnd) is not None:
+                        _abort_numeric_poison(rnd)
 
-            with span("eval_log", attributes={"round": rnd}):
-                if batch_metrics is not None:
-                    # device-computed per-round metrics: [K, n_sets, n_metrics]
-                    results = [
-                        (name, metric_name, float(batch_metrics[j, si, i]))
-                        for si, (name, _dm, _b) in enumerate(session.eval_sets)
-                        for i, metric_name in enumerate(session.device_metric_names)
-                    ]
-                elif not session.eval_sets:
-                    results = []
-                elif not session.host_eval_batched:
-                    results = session.evaluate(metric_names, feval=feval)
-                elif j == len(trees_batch) - 1:
-                    # host-fallback cadence: the fused K-round dispatch finished
-                    # and the device margins cover exactly the committed trees —
-                    # one host evaluation per dispatch, attributed to the
-                    # batch-end round.
-                    results = session.evaluate(metric_names, feval=feval)
-                elif rnd == end_round - 1:
-                    # final round lands mid-batch (num_boost_round % K != 0):
-                    # the device margins include the over-built, discarded trees
-                    # — evaluate the committed forest so the last metric line
-                    # (the one HPO reads) is exact.
-                    results = session.evaluate(metric_names, feval=feval, forest=forest)
-                else:
-                    results = []  # stale round inside the fused batch
-                for data_name, metric_name, value in results:
-                    evals_log.setdefault(data_name, {}).setdefault(metric_name, []).append(value)
+                with span("eval_log", attributes={"round": rnd}):
+                    if batch_metrics is not None:
+                        # device-computed per-round metrics: [K, n_sets, n_metrics]
+                        results = [
+                            (name, metric_name, float(batch_metrics[j, si, i]))
+                            for si, (name, _dm, _b) in enumerate(session.eval_sets)
+                            for i, metric_name in enumerate(session.device_metric_names)
+                        ]
+                    elif not session.eval_sets:
+                        results = []
+                    elif not session.host_eval_batched:
+                        results = session.evaluate(metric_names, feval=feval)
+                    elif j == len(trees_batch) - 1:
+                        # host-fallback cadence: the fused K-round dispatch finished
+                        # and the device margins cover exactly the committed trees —
+                        # one host evaluation per dispatch, attributed to the
+                        # batch-end round.
+                        results = session.evaluate(metric_names, feval=feval)
+                    elif rnd == end_round - 1:
+                        # final round lands mid-batch (num_boost_round % K != 0):
+                        # the device margins include the over-built, discarded trees
+                        # — evaluate the committed forest so the last metric line
+                        # (the one HPO reads) is exact.
+                        results = session.evaluate(metric_names, feval=feval, forest=forest)
+                    else:
+                        results = []  # stale round inside the fused batch
+                    for data_name, metric_name, value in results:
+                        by_metric = evals_log.setdefault(data_name, {})
+                        by_metric.setdefault(metric_name, []).append(value)
 
-            # covering: checkpoint, eval-monitor and RoundTimer spans lie inside
-            with span("callbacks", covering=True, attributes={"round": rnd}):
-                for cb in callbacks:
-                    if hasattr(cb, "after_iteration") and cb.after_iteration(
-                        forest, rnd, evals_log
-                    ):
-                        stop = True
-            rnd += 1
-            if stop:
-                break
-
-    session.end_turnaround()
+                # covering: checkpoint, eval-monitor and RoundTimer spans lie inside
+                with span("callbacks", covering=True, attributes={"round": rnd}):
+                    for cb in callbacks:
+                        if hasattr(cb, "after_iteration") and cb.after_iteration(
+                            forest, rnd, evals_log
+                        ):
+                            stop = True
+                rnd += 1
+                if stop:
+                    break
+    finally:
+        # also on a callback's or a dispatch's exception: no span of this
+        # job stays open on the caller's thread
+        session.end_turnaround()
 
     for cb in callbacks:
         if hasattr(cb, "after_training"):

@@ -34,7 +34,8 @@ def _host_predict_rows():
     instead of the compiled device kernel (0 disables). Default 32: host
     traversal of a few rows costs microseconds while any device dispatch
     pays a host<->device round trip. The crossover is not measured on this
-    chip (bench_serve.py measures both sides of the cutover)."""
+    chip (chip_smoke.py's serve phase drives both sides of the cutover; a
+    serving cell of benchmark/run.py would measure it: PERF.md section 7)."""
     from ..utils.envconfig import env_int
 
     return env_int("GRAFT_HOST_PREDICT_ROWS", 32)

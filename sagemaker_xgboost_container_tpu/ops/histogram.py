@@ -7,23 +7,22 @@ algorithm_mode/train.py:367-376 -> C++), followed by an optional
 ``lax.psum`` over the data-parallel mesh axis, which is the entire
 multi-host story (SURVEY.md §2.3 row 1).
 
-Four interchangeable implementations (``GRAFT_HIST_IMPL``), A/B-able on
-hardware without code changes:
+Two builders, one a backend (``choose_hist_impl``, as
+``ops/tree_build.choose_route_impl`` picks the bin fetch):
 
-* ``flat`` (default): one ``jax.ops.segment_sum`` over n*d flattened
-  (node, feature, bin) ids. XLA lowers it to a sorted scatter-add —
-  correct everywhere, fast on CPU, scatter-bound on TPU.
-* ``per_feature``: d segment_sums over n with (node, bin) ids — smaller key
-  space per sort, no [n, d] id materialization.
-* ``matmul``: one-hot matmul formulation for the MXU — histograms become
-  [2W, chunk] @ [chunk, B] dots (grad/hess stacked along the node axis),
-  scanned over row chunks. No scatter at all; bandwidth-bound on the
-  materialized bin one-hots.
-* ``pallas``: the matmul formulation as a Pallas TPU kernel — per-block bin
-  one-hots live only in VMEM (never HBM), accumulator resident in VMEM
-  across the row-block grid. Compute-bound; bf16x2 split-precision operands
-  (hi/lo decomposition of f32 grads) keep MXU rate with ~f16-mantissa
-  accuracy, accumulated in f32.
+* ``pallas`` (the TPU): the one-hot matmul as a Pallas TPU kernel — per-block
+  bin one-hots live only in VMEM (never HBM), accumulator resident in VMEM
+  across the row-block grid. bf16x2 split-precision operands (hi/lo
+  decomposition of f32 grads) keep MXU rate with ~f16-mantissa accuracy,
+  accumulated in f32. Interpreted on the CPU backend (tests, rehearsals).
+* ``flat`` (everything else, and the tests' reference): one
+  ``jax.ops.segment_sum`` over n*d flattened (node, feature, bin) ids. XLA
+  lowers it to a sorted scatter-add — correct everywhere, fast on CPU,
+  scatter-bound on TPU (0.265 rounds/s against the kernel's 3.1 at 1M x 28:
+  builders' probe, one v5e, round 2).
+
+and two node-total lowerings (``choose_totals_impl``): ``onehot`` on the TPU,
+``segment`` elsewhere (2.3 against 16.8 ms a call on the chip, same probe).
 """
 
 import collections
@@ -34,111 +33,79 @@ import jax
 import jax.numpy as jnp
 
 from ..telemetry.device import STAGE_HIST_ALLREDUCE, stage
-from ..utils.envconfig import env_int
 
-# Session-build-time snapshot of every histogram/scan/routing tuning knob.
-# Trace-safety contract (graftlint trace-env-read, docs/static-analysis.md):
-# the jitted round path must not read env — the training session resolves
-# one HistKnobs via resolve_hist_knobs() when it builds the round closure
-# (the PR-4 GRAFT_HIST_COMM pattern) and threads it through the builders.
-# The per-knob env fallbacks below remain the documented API for DIRECT
-# callers only (unit tests, bench probes A/B-ing a single kernel).
+# What a training session freezes, host-side, when it builds its round
+# closure (models/booster.py), so that every shard and every re-trace sees
+# the same values for the session's life. Trace-safety contract (graftlint
+# trace-env-read, docs/static-analysis.md): the jitted round path reads no
+# env; the builders take this snapshot as ``knobs``.
 HistKnobs = collections.namedtuple(
     "HistKnobs",
     [
-        "impl",          # GRAFT_HIST_IMPL (backend-aware default)
-        "totals_impl",   # GRAFT_TOTALS_IMPL (backend-aware default)
-        "route_backend", # jax.default_backend() (ops/tree_build.choose_route_impl)
-        "matmul_chunk",  # GRAFT_HIST_CHUNK
-        "pallas_block",  # GRAFT_HIST_BLOCK
+        "backend",       # jax.default_backend(): what the four choosers read
         "precision",     # GRAFT_HIST_MM_PREC
-        "align",         # GRAFT_HIST_ALIGN
-        "vnodes",        # GRAFT_HIST_VNODES
-        "vnode_vmem",    # GRAFT_VNODE_VMEM
-        "subtract",      # GRAFT_HIST_SUBTRACT
-        "subtract_mem",  # GRAFT_SUBTRACT_MEM
         "comm_overlap",  # GRAFT_HIST_OVERLAP
     ],
 )
 
+# operand precision of the Pallas histogram: ``bf16x2`` (hi + lo bf16 pair,
+# two MXU passes) is the program; ``bf16`` (one pass) is the failing control
+# that proves a benchmark configuration's check_limits (PERF.md section 2)
+HIST_PRECISIONS = ("bf16x2", "bf16")
+
 
 def resolve_hist_knobs():
-    """Resolve every histogram-path knob from env ONCE, host-side.
+    """The session's :class:`HistKnobs`, resolved ONCE, host-side.
 
     Call at session build time (models/booster.py), never from code that
-    can run under trace: the snapshot is what keeps every shard — and
-    every re-trace — seeing identical knob values for the session's life.
+    can run under trace.
     """
+    precision = os.environ.get("GRAFT_HIST_MM_PREC", HIST_PRECISIONS[0])
+    if precision not in HIST_PRECISIONS:
+        raise ValueError(
+            "Unknown GRAFT_HIST_MM_PREC=%r; expected %s"
+            % (precision, "|".join(HIST_PRECISIONS))
+        )
     return HistKnobs(
-        impl=_impl(),
-        totals_impl=_totals_impl(),
-        route_backend=jax.default_backend(),
-        matmul_chunk=_matmul_chunk(),
-        pallas_block=_pallas_block(),
-        precision=_matmul_precision(),
-        align=os.environ.get("GRAFT_HIST_ALIGN", "1") == "1",
-        vnodes=os.environ.get("GRAFT_HIST_VNODES", "1") == "1",
-        vnode_vmem=env_int("GRAFT_VNODE_VMEM", 4 * 1024 * 1024, minimum=0),
-        subtract=os.environ.get("GRAFT_HIST_SUBTRACT", "1") == "1",
-        subtract_mem=env_int("GRAFT_SUBTRACT_MEM", 512 * 1024 * 1024, minimum=0),
+        backend=jax.default_backend(),
+        precision=precision,
         comm_overlap=_comm_overlap(),
     )
 
 
-def _impl():
-    """Backend-aware default: the pallas one-hot matmul kernel on TPU
-    (pallas 3.15 r/s vs flat 0.265 on the bench config — builders'
-    self-report, one v5e, round 2, not the driver's); the flat segment-sum
-    wins on CPU. GRAFT_HIST_IMPL overrides either way."""
-    # graftlint: disable=trace-env-read — direct-caller fallback only;
-    # sessions snapshot this via resolve_hist_knobs() at build time
-    v = os.environ.get("GRAFT_HIST_IMPL")
-    if v:
-        return v
-    return "pallas" if jax.default_backend() == "tpu" else "flat"
+def _backend(knobs):
+    """The backend the choosers read: the session snapshot's, or the
+    process's for direct callers (unit tests, probes) with no snapshot."""
+    return knobs.backend if knobs is not None else jax.default_backend()
 
 
-def _totals_impl():
-    """Backend-aware GRAFT_TOTALS_IMPL default (see node_totals)."""
-    # graftlint: disable=trace-env-read — direct-caller fallback only;
-    # sessions snapshot this via resolve_hist_knobs() at build time
-    impl = os.environ.get("GRAFT_TOTALS_IMPL")
-    if not impl:
-        impl = "onehot" if jax.default_backend() == "tpu" else "segment"
-    return impl
+def choose_hist_impl(backend):
+    """The builder ``level_histogram`` takes on ``backend``: the Pallas
+    one-hot matmul kernel where scatters serialize (the TPU), the flat
+    segment-sum elsewhere."""
+    return "pallas" if backend == "tpu" else "flat"
 
 
-def _matmul_chunk():
-    # graftlint: disable=trace-env-read — direct-caller fallback only;
-    # sessions snapshot this via resolve_hist_knobs() at build time
-    return env_int("GRAFT_HIST_CHUNK", 65536, minimum=1)
+def choose_totals_impl(backend):
+    """The lowering ``node_totals`` takes on ``backend``, chosen as
+    ``choose_hist_impl`` chooses: no sort on the TPU."""
+    return "onehot" if backend == "tpu" else "segment"
 
 
-def _balanced_chunks(n, chunk_rows=None):
-    """(chunk, steps) for scanning n rows in ~GRAFT_HIST_CHUNK-row chunks.
+# rows a step of the node-totals scan (_totals_onehot), before balancing
+TOTALS_CHUNK_ROWS = 65536
+
+
+def _balanced_chunks(n, chunk_rows):
+    """(chunk, steps) for scanning n rows in ~chunk_rows-row chunks.
 
     Balanced: caps padding waste at steps-1 rows instead of a nearly full
     chunk when n slightly exceeds a multiple of the configured size.
     Requires n >= 1.
     """
-    if chunk_rows is None:
-        chunk_rows = _matmul_chunk()
     steps_wanted = -(-n // min(chunk_rows, n))
     chunk = -(-n // steps_wanted)
     return chunk, -(-n // chunk)
-
-
-def _pallas_block():
-    # graftlint: disable=trace-env-read — direct-caller fallback only;
-    # sessions snapshot this via resolve_hist_knobs() at build time
-    return env_int("GRAFT_HIST_BLOCK", 512, minimum=1)
-
-
-def _matmul_precision():
-    """f32 | bf16x2 | bf16 for matmul/pallas operand precision."""
-    # graftlint: disable=trace-env-read — direct-caller fallback only;
-    # sessions snapshot this via resolve_hist_knobs() at build time
-    return os.environ.get("GRAFT_HIST_MM_PREC", "bf16x2")
 
 
 def _comm_overlap():
@@ -344,21 +311,15 @@ def round_comm_plan(
     return entries, int(total_bytes)
 
 
-def subtraction_enabled(cache_bytes, knobs=None):
+# most bytes of level histograms a builder keeps alive for sibling subtraction
+SUBTRACT_CACHE_MAX_BYTES = 512 * 1024 * 1024
+
+
+def subtraction_enabled(cache_bytes):
     """Shared gate for sibling-subtraction paths (both growers): the
-    GRAFT_HIST_SUBTRACT kill-switch plus a memory cap on the histogram cache
-    the caller would have to keep alive (GRAFT_SUBTRACT_MEM, default 512MB).
-    ``knobs``: the session's :class:`HistKnobs` (env fallback for direct
-    callers)."""
-    if knobs is not None:
-        return knobs.subtract and cache_bytes <= knobs.subtract_mem
-    # graftlint: disable=trace-env-read — direct-caller fallback only;
-    # sessions snapshot these via resolve_hist_knobs() at build time
-    if os.environ.get("GRAFT_HIST_SUBTRACT", "1") != "1":
-        return False
-    # graftlint: disable=trace-env-read — direct-caller fallback only
-    cap = env_int("GRAFT_SUBTRACT_MEM", 512 * 1024 * 1024, minimum=0)
-    return cache_bytes <= cap
+    histogram cache the caller would have to keep alive fits under
+    SUBTRACT_CACHE_MAX_BYTES. Over it, both children are built directly."""
+    return cache_bytes <= SUBTRACT_CACHE_MAX_BYTES
 
 
 def level_histogram(
@@ -372,6 +333,7 @@ def level_histogram(
     comm="psum",
     axis_size=1,
     knobs=None,
+    impl=None,
 ):
     """Build (G, H) histograms for one tree level.
 
@@ -387,61 +349,53 @@ def level_histogram(
         full histograms; "reduce_scatter" psum_scatters them along the
         feature dim so each shard gets only its d/axis_size column slice.
       axis_size: static size of ``axis_name`` (required for reduce_scatter).
-      knobs: the session's :class:`HistKnobs` snapshot. None falls back to
-        per-knob env reads — direct unit-test/bench callers only; traced
-        production code must thread the session snapshot (trace-safety).
+      knobs: the session's :class:`HistKnobs` snapshot; traced production
+        code must thread it (trace-safety). None, for direct callers (unit
+        tests, probes): the process's backend and ``bf16x2``.
+      impl: a builder by name (``flat`` | ``pallas``), for direct callers;
+        None chooses from the backend through ``choose_hist_impl``.
 
     Returns:
       (G, H): f32 [num_nodes, d, num_bins] for psum / no axis;
       f32 [num_nodes, padded_d/axis_size, num_bins] for reduce_scatter.
     """
-    impl = knobs.impl if knobs is not None else _impl()
-    if impl == "per_feature":
-        G, H = _hist_per_feature(bins, grad, hess, node_local, num_nodes, num_bins)
-    elif impl == "matmul":
-        G, H = _hist_matmul(bins, grad, hess, node_local, num_nodes, num_bins,
-                            knobs=knobs)
-    elif impl == "pallas":
-        G, H = _hist_pallas(bins, grad, hess, node_local, num_nodes, num_bins,
-                            knobs=knobs)
+    if impl is None:
+        impl = choose_hist_impl(_backend(knobs))
+    if impl == "pallas":
+        G, H = _hist_pallas(
+            bins, grad, hess, node_local, num_nodes, num_bins,
+            prec=knobs.precision if knobs is not None else HIST_PRECISIONS[0],
+        )
     elif impl == "flat":
         G, H = _hist_flat(bins, grad, hess, node_local, num_nodes, num_bins)
     else:
         raise ValueError(
-            "Unknown GRAFT_HIST_IMPL=%r; expected flat|per_feature|matmul|pallas"
-            % impl
+            "unknown level_histogram builder: {!r}; expected flat|pallas".format(impl)
         )
     return apply_hist_collective(G, H, axis_name, comm, axis_size)
 
 
-def node_totals(grad, hess, node_local, num_nodes, axis_name=None, knobs=None):
+def node_totals(grad, hess, node_local, num_nodes, axis_name=None, knobs=None,
+                impl=None):
     """Per-node (sum g, sum h) without the full histogram.
 
     The last tree level only needs leaf weights -> node totals; skipping the
     [W, d, B] histogram there removes the widest (most expensive) level from
     every tree build.
 
-    Three lowerings via ``GRAFT_TOTALS_IMPL``: ``segment`` uses segment_sum
-    (a sorted scatter-add on TPU — sorts all n rows by node id; fast on
-    CPU); ``onehot`` scans row chunks and contracts a node one-hot on the
-    MXU, avoiding the sort entirely (same trick as the matmul histograms);
-    ``pallas`` is the VMEM-resident VPU reduction. Default is backend-aware
-    like ``_impl``: scatter lowerings are the measured pathology on TPU
-    (flat-vs-pallas histograms: 12x), so TPU defaults to ``onehot`` and
-    everything else to ``segment`` — the env var overrides either way and
-    the bench probe battery A/Bs all three. ``knobs``: the session's
-    :class:`HistKnobs` (env fallback for direct callers).
+    Two lowerings: ``segment`` uses segment_sum (a sorted scatter-add on TPU
+    — sorts all n rows by node id; fast on CPU); ``onehot`` scans row chunks
+    and contracts a node one-hot on the MXU, avoiding the sort entirely.
+    ``knobs`` and ``impl`` as for :func:`level_histogram`: None chooses from
+    the backend through ``choose_totals_impl``.
     """
-    impl = knobs.totals_impl if knobs is not None else _totals_impl()
+    if impl is None:
+        impl = choose_totals_impl(_backend(knobs))
     if impl == "onehot":
-        g_tot, h_tot = _totals_onehot(grad, hess, node_local, num_nodes,
-                                      knobs=knobs)
-    elif impl == "pallas":
-        g_tot, h_tot = _totals_pallas(grad, hess, node_local, num_nodes,
-                                      knobs=knobs)
+        g_tot, h_tot = _totals_onehot(grad, hess, node_local, num_nodes)
     elif impl != "segment":
         raise ValueError(
-            "Unknown GRAFT_TOTALS_IMPL=%r; expected segment|onehot|pallas" % impl
+            "unknown node_totals lowering: {!r}; expected segment|onehot".format(impl)
         )
     else:
         active = node_local >= 0
@@ -458,7 +412,7 @@ def node_totals(grad, hess, node_local, num_nodes, axis_name=None, knobs=None):
     return g_tot, h_tot
 
 
-def _totals_onehot(grad, hess, node_local, num_nodes, knobs=None):
+def _totals_onehot(grad, hess, node_local, num_nodes):
     """[2, c] @ node-one-hot[c, W] per row chunk, f32 accumulated — no sort,
     no scatter; the one-hot never leaves registers/VMEM after fusion."""
     n = grad.shape[0]
@@ -471,9 +425,7 @@ def _totals_onehot(grad, hess, node_local, num_nodes, knobs=None):
     h = jnp.where(active, hess, 0.0)
     node = jnp.where(active, node_local, W)  # dead slot -> one-hot 0
 
-    chunk, steps = _balanced_chunks(
-        n, knobs.matmul_chunk if knobs is not None else None
-    )
+    chunk, steps = _balanced_chunks(n, TOTALS_CHUNK_ROWS)
     n_pad = steps * chunk
     if n_pad != n:
         pad = [(0, n_pad - n)]
@@ -508,70 +460,6 @@ def _totals_onehot(grad, hess, node_local, num_nodes, knobs=None):
     return GH[0], GH[1]
 
 
-@functools.lru_cache(maxsize=None)
-def _totals_pallas_fn(n, W, block, interpret):
-    """Pallas node-totals: per block, one-hot-scale (g|h) into [blk, 2W] and
-    row-sum into a VMEM [1, 2W] accumulator — pure VPU reduction, no sort
-    (segment_sum) and no matmul (the [2, c] @ [c, W] onehot dot pads M=2 to
-    a 128 tile). The last tree level runs this over every row."""
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    def kernel(gh_ref, node_ref, out_ref):
-        step = pl.program_id(0)
-
-        @pl.when(step == 0)
-        def _():
-            out_ref[:] = jnp.zeros_like(out_ref)
-
-        node = node_ref[:, 0]
-        onehot = (node[:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (block, W), 1)).astype(jnp.float32)
-        g = gh_ref[:, 0]
-        h = gh_ref[:, 1]
-        A = jnp.concatenate([onehot * g[:, None], onehot * h[:, None]], axis=1)
-        out_ref[:] += jnp.sum(A, axis=0, keepdims=True)
-
-    steps = n // block
-    in_space = {} if interpret else dict(memory_space=pltpu.VMEM)
-    return pl.pallas_call(
-        kernel,
-        grid=(steps,),
-        in_specs=[
-            pl.BlockSpec((block, 2), lambda i: (i, 0), **in_space),
-            pl.BlockSpec((block, 1), lambda i: (i, 0), **in_space),
-        ],
-        out_specs=pl.BlockSpec((1, 2 * W), lambda i: (0, 0), **in_space),
-        out_shape=jax.ShapeDtypeStruct((1, 2 * W), jnp.float32),
-        interpret=interpret,
-    )
-
-
-def _totals_pallas(grad, hess, node_local, num_nodes, knobs=None):
-    n = grad.shape[0]
-    W = num_nodes
-    if n == 0:
-        z = jnp.zeros(W, jnp.float32)
-        return z, z
-    block = knobs.pallas_block if knobs is not None else _pallas_block()
-    interpret = pallas_interpret()
-    active = node_local >= 0
-    g = jnp.where(active, grad, 0.0)
-    h = jnp.where(active, hess, 0.0)
-    node = jnp.where(active, node_local, jnp.int32(W))
-    n_pad = -(-n // block) * block
-    if n_pad != n:
-        pad = [(0, n_pad - n)]
-        g = jnp.pad(g, pad)
-        h = jnp.pad(h, pad)
-        node = jnp.pad(node, pad, constant_values=W)
-    gh = jnp.stack([g, h], axis=1)
-    out = _totals_pallas_fn(n_pad, W, block, interpret)(
-        gh, node[:, None].astype(jnp.int32)
-    )[0]
-    return out[:W], out[W:]
-
-
 # --------------------------------------------------------------------- flat
 
 
@@ -596,27 +484,7 @@ def _hist_flat(bins, grad, hess, node_local, num_nodes, num_bins):
     return G, H
 
 
-# -------------------------------------------------------------- per_feature
-
-
-def _hist_per_feature(bins, grad, hess, node_local, num_nodes, num_bins):
-    n, d = bins.shape
-    active = node_local >= 0
-    safe_node = jnp.where(active, node_local, num_nodes)
-    seg_base = safe_node * num_bins            # [n]
-    trash = num_nodes * num_bins
-    num_segments = trash + 1
-    Gs, Hs = [], []
-    for f in range(d):
-        seg_f = jnp.where(active, seg_base + bins[:, f], trash)
-        Gs.append(jax.ops.segment_sum(grad, seg_f, num_segments=num_segments)[:-1])
-        Hs.append(jax.ops.segment_sum(hess, seg_f, num_segments=num_segments)[:-1])
-    G = jnp.stack(Gs, axis=1).reshape(num_nodes, num_bins, d).transpose(0, 2, 1)
-    H = jnp.stack(Hs, axis=1).reshape(num_nodes, num_bins, d).transpose(0, 2, 1)
-    return G, H
-
-
-# ------------------------------------------------------------------- matmul
+# ------------------------------------------------------------------- pallas
 
 
 def _split_bf16(x):
@@ -626,156 +494,37 @@ def _split_bf16(x):
     return hi, lo
 
 
-def _mxu_split_missing(B, knobs=None):
+def _mxu_split_missing(B):
     """When B = k*128 + 1 (the usual max_bin=256 -> 257 with the missing bin
     last), the one-hot dot's N dimension pads to the next lane multiple
     (257 -> 384 on the MXU, +50% wasted FLOPs). Splitting the missing column
     out — one [2W, d] dot over the (bins == B-1) mask — keeps the per-feature
-    dots at an exact lane multiple. GRAFT_HIST_ALIGN=0 disables."""
-    if knobs is not None:
-        align = knobs.align
-    else:
-        # graftlint: disable=trace-env-read — direct-caller fallback only;
-        # sessions snapshot this via resolve_hist_knobs() at build time
-        align = os.environ.get("GRAFT_HIST_ALIGN", "1") == "1"
-    if not align:
-        return False
+    dots at an exact lane multiple."""
     return B > 128 and (B - 1) % 128 == 0
 
 
-def _dot_prec(A, Ob32, prec):
-    """dot_general(A^T, Ob) with GRAFT_HIST_MM_PREC operand handling,
-    f32 accumulation. A [c, M] f32; Ob32 [c, N] f32 -> [M, N] f32."""
-    if prec == "f32":
-        return jax.lax.dot_general(
-            A, Ob32, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-    if prec == "bf16":
-        return jax.lax.dot_general(
-            A.astype(jnp.bfloat16),
-            Ob32.astype(jnp.bfloat16),
-            (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-    Ob = Ob32.astype(jnp.bfloat16)
-    hi, lo = _split_bf16(A)
-    return jax.lax.dot_general(
-        hi, Ob, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    ) + jax.lax.dot_general(
-        lo, Ob, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+# rows a grid step of the Pallas histogram (on the lane axis: whole 128-lane
+# tiles)
+PALLAS_ROW_BLOCK = 512
+
+# most VMEM the virtual-node-packed accumulator of one feature group may take
+VNODE_VMEM_BYTES = 4 * 1024 * 1024
 
 
-def _hist_matmul(bins, grad, hess, node_local, num_nodes, num_bins, knobs=None):
-    """One-hot matmul histogram, scanned over row chunks.
-
-    Per chunk: A[c, 2W] = node-one-hot * (grad | hess); per feature,
-    P[2W, B] = A^T @ bin-one-hot[c, B]; accumulate into [2W, d, B] f32.
-    The MXU does the binning — no scatter anywhere. Virtual-node packing
-    (see _vnode_factor) fills the M tile at shallow levels exactly as in
-    the pallas kernel.
-    """
-    n, d = bins.shape
-    W = num_nodes
-    B = num_bins
-    prec = knobs.precision if knobs is not None else _matmul_precision()
-    if n == 0:
-        z = jnp.zeros((W, d, B), jnp.float32)
-        return z, z
-
-    # chunk rows needn't divide v here (sub-group = row index mod v), so
-    # pass a block any power-of-two v divides — NOT 1, which would force
-    # the divisibility loop to grind v down to 1 and disable the packing
-    v = _vnode_factor(W, 128, d, B, knobs=knobs)
-    Wv = W * v
-    active = node_local >= 0
-    g = jnp.where(active, grad, 0.0)
-    h = jnp.where(active, hess, 0.0)
-    node = jnp.where(active, node_local, Wv)  # dead slot, one-hot -> 0
-    if v > 1:
-        s = (jnp.arange(n, dtype=jnp.int32) % v) * W
-        node = jnp.where(node >= Wv, Wv, node + s)
-
-    chunk, steps = _balanced_chunks(
-        n, knobs.matmul_chunk if knobs is not None else None
-    )
-    n_pad = steps * chunk
-    if n_pad != n:
-        pad = [(0, n_pad - n)]
-        g = jnp.pad(g, pad)
-        h = jnp.pad(h, pad)
-        node = jnp.pad(node, pad, constant_values=Wv)
-        bins = jnp.pad(bins, pad + [(0, 0)])
-
-    split_missing = _mxu_split_missing(B, knobs=knobs)
-    Bm = B - 1 if split_missing else B
-    iota_w = jnp.arange(Wv, dtype=jnp.int32)
-    iota_b = jnp.arange(Bm, dtype=jnp.int32)
-
-    def body(carry, i):
-        GH = carry
-        sl = i * chunk
-        node_c = jax.lax.dynamic_slice(node, (sl,), (chunk,))
-        g_c = jax.lax.dynamic_slice(g, (sl,), (chunk,))
-        h_c = jax.lax.dynamic_slice(h, (sl,), (chunk,))
-        bins_c = jax.lax.dynamic_slice(bins, (sl, 0), (chunk, d))
-        onehot_w = (node_c[:, None] == iota_w[None, :]).astype(jnp.float32)
-        A = jnp.concatenate(
-            [onehot_w * g_c[:, None], onehot_w * h_c[:, None]], axis=1
-        )  # [c, 2*Wv]
-        per_f = []
-        for f in range(d):
-            Ob32 = (bins_c[:, f][:, None] == iota_b[None, :]).astype(jnp.float32)
-            per_f.append(_dot_prec(A, Ob32, prec))
-        delta = jnp.stack(per_f, axis=1)  # [2*Wv, d, Bm]
-        if split_missing:
-            miss = (bins_c == (B - 1)).astype(jnp.float32)  # [c, d]
-            Pm = _dot_prec(A, miss, prec)  # [2*Wv, d]
-            delta = jnp.concatenate([delta, Pm[:, :, None]], axis=2)
-        GH = GH + delta
-        return GH, None
-
-    init = jnp.zeros((2 * Wv, d, B), jnp.float32)
-    if steps == 1:
-        GH, _ = body(init, jnp.int32(0))
-    else:
-        GH, _ = jax.lax.scan(body, init, jnp.arange(steps, dtype=jnp.int32))
-    if v > 1:
-        G = GH[:Wv].reshape(v, W, d, B).sum(axis=0)
-        H = GH[Wv:].reshape(v, W, d, B).sum(axis=0)
-        return G, H
-    return GH[:W], GH[W:]
-
-
-# ------------------------------------------------------------------- pallas
-
-
-def _vnode_factor(W, block, d, B, knobs=None):
+def _vnode_factor(W, block, d, B):
     """Virtual-node packing factor: the MXU processes M in 128-row tiles, so
     a [blk, 2W] @ [blk, B] dot with 2W < 128 pads M and wastes (128/2W)x the
     FLOPs — the histogram cost of a SHALLOW level would match the deepest
     level's. Packing v = 128//(2W) row sub-groups as disjoint virtual node
     ranges fills the tile with real work; the v partial histograms sum after
-    the grid. Exact (pure reassociation of the sum). GRAFT_HIST_VNODES=0
-    disables for A/B.
+    the grid. Exact (pure reassociation of the sum).
 
     The VMEM accumulator grows to [2*W*v, d, B] f32, so v is also capped by
-    GRAFT_VNODE_VMEM (default 4MB) — shallow levels of WIDE matrices must
-    not allocate more VMEM than the deepest level the kernel already
-    handles."""
-    if knobs is not None:
-        if not knobs.vnodes:
-            return 1
-        budget = knobs.vnode_vmem
-    else:
-        # graftlint: disable=trace-env-read — direct-caller fallback only;
-        # sessions snapshot these via resolve_hist_knobs() at build time
-        if os.environ.get("GRAFT_HIST_VNODES", "1") != "1":
-            return 1
-        # graftlint: disable=trace-env-read — direct-caller fallback only
-        budget = env_int("GRAFT_VNODE_VMEM", 4 * 1024 * 1024, minimum=0)
+    VNODE_VMEM_BYTES — shallow levels of WIDE matrices must not allocate
+    more VMEM than the deepest level the kernel already handles; a level
+    over the budget packs nothing (v = 1)."""
     v = max(1, 128 // (2 * W))
-    v = min(v, max(1, budget // (2 * W * d * B * 4)))
+    v = min(v, max(1, VNODE_VMEM_BYTES // (2 * W * d * B * 4)))
     while block % v or v & (v - 1):  # equal sub-groups; power of two
         v -= 1
     return max(1, v)
@@ -859,10 +608,8 @@ def _pallas_hist_fn(n, d_pad, fg, W, B, block, prec, interpret, split_missing, v
         )  # [2*M, blk]
         if prec == "bf16x2":
             parts = _split_bf16(A)
-        elif prec == "bf16":
+        else:  # "bf16": one pass, the failing control
             parts = (A.astype(jnp.bfloat16),)
-        else:
-            parts = (A,)
         op_dtype = parts[0].dtype
         lanes = (((1,), (1,)), ((), ()))               # contract rows
 
@@ -916,7 +663,10 @@ def _pallas_hist_fn(n, d_pad, fg, W, B, block, prec, interpret, split_missing, v
     )
 
 
-def _hist_pallas(bins, grad, hess, node_local, num_nodes, num_bins, knobs=None):
+def _hist_pallas(bins, grad, hess, node_local, num_nodes, num_bins,
+                 prec=HIST_PRECISIONS[0]):
+    if prec not in HIST_PRECISIONS:
+        raise ValueError("unknown histogram precision: {!r}".format(prec))
     n, d = bins.shape
     W = num_nodes
     B = num_bins
@@ -925,11 +675,7 @@ def _hist_pallas(bins, grad, hess, node_local, num_nodes, num_bins, knobs=None):
         # kernel would return an uninitialized buffer
         zeros = jnp.zeros((W, d, B), jnp.float32)
         return zeros, zeros
-    # row blocks sit on the lane axis: whole 128-lane tiles only
-    block = _round_up(
-        knobs.pallas_block if knobs is not None else _pallas_block(), 128
-    )
-    prec = knobs.precision if knobs is not None else _matmul_precision()
+    block = PALLAS_ROW_BLOCK
 
     active = node_local >= 0
     g = jnp.where(active, grad, 0.0)
@@ -945,9 +691,9 @@ def _hist_pallas(bins, grad, hess, node_local, num_nodes, num_bins, knobs=None):
     gh = jnp.pad(jnp.stack([g, h]), [(0, 0), (0, n_pad - n)])
     node = jnp.pad(node, [(0, n_pad - n)], constant_values=W)
 
-    split_missing = _mxu_split_missing(B, knobs=knobs)
+    split_missing = _mxu_split_missing(B)
     # the VMEM-resident accumulator spans one feature group, not all of d
-    v = _vnode_factor(W, block, fg, B, knobs=knobs)
+    v = _vnode_factor(W, block, fg, B)
     fn = _pallas_hist_fn(
         n_pad, d_pad, fg, W, B, block, prec, pallas_interpret(), split_missing, v
     )

@@ -41,7 +41,7 @@ from .split import (
 MIN_SPLIT_LOSS = 1e-6
 
 
-def _subtraction_enabled(max_leaves, d_hist, num_bins, knobs=None):
+def _subtraction_enabled(max_leaves, d_hist, num_bins):
     """Sibling subtraction for leaf-wise growth: every split step histograms
     only the LEFT fresh child (W=1 scan over rows) and derives the right one
     from the parent's cached histogram — halving per-step histogram work.
@@ -50,9 +50,7 @@ def _subtraction_enabled(max_leaves, d_hist, num_bins, knobs=None):
     GRAFT_HIST_COMM lowering (same-decision-both-lowerings bit-identity
     contract — see ops.tree_build._subtraction_enabled); under
     reduce_scatter the resident cache is only the d/axis_size slice."""
-    return subtraction_enabled(
-        2 * (2 * max_leaves - 1) * d_hist * num_bins * 4, knobs=knobs
-    )
+    return subtraction_enabled(2 * (2 * max_leaves - 1) * d_hist * num_bins * 4)
 
 
 def build_tree_lossguide(
@@ -90,8 +88,8 @@ def build_tree_lossguide(
     data-axis collective (see ops.tree_build.build_tree): reduce_scatter
     scans only this shard's feature slice per step and merges winners into
     the candidate store with bit-identical tie-breaking. ``knobs``: the
-    session's ``ops.histogram.HistKnobs`` snapshot (trace-safety; None
-    falls back to env reads for direct unit-test/bench callers).
+    session's ``ops.histogram.HistKnobs`` snapshot (trace-safety; None,
+    for direct unit-test/probe callers, chooses from the process's backend).
     """
     n, d = bins.shape
     max_nodes = 2 * max_leaves - 1
@@ -277,7 +275,7 @@ def build_tree_lossguide(
         return splits, gains
 
     # full-width gate under both lowerings (bit-identity: same build path)
-    subtract = _subtraction_enabled(max_leaves, d, num_bins, knobs=knobs)
+    subtract = _subtraction_enabled(max_leaves, d, num_bins)
     if subtract:
         # per-node histogram cache (filled as leaves are created); stores
         # only this shard's feature slice under reduce_scatter

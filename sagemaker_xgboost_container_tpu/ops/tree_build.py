@@ -90,7 +90,7 @@ def row_bin_lookup(bins, feat_idx, impl=None):
 
     Used by level routing here and by binned eval prediction. ``impl``: a
     lowering by name (traced callers resolve it from the session's
-    ``HistKnobs.route_backend`` through ``choose_route_impl``); None chooses
+    ``HistKnobs.backend`` through ``choose_route_impl``); None chooses
     from the process's backend, for direct callers only.
     """
     if impl is None:
@@ -180,7 +180,7 @@ def max_nodes_for_depth(max_depth):
     return 2 ** (max_depth + 1) - 1
 
 
-def _subtraction_enabled(max_depth, d_hist, num_bins, knobs=None):
+def _subtraction_enabled(max_depth, d_hist, num_bins):
     """Histogram subtraction: build only left children, derive right ones as
     parent - left (libxgboost's standard sibling trick) — halves histogram
     work per level. Needs the previous level's histograms cached
@@ -192,9 +192,7 @@ def _subtraction_enabled(max_depth, d_hist, num_bins, knobs=None):
     slice (1/axis_size of this estimate)."""
     if max_depth < 2:
         return False
-    return subtraction_enabled(
-        2 * (2 ** (max_depth - 1)) * d_hist * num_bins * 4, knobs=knobs
-    )
+    return subtraction_enabled(2 * (2 ** (max_depth - 1)) * d_hist * num_bins * 4)
 
 
 def build_tree(
@@ -255,8 +253,8 @@ def build_tree(
     the same mesh, so committed trees match bitwise.
 
     knobs: the session's ``ops.histogram.HistKnobs`` snapshot (trace-safety:
-    the traced build must not read env; None falls back to per-knob env
-    reads for direct unit-test/bench callers).
+    the traced build must not read env; None, for direct unit-test/probe
+    callers, chooses every lowering from the process's backend).
     """
     n, d = bins.shape
     reduce_scatter = hist_comm == "reduce_scatter" and axis_name is not None
@@ -269,7 +267,7 @@ def build_tree(
     data_shard = jax.lax.axis_index(axis_name) if reduce_scatter else None
     max_nodes = max_nodes_for_depth(max_depth)
     route_impl = (
-        choose_route_impl(knobs.route_backend, d) if knobs is not None else None
+        choose_route_impl(knobs.backend, d) if knobs is not None else None
     )
     # bins stay in their storage dtype (u8/u16 from binning) end to end:
     # every consumer widens inside a fused op, so no [n, d] i32 copy is ever
@@ -310,7 +308,7 @@ def build_tree(
     # commit bitwise-divergent trees in the (cap/p, cap] window, breaking
     # the bit-identity contract. The resident cache under reduce_scatter is
     # still only the [W/2, d_scan, B] slice (1/p of the gate's estimate).
-    subtract = _subtraction_enabled(max_depth, d, num_bins, knobs=knobs)
+    subtract = _subtraction_enabled(max_depth, d, num_bins)
     G_cache = H_cache = None      # previous level's [W/2, d_scan, B] histograms
     parent_leaf = None            # previous level's becomes_leaf [W/2]
 
@@ -640,7 +638,7 @@ def predict_binned(tree, bins, max_depth, num_bins, route_impl=None):
     validation-set evaluation during training (validation is binned with the
     training cuts, so bin comparison == float comparison). ``route_impl``:
     ``row_bin_lookup``'s lowering — traced callers resolve it from the
-    session's ``HistKnobs.route_backend`` (trace-safety; None chooses from
+    session's ``HistKnobs.backend`` (trace-safety; None chooses from
     the process's backend, for direct callers only).
     """
     n = bins.shape[0]
@@ -681,7 +679,7 @@ def predict_binned_levels(tree, bins, max_depth, num_bins, route_impl=None,
     one a level: 15.6 against 18.3 ms over 2.2M rows; PERF.md section 5).
     ``table_backend`` chooses each ``node_table_lookup``'s lowering through
     ``choose_table_impl`` (traced callers pass the session's
-    ``HistKnobs.route_backend``; None reads the process's backend, for direct
+    ``HistKnobs.backend``; None reads the process's backend, for direct
     callers only).
     """
     if table_backend is None:

@@ -347,7 +347,7 @@ class ServingLifecycle:
 
     One instance per server process, installed via :func:`install`; the
     WSGI apps and middleware consult it through the module-level helpers so
-    code paths without a server (unit tests, bench legs) behave exactly as
+    code paths without a server (unit tests, library callers) behave exactly as
     before.
     """
 
@@ -514,7 +514,7 @@ class ServingLifecycle:
             self.watchdog.unregister(name)
 
     def shutdown(self):
-        """Stop owned threads (tests / bench churn teardown)."""
+        """Stop owned threads (tests, restart drills)."""
         if self.watchdog is not None:
             self.watchdog.stop()
 
@@ -538,7 +538,7 @@ def install(lifecycle):
 
 
 def uninstall():
-    """Clear the active lifecycle (tests / bench churn)."""
+    """Clear the active lifecycle (tests, restart drills)."""
     global _current
     from ..telemetry import wsgi as telemetry_wsgi
 

@@ -200,9 +200,8 @@ def drain_and_shutdown(httpd, lifecycle, reporter=None):
     signal handler; daemon request threads die with the process, exactly
     the pre-drain behavior).
 
-    Shared by the SIGTERM handler, the serve drill, and bench_serve's churn
-    leg. Returns True on a clean drain (False only from the test hook's
-    fake exit).
+    Shared by the SIGTERM handler and the serve drill. Returns True on a
+    clean drain (False only from the test hook's fake exit).
     """
     drain_start = time.monotonic()
     if lifecycle is not None and lifecycle.graceful_drain:

@@ -4,7 +4,7 @@ target, with burn-rate.
 The serving stack has latency *metrics* (``serving_request_seconds``) but no
 *objective* to judge them against: ROADMAP item 3's "millions of users"
 scale-out needs a machine-readable "are we inside SLO right now" signal that
-a fleet scheduler, the PR-9 lifecycle, and bench_serve.py can all consult.
+a fleet scheduler and the PR-9 lifecycle can both consult.
 This module provides it:
 
 * ``SM_SLO_P95_MS`` arms the plane (unset/0 = completely inert: no window,
@@ -24,8 +24,8 @@ This module provides it:
   owns that).
 
 Fed by the WSGI middleware (telemetry/wsgi.py) for the ``/invocations``
-route on BOTH serving apps, and read by bench_serve.py's steady-state leg
-and the rank-0 ``/status`` endpoint (telemetry/fleet.py).
+route on BOTH serving apps, and read by the rank-0 ``/status`` endpoint
+(telemetry/fleet.py).
 """
 
 import collections
@@ -65,7 +65,7 @@ class SloWindow:
 
     ``observe`` is O(amortized 1): append + trim + an incremental violation
     count; percentiles are computed only in :meth:`snapshot` (scrape /
-    status / bench cadence, not request cadence). ``clock`` is injectable
+    status cadence, not request cadence). ``clock`` is injectable
     so the burn-rate math is unit-testable without sleeping.
     """
 
@@ -132,8 +132,7 @@ class SloWindow:
 
     def snapshot(self):
         """-> dict(target/window/samples/p50/p95/violation_rate/burn_rate/
-        degraded) — the shape bench_serve's steady leg and ``/status``
-        publish."""
+        degraded) — the shape ``/status`` publishes."""
         with self._lock:
             self._trim_locked(self._clock())
             lat = [ms for _t, ms, _v in self._samples]

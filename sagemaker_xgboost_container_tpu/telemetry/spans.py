@@ -101,9 +101,13 @@ def current_phase():
 class OpenSpan:
     """A span between :func:`begin_span` and :func:`end_span`, for work that
     no ``with`` block can hold (``host_turnaround`` runs from the end of one
-    ``run_rounds()`` into the next). End it on the thread that began it."""
+    ``run_rounds()`` into the next). End it on the thread that began it; a
+    span ended elsewhere (its owner collected on another thread) still
+    leaves the open phases of the thread that began it."""
 
-    __slots__ = ("name", "covering", "registry", "start", "tspan", "annotation", "bytes")
+    __slots__ = (
+        "name", "covering", "registry", "start", "tspan", "annotation", "bytes", "phases",
+    )
 
     def __init__(self, name, covering, registry, attributes):
         self.name = name
@@ -121,7 +125,8 @@ class OpenSpan:
         self.annotation = (
             tracing.annotate(name, attributes) if self.tspan is None else None
         )
-        _open_phases().append(name)
+        self.phases = _open_phases()
+        self.phases.append(name)
         self.start = time.perf_counter()
 
     def add_bytes(self, up=0, down=0):
@@ -141,7 +146,7 @@ def end_span(open_span, emit=False):
     its ``bytes_up`` / ``bytes_down`` attributes in the byte counter."""
     elapsed = time.perf_counter() - open_span.start
     name = open_span.name
-    phases = _open_phases()
+    phases = open_span.phases
     if phases and phases[-1] == name:
         phases.pop()
     elif name in phases:  # ended out of order: drop it all the same

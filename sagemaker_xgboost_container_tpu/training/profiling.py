@@ -263,8 +263,8 @@ class RoundTimer:
 
 def attribution_fields(total_ms, compile_ms, host_ms, device_ms, collective_ms):
     """The shared compile/host/device/collective attribution shape — stable
-    keys for CloudWatch regexes, used by both the ``training.attribution``
-    record and bench.py's ``attribution`` section. Percentages are shares of
+    keys for CloudWatch regexes, used by the ``training.attribution``
+    record. Percentages are shares of
     ``total_ms`` (0.0 when the window is empty)."""
 
     def pct(ms):

@@ -86,7 +86,12 @@ def start_device_runtime(
     import jaxlib
 
     from ..data.binning import _sketch_impl
-    from ..ops.histogram import pallas_interpret, resolve_hist_knobs
+    from ..ops.histogram import (
+        choose_hist_impl,
+        choose_totals_impl,
+        pallas_interpret,
+        resolve_hist_knobs,
+    )
     from ..ops.tree_build import choose_eval_traversal, choose_route_impl
 
     cache_dir = enable_compile_cache()
@@ -101,10 +106,10 @@ def start_device_runtime(
             if mesh is not None
             else None
         ),
-        hist_impl=knobs.impl,
-        totals_impl=knobs.totals_impl,
+        hist_impl=choose_hist_impl(knobs.backend),
+        totals_impl=choose_totals_impl(knobs.backend),
         route_impl=(
-            choose_route_impl(knobs.route_backend, route_width)
+            choose_route_impl(knobs.backend, route_width)
             if route_width is not None
             else None
         ),

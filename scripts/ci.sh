@@ -145,17 +145,5 @@ if [ $rc -eq 0 ] && [ "$TIER" = "full" ]; then
   fi
 fi
 
-# fused-dispatch smoke (full): bounded K=1 vs K=4 micro-run asserting the
-# fused lax.scan round pipeline is bit-identical and not slower; the
-# measured JSON is archived next to the trace/graftlint artifacts
-if [ $rc -eq 0 ] && [ "$TIER" = "full" ]; then
-  if python "$REPO/scripts/bench_smoke.py" "$ARTIFACT_DIR/bench"; then
-    echo "bench smoke: OK (artifact: $ARTIFACT_DIR/bench/bench_smoke.json)"
-  else
-    rc=1
-    echo "CI $TIER TIER FAILED (bench smoke; see $ARTIFACT_DIR/bench)"
-  fi
-fi
-
 [ $rc -eq 0 ] && echo "CI $TIER TIER OK" || echo "CI $TIER TIER FAILED (rc=$rc)"
 exit $rc

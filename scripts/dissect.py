@@ -1,16 +1,19 @@
 #!/usr/bin/env python
 """Per-stage timing dissection of one boosting round on the current backend.
 
-Times each stage of the bench configuration (bench.py: 1M x 28, depth 8,
-max_bin 256, binary:logistic) in isolation under jit, so a round's time on
+Times each stage of one configuration (1M x 28, depth 8, max_bin 256,
+binary:logistic) in isolation under jit, so a round's time on
 the device can be attributed: grad/hess, per-level histograms (with the sibling
 subtraction that the real build does), node totals, split scan, row routing,
 eval prediction, and the full fused tree build.
 
 Prints one "stage: ms" line per stage plus a JSON summary line at the end.
-Honors GRAFT_HIST_IMPL / GRAFT_HIST_MM_PREC. ``--route-impl gather|dense``
-forces the lowering of the routing stage's bin fetch (default: what
-``ops/tree_build.choose_route_impl`` picks here). ``--route-widths`` runs the
+``--hist-impl flat|pallas`` names the level histogram's builder (default:
+what ``ops/histogram.choose_hist_impl`` picks here; the full tree build always
+takes the backend's); GRAFT_HIST_MM_PREC is honored through the session
+snapshot. ``--route-impl gather|dense`` forces the lowering of the routing
+stage's bin fetch (default: what ``ops/tree_build.choose_route_impl`` picks
+here). ``--route-widths`` runs the
 width probe instead: both lowerings of ``row_bin_lookup`` at each feature
 width and bins dtype, the table beside ``ROUTE_DENSE_MAX_WIDTH``.
 ``--node-table-widths`` runs the node-table probe: both lowerings of
@@ -222,6 +225,7 @@ def _emit(summary, out_path):
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--hist-impl", choices=("flat", "pallas"), default=None)
     ap.add_argument("--route-impl", choices=("gather", "dense"), default=None)
     ap.add_argument(
         "--route-widths", nargs="*", type=int, default=None, metavar="D",
@@ -271,12 +275,10 @@ def main():
     route_impl = args.route_impl or TB.choose_route_impl(
         jax.default_backend(), N_FEATURES
     )
+    knobs = H.resolve_hist_knobs()
+    hist_impl = args.hist_impl or H.choose_hist_impl(knobs.backend)
     print(
-        "impl={} prec={} route={}".format(
-            os.environ.get("GRAFT_HIST_IMPL", "flat"),
-            os.environ.get("GRAFT_HIST_MM_PREC", "bf16x2"),
-            route_impl,
-        ),
+        "impl={} prec={} route={}".format(hist_impl, knobs.precision, route_impl),
         flush=True,
     )
 
@@ -308,7 +310,9 @@ def main():
         key = ("hist", width_out)
         if key not in node_fns:
             node_fns[key] = jax.jit(
-                lambda b, g, h, nl: H.level_histogram(b, g, h, nl, width_out, B)
+                lambda b, g, h, nl: H.level_histogram(
+                    b, g, h, nl, width_out, B, knobs=knobs, impl=hist_impl
+                )
             )
         return node_fns[key]
 
@@ -330,7 +334,7 @@ def main():
     # --- last-level node totals -----------------------------------------
     W_last = 2**MAX_DEPTH
     nl = jnp.asarray(rng.randint(0, W_last, size=n).astype(np.int32))
-    fn_tot = jax.jit(lambda g, h, x: H.node_totals(g, h, x, W_last))
+    fn_tot = jax.jit(lambda g, h, x: H.node_totals(g, h, x, W_last, knobs=knobs))
     timings["node_totals[{}]".format(W_last)] = _time(fn_tot, grad, hess, nl)
 
     # --- split scan across all levels -----------------------------------
@@ -360,7 +364,7 @@ def main():
     @jax.jit
     def full_tree(b, g, h):
         tree, row_out = TB.build_tree(
-            b, g, h, num_cuts, MAX_DEPTH, B, eta=0.2
+            b, g, h, num_cuts, MAX_DEPTH, B, eta=0.2, knobs=knobs
         )
         return TB.pack_tree(tree), row_out
 
@@ -370,7 +374,9 @@ def main():
     @jax.jit
     def full_round(b, m, y):
         g, h = gradhess(m, y)
-        tree, row_out = TB.build_tree(b, g, h, num_cuts, MAX_DEPTH, B, eta=0.2)
+        tree, row_out = TB.build_tree(
+            b, g, h, num_cuts, MAX_DEPTH, B, eta=0.2, knobs=knobs
+        )
         return TB.pack_tree(tree), m + row_out
 
     timings["full_round"] = _time(full_round, bins, margins, labels)
@@ -378,7 +384,12 @@ def main():
     for k, v in timings.items():
         print("{:28s} {:9.2f} ms".format(k, v), flush=True)
     _emit(
-        {"backend": jax.default_backend(), "route_impl": route_impl, "timings_ms": timings},
+        {
+            "backend": jax.default_backend(),
+            "hist_impl": hist_impl,
+            "route_impl": route_impl,
+            "timings_ms": timings,
+        },
         args.out,
     )
 

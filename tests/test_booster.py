@@ -435,6 +435,7 @@ def test_approx_resketch_refreshes_cuts_and_evals():
     session.run_rounds()
     cuts_before = [np.asarray(c).copy() for c in session.cuts]
     session.run_rounds()  # triggers _resketch_bins
+    session.end_turnaround()
     changed = any(
         a.shape != np.asarray(b).shape or not np.allclose(a, np.asarray(b))
         for a, b in zip(cuts_before, session.cuts)

@@ -1,4 +1,5 @@
-"""Data-layer tests against the reference's real fixture files.
+"""Data-layer tests against files of the reference's fixture layout, made
+from a seed (tests/reference_fixtures.py).
 
 Coverage model: test/unit/test_data_utils.py (content types, format
 validation, loaders over test/resources/data/*) — but asserting on DataMatrix
@@ -15,9 +16,10 @@ from sagemaker_xgboost_container_tpu.data.recordio import (
     write_recordio_protobuf,
 )
 from sagemaker_xgboost_container_tpu.toolkit import exceptions as exc
+from tests.reference_fixtures import resources
 
-FIXTURES = "/root/reference/test/resources/data"
-ABALONE = "/root/reference/test/resources/abalone/data"
+FIXTURES = resources() + "/data"
+ABALONE = resources() + "/abalone/data"
 
 
 def test_get_content_type_aliases():
@@ -117,16 +119,14 @@ def test_validate_libsvm_rejects_csv(tmp_path):
 
 def test_nested_dir_staging():
     dm = readers.get_data_matrix(
-        "/root/reference/test/resources/abalone-subdirs/train", "libsvm"
+        resources() + "/abalone-subdirs/train", "libsvm"
     )
     assert dm is not None and dm.num_row > 0
 
 
 def test_staging_depth_cap_warns_but_loads_nothing_deeper(caplog):
     # dir1/dir2/dir3/dir4/abalone.train_0 sits at depth 4 > MAX_FOLDER_DEPTH
-    staged = readers.stage_input_files(
-        "/root/reference/test/resources/abalone-subdirs/dir1"
-    )
+    staged = readers.stage_input_files(resources() + "/abalone-subdirs/dir1")
     import os
 
     assert staged is not None
@@ -223,7 +223,7 @@ def test_abalone_binary_and_multiclass_train():
     from sagemaker_xgboost_container_tpu.models import train
 
     dm_bin = readers.get_data_matrix(
-        "/root/reference/test/resources/abalone-binary/data/train", "libsvm"
+        resources() + "/abalone-binary/data/train", "libsvm"
     )
     assert set(np.unique(dm_bin.labels)) <= {0.0, 1.0}
     forest = train(
@@ -233,7 +233,7 @@ def test_abalone_binary_and_multiclass_train():
     assert ((p > 0.5) == dm_bin.labels).mean() > 0.7
 
     dm_multi = readers.get_data_matrix(
-        "/root/reference/test/resources/abalone-multiclass/data/train", "libsvm"
+        resources() + "/abalone-multiclass/data/train", "libsvm"
     )
     n_class = int(dm_multi.labels.max()) + 1
     forest = train(

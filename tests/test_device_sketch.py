@@ -197,6 +197,7 @@ def test_approx_resketch_device_impl(monkeypatch):
     assert staged is not None
     cuts0 = [np.asarray(c).copy() for c in session.cuts]
     session.run_rounds()
+    session.end_turnaround()
     assert session._feats_dev is staged, "features must stage exactly once"
     assert any(
         a.shape != np.asarray(b).shape or not np.allclose(a, np.asarray(b))

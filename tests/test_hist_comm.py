@@ -26,6 +26,7 @@ from jax.sharding import Mesh
 
 from sagemaker_xgboost_container_tpu.data.matrix import DataMatrix
 from sagemaker_xgboost_container_tpu.models import train
+from sagemaker_xgboost_container_tpu.ops import histogram as hist_mod
 from sagemaker_xgboost_container_tpu.ops.histogram import (
     MERGE_COLLECTIVES_PER_SCAN,
     padded_feature_width,
@@ -72,11 +73,12 @@ def _assert_forests_bitwise(f1, f2):
             assert np.array_equal(a, b), "tree field {!r} diverges".format(k)
 
 
-def _train_both(monkeypatch, params, X, y, mesh, rounds=4, extra_env=()):
+_CACHE_CAP_AS_SHIPPED = hist_mod.SUBTRACT_CACHE_MAX_BYTES
+
+
+def _train_both(monkeypatch, params, X, y, mesh, rounds=4):
     """Train under psum and reduce_scatter; assert packed trees AND
     predictions are bitwise identical; return the psum forest."""
-    for k, v in extra_env:
-        monkeypatch.setenv(k, v)
     forests = []
     for comm in ("psum", "reduce_scatter"):
         monkeypatch.setenv("GRAFT_HIST_COMM", comm)
@@ -113,8 +115,8 @@ def test_k_round_equivalence_matrix(monkeypatch, mesh8):
         },
     }
     for builder, params in builder_params.items():
-        for subtract in ("1", "0"):
-            monkeypatch.setenv("GRAFT_HIST_SUBTRACT", subtract)
+        for cache_cap in (_CACHE_CAP_AS_SHIPPED, 0):  # subtraction on, off
+            monkeypatch.setattr(hist_mod, "SUBTRACT_CACHE_MAX_BYTES", cache_cap)
             reference = None
             for comm in ("psum", "reduce_scatter"):
                 monkeypatch.setenv("GRAFT_HIST_COMM", comm)
@@ -129,7 +131,7 @@ def test_k_round_equivalence_matrix(monkeypatch, mesh8):
                     if reference is None:
                         reference = f
                         continue
-                    cell = (builder, subtract, comm, k_rounds)
+                    cell = (builder, cache_cap, comm, k_rounds)
                     _assert_forests_bitwise(reference, f)
                     pr = np.asarray(reference.predict(X), np.float32)
                     pf = np.asarray(f.predict(X), np.float32)
@@ -196,12 +198,14 @@ def test_scan_carry_donation_reuses_round_buffers():
     )
     assert session.use_scan_rounds and session.rounds_per_dispatch == 3
     session.run_rounds()  # compile + first allocation
+    session.end_turnaround()
     try:
         margin_ptr = session.margins.unsafe_buffer_pointer()
         eval_ptr = session.eval_margins[0].unsafe_buffer_pointer()
     except (AttributeError, NotImplementedError):
         pytest.skip("backend exposes no unsafe_buffer_pointer")
     session.run_rounds()
+    session.end_turnaround()
     if session.margins.unsafe_buffer_pointer() != margin_ptr:
         pytest.skip("backend does not alias donated round buffers")
     # train margins AND the scanned eval-margin carry both reuse their
@@ -243,11 +247,12 @@ def test_reduce_scatter_bitwise_without_subtraction(monkeypatch, mesh8):
     # the default runs exercise the subtraction cache (parent - left on the
     # local slice); this pins the direct-histogram path for both growers
     X, y = _data(d=11, seed=3)
+    # a cache over the cap: both growers build both children directly
+    monkeypatch.setattr(hist_mod, "SUBTRACT_CACHE_MAX_BYTES", 0)
     _train_both(
         monkeypatch,
         {"objective": "binary:logistic", "max_depth": 4, "seed": 1},
         X, y, mesh8,
-        extra_env=(("GRAFT_HIST_SUBTRACT", "0"),),
     )
     _train_both(
         monkeypatch,
@@ -259,7 +264,6 @@ def test_reduce_scatter_bitwise_without_subtraction(monkeypatch, mesh8):
             "seed": 1,
         },
         X, y, mesh8, rounds=3,
-        extra_env=(("GRAFT_HIST_SUBTRACT", "0"),),
     )
 
 
@@ -357,8 +361,8 @@ def test_2d_mesh_equivalence_matrix(monkeypatch, mesh_shape):
     mesh = _mesh2d(mesh_shape)
     X, y = _data(n=256, d=9, seed=21)
     for builder, params in _BUILDER_PARAMS_2D.items():
-        for subtract in ("1", "0"):
-            monkeypatch.setenv("GRAFT_HIST_SUBTRACT", subtract)
+        for cache_cap in (_CACHE_CAP_AS_SHIPPED, 0):  # subtraction on, off
+            monkeypatch.setattr(hist_mod, "SUBTRACT_CACHE_MAX_BYTES", cache_cap)
             monkeypatch.setenv("GRAFT_HIST_OVERLAP", "1")
             monkeypatch.setenv("GRAFT_HIST_COMM", "psum")
             reference = train(
@@ -376,7 +380,7 @@ def test_2d_mesh_equivalence_matrix(monkeypatch, mesh_shape):
                         num_boost_round=4,
                         mesh=mesh,
                     )
-                    cell = (mesh_shape, builder, subtract, k_rounds, overlap)
+                    cell = (mesh_shape, builder, cache_cap, k_rounds, overlap)
                     assert f.num_boosted_rounds == 4, cell
                     _assert_forests_bitwise(reference, f)
                     pf = np.asarray(f.predict(X), np.float32)

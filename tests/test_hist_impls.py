@@ -1,5 +1,6 @@
-"""Histogram engine A/B tests: every GRAFT_HIST_IMPL and the subtraction
-path must produce the same trees as the flat scatter-add reference.
+"""Histogram engine tests: both builders, both node-total lowerings and the
+subtraction path must produce the same trees as the flat scatter-add
+reference, and the choosers pick one of each a backend.
 
 The reference's hist tree builder delegates to libxgboost's hist updater
 (reference algorithm_mode/train.py:367-376); sibling subtraction is
@@ -8,7 +9,8 @@ parent - child). Here the equivalents are exercised over data with missing
 values and uneven node occupancy.
 """
 
-import os
+import pathlib
+import re
 
 import numpy as np
 import pytest
@@ -16,6 +18,13 @@ import jax.numpy as jnp
 
 from sagemaker_xgboost_container_tpu.ops import histogram as hist_mod
 from sagemaker_xgboost_container_tpu.ops.tree_build import build_tree
+
+
+def _chip_knobs(**fields):
+    """The chip's program on the CPU: every chooser reads ``tpu`` (Pallas
+    histogram, one-hot totals, dense bin fetch, select tables), and
+    ``pallas_interpret()`` keeps reading the real backend."""
+    return hist_mod.resolve_hist_knobs()._replace(backend="tpu", **fields)
 
 
 @pytest.fixture
@@ -29,12 +38,11 @@ def rand_problem():
     return bins, grad, hess, num_cuts, num_bins
 
 
-def _build(bins, grad, hess, num_cuts, num_bins, max_depth=5, **env):
-    old = {}
-    for k, v in env.items():
-        old[k] = os.environ.get(k)
-        os.environ[k] = v
-    try:
+def _build(bins, grad, hess, num_cuts, num_bins, max_depth=5, knobs=None,
+           subtract=True):
+    with pytest.MonkeyPatch.context() as mp:
+        if not subtract:  # a cache over the cap builds both children directly
+            mp.setattr(hist_mod, "SUBTRACT_CACHE_MAX_BYTES", 0)
         tree, row_out = build_tree(
             jnp.asarray(bins),
             jnp.asarray(grad),
@@ -42,14 +50,9 @@ def _build(bins, grad, hess, num_cuts, num_bins, max_depth=5, **env):
             jnp.asarray(num_cuts),
             max_depth=max_depth,
             num_bins=num_bins,
+            knobs=knobs,
         )
-        return {k: np.asarray(v) for k, v in tree.items()}, np.asarray(row_out)
-    finally:
-        for k, v in old.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
+    return {k: np.asarray(v) for k, v in tree.items()}, np.asarray(row_out)
 
 
 def _assert_trees_match(ta, ra, tb, rb, atol=2e-4):
@@ -77,71 +80,41 @@ def _assert_trees_match(ta, ra, tb, rb, atol=2e-4):
 
 
 def test_subtraction_matches_direct(rand_problem):
-    bins, grad, hess, num_cuts, num_bins = rand_problem
-    t_direct, r_direct = _build(
-        bins, grad, hess, num_cuts, num_bins, GRAFT_HIST_SUBTRACT="0"
-    )
-    t_sub, r_sub = _build(
-        bins, grad, hess, num_cuts, num_bins, GRAFT_HIST_SUBTRACT="1"
-    )
+    t_direct, r_direct = _build(*rand_problem, subtract=False)
+    t_sub, r_sub = _build(*rand_problem)
     _assert_trees_match(t_direct, r_direct, t_sub, r_sub)
 
 
-@pytest.mark.parametrize("impl", ["per_feature", "matmul", "pallas"])
+@pytest.mark.parametrize("impl", ["pallas"])
 def test_impls_match_flat(rand_problem, impl):
-    bins, grad, hess, num_cuts, num_bins = rand_problem
-    t0, r0 = _build(
-        bins, grad, hess, num_cuts, num_bins,
-        GRAFT_HIST_IMPL="flat", GRAFT_HIST_SUBTRACT="0",
-    )
-    t1, r1 = _build(
-        bins, grad, hess, num_cuts, num_bins,
-        GRAFT_HIST_IMPL=impl, GRAFT_HIST_SUBTRACT="0",
-        GRAFT_HIST_CHUNK="1024", GRAFT_HIST_BLOCK="256",
-    )
+    assert hist_mod.choose_hist_impl("tpu") == impl
+    t0, r0 = _build(*rand_problem, subtract=False)
+    t1, r1 = _build(*rand_problem, subtract=False, knobs=_chip_knobs())
     _assert_trees_match(t0, r0, t1, r1)
 
 
 def test_matmul_subtract_combo(rand_problem):
-    bins, grad, hess, num_cuts, num_bins = rand_problem
-    t0, r0 = _build(
-        bins, grad, hess, num_cuts, num_bins,
-        GRAFT_HIST_IMPL="flat", GRAFT_HIST_SUBTRACT="0",
-    )
-    t1, r1 = _build(
-        bins, grad, hess, num_cuts, num_bins,
-        GRAFT_HIST_IMPL="matmul", GRAFT_HIST_SUBTRACT="1",
-        GRAFT_HIST_CHUNK="1024",
-    )
+    """The Pallas one-hot matmul with sibling subtraction against the flat
+    builder with both children built directly."""
+    t0, r0 = _build(*rand_problem, subtract=False)
+    t1, r1 = _build(*rand_problem, knobs=_chip_knobs())
     _assert_trees_match(t0, r0, t1, r1)
 
 
 def test_matmul_precision_modes(rand_problem):
+    """The Pallas kernel's two operand precisions against the flat builder:
+    the program's (``bf16x2``) and the failing control's (``bf16``)."""
     bins, grad, hess, num_cuts, num_bins = rand_problem
     node = np.zeros(len(grad), np.int32)
-    ref_G, ref_H = hist_mod._hist_flat(
-        jnp.asarray(bins), jnp.asarray(grad), jnp.asarray(hess),
-        jnp.asarray(node), 1, num_bins,
-    )
-    saved = {
-        k: os.environ.get(k) for k in ("GRAFT_HIST_MM_PREC", "GRAFT_HIST_CHUNK")
-    }
-    try:
-        for prec, tol in [("f32", 1e-4), ("bf16x2", 5e-4), ("bf16", 0.3)]:
-            os.environ["GRAFT_HIST_MM_PREC"] = prec
-            os.environ["GRAFT_HIST_CHUNK"] = "1024"
-            G, H = hist_mod._hist_matmul(
-                jnp.asarray(bins), jnp.asarray(grad), jnp.asarray(hess),
-                jnp.asarray(node), 1, num_bins,
-            )
-            assert float(jnp.abs(G - ref_G).max()) < tol, prec
-            assert float(jnp.abs(H - ref_H).max()) < tol, prec
-    finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
+    args = (jnp.asarray(bins), jnp.asarray(grad), jnp.asarray(hess), jnp.asarray(node))
+    ref_G, ref_H = hist_mod._hist_flat(*args, 1, num_bins)
+    assert hist_mod.HIST_PRECISIONS == ("bf16x2", "bf16")
+    for prec, tol in [("bf16x2", 5e-4), ("bf16", 0.3)]:
+        G, H = hist_mod._hist_pallas(*args, 1, num_bins, prec=prec)
+        assert float(jnp.abs(G - ref_G).max()) < tol, prec
+        assert float(jnp.abs(H - ref_H).max()) < tol, prec
+    with pytest.raises(ValueError, match="f32"):
+        hist_mod._hist_pallas(*args, 1, num_bins, prec="f32")
 
 
 def test_node_totals_matches_histogram(rand_problem):
@@ -168,23 +141,18 @@ def test_lossguide_subtraction_matches_direct(rand_problem):
 
     bins, grad, hess, num_cuts, num_bins = rand_problem
 
-    def build(env_val):
-        old = os.environ.get("GRAFT_HIST_SUBTRACT")
-        os.environ["GRAFT_HIST_SUBTRACT"] = env_val
-        try:
+    def build(cache_cap=None):
+        with pytest.MonkeyPatch.context() as mp:
+            if cache_cap is not None:
+                mp.setattr(hist_mod, "SUBTRACT_CACHE_MAX_BYTES", cache_cap)
             tree, row_out = build_tree_lossguide(
                 jnp.asarray(bins), jnp.asarray(grad), jnp.asarray(hess),
                 jnp.asarray(num_cuts), max_leaves=16, num_bins=num_bins,
             )
-            return {k: np.asarray(v) for k, v in tree.items()}, np.asarray(row_out)
-        finally:
-            if old is None:
-                os.environ.pop("GRAFT_HIST_SUBTRACT", None)
-            else:
-                os.environ["GRAFT_HIST_SUBTRACT"] = old
+        return {k: np.asarray(v) for k, v in tree.items()}, np.asarray(row_out)
 
-    t0, r0 = build("0")
-    t1, r1 = build("1")
+    t0, r0 = build(cache_cap=0)
+    t1, r1 = build()
     _assert_trees_match(t0, r0, t1, r1)
 
 
@@ -242,11 +210,13 @@ def test_colsample_bynode_actually_wired():
     ), "colsample_bynode had no effect on tree structure"
 
 
-def test_route_impls_equivalent():
-    """train() under the dense bin fetch must build identical trees to the
-    gather (both levelwise routing and binned eval prediction use it, so the
-    logged validation losses must agree too). The lowering follows the
-    session snapshot's backend; tests/test_row_routing.py has the pieces."""
+def test_route_impls_equivalent(monkeypatch):
+    """train() under the dense bin fetch and the select table lookup must
+    build identical trees to the gathers (both levelwise routing and binned
+    eval prediction use them, so the logged validation losses must agree
+    too). The lowerings follow the session snapshot's backend and the width
+    rules; tests/test_row_routing.py has the pieces."""
+    from sagemaker_xgboost_container_tpu.ops import tree_build
     from sagemaker_xgboost_container_tpu.data.matrix import DataMatrix
     from sagemaker_xgboost_container_tpu.models import train
 
@@ -264,129 +234,111 @@ def test_route_impls_equivalent():
             return False
 
     forests, logs = {}, {}
-    for backend in ("cpu", "tpu"):  # gather, dense at this width
-        keep = KeepLog()
-        forests[backend] = train(
-            params, d, num_boost_round=4, evals=[(d, "train"), (dval, "validation")],
-            callbacks=[keep], verbose_eval=False,
-            hist_knobs=hist_mod.resolve_hist_knobs()._replace(route_backend=backend),
+    # the chip's program twice: as the width rules pick at this width (dense,
+    # select), then with both rules cut to zero (gathers)
+    for side, max_width in (("rules", None), ("gathers", 0)):
+        if max_width is not None:
+            monkeypatch.setattr(tree_build, "ROUTE_DENSE_MAX_WIDTH", max_width)
+            monkeypatch.setattr(tree_build, "NODE_TABLE_SELECT_MAX_WIDTH", max_width)
+        assert tree_build.choose_route_impl("tpu", 7) == (
+            "gather" if side == "gathers" else "dense"
         )
-        logs[backend] = {k: dict(v) for k, v in keep.evals_log.items()}
+        keep = KeepLog()
+        forests[side] = train(
+            params, d, num_boost_round=4, evals=[(d, "train"), (dval, "validation")],
+            callbacks=[keep], verbose_eval=False, hist_knobs=_chip_knobs(),
+        )
+        logs[side] = {k: dict(v) for k, v in keep.evals_log.items()}
     np.testing.assert_array_equal(
-        np.asarray(forests["cpu"].predict_margin(X)),
-        np.asarray(forests["tpu"].predict_margin(X)),
+        np.asarray(forests["rules"].predict_margin(X)),
+        np.asarray(forests["gathers"].predict_margin(X)),
     )
-    assert logs["cpu"] == logs["tpu"] and len(logs["cpu"]["validation"]["logloss"]) == 4
+    assert logs["rules"] == logs["gathers"]
+    assert len(logs["rules"]["validation"]["logloss"]) == 4
 
 
-def test_mxu_aligned_hist_matches_flat():
-    """GRAFT_HIST_ALIGN splits the missing-bin column out of the one-hot dot
-    whenever B = k*128 + 1 (max_bin=256 -> B=257 pads to 384 MXU lanes
-    otherwise). Both aligned and unaligned matmul/pallas paths must match
-    the flat scatter reference bin-for-bin, including the missing column."""
+@pytest.mark.parametrize("B, split", [(257, True), (129, True), (200, False)])
+def test_mxu_aligned_hist_matches_flat(B, split):
+    """Whenever B = k*128 + 1 (max_bin=256 -> B=257 pads to 384 MXU lanes
+    otherwise) the Pallas kernel splits the missing-bin column out of the
+    one-hot dot; any other B keeps it inside. Both must match the flat
+    scatter reference bin-for-bin, including the missing column."""
+    assert hist_mod._mxu_split_missing(B) is split
     rng = np.random.RandomState(11)
-    n, d, W, B = 4000, 5, 8, 257
+    n, d, W = 4000, 5, 8
     bins = jnp.asarray(rng.randint(0, B, size=(n, d)).astype(np.int32))
     grad = jnp.asarray(rng.randn(n).astype(np.float32))
     hess = jnp.asarray((rng.rand(n) + 0.1).astype(np.float32))
     node = jnp.asarray(rng.randint(-1, W, size=n).astype(np.int32))
 
-    def hist(**env):
-        old = {k: os.environ.get(k) for k in env}
-        os.environ.update(env)
-        try:
-            G, H = hist_mod.level_histogram(bins, grad, hess, node, W, B)
-            return np.asarray(G), np.asarray(H)
-        finally:
-            for k, v in old.items():
-                if v is None:
-                    os.environ.pop(k, None)
-                else:
-                    os.environ[k] = v
+    def hist(impl, prec="bf16x2"):
+        G, H = hist_mod.level_histogram(
+            bins, grad, hess, node, W, B, impl=impl, knobs=_chip_knobs(precision=prec)
+        )
+        return np.asarray(G), np.asarray(H)
 
-    G0, H0 = hist(GRAFT_HIST_IMPL="flat")
+    G0, H0 = hist("flat")
     assert G0[:, :, B - 1].any(), "fixture must exercise the missing bin"
-    # f32 exactly; bf16x2 (production default) to split-precision tolerance;
-    # bf16 to operand-rounding tolerance — all three run the aligned miss dot
-    for prec, atol in (("f32", 2e-4), ("bf16x2", 5e-3), ("bf16", 0.2)):
-        for impl in ("matmul", "pallas"):
-            for align in ("0", "1"):
-                G1, H1 = hist(
-                    GRAFT_HIST_IMPL=impl,
-                    GRAFT_HIST_MM_PREC=prec,
-                    GRAFT_HIST_ALIGN=align,
-                )
-                msg = f"{impl} align={align} prec={prec}"
-                np.testing.assert_allclose(G1, G0, atol=atol, err_msg=msg)
-                np.testing.assert_allclose(H1, H0, atol=atol, err_msg=msg)
+    # bf16x2 (the program) to split-precision tolerance; bf16 (the control)
+    # to operand-rounding tolerance
+    for prec, atol in (("bf16x2", 5e-3), ("bf16", 0.2)):
+        G1, H1 = hist("pallas", prec)
+        np.testing.assert_allclose(G1, G0, atol=atol, err_msg=prec)
+        np.testing.assert_allclose(H1, H0, atol=atol, err_msg=prec)
 
 
 def test_node_totals_onehot_matches_segment():
-    """GRAFT_TOTALS_IMPL=onehot (MXU contraction, no sort) must match the
-    segment_sum lowering used for last-level leaf weights."""
+    """``onehot`` (MXU contraction, no sort) must match the segment_sum
+    lowering used for last-level leaf weights."""
     rng = np.random.RandomState(12)
-    n, W = 70000, 256  # > one chunk when GRAFT_HIST_CHUNK=65536
+    n, W = 70000, 256  # more than one chunk of TOTALS_CHUNK_ROWS
+    assert n > hist_mod.TOTALS_CHUNK_ROWS
     grad = jnp.asarray(rng.randn(n).astype(np.float32))
     hess = jnp.asarray((rng.rand(n) + 0.1).astype(np.float32))
     node = jnp.asarray(rng.randint(-1, W, size=n).astype(np.int32))
 
     def totals(impl):
-        old = os.environ.get("GRAFT_TOTALS_IMPL")
-        os.environ["GRAFT_TOTALS_IMPL"] = impl
-        try:
-            g, h = hist_mod.node_totals(grad, hess, node, W)
-            return np.asarray(g), np.asarray(h)
-        finally:
-            if old is None:
-                os.environ.pop("GRAFT_TOTALS_IMPL", None)
-            else:
-                os.environ["GRAFT_TOTALS_IMPL"] = old
+        g, h = hist_mod.node_totals(grad, hess, node, W, impl=impl)
+        return np.asarray(g), np.asarray(h)
 
     g0, h0 = totals("segment")
-    for impl in ("onehot", "pallas"):
-        g1, h1 = totals(impl)
-        np.testing.assert_allclose(g1, g0, rtol=1e-4, atol=1e-3, err_msg=impl)
-        np.testing.assert_allclose(h1, h0, rtol=1e-4, atol=1e-3, err_msg=impl)
+    g1, h1 = totals("onehot")
+    np.testing.assert_allclose(g1, g0, rtol=1e-4, atol=1e-3)
+    np.testing.assert_allclose(h1, h0, rtol=1e-4, atol=1e-3)
 
 
-def test_vnode_packing_matches_flat():
-    """GRAFT_HIST_VNODES packs v=128//(2W) row sub-groups into the MXU's M
-    tile at shallow levels (virtual node ranges, summed after the grid) —
-    pure sum reassociation, so histograms must match the flat reference at
-    every width, dead rows excluded correctly."""
+def test_vnode_packing_matches_flat(monkeypatch):
+    """The kernel packs v=128//(2W) row sub-groups into the MXU's M tile at
+    shallow levels (virtual node ranges, summed after the grid) — pure sum
+    reassociation, so histograms must match the flat reference at every
+    width, dead rows excluded correctly; a level over the VMEM budget packs
+    nothing and must match too."""
     rng = np.random.RandomState(13)
     n, d, B = 4096, 5, 129  # B = 128+1 also exercises the aligned miss dot
     bins = jnp.asarray(rng.randint(0, B, size=(n, d)).astype(np.uint8))
     grad = jnp.asarray(rng.randn(n).astype(np.float32))
     hess = jnp.asarray((rng.rand(n) + 0.1).astype(np.float32))
 
-    def hist(W, node, **env):
-        old = {k: os.environ.get(k) for k in env}
-        os.environ.update(env)
-        try:
-            G, H = hist_mod.level_histogram(bins, grad, hess, node, W, B)
-            return np.asarray(G), np.asarray(H)
-        finally:
-            for k, v in old.items():
-                if v is None:
-                    os.environ.pop(k, None)
-                else:
-                    os.environ[k] = v
+    def hist(W, node, impl):
+        G, H = hist_mod.level_histogram(bins, grad, hess, node, W, B, impl=impl)
+        return np.asarray(G), np.asarray(H)
 
+    fg = hist_mod._pallas_feature_group(d, np.uint8)
+    budget_as_shipped = hist_mod.VNODE_VMEM_BYTES
     for W in (1, 2, 16, 64):
         node = jnp.asarray(rng.randint(-1, W, size=n).astype(np.int32))
-        G0, H0 = hist(W, node, GRAFT_HIST_IMPL="flat")
-        G1, H1 = hist(
-            W, node,
-            GRAFT_HIST_IMPL="pallas",
-            GRAFT_HIST_MM_PREC="f32",
-            GRAFT_HIST_VNODES="1",
-        )
-        np.testing.assert_allclose(G1, G0, atol=2e-4, err_msg=f"W={W}")
-        np.testing.assert_allclose(H1, H0, atol=2e-4, err_msg=f"W={W}")
+        G0, H0 = hist(W, node, "flat")
+        for budget, packed in ((budget_as_shipped, 64 // W), (0, 1)):
+            monkeypatch.setattr(hist_mod, "VNODE_VMEM_BYTES", budget)
+            v = hist_mod._vnode_factor(W, hist_mod.PALLAS_ROW_BLOCK, fg, B)
+            assert v == packed, (W, budget, v)
+            G1, H1 = hist(W, node, "pallas")
+            msg = f"W={W} v={v}"
+            np.testing.assert_allclose(G1, G0, atol=5e-3, err_msg=msg)
+            np.testing.assert_allclose(H1, H0, atol=5e-3, err_msg=msg)
 
 
-@pytest.mark.parametrize("impl", ["flat", "per_feature", "matmul", "pallas"])
+@pytest.mark.parametrize("impl", ["flat", "pallas"])
 def test_empty_input_yields_zero_histograms(impl):
     """n==0 (empty shard / empty eval set) must return zeros from every
     impl — the pallas grid would be (0,) and its step-0 out_ref init never
@@ -396,40 +348,23 @@ def test_empty_input_yields_zero_histograms(impl):
     grad = jnp.zeros((0,), jnp.float32)
     hess = jnp.zeros((0,), jnp.float32)
     node = jnp.zeros((0,), jnp.int32)
-    old = os.environ.get("GRAFT_HIST_IMPL")
-    try:
-        os.environ["GRAFT_HIST_IMPL"] = impl
-        G, H = hist_mod.level_histogram(bins, grad, hess, node, 4, 17)
-    finally:
-        if old is None:
-            os.environ.pop("GRAFT_HIST_IMPL", None)
-        else:
-            os.environ["GRAFT_HIST_IMPL"] = old
+    G, H = hist_mod.level_histogram(bins, grad, hess, node, 4, 17, impl=impl)
     assert G.shape == (4, 4, 17) and H.shape == (4, 4, 17)
     assert not np.asarray(G).any() and not np.asarray(H).any()
 
 
-@pytest.mark.parametrize("impl", ["segment", "onehot", "pallas"])
+@pytest.mark.parametrize("impl", ["segment", "onehot"])
 def test_empty_input_yields_zero_totals(impl):
     grad = jnp.zeros((0,), jnp.float32)
     node = jnp.zeros((0,), jnp.int32)
-    old = os.environ.get("GRAFT_TOTALS_IMPL")
-    try:
-        os.environ["GRAFT_TOTALS_IMPL"] = impl
-        g, h = hist_mod.node_totals(grad, grad, node, 8)
-    finally:
-        if old is None:
-            os.environ.pop("GRAFT_TOTALS_IMPL", None)
-        else:
-            os.environ["GRAFT_TOTALS_IMPL"] = old
+    g, h = hist_mod.node_totals(grad, grad, node, 8, impl=impl)
     assert g.shape == (8,) and not np.asarray(g).any()
     assert h.shape == (8,) and not np.asarray(h).any()
 
 
 def test_multiclass_vmap_over_pallas():
     """Multiclass training vmaps the tree builder over classes; the pallas
-    histogram kernel must survive the vmap batching rule (bench BENCH_TASK=
-    multiclass exercises this on hardware)."""
+    histogram kernel must survive the vmap batching rule."""
     from sagemaker_xgboost_container_tpu.data.matrix import DataMatrix
     from sagemaker_xgboost_container_tpu.models import train
 
@@ -439,20 +374,109 @@ def test_multiclass_vmap_over_pallas():
         np.float32
     )
     params = {"objective": "multi:softprob", "num_class": 3, "max_depth": 3}
-    old = os.environ.get("GRAFT_HIST_IMPL")
-    try:
-        os.environ["GRAFT_HIST_IMPL"] = "pallas"
-        f1 = train(dict(params), DataMatrix(X, labels=y), num_boost_round=2)
-        os.environ["GRAFT_HIST_IMPL"] = "flat"
-        f0 = train(dict(params), DataMatrix(X, labels=y), num_boost_round=2)
-    finally:
-        if old is None:
-            os.environ.pop("GRAFT_HIST_IMPL", None)
-        else:
-            os.environ["GRAFT_HIST_IMPL"] = old
+    f1 = train(
+        dict(params), DataMatrix(X, labels=y), num_boost_round=2,
+        hist_knobs=_chip_knobs(),
+    )
+    f0 = train(dict(params), DataMatrix(X, labels=y), num_boost_round=2)
     np.testing.assert_allclose(
         np.asarray(f1.predict(X)), np.asarray(f0.predict(X)), atol=1e-4
     )
+
+
+@pytest.mark.parametrize(
+    "backend, hist, totals",
+    [("tpu", "pallas", "onehot"), ("cpu", "flat", "segment"), ("gpu", "flat", "segment")],
+)
+@pytest.mark.parametrize("chooser", ["choose_hist_impl", "choose_totals_impl"])
+def test_choosers_pick_one_lowering_a_backend(chooser, backend, hist, totals):
+    """The one-hot matmul forms where scatters serialize (the TPU), the
+    segment sums elsewhere: the defaults the environment used to override."""
+    want = hist if chooser == "choose_hist_impl" else totals
+    assert getattr(hist_mod, chooser)(backend) == want
+
+
+def test_unknown_builder_or_lowering_raises():
+    z = jnp.zeros((4,), jnp.float32)
+    node = jnp.zeros((4,), jnp.int32)
+    with pytest.raises(ValueError, match="matmul"):
+        hist_mod.level_histogram(
+            jnp.zeros((4, 2), jnp.uint8), z, z, node, 1, 9, impl="matmul"
+        )
+    with pytest.raises(ValueError, match="pallas"):
+        hist_mod.node_totals(z, z, node, 1, impl="pallas")
+
+
+def test_hist_knobs_is_what_a_session_must_freeze():
+    assert hist_mod.HistKnobs._fields == ("backend", "precision", "comm_overlap")
+    knobs = hist_mod.resolve_hist_knobs()
+    assert knobs == ("cpu", "bf16x2", True)
+
+
+@pytest.mark.parametrize("value", ["f32", "fp8", ""])
+def test_unknown_precision_raises_at_session_build(monkeypatch, value):
+    """``GRAFT_HIST_MM_PREC`` has two values: the program's and the failing
+    control's. Anything else stops the job before it traces a round."""
+    from sagemaker_xgboost_container_tpu.data.matrix import DataMatrix
+    from sagemaker_xgboost_container_tpu.models import train
+
+    monkeypatch.setenv("GRAFT_HIST_MM_PREC", value)
+    X = np.random.RandomState(0).rand(40, 3).astype(np.float32)
+    with pytest.raises(ValueError, match="GRAFT_HIST_MM_PREC"):
+        train({"max_depth": 2}, DataMatrix(X, labels=X[:, 0]), num_boost_round=1)
+
+
+# the nine histogram knobs PR 30 retired, each with a value that took its
+# other branch while it lived
+RETIRED_KNOBS = {
+    "GRAFT_HIST_IMPL": "flat",
+    "GRAFT_TOTALS_IMPL": "segment",
+    "GRAFT_HIST_CHUNK": "1024",
+    "GRAFT_HIST_BLOCK": "256",
+    "GRAFT_HIST_ALIGN": "0",
+    "GRAFT_HIST_VNODES": "0",
+    "GRAFT_VNODE_VMEM": "0",
+    "GRAFT_HIST_SUBTRACT": "0",
+    "GRAFT_SUBTRACT_MEM": "0",
+}
+
+
+@pytest.fixture(scope="module")
+def chip_program_tree():
+    """One tree of the chip's program (interpreted), no retired name set."""
+    rng = np.random.RandomState(19)
+    n, d, num_bins = 1500, 6, 129  # 129: the aligned missing-bin dot too
+    problem = (
+        rng.randint(0, num_bins, size=(n, d)).astype(np.uint8),
+        rng.randn(n).astype(np.float32),
+        rng.rand(n).astype(np.float32) + 0.1,
+        np.full(d, num_bins - 2, np.int32),
+        num_bins,
+    )
+    return problem, _build(*problem, max_depth=4, knobs=_chip_knobs())
+
+
+@pytest.mark.parametrize("name", sorted(RETIRED_KNOBS))
+def test_retired_knob_is_dead(monkeypatch, chip_program_tree, name):
+    """Setting a retired name changes no bit of a tree, and no module of the
+    package reads it."""
+    problem, (tree, row_out) = chip_program_tree
+    monkeypatch.setenv(name, RETIRED_KNOBS[name])
+    tree_set, row_out_set = _build(*problem, max_depth=4, knobs=_chip_knobs())
+    for field in tree:
+        np.testing.assert_array_equal(tree_set[field], tree[field], err_msg=field)
+    np.testing.assert_array_equal(row_out_set, row_out)
+
+    import sagemaker_xgboost_container_tpu as package
+
+    root = pathlib.Path(package.__file__).parent
+    word = re.compile(r"\b%s\b" % name)
+    readers = [
+        str(path.relative_to(root))
+        for path in sorted(root.rglob("*.py"))
+        if word.search(path.read_text())
+    ]
+    assert readers == []
 
 
 @pytest.mark.parametrize(

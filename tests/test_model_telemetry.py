@@ -22,6 +22,7 @@ import pytest
 from sagemaker_xgboost_container_tpu.constants import EXIT_NUMERIC_POISON
 from sagemaker_xgboost_container_tpu.data.matrix import DataMatrix
 from sagemaker_xgboost_container_tpu.models import train
+from sagemaker_xgboost_container_tpu.ops import histogram
 from sagemaker_xgboost_container_tpu.serving import lifecycle
 from sagemaker_xgboost_container_tpu.telemetry import fleet, tracing
 from sagemaker_xgboost_container_tpu.telemetry import model as model_telemetry
@@ -66,7 +67,7 @@ def _tiny_data(n=192, d=5, seed=3):
     return X, y
 
 
-def _train_tiny(rounds=4, k=1, evals=False, monitor=False, seed=3):
+def _train_tiny(rounds=4, k=1, evals=False, monitor=False, seed=3, hist_knobs=None):
     X, y = _tiny_data(seed=seed)
     Xv, yv = _tiny_data(n=64, seed=seed + 1)
     kwargs = {}
@@ -87,6 +88,7 @@ def _train_tiny(rounds=4, k=1, evals=False, monitor=False, seed=3):
         DataMatrix(X, labels=y),
         num_boost_round=rounds,
         verbose_eval=False,
+        hist_knobs=hist_knobs,
         **kwargs
     )
 
@@ -116,17 +118,19 @@ def test_gate_off_no_records_no_state(model_env, capsys):
 
 
 @pytest.mark.parametrize("k", [1, 4])
-@pytest.mark.parametrize("impl", ["per_feature", "matmul"])
+@pytest.mark.parametrize("impl", ["flat", "pallas"])
 def test_gate_does_not_change_trees(model_env, tmp_path, capsys, k, impl):
     """Arming the plane must be pure observation: the per-round stats are
     read-only reductions riding the same dispatch, so the tree stream is
     bit-identical with and without it — under both fused-dispatch shapes
     and both histogram builders."""
-    model_env.setenv("GRAFT_HIST_IMPL", impl)
-    off = _train_tiny(k=k)
+    backend = {"flat": "cpu", "pallas": "tpu"}[impl]
+    assert histogram.choose_hist_impl(backend) == impl
+    knobs = histogram.resolve_hist_knobs()._replace(backend=backend)
+    off = _train_tiny(k=k, hist_knobs=knobs)
     model_env.setenv(model_telemetry.MODEL_TELEMETRY_ENV, "1")
     model_telemetry._reset_for_tests()
-    on = _train_tiny(k=k)
+    on = _train_tiny(k=k, hist_knobs=knobs)
     out = capsys.readouterr().out
     assert len(_records(out, "training.learning")) == 4
     p_off, p_on = str(tmp_path / "off.json"), str(tmp_path / "on.json")

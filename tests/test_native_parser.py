@@ -6,6 +6,7 @@ import pytest
 from sagemaker_xgboost_container_tpu.data import native
 from sagemaker_xgboost_container_tpu.data.readers import parse_libsvm_text
 from sagemaker_xgboost_container_tpu.toolkit import exceptions as exc
+from tests.reference_fixtures import resources
 
 pytestmark = pytest.mark.skipif(
     not native.native_available(), reason="no C++ toolchain"
@@ -43,7 +44,7 @@ def test_equivalence_on_sample():
 
 
 def test_equivalence_on_abalone():
-    with open("/root/reference/test/resources/abalone/data/train/abalone.train_0") as f:
+    with open(resources() + "/abalone/data/train/abalone.train_0") as f:
         text = f.read()
     native._tried = False
     got = parse_libsvm_text(text)

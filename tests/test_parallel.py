@@ -1381,10 +1381,9 @@ def test_mesh_with_pallas_hist_matches_single_device():
     INSIDE shard_map with the data-axis psum — the v5p pod path. It must
     compose (per-device kernel, XLA collective around it) and match the
     single-device flat reference."""
-    import os
-
     from sagemaker_xgboost_container_tpu.data.matrix import DataMatrix
     from sagemaker_xgboost_container_tpu.models import train
+    from sagemaker_xgboost_container_tpu.ops.histogram import resolve_hist_knobs
     from jax.sharding import Mesh
 
     rng = np.random.RandomState(5)
@@ -1392,18 +1391,12 @@ def test_mesh_with_pallas_hist_matches_single_device():
     y = ((X[:, 0] + X[:, 1] * X[:, 2]) > 0).astype(np.float32)
     d = DataMatrix(X, labels=y)
     params = {"objective": "binary:logistic", "max_depth": 4, "eta": 0.3}
-    old = os.environ.get("GRAFT_HIST_IMPL")
-    try:
-        os.environ["GRAFT_HIST_IMPL"] = "pallas"
-        mesh = Mesh(np.array(jax.devices()), axis_names=("data",))
-        f_mesh = train(dict(params), d, num_boost_round=3, mesh=mesh)
-        os.environ["GRAFT_HIST_IMPL"] = "flat"
-        f_flat = train(dict(params), d, num_boost_round=3)
-    finally:
-        if old is None:
-            os.environ.pop("GRAFT_HIST_IMPL", None)
-        else:
-            os.environ["GRAFT_HIST_IMPL"] = old
+    mesh = Mesh(np.array(jax.devices()), axis_names=("data",))
+    f_mesh = train(
+        dict(params), d, num_boost_round=3, mesh=mesh,
+        hist_knobs=resolve_hist_knobs()._replace(backend="tpu"),  # the chip's program
+    )
+    f_flat = train(dict(params), d, num_boost_round=3)
     np.testing.assert_allclose(
         np.asarray(f_mesh.predict(X)),
         np.asarray(f_flat.predict(X)),

@@ -1,5 +1,5 @@
 """training/profiling.py unit tier (r5: shrink the covgate blind-spot list —
-the module previously ran only under scripts/dissect.py + bench.py on real
+the module previously ran only under scripts/dissect.py on real
 hardware, reporting 0% in-process coverage)."""
 
 import json
@@ -129,6 +129,7 @@ def test_compile_inside_fenced_dispatch_not_double_counted(
     timer = RoundTimer(log_every=0)
     timer.before_training(None)
     session.run_rounds()
+    session.end_turnaround()
     timer.after_iteration(None, 0, {})
     timer.after_training(None)
     out = capsys.readouterr().out
@@ -150,6 +151,7 @@ def test_compile_outside_fence_keeps_host_dispatch(monkeypatch, capsys):
     timer = RoundTimer(log_every=0)
     timer.before_training(None)
     session.run_rounds()
+    session.end_turnaround()
     fire()  # completes after the fence closed — outside host_dispatch
     timer.after_iteration(None, 0, {})
     timer.after_training(None)

@@ -2,8 +2,9 @@
 integers, the tree builder and the binned traversal give the same trees and
 margins under either, and the chooser reads a backend and a width and nothing
 else. Everything runs on the CPU with the lowering forced: through ``impl=`` /
-``route_impl=`` where the function takes it, through the session snapshot's
-``route_backend`` where it takes ``knobs``.
+``route_impl=`` where the function takes it; where it takes ``knobs``, through
+a session snapshot whose ``backend`` says ``tpu`` (the chip's program: every
+chooser reads that one field) with the width rule cut to zero for the gather.
 
 The evaluation walk: ``predict_binned_levels`` (depth-wise trees, level by
 level over the level's own node tables), ``predict_binned`` (the pointer
@@ -28,6 +29,7 @@ from sagemaker_xgboost_container_tpu.data.binning import (
     apply_cut_points,
     compute_cut_points,
 )
+from sagemaker_xgboost_container_tpu.ops import tree_build
 from sagemaker_xgboost_container_tpu.ops.histogram import resolve_hist_knobs
 from sagemaker_xgboost_container_tpu.ops.tree_build import (
     NODE_TABLE_SELECT_MAX_WIDTH,
@@ -44,8 +46,6 @@ from sagemaker_xgboost_container_tpu.ops.tree_build import (
     tree_from_packed,
 )
 
-# the backend whose chooser picks each lowering at the widths used here
-BACKEND_OF = {"dense": "tpu", "gather": "cpu"}
 # the node-table lowering each backend's chooser picks at a level's width
 BACKEND_OF_TABLE = {"tpu": "select", "cpu": "gather"}
 
@@ -133,14 +133,16 @@ def _build_feature_sharded(problem, knobs):
 @pytest.mark.parametrize(
     "build", [_build_plain, _build_feature_sharded], ids=["plain", "feature_sharded"]
 )
-def test_build_tree_identical_under_each_lowering(build):
+def test_build_tree_identical_under_each_lowering(monkeypatch, build):
     """Tree arrays and ``row_out`` bit for bit; on the feature axis the width
-    the chooser sees is the shard's own two columns."""
+    the chooser sees is the shard's own two columns. Both sides are the chip's
+    program; the gather side has the width rule cut to zero."""
     problem = _nan_problem()
+    knobs = resolve_hist_knobs()._replace(backend="tpu")
     results = {}
-    for impl, backend in BACKEND_OF.items():
-        knobs = resolve_hist_knobs()._replace(route_backend=backend)
-        assert choose_route_impl(knobs.route_backend, 2) == impl
+    for impl, max_width in (("dense", ROUTE_DENSE_MAX_WIDTH), ("gather", 0)):
+        monkeypatch.setattr(tree_build, "ROUTE_DENSE_MAX_WIDTH", max_width)
+        assert choose_route_impl(knobs.backend, 2) == impl
         results[impl] = build(problem, knobs)
     packed, row_out = results["gather"]
     assert (packed[3] < 0.5).sum() > 10  # is_leaf row: a tree with real splits
@@ -417,7 +419,7 @@ def test_chooser_reads_backend_and_width_only(monkeypatch, backend, width, want)
 
 def test_session_snapshot_holds_the_backend():
     knobs = resolve_hist_knobs()
-    assert knobs.route_backend == jax.default_backend() == "cpu"
+    assert knobs.backend == jax.default_backend() == "cpu"
 
 
 @pytest.mark.parametrize(
@@ -435,7 +437,7 @@ def test_device_runtime_line_names_the_resolved_lowering(
     from sagemaker_xgboost_container_tpu.utils import device_runtime
 
     monkeypatch.setattr(device_runtime, "enable_compile_cache", lambda: None)
-    knobs = resolve_hist_knobs()._replace(route_backend=backend)
+    knobs = resolve_hist_knobs()._replace(backend=backend)
     with caplog.at_level(logging.INFO, logger=device_runtime.__name__):
         fields = device_runtime.start_device_runtime(
             "train", knobs=knobs, route_width=width, grow_policy=grow_policy

@@ -1,4 +1,5 @@
-"""Serving tests: real HTTP server over a socket, reference-fixture models.
+"""Serving tests: real HTTP server over a socket, a model trained on the
+seeded Abalone-shaped channel (tests/reference_fixtures.py).
 
 Coverage model: reference test/unit/algorithm_mode/test_serve(_utils).py +
 the MME lifecycle from test/integration/local/test_multiple_model_endpoint.py
@@ -19,10 +20,16 @@ from sagemaker_xgboost_container_tpu.models import Forest, train
 from sagemaker_xgboost_container_tpu.serving import serve_utils
 from sagemaker_xgboost_container_tpu.serving.app import ScoringService, make_app
 from sagemaker_xgboost_container_tpu.serving.mme import make_mme_app
+from tests.reference_fixtures import (
+    REFERENCE_RESOURCES,
+    needs_reference_artifacts,
+    resources,
+)
 from tests.util_ports import free_port
 
-ABALONE_MODELS = "/root/reference/test/resources/abalone/models"
-REF_MODELS = "/root/reference/test/resources/models"
+# models pickled and saved by real xgboost: the reference's own artefacts
+ABALONE_MODELS = REFERENCE_RESOURCES + "/abalone/models"
+REF_MODELS = REFERENCE_RESOURCES + "/models"
 
 
 @pytest.fixture(scope="module")
@@ -30,7 +37,7 @@ def abalone_model_dir(tmp_path_factory):
     """Train a small abalone model into a model dir."""
     from sagemaker_xgboost_container_tpu.data.readers import get_data_matrix
 
-    dm = get_data_matrix("/root/reference/test/resources/abalone/data/train", "libsvm")
+    dm = get_data_matrix(resources() + "/abalone/data/train", "libsvm")
     forest = train(
         {"objective": "reg:squarederror", "max_depth": 4}, dm, num_boost_round=8
     )
@@ -159,6 +166,7 @@ class TestSingleModelEndpoint:
         assert len(body.decode().strip().split("\n")) == 5
 
 
+@needs_reference_artifacts
 class TestReferenceModelServing:
     """Models produced by real xgboost (pickle/UBJ/legacy binary) serve."""
 

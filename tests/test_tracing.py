@@ -4,8 +4,8 @@ Covers the tracer core (nesting, cross-thread propagation, ring bound, the
 disabled fast path), Chrome-trace export validity, the end-to-end training
 tree (round -> {collective, checkpoint, compile}), the flight-recorder dump
 on a watchdog abort (exit 79), correlation-id -> trace-id propagation
-across the serving batcher's worker thread, device-sync attribution
-(SM_TRACE_DEVICE_SYNC), and the bench result line.
+across the serving batcher's worker thread, and device-sync attribution
+(SM_TRACE_DEVICE_SYNC).
 """
 
 import json
@@ -552,48 +552,3 @@ def test_device_sync_off_still_splits_every_dispatch(monkeypatch, capsys):
         assert "host_turnaround" not in rec["phases_ms"]
         assert "callbacks" not in rec["phases_ms"]
     assert fences == []  # no fence was added
-
-
-# ------------------------------------------------------------ bench satellite
-class TestBenchResultLine:
-    def test_bench_final_line_carries_attribution(self, monkeypatch, capsys):
-        """The acceptance contract: the final JSON line has the
-        compile/host/device/collective attribution section and names the
-        device it ran on."""
-        import bench
-
-        monkeypatch.setattr(bench, "N_ROWS", 400)
-        monkeypatch.setattr(bench, "N_FEATURES", 4)
-        monkeypatch.setattr(bench, "MAX_DEPTH", 3)
-        monkeypatch.setattr(bench, "WARMUP_ROUNDS", 1)
-        monkeypatch.setattr(bench, "BENCH_ROUNDS", 2)
-        monkeypatch.setenv("BENCH_ROUNDS_PER_DISPATCH", "1")
-        monkeypatch.setenv("BENCH_MESH", "0")
-        # bench.main() arms these with os.environ.setdefault: set here, they
-        # are taken back when the test ends and do not leak into later tests
-        for knob in ("SM_TRACE_DEVICE_SYNC", "SM_DEVICE_TELEMETRY", "SM_MODEL_TELEMETRY"):
-            monkeypatch.setenv(knob, "1")
-        bench.main()
-        lines = [
-            l for l in capsys.readouterr().out.splitlines() if l.startswith("{")
-        ]
-        doc = json.loads(lines[-1])
-        attribution = doc["attribution"]
-        for key in ("compile_ms", "host_ms", "device_ms", "collective_ms"):
-            assert key in attribution, key
-            assert attribution[key] >= 0.0
-        assert attribution["host_ms"] > 0.0  # sync sampling was armed
-        assert doc["device"] == {"platform": "cpu", "kind": "cpu", "count": 8}
-
-    def test_bench_refuses_a_cpu_nobody_asked_for(self, monkeypatch, capsys):
-        """No chip and no explicit JAX_PLATFORMS=cpu: exit 2, no result line
-        (a CPU timing is never printed under the device metric's name)."""
-        import bench
-
-        monkeypatch.delenv("JAX_PLATFORMS")
-        with pytest.raises(SystemExit) as exit_info:
-            bench.main()
-        assert exit_info.value.code == 2
-        captured = capsys.readouterr()
-        assert "no accelerator" in captured.err
-        assert not [l for l in captured.out.splitlines() if l.startswith("{")]

@@ -199,6 +199,46 @@ def test_train_leaves_its_spans_in_order_and_they_cover_the_call(finished_spans)
     assert covered <= 1.001 * wall
 
 
+@pytest.mark.parametrize("way_out", ["returns", "callback_raises", "session_dropped"])
+def test_no_span_outlives_the_code_that_opened_it(way_out):
+    """`host_turnaround` runs from the end of one dispatch into the next, so
+    no ``with`` block holds it: ``train()`` closes it on every way out, and a
+    caller that drives ``run_rounds()`` itself and drops the session closes
+    it with the session. The open span would otherwise name every later
+    phase of this thread (the program-load counters' ``phase`` label)."""
+    assert spans.current_phase() == ""
+    if way_out == "returns":
+        _train()
+    elif way_out == "callback_raises":
+
+        class Raises:
+            def after_iteration(self, forest, rnd, evals_log):
+                assert spans.current_phase() == "host_turnaround/callbacks"
+                raise RuntimeError("a callback's own failure")
+
+        with pytest.raises(RuntimeError, match="a callback's own failure"):
+            _train(callbacks=[Raises()])
+    else:
+        from sagemaker_xgboost_container_tpu.models.booster import (
+            Forest,
+            TrainConfig,
+            _TrainingSession,
+        )
+
+        X, y = _data()
+        config = TrainConfig({"objective": "binary:logistic", "max_depth": 2})
+        forest = Forest(
+            objective_name=config.objective, base_score=config.base_score,
+            num_feature=X.shape[1],
+        )
+        session = _TrainingSession(config, DataMatrix(X, labels=y), [], forest)
+        session.run_rounds()
+        assert spans.current_phase() == "host_turnaround"
+        del session, forest
+        gc.collect()
+    assert spans.current_phase() == ""
+
+
 def test_train_with_no_variable_set_adds_no_fence_and_computes_no_table(monkeypatch):
     import jax
 

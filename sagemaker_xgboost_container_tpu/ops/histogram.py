@@ -14,7 +14,9 @@ Two builders, one a backend (``choose_hist_impl``, as
   bin one-hots live only in VMEM (never HBM), accumulator resident in VMEM
   across the row-block grid. bf16x2 split-precision operands (hi/lo
   decomposition of f32 grads) keep MXU rate with ~f16-mantissa accuracy,
-  accumulated in f32. Interpreted on the CPU backend (tests, rehearsals).
+  accumulated in f32. The gradient operand holds the level's 2W rows
+  (``_operand_rows``), and a padding feature gets no dot. Interpreted on the
+  CPU backend (tests, rehearsals).
 * ``flat`` (everything else, and the tests' reference): one
   ``jax.ops.segment_sum`` over n*d flattened (node, feature, bin) ids. XLA
   lowers it to a sorted scatter-add — correct everywhere, fast on CPU,
@@ -507,27 +509,59 @@ def _mxu_split_missing(B):
 # tiles)
 PALLAS_ROW_BLOCK = 512
 
-# most VMEM the virtual-node-packed accumulator of one feature group may take
-VNODE_VMEM_BYTES = 4 * 1024 * 1024
+# most partial sums (outer chunks of the row axis, each with an output slab of
+# its own, added after the kernel) a level's histogram is accumulated in. One
+# f32 cell a (node, feature, bin) over every row block of 8.8M rows is a chain
+# of 17,188 adds, and its error grows with its length and with the sum it
+# carries. At the root of higgs-d8's matrix, cell error over the root-sum-square
+# of the cell's hessians, median / p90 (.chipwork probe, one v5e, PR 31): one
+# chain 2.5e-4 / 5.6e-4, 32 chunks 1.2e-5 / 3.0e-5; W = 8: 3.5e-5 / 8.6e-5
+# against 8.9e-6 / 2.2e-5 with 4. The chunks cost the MXU nothing (44.55
+# against 44.45 ms a call), so the levels whose nodes hold the most rows get
+# the most (_row_chunks).
+HIST_ROW_CHUNKS = 32
 
 
-def _vnode_factor(W, block, d, B):
-    """Virtual-node packing factor: the MXU processes M in 128-row tiles, so
-    a [blk, 2W] @ [blk, B] dot with 2W < 128 pads M and wastes (128/2W)x the
-    FLOPs — the histogram cost of a SHALLOW level would match the deepest
-    level's. Packing v = 128//(2W) row sub-groups as disjoint virtual node
-    ranges fills the tile with real work; the v partial histograms sum after
-    the grid. Exact (pure reassociation of the sum).
+def _operand_rows(W):
+    """Rows of the kernel's gradient operand for a level of W nodes: g of
+    node w in row w, h in row W + w, padded ONCE to the bf16 operand tile (16
+    sublanes). The operand is the side the MXU streams against each latched
+    [128, 128] tile of a bin one-hot, and a level issues the rows it holds.
 
-    The VMEM accumulator grows to [2*W*v, d, B] f32, so v is also capped by
-    VNODE_VMEM_BYTES — shallow levels of WIDE matrices must not allocate
-    more VMEM than the deepest level the kernel already handles; a level
-    over the budget packs nothing (v = 1)."""
-    v = max(1, 128 // (2 * W))
-    v = min(v, max(1, VNODE_VMEM_BYTES // (2 * W * d * B * 4)))
-    while block % v or v & (v - 1):  # equal sub-groups; power of two
-        v -= 1
-    return max(1, v)
+    ms a call by the rows streamed against one latched tile (hi and lo halves
+    of the bf16x2 operand together), W = 1, padding features dotted
+    (scripts/dissect.py --hist-levels re-reads it; one v5e, jax 0.9.0, PR 31):
+
+        rows a latch             16     32     64    128    256
+        8.8M x 28, one dot      50.2   50.4   51.0   97.8  192.2  (stacked)
+        8.8M x 28, two dots     99.5   97.9   98.8  192.4         (hi | lo)
+        2.27M x 136, one dot    65.1   65.3   66.0  126.6  248.6
+
+    Linear down to 64 rows a latch, flat below: a latch costs what 64 rows
+    cost (about 67 cycles), whatever rides it. Hence ONE dot with the halves
+    stacked on the row axis (two dots are two latches: W <= 16 would cost
+    what W = 32 does), and no operand narrower than the bf16 tile: under 64
+    rows nothing more is to be had from this side of the dot."""
+    return _round_up(2 * W, 16)
+
+
+def _floor_pow2(x):
+    return 1 << (max(1, x).bit_length() - 1)
+
+
+def _chunk_cap(steps):
+    """Most row chunks a matrix of ``steps`` row blocks is cut into: a power
+    of two up to HIST_ROW_CHUNKS, and small enough that padding the rows to
+    whole chunks adds under a thirty-second of them. Every level of a tree
+    pads to this one multiple, so its transposed operands are one array."""
+    return _floor_pow2(min(HIST_ROW_CHUNKS, steps // 32))
+
+
+def _row_chunks(W, cap):
+    """Partial sums of a level of W nodes: ``cap`` over W, a power of two, so
+    that chunks x nodes, the accumulator cells a feature's bin is spread
+    over, stays what the root level has."""
+    return _floor_pow2(cap // W)
 
 
 def pallas_interpret():
@@ -557,105 +591,117 @@ def _pallas_feature_group(d, bins_dtype):
 
 
 @functools.lru_cache(maxsize=None)
-def _pallas_hist_fn(n, d_pad, fg, W, B, block, prec, interpret, split_missing, v):
+def _pallas_hist_fn(n, d, fg, W, B, block, prec, interpret, split_missing,
+                    rows, chunks):
     """Compiled pallas histogram over ROW-ON-LANES operands: (bins int
     [d_pad, n] — any integer storage dtype, widened per block in VMEM so
     u8/u16 bins move fewer HBM bytes — gh f32 [2, n], node i32 [1, n]) ->
-    (main f32 [d_pad, 2*M, Bp], miss f32 [d_pad, 2*M]) with the g rows in
-    [:M] and h in [M:], M = W*v rounded up to a sublane tile and v
-    sub-group copies each (see _vnode_factor; the caller reduces them).
+    (main f32 [chunks, d_pad, rows, Bp], miss f32 [chunks, d_pad, rows or
+    2*rows]), d_pad = d rounded up to whole groups of fg. Of the ``rows``
+    (>= 2W, see _operand_rows; the probe scripts/dissect.py --hist-levels
+    passes more) row w holds g of node w and row W + w its h; ``chunks``
+    outer slices of the row axis accumulate into slabs of their own (see
+    HIST_ROW_CHUNKS; the caller adds them), n = chunks * whole blocks.
+
+    bf16x2: the hi and lo halves of the operand ride ONE dot, stacked on the
+    row axis ([2*rows, blk] against each latched one-hot tile), and the two
+    halves of the product are added; the missing-bin product keeps both
+    halves on its lane axis for the caller to add.
 
     Every operand keeps rows on the lane axis, so the kernel has no
     lane-sparse [block, 1] blocks, no in-kernel transposes and no strided
     stores: the node and bin one-hots are sublane-broadcast compares, both
     dots contract the lane axis of both operands (the A @ B^T form the MXU
-    takes natively), and each feature's [2*M, Bp] product lands on a whole
+    takes natively), and each feature's [rows, Bp] product lands on a whole
     tile-aligned slab of the accumulator. Bp is the bin axis padded to a
     lane multiple; split_missing (see _mxu_split_missing) moves the missing
     bin out of it into the second output. Grid = (feature groups of fg
-    rows, row blocks): the accumulator block for one feature group stays
-    resident in VMEM across the row axis, so VMEM use is bounded by the
-    group size, not by the matrix width."""
+    rows, row chunks, row blocks of a chunk): the accumulator block for one
+    feature group stays resident in VMEM across the row blocks, so VMEM use
+    is bounded by the group size, not by the matrix width. The operand block
+    is a whole (fg, block) tile whatever d is, but a padding feature (only
+    the last group has any) gets neither a one-hot nor a dot."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     Bm = B - 1 if split_missing else B
     Bp = _round_up(Bm, 128)
-    Wv = W * v
-    M = _round_up(Wv, 8)
+    d_pad = _round_up(d, fg)
+    groups = d_pad // fg
+    real_in_last = d - (groups - 1) * fg   # features of the last group
+    per = n // (block * chunks)            # row blocks a chunk
+    stacked = prec == "bf16x2"
+    miss_rows = 2 * rows if stacked else rows
 
     def kernel(bins_ref, gh_ref, node_ref, out_ref, miss_ref):
-        @pl.when(pl.program_id(1) == 0)
+        @pl.when(pl.program_id(2) == 0)
         def _():
             out_ref[...] = jnp.zeros_like(out_ref)
             miss_ref[...] = jnp.zeros_like(miss_ref)
 
         node = node_ref[...]                           # [1, blk]
         dead = node >= W
-        if v > 1:
-            # row i -> virtual node range (i % v); v is a power of two
-            lane = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
-            node = node + (lane & (v - 1)) * W
-        # dead rows must stay out of EVERY range, not collide with the next
-        node = jnp.where(dead, -1, node)
-        hit = jax.lax.broadcasted_iota(jnp.int32, (M, block), 0) == node
-        A = jnp.concatenate(
-            [
-                jnp.where(hit, gh_ref[0:1, :], 0.0),
-                jnp.where(hit, gh_ref[1:2, :], 0.0),
-            ],
-            axis=0,
-        )  # [2*M, blk]
-        if prec == "bf16x2":
-            parts = _split_bf16(A)
-        else:  # "bf16": one pass, the failing control
-            parts = (A.astype(jnp.bfloat16),)
-        op_dtype = parts[0].dtype
+        # a dead row must stay out of BOTH halves, not land in h's first rows
+        g_row = jnp.where(dead, -1, node)
+        h_row = jnp.where(dead, -1, node + W)
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, block), 0)
+        A = jnp.where(
+            row == g_row, gh_ref[0:1, :],
+            jnp.where(row == h_row, gh_ref[1:2, :], 0.0),
+        )  # [rows, blk]
+        if stacked:
+            A = jnp.concatenate(_split_bf16(A), axis=0)    # [2*rows, blk]
+        else:  # "bf16": one rounded half, the failing control
+            A = A.astype(jnp.bfloat16)
         lanes = (((1,), (1,)), ((), ()))               # contract rows
 
         bw = bins_ref[...].astype(jnp.int32)           # widen in VMEM
         iota_b = jax.lax.broadcasted_iota(jnp.int32, (Bp, block), 0)
-        for f in range(fg):
-            ob = (iota_b == bw[f:f + 1, :]).astype(op_dtype)   # [Bp, blk]
-            P = sum(
-                jax.lax.dot_general(
-                    a, ob, lanes, preferred_element_type=jnp.float32
-                )
-                for a in parts
+
+        def feature(f):
+            ob = (iota_b == bw[f:f + 1, :]).astype(jnp.bfloat16)   # [Bp, blk]
+            P = jax.lax.dot_general(
+                A, ob, lanes, preferred_element_type=jnp.float32
             )
-            out_ref[f] += P
+            out_ref[0, f] += (P[:rows] + P[rows:]) if stacked else P
+
+        for f in range(real_in_last):                  # real in every group
+            feature(f)
+        if real_in_last < fg and groups > 1:
+            @pl.when(pl.program_id(0) < groups - 1)
+            def _():
+                for f in range(real_in_last, fg):
+                    feature(f)
+
         if split_missing:
-            miss = (bw == (B - 1)).astype(op_dtype)    # [fg, blk]
-            miss_ref[...] += sum(
-                jax.lax.dot_general(
-                    miss, a, lanes, preferred_element_type=jnp.float32
-                )
-                for a in parts
+            miss = (bw == (B - 1)).astype(jnp.bfloat16)    # [fg, blk]
+            miss_ref[0] += jax.lax.dot_general(
+                miss, A, lanes, preferred_element_type=jnp.float32
             )
 
     # accumulator blocks (main + the lane-padded missing-bin block) are
     # double-buffered by the pipeline; operand blocks and the per-feature
     # one-hot temporaries are small next to them
-    acc_bytes = fg * 2 * M * (Bp + 128) * 4
+    acc_bytes = fg * rows * (Bp + 128) * 4
     vmem_limit = min(2 * acc_bytes + 16 * 1024 * 1024, 100 * 1024 * 1024)
     return pl.pallas_call(
         kernel,
-        grid=(d_pad // fg, n // block),
+        grid=(groups, chunks, per),
         in_specs=[
-            pl.BlockSpec((fg, block), lambda j, i: (j, i)),
-            pl.BlockSpec((2, block), lambda j, i: (0, i)),
-            pl.BlockSpec((1, block), lambda j, i: (0, i)),
+            pl.BlockSpec((fg, block), lambda j, c, i: (j, c * per + i)),
+            pl.BlockSpec((2, block), lambda j, c, i: (0, c * per + i)),
+            pl.BlockSpec((1, block), lambda j, c, i: (0, c * per + i)),
         ],
         out_specs=[
-            pl.BlockSpec((fg, 2 * M, Bp), lambda j, i: (j, 0, 0)),
-            pl.BlockSpec((fg, 2 * M), lambda j, i: (j, 0)),
+            pl.BlockSpec((1, fg, rows, Bp), lambda j, c, i: (c, j, 0, 0)),
+            pl.BlockSpec((1, fg, miss_rows), lambda j, c, i: (c, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((d_pad, 2 * M, Bp), jnp.float32),
-            jax.ShapeDtypeStruct((d_pad, 2 * M), jnp.float32),
+            jax.ShapeDtypeStruct((chunks, d_pad, rows, Bp), jnp.float32),
+            jax.ShapeDtypeStruct((chunks, d_pad, miss_rows), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=vmem_limit,
         ),
         interpret=interpret,
@@ -682,7 +728,8 @@ def _hist_pallas(bins, grad, hess, node_local, num_nodes, num_bins,
     h = jnp.where(active, hess, 0.0)
     node = jnp.where(active, node_local, jnp.int32(W))
 
-    n_pad = _round_up(n, block)
+    cap = _chunk_cap(-(-n // block))
+    n_pad = _round_up(n, block * cap)
     fg = _pallas_feature_group(d, bins.dtype)
     d_pad = _round_up(d, fg)
     # rows onto the lane axis (see _pallas_hist_fn); the padding rows are
@@ -692,20 +739,19 @@ def _hist_pallas(bins, grad, hess, node_local, num_nodes, num_bins,
     node = jnp.pad(node, [(0, n_pad - n)], constant_values=W)
 
     split_missing = _mxu_split_missing(B)
-    # the VMEM-resident accumulator spans one feature group, not all of d
-    v = _vnode_factor(W, block, fg, B)
+    rows = _operand_rows(W)
     fn = _pallas_hist_fn(
-        n_pad, d_pad, fg, W, B, block, prec, pallas_interpret(), split_missing, v
+        n_pad, d, fg, W, B, block, prec, pallas_interpret(), split_missing,
+        rows, _row_chunks(W, cap),
     )
     main, miss = fn(bins_t, gh, node[None, :].astype(jnp.int32))
 
-    M = main.shape[1] // 2
     Bm = B - 1 if split_missing else B
-    GH = main[:d, :, :Bm]                              # [d, 2*M, Bm]
+    GH = main.sum(axis=0)[:d, :, :Bm]                  # [d, rows, Bm]
     if split_missing:
-        GH = jnp.concatenate([GH, miss[:d, :, None]], axis=2)
-
-    def _half(x):                                      # [d, M, B] -> [W, d, B]
-        return x[:, : W * v].reshape(d, v, W, B).sum(axis=1).transpose(1, 0, 2)
-
-    return _half(GH[:, :M]), _half(GH[:, M:])
+        miss = miss.sum(axis=0)[:d]
+        if miss.shape[1] != rows:                      # hi and lo halves
+            miss = miss[:, :rows] + miss[:, rows:]
+        GH = jnp.concatenate([GH, miss[:, :, None]], axis=2)
+    GH = GH.transpose(1, 0, 2)                         # [rows, d, B]
+    return GH[:W], GH[W:2 * W]

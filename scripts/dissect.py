@@ -19,8 +19,12 @@ width and bins dtype, the table beside ``ROUTE_DENSE_MAX_WIDTH``.
 ``--node-table-widths`` runs the node-table probe: both lowerings of
 ``node_table_lookup`` at each table width, the table beside
 ``NODE_TABLE_SELECT_MAX_WIDTH``, then the two traversals of one depth-8 tree
-over the same rows. Run under an external timeout, like anything that holds a
-device.
+over the same rows. ``--hist-levels`` runs the level-histogram probe: the
+Pallas kernel builder called directly at the two cells' shapes, ms a call at
+every level's node count W under the operand-row rule
+(``ops/histogram._operand_rows``), then at W = 1 with the operand padded to
+more rows: the table the rule was read from. Run under an external timeout,
+like anything that holds a device.
 """
 
 import argparse
@@ -214,6 +218,78 @@ def eval_walk_probe(n_rows, d=N_FEATURES, depth=MAX_DEPTH, max_bin=MAX_BIN):
     return out
 
 
+# the cells' train matrices (benchmark/configs): rows, features; 257 bins, u16
+HIST_PROBE_SHAPES = {"higgs-d8": (8_800_000, 28), "mslr-ndcg": (2_270_296, 136)}
+HIST_PROBE_LEVELS = (1, 2, 4, 8, 16, 32, 64)  # a depth-8 tree with subtraction
+HIST_PROBE_ROWS = (16, 32, 64, 128)           # operand rows at W = 1
+
+
+def hist_level_probe(shapes, num_bins=MAX_BIN + 1):
+    """ms a call of the level histogram kernel by node count W under the
+    shipped operand-row and chunk rules, then by operand rows at W = 1 in
+    both precisions, with the rows the MXU streams against one latched
+    one-hot tile (both halves of the split operand) and the share of the
+    MXU's peak that the issued flops make. Bins and gradients are made on
+    the device."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.peaks import PEAKS
+    from sagemaker_xgboost_container_tpu.ops import histogram as H
+
+    block, B = H.PALLAS_ROW_BLOCK, num_bins
+    split_missing = H._mxu_split_missing(B)
+    bin_lanes = -(-(B - 1 if split_missing else B) // 128) * 128
+    # no published peak for this device kind: the share is left out
+    peak = PEAKS.get(jax.devices()[0].device_kind, {}).get("flops_bf16")
+    out = []
+    for name, (n, d) in shapes.items():
+        dtype = jnp.uint8 if B <= 256 else jnp.uint16
+        fg = H._pallas_feature_group(d, dtype)
+        d_pad = -(-d // fg) * fg
+        cap = H._chunk_cap(-(-n // block))
+        n_pad = -(-n // (block * cap)) * block * cap
+        k_bins, k_gh, k_node = jax.random.split(jax.random.PRNGKey(31), 3)
+        bins = jax.jit(
+            lambda k: jnp.where(
+                (jnp.arange(d_pad) < d)[:, None],
+                jax.random.bits(k, (d_pad, n_pad), jnp.uint16) % B, 0,
+            ).astype(dtype)
+        )(k_bins)
+        gh = jax.random.normal(k_gh, (2, n_pad), jnp.float32)
+        jax.block_until_ready((bins, gh))
+
+        def call(W, rows, chunks, prec="bf16x2"):
+            node = jax.random.randint(k_node, (1, n_pad), 0, W, jnp.int32)
+            fn = jax.jit(H._pallas_hist_fn(
+                n_pad, d, fg, W, B, block, prec, H.pallas_interpret(),
+                split_missing, rows, chunks,
+            ))
+            ms = _time(fn, bins, gh, node)
+            # what the MXU is handed: for every real feature the operand's
+            # rows (both halves of bf16x2 in one dot) against a [block,
+            # bin_lanes] one-hot, two flops a multiply-add
+            streamed = rows * (2 if prec == "bf16x2" else 1)
+            flops = 2.0 * n_pad * d * streamed * bin_lanes
+            row = {
+                "shape": name, "W": W, "prec": prec, "operand_rows": rows,
+                "chunks": chunks, "streamed_rows_a_tile": streamed, "ms": ms,
+                "mxu_share_of_peak": flops / (ms * 1e-3) / peak if peak else None,
+            }
+            print(json.dumps(row), flush=True)
+            out.append(row)
+
+        for W in HIST_PROBE_LEVELS:
+            call(W, H._operand_rows(W), H._row_chunks(W, cap))
+        # the rule's table: one latched one-hot tile costs what 64 streamed
+        # rows cost; the one-pass control reaches 16 rows a tile
+        for prec in H.HIST_PRECISIONS:
+            for rows in HIST_PROBE_ROWS:
+                call(1, rows, 1, prec)
+        del bins, gh
+    return out
+
+
 def _emit(summary, out_path):
     line = json.dumps(summary)
     print(line)
@@ -236,6 +312,10 @@ def main():
     ap.add_argument(
         "--node-table-widths", nargs="*", type=int, default=None, metavar="W",
         help="run only the node-table probe (default widths: 1 to 8192 doubling)",
+    )
+    ap.add_argument(
+        "--hist-levels", action="store_true",
+        help="run only the level-histogram probe (both cells' shapes)",
     )
     ap.add_argument("--out", default=None, help="also write the JSON summary here")
     args = ap.parse_args()
@@ -269,6 +349,17 @@ def main():
                 NODE_TABLE_PROBE_ROWS,
             ),
             "eval_walk_probe": eval_walk_probe(NODE_TABLE_PROBE_ROWS),
+        }
+        _emit(summary, args.out)
+        return
+    if args.hist_levels:
+        shapes = HIST_PROBE_SHAPES
+        if os.getenv("DISSECT_ROWS"):  # a rehearsal's size, one shape
+            shapes = {"rehearsal": (N_ROWS, N_FEATURES)}
+        summary = {
+            "backend": jax.default_backend(),
+            "device_kind": jax.devices()[0].device_kind,
+            "hist_level_probe": hist_level_probe(shapes),
         }
         _emit(summary, args.out)
         return

@@ -307,35 +307,87 @@ def test_node_totals_onehot_matches_segment():
     np.testing.assert_allclose(h1, h0, rtol=1e-4, atol=1e-3)
 
 
-def test_vnode_packing_matches_flat(monkeypatch):
-    """The kernel packs v=128//(2W) row sub-groups into the MXU's M tile at
-    shallow levels (virtual node ranges, summed after the grid) — pure sum
-    reassociation, so histograms must match the flat reference at every
-    width, dead rows excluded correctly; a level over the VMEM budget packs
-    nothing and must match too."""
-    rng = np.random.RandomState(13)
-    n, d, B = 4096, 5, 129  # B = 128+1 also exercises the aligned miss dot
-    bins = jnp.asarray(rng.randint(0, B, size=(n, d)).astype(np.uint8))
-    grad = jnp.asarray(rng.randn(n).astype(np.float32))
-    hess = jnp.asarray((rng.rand(n) + 0.1).astype(np.float32))
+def _level_problem(seed, n, d, B, W, dtype=np.uint8):
+    rng = np.random.RandomState(seed)
+    return (
+        jnp.asarray(rng.randint(0, B, size=(n, d)).astype(dtype)),
+        jnp.asarray(rng.randn(n).astype(np.float32)),
+        jnp.asarray((rng.rand(n) + 0.1).astype(np.float32)),
+        jnp.asarray(rng.randint(-1, W, size=n).astype(np.int32)),  # -1: dead
+    )
 
-    def hist(W, node, impl):
-        G, H = hist_mod.level_histogram(bins, grad, hess, node, W, B, impl=impl)
-        return np.asarray(G), np.asarray(H)
 
-    fg = hist_mod._pallas_feature_group(d, np.uint8)
-    budget_as_shipped = hist_mod.VNODE_VMEM_BYTES
-    for W in (1, 2, 16, 64):
-        node = jnp.asarray(rng.randint(-1, W, size=n).astype(np.int32))
-        G0, H0 = hist(W, node, "flat")
-        for budget, packed in ((budget_as_shipped, 64 // W), (0, 1)):
-            monkeypatch.setattr(hist_mod, "VNODE_VMEM_BYTES", budget)
-            v = hist_mod._vnode_factor(W, hist_mod.PALLAS_ROW_BLOCK, fg, B)
-            assert v == packed, (W, budget, v)
-            G1, H1 = hist(W, node, "pallas")
-            msg = f"W={W} v={v}"
-            np.testing.assert_allclose(G1, G0, atol=5e-3, err_msg=msg)
-            np.testing.assert_allclose(H1, H0, atol=5e-3, err_msg=msg)
+@pytest.mark.parametrize("B", [129, 257])
+@pytest.mark.parametrize("d", [5, 28, 40])
+@pytest.mark.parametrize("W", [1, 2, 4, 8, 16, 32, 64])
+def test_operand_rows_follow_the_level(W, d, B):
+    """The kernel's gradient operand holds the level's 2W rows (g of node w
+    in row w, h in row W + w), padded once to the bf16 tile, whatever the
+    width: a short group (5), a group with a padded tail (28), a full group
+    and a padded one (40). Every level against the flat reference, dead rows
+    excluded, the aligned missing-bin dot included (B = k*128 + 1)."""
+    assert hist_mod._operand_rows(W) == max(16, 2 * W)
+    dtype = np.uint8 if B <= 256 else np.uint16
+    bins, grad, hess, node = _level_problem(13 + W, 2048, d, B, W, dtype)
+    assert (np.asarray(node) < 0).any()
+    G0, H0 = hist_mod.level_histogram(bins, grad, hess, node, W, B, impl="flat")
+    G1, H1 = hist_mod.level_histogram(bins, grad, hess, node, W, B, impl="pallas")
+    assert G1.shape == (W, d, B)
+    np.testing.assert_allclose(np.asarray(G1), np.asarray(G0), atol=5e-3)
+    np.testing.assert_allclose(np.asarray(H1), np.asarray(H0), atol=5e-3)
+
+
+@pytest.mark.parametrize("d, dtype", [(28, np.uint16), (40, np.uint8), (70, np.uint8)])
+def test_padding_features_get_no_dot(d, dtype):
+    """The operand block is a whole (fg, block) tile, but a padding feature
+    costs neither a one-hot nor a dot. Its bins are all 0, so one dot would
+    leave the node totals in bin 0 of its rows of the kernel's RAW output:
+    they must stay zero, and so must its missing-bin rows."""
+    W, B, n = 2, 129, 1024
+    bins, grad, hess, node = _level_problem(5, n, d, B, W, dtype)
+    fg = hist_mod._pallas_feature_group(d, dtype)
+    d_pad = -(-d // fg) * fg
+    assert d_pad > d
+    rows = hist_mod._operand_rows(W)
+    fn = hist_mod._pallas_hist_fn(
+        n, d, fg, W, B, hist_mod.PALLAS_ROW_BLOCK, "bf16x2", True, True, rows, 1
+    )
+    main, miss = fn(
+        jnp.pad(bins.T, [(0, d_pad - d), (0, 0)]),
+        jnp.stack([grad, hess]),
+        jnp.where(node >= 0, node, W)[None, :],
+    )
+    assert main.shape == (1, d_pad, rows, 128) and miss.shape == (1, d_pad, 2 * rows)
+    assert np.asarray(main[0, d - 1, : 2 * W, 0]).any(), "the last real feature's bin 0 is hit"
+    assert not np.asarray(main[0, d:]).any()
+    assert not np.asarray(miss[0, d:]).any()
+    # and the rows of the operand past 2W hold nothing either
+    assert not np.asarray(main[0, :, 2 * W:]).any()
+
+
+def test_row_chunks_keep_the_partial_sums(monkeypatch):
+    """A shallow level's rows are accumulated in outer chunks of the row axis,
+    each into an output slab of its own, added after the kernel: at the
+    cells' sizes 32 over W of them, as many accumulator cells a (feature,
+    bin) as the root level has. Rows that are no multiple of chunks x block
+    are padded with dead ones."""
+    assert hist_mod.HIST_ROW_CHUNKS == 32
+    cap = hist_mod._chunk_cap
+    # higgs-d8's 17,188 row blocks, mslr-ndcg's 4,435, a small job's 196, a test's 6
+    assert [cap(s) for s in (17188, 4435, 196, 63, 6)] == [32, 32, 4, 1, 1]
+    chunks = hist_mod._row_chunks
+    assert [chunks(W, 32) for W in (1, 2, 3, 4, 8, 16, 32, 64)] == [32, 16, 8, 8, 4, 2, 1, 1]
+    assert [chunks(W, 1) for W in (1, 2, 64)] == [1, 1, 1]
+
+    monkeypatch.setattr(hist_mod, "PALLAS_ROW_BLOCK", 128)
+    n, d, B = 16500, 5, 129  # 129 blocks of 128: four chunks, padded to 132 blocks
+    assert cap(-(-n // 128)) == 4 and n % (4 * 128)
+    for W in (1, 2, 8):
+        bins, grad, hess, node = _level_problem(17, n, d, B, W)
+        G0, H0 = hist_mod.level_histogram(bins, grad, hess, node, W, B, impl="flat")
+        G1, H1 = hist_mod.level_histogram(bins, grad, hess, node, W, B, impl="pallas")
+        np.testing.assert_allclose(np.asarray(G1), np.asarray(G0), atol=5e-3)
+        np.testing.assert_allclose(np.asarray(H1), np.asarray(H0), atol=5e-3)
 
 
 @pytest.mark.parametrize("impl", ["flat", "pallas"])

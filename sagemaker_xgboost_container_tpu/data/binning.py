@@ -164,6 +164,28 @@ def _sketch_impl():
     return v
 
 
+# What one call of a device kernel below may ask of the chip, and what a value
+# costs it. The sort behind the sketch needs 27 to 28 bytes of scratch a value
+# at the tightest schedule the chip's compiler finds (17.58 GB refused at
+# 16.39M x 40, 8.65 GB held at 2.27M x 136) and takes up to 76 where there is
+# room (9.96 GB at 16.39M x 8, 11.5 GB at x 16); the bin-apply's search takes
+# 28 to 30 with its float input and int32 output (7.6 GB at 8.8M x 28, 17.7 GB
+# at 16.39M x 39, more than the chip has). A matrix over the budget goes
+# through the same compiled kernel in equal blocks: of columns in the sketch,
+# of rows in the bin-apply. Both are independent along the axis they are cut
+# on, so the results are the same bits for any block size.
+DEVICE_BLOCK_BYTES = 9 << 30
+DEVICE_BYTES_PER_VALUE = 30
+
+
+def _equal_blocks(total, most):
+    """(blocks, size): the fewest equal blocks of at most ``most`` that cover
+    ``total``. Block ``b`` starts at ``min(b * size, total - size)``: the last
+    one overlaps its neighbour rather than change shape."""
+    blocks = max(1, -(-total // max(most, 1)))
+    return blocks, -(-total // blocks)
+
+
 @functools.lru_cache(maxsize=32)
 def _cut_points_kernel(max_cuts, L):
     """Jitted device-sketch kernel, cached per (max_cuts, L).
@@ -254,8 +276,10 @@ def _cut_points_kernel(max_cuts, L):
     return kernel
 
 
-def _device_cut_points(features, w, max_cuts):
-    """compute_cut_points's selection semantics as one vmapped XLA program.
+def _device_cut_points(features, w, max_cuts, blocks, block_columns):
+    """compute_cut_points's selection semantics as one vmapped XLA program,
+    run over ``blocks`` blocks of ``block_columns`` columns
+    (``compute_cut_points`` sizes them by ``DEVICE_BLOCK_BYTES``).
 
     Mirrors the _select_cuts ALGORITHM step for step: stable sort, cumulative
     weight at each distinct value's run end, evenly spaced weighted-quantile
@@ -284,13 +308,22 @@ def _device_cut_points(features, w, max_cuts):
     # scatter buffers sized so distinct[:max_cuts] is well-defined even when
     # the dataset has fewer rows than max_cuts (n=100, max_bin=256)
     L = max(n, max_cuts)
-
-    mids, counts = _cut_points_kernel(max_cuts, L)(
-        jnp.asarray(features, jnp.float32), jnp.asarray(w, jnp.float32)
-    )
-    mids = np.asarray(mids, np.float32)
-    counts = np.asarray(counts)
-    return [mids[f, : int(counts[f])].copy() for f in range(d)]
+    kernel = _cut_points_kernel(max_cuts, L)
+    wv = jnp.asarray(w, jnp.float32)
+    cuts = []
+    for b in range(blocks):
+        lo = min(b * block_columns, d - block_columns)
+        mids, counts = kernel(
+            jnp.asarray(features[:, lo : lo + block_columns], jnp.float32), wv
+        )
+        mids = np.asarray(mids, np.float32)
+        counts = np.asarray(counts)
+        # an overlapping last block repeats columns the one before gave
+        cuts += [
+            mids[f - lo, : int(counts[f - lo])].copy()
+            for f in range(len(cuts), lo + block_columns)
+        ]
+    return cuts
 
 
 def _host_bytes(*arrays):
@@ -315,10 +348,17 @@ def compute_cut_points(features, weights=None, max_bin=256):
     w = np.ones(n, dtype=np.float32) if weights is None else weights
     max_cuts = n if max_bin is None else max_bin - 1
     on_device = max_bin is not None and n > 0 and _sketch_impl() == "device"
+    blocks, block_columns = (
+        _equal_blocks(d, DEVICE_BLOCK_BYTES // DEVICE_BYTES_PER_VALUE // max(n, max_cuts))
+        if on_device
+        else (1, d)
+    )
     attributes = {
         "rows": n,
         "columns": d,
         "impl": "device" if on_device else "host",
+        "column_blocks": blocks,
+        "block_columns": block_columns,
         # the device kernel takes the float matrix and the weights; what is
         # on the device already (the approx re-sketch stages it) moves nothing
         "bytes_up": _host_bytes(features, w) if on_device else 0,
@@ -326,7 +366,7 @@ def compute_cut_points(features, weights=None, max_bin=256):
     # the span ends where the cuts are on the host (np.asarray(mids))
     with span("setup.sketch", attributes=attributes):
         if on_device:
-            return _device_cut_points(features, w, max_cuts)
+            return _device_cut_points(features, w, max_cuts, blocks, block_columns)
         cuts = []
         order = np.argsort(features, axis=0, kind="stable")
         for f in range(d):
@@ -422,10 +462,11 @@ def _device_apply(features, cut_points, max_bin, dtype):
     """apply_cut_points as one vmapped on-device searchsorted (the binning
     stage's other host loop, ~5s for 1M x 28). Cuts pad to [d, L] with +inf
     (finite values never land in the pad; +inf values clip to the feature's
-    true cut count, matching numpy searchsorted semantics)."""
+    true cut count, matching numpy searchsorted semantics). A matrix over
+    ``DEVICE_BLOCK_BYTES`` goes through in equal blocks of rows."""
     import jax.numpy as jnp
 
-    d = features.shape[1]
+    n, d = features.shape
     L = max(1, max((len(c) for c in cut_points), default=1))
     padded = np.full((d, L), np.inf, np.float32)
     counts = np.zeros(d, np.int32)
@@ -433,12 +474,15 @@ def _device_apply(features, cut_points, max_bin, dtype):
         padded[f, : len(c)] = c
         counts[f] = len(c)
 
-    out = _apply_kernel(max_bin)(
-        jnp.asarray(features, jnp.float32),
-        jnp.asarray(padded),
-        jnp.asarray(counts),
-    )
-    return np.asarray(out).astype(dtype)
+    kernel = _apply_kernel(max_bin)
+    cuts_dev, counts_dev = jnp.asarray(padded), jnp.asarray(counts)
+    blocks, rows = _equal_blocks(n, DEVICE_BLOCK_BYTES // DEVICE_BYTES_PER_VALUE // d)
+    bins = np.empty((n, d), dtype)
+    for b in range(blocks):
+        lo = min(b * rows, n - rows)
+        block = jnp.asarray(features[lo : lo + rows], jnp.float32)
+        bins[lo : lo + rows] = np.asarray(kernel(block, cuts_dev, counts_dev))
+    return bins
 
 
 def bin_matrix(dmatrix, max_bin=256, cut_points=None, exact_cap=None, name=None):

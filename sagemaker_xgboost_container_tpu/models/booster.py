@@ -552,6 +552,7 @@ class _TrainingSession:
                 name="train",
             )
         self.cuts = self.train_binned.cut_points
+        self._note_binned_shape()
         self.eval_sets = []
         for dm, name in evals:
             if dm is dtrain:
@@ -932,6 +933,27 @@ class _TrainingSession:
             "Ordered document pairs inside the query groups: the sum of the "
             "squared group sizes",
         ).set(float(np.sum(np.square(groups.astype(np.float64)))))
+
+    def _note_binned_shape(self):
+        """What the binned training matrix holds, set once: how many of its
+        cells sit in the missing bin, and how many of the histogram's cut
+        slots (``max_bin - 1`` a column, all of them built every level) the
+        sketch filled. This host's rows."""
+        from ..telemetry import REGISTRY
+
+        binned = self.train_binned
+        gauges = (
+            ("train_cells_missing", "Cells of the binned training matrix in the missing bin",
+             np.count_nonzero(binned.bins == binned.max_bin)),
+            ("train_cells_total", "Cells of the binned training matrix (rows x columns)",
+             binned.bins.size),
+            ("sketch_cuts_selected", "Cut points the sketch selected, summed over columns",
+             sum(len(c) for c in binned.cut_points)),
+            ("sketch_cut_slots", "Cut slots a level histogram carries: columns x (max_bin - 1)",
+             binned.num_col * (binned.max_bin - 1)),
+        )
+        for name, text, value in gauges:
+            REGISTRY.gauge(name, text).set(float(value))
 
     # ------------------------------------------------------------------ jit
     def _grad_hess_fn(self):

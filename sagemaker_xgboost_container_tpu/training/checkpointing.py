@@ -24,6 +24,7 @@ import threading
 import time
 import weakref
 
+from ..telemetry.spans import span
 from ..telemetry.tracing import trace_span
 from ..utils import integrity
 from ..utils.faults import fault_point
@@ -207,10 +208,12 @@ def _atomic_save(
                 pass
             raise
 
-    # tracer spans (SM_TRACE): the save and its manifest nest under the
-    # callback's `checkpoint` phase span inside the open round span, so a
-    # slow storage volume shows up as a fat checkpoint.save in the timeline
-    with trace_span("checkpoint.save", attributes={"file": final_name}):
+    # `checkpoint.write`: the write itself (serialise, digest, rename, with
+    # its retries), on whichever thread runs it; the manifest's span follows.
+    # Under SM_TRACE both nest below the callback's `checkpoint.save`, so a
+    # slow storage volume shows up as a fat checkpoint.write in the timeline
+    # (`covering`: the round record's `checkpoint` phase already holds it)
+    with span("checkpoint.write", covering=True, attributes={"file": final_name}):
         retry_transient(_attempt, site="checkpoint.save")
     if not want_manifest:
         try:
@@ -311,26 +314,29 @@ class SaveCheckpointCallBack:
         )
 
     def after_iteration(self, model, epoch, evals_log):
-        _atomic_save(
-            model,
-            self.checkpoint_dir,
-            "{}.{}".format(CHECKPOINT_FILENAME, epoch),
-            iteration=epoch,
-            fingerprint=self.fingerprint,
-            membership_log=(
-                self.membership_provider() if self.membership_provider else None
-            ),
-        )
-        self.delete_queue.put(epoch - self.max_to_keep)
-        # /status carries the last durably-saved checkpoint: the resume
-        # point an operator would restart from if they killed the job now
-        from ..telemetry import fleet
+        # `checkpoint.save`: all the training thread spends on a round's
+        # checkpoint (write, manifest, retention hand-off, status note)
+        with span("checkpoint.save", covering=True, attributes={"round": epoch}):
+            _atomic_save(
+                model,
+                self.checkpoint_dir,
+                "{}.{}".format(CHECKPOINT_FILENAME, epoch),
+                iteration=epoch,
+                fingerprint=self.fingerprint,
+                membership_log=(
+                    self.membership_provider() if self.membership_provider else None
+                ),
+            )
+            self.delete_queue.put(epoch - self.max_to_keep)
+            # /status carries the last durably-saved checkpoint: the resume
+            # point an operator would restart from if they killed the job now
+            from ..telemetry import fleet
 
-        fleet.note_status(
-            last_checkpoint={"path": self.format_path(epoch), "round": epoch}
-        )
-        if self.num_round is not None and epoch + 1 >= self.num_round:
-            self.stop()
+            fleet.note_status(
+                last_checkpoint={"path": self.format_path(epoch), "round": epoch}
+            )
+            if self.num_round is not None and epoch + 1 >= self.num_round:
+                self.stop()
         return False
 
     def after_training(self, model):

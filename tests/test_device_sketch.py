@@ -292,3 +292,87 @@ def test_approx_resketch_forces_single_round_dispatch(monkeypatch, caplog):
     session2 = _session()
     assert not session2.approx_resketch
     assert session2.rounds_per_dispatch == 4
+
+
+# ------------------------------------------------ blocks (DEVICE_BLOCK_BYTES)
+def _criteo_like_columns(n=6000):
+    """The column shapes a click log has: all missing, 77 % missing, one
+    value, three values, counts spiked at 0 with a heavy tail, all distinct."""
+    rng = np.random.RandomState(7)
+    heavy = np.floor(3.0 * (rng.rand(n) ** -0.8 - 1.0))
+    cols = [
+        np.full(n, np.nan),
+        np.where(rng.rand(n) < 0.77, np.nan, np.floor(0.4 * (rng.rand(n) ** -0.7 - 1.0))),
+        np.full(n, 7.0),
+        rng.randint(0, 3, n).astype(np.float64),
+        heavy,
+        rng.permutation(n).astype(np.float64) * 0.37,
+        np.where(rng.rand(n) < 0.45, np.nan, heavy),
+    ]
+    return np.stack(cols, axis=1).astype(np.float32)
+
+
+@pytest.mark.parametrize("weighted", [False, True])
+@pytest.mark.parametrize("block_columns", [1, 2, 3, 4, 6])
+def test_blocked_sketch_gives_the_bits_of_the_unblocked_one(
+    monkeypatch, weighted, block_columns
+):
+    """Columns are independent under the kernel's vmap: cut for cut, bit for
+    bit, a sketch run in blocks of any width (the last overlapping its
+    neighbour) is the sketch run in one."""
+    X = _criteo_like_columns()
+    n, d = X.shape
+    w = (np.random.RandomState(3).rand(n) + 0.2).astype(np.float32) if weighted else None
+    whole = _cuts(X, w, 32, "device")
+    assert [len(c) for c in whole[:4]] == [0, whole[1].size, 1, 2]
+    # a budget that holds `block_columns` columns of n rows and no more
+    monkeypatch.setattr(
+        binning, "DEVICE_BLOCK_BYTES", binning.DEVICE_BYTES_PER_VALUE * n * block_columns
+    )
+    assert binning._equal_blocks(d, block_columns)[0] > 1
+    blocked = _cuts(X, w, 32, "device")
+    assert len(blocked) == d
+    for f, (a, b) in enumerate(zip(whole, blocked)):
+        assert a.dtype == b.dtype == np.float32
+        assert a.tobytes() == b.tobytes(), (f, a, b)
+
+
+@pytest.mark.parametrize("block_rows", [1, 7, 2999, 3000, 5999])
+def test_blocked_apply_gives_the_bins_of_the_unblocked_one(monkeypatch, block_rows):
+    X = _criteo_like_columns()
+    n, d = X.shape
+    cuts = _cuts(X, None, 32, "device")
+    monkeypatch.setenv("GRAFT_SKETCH_IMPL", "device")
+    whole = binning.apply_cut_points(X, cuts, 32)
+    monkeypatch.setattr(
+        binning, "DEVICE_BLOCK_BYTES", binning.DEVICE_BYTES_PER_VALUE * d * block_rows
+    )
+    blocked = binning.apply_cut_points(X, cuts, 32)
+    assert blocked.dtype == whole.dtype == np.uint8
+    np.testing.assert_array_equal(whole, blocked)
+    monkeypatch.setenv("GRAFT_SKETCH_IMPL", "host")
+    np.testing.assert_array_equal(binning.apply_cut_points(X, cuts, 32), whole)
+    assert (whole[:, 0] == 32).all() and abs((whole[:, 1] == 32).mean() - 0.77) < 0.02
+
+
+def test_equal_blocks_cover_everything_in_one_shape():
+    for total in (1, 5, 28, 39, 136, 16387491):
+        for most in (0, 1, 3, 8, 13, 19, 39, 10**9):
+            blocks, size = binning._equal_blocks(total, most)
+            assert 1 <= size <= max(most, 1) or blocks == 1
+            starts = [min(b * size, total - size) for b in range(blocks)]
+            covered = set()
+            for s in starts:
+                assert 0 <= s and s + size <= total
+                covered.update(range(s, s + size) if total < 1000 else (s, s + size - 1))
+            if total < 1000:
+                assert covered == set(range(total))
+            assert (blocks - 1) * size < total <= blocks * size
+    # the cells the benchmark has keep one block: their programs do not move
+    cap = binning.DEVICE_BLOCK_BYTES // binning.DEVICE_BYTES_PER_VALUE
+    assert binning._equal_blocks(28, cap // 8800000) == (1, 28)
+    assert binning._equal_blocks(136, cap // 2270296) == (1, 136)
+    assert binning._equal_blocks(39, cap // 16387491) == (3, 13)
+    assert binning._equal_blocks(8800000, cap // 28)[0] == 1
+    assert binning._equal_blocks(2270296, cap // 136)[0] == 1
+    assert binning._equal_blocks(16387491, cap // 39) == (2, 8193746)

@@ -1402,3 +1402,133 @@ def test_mesh_with_pallas_hist_matches_single_device():
         np.asarray(f_flat.predict(X)),
         atol=2e-5,
     )
+
+
+# ------------------------------------------ a chip's share of a `data` mesh
+def _click_like(n, seed):
+    """Missing-heavy click-log columns (45 %, 77 %, 22 %, none missing;
+    spiked counts, a three-valued code) and a gradient near a 3 % click rate
+    that leans on whether column 0 is there."""
+    rng = np.random.RandomState(seed)
+    counts = np.floor(2.0 * (rng.rand(n, 4) ** -0.8 - 1.0))
+    X = np.concatenate([counts, rng.randint(0, 3, (n, 1)), rng.rand(n, 1) * 50], axis=1)
+    rates = np.asarray([0.45, 0.77, 0.22, 0.0, 0.12, 0.0])
+    X = np.where(rng.rand(n, 6) < rates, np.nan, X).astype(np.float32)
+    p = 1.0 / (
+        1.0 + np.exp(
+            3.4 - 1.6 * np.isnan(X[:, 0]) - 1.2 * (np.nan_to_num(X[:, 2]) > 1)
+            - 0.9 * (X[:, 4] == 2) + 0.8 * np.isnan(X[:, 1]) - 0.03 * X[:, 5]
+        )
+    )
+    y = (rng.rand(n) < p).astype(np.float32)
+    return X, (0.1 - y).astype(np.float32), np.full(n, 0.09, np.float32)
+
+
+@pytest.mark.multichip
+def test_four_row_shares_add_up_to_the_uncut_data_and_grow_its_tree(monkeypatch):
+    """What `criteo-tb-d8` leaves out on one chip, at a small size: under the
+    cuts `_merged_distributed_cuts` gives four row shares, the root and
+    level-1 histograms and node totals of the shares sum to those of the
+    uncut data (to 1e-4 of the largest entry: the histogram's gradient operand
+    is two bfloat16 halves, 2^-16 a term, and the shares sum in another order), and `build_tree` over a `data` mesh of 4 returns the
+    one-device tree, default directions included."""
+    import jax.numpy as jnp
+    from jax import shard_map
+    from jax.experimental import multihost_utils
+    from jax.sharding import PartitionSpec as P
+
+    from sagemaker_xgboost_container_tpu.data.binning import apply_cut_points
+    from sagemaker_xgboost_container_tpu.models import booster
+    from sagemaker_xgboost_container_tpu.ops.histogram import level_histogram, node_totals
+    from sagemaker_xgboost_container_tpu.ops.split import find_best_splits
+    from sagemaker_xgboost_container_tpu.ops.tree_build import (
+        build_tree, pack_tree, unpack_tree,
+    )
+
+    shares, max_bin = 4, 32
+    n = shares * 1024
+    X, grad, hess = _click_like(n, seed=5)
+    parts = [slice(s * n // shares, (s + 1) * n // shares) for s in range(shares)]
+
+    # every process's candidates, as the allgather would bring them
+    gathered = {}
+    monkeypatch.setattr(
+        multihost_utils, "process_allgather", lambda x: np.asarray(x)[None]
+    )
+    for part in parts:
+        booster._merged_distributed_cuts(DataMatrix(X[part]), max_bin)  # warm the shapes
+    local = []
+
+    def record(x):
+        local.append(np.asarray(x))
+        return np.asarray(x)[None]
+
+    monkeypatch.setattr(multihost_utils, "process_allgather", record)
+    for part in parts:
+        booster._merged_distributed_cuts(DataMatrix(X[part]), max_bin)
+    mats, counts = local[0::2], local[1::2]
+    monkeypatch.setattr(
+        multihost_utils,
+        "process_allgather",
+        lambda x: np.stack(mats if np.asarray(x).ndim == 2 else counts),
+    )
+    merged = [booster._merged_distributed_cuts(DataMatrix(X[part]), max_bin) for part in parts]
+    for other in merged[1:]:  # every share agrees on the cuts, to the bit
+        assert all(a.tobytes() == b.tobytes() for a, b in zip(merged[0], other))
+    cuts = merged[0]
+    assert all(len(c) <= max_bin - 1 for c in cuts)
+
+    bins = apply_cut_points(X, cuts, max_bin).astype(np.int32)
+    assert abs((bins[:, 1] == max_bin).mean() - 0.77) < 0.03
+    num_cuts = np.asarray([len(c) for c in cuts], np.int32)
+    B = max_bin + 1
+
+    def level(rows, node_local, width):
+        G, H = level_histogram(
+            jnp.asarray(bins[rows]), jnp.asarray(grad[rows]), jnp.asarray(hess[rows]),
+            jnp.asarray(node_local[rows]), width, B,
+        )
+        g, h = node_totals(
+            jnp.asarray(grad[rows]), jnp.asarray(hess[rows]), jnp.asarray(node_local[rows]), width
+        )
+        return [np.asarray(a, np.float64) for a in (G, H, g, h)]
+
+    everything = slice(0, n)
+    root = np.zeros(n, np.int32)
+    whole = level(everything, root, 1)
+    split = find_best_splits(
+        jnp.asarray(whole[0], jnp.float32), jnp.asarray(whole[1], jnp.float32),
+        jnp.asarray(num_cuts), reg_lambda=1.0, gamma=0.0, min_child_weight=1.0,
+    )
+    f, b, left = int(split["feature"][0]), int(split["bin"][0]), bool(split["default_left"][0])
+    col = bins[:, f]
+    level1 = np.where(col == max_bin, not left, col > b).astype(np.int32)
+    for node_local, width in ((root, 1), (level1, 2)):
+        want = level(everything, node_local, width)
+        got = [sum(x) for x in zip(*(level(part, node_local, width) for part in parts))]
+        for a, w in zip(got, want):
+            np.testing.assert_allclose(a, w, rtol=0, atol=1e-4 * np.abs(w).max())
+    assert want[1][:, :, max_bin].sum() > 0.2 * want[1].sum() / 6  # a live missing bin
+
+    kwargs = dict(max_depth=3, num_bins=B, reg_lambda=1.0, eta=0.1)
+    ref_tree, ref_out = jax.jit(lambda *a: build_tree(*a, **kwargs))(
+        jnp.asarray(bins), jnp.asarray(grad), jnp.asarray(hess), jnp.asarray(num_cuts)
+    )
+    mesh = Mesh(np.array(jax.devices()[:shares]), axis_names=("data",))
+
+    def build(bn, g, h, nc):
+        tree, row_out = build_tree(bn, g, h, nc, axis_name="data", n_data_shards=shares, **kwargs)
+        return pack_tree(tree), row_out
+
+    packed, row_out = jax.jit(shard_map(
+        build, mesh=mesh, in_specs=(P("data", None), P("data"), P("data"), P()),
+        out_specs=(P(), P("data")), check_vma=False,
+    ))(jnp.asarray(bins), jnp.asarray(grad), jnp.asarray(hess), jnp.asarray(num_cuts))
+    got = unpack_tree(np.asarray(packed))
+    want = {k: np.asarray(v) for k, v in ref_tree.items()}
+    for key in ("feature", "bin", "is_leaf", "default_left"):
+        np.testing.assert_array_equal(got[key], want[key], err_msg=key)
+    assert want["default_left"][~want["is_leaf"]].any()
+    assert (~want["default_left"][~want["is_leaf"]]).any()
+    np.testing.assert_allclose(got["leaf_value"], want["leaf_value"], rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(row_out), np.asarray(ref_out), rtol=1e-5, atol=1e-6)

@@ -156,3 +156,54 @@ def test_tree_growth_matches_exact_greedy(seed):
     # the induced predictions, which equal-gain ties preserve in expectation)
     mismatch = np.abs(got - want) > 1e-4
     assert mismatch.mean() < 0.02, (seed, mismatch.mean())
+
+
+@pytest.mark.parametrize("missing_rate", [0.45, 0.77])
+@pytest.mark.parametrize("missing_goes", ["left", "right"])
+def test_split_and_default_direction_on_missing_heavy_counts(missing_rate, missing_goes):
+    """Click-log columns: counts spiked at 0 with a heavy tail, NaN at 45 %
+    and 77 %, and a gradient that depends on *whether* the value is there.
+    The scan's split, and the side it sends the missing rows to, are the
+    brute-force oracle's, through the sketch and the bin-apply."""
+    rng = np.random.RandomState(int(missing_rate * 100))
+    n, max_bin = 4000, 16
+    counts = np.floor(2.0 * (rng.rand(n, 3) ** -0.8 - 1.0)).astype(np.float32)
+    X = np.where(rng.rand(n, 3) < missing_rate, np.nan, counts).astype(np.float32)
+    absent = np.isnan(X[:, 0])
+    big = np.nan_to_num(X[:, 0]) >= 2.0
+    # missing rows pull with the small values (left of the cut) or the large
+    side = absent & (missing_goes == "right") | big
+    grad = (np.where(side, -1.0, 1.0) + 0.3 * rng.randn(n)).astype(np.float32)
+    hess = (0.03 + 0.02 * rng.rand(n)).astype(np.float32)  # a 3 % click rate's
+    cuts = compute_cut_points(X, None, max_bin)
+    bins = apply_cut_points(X, cuts, max_bin).astype(np.int32)
+    assert abs((bins[:, 0] == max_bin).mean() - missing_rate) < 0.03
+    num_cuts = np.asarray([len(c) for c in cuts], np.int32)
+
+    from sagemaker_xgboost_container_tpu.ops.histogram import level_histogram
+
+    G, H = level_histogram(
+        jnp.asarray(bins), jnp.asarray(grad), jnp.asarray(hess),
+        jnp.zeros(n, jnp.int32), 1, max_bin + 1,
+    )
+    splits = find_best_splits(
+        G, H, jnp.asarray(num_cuts), reg_lambda=LAM, gamma=GAMMA, min_child_weight=MINCW,
+    )
+    got = (int(splits["feature"][0]), int(splits["bin"][0]), bool(splits["default_left"][0]))
+    g64, h64 = grad.astype(np.float64), hess.astype(np.float64)
+    best = max(
+        (_brute_best_split(bins[:, f], g64, h64, int(num_cuts[f]), max_bin) + (f,))
+        for f in range(3)
+    )
+    gain, b, missing_left, f = best
+    assert got == (f, b, missing_left) == (0, b, missing_goes == "left")
+    assert abs(float(splits["gain"][0]) - gain) < 1e-3 * abs(gain)
+    # the other placement of the same cut is clearly worse: the direction is
+    # decided by the data, not by a tie
+    present = bins[:, 0] != max_bin
+    lm = (present & (bins[:, 0] <= b)) | (~present if not missing_left else False)
+    gl, hl = g64[lm].sum(), h64[lm].sum()
+    other = 0.5 * (
+        _score(gl, hl) + _score(g64.sum() - gl, h64.sum() - hl) - _score(g64.sum(), h64.sum())
+    ) - GAMMA
+    assert other < 0.8 * gain

@@ -522,6 +522,8 @@ def smoke(rehearsal, work):
         )
     )
 
+    # how a depth-wise build reads its node tables (ops/tree_build.choose_table_impl)
+    build_table_impl = "select" if platform == "tpu" else "gather"
     # 1. fused dispatch (no checkpoint directory)
     out, text, model_dir, wall = run_trainer("train_fused", work, platform, ROUNDS_FUSED)
     fields = runtime_line(text, "train_fused")
@@ -529,6 +531,7 @@ def smoke(rehearsal, work):
         fields, "train_fused", device, platform, "train",
         route_impl="dense" if platform == "tpu" else "gather",
         route_width=NUM_FEATURES, eval_traversal="level",
+        build_table_impl=build_table_impl,
     )
     cache_dir = fields.get("compile_cache_dir")
     losses, compile_s, rounds_s = check_training(
@@ -538,10 +541,10 @@ def smoke(rehearsal, work):
         "train_fused: {} rounds, wall {:.1f}s (smoke observation), rounds "
         "{:.1f}s of which compile {:.1f}s as the child reports; "
         "validation-logloss {:.4f} -> {:.4f}; hist={} (interpreted: {}) "
-        "totals={} route={} eval_traversal={} ingest={} mesh={}".format(
+        "totals={} route={} build_table={} eval_traversal={} ingest={} mesh={}".format(
             ROUNDS_FUSED, wall, rounds_s, compile_s, losses[0], losses[-1],
             fields["hist_impl"], fields["pallas_interpret"],
-            fields["totals_impl"], fields["route_impl"],
+            fields["totals_impl"], fields["route_impl"], fields["build_table_impl"],
             fields["eval_traversal"], fields["ingest"], fields["mesh"],
         )
     )
@@ -556,6 +559,7 @@ def smoke(rehearsal, work):
     check_runtime(
         fields2, "train_checkpointed", device, platform, "train", ingest="whole",
         eval_traversal="level",
+        build_table_impl=build_table_impl,
     )
     losses2, compile2_s, rounds2_s = check_training(
         "train_checkpointed", out2, model_dir2, ROUNDS_CHECKPOINTED

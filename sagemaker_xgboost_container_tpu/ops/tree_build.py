@@ -4,7 +4,7 @@ Replaces libxgboost's depthwise hist updater. Shapes are fully static: a tree
 with ``max_depth`` grows into a padded full-binary layout of
 ``2**(max_depth+1) - 1`` node slots (children of i at 2i+1 / 2i+2), with the
 level loop unrolled in Python (max_depth is a compile-time constant), so XLA
-sees straight-line code of segment-sums, scans, and gathers — no
+sees straight-line code of segment-sums, scans, and node-table lookups — no
 data-dependent control flow (SURVEY.md §7 "static shapes" risk).
 
 Per level: histogram -> (psum over the data axis when distributed) -> split
@@ -120,11 +120,21 @@ def row_bin_lookup(bins, feat_idx, impl=None):
 #   4,096     7.53 / 8.56 / 7.52         3.17 / 3.93 / 3.17
 #   8,192     7.53 / 8.56 / 7.52         6.34 / 7.88 / 6.25   <- a tenth apart
 #
-# Up to 64 entries XLA expands the gather into a select itself (why the
-# build's lookups read 1 ns where the finished tree's 511 entries read 7);
-# from 128 it is a real gather, 8 ns a row whatever it fetches. The select
-# pass costs per lookup and not per byte up to 1,024 entries, then 3 operations
-# an entry at the VPU's rate (0.77 ns a row per 1,000 entries).
+# Up to 64 entries XLA expands the gather into a select itself; from 128 it
+# is a real gather, 8 ns a row whatever it fetches. The select pass costs per
+# lookup and not per byte up to 1,024 entries, then 3 operations an entry at
+# the VPU's rate (0.77 ns a row per 1,000 entries).
+#
+# What a depth-8 build reads at its two widest levels (the four split fields
+# and the leaf weight by one index at 128 entries, the leaf weight at 256;
+# the same probe's build read set, every variant bit-equal; PR 33), ns a row:
+#
+#   rows          six gathers    one select a field    packed word + 2 weights
+#    8,800,000      42.26            5.14                   2.56
+#   16,387,491      42.27            4.85                   2.44
+#
+# and a whole tree over 8,800,000 x 28: 998.8 / 611.0 / 581.6 ms, which is
+# why the build packs (``split_word_bin_bits``).
 NODE_TABLE_SELECT_MAX_WIDTH = 4096
 
 
@@ -151,7 +161,9 @@ def node_table_lookup(table, idx, impl):
       over the table's axis — ``row_bin_lookup``'s dense pass with the table
       broadcast over the rows, one fusion and no [n, width] intermediate.
 
-    ``impl``: a lowering by name, as ``choose_table_impl`` gives it.
+    ``impl``: a lowering by name, as ``choose_table_impl`` gives it. Read by
+    the build (each level's split word and leaf weight, by the row's node)
+    and by the evaluation walk.
     """
     if impl == "gather":
         return table[idx]
@@ -166,6 +178,41 @@ def node_table_lookup(table, idx, impl):
             jnp.sum(jnp.where(wanted, bits[None, :], 0), axis=1), jnp.float32
         )
     return jnp.sum(jnp.where(wanted, table[None, :], 0), axis=1)
+
+
+# A level's split fields share one index, so the build reads them as one int32
+# a node where they fit: feature | bin | default_left | becomes_leaf, low bits
+# last. The word stays non-negative (bit 31 clear), so shifts unpack it.
+SPLIT_WORD_BITS = 31
+
+
+def split_word_bin_bits(feature_ids, num_bins):
+    """Bits of the packed split word's bin field, or None where ``feature_ids``
+    feature ids and ``num_bins`` bins do not fit ``SPLIT_WORD_BITS`` beside the
+    two flags (both static at trace time): the fields are then read one by one."""
+    bin_bits = int(num_bins).bit_length()
+    if int(feature_ids).bit_length() + bin_bits + 2 <= SPLIT_WORD_BITS:
+        return bin_bits
+    return None
+
+
+def pack_split_word(feature, split_bin, default_left, becomes_leaf, bin_bits):
+    return (
+        (feature << (bin_bits + 2))
+        | (split_bin << 2)
+        | (default_left.astype(jnp.int32) << 1)
+        | becomes_leaf.astype(jnp.int32)
+    )
+
+
+def unpack_split_word(word, bin_bits):
+    """(feature, bin, default_left, becomes_leaf) of ``pack_split_word``."""
+    return (
+        word >> (bin_bits + 2),
+        (word >> 2) & ((1 << bin_bits) - 1),
+        (word & 2) != 0,
+        (word & 1) != 0,
+    )
 
 
 def choose_eval_traversal(grow_policy):
@@ -255,6 +302,13 @@ def build_tree(
     knobs: the session's ``ops.histogram.HistKnobs`` snapshot (trace-safety:
     the traced build must not read env; None, for direct unit-test/probe
     callers, chooses every lowering from the process's backend).
+
+    Every per-row read of a level's per-node table is ``node_table_lookup`` in
+    the lowering ``choose_table_impl(backend, 2**level)`` picks: the four
+    fields a row reads of its node's split (feature, bin, default_left,
+    becomes_leaf) as one packed word where ``split_word_bin_bits`` fits them,
+    one by one otherwise, and the leaf weight as a lookup of its own. The
+    same bits under either lowering, packed or not.
     """
     n, d = bins.shape
     reduce_scatter = hist_comm == "reduce_scatter" and axis_name is not None
@@ -269,6 +323,20 @@ def build_tree(
     route_impl = (
         choose_route_impl(knobs.backend, d) if knobs is not None else None
     )
+    table_backend = knobs.backend if knobs is not None else jax.default_backend()
+
+    def at_node(table, local_safe):
+        """The level's per-node ``table`` read by each row's own node."""
+        return node_table_lookup(
+            table, local_safe, impl=choose_table_impl(table_backend, table.shape[0])
+        )
+
+    # every id a merged split's feature field can hold: the scanned width
+    # (padded under reduce_scatter) times the feature shards
+    feature_ids = d_scan * n_data_shards if reduce_scatter else d
+    if feature_axis_name is not None:
+        feature_ids *= jax.lax.axis_size(feature_axis_name)
+    word_bin_bits = split_word_bin_bits(feature_ids, num_bins)
     # bins stay in their storage dtype (u8/u16 from binning) end to end:
     # every consumer widens inside a fused op, so no [n, d] i32 copy is ever
     # materialized in HBM and the hot-loop bin reads move half the bytes
@@ -347,7 +415,7 @@ def build_tree(
                 tree["sum_hess"] = tree["sum_hess"].at[sl].set(h_tot)
                 at_level = node_local >= 0
                 local_safe = jnp.clip(node_local, 0, width - 1)
-                row_out = jnp.where(at_level, eta * weight[local_safe], row_out)
+                row_out = jnp.where(at_level, eta * at_node(weight, local_safe), row_out)
             break
 
         with stage(STAGE_HIST):
@@ -510,6 +578,49 @@ def build_tree(
             becomes_leaf = ~can_split
             parent_leaf = becomes_leaf
 
+        # --- route rows ----------------------------------------------------
+        with stage(STAGE_ROUTE_ROWS):
+            # what each row reads of its node: one index, so one pass where
+            # the fields pack into a word
+            at_level = node_local >= 0
+            local_safe = jnp.clip(node_local, 0, width - 1)
+            fields = (
+                splits["feature"], splits["bin"], splits["default_left"], becomes_leaf
+            )
+            if word_bin_bits is None:
+                split_feat, split_bin, default_left, row_at_leaf = (
+                    at_node(field, local_safe) for field in fields
+                )
+            else:
+                split_feat, split_bin, default_left, row_at_leaf = unpack_split_word(
+                    at_node(pack_split_word(*fields, word_bin_bits), local_safe),
+                    word_bin_bits,
+                )
+            row_leafed = at_level & row_at_leaf
+            if feature_axis_name is None:
+                row_bin = row_bin_lookup(bins, split_feat, impl=route_impl)
+                is_missing = row_bin == (num_bins - 1)
+                go_right = jnp.where(is_missing, ~default_left, row_bin > split_bin)
+            else:
+                # only the shard owning a node's split feature can decide its
+                # rows; decisions psum-broadcast along the feature axis
+                owner = (split_feat // d) == feat_shard
+                local_idx = jnp.clip(split_feat - feat_shard * d, 0, d - 1)
+                row_bin = row_bin_lookup(bins, local_idx, impl=route_impl)
+                is_missing = row_bin == (num_bins - 1)
+                decision = jnp.where(is_missing, ~default_left, row_bin > split_bin)
+                go_right = (
+                    jax.lax.psum(
+                        jnp.where(owner, decision, False).astype(jnp.int32),
+                        feature_axis_name,
+                    )
+                    > 0
+                )
+            child = node_of_row * 2 + 1 + go_right.astype(jnp.int32)
+            node_of_row = jnp.where(
+                row_leafed, -1, jnp.where(at_level, child, node_of_row)
+            )
+
         with stage(STAGE_LEAF_MARGIN):
             sl = slice(first, first + width)
             tree["feature"] = tree["feature"].at[sl].set(splits["feature"])
@@ -524,43 +635,7 @@ def build_tree(
                 jnp.where(can_split, splits["gain"], 0.0)
             )
             tree["sum_hess"] = tree["sum_hess"].at[sl].set(h_tot)
-
-            at_level = node_local >= 0
-            local_safe = jnp.clip(node_local, 0, width - 1)
-            row_leafed = at_level & becomes_leaf[local_safe]
-            row_out = jnp.where(row_leafed, eta * weight[local_safe], row_out)
-
-        # --- route rows ----------------------------------------------------
-        with stage(STAGE_ROUTE_ROWS):
-            split_feat = splits["feature"][local_safe]
-            split_bin = splits["bin"][local_safe]
-            if feature_axis_name is None:
-                row_bin = row_bin_lookup(bins, split_feat, impl=route_impl)
-                is_missing = row_bin == (num_bins - 1)
-                go_right = jnp.where(
-                    is_missing, ~splits["default_left"][local_safe], row_bin > split_bin
-                )
-            else:
-                # only the shard owning a node's split feature can decide its
-                # rows; decisions psum-broadcast along the feature axis
-                owner = (split_feat // d) == feat_shard
-                local_idx = jnp.clip(split_feat - feat_shard * d, 0, d - 1)
-                row_bin = row_bin_lookup(bins, local_idx, impl=route_impl)
-                is_missing = row_bin == (num_bins - 1)
-                decision = jnp.where(
-                    is_missing, ~splits["default_left"][local_safe], row_bin > split_bin
-                )
-                go_right = (
-                    jax.lax.psum(
-                        jnp.where(owner, decision, False).astype(jnp.int32),
-                        feature_axis_name,
-                    )
-                    > 0
-                )
-            child = node_of_row * 2 + 1 + go_right.astype(jnp.int32)
-            node_of_row = jnp.where(
-                row_leafed, -1, jnp.where(at_level, child, node_of_row)
-            )
+            row_out = jnp.where(row_leafed, eta * at_node(weight, local_safe), row_out)
 
         if alive_sets is not None and level < max_depth:
             feat_sets = interaction_sets[:, splits["feature"]].T  # [W, S]
@@ -675,8 +750,9 @@ def predict_binned_levels(tree, bins, max_depth, num_bins, route_impl=None,
     above staying on it. No ``while_loop``, no reduction over the rows, no
     ``left`` / ``right``: no data-dependent control flow, so under ``vmap``
     over a stack of trees it is the same program with a leading axis. The
-    leaf value is one lookup at the end (on the chip cheaper than the build's
-    one a level: 15.6 against 18.3 ms over 2.2M rows; PERF.md section 5).
+    leaf value is one lookup at the end, where the build reads its leaf weight
+    once a level, both through ``node_table_lookup`` (15.5 ms a tree over 2.2M
+    rows on the chip; PERF.md section 6, PR 33).
     ``table_backend`` chooses each ``node_table_lookup``'s lowering through
     ``choose_table_impl`` (traced callers pass the session's
     ``HistKnobs.backend``; None reads the process's backend, for direct

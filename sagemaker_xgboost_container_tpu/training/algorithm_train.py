@@ -522,6 +522,7 @@ def train_job(
     start_device_runtime(
         "train", mesh=mesh, knobs=hist_knobs, route_width=train_dmatrix.num_col,
         grow_policy=train_cfg.get("grow_policy", "depthwise"),
+        max_depth=int(train_cfg.get("max_depth") or 6),  # TrainConfig's default
         ingest="chunked" if isinstance(train_dmatrix, BinnedMatrix) else "whole",
     )
     # r2: ranking objectives shard rows by group and survival:cox gathers

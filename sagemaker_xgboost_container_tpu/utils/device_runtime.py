@@ -68,7 +68,8 @@ def _libtpu_version():
 
 
 def start_device_runtime(
-    role, mesh=None, knobs=None, route_width=None, grow_policy=None, **facts
+    role, mesh=None, knobs=None, route_width=None, grow_policy=None, max_depth=None,
+    **facts
 ):
     """Arm the compile cache and log the ``device runtime:`` line.
 
@@ -79,7 +80,10 @@ def start_device_runtime(
     for which the line names the bin fetch's lowering (the trainer; a server
     routes no binned rows). ``grow_policy``: the trainer's, for which the line
     names how evaluation rows walk a new tree (``eval_traversal``; a server
-    builds no trees). ``facts``: what else only the caller knows about
+    builds no trees). ``max_depth``: the trainer's, for which the line names how
+    the build's rows read their level's node tables (``build_table_impl``: the
+    lowering at the widest level, ``2**max_depth`` entries; a loss-guided job
+    has lookups of its own). ``facts``: what else only the caller knows about
     the path taken (the trainer's ingest mode). Returns the logged fields.
     """
     import jax
@@ -92,11 +96,18 @@ def start_device_runtime(
         pallas_interpret,
         resolve_hist_knobs,
     )
-    from ..ops.tree_build import choose_eval_traversal, choose_route_impl
+    from ..ops.tree_build import (
+        choose_eval_traversal,
+        choose_route_impl,
+        choose_table_impl,
+    )
 
     cache_dir = enable_compile_cache()
     if knobs is None:
         knobs = resolve_hist_knobs()
+    eval_traversal = (
+        choose_eval_traversal(grow_policy) if grow_policy is not None else None
+    )
     fields = dict(device_summary())
     fields.update(
         role=role,
@@ -114,8 +125,11 @@ def start_device_runtime(
             else None
         ),
         route_width=route_width,
-        eval_traversal=(
-            choose_eval_traversal(grow_policy) if grow_policy is not None else None
+        eval_traversal=eval_traversal,
+        build_table_impl=(
+            choose_table_impl(knobs.backend, 2 ** max_depth)
+            if eval_traversal == "level" and max_depth is not None
+            else None
         ),
         sketch_impl=_sketch_impl(),
         pallas_interpret=pallas_interpret(),

@@ -19,7 +19,9 @@ width and bins dtype, the table beside ``ROUTE_DENSE_MAX_WIDTH``.
 ``--node-table-widths`` runs the node-table probe: both lowerings of
 ``node_table_lookup`` at each table width, the table beside
 ``NODE_TABLE_SELECT_MAX_WIDTH``, then the two traversals of one depth-8 tree
-over the same rows. ``--hist-levels`` runs the level-histogram probe: the
+over the same rows, then the build's own read set at its two widest levels
+(gathers, one select a field, the packed word) at the cells' train row
+counts. ``--hist-levels`` runs the level-histogram probe: the
 Pallas kernel builder called directly at the two cells' shapes, ms a call at
 every level's node count W under the operand-row rule
 (``ops/histogram._operand_rows``), then at W = 1 with the operand padded to
@@ -62,6 +64,19 @@ ROUTE_PROBE_WIDTHS = (28, 54, 136, 512, 1024, 2048)
 ROUTE_PROBE_CALLS = 8  # one per level: enqueued back to back, one wait
 
 
+def _time_a_call(fn, *args):
+    """ms a call of ``fn`` over ``ROUTE_PROBE_CALLS`` calls enqueued back to
+    back, as a round's levels are."""
+
+    def calls(*a):
+        out = None
+        for _ in range(ROUTE_PROBE_CALLS):
+            out = fn(*a)
+        return out
+
+    return _time(calls, *args) / ROUTE_PROBE_CALLS
+
+
 def route_width_probe(widths, n_rows):
     """ms per call of ``row_bin_lookup`` under each lowering, by feature width
     and bins dtype, as a level of the round program calls it (per-row feature
@@ -91,14 +106,7 @@ def route_width_probe(widths, n_rows):
                 fn = jax.jit(
                     lambda b, f, sb, impl=impl: TB.row_bin_lookup(b, f, impl=impl) > sb
                 )
-
-                def calls(b, f, sb, fn=fn):
-                    out = None
-                    for _ in range(ROUTE_PROBE_CALLS):
-                        out = fn(b, f, sb)
-                    return out
-
-                ms = _time(calls, bins, feat, split_bin) / ROUTE_PROBE_CALLS
+                ms = _time_a_call(fn, bins, feat, split_bin)
                 row[impl + "_ms"] = ms
                 row[impl + "_ns_per_row"] = ms * 1e6 / n
                 outs[impl] = fn(bins, feat, split_bin)
@@ -142,15 +150,7 @@ def node_table_probe(widths, n_rows):
                 fn = jax.jit(
                     lambda t, i, impl=impl: TB.node_table_lookup(t, i, impl=impl)
                 )
-
-                def calls(t, i, fn=fn):
-                    out = None
-                    for _ in range(ROUTE_PROBE_CALLS):
-                        out = fn(t, i)
-                    return out
-
-                ms = _time(calls, table, idx) / ROUTE_PROBE_CALLS
-                row[impl + "_ns_per_row"] = ms * 1e6 / n_rows
+                row[impl + "_ns_per_row"] = _time_a_call(fn, table, idx) * 1e6 / n_rows
                 outs[impl] = fn(table, idx)
             row["equal"] = bool(
                 jnp.array_equal(
@@ -165,6 +165,73 @@ def node_table_probe(widths, n_rows):
             row["chosen"] = TB.choose_table_impl(jax.default_backend(), width)
             print(json.dumps(row), flush=True)
             rows.append(row)
+    return rows
+
+
+# the cells' train rows whose levels 7 and 8 are real gathers (benchmark/configs)
+BUILD_READ_PROBE_ROWS = (8_800_000, 16_387_491)
+
+
+def build_read_probe(row_counts, d=N_FEATURES, num_bins=MAX_BIN + 1):
+    """ns a row of what ``build_tree`` reads of its node tables at a depth-8
+    tree's two widest levels: the four split fields and the leaf weight by one
+    index at 128 entries, the leaf weight at 256. As gathers, as one select a
+    field, and as the packed word's select beside the two weights'; every
+    variant bit-equal to the gathers."""
+    import jax
+    import jax.numpy as jnp
+
+    from sagemaker_xgboost_container_tpu.ops import tree_build as TB
+
+    bin_bits = TB.split_word_bin_bits(d, num_bins)
+    keys = jax.random.split(jax.random.PRNGKey(33), 8)
+    feature = jax.random.randint(keys[0], (128,), 0, d, jnp.int32)
+    split_bin = jax.random.randint(keys[1], (128,), 0, num_bins, jnp.int32)
+    default_left = jax.random.bernoulli(keys[2], 0.5, (128,))
+    becomes_leaf = jax.random.bernoulli(keys[3], 0.2, (128,))
+    weight7 = jax.random.normal(keys[4], (128,), jnp.float32)
+    weight8 = jax.random.normal(keys[5], (256,), jnp.float32)
+
+    def reads(idx7, idx8, variant):
+        impl = "gather" if variant == "gather" else "select"
+        fields = (feature, split_bin, default_left, becomes_leaf)
+        if variant == "packed":
+            feat, sbin, left, leaf = TB.unpack_split_word(
+                TB.node_table_lookup(
+                    TB.pack_split_word(*fields, bin_bits), idx7, impl=impl
+                ),
+                bin_bits,
+            )
+        else:
+            feat, sbin, left, leaf = (
+                TB.node_table_lookup(f, idx7, impl=impl) for f in fields
+            )
+        # consumed as the build consumes them: a node id and a margin a row
+        node = jnp.where(leaf, -1, feat * num_bins * 2 + sbin * 2 + left)
+        margin = jnp.where(
+            leaf, TB.node_table_lookup(weight7, idx7, impl=impl),
+            TB.node_table_lookup(weight8, idx8, impl=impl),
+        )
+        return node, jax.lax.bitcast_convert_type(margin, jnp.int32)
+
+    rows = []
+    for n in row_counts:
+        idx7 = jax.random.randint(keys[6], (n,), 0, 128, jnp.int32)
+        idx8 = jax.random.randint(keys[7], (n,), 0, 256, jnp.int32)
+        jax.block_until_ready((idx7, idx8))
+        row = {"rows": n, "features": d, "num_bins": num_bins, "bin_bits": bin_bits}
+        want = None
+        for variant in ("gather", "selects", "packed"):
+            fn = jax.jit(lambda a, b, variant=variant: reads(a, b, variant))
+            row[variant + "_ns_per_row"] = _time_a_call(fn, idx7, idx8) * 1e6 / n
+            got = fn(idx7, idx8)
+            want = got if want is None else want
+            row[variant + "_equal"] = all(
+                bool(jnp.array_equal(g, w)) for g, w in zip(got, want)
+            )
+        print(json.dumps(row), flush=True)
+        rows.append(row)
+        del idx7, idx8
     return rows
 
 
@@ -349,6 +416,9 @@ def main():
                 NODE_TABLE_PROBE_ROWS,
             ),
             "eval_walk_probe": eval_walk_probe(NODE_TABLE_PROBE_ROWS),
+            "build_read_probe": build_read_probe(
+                (N_ROWS,) if os.getenv("DISSECT_ROWS") else BUILD_READ_PROBE_ROWS
+            ),
         }
         _emit(summary, args.out)
         return

@@ -1,0 +1,120 @@
+"""The tree build compiled for the chip, without the chip.
+
+The TPU's compiler is installed beside the CPU's and compiles for a v5e that
+is described and not attached, so what it makes of ``build_tree`` can be read
+here: the per-row reads of a level's node tables have to stay one
+compare-select-reduce fusion each. A ``[rows, table width]`` intermediate
+would not fit the chip at a cell's size (16,387,491 rows x 256 entries of
+``int32`` are 16.8 GB), and a row-length gather is the serialised read that
+cost 8 ns a row and lookup (PERF.md section 6, PR 33). Nothing runs, so
+nothing here is a time.
+
+Every test of this file describes the topology through the fixture below, in
+the test's own process: only one process at a time may load the TPU's library,
+and this file is the only one that does.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from sagemaker_xgboost_container_tpu.ops.histogram import resolve_hist_knobs
+from sagemaker_xgboost_container_tpu.ops.tree_build import build_tree, pack_tree
+
+ROWS, FEATURES, NUM_BINS, DEPTH = 2_000_000, 39, 257, 8
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip("no v5e:2x2 topology can be described here: {}".format(e))
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A program compiled for a described chip cannot be read back from the
+    persistent cache without one: keep it out."""
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+_COMPUTATION = r"\n(?=(?:ENTRY |%?[\w.\-]+ \([^\n]*\) -> [^\n]*\{))"
+_INSTRUCTION = r"\s+(?:ROOT )?%?[\w.\-]+ = (\(?\S.*?) ([\w\-]+)\("
+_ARRAY = r"\b(pred|[suf]\d+|bf16)\[([0-9,]*)\]"
+
+
+def _results(hlo, fused):
+    """(opcode, dtype, dims) of every instruction's results: inside the fused
+    computations (what a fusion computes on the way), or outside them (the
+    buffers the program holds between its fusions). Reducers are neither."""
+    out = []
+    for comp in re.split(_COMPUTATION, hlo):
+        head = comp.split("\n", 1)[0].lstrip("%")
+        if head.startswith("region") or head.startswith("fused_computation") != fused:
+            continue
+        for line in comp.split("\n"):
+            m = re.match(_INSTRUCTION, line)
+            if not m:
+                continue
+            for dtype, dims in re.findall(_ARRAY, m.group(1)):
+                out.append((m.group(2), dtype, tuple(int(x) for x in dims.split(",") if x)))
+    return out
+
+
+@pytest.mark.parametrize("classes", [0, 3], ids=["one_tree", "class_vmap"])
+def test_build_tree_holds_no_rows_by_width_buffer_and_no_row_gather(
+    one_chip, no_compile_cache, classes
+):
+    knobs = resolve_hist_knobs()._replace(backend="tpu")
+
+    def build(bins, grad, hess, num_cuts):
+        def one(g, h):
+            tree, row_out = build_tree(
+                bins, g, h, num_cuts, DEPTH, NUM_BINS, eta=0.1, knobs=knobs
+            )
+            return pack_tree(tree), row_out
+
+        return jax.vmap(one)(grad, hess) if classes else one(grad, hess)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    per_row = (classes, ROWS) if classes else (ROWS,)
+    compiled = (
+        jax.jit(build)
+        .lower(
+            shape((ROWS, FEATURES), jnp.uint16),
+            shape(per_row, jnp.float32),
+            shape(per_row, jnp.float32),
+            shape((FEATURES,), jnp.int32),
+        )
+        .compile()
+    )
+    hlo = compiled.as_text()
+    held = _results(hlo, fused=False)
+    assert any(op == "fusion" and ROWS in dims for op, _dtype, dims in held)
+    # the bins and the histogram's padded copies of them are the widest
+    # buffers a build may hold: rows x features of u16 and a few per-row
+    # vectors a class, never rows x the 128 or 256 entries of a node table
+    widest = max(1, classes) * 4 * ROWS
+    for op, dtype, dims in held:
+        elements = 1
+        for x in dims:
+            elements *= x
+        if dtype != "u16" and elements > widest:
+            assert max(dims) < ROWS, (op, dtype, dims)
+    # every gather left, fused or not, is the split scan's: a node long
+    gathers = [
+        dims for op, _dtype, dims in held + _results(hlo, fused=True) if op == "gather"
+    ]
+    assert gathers and max(max(dims, default=1) for dims in gathers) <= 2**DEPTH, gathers

@@ -14,7 +14,7 @@ the walk its ``grow_policy`` names.
 """
 
 import functools
-
+import itertools
 import json
 import logging
 import os
@@ -39,11 +39,14 @@ from sagemaker_xgboost_container_tpu.ops.tree_build import (
     choose_route_impl,
     choose_table_impl,
     node_table_lookup,
+    pack_split_word,
     pack_tree,
     predict_binned,
     predict_binned_levels,
     row_bin_lookup,
+    split_word_bin_bits,
     tree_from_packed,
+    unpack_split_word,
 )
 
 # the node-table lowering each backend's chooser picks at a level's width
@@ -87,13 +90,14 @@ def _nan_problem(n=3000, d=8, max_bin=32, seed=5):
     return bins, grad, hess, num_cuts, max_bin + 1
 
 
-def _build_plain(problem, knobs):
+def _build_plain(problem, knobs, depth=5, **growth):
     bins, grad, hess, num_cuts, num_bins = problem
 
     @jax.jit
     def build(b, g, h, nc):
         tree, row_out = build_tree(
-            b, g, h, nc, max_depth=5, num_bins=num_bins, eta=0.3, knobs=knobs
+            b, g, h, nc, max_depth=depth, num_bins=num_bins, eta=0.3, knobs=knobs,
+            **growth
         )
         return pack_tree(tree), row_out
 
@@ -103,14 +107,14 @@ def _build_plain(problem, knobs):
     return np.asarray(packed), np.asarray(row_out)
 
 
-def _build_feature_sharded(problem, knobs):
+def _build_feature_sharded(problem, knobs, depth=5, **growth):
     bins, grad, hess, num_cuts, num_bins = problem
     mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 4), axis_names=("data", "feature"))
 
     def build(b, g, h, nc):
         tree, row_out = build_tree(
-            b, g, h, nc, max_depth=5, num_bins=num_bins, eta=0.3, knobs=knobs,
-            axis_name="data", feature_axis_name="feature",
+            b, g, h, nc, max_depth=depth, num_bins=num_bins, eta=0.3, knobs=knobs,
+            axis_name="data", feature_axis_name="feature", **growth
         )
         return pack_tree(tree), row_out
 
@@ -129,25 +133,133 @@ def _build_feature_sharded(problem, knobs):
     return np.asarray(packed), np.asarray(row_out)
 
 
+def _build_class_stack(problem, knobs, depth=5, **growth):
+    """Three one-vs-rest trees side by side, as the booster's class ``vmap``
+    builds them: every node table gains a leading axis."""
+    bins, _grad, hess, num_cuts, num_bins = problem
+    classes = np.digitize(bins[:, 0], [8, 20])
+    # seeded noise on the gradients: something to split on down to the last level
+    noise = np.random.RandomState(3).randn(3, bins.shape[0]) * 0.3
+    grads = np.stack([0.3 - (classes == c) for c in range(3)]) + noise
+
+    @jax.jit
+    def build(b, g, h, nc):
+        def one(gc):
+            tree, row_out = build_tree(
+                b, gc, h, nc, max_depth=depth, num_bins=num_bins, eta=0.3, knobs=knobs,
+                **growth
+            )
+            return pack_tree(tree), row_out
+
+        return jax.vmap(one)(g)
+
+    packed, row_out = build(
+        jnp.asarray(bins), jnp.asarray(grads, jnp.float32), jnp.asarray(hess),
+        jnp.asarray(num_cuts),
+    )
+    return np.asarray(packed), np.asarray(row_out)
+
+
+# the rule each axis cuts to give the other lowering, the chooser that reads it
+# (with the width the assertion asks about), its two answers, and the cut: zero,
+# or for the word one bit less than `_nan_problem`'s fields need (4 + 6 + 2), so
+# that the four fields are read one by one
+LOWERING_AXES = {
+    "route": (
+        "ROUTE_DENSE_MAX_WIDTH", lambda k: choose_route_impl(k.backend, 2),
+        "dense", "gather", 0,
+    ),
+    "table": (
+        "NODE_TABLE_SELECT_MAX_WIDTH", lambda k: choose_table_impl(k.backend, 256),
+        "select", "gather", 0,
+    ),
+    "word": ("SPLIT_WORD_BITS", lambda k: split_word_bin_bits(8, 33), 6, None, 11),
+}
+DEEP = {"depth": 8}  # levels 7 and 8: the 128- and 256-entry tables
+# a hessian floor of 12 rows: branches stop at levels 1-6, four nodes split at level 7
+EARLY_LEAVES = {"depth": 8, "min_child_weight": 3.0}
+
+
 @pytest.mark.multichip
 @pytest.mark.parametrize(
-    "build", [_build_plain, _build_feature_sharded], ids=["plain", "feature_sharded"]
+    "axis,build,growth",
+    [
+        pytest.param("route", _build_plain, {}, id="plain"),
+        pytest.param("route", _build_feature_sharded, {}, id="feature_sharded"),
+        pytest.param("table", _build_plain, DEEP, id="table-plain"),
+        pytest.param("table", _build_feature_sharded, DEEP, id="table-feature_sharded"),
+        pytest.param("table", _build_class_stack, DEEP, id="table-class_stack"),
+        pytest.param("table", _build_plain, EARLY_LEAVES, id="table-early_leaves"),
+        pytest.param("word", _build_plain, DEEP, id="word-plain"),
+        pytest.param("word", _build_feature_sharded, DEEP, id="word-feature_sharded"),
+        pytest.param("word", _build_class_stack, EARLY_LEAVES, id="word-class_stack"),
+    ],
 )
-def test_build_tree_identical_under_each_lowering(monkeypatch, build):
+def test_build_tree_identical_under_each_lowering(monkeypatch, axis, build, growth):
     """Tree arrays and ``row_out`` bit for bit; on the feature axis the width
-    the chooser sees is the shard's own two columns. Both sides are the chip's
-    program; the gather side has the width rule cut to zero."""
-    problem = _nan_problem()
+    the route chooser sees is the shard's own two columns. Both sides are the
+    chip's program; the other side has the axis's rule cut to zero (the word's
+    to one bit short). The deep cases read the 128- and 256-entry levels."""
+    deep = growth.get("depth") == 8
+    problem = _nan_problem(n=9000) if deep else _nan_problem()
     knobs = resolve_hist_knobs()._replace(backend="tpu")
+    rule, chosen, ours, other, cut = LOWERING_AXES[axis]
     results = {}
-    for impl, max_width in (("dense", ROUTE_DENSE_MAX_WIDTH), ("gather", 0)):
-        monkeypatch.setattr(tree_build, "ROUTE_DENSE_MAX_WIDTH", max_width)
-        assert choose_route_impl(knobs.backend, 2) == impl
-        results[impl] = build(problem, knobs)
-    packed, row_out = results["gather"]
-    assert (packed[3] < 0.5).sum() > 10  # is_leaf row: a tree with real splits
-    np.testing.assert_array_equal(results["dense"][0], packed)
-    np.testing.assert_array_equal(results["dense"][1], row_out)
+    for impl, limit in ((ours, getattr(tree_build, rule)), (other, cut)):
+        monkeypatch.setattr(tree_build, rule, limit)
+        assert chosen(knobs) == impl
+        results[impl] = build(problem, knobs, **growth)
+    packed, row_out = results[other]
+    split = packed[..., 3, :] < 0.5  # is_leaf row: a tree with real splits
+    assert split.sum() > 10
+    if deep:
+        reached = packed[..., 7, :] > 0  # sum_hess
+        assert (split & reached)[..., 127:255].any()  # level 7 splits, so level 8 is read
+        if "min_child_weight" in growth:
+            # rows that finish above level 7, beside branches that go on
+            assert (~split & reached)[..., 1:127].any()
+    np.testing.assert_array_equal(results[ours][0], packed)
+    np.testing.assert_array_equal(results[ours][1], row_out)
+
+
+@pytest.mark.parametrize(
+    "feature_ids,num_bins,want",
+    [
+        (28, 257, 9),    # higgs-d8: 5 + 9 + 2 bits
+        (136, 257, 9),   # mslr-ndcg: 8 + 9 + 2
+        (39, 257, 9),    # criteo-tb-d8: 6 + 9 + 2
+        ((1 << 19) - 1, 1023, 10),     # 19 + 10 + 2: the widest that fits
+        (1 << 19, 1023, None),         # 20 + 10 + 2
+        ((1 << 19) - 1, 1024, None),   # 19 + 11 + 2
+    ],
+)
+def test_split_word_fits_31_bits_or_is_not_packed(feature_ids, num_bins, want):
+    assert tree_build.SPLIT_WORD_BITS == 31
+    assert split_word_bin_bits(feature_ids, num_bins) == want
+
+
+@pytest.mark.parametrize(
+    "feature_ids,num_bins", [(1, 2), (28, 257), (4095, 65535), ((1 << 19) - 1, 1023)]
+)
+def test_split_word_round_trips_the_extreme_values(feature_ids, num_bins):
+    bin_bits = split_word_bin_bits(feature_ids, num_bins)
+    feature, split_bin, default_left, becomes_leaf = (
+        np.asarray(v)
+        for v in zip(
+            *itertools.product(
+                (0, feature_ids - 1), (0, num_bins - 1), (False, True), (False, True)
+            )
+        )
+    )
+    word = pack_split_word(
+        jnp.asarray(feature, jnp.int32), jnp.asarray(split_bin, jnp.int32),
+        jnp.asarray(default_left), jnp.asarray(becomes_leaf), bin_bits,
+    )
+    assert word.dtype == jnp.int32 and (np.asarray(word) >= 0).all()
+    got = unpack_split_word(word, bin_bits)
+    for g, w in zip(got, (feature, split_bin, default_left, becomes_leaf)):
+        assert g.dtype == (jnp.bool_ if w.dtype == bool else jnp.int32)
+        np.testing.assert_array_equal(np.asarray(g), w)
 
 
 def test_predict_binned_identical_under_each_lowering():
@@ -423,16 +535,20 @@ def test_session_snapshot_holds_the_backend():
 
 
 @pytest.mark.parametrize(
-    "backend,width,grow_policy,want",
+    "backend,width,grow_policy,max_depth,want",
     [
-        ("tpu", 28, "depthwise", ("dense", "level")),
-        ("cpu", 28, "depthwise", ("gather", "level")),
-        ("tpu", 28, "lossguide", ("dense", "pointer")),
-        ("tpu", None, None, (None, None)),  # a server: no binned rows, no trees built
+        ("tpu", 28, "depthwise", 8, ("dense", "level", "select")),
+        ("cpu", 28, "depthwise", 8, ("gather", "level", "gather")),
+        ("tpu", 28, "lossguide", 0, ("dense", "pointer", None)),
+        # a server: no binned rows, no trees built
+        ("tpu", None, None, None, (None, None, None)),
+        # the widest level a select still reads, and one level past it
+        ("tpu", 28, "depthwise", 12, ("dense", "level", "select")),
+        ("tpu", 28, "depthwise", 13, ("dense", "level", "gather")),
     ],
 )
 def test_device_runtime_line_names_the_resolved_lowering(
-    monkeypatch, caplog, backend, width, grow_policy, want
+    monkeypatch, caplog, backend, width, grow_policy, max_depth, want
 ):
     from sagemaker_xgboost_container_tpu.utils import device_runtime
 
@@ -440,10 +556,13 @@ def test_device_runtime_line_names_the_resolved_lowering(
     knobs = resolve_hist_knobs()._replace(backend=backend)
     with caplog.at_level(logging.INFO, logger=device_runtime.__name__):
         fields = device_runtime.start_device_runtime(
-            "train", knobs=knobs, route_width=width, grow_policy=grow_policy
+            "train", knobs=knobs, route_width=width, grow_policy=grow_policy,
+            max_depth=max_depth,
         )
     line = [r.getMessage() for r in caplog.records if "device runtime: " in r.getMessage()][-1]
     logged = json.loads(line.split("device runtime: ", 1)[1])
     for said in (fields, logged):
-        assert (said["route_impl"], said["eval_traversal"]) == want
+        assert (
+            said["route_impl"], said["eval_traversal"], said["build_table_impl"]
+        ) == want
         assert said["route_width"] == width

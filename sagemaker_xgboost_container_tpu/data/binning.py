@@ -22,6 +22,7 @@ Design choices:
   adjacent midpoint), matching `exact`-method fidelity on small data.
 """
 
+import concurrent.futures
 import functools
 import os
 
@@ -46,8 +47,12 @@ class BinnedMatrix:
     """
 
     def __init__(self, bins, cut_points, max_bin, labels=None, weights=None,
-                 groups=None, feature_names=None):
-        self.bins = bins                  # uint8/uint16 [n, d]; max_bin == missing
+                 groups=None, feature_names=None, shape=None):
+        # uint8/uint16 [n, d]; max_bin == missing. A training session leaves
+        # its bins on the device and hands a function that pulls them, with
+        # their ``shape``: whoever reads ``.bins`` on the host pays the trip.
+        self._bins = bins
+        self._shape = tuple(shape) if shape is not None else None
         self.cut_points = cut_points      # list of d float32 ascending arrays
         self.max_bin = int(max_bin)       # missing-bin index; num_bins = max_bin + 1
         self.labels = labels
@@ -56,12 +61,22 @@ class BinnedMatrix:
         self.feature_names = list(feature_names) if feature_names is not None else None
 
     @property
+    def bins(self):
+        if callable(self._bins):
+            self._bins = self._bins()
+        return self._bins
+
+    @property
+    def shape(self):
+        return self._shape if self._shape is not None else self._bins.shape
+
+    @property
     def num_row(self):
-        return self.bins.shape[0]
+        return self.shape[0]
 
     @property
     def num_col(self):
-        return self.bins.shape[1]
+        return self.shape[1]
 
     @property
     def num_bins(self):
@@ -276,10 +291,31 @@ def _cut_points_kernel(max_cuts, L):
     return kernel
 
 
-def _device_cut_points(features, w, max_cuts, blocks, block_columns):
+def _map_shards(fn, count):
+    """``fn(s)`` for every shard, side by side: one thread a shard, so that
+    every chip is given its work before any is waited for (a transfer, a
+    kernel and a compile all release the interpreter). One shard runs here."""
+    if count == 1:
+        return [fn(0)]
+    with concurrent.futures.ThreadPoolExecutor(max_workers=count) as pool:
+        return list(pool.map(fn, range(count)))
+
+
+def _float_block(block, device):
+    """A float32 block for a device kernel, on ``device`` (None: where jax
+    puts an array by default). What is on a device already stays there."""
+    import jax
+    import jax.numpy as jnp
+
+    if device is None or not isinstance(block, np.ndarray):
+        return jnp.asarray(block, jnp.float32)
+    return jax.device_put(np.asarray(block, np.float32), device)
+
+
+def _device_cut_points(features, w, max_cuts, blocks, block_columns, device=None):
     """compute_cut_points's selection semantics as one vmapped XLA program,
-    run over ``blocks`` blocks of ``block_columns`` columns
-    (``compute_cut_points`` sizes them by ``DEVICE_BLOCK_BYTES``).
+    run on ``device`` over ``blocks`` blocks of ``block_columns`` columns
+    (``sketch_shards`` sizes them by ``DEVICE_BLOCK_BYTES``).
 
     Mirrors the _select_cuts ALGORITHM step for step: stable sort, cumulative
     weight at each distinct value's run end, evenly spaced weighted-quantile
@@ -302,19 +338,17 @@ def _device_cut_points(features, w, max_cuts, blocks, block_columns):
     cuts. TPU has no native f64, so exact host parity would need a
     compensated scan — not worth it for a one-bin boundary shift.
     """
-    import jax.numpy as jnp
-
     n, d = features.shape
     # scatter buffers sized so distinct[:max_cuts] is well-defined even when
     # the dataset has fewer rows than max_cuts (n=100, max_bin=256)
     L = max(n, max_cuts)
     kernel = _cut_points_kernel(max_cuts, L)
-    wv = jnp.asarray(w, jnp.float32)
+    wv = _float_block(w, device)
     cuts = []
     for b in range(blocks):
         lo = min(b * block_columns, d - block_columns)
         mids, counts = kernel(
-            jnp.asarray(features[:, lo : lo + block_columns], jnp.float32), wv
+            _float_block(features[:, lo : lo + block_columns], device), wv
         )
         mids = np.asarray(mids, np.float32)
         counts = np.asarray(counts)
@@ -326,6 +360,17 @@ def _device_cut_points(features, w, max_cuts, blocks, block_columns):
     return cuts
 
 
+def _host_cut_points(features, w, max_cuts):
+    cuts = []
+    order = np.argsort(features, axis=0, kind="stable")
+    for f in range(features.shape[1]):
+        col = features[order[:, f], f]
+        colw = w[order[:, f]]
+        valid = ~np.isnan(col)
+        cuts.append(_select_cuts(col[valid], colw[valid], max_cuts))
+    return cuts
+
+
 def _host_bytes(*arrays):
     """Bytes of those arrays that are host (numpy) arrays: what handing them
     to a device kernel uploads, as float32."""
@@ -334,18 +379,48 @@ def _host_bytes(*arrays):
     )
 
 
-def compute_cut_points(features, weights=None, max_bin=256):
-    """Per-feature cut thresholds via weighted quantiles. NaN = missing.
+def merge_cut_candidates(candidate_sets, max_bin):
+    """The one rule by which row shards agree on their cuts: a column's
+    candidates from every shard, sorted and deduplicated, and where they are
+    more than ``max_bin - 1`` that many of them, evenly spaced by rank. The
+    shards are the chips of a one-process mesh, the processes of a job, or
+    both (``models/booster.py``). Deterministic, so every process that merges
+    the same sets holds the same cuts; one set is returned as it is, so one
+    device's cuts are its own sketch's."""
+    candidate_sets = list(candidate_sets)
+    if len(candidate_sets) == 1:
+        return list(candidate_sets[0])
+    width = max_bin - 1
+    merged = []
+    for column in zip(*candidate_sets):
+        cands = np.concatenate([np.asarray(c, np.float32) for c in column])
+        cands = np.unique(cands[np.isfinite(cands)])
+        if len(cands) > width:
+            picks = np.linspace(0, len(cands) - 1, width).round().astype(int)
+            cands = cands[np.unique(picks)]
+        merged.append(cands.astype(np.float32))
+    return merged
+
+
+def sketch_shards(features, weights, max_bin, devices=(None,), merge=None):
+    """Cut thresholds of a matrix held as row shards: ``features[s]`` (rows
+    of shard ``s`` x all columns; NaN = missing, a row of NaN is padding) is
+    sketched with ``weights[s]`` (None: unit weights) on ``devices[s]``, the
+    shards side by side, and ``merge`` (default ``merge_cut_candidates``)
+    makes one set of the shards' candidates. One ``setup.sketch`` span covers
+    the phase; inside it a ``setup.sketch.shard`` span a shard (attribute
+    ``shard``) and ``setup.sketch_merge``.
 
     ``max_bin=None`` selects EVERY adjacent-distinct midpoint (no quantile
     subsetting) — the candidate set and thresholds of xgboost's exact greedy
     enumeration (reference tree_method=exact, schema
-    hyperparameter_validation.py:22-24), made static-shape by binning.
+    hyperparameter_validation.py:22-24), made static-shape by binning. It
+    is no sketch and takes the matrix whole: one shard.
     """
-    n, d = features.shape
     if max_bin is not None and max_bin < 2:
         raise exc.UserError("max_bin must be at least 2")
-    w = np.ones(n, dtype=np.float32) if weights is None else weights
+    shards = len(features)
+    n, d = features[0].shape
     max_cuts = n if max_bin is None else max_bin - 1
     on_device = max_bin is not None and n > 0 and _sketch_impl() == "device"
     blocks, block_columns = (
@@ -353,28 +428,49 @@ def compute_cut_points(features, weights=None, max_bin=256):
         if on_device
         else (1, d)
     )
+    weights = [
+        np.ones(f.shape[0], dtype=np.float32) if w is None else w
+        for f, w in zip(features, weights)
+    ]
+
+    # the device kernel takes the float matrix and the weights; what is on
+    # the device already (the approx re-sketch stages it) moves nothing
+    shard_attributes = [
+        {"shard": s, "rows": f.shape[0], "bytes_up": _host_bytes(f, w) if on_device else 0}
+        for s, (f, w) in enumerate(zip(features, weights))
+    ]
+
+    def sketch(s):
+        # a part of `setup.sketch`: kept out of the round record's phases
+        with span("setup.sketch.shard", covering=True, attributes=shard_attributes[s]):
+            if on_device:
+                return _device_cut_points(
+                    features[s], weights[s], max_cuts, blocks, block_columns, devices[s]
+                )
+            return _host_cut_points(features[s], weights[s], max_cuts)
+
     attributes = {
-        "rows": n,
+        "rows": sum(f.shape[0] for f in features),
         "columns": d,
         "impl": "device" if on_device else "host",
+        "shards": shards,
         "column_blocks": blocks,
         "block_columns": block_columns,
-        # the device kernel takes the float matrix and the weights; what is
-        # on the device already (the approx re-sketch stages it) moves nothing
-        "bytes_up": _host_bytes(features, w) if on_device else 0,
+        "bytes_up": sum(a["bytes_up"] for a in shard_attributes),
     }
-    # the span ends where the cuts are on the host (np.asarray(mids))
+    # the span ends where the merged cuts are on the host
     with span("setup.sketch", attributes=attributes):
-        if on_device:
-            return _device_cut_points(features, w, max_cuts, blocks, block_columns)
-        cuts = []
-        order = np.argsort(features, axis=0, kind="stable")
-        for f in range(d):
-            col = features[order[:, f], f]
-            colw = w[order[:, f]]
-            valid = ~np.isnan(col)
-            cuts.append(_select_cuts(col[valid], colw[valid], max_cuts))
-        return cuts
+        candidates = _map_shards(sketch, shards)
+        with span("setup.sketch_merge", covering=True, attributes={"sets": shards}):
+            return (merge or functools.partial(merge_cut_candidates, max_bin=max_bin))(
+                candidates
+            )
+
+
+def compute_cut_points(features, weights=None, max_bin=256):
+    """Per-feature cut thresholds via weighted quantiles. NaN = missing. The
+    one-shard case of ``sketch_shards``."""
+    return sketch_shards([features], [weights], max_bin)
 
 
 def cuts_from_summaries(summaries, max_bin):
@@ -407,96 +503,138 @@ def cuts_from_summaries(summaries, max_bin):
     ]
 
 
-def apply_cut_points(features, cut_points, max_bin, name=None):
-    """Map float features to bin indices; NaN -> missing bin (== max_bin).
-    ``name`` says which matrix it is (``train``, an evaluation set's name)
-    on the ``setup.bin_apply`` span."""
+def bin_dtype(max_bin):
+    return np.uint8 if max_bin + 1 <= 256 else np.uint16
+
+
+def _host_apply(features, cut_points, max_bin, dtype):
     n, d = features.shape
-    dtype = np.uint8 if max_bin + 1 <= 256 else np.uint16
+    bins = np.empty((n, d), dtype=dtype)
+    for f in range(d):
+        col = features[:, f]
+        idx = np.searchsorted(cut_points[f], col, side="right")
+        idx[np.isnan(col)] = max_bin
+        bins[:, f] = idx.astype(dtype)
+    return bins
+
+
+def apply_shards(features, cut_points, max_bin, devices=(None,), name=None, to_host=False):
+    """Bin indices of a matrix held as row shards (``features[s]`` as
+    ``sketch_shards`` takes them; NaN -> the missing bin, == ``max_bin``):
+    shard ``s`` is binned on ``devices[s]`` and, under the device lowering,
+    left there, in the bin matrix's narrow dtype; the shards side by side.
+    ``to_host`` brings each back as numpy (the host lowering gives numpy
+    anyway). ``name`` says which matrix it is (``train``, an evaluation
+    set's name) on the one ``setup.bin_apply`` span that covers the phase;
+    inside it a ``setup.bin_apply.shard`` span a shard, which ends where the
+    shard's bins are ready."""
+    shards = len(features)
+    n, d = features[0].shape
+    dtype = bin_dtype(max_bin)
     on_device = n > 0 and d > 0 and _sketch_impl() == "device"
+    if on_device:
+        L = max(1, max((len(c) for c in cut_points), default=1))
+        padded = np.full((d, L), np.inf, np.float32)
+        counts = np.zeros(d, np.int32)
+        for f, c in enumerate(cut_points):
+            padded[f, : len(c)] = c
+            counts[f] = len(c)
+
+    # up: the float matrix; down: the bin indices, where asked for
+    down = d * np.dtype(dtype).itemsize if on_device and to_host else 0
+    shard_attributes = [
+        {
+            "shard": s,
+            "rows": f.shape[0],
+            "bytes_up": _host_bytes(f) if on_device else 0,
+            "bytes_down": f.shape[0] * down,
+        }
+        for s, f in enumerate(features)
+    ]
+
+    def apply(s):
+        with span("setup.bin_apply.shard", covering=True, attributes=shard_attributes[s]):
+            if not on_device:
+                return _host_apply(np.asarray(features[s]), cut_points, max_bin, dtype)
+            bins = _device_apply(features[s], padded, counts, max_bin, devices[s])
+            return np.asarray(bins) if to_host else bins.block_until_ready()
+
     attributes = {
-        "rows": n,
+        "rows": sum(f.shape[0] for f in features),
         "columns": d,
         "set": name or "",
         "impl": "device" if on_device else "host",
-        # up: the float matrix; down: the kernel's int32 bin indices, which
-        # are narrowed to ``dtype`` on the host
-        "bytes_up": _host_bytes(features) if on_device else 0,
-        "bytes_down": n * d * 4 if on_device else 0,
+        "shards": shards,
+        "bytes_up": sum(a["bytes_up"] for a in shard_attributes),
+        "bytes_down": sum(a["bytes_down"] for a in shard_attributes),
     }
-    # the span ends where the bins are on the host (np.asarray(out))
     with span("setup.bin_apply", attributes=attributes):
-        if on_device:
-            return _device_apply(features, cut_points, max_bin, dtype)
-        bins = np.empty((n, d), dtype=dtype)
-        for f in range(d):
-            col = features[:, f]
-            idx = np.searchsorted(cut_points[f], col, side="right")
-            idx[np.isnan(col)] = max_bin
-            bins[:, f] = idx.astype(dtype)
-        return bins
+        return _map_shards(apply, shards)
+
+
+def apply_cut_points(features, cut_points, max_bin, name=None):
+    """Map float features to bin indices on the host; NaN -> missing bin
+    (== max_bin). The one-shard case of ``apply_shards``."""
+    return apply_shards([features], cut_points, max_bin, name=name, to_host=True)[0]
 
 
 @functools.lru_cache(maxsize=None)
 def _apply_kernel(max_bin):
     """Jitted bin-apply kernel, cached per max_bin (hoisted like
     _cut_points_kernel — the approx re-sketch re-bins train + eval sets
-    every dispatch and must hit the jit cache, not recompile)."""
+    every dispatch and must hit the jit cache, not recompile). Its output
+    is the bin matrix's own narrow dtype, so it can stay on the device."""
     import jax
     import jax.numpy as jnp
+
+    dtype = bin_dtype(max_bin)
 
     @jax.jit
     def kernel(feats, cuts, cnts):
         cols = feats.T  # folded into the program (see _device_cut_points)
         def one(col, cf, kf):
-            idx = jnp.searchsorted(cf, col, side="right")
+            # every cut compared and counted, one fused compare-and-sum a
+            # column: the default bisection is a serial gather a step, 8 steps
+            # a value at 255 cuts (PERF.md section 6, PR 34); the same counts
+            idx = jnp.searchsorted(cf, col, side="right", method="compare_all")
             idx = jnp.minimum(idx, kf)          # +inf values -> n_cuts
-            return jnp.where(jnp.isnan(col), max_bin, idx)
+            return jnp.where(jnp.isnan(col), max_bin, idx).astype(dtype)
 
         return jax.vmap(one)(cols, cuts, cnts).T
 
     return kernel
 
 
-def _device_apply(features, cut_points, max_bin, dtype):
-    """apply_cut_points as one vmapped on-device searchsorted (the binning
-    stage's other host loop, ~5s for 1M x 28). Cuts pad to [d, L] with +inf
-    (finite values never land in the pad; +inf values clip to the feature's
-    true cut count, matching numpy searchsorted semantics). A matrix over
-    ``DEVICE_BLOCK_BYTES`` goes through in equal blocks of rows."""
+def _device_apply(features, padded, counts, max_bin, device=None):
+    """Binning as one vmapped on-device searchsorted (the binning stage's
+    other host loop, ~5s for 1M x 28), on ``device``. Cuts pad to [d, L]
+    with +inf (finite values never land in the pad; +inf values clip to the
+    feature's true cut count, matching numpy searchsorted semantics). A
+    matrix over ``DEVICE_BLOCK_BYTES`` goes through in equal blocks of rows,
+    joined on the device."""
+    import jax
     import jax.numpy as jnp
 
     n, d = features.shape
-    L = max(1, max((len(c) for c in cut_points), default=1))
-    padded = np.full((d, L), np.inf, np.float32)
-    counts = np.zeros(d, np.int32)
-    for f, c in enumerate(cut_points):
-        padded[f, : len(c)] = c
-        counts[f] = len(c)
-
     kernel = _apply_kernel(max_bin)
-    cuts_dev, counts_dev = jnp.asarray(padded), jnp.asarray(counts)
+    cuts_dev, counts_dev = jax.device_put((padded, counts), device)
     blocks, rows = _equal_blocks(n, DEVICE_BLOCK_BYTES // DEVICE_BYTES_PER_VALUE // d)
-    bins = np.empty((n, d), dtype)
+    parts = []
     for b in range(blocks):
         lo = min(b * rows, n - rows)
-        block = jnp.asarray(features[lo : lo + rows], jnp.float32)
-        bins[lo : lo + rows] = np.asarray(kernel(block, cuts_dev, counts_dev))
-    return bins
+        part = kernel(_float_block(features[lo : lo + rows], device), cuts_dev, counts_dev)
+        # an overlapping last block repeats rows the one before gave
+        parts.append(part[b * rows - lo :])
+    return parts[0] if blocks == 1 else jnp.concatenate(parts, axis=0)
 
 
-def bin_matrix(dmatrix, max_bin=256, cut_points=None, exact_cap=None, name=None):
-    """DataMatrix -> BinnedMatrix (computing cuts unless provided). ``name``
-    labels the matrix on its ``setup.bin_apply`` span.
-
-    ``max_bin=None`` = exact-greedy binning: cuts at every adjacent-distinct
-    midpoint, and the bin width sized by the data (see compute_cut_points).
-    ``exact_cap`` bounds that data-driven width: per-node histograms are
-    O(nodes x features x bins), so pathologically many distinct values must
-    fail loudly rather than exhaust HBM.
-    """
-    if cut_points is None:
-        cut_points = compute_cut_points(dmatrix.features, dmatrix.weights, max_bin)
+def resolve_max_bin(cut_points, max_bin, exact_cap=None):
+    """The bin width the cuts need: ``max_bin`` itself, or with
+    ``max_bin=None`` (exact-greedy binning: cuts at every adjacent-distinct
+    midpoint) the width sized by the data. ``exact_cap`` bounds that
+    data-driven width: per-node histograms are O(nodes x features x bins),
+    so pathologically many distinct values must fail loudly rather than
+    exhaust HBM."""
     longest = max((len(c) for c in cut_points), default=0)
     if max_bin is None:
         max_bin = longest + 1
@@ -518,6 +656,15 @@ def bin_matrix(dmatrix, max_bin=256, cut_points=None, exact_cap=None, name=None)
         raise exc.AlgorithmError(
             "cut selection produced {} cuts for max_bin {}".format(longest, max_bin)
         )
+    return max_bin
+
+
+def bin_matrix(dmatrix, max_bin=256, cut_points=None, exact_cap=None, name=None):
+    """DataMatrix -> BinnedMatrix on the host (computing cuts unless
+    provided). ``name`` labels the matrix on its ``setup.bin_apply`` span."""
+    if cut_points is None:
+        cut_points = compute_cut_points(dmatrix.features, dmatrix.weights, max_bin)
+    max_bin = resolve_max_bin(cut_points, max_bin, exact_cap)
     bins = apply_cut_points(dmatrix.features, cut_points, max_bin, name=name)
     return BinnedMatrix(
         bins,

@@ -26,6 +26,7 @@ import functools
 import logging
 import os
 import sys
+import typing
 from functools import partial
 
 import jax
@@ -33,7 +34,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..data.binning import BinnedMatrix, bin_matrix
+from ..data.binning import (
+    BinnedMatrix,
+    apply_shards,
+    merge_cut_candidates,
+    resolve_max_bin,
+    sketch_shards,
+)
 from ..ops.histogram import (
     hist_comm_impl,
     padded_feature_width,
@@ -220,44 +227,32 @@ def _predict_margin_rows(forest, dm, block_rows=1 << 16):
     return np.concatenate(parts, axis=0)
 
 
-def _merged_distributed_cuts(dtrain, max_bin, weights=None):
-    """Allgather per-host cut candidates and deterministically merge them.
-
-    Every process computes shard-local quantile cuts, gathers all hosts'
-    candidates, and re-selects <= max_bin - 1 evenly spaced thresholds from
-    the sorted union. Deterministic: identical inputs on every host yield
-    identical cuts everywhere.
-
-    weights: sketch weights overriding dtrain.weights (the approx
-    re-sketch passes current hessians).
-    """
+def _merge_cuts_across_processes(local_sets, max_bin):
+    """Every row shard of the job agrees on its cuts: this process's shards'
+    candidate sets are allgathered and all of them (processes x local shards)
+    merged by the one rule (``data/binning.py::merge_cut_candidates``).
+    Deterministic: every process merges the same sets, so identical cuts
+    everywhere. The TPU analog of xgboost's allreduced quantile sketch."""
     from jax.experimental import multihost_utils
 
-    from ..data.binning import compute_cut_points
-
-    if weights is None:
-        weights = dtrain.weights
-    local_cuts = compute_cut_points(dtrain.features, weights, max_bin)
     width = max_bin - 1
-    d = dtrain.num_col
-    mat = np.full((d, width), np.nan, np.float32)
-    counts = np.zeros(d, np.int32)
-    for f, c in enumerate(local_cuts):
+    columns = [c for cuts in local_sets for c in cuts]  # local shards x d
+    mat = np.full((len(columns), width), np.nan, np.float32)
+    counts = np.zeros(len(columns), np.int32)
+    for f, c in enumerate(columns):
         mat[f, : len(c)] = c
         counts[f] = len(c)
-    all_mats = np.asarray(multihost_utils.process_allgather(mat))       # [P, d, W]
-    all_counts = np.asarray(multihost_utils.process_allgather(counts))  # [P, d]
-    merged = []
-    for f in range(d):
-        cands = np.concatenate(
-            [all_mats[p, f, : all_counts[p, f]] for p in range(all_mats.shape[0])]
-        )
-        cands = np.unique(cands[np.isfinite(cands)])
-        if len(cands) > width:
-            picks = np.linspace(0, len(cands) - 1, width).round().astype(int)
-            cands = cands[np.unique(picks)]
-        merged.append(cands.astype(np.float32))
-    return merged
+    all_mats = np.asarray(multihost_utils.process_allgather(mat))       # [P, S*d, W]
+    all_counts = np.asarray(multihost_utils.process_allgather(counts))  # [P, S*d]
+    d = len(local_sets[0])
+    return merge_cut_candidates(
+        [
+            [all_mats[p, f, : all_counts[p, f]] for f in range(lo, lo + d)]
+            for p in range(all_mats.shape[0])
+            for lo in range(0, all_mats.shape[1], d)
+        ],
+        max_bin,
+    )
 
 
 def _apply_packed_tree(packed, bins, margins, num_group, num_parallel, depth,
@@ -405,6 +400,29 @@ def _pad_rows(array, target_rows, fill):
     return np.concatenate([array, np.full(pad_shape, fill, array.dtype)], axis=0)
 
 
+class _RowShard(typing.NamedTuple):
+    """One data shard's rows of a matrix, in this process's padded layout."""
+
+    lo: int
+    hi: int
+    device: typing.Any   # sketches and bins the shard (None: jax's default device)
+    placements: tuple    # ((device, column slice), ...): who holds which of its columns
+
+
+class _RowLayout(typing.NamedTuple):
+    """How a matrix's rows lie over the devices: this process's row shards,
+    and the sharding and global shape of the array they are assembled into
+    (sharding None: one device)."""
+
+    shards: tuple
+    sharding: typing.Any
+    shape: tuple
+
+    @property
+    def devices(self):
+        return [shard.device for shard in self.shards]
+
+
 class _TrainingSession:
     """Device state for one training run (bins, margins, jitted round fns)."""
 
@@ -511,76 +529,25 @@ class _TrainingSession:
                 self._build_rank_layout(groups, dtrain.num_row)
 
         pre_binned = isinstance(dtrain, BinnedMatrix)
-        shared_cuts = None
-        if self.is_multiprocess:
-            if config.max_bin is None:
-                # libxgboost's exact updater is likewise single-machine only
-                raise exc.UserError(
-                    "tree_method='exact' does not support distributed "
-                    "training (it doesn't in XGBoost either); use "
-                    "tree_method='hist'."
-                )
-            # every host must bin with identical thresholds or the psum'd
-            # histograms are meaningless: merge the per-host quantile sketches
-            # (allgather candidate cuts, union, re-select) — the TPU analog of
-            # xgboost's allreduced weighted quantile sketch. Pre-binned input
-            # (chunked streaming ingest) already agreed its cuts cross-rank
-            # through the ingest sketch allgather, so it skips this.
-            if not pre_binned:
-                shared_cuts = _merged_distributed_cuts(dtrain, config.max_bin)
-
-        if pre_binned:
+        if self.is_multiprocess and config.max_bin is None:
+            # libxgboost's exact updater is likewise single-machine only
+            raise exc.UserError(
+                "tree_method='exact' does not support distributed "
+                "training (it doesn't in XGBoost either); use "
+                "tree_method='hist'."
+            )
+        if pre_binned and (config.max_bin is None or int(config.max_bin) != dtrain.max_bin):
             # chunked streaming ingest: the sketch+bin stage already ran at
             # ingest time (with rank-agreed cuts); trust the matrix, but
             # fail loudly on a config/ingest max_bin drift — a silently
             # re-interpreted bin width would corrupt every histogram
-            if config.max_bin is None or int(config.max_bin) != dtrain.max_bin:
-                raise exc.UserError(
-                    "Pre-binned training data was ingested with max_bin={} "
-                    "but the training config resolves max_bin={}; re-ingest "
-                    "or align the hyperparameters.".format(
-                        dtrain.max_bin, config.max_bin
-                    )
+            raise exc.UserError(
+                "Pre-binned training data was ingested with max_bin={} "
+                "but the training config resolves max_bin={}; re-ingest "
+                "or align the hyperparameters.".format(
+                    dtrain.max_bin, config.max_bin
                 )
-            self.train_binned = dtrain
-        else:
-            self.train_binned = bin_matrix(
-                dtrain,
-                config.max_bin,
-                cut_points=shared_cuts,
-                exact_cap=config.exact_bin_cap,
-                name="train",
             )
-        self.cuts = self.train_binned.cut_points
-        self._note_binned_shape()
-        self.eval_sets = []
-        for dm, name in evals:
-            if dm is dtrain:
-                binned = self.train_binned
-            elif isinstance(dm, BinnedMatrix):
-                # pre-binned eval set: must carry the training channel's
-                # bin edges (streaming ingest bins validation with the
-                # train cuts) or its bin indices mean different thresholds
-                if dm.max_bin != self.train_binned.max_bin or not (
-                    dm.cut_points is self.cuts
-                    or (
-                        len(dm.cut_points) == len(self.cuts)
-                        and all(
-                            np.array_equal(a, b)
-                            for a, b in zip(dm.cut_points, self.cuts)
-                        )
-                    )
-                ):
-                    raise exc.AlgorithmError(
-                        "pre-binned eval set {!r} was binned with different "
-                        "cut points than the training data".format(name)
-                    )
-                binned = dm
-            else:
-                binned = bin_matrix(
-                    dm, config.max_bin, cut_points=self.cuts, name=name
-                )
-            self.eval_sets.append((name, dm, binned))
 
         def _agreed_pad(num_row):
             """Local padded row count, agreed across processes. Hosts may
@@ -619,7 +586,7 @@ class _TrainingSession:
 
         # column padding: features pad to a multiple of the feature shards
         # with always-missing columns (zero cuts -> never split on)
-        d_real = self.train_binned.num_col
+        d_real = dtrain.num_col
         d_pad = padded_feature_width(d_real, self.n_feature_shards)
         self.d_pad = d_pad
 
@@ -646,14 +613,10 @@ class _TrainingSession:
         self._put = _put
         self._layout_rows = _layout_rows
         self._d_real = d_real
-        self._stage_train_bins(
-            self.train_binned.bins, self.cuts, self.train_binned.max_bin
-        )
+        self._n_pad = n_pad
+
         # approx re-sketch state (see _resketch_bins)
-        self._dtrain = dtrain
         self._grad_fn = None
-        self._feats_dev = None       # device-staged float features (sketch)
-        self._eval_feats_dev = {}    # eval-set index -> device features
         self.approx_resketch = (
             config.tree_method == "approx"
             and os.environ.get("GRAFT_APPROX_RESKETCH", "1") != "0"
@@ -675,6 +638,86 @@ class _TrainingSession:
                 "support per-iteration re-binning)."
             )
             self.approx_resketch = False
+        # A matrix is set up shard by shard, each on the chip that will hold
+        # it (one device: one shard): sketched there, the shards' candidates
+        # merged into one set of cuts (every host must bin with identical
+        # thresholds or the psum'd histograms are meaningless — the TPU
+        # analog of xgboost's allreduced weighted quantile sketch), binned
+        # there against the merged cuts, and the bins left there. Pre-binned
+        # input (chunked streaming ingest) already agreed its cuts cross-rank
+        # through the ingest sketch allgather and is only dealt out.
+        self._dtrain = dtrain
+        self._train_rows = self._row_shards(n_pad, d_pad, self.bins_spec)
+        self._train_floats = None    # the train rows' float blocks, a shard each
+        if pre_binned:
+            cuts, max_bin = dtrain.cut_points, dtrain.max_bin
+            shard_bins = [
+                self._shard_rows(dtrain.bins, shard, max_bin, self.rank_perm)
+                for shard in self._train_rows.shards
+            ]
+        else:
+            self._train_floats = self._float_rows(
+                dtrain.features, self._train_rows, self.rank_perm
+            )
+            weights = dtrain.weights
+            cuts = self._sketch(
+                None if weights is None else [
+                    self._shard_rows(weights, shard, 0.0, self.rank_perm)
+                    for shard in self._train_rows.shards
+                ]
+            )
+            max_bin = resolve_max_bin(cuts, config.max_bin, config.exact_bin_cap)
+            shard_bins = apply_shards(
+                self._train_floats, cuts, max_bin, self._train_rows.devices, name="train"
+            )
+        self._stage_train_bins(shard_bins, cuts, max_bin)
+        self._note_binned_shape(shard_bins)
+        del shard_bins
+        if not self.approx_resketch:
+            self._train_floats = None  # only the re-sketch reads them again
+        self.eval_sets = []
+        self._eval_rows = []    # per eval set: its row layout (None = shared)
+        self._eval_floats = {}  # eval-set index -> its float blocks (re-sketch)
+        staged = []  # per eval set: its bins a shard, placed under `setup.upload` below
+        for i, (dm, name) in enumerate(evals):
+            if dm is dtrain:
+                self.eval_sets.append((name, dm, self.train_binned))
+                self._eval_rows.append(None)
+                staged.append(None)
+                continue
+            rows = self._row_shards(_agreed_pad(dm.num_row), d_real, P("data", None))
+            if isinstance(dm, BinnedMatrix):
+                # pre-binned eval set: must carry the training channel's
+                # bin edges (streaming ingest bins validation with the
+                # train cuts) or its bin indices mean different thresholds
+                if dm.max_bin != max_bin or not (
+                    dm.cut_points is cuts
+                    or (
+                        len(dm.cut_points) == len(cuts)
+                        and all(
+                            np.array_equal(a, b)
+                            for a, b in zip(dm.cut_points, cuts)
+                        )
+                    )
+                ):
+                    raise exc.AlgorithmError(
+                        "pre-binned eval set {!r} was binned with different "
+                        "cut points than the training data".format(name)
+                    )
+                binned = dm
+                shard_bins = [
+                    self._shard_rows(dm.bins, shard, max_bin) for shard in rows.shards
+                ]
+            else:
+                binned = BinnedMatrix(
+                    partial(self._eval_bins_to_host, i), cuts, max_bin,
+                    labels=dm.labels, weights=dm.weights, groups=dm.groups,
+                    shape=(dm.num_row, d_real),
+                )
+                shard_bins = self._bin_eval_rows(i, name, dm, rows, cuts, max_bin)
+            self.eval_sets.append((name, dm, binned))
+            self._eval_rows.append(rows)
+            staged.append(shard_bins)
         with self._upload_span("labels_weights_margins"):
             self.labels = _put(_layout_rows(labels, 0.0), P("data"))
             self.weights = _put(_layout_rows(dtrain.get_weight(), 0.0), P("data"))
@@ -702,21 +745,16 @@ class _TrainingSession:
         self.eval_margins = []
         self.eval_labels = []
         self.eval_weights = []
-        self._eval_pads = []  # per eval set: padded row count (None = shared)
         with self._upload_span("eval_sets"):
-            for name, dm, binned in self.eval_sets:
+            for i, (name, dm, binned) in enumerate(self.eval_sets):
                 if binned is self.train_binned:
                     self.eval_bins.append(None)     # shares training margins
                     self.eval_margins.append(None)
                     self.eval_labels.append(self.labels)
                     self.eval_weights.append(self.weights)
-                    self._eval_pads.append(None)
                     continue
-                m_pad = _agreed_pad(dm.num_row)
-                self._eval_pads.append(m_pad)
-                self.eval_bins.append(
-                    _put(_pad_rows(binned.bins, m_pad, binned.max_bin), P("data", None))
-                )
+                m_pad = self._eval_rows[i].shards[-1].hi
+                self.eval_bins.append(self._place_rows(staged[i], self._eval_rows[i]))
                 self.eval_labels.append(_put(_pad_rows(dm.labels, m_pad, 0.0), P("data")))
                 self.eval_weights.append(
                     _put(_pad_rows(dm.get_weight(), m_pad, 0.0), P("data"))
@@ -830,6 +868,16 @@ class _TrainingSession:
         self.hist_comm_plan, self.hist_comm_bytes_per_round = self._comm_plan()
         self._hist_comm_ms = None  # lazily calibrated at the first dispatch
         self._set_comm_round_fields()
+        REGISTRY.gauge(
+            "mesh_data_shards",
+            "Row shards of the training mesh's `data` axis (1 on one device)",
+        ).set(self.n_data_shards)
+        REGISTRY.gauge(
+            "hist_allreduce_bytes_per_round",
+            "Wire bytes one round's data-axis histogram collectives move, "
+            "from the round program's shapes (ring formula, docs/DESIGN.md "
+            "Communication; 0 on one device)",
+        ).set(self.hist_comm_bytes_per_round)
 
         # every dispatch records a `host_dispatch` span (python + XLA
         # dispatch until the async call returns) and a `device_sync` span
@@ -934,19 +982,155 @@ class _TrainingSession:
             "squared group sizes",
         ).set(float(np.sum(np.square(groups.astype(np.float64)))))
 
-    def _note_binned_shape(self):
+    def _row_shards(self, n_pad, width, spec):
+        """The ``_RowLayout`` of a matrix of ``n_pad`` local rows (padding
+        included) x ``width`` columns laid out by ``spec``: a shard for each
+        of this process's data shards, the device that sets it up (the one
+        that holds its first columns) and who holds which of its columns."""
+        if self.mesh is None:
+            whole = _RowShard(0, n_pad, None, ((None, slice(0, width)),))
+            return _RowLayout((whole,), None, (n_pad, width))
+        from jax.sharding import NamedSharding
+
+        sharding = NamedSharding(self.mesh, spec)
+        shape = (n_pad * (jax.process_count() if self.is_multiprocess else 1), width)
+        by_rows = {}
+        for device, (rows, cols) in sharding.addressable_devices_indices_map(shape).items():
+            lo, hi, _ = rows.indices(shape[0])
+            c_lo, c_hi, _ = cols.indices(shape[1])
+            by_rows.setdefault((lo, hi), []).append((c_lo, device.id, device, slice(c_lo, c_hi)))
+        first = min(lo for lo, _hi in by_rows)  # this process's first global row
+        shards = tuple(
+            _RowShard(
+                lo - first, hi - first, min(places)[2],
+                tuple((device, cols) for _c, _id, device, cols in sorted(places)),
+            )
+            for (lo, hi), places in sorted(by_rows.items())
+        )
+        return _RowLayout(shards, sharding, shape)
+
+    def _shard_rows(self, arr, shard, fill, perm=None):
+        """A shard's rows of a host array in original row order: the rows of
+        its slice of the padded layout (``perm``: the group-partitioned
+        permutation of distributed ranking), padding filled with ``fill``."""
+        if perm is None:
+            return _pad_rows(arr[shard.lo : shard.hi], shard.hi - shard.lo, fill)
+        take = perm[shard.lo : shard.hi]
+        out = np.full((len(take),) + arr.shape[1:], fill, arr.dtype)
+        m = take >= 0
+        out[m] = arr[take[m]]
+        return out
+
+    def _sketch(self, weights):
+        """The training rows' cuts: every shard sketched on its own chip
+        under ``weights`` (a block a shard; None: unit weights), the
+        candidates merged over this process's shards and, in a multi-process
+        job, every other process's. The exact method's candidate set is no
+        sketch (every distinct midpoint of the matrix): it is taken whole."""
+        max_bin = self.config.max_bin
+        if max_bin is None:
+            return sketch_shards([self._dtrain.features], [self._dtrain.weights], None)
+        merge = (
+            partial(_merge_cuts_across_processes, max_bin=max_bin)
+            if self.is_multiprocess
+            else None
+        )
+        return sketch_shards(
+            self._train_floats,
+            weights or [None] * len(self._train_floats),
+            max_bin,
+            self._train_rows.devices,
+            merge=merge,
+        )
+
+    def _place_rows(self, blocks, layout, fill=None):
+        """A matrix's row shards -> the placed (global) device array. A block
+        that is on its shard's device already moves nothing; its columns pad
+        to the layout's width with ``fill`` and go to whoever holds them."""
+        pieces = []
+        for block, shard in zip(blocks, layout.shards):
+            if isinstance(block, np.ndarray):
+                self._bytes_put += int(block.nbytes)
+            pad = layout.shape[1] - block.shape[1]
+            if pad:
+                xp = np if isinstance(block, np.ndarray) else jnp
+                block = xp.concatenate(
+                    [block, xp.full((block.shape[0], pad), fill, block.dtype)], axis=1
+                )
+            for device, cols in shard.placements:
+                part = block if len(shard.placements) == 1 else block[:, cols]
+                pieces.append(jnp.asarray(part) if device is None else jax.device_put(part, device))
+        if layout.sharding is None:
+            return pieces[0]
+        return jax.make_array_from_single_device_arrays(layout.shape, layout.sharding, pieces)
+
+    def _train_bins_to_host(self, bins):
+        """A placed train bin matrix's real rows and columns on the host, in
+        original row order (this process's rows)."""
+        if self.rank_pos is not None:
+            return self._to_host(bins, None)[:, : self._d_real][self.rank_pos]
+        return self._to_host(bins, self.n)[:, : self._d_real]
+
+    def _eval_bins_to_host(self, index):
+        dm = self.eval_sets[index][1]
+        return self._to_host(self.eval_bins[index], dm.num_row)[:, : self._d_real]
+
+    def _float_rows(self, features, layout, perm=None):
+        """A matrix's float rows a shard, padding rows all NaN: missing in
+        every column, so they weigh nothing in a sketch and bin to the
+        missing bin, which is what padding holds. An approx job under the
+        device sketch stages each block on its shard's chip ONCE —
+        re-uploading [n, d] floats every dispatch would pay n*d*4 bytes of
+        host->HBM per round. Trade: the staged floats stay resident
+        alongside the round program for the whole job; GRAFT_SKETCH_IMPL=host
+        trades them back for per-round uploads if an approx job is HBM-bound."""
+        from ..data.binning import _float_block, _sketch_impl
+
+        blocks = [self._shard_rows(features, shard, np.nan, perm) for shard in layout.shards]
+        if self.approx_resketch and _sketch_impl() == "device":
+            blocks = [_float_block(b, shard.device) for b, shard in zip(blocks, layout.shards)]
+        return blocks
+
+    def _shard_values(self, arr, layout):
+        """A placed row vector (the hessians) a shard, for the sketch's
+        weights: under the device sketch the piece each shard's chip holds,
+        which never leaves it; else the host's copy, cut by shard."""
+        from ..data.binning import _sketch_impl
+
+        if _sketch_impl() != "device":
+            host = np.asarray(self._to_host(arr, None), np.float32)
+            return [host[shard.lo : shard.hi] for shard in layout.shards]
+        if layout.sharding is None:
+            return [arr]
+        held = {piece.device: piece.data for piece in arr.addressable_shards}
+        return [held[shard.device] for shard in layout.shards]
+
+    def _bin_eval_rows(self, index, name, dm, layout, cuts, max_bin):
+        """An evaluation set's bins a shard, each binned on its own chip
+        under the training cuts."""
+        floats = self._eval_floats.get(index)
+        if floats is None:
+            floats = self._float_rows(dm.features, layout)
+            if self.approx_resketch:
+                self._eval_floats[index] = floats
+        return apply_shards(floats, cuts, max_bin, layout.devices, name=name)
+
+    def _note_binned_shape(self, shard_bins):
         """What the binned training matrix holds, set once: how many of its
-        cells sit in the missing bin, and how many of the histogram's cut
-        slots (``max_bin - 1`` a column, all of them built every level) the
-        sketch filled. This host's rows."""
+        cells sit in the missing bin (counted where the shards lie; padding
+        rows, which are all missing, taken off), and how many of the
+        histogram's cut slots (``max_bin - 1`` a column, all of them built
+        every level) the sketch filled. This host's rows."""
         from ..telemetry import REGISTRY
 
         binned = self.train_binned
+        counts = [(block == binned.max_bin).sum() for block in shard_bins]
+        missing = sum(int(c) for c in counts) - (self._n_pad - self.n) * binned.num_col
         gauges = (
             ("train_cells_missing", "Cells of the binned training matrix in the missing bin",
-             np.count_nonzero(binned.bins == binned.max_bin)),
+             missing),
             ("train_cells_total", "Cells of the binned training matrix (rows x columns)",
-             binned.bins.size),
+             binned.num_row * binned.num_col),
             ("sketch_cuts_selected", "Cut points the sketch selected, summed over columns",
              sum(len(c) for c in binned.cut_points)),
             ("sketch_cut_slots", "Cut slots a level histogram carries: columns x (max_bin - 1)",
@@ -1507,36 +1691,36 @@ class _TrainingSession:
             upload.add_bytes(up=self._bytes_put - before)
 
     # ------------------------------------------------------------- resketch
-    def _stage_train_bins(self, raw_bins, cuts, max_bin):
-        """Stage [n_local, d_real] bin indices + per-feature cuts as the
-        session's padded, placed device arrays (cuts/num_cuts/bins). Shared
-        by __init__ and the approx re-sketch so the two paths can never
-        disagree on padding conventions."""
-        cuts = list(cuts)
-        if self.d_pad != self._d_real:
-            cuts += [
-                np.zeros(0, np.float32)
-                for _ in range(self.d_pad - self._d_real)
-            ]
+    def _stage_train_bins(self, shard_bins, cuts, max_bin):
+        """Stage the training rows' bin indices (a block a shard, where the
+        bin-apply left it) + per-feature cuts as the session's padded, placed
+        device arrays (cuts/num_cuts/bins). Shared by __init__ and the approx
+        re-sketch so the two paths can never disagree on padding conventions.
+        ``train_binned`` is the same matrix for whoever reads it on the host:
+        its bins are pulled from the device when first asked for."""
+        real_cuts = list(cuts)
+        cuts = real_cuts + [
+            np.zeros(0, np.float32) for _ in range(self.d_pad - self._d_real)
+        ]
         with self._upload_span("train_bins"):
-            bins_np = self._layout_rows(np.asarray(raw_bins), max_bin)
-            if self.d_pad != self._d_real:
-                bins_np = np.concatenate(
-                    [
-                        bins_np,
-                        np.full(
-                            (bins_np.shape[0], self.d_pad - self._d_real),
-                            max_bin,
-                            bins_np.dtype,
-                        ),
-                    ],
-                    axis=1,
-                )
             self.cuts = cuts
             self.num_cuts = self._put(
                 np.array([len(c) for c in cuts], np.int32), self.feat_spec
             )
-            self.bins = self._put(bins_np, self.bins_spec)
+            self.bins = self._place_rows(shard_bins, self._train_rows, fill=max_bin)
+        dtrain = self._dtrain
+        if isinstance(dtrain, BinnedMatrix):
+            self.train_binned = dtrain
+            return
+        self.train_binned = BinnedMatrix(
+            partial(self._train_bins_to_host, self.bins),
+            real_cuts,
+            max_bin,
+            labels=dtrain.labels,
+            weights=dtrain.weights,
+            groups=dtrain.groups,
+            shape=(self.n, self._d_real),
+        )
 
     def _resketch_bins(self):
         """Per-dispatch candidate re-sketch for tree_method='approx'.
@@ -1544,19 +1728,16 @@ class _TrainingSession:
         libxgboost's approx re-selects split candidates every iteration via
         a hessian-weighted quantile sketch (its GlobalApproxUpdater; the
         reference delegates to it through the tree_method HP,
-        hyperparameter_validation.py:22-24). Here: pull current hessians,
-        recompute cuts (allgather-merged across hosts in multi-process
-        runs), re-bin train + cached eval sets, and refresh cuts/num_cuts —
-        all shapes/dtypes static, so the jitted round program is reused with
+        hyperparameter_validation.py:22-24). Here: the current hessians
+        weigh the same shard-by-shard sketch set-up ran (merged across the
+        chips and, in multi-process runs, the hosts), train + cached eval
+        sets re-bin where they lie, and cuts/num_cuts refresh — all
+        shapes/dtypes static, so the jitted round program is reused with
         new array CONTENTS. Committed trees are unaffected: each round's
         trees were already compacted to float thresholds under the cuts
         active when they were built. Runs before EVERY dispatch (including
         the first: libxgboost hessian-weights the iteration-0 sketch too —
         from the base margin, or real margins on checkpoint resume)."""
-        from ..data.binning import (
-            _sketch_impl, apply_cut_points, compute_cut_points,
-        )
-
         if self._grad_fn is None:
             # graftlint: disable=trace-uncached-jit — memoized on self._grad_fn: constructed once per session
             self._grad_fn = jax.jit(self.objective.grad_hess)
@@ -1564,43 +1745,22 @@ class _TrainingSession:
         if h.ndim == 2:  # multi-class: sketch weight = summed class hessians
             h = h.sum(axis=1)
         max_bin = self.train_binned.max_bin
-        device_sketch = not self.is_multiprocess and _sketch_impl() == "device"
-        if not device_sketch:
-            h_host = np.asarray(self._to_host(h, self.n), np.float32)
-        if self.is_multiprocess:
-            cuts = _merged_distributed_cuts(self._dtrain, max_bin, weights=h_host)
-            feats = self._dtrain.features
-        elif device_sketch:
-            # TPU path: float features staged on device ONCE — re-uploading
-            # [n, d] floats every dispatch would pay n*d*4 bytes of
-            # host->HBM per round; hessians never leave the device at all.
-            # Trade: the staged floats stay resident (n*d*4 bytes of HBM)
-            # alongside the round program for the whole job —
-            # GRAFT_SKETCH_IMPL=host trades them back for per-round uploads
-            # if an approx job is HBM-bound.
-            if self._feats_dev is None:
-                self._feats_dev = jnp.asarray(self._dtrain.features, jnp.float32)
-            feats = self._feats_dev
-            cuts = compute_cut_points(feats, h[: self.n], max_bin)
-        else:
-            feats = self._dtrain.features
-            cuts = compute_cut_points(feats, h_host, max_bin)
+        cuts = self._sketch(self._shard_values(h, self._train_rows))
         self._stage_train_bins(
-            apply_cut_points(feats, cuts, max_bin, name="train"), cuts, max_bin
+            apply_shards(
+                self._train_floats, cuts, max_bin, self._train_rows.devices, name="train"
+            ),
+            cuts,
+            max_bin,
         )
         # cached eval bins were built with the old cuts; the incremental
         # eval-margin apply reads bin indices, so they must re-bin too
-        for i, (name, dm, binned) in enumerate(self.eval_sets):
+        for i, (name, dm, _binned) in enumerate(self.eval_sets):
             if self.eval_bins[i] is None:
                 continue
-            efeats = dm.features
-            if device_sketch:
-                if i not in self._eval_feats_dev:
-                    self._eval_feats_dev[i] = jnp.asarray(efeats, jnp.float32)
-                efeats = self._eval_feats_dev[i]
-            eb = np.asarray(apply_cut_points(efeats, cuts, max_bin, name=name))
-            self.eval_bins[i] = self._put(
-                _pad_rows(eb, self._eval_pads[i], max_bin), P("data", None)
+            layout = self._eval_rows[i]
+            self.eval_bins[i] = self._place_rows(
+                self._bin_eval_rows(i, name, dm, layout, cuts, max_bin), layout
             )
 
     # ------------------------------------------------------- device window

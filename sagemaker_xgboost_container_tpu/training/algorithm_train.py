@@ -351,7 +351,7 @@ def sagemaker_train(
         raise exc.PlatformError("Number of hosts should be an int greater than or equal to 1")
 
 
-def _training_mesh(num_devices_cap=None):
+def training_mesh(num_devices_cap=None):
     """Data-parallel mesh over every visible device (None on one device).
 
     Under multi-host ``jax.distributed``, jax.devices() spans the whole job,
@@ -511,7 +511,7 @@ def train_job(
 
     train_cfg = dict(train_cfg)
     num_devices_cap = train_cfg.pop("_num_devices", None)
-    mesh = _training_mesh(num_devices_cap)
+    mesh = training_mesh(num_devices_cap)
     # one knob snapshot for the whole job: every generation the reform loop
     # rebuilds the session with, so a shrink can never pick up mid-job env
     # drift — and what the device-runtime line reports is what trains
@@ -634,7 +634,7 @@ def train_job(
                 # then the mesh over the new device set, then the control
                 # planes over the survivor list
                 _reinit_jax_distributed(new_hosts, current_host)
-                mesh_box["mesh"] = _training_mesh(num_devices_cap)
+                mesh_box["mesh"] = training_mesh(num_devices_cap)
                 from .watchdog import start_abort_plane
 
                 start_abort_plane(new_hosts, current_host)

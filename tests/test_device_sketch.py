@@ -193,12 +193,12 @@ def test_approx_resketch_device_impl(monkeypatch):
                num_feature=X.shape[1]),
     )
     session.run_rounds()
-    staged = session._feats_dev
-    assert staged is not None
+    staged = session._train_floats  # a block a shard: one, on one device
+    assert staged and not any(isinstance(block, np.ndarray) for block in staged)
     cuts0 = [np.asarray(c).copy() for c in session.cuts]
     session.run_rounds()
     session.end_turnaround()
-    assert session._feats_dev is staged, "features must stage exactly once"
+    assert session._train_floats is staged, "features must stage exactly once"
     assert any(
         a.shape != np.asarray(b).shape or not np.allclose(a, np.asarray(b))
         for a, b in zip(cuts0, session.cuts)
@@ -376,3 +376,85 @@ def test_equal_blocks_cover_everything_in_one_shape():
     assert binning._equal_blocks(8800000, cap // 28)[0] == 1
     assert binning._equal_blocks(2270296, cap // 136)[0] == 1
     assert binning._equal_blocks(16387491, cap // 39) == (2, 8193746)
+
+
+def _shard_data(seed=17, rows=257, shards=4):
+    rng = np.random.RandomState(seed)
+    blocks = []
+    for s in range(shards):
+        x = rng.randn(rows, 6).astype(np.float32) + 0.3 * s  # the shares differ
+        x[rng.rand(rows) < 0.2, 1] = np.nan
+        x[:, 4] = rng.randint(0, 5, rows)
+        blocks.append(x)
+    return blocks
+
+
+def test_each_shard_is_sketched_on_its_own_device(monkeypatch):
+    """The device lowering a shard on the device it is given: the four
+    shards' candidates are what each gives alone, merged by the one rule;
+    the shards run side by side (one thread a shard)."""
+    import threading
+
+    import jax
+
+    monkeypatch.setenv("GRAFT_SKETCH_IMPL", "device")
+    blocks = _shard_data()
+    devices = jax.devices()[:4]
+    placed, threads = [], set()
+    real = binning._float_block
+
+    def recording(block, device):
+        out = real(block, device)
+        placed.append((device, next(iter(out.devices()))))
+        threads.add(threading.get_ident())
+        return out
+
+    monkeypatch.setattr(binning, "_float_block", recording)
+    weights = [np.full(len(b), 1.0 + s, np.float32) for s, b in enumerate(blocks)]
+    merged = binning.sketch_shards(blocks, weights, 32, devices)
+    assert placed and all(asked == held for asked, held in placed)
+    assert {asked for asked, _held in placed} == set(devices) and len(threads) == 4
+    alone = [binning.compute_cut_points(b, w, 32) for b, w in zip(blocks, weights)]
+    want = binning.merge_cut_candidates(alone, 32)
+    assert [c.tobytes() for c in merged] == [c.tobytes() for c in want]
+    assert all(len(c) <= 31 for c in merged)
+    # one shard on the default device: its own sketch, untouched by the merge
+    assert [c.tobytes() for c in binning.sketch_shards(blocks[:1], weights[:1], 32)] == [
+        c.tobytes() for c in alone[0]
+    ]
+
+
+def test_each_shard_is_binned_on_its_own_device_and_left_there(monkeypatch):
+    import jax
+
+    blocks = _shard_data(seed=18)
+    cuts = binning.compute_cut_points(np.concatenate(blocks), None, 32)
+    monkeypatch.setenv("GRAFT_SKETCH_IMPL", "host")
+    want = [binning.apply_cut_points(b, cuts, 32) for b in blocks]
+    monkeypatch.setenv("GRAFT_SKETCH_IMPL", "device")
+    devices = jax.devices()[:4]
+    binned = binning.apply_shards(blocks, cuts, 32, devices, name="train")
+    for out, dev, expect in zip(binned, devices, want):
+        assert isinstance(out, jax.Array) and out.devices() == {dev}
+        assert out.dtype == np.uint8  # narrowed where it lies: no int32 comes back
+        assert np.asarray(out).tobytes() == expect.tobytes()
+    back = binning.apply_shards(blocks, cuts, 32, devices, to_host=True)
+    assert all(isinstance(b, np.ndarray) and b.tobytes() == w.tobytes() for b, w in zip(back, want))
+
+
+def test_row_blocks_of_a_device_apply_join_on_the_device(monkeypatch):
+    """A matrix over the block budget goes through in equal blocks of rows,
+    the last overlapping its neighbour, and comes out whole, on the device."""
+    import jax
+
+    monkeypatch.setenv("GRAFT_SKETCH_IMPL", "device")
+    rng = np.random.RandomState(19)
+    X = rng.randn(1001, 5).astype(np.float32)
+    X[::9, 2] = np.nan
+    cuts = binning.compute_cut_points(X, None, 300)  # uint16 bins
+    whole = binning.apply_shards([X], cuts, 300)[0]
+    monkeypatch.setattr(binning, "DEVICE_BLOCK_BYTES", 30 * 5 * 334)  # 3 blocks of 334 rows
+    blocked = binning.apply_shards([X], cuts, 300)[0]
+    assert isinstance(blocked, jax.Array) and blocked.shape == (1001, 5)
+    assert blocked.dtype == np.uint16
+    assert np.asarray(blocked).tobytes() == np.asarray(whole).tobytes()

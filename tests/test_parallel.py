@@ -38,7 +38,8 @@ def mesh8():
 
 
 @pytest.mark.multichip
-def test_mesh_training_matches_single_device(mesh8):
+def test_mesh_training_matches_single_device(mesh8, sketch_as_mesh):
+    sketch_as_mesh(8)  # both sides under the mesh session's cuts
     X, y = _friedman(1024)
     dtrain = DataMatrix(X, labels=y)
     params = {"max_depth": 4, "eta": 0.3, "seed": 3}
@@ -249,11 +250,12 @@ def _paths_within_sets(tree, sets):
 
 
 @pytest.mark.multichip
-def test_lossguide_2d_mesh_matches_single_device():
+def test_lossguide_2d_mesh_matches_single_device(sketch_as_mesh):
     """r3 parity lift (ADVICE medium): lossguide growth on a
     (data x feature) mesh — candidate-store combine across column shards +
     owner/psum row routing — must reproduce the single-device trees, with
     and without colsample draws."""
+    sketch_as_mesh(4)  # both sides under the mesh session's cuts
     from jax.sharding import Mesh as JMesh
 
     X, y = _friedman(768)  # d = 5 pads to 6 across 2 feature shards
@@ -307,9 +309,10 @@ def test_interaction_constraints_lossguide():
 
 
 @pytest.mark.multichip
-def test_interaction_constraints_lossguide_2d_mesh():
+def test_interaction_constraints_lossguide_2d_mesh(sketch_as_mesh):
     """Constraint masks are sliced per column shard: the sharded lossguide
     build must agree with single-device under interaction_constraints."""
+    sketch_as_mesh(4)  # both sides under the mesh session's cuts
     from jax.sharding import Mesh as JMesh
 
     rng = np.random.RandomState(17)
@@ -538,9 +541,10 @@ def test_2d_mesh_feature_axis_tree_build():
 
 
 @pytest.mark.multichip
-def test_train_api_2d_mesh():
+def test_train_api_2d_mesh(sketch_as_mesh):
     """train() on a (data x feature) 2D mesh matches single-device output,
     including column padding when d doesn't divide the feature shards."""
+    sketch_as_mesh(4)  # both sides under the mesh session's cuts
     from jax.sharding import Mesh as JMesh
 
     X, y = _friedman(512)  # d = 5, feature shards = 2 -> pads to 6
@@ -556,8 +560,9 @@ def test_train_api_2d_mesh():
 
 
 @pytest.mark.multichip
-def test_mesh_k_batching_no_evals(mesh8):
+def test_mesh_k_batching_no_evals(mesh8, sketch_as_mesh):
     """mesh + _rounds_per_dispatch>1 without eval sets (spec-structure path)."""
+    sketch_as_mesh(8)  # both sides under the mesh session's cuts
     X, y = _friedman(1024)
     dtrain = DataMatrix(X, labels=y)
     forest = train(
@@ -646,9 +651,10 @@ def test_mesh_k_batching_metrics_match_k1(mesh8):
 
 
 @pytest.mark.multichip
-def test_mesh_k_batching_matches_single_device_rmse(mesh8):
+def test_mesh_k_batching_matches_single_device_rmse(mesh8, sketch_as_mesh):
     """K-batched mesh run vs plain single-device run: same trees, same
     device-metric values (rmse decomposes exactly across shards)."""
+    sketch_as_mesh(8)  # both sides under the mesh session's cuts
     X, y = _friedman(1280)
     dtrain = DataMatrix(X, labels=y)
 
@@ -978,7 +984,7 @@ def test_two_process_update_refresh():
 
 
 @pytest.mark.multichip
-def test_ranking_on_mesh_matches_single_device(mesh8):
+def test_ranking_on_mesh_matches_single_device(mesh8, sketch_as_mesh):
     """rank:ndcg trains on a data mesh — rows sharded BY
     GROUP (groups whole per shard), LambdaMART gradients shard-local, psum'd
     histograms. Must match the single-device trees (reference bar: ranking
@@ -986,6 +992,7 @@ def test_ranking_on_mesh_matches_single_device(mesh8):
     rng = np.random.RandomState(21)
     n_groups = 64
     sizes = rng.randint(5, 40, n_groups).astype(np.int32)  # uneven groups
+    sketch_as_mesh(8, groups=sizes)  # both sides under the mesh session's cuts
     n = int(sizes.sum())
     X = rng.randn(n, 4).astype(np.float32)
     relevance = np.clip(np.round(X[:, 0] * 1.5 + 1.5), 0, 4).astype(np.float32)
@@ -1018,7 +1025,7 @@ def test_ranking_on_mesh_matches_single_device(mesh8):
 
 
 @pytest.mark.multichip
-def test_ranking_on_2d_mesh_matches_single_device():
+def test_ranking_on_2d_mesh_matches_single_device(sketch_as_mesh):
     """r3 parity lift: rank:ndcg on a (data x feature) mesh —
     the group-partitioned row layout composes with column sharding; trees
     must match single-device."""
@@ -1027,6 +1034,7 @@ def test_ranking_on_2d_mesh_matches_single_device():
     rng = np.random.RandomState(23)
     n_groups = 48
     sizes = rng.randint(5, 40, n_groups).astype(np.int32)
+    sketch_as_mesh(4, groups=sizes)  # both sides under the mesh session's cuts
     n = int(sizes.sum())
     X = rng.randn(n, 5).astype(np.float32)  # d=5 pads to 6 over 2 shards
     relevance = np.clip(np.round(X[:, 0] * 1.5 + 1.5), 0, 4).astype(np.float32)
@@ -1045,10 +1053,11 @@ def test_ranking_on_2d_mesh_matches_single_device():
 
 
 @pytest.mark.multichip
-def test_mesh_colsample_matches_single_device(mesh8):
+def test_mesh_colsample_matches_single_device(mesh8, sketch_as_mesh):
     """colsample feature draws must be replicated across data shards (the
     row-subsample rng is shard-folded, the feature rng must NOT be): with
     subsample=1, a colsample_bylevel/bynode mesh run equals single-device."""
+    sketch_as_mesh(8)  # both sides under the mesh session's cuts
     X, y = _friedman(1024, seed=13)
     dtrain = DataMatrix(X, labels=y)
     for extra in ({"colsample_bylevel": 0.6}, {"colsample_bynode": 0.6}):
@@ -1063,11 +1072,12 @@ def test_mesh_colsample_matches_single_device(mesh8):
 
 
 @pytest.mark.multichip
-def test_2d_mesh_colsample_monotone_interaction():
+def test_2d_mesh_colsample_monotone_interaction(sketch_as_mesh):
     """The (data x feature) mesh supports colsample /
     monotone / interaction constraints — draws are made over GLOBAL columns
     with the replicated rng, each shard slicing its own segment, so the 2-D
     run equals single-device."""
+    sketch_as_mesh(4)  # both sides under the mesh session's cuts
     from jax.sharding import Mesh as JMesh
 
     X, y = _friedman(512, seed=23)
@@ -1157,9 +1167,10 @@ def test_two_process_2d_mesh_training():
 
 
 @pytest.mark.multichip
-def test_survival_cox_on_mesh_matches_single_device(mesh8):
+def test_survival_cox_on_mesh_matches_single_device(mesh8, sketch_as_mesh):
     """survival:cox trains on a mesh — global risk sets
     via all_gather inside the jitted round (exact, not per-shard)."""
+    sketch_as_mesh(8)  # both sides under the mesh session's cuts
     rng = np.random.RandomState(31)
     n = 1024
     X = rng.rand(n, 4).astype(np.float32)
@@ -1289,10 +1300,11 @@ def test_gblinear_mesh_matches_single_device(mesh8):
 
 
 @pytest.mark.multichip
-def test_approx_resketch_mesh_matches_single_device(mesh8):
+def test_approx_resketch_mesh_matches_single_device(mesh8, sketch_as_mesh):
     """tree_method=approx (r5 per-dispatch re-sketch): the hessian-weighted
     cut refresh is computed from globally identical margins, so a data mesh
     trains the same trees as single-device."""
+    sketch_as_mesh(8)  # both sides under the mesh session's cuts
     rng = np.random.RandomState(7)
     X = rng.rand(1003, 5).astype(np.float32)
     y = (np.sin(4 * X[:, 0]) + X[:, 1] * X[:, 2]).astype(np.float32)
@@ -1333,10 +1345,11 @@ def test_gblinear_cox_mesh_matches_single_device(mesh8):
 
 
 @pytest.mark.multichip
-def test_dart_mesh_matches_single_device(mesh8):
+def test_dart_mesh_matches_single_device(mesh8, sketch_as_mesh):
     """dart on a data mesh: the session shards rows; GSPMD partitions the
     dart builder's histogram ops, so dropout/rescale bookkeeping and trees
     match single-device."""
+    sketch_as_mesh(8)  # both sides under the mesh session's cuts
     rng = np.random.RandomState(0)
     X = rng.rand(2005, 6).astype(np.float32)
     y = (X[:, 0] + 0.5 * X[:, 1] > 0.8).astype(np.float32)
@@ -1354,11 +1367,12 @@ def test_dart_mesh_matches_single_device(mesh8):
 
 
 @pytest.mark.multichip
-def test_dart_multiclass_mesh_matches_single_device(mesh8):
+def test_dart_multiclass_mesh_matches_single_device(mesh8, sketch_as_mesh):
     """r5 guard lift: dart × multi:softprob on a data mesh. The per-class
     vmap'd builder runs on row-sharded [n, C] gradients under GSPMD; the
     shared-seed round-unit dropout bookkeeping is host-side and identical,
     so predictions match single-device."""
+    sketch_as_mesh(8)  # both sides under the mesh session's cuts
     rng = np.random.RandomState(3)
     X = rng.randn(1203, 5).astype(np.float32)  # not divisible by 8
     y = rng.randint(0, 3, size=1203).astype(np.float32)
@@ -1376,11 +1390,12 @@ def test_dart_multiclass_mesh_matches_single_device(mesh8):
     )
 
 
-def test_mesh_with_pallas_hist_matches_single_device():
+def test_mesh_with_pallas_hist_matches_single_device(sketch_as_mesh):
     """The production TPU configuration is the pallas histogram kernel
     INSIDE shard_map with the data-axis psum — the v5p pod path. It must
     compose (per-device kernel, XLA collective around it) and match the
     single-device flat reference."""
+    sketch_as_mesh(8)  # both sides under the mesh session's cuts
     from sagemaker_xgboost_container_tpu.data.matrix import DataMatrix
     from sagemaker_xgboost_container_tpu.models import train
     from sagemaker_xgboost_container_tpu.ops.histogram import resolve_hist_knobs
@@ -1427,7 +1442,7 @@ def _click_like(n, seed):
 @pytest.mark.multichip
 def test_four_row_shares_add_up_to_the_uncut_data_and_grow_its_tree(monkeypatch):
     """What `criteo-tb-d8` leaves out on one chip, at a small size: under the
-    cuts `_merged_distributed_cuts` gives four row shares, the root and
+    cuts four row shares agree on through their allgather, the root and
     level-1 histograms and node totals of the shares sum to those of the
     uncut data (to 1e-4 of the largest entry: the histogram's gradient operand
     is two bfloat16 halves, 2^-16 a term, and the shares sum in another order), and `build_tree` over a `data` mesh of 4 returns the
@@ -1445,10 +1460,21 @@ def test_four_row_shares_add_up_to_the_uncut_data_and_grow_its_tree(monkeypatch)
         build_tree, pack_tree, unpack_tree,
     )
 
+    from functools import partial
+
+    from sagemaker_xgboost_container_tpu.data.binning import sketch_shards
+
     shares, max_bin = 4, 32
     n = shares * 1024
     X, grad, hess = _click_like(n, seed=5)
     parts = [slice(s * n // shares, (s + 1) * n // shares) for s in range(shares)]
+
+    def merged_cuts(part):
+        """One process's share sketched and merged with everybody's candidates."""
+        return sketch_shards(
+            [X[part]], [None], max_bin,
+            merge=partial(booster._merge_cuts_across_processes, max_bin=max_bin),
+        )
 
     # every process's candidates, as the allgather would bring them
     gathered = {}
@@ -1456,7 +1482,7 @@ def test_four_row_shares_add_up_to_the_uncut_data_and_grow_its_tree(monkeypatch)
         multihost_utils, "process_allgather", lambda x: np.asarray(x)[None]
     )
     for part in parts:
-        booster._merged_distributed_cuts(DataMatrix(X[part]), max_bin)  # warm the shapes
+        merged_cuts(part)  # warm the shapes
     local = []
 
     def record(x):
@@ -1465,14 +1491,14 @@ def test_four_row_shares_add_up_to_the_uncut_data_and_grow_its_tree(monkeypatch)
 
     monkeypatch.setattr(multihost_utils, "process_allgather", record)
     for part in parts:
-        booster._merged_distributed_cuts(DataMatrix(X[part]), max_bin)
+        merged_cuts(part)
     mats, counts = local[0::2], local[1::2]
     monkeypatch.setattr(
         multihost_utils,
         "process_allgather",
         lambda x: np.stack(mats if np.asarray(x).ndim == 2 else counts),
     )
-    merged = [booster._merged_distributed_cuts(DataMatrix(X[part]), max_bin) for part in parts]
+    merged = [merged_cuts(part) for part in parts]
     for other in merged[1:]:  # every share agrees on the cuts, to the bit
         assert all(a.tobytes() == b.tobytes() for a, b in zip(merged[0], other))
     cuts = merged[0]
@@ -1532,3 +1558,190 @@ def test_four_row_shares_add_up_to_the_uncut_data_and_grow_its_tree(monkeypatch)
     assert (~want["default_left"][~want["is_leaf"]]).any()
     np.testing.assert_allclose(got["leaf_value"], want["leaf_value"], rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(np.asarray(row_out), np.asarray(ref_out), rtol=1e-5, atol=1e-6)
+
+
+# ------------------------------------------------------ set-up shard by shard
+def _mesh4():
+    return Mesh(np.array(jax.devices()[:4]), axis_names=("data",))
+
+
+def _mesh_session(X, y, mesh, params=None, evals=(), weights=None):
+    from sagemaker_xgboost_container_tpu.models.booster import TrainConfig, _TrainingSession
+    from sagemaker_xgboost_container_tpu.models.forest import Forest
+
+    cfg = TrainConfig(dict({"objective": "binary:logistic", "max_depth": 3, "max_bin": 32},
+                           **(params or {})))
+    forest = Forest(objective_name=cfg.objective, base_score=cfg.base_score,
+                    num_feature=X.shape[1])
+    return _TrainingSession(cfg, DataMatrix(X, labels=y, weights=weights), list(evals), forest, mesh=mesh)
+
+
+def _shard_blocks(X, shards):
+    """The mesh session's shards of a matrix: contiguous, ceil(n / shards)
+    rows, the tail padded with rows that are missing in every column."""
+    rows = -(-len(X) // shards)
+    padded = np.full((rows * shards,) + X.shape[1:], np.nan, np.float32)
+    padded[: len(X)] = X
+    return [padded[s * rows : (s + 1) * rows] for s in range(shards)]
+
+
+@pytest.mark.multichip
+def test_mesh_session_cuts_are_the_merge_of_its_shards_sketches():
+    """A one-process mesh job's cuts are the merge of its shards' sketches
+    (as a multi-process job's always were), one set for every shard; one
+    shard's merge is its own sketch, to the bit."""
+    from sagemaker_xgboost_container_tpu.data import binning
+
+    X, grad, _hess = _click_like(1003, seed=8)   # 1003 rows: the last share is padded
+    y = (grad < 0).astype(np.float32)
+    session = _mesh_session(X, y, _mesh4())
+    per_shard = [
+        binning._host_cut_points(block, np.ones(len(block), np.float32), 31)
+        for block in _shard_blocks(X, 4)
+    ]
+    want = binning.merge_cut_candidates(per_shard, 32)
+    assert [c.tobytes() for c in session.cuts] == [c.tobytes() for c in want]
+    whole = binning._host_cut_points(X, np.ones(len(X), np.float32), 31)
+    assert any(a.tobytes() != b.tobytes() for a, b in zip(want, whole))  # not the uncut sketch
+    # one shard: the old cuts, to the bit, through the same path
+    one = _mesh_session(X, y, None)
+    assert [c.tobytes() for c in one.cuts] == [c.tobytes() for c in whole]
+    assert [c.tobytes() for c in binning.merge_cut_candidates([whole], 32)] == [
+        c.tobytes() for c in whole
+    ]
+
+
+@pytest.mark.multichip
+def test_bins_binned_by_shard_are_the_whole_matrix_binned_under_the_same_cuts():
+    from sagemaker_xgboost_container_tpu.data.binning import apply_cut_points
+
+    X, grad, _hess = _click_like(1003, seed=9)
+    y = (grad < 0).astype(np.float32)
+    Xv = _click_like(301, seed=10)[0]
+    session = _mesh_session(X, y, _mesh4(), evals=[(DataMatrix(Xv, labels=np.zeros(301, np.float32)), "v")])
+    cuts = session.cuts
+    placed = np.asarray(session.bins)
+    assert placed.shape == (1004, X.shape[1]) and len(session.bins.addressable_shards) == 4
+    want = apply_cut_points(X, cuts, 32)
+    assert placed.dtype == want.dtype
+    assert placed[:1003].tobytes() == want.tobytes() and (placed[1003:] == 32).all()
+    # whoever asks for the bins on the host pulls them then
+    assert session.train_binned.bins.tobytes() == want.tobytes()
+    assert session.train_binned.num_row == 1003
+    ev = np.asarray(session.eval_bins[0])
+    assert ev[:301].tobytes() == apply_cut_points(Xv, cuts, 32).tobytes() and (ev[301:] == 32).all()
+    assert session.eval_sets[0][2].bins.tobytes() == ev[:301].tobytes()
+    # the gauges come from where the shards lie, padding taken off
+    from sagemaker_xgboost_container_tpu.telemetry import REGISTRY
+
+    gauges = {name: family for name, _k, _h, family in REGISTRY.collect()}
+    assert gauges["train_cells_missing"][0].value == float((want == 32).sum())
+    assert gauges["train_cells_total"][0].value == float(want.size)
+
+
+@pytest.mark.multichip
+def test_mesh_of_four_forest_is_the_one_device_forest_under_the_same_cuts(sketch_as_mesh):
+    """Splits to the bit; leaf values to float32 summation order (the psum
+    adds four partial sums where one device adds one)."""
+    X, grad, _hess = _click_like(4 * 1024, seed=11)
+    y = (grad < 0).astype(np.float32)
+    params = {"objective": "binary:logistic", "max_depth": 4, "eta": 0.3, "max_bin": 32,
+              "_rounds_per_dispatch": 2}
+    on_mesh = train(dict(params), DataMatrix(X, labels=y), num_boost_round=4, mesh=_mesh4())
+    sketch_as_mesh(4)
+    alone = train(dict(params), DataMatrix(X, labels=y), num_boost_round=4)
+    assert len(on_mesh.trees) == len(alone.trees) == 4
+    for a, b in zip(on_mesh.trees, alone.trees):
+        for key in ("feature", "threshold", "default_left", "left", "right"):
+            assert np.asarray(getattr(a, key)).tobytes() == np.asarray(getattr(b, key)).tobytes(), key
+        np.testing.assert_allclose(a.value, b.value, rtol=0, atol=2e-5)
+
+
+def test_one_process_merge_is_what_four_processes_would_merge(monkeypatch):
+    """Four local shards merged in one process give the cuts four processes
+    of one shard each agree on through their allgather (the stand-in of
+    `test_four_row_shares...`), and two processes of two shards each."""
+    from jax.experimental import multihost_utils
+
+    from sagemaker_xgboost_container_tpu.data import binning
+    from sagemaker_xgboost_container_tpu.models import booster
+
+    X, _grad, _hess = _click_like(4096, seed=12)
+    max_bin = 32
+    sets = [
+        binning._host_cut_points(block, np.ones(len(block), np.float32), max_bin - 1)
+        for block in _shard_blocks(X, 4)
+    ]
+    want = binning.merge_cut_candidates(sets, max_bin)
+
+    def gathered(processes):
+        """What each process hands the allgather, stacked as it returns them."""
+        sent = []
+        monkeypatch.setattr(
+            multihost_utils, "process_allgather",
+            lambda x: (sent.append(np.asarray(x)), np.asarray(x)[None])[1],
+        )
+        for local in processes:
+            booster._merge_cuts_across_processes(local, max_bin)
+        mats, counts = sent[0::2], sent[1::2]
+        monkeypatch.setattr(
+            multihost_utils, "process_allgather",
+            lambda x: np.stack(mats if np.asarray(x).ndim == 2 else counts),
+        )
+        return [booster._merge_cuts_across_processes(local, max_bin) for local in processes]
+
+    for processes in ([[s] for s in sets], [sets[:2], sets[2:]]):
+        for merged in gathered(processes):
+            assert [c.tobytes() for c in merged] == [c.tobytes() for c in want]
+
+
+@pytest.mark.multichip
+def test_weighted_sketch_goes_shard_by_shard_through_the_same_path():
+    """The approx re-sketch: hessians weigh each shard's sketch where it lies
+    and the shards' candidates are merged, as set-up's are."""
+    from sagemaker_xgboost_container_tpu.data import binning
+
+    X, grad, _hess = _click_like(1003, seed=13)
+    y = (grad < 0).astype(np.float32)
+    session = _mesh_session(X, y, _mesh4(), params={"tree_method": "approx", "max_bin": 16})
+    assert session.approx_resketch and len(session._train_floats) == 4
+    first = [np.asarray(c).copy() for c in session.cuts]
+    session.run_rounds()   # re-sketches under the current hessians, then one round
+    session.end_turnaround()
+    # the hessians the re-sketch saw: base margin 0, so p (1 - p) = 0.25 a real row
+    weights = np.zeros(1004, np.float32)
+    weights[:1003] = 0.25
+    want = binning.merge_cut_candidates(
+        [
+            binning._host_cut_points(block, weights[s * 251 : (s + 1) * 251], 15)
+            for s, block in enumerate(_shard_blocks(X, 4))
+        ],
+        16,
+    )
+    assert [np.asarray(c).tobytes() for c in session.cuts] == [c.tobytes() for c in want]
+    assert len(first) == len(want)
+
+
+@pytest.mark.multichip
+def test_setup_spans_cover_the_phase_once_and_each_shard_inside_it(monkeypatch):
+    from sagemaker_xgboost_container_tpu.telemetry import spans
+
+    ended = []
+    real = spans.end_span
+    monkeypatch.setattr(
+        spans, "end_span",
+        lambda open_span, emit=False: (ended.append(open_span), real(open_span, emit=emit))[1],
+    )
+    X, grad, _hess = _click_like(1003, seed=14)
+    _mesh_session(X, (grad < 0).astype(np.float32), _mesh4())
+    names = [s.name for s in ended]
+    assert names.count("setup.sketch") == 1 and names.count("setup.bin_apply") == 1
+    assert names.count("setup.sketch.shard") == 4 and names.count("setup.bin_apply.shard") == 4
+    assert names.count("setup.sketch_merge") == 1
+    # the merge ends inside the sketch's span, the shards before the merge
+    assert names.index("setup.sketch_merge") < names.index("setup.sketch")
+    assert max(i for i, n in enumerate(names) if n == "setup.sketch.shard") < names.index(
+        "setup.sketch_merge"
+    )
+    # parts of a phase stay out of the round record's phases
+    assert all(s.covering for s in ended if s.name.endswith(".shard") or s.name == "setup.sketch_merge")

@@ -42,10 +42,13 @@ from ..data.binning import (
     sketch_shards,
 )
 from ..ops.histogram import (
+    choose_hist_impl,
     hist_comm_impl,
     padded_feature_width,
     resolve_hist_knobs,
     round_comm_plan,
+    round_hist_levels,
+    round_onehot_tiles,
 )
 from ..ops.ranking import (
     GroupLayout,
@@ -878,6 +881,20 @@ class _TrainingSession:
             "from the round program's shapes (ring formula, docs/DESIGN.md "
             "Communication; 0 on one device)",
         ).set(self.hist_comm_bytes_per_round)
+        tiles, tiles_unfolded = self._onehot_tile_plan()
+        REGISTRY.gauge(
+            "hist_onehot_tiles_per_round",
+            "One-hot tiles ([128 rows, 128 bin lanes]) a shard's level "
+            "histogram kernel latches a round: over the build's histogram "
+            "levels, row tiles x features x bin tiles after the fold "
+            "(ops/histogram.py::_bin_fold; 0 where the builder is not the "
+            "kernel)",
+        ).set(tiles)
+        REGISTRY.gauge(
+            "hist_onehot_tiles_unfolded_per_round",
+            "The same count with every level's fold at 1: what the kernel "
+            "would latch with the whole bin axis in the one-hot",
+        ).set(tiles_unfolded)
 
         # every dispatch records a `host_dispatch` span (python + XLA
         # dispatch until the async call returns) and a `device_sync` span
@@ -1561,6 +1578,23 @@ class _TrainingSession:
         cfg = self.config
         if self.mesh is None or self.n_data_shards <= 1:
             return [], 0
+        d_local, num_bins, subtract, trees_per_round = self._build_structure()
+        return round_comm_plan(
+            cfg.grow_policy,
+            cfg.max_depth,
+            cfg.max_leaves,
+            d_local,
+            num_bins,
+            self.n_data_shards,
+            self.hist_comm,
+            subtract,
+            trees_per_round=trees_per_round,
+        )
+
+    def _build_structure(self):
+        """(columns a shard histograms, bins, subtraction, trees a round):
+        the static structure of a round's tree builds, as they trace it."""
+        cfg = self.config
         # columns each data shard histograms: the whole width, unless a
         # feature axis splits them — under the 2-D reduce_scatter lowering
         # round_comm_plan further pads/scatters this local width to
@@ -1579,16 +1613,28 @@ class _TrainingSession:
             from ..ops.tree_build import _subtraction_enabled
 
             subtract = _subtraction_enabled(cfg.max_depth, d_local, num_bins)
-        return round_comm_plan(
-            cfg.grow_policy,
-            cfg.max_depth,
-            cfg.max_leaves,
+        return (
+            d_local, num_bins, subtract,
+            cfg.num_parallel_tree * max(self.num_group, 1),
+        )
+
+    def _onehot_tile_plan(self):
+        """(latched, unfolded) one-hot tiles a round of this session's level
+        histogram kernel — ops.histogram.round_onehot_tiles over a shard's
+        rows and columns; (0, 0) where the builder is not the kernel."""
+        if choose_hist_impl(self.hist_knobs.backend) != "pallas":
+            return 0, 0
+        cfg = self.config
+        d_local, num_bins, subtract, trees_per_round = self._build_structure()
+        return round_onehot_tiles(
+            round_hist_levels(
+                cfg.grow_policy, cfg.max_depth, cfg.max_leaves, subtract
+            ),
+            self.bins.shape[0] // self.n_data_shards,
             d_local,
             num_bins,
-            self.n_data_shards,
-            self.hist_comm,
-            subtract,
-            trees_per_round=cfg.num_parallel_tree * max(self.num_group, 1),
+            self.hist_knobs.precision,
+            trees_per_round=trees_per_round,
         )
 
     def _set_comm_round_fields(self):

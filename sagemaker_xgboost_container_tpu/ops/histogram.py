@@ -15,8 +15,12 @@ Two builders, one a backend (``choose_hist_impl``, as
   across the row-block grid. bf16x2 split-precision operands (hi/lo
   decomposition of f32 grads) keep MXU rate with ~f16-mantissa accuracy,
   accumulated in f32. The gradient operand holds the level's 2W rows
-  (``_operand_rows``), and a padding feature gets no dot. Interpreted on the
-  CPU backend (tests, rehearsals).
+  (``_operand_rows``), and a padding feature gets no dot. Where that operand
+  leaves free rows on a latched one-hot tile (W <= 8 at 256 bin lanes), the
+  bin axis is folded into it (``_bin_fold``): the kernel latches the one-hot
+  of a bin's low part alone, half the tiles, and the bin's high part picks
+  which copy of the operand a row's gradients ride. Interpreted on the CPU
+  backend (tests, rehearsals).
 * ``flat`` (everything else, and the tests' reference): one
   ``jax.ops.segment_sum`` over n*d flattened (node, feature, bin) ids. XLA
   lowers it to a sorted scatter-add — correct everywhere, fast on CPU,
@@ -233,6 +237,22 @@ def _wire_ratio(comm, axis_size):
 MERGE_COLLECTIVES_PER_SCAN = 7
 
 
+def round_hist_levels(grow_policy, max_depth, max_leaves, subtract):
+    """``(W, count)`` of the ``level_histogram`` calls one tree build issues:
+    W nodes built a call, ``count`` such calls. With sibling subtraction a
+    depth-wise level builds its left children alone and a loss-guided split
+    step one child."""
+    if grow_policy == "lossguide":
+        levels = [(1, 1)]                                # root
+        if max_leaves > 1:
+            levels.append((1 if subtract else 2, max_leaves - 1))  # per split step
+        return levels
+    return [(1, 1)] + [                                  # level 0, then 1 ..
+        (2 ** (level - 1) if subtract else 2**level, 1)
+        for level in range(1, max_depth)
+    ]
+
+
 def round_comm_plan(
     grow_policy,
     max_depth,
@@ -267,22 +287,14 @@ def round_comm_plan(
     d_eff = padded_feature_width(d, axis_size) if comm == "reduce_scatter" else d
     ratio = _wire_ratio(comm, axis_size)
     psum_ratio = _wire_ratio("psum", axis_size)
-    hist_widths = []
-    merge_widths = []   # winner-merge scan widths (reduce_scatter only)
+    hist_widths = round_hist_levels(grow_policy, max_depth, max_leaves, subtract)
     totals = []
     if grow_policy == "lossguide":
-        hist_widths.append((1, 1))                       # root
-        merge_widths.append((1, 1))
-        if max_leaves > 1:
-            w = 1 if subtract else 2
-            hist_widths.append((w, max_leaves - 1))      # per split step
-            merge_widths.append((2, max_leaves - 1))     # both fresh children
+        # winner-merge scan widths (reduce_scatter only): the root, then
+        # both fresh children a split step
+        merge_widths = [(1, 1)] + ([(2, max_leaves - 1)] if max_leaves > 1 else [])
     else:
-        hist_widths.append((1, 1))                       # level 0
-        merge_widths.append((1, 1))
-        for level in range(1, max_depth):
-            hist_widths.append((2 ** (level - 1) if subtract else 2**level, 1))
-            merge_widths.append((2**level, 1))           # full level scan
+        merge_widths = [(2**level, 1) for level in range(max_depth)]  # full level scans
         totals.append((2**max_depth, 1))                 # last-level node totals
     entries = []
     total_bytes = 0.0
@@ -505,6 +517,12 @@ def _mxu_split_missing(B):
     return B > 128 and (B - 1) % 128 == 0
 
 
+def _bin_lanes(B):
+    """Lanes of the kernel's bin axis (Bp): the bins the main dots hold,
+    without the missing bin where it is split out, padded to whole tiles."""
+    return _round_up(B - 1 if _mxu_split_missing(B) else B, 128)
+
+
 # rows a grid step of the Pallas histogram (on the lane axis: whole 128-lane
 # tiles)
 PALLAS_ROW_BLOCK = 512
@@ -541,8 +559,71 @@ def _operand_rows(W):
     cost (about 67 cycles), whatever rides it. Hence ONE dot with the halves
     stacked on the row axis (two dots are two latches: W <= 16 would cost
     what W = 32 does), and no operand narrower than the bf16 tile: under 64
-    rows nothing more is to be had from this side of the dot."""
+    rows nothing more is to be had from this side of the dot, so the rows a
+    latch still carries free take a part of the bin axis (``_bin_fold``)."""
     return _round_up(2 * W, 16)
+
+
+# rows of the streamed operand one latched one-hot tile carries at the
+# latch's own cost (_operand_rows' table: flat to 64 rows, linear from there)
+LATCH_FREE_ROWS = 64
+
+
+def _bin_fold(rows, bin_lanes, prec):
+    """Bin tiles folded into the streamed operand: the largest power of two
+    that cuts ``bin_lanes`` (the padded bin axis, Bp) into whole 128-lane
+    tiles and keeps ``fold`` copies of the stacked operand (``rows``, twice
+    for bf16x2) within LATCH_FREE_ROWS. From shapes alone, as ``rows`` and
+    the row chunks are; 1 is the unfolded kernel.
+
+    A bin is ``L * top + low`` with ``L = bin_lanes / fold``. The kernel
+    latches the one-hot of ``low`` alone ([L, blk]: 1/fold of the tiles) and
+    streams ``fold`` copies of the operand against it, copy t masked to the
+    rows whose ``top`` is t; copy t's product lands on lanes [t * L,
+    (t + 1) * L) of the same accumulator slab. The same products reach the
+    same cells in the same position of the same contraction, beside zeros.
+
+    ms a call (scripts/dissect.py --hist-levels re-reads it; 257 bins in u16:
+    256 bin lanes and the missing bin's own dot; one v5e, jax 0.9.0, PR 35),
+    by the level's node count, the shipped fold beside ``fold`` 1:
+
+        W (operand rows)      1 (16)  2 (16)  4 (16)  8 (16)  16 (32)  32 (64)  64 (128)
+        8.8M x 28, fold 1      45.5    45.4    45.4    45.5    46.2     86.5    169.2
+        8.8M x 28, fold 2      25.5    25.7    25.6    25.7
+        2.27M x 136, fold 1    61.7    61.4    61.6    61.3    63.0    114.7    219.0
+        2.27M x 136, fold 2    37.2    37.3    37.2    37.3
+
+    and the one-pass control at W = 1 (its operand is one half: 16 streamed
+    rows a copy): 44.8 -> 24.4 and 60.7 -> 35.5. Half the tiles at 64
+    streamed rows cost 56 to 61 % of all of them at 32 (3.86M -> 1.93M tiles
+    a call at 8.8M x 28): the floor was the latch, and the masked copies (two
+    selects on a [32, blk] bf16 operand a feature) cost nothing that shows,
+    whether selected as bf16, as 32-bit words or not at all (25.6 / 25.5 /
+    25.4). Past LATCH_FREE_ROWS a fold buys nothing: W = 16 at 64 rows
+    against all tiles (46.2) is what 128 rows against half would stream."""
+    stacked = rows * (2 if prec == "bf16x2" else 1)
+    tiles = bin_lanes // 128
+    fold = 1
+    while tiles % (2 * fold) == 0 and 2 * fold * stacked <= LATCH_FREE_ROWS:
+        fold *= 2
+    return fold
+
+
+def round_onehot_tiles(levels, n, d, num_bins, prec, trees_per_round=1):
+    """``(latched, unfolded)``: the [128, 128] one-hot tiles the Pallas kernel
+    latches a round over ``n`` rows x ``d`` features (a shard's), summed over
+    ``levels`` (``round_hist_levels``): row tiles x features x bin tiles
+    after the fold, and the same with ``fold`` 1. From shapes alone: what
+    the fold rule engages on, stated before a round runs."""
+    block = PALLAS_ROW_BLOCK
+    row_tiles = _round_up(n, block * _chunk_cap(-(-n // block))) // 128
+    lanes = _bin_lanes(num_bins)
+    latched = unfolded = 0
+    for W, count in levels:
+        tiles = count * trees_per_round * row_tiles * d * (lanes // 128)
+        unfolded += tiles
+        latched += tiles // _bin_fold(_operand_rows(W), lanes, prec)
+    return latched, unfolded
 
 
 def _floor_pow2(x):
@@ -592,7 +673,7 @@ def _pallas_feature_group(d, bins_dtype):
 
 @functools.lru_cache(maxsize=None)
 def _pallas_hist_fn(n, d, fg, W, B, block, prec, interpret, split_missing,
-                    rows, chunks):
+                    rows, chunks, fold=1):
     """Compiled pallas histogram over ROW-ON-LANES operands: (bins int
     [d_pad, n] — any integer storage dtype, widened per block in VMEM so
     u8/u16 bins move fewer HBM bytes — gh f32 [2, n], node i32 [1, n]) ->
@@ -607,6 +688,15 @@ def _pallas_hist_fn(n, d, fg, W, B, block, prec, interpret, split_missing,
     row axis ([2*rows, blk] against each latched one-hot tile), and the two
     halves of the product are added; the missing-bin product keeps both
     halves on its lane axis for the caller to add.
+
+    ``fold`` > 1 (see _bin_fold; 1 is the unfolded kernel): the latched
+    one-hot is that of ``bin % L``, L = Bp / fold lanes, and the streamed
+    operand is ``fold`` copies of the stacked one, copy t zeroed (a select on
+    the block's bf16 operand) in the rows whose ``bin // L`` is not t; copy
+    t's rows of the product are added into lanes [t * L, (t + 1) * L) of the
+    feature's slab. The missing bin (B - 1 = fold * L where it is split out)
+    matches no copy, as it matches no lane of the unfolded one-hot. Output
+    shapes and the kernel's name do not depend on ``fold``.
 
     Every operand keeps rows on the lane axis, so the kernel has no
     lane-sparse [block, 1] blocks, no in-kernel transposes and no strided
@@ -624,14 +714,16 @@ def _pallas_hist_fn(n, d, fg, W, B, block, prec, interpret, split_missing,
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    Bm = B - 1 if split_missing else B
-    Bp = _round_up(Bm, 128)
+    Bp = _round_up(B - 1 if split_missing else B, 128)
     d_pad = _round_up(d, fg)
     groups = d_pad // fg
     real_in_last = d - (groups - 1) * fg   # features of the last group
     per = n // (block * chunks)            # row blocks a chunk
     stacked = prec == "bf16x2"
     miss_rows = 2 * rows if stacked else rows
+    L = Bp // fold                         # lanes of the latched one-hot
+    if L * fold != Bp or L % 128:
+        raise ValueError("fold {} does not cut {} bin lanes into whole tiles".format(fold, Bp))
 
     def kernel(bins_ref, gh_ref, node_ref, out_ref, miss_ref):
         @pl.when(pl.program_id(2) == 0)
@@ -656,14 +748,31 @@ def _pallas_hist_fn(n, d, fg, W, B, block, prec, interpret, split_missing,
         lanes = (((1,), (1,)), ((), ()))               # contract rows
 
         bw = bins_ref[...].astype(jnp.int32)           # widen in VMEM
-        iota_b = jax.lax.broadcasted_iota(jnp.int32, (Bp, block), 0)
+        iota_b = jax.lax.broadcasted_iota(jnp.int32, (L, block), 0)
+        S = A.shape[0]                                 # streamed rows a copy
 
         def feature(f):
-            ob = (iota_b == bw[f:f + 1, :]).astype(jnp.bfloat16)   # [Bp, blk]
+            b = bw[f:f + 1, :]                         # [1, blk]
+            if fold == 1:
+                low, Af = b, A
+            else:
+                # bin = L * top + low: the one-hot of ``low`` is latched,
+                # ``top`` picks the copy of the operand a row's g and h ride
+                top = sum((b >= t * L).astype(jnp.int32) for t in range(1, fold))
+                low = b - L * top
+                zero = jnp.zeros_like(A)
+                Af = jnp.concatenate(
+                    [jnp.where(top == t, A, zero) for t in range(fold)], axis=0
+                )                                      # [fold * S, blk]
+            ob = (iota_b == low).astype(jnp.bfloat16)  # [L, blk]
             P = jax.lax.dot_general(
-                A, ob, lanes, preferred_element_type=jnp.float32
+                Af, ob, lanes, preferred_element_type=jnp.float32
             )
-            out_ref[0, f] += (P[:rows] + P[rows:]) if stacked else P
+            for t in range(fold):
+                Pt = P[t * S:(t + 1) * S]
+                out_ref[0, f, :, t * L:(t + 1) * L] += (
+                    (Pt[:rows] + Pt[rows:]) if stacked else Pt
+                )
 
         for f in range(real_in_last):                  # real in every group
             feature(f)
@@ -742,7 +851,7 @@ def _hist_pallas(bins, grad, hess, node_local, num_nodes, num_bins,
     rows = _operand_rows(W)
     fn = _pallas_hist_fn(
         n_pad, d, fg, W, B, block, prec, pallas_interpret(), split_missing,
-        rows, _row_chunks(W, cap),
+        rows, _row_chunks(W, cap), _bin_fold(rows, _bin_lanes(B), prec),
     )
     main, miss = fn(bins_t, gh, node[None, :].astype(jnp.int32))
 

@@ -24,8 +24,10 @@ over the same rows, then the build's own read set at its two widest levels
 counts. ``--hist-levels`` runs the level-histogram probe: the
 Pallas kernel builder called directly at the two cells' shapes, ms a call at
 every level's node count W under the operand-row rule
-(``ops/histogram._operand_rows``), then at W = 1 with the operand padded to
-more rows: the table the rule was read from. Run under an external timeout,
+(``ops/histogram._operand_rows``) and the bin fold (``_bin_fold``: each folded
+level also at ``fold`` 1, with the one-hot tiles a call latches), then at
+W = 1 with the operand padded to more rows: the tables the two rules were
+read from. Run under an external timeout,
 like anything that holds a device.
 """
 
@@ -293,11 +295,12 @@ HIST_PROBE_ROWS = (16, 32, 64, 128)           # operand rows at W = 1
 
 def hist_level_probe(shapes, num_bins=MAX_BIN + 1):
     """ms a call of the level histogram kernel by node count W under the
-    shipped operand-row and chunk rules, then by operand rows at W = 1 in
-    both precisions, with the rows the MXU streams against one latched
-    one-hot tile (both halves of the split operand) and the share of the
-    MXU's peak that the issued flops make. Bins and gradients are made on
-    the device."""
+    shipped operand-row, chunk and fold rules (a folded level also at
+    ``fold`` 1), then by operand rows at W = 1 in both precisions, with the
+    rows the MXU streams against one latched one-hot tile (both halves of
+    the split operand, every folded copy), the tiles a call latches and the
+    share of the MXU's peak that the issued flops make. Bins and gradients
+    are made on the device."""
     import jax
     import jax.numpy as jnp
 
@@ -306,7 +309,7 @@ def hist_level_probe(shapes, num_bins=MAX_BIN + 1):
 
     block, B = H.PALLAS_ROW_BLOCK, num_bins
     split_missing = H._mxu_split_missing(B)
-    bin_lanes = -(-(B - 1 if split_missing else B) // 128) * 128
+    bin_lanes = H._bin_lanes(B)
     # no published peak for this device kind: the share is left out
     peak = PEAKS.get(jax.devices()[0].device_kind, {}).get("flops_bf16")
     out = []
@@ -326,33 +329,41 @@ def hist_level_probe(shapes, num_bins=MAX_BIN + 1):
         gh = jax.random.normal(k_gh, (2, n_pad), jnp.float32)
         jax.block_until_ready((bins, gh))
 
-        def call(W, rows, chunks, prec="bf16x2"):
+        def call(W, rows, chunks, prec="bf16x2", fold=1):
             node = jax.random.randint(k_node, (1, n_pad), 0, W, jnp.int32)
             fn = jax.jit(H._pallas_hist_fn(
                 n_pad, d, fg, W, B, block, prec, H.pallas_interpret(),
-                split_missing, rows, chunks,
+                split_missing, rows, chunks, fold,
             ))
             ms = _time(fn, bins, gh, node)
-            # what the MXU is handed: for every real feature the operand's
-            # rows (both halves of bf16x2 in one dot) against a [block,
-            # bin_lanes] one-hot, two flops a multiply-add
-            streamed = rows * (2 if prec == "bf16x2" else 1)
-            flops = 2.0 * n_pad * d * streamed * bin_lanes
+            # what the MXU is handed: for every real feature ``fold`` copies
+            # of the operand's rows (both halves of bf16x2 in one dot)
+            # against a [block, bin_lanes / fold] one-hot, two flops a
+            # multiply-add; a tile is [128 rows, 128 bin lanes] of it
+            streamed = fold * rows * (2 if prec == "bf16x2" else 1)
+            flops = 2.0 * n_pad * d * streamed * (bin_lanes // fold)
             row = {
                 "shape": name, "W": W, "prec": prec, "operand_rows": rows,
-                "chunks": chunks, "streamed_rows_a_tile": streamed, "ms": ms,
+                "chunks": chunks, "fold": fold,
+                "streamed_rows_a_tile": streamed,
+                "tiles_latched": (n_pad // 128) * d * (bin_lanes // 128 // fold),
+                "ms": ms,
                 "mxu_share_of_peak": flops / (ms * 1e-3) / peak if peak else None,
             }
             print(json.dumps(row), flush=True)
             out.append(row)
 
         for W in HIST_PROBE_LEVELS:
-            call(W, H._operand_rows(W), H._row_chunks(W, cap))
+            rows = H._operand_rows(W)
+            # the shipped fold, and the unfolded kernel beside it
+            for fold in sorted({1, H._bin_fold(rows, bin_lanes, "bf16x2")}):
+                call(W, rows, H._row_chunks(W, cap), fold=fold)
         # the rule's table: one latched one-hot tile costs what 64 streamed
         # rows cost; the one-pass control reaches 16 rows a tile
         for prec in H.HIST_PRECISIONS:
             for rows in HIST_PROBE_ROWS:
-                call(1, rows, 1, prec)
+                for fold in sorted({1, H._bin_fold(rows, bin_lanes, prec)}):
+                    call(1, rows, 1, prec, fold)
         del bins, gh
     return out
 

@@ -337,6 +337,169 @@ def test_operand_rows_follow_the_level(W, d, B):
     np.testing.assert_allclose(np.asarray(H1), np.asarray(H0), atol=5e-3)
 
 
+FOLD_BINS = [
+    (128, np.uint8), (129, np.uint8), (256, np.uint8), (256, np.uint16),
+    (257, np.uint16), (513, np.uint16),
+]
+
+
+def _unfolded(monkeypatch):
+    """The kernel with the whole bin axis in its one-hot: the parent's."""
+    monkeypatch.setattr(hist_mod, "_bin_fold", lambda rows, lanes, prec: 1)
+
+
+@pytest.fixture
+def drop_compiled_kernels():
+    """An interpreted kernel with its features unrolled is some 650 memory
+    mappings of compiled code, kept for the process's life: a hundred cases
+    of them would bring a worker to the kernel's limit of 65,530."""
+    yield
+    import jax
+
+    hist_mod._pallas_hist_fn.cache_clear()
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("B, dtype", FOLD_BINS, ids=lambda v: getattr(v, "__name__", str(v)))
+@pytest.mark.parametrize("d", [5, 28, 39])
+@pytest.mark.parametrize("W", [1, 2, 4, 8, 16, 64])
+def test_folded_kernel_equals_the_unfolded_one_to_the_bit(
+    monkeypatch, drop_compiled_kernels, W, d, B, dtype
+):
+    """Where the latch has free rows the kernel latches the one-hot of a
+    bin's low part alone and the high part picks the operand's copy: every
+    product lands where it landed, so both histograms keep every bit, over
+    rows in the missing bin (B - 1), dead rows, a row count that pads (1,100
+    to three blocks), one bin tile (no fold), two and four."""
+    bins, grad, hess, node = _level_problem(41 + W, 1100, d, B, W, dtype)
+    assert (np.asarray(node) < 0).any() and (np.asarray(bins) == B - 1).any()
+    lanes = hist_mod._bin_lanes(B)
+    fold = hist_mod._bin_fold(hist_mod._operand_rows(W), lanes, "bf16x2")
+    assert fold == (2 if W <= 8 and lanes >= 256 else 1)
+    G1, H1 = hist_mod._hist_pallas(bins, grad, hess, node, W, B)
+    _unfolded(monkeypatch)
+    G0, H0 = hist_mod._hist_pallas(bins, grad, hess, node, W, B)
+    np.testing.assert_array_equal(np.asarray(G1), np.asarray(G0))
+    np.testing.assert_array_equal(np.asarray(H1), np.asarray(H0))
+    assert np.asarray(H1).any() and G1.shape == (W, d, B)
+
+
+@pytest.mark.parametrize("W, B, fold", [(1, 257, 2), (16, 257, 2), (32, 257, 1), (8, 513, 4)])
+def test_one_pass_control_folds_too_and_keeps_its_bits(monkeypatch, drop_compiled_kernels, W, B, fold):
+    """The one-pass control streams half the rows, so it folds one level
+    further (and four tiles of u16 bins into one): the same rounded sums."""
+    bins, grad, hess, node = _level_problem(43 + W, 1100, 28, B, W, np.uint16)
+    assert hist_mod._bin_fold(hist_mod._operand_rows(W), hist_mod._bin_lanes(B), "bf16") == fold
+    G1, H1 = hist_mod._hist_pallas(bins, grad, hess, node, W, B, prec="bf16")
+    _unfolded(monkeypatch)
+    G0, H0 = hist_mod._hist_pallas(bins, grad, hess, node, W, B, prec="bf16")
+    np.testing.assert_array_equal(np.asarray(G1), np.asarray(G0))
+    np.testing.assert_array_equal(np.asarray(H1), np.asarray(H0))
+
+
+@pytest.mark.parametrize(
+    "W, B, prec, fold",
+    [
+        # 256 bin lanes (max_bin 256 with its missing bin split out, or 255)
+        (1, 257, "bf16x2", 2), (2, 257, "bf16x2", 2), (4, 257, "bf16x2", 2),
+        (8, 257, "bf16x2", 2), (16, 257, "bf16x2", 1), (32, 257, "bf16x2", 1),
+        (64, 257, "bf16x2", 1), (8, 256, "bf16x2", 2), (8, 200, "bf16x2", 2),
+        # one tile: max_bin <= 127
+        (1, 128, "bf16x2", 1), (1, 129, "bf16x2", 1), (8, 64, "bf16x2", 1),
+        # u16 bins: two of four tiles at W <= 8, whole tiles only (three: none)
+        (1, 513, "bf16x2", 2), (8, 513, "bf16x2", 2), (16, 513, "bf16x2", 1),
+        (1, 385, "bf16x2", 1), (8, 1025, "bf16x2", 2),
+        # the one-pass control streams half the rows
+        (8, 257, "bf16", 2), (16, 257, "bf16", 2), (32, 257, "bf16", 1),
+        (8, 513, "bf16", 4), (16, 513, "bf16", 2), (1, 129, "bf16", 1),
+    ],
+)
+def test_bin_fold_follows_the_level_the_bin_lanes_and_the_precision(W, B, prec, fold):
+    """``fold`` copies of the stacked operand fit the rows a latch carries
+    free (64), and the folded one-hot is whole 128-lane tiles."""
+    assert hist_mod.LATCH_FREE_ROWS == 64
+    rows, lanes = hist_mod._operand_rows(W), hist_mod._bin_lanes(B)
+    got = hist_mod._bin_fold(rows, lanes, prec)
+    assert got == fold
+    assert got * rows * (2 if prec == "bf16x2" else 1) <= 64 or got == 1
+    assert (lanes // 128) % got == 0
+
+
+def test_a_fold_that_cuts_no_whole_tiles_is_refused():
+    with pytest.raises(ValueError, match="whole tiles"):
+        hist_mod._pallas_hist_fn(512, 5, 16, 1, 385, 512, "bf16x2", True, True, 16, 1, 2)
+
+
+@pytest.mark.parametrize(
+    "policy, depth, leaves, subtract, levels",
+    [
+        ("depthwise", 8, 0, True, [(1, 1), (1, 1), (2, 1), (4, 1), (8, 1), (16, 1), (32, 1), (64, 1)]),
+        ("depthwise", 3, 0, False, [(1, 1), (2, 1), (4, 1)]),
+        ("depthwise", 1, 0, False, [(1, 1)]),
+        ("lossguide", 0, 31, True, [(1, 1), (1, 30)]),
+        ("lossguide", 0, 31, False, [(1, 1), (2, 30)]),
+        ("lossguide", 0, 1, True, [(1, 1)]),
+    ],
+)
+def test_round_hist_levels_are_the_calls_a_build_issues(policy, depth, leaves, subtract, levels):
+    assert hist_mod.round_hist_levels(policy, depth, leaves, subtract) == levels
+
+
+@pytest.mark.parametrize(
+    "n, d, B, prec, depth, pct",
+    [
+        (8_800_000, 28, 257, "bf16x2", 8, 68.75),     # higgs-d8: five of eight levels at half
+        (2_270_296, 136, 257, "bf16x2", 8, 68.75),    # mslr-ndcg
+        (16_387_491, 39, 257, "bf16x2", 8, 68.75),    # both Criteo cells, a chip
+        (8_800_000, 28, 257, "bf16", 8, 62.5),        # the one-pass control: six of eight
+        (8_800_000, 28, 128, "bf16x2", 8, 100.0),     # one bin tile: nothing to fold
+        (8_800_000, 28, 257, "bf16x2", 4, 50.0),      # a depth-4 tree: every level
+    ],
+)
+def test_onehot_tile_plan_counts_what_the_fold_saves(n, d, B, prec, depth, pct):
+    levels = hist_mod.round_hist_levels("depthwise", depth, 0, True)
+    latched, unfolded = hist_mod.round_onehot_tiles(levels, n, d, B, prec)
+    row_tiles = -(-n // (512 * 32)) * 512 * 32 // 128
+    assert unfolded == depth * row_tiles * d * (hist_mod._bin_lanes(B) // 128)
+    assert 100.0 * latched / unfolded == pct
+    twice = hist_mod.round_onehot_tiles(levels, n, d, B, prec, trees_per_round=2)
+    assert twice == (2 * latched, 2 * unfolded)
+    assert hist_mod.round_onehot_tiles(levels, 0, d, B, prec) == (0, 0)
+
+
+@pytest.mark.parametrize("chip", [True, False])
+def test_session_states_its_tile_plan_where_the_kernel_builds(chip):
+    """The two gauges the benchmark's ``hist_tiles_latched_pct`` divides: set
+    at session build from shapes, 0 where the builder is not the kernel."""
+    import json
+
+    from sagemaker_xgboost_container_tpu.data.matrix import DataMatrix
+    from sagemaker_xgboost_container_tpu.models import train
+    from sagemaker_xgboost_container_tpu.telemetry import REGISTRY
+
+    root = pathlib.Path(__file__).parent.parent
+    spec = json.loads((root / "benchmark/layer_metrics/hist_tiles_latched_pct.json").read_text())
+    assert spec["reader"] == "gauge_ratio" and spec["args"]["scale"] == 100.0
+    rng = np.random.RandomState(5)
+    X = rng.rand(600, 4).astype(np.float32)
+    train(
+        {"max_depth": 5, "max_bin": 256}, DataMatrix(X, labels=X[:, 0]), num_boost_round=1,
+        hist_knobs=_chip_knobs() if chip else None,
+    )
+    gauges = {
+        name: family[0].value for name, _kind, _help, family in REGISTRY.collect()
+        if name in (spec["args"]["over"], spec["args"]["under"])
+    }
+    over, under = gauges[spec["args"]["over"]], gauges[spec["args"]["under"]]
+    if not chip:
+        assert (over, under) == (0, 0)
+        return
+    # 600 rows pad to 1,024: 8 row tiles x 4 features x 2 bin tiles, 5 levels
+    assert under == 5 * 8 * 4 * 2
+    # W = 1, 1, 2, 4, 8: every level of a depth-5 tree folds
+    assert over == under // 2
+
+
 @pytest.mark.parametrize("d, dtype", [(28, np.uint16), (40, np.uint8), (70, np.uint8)])
 def test_padding_features_get_no_dot(d, dtype):
     """The operand block is a whole (fg, block) tile, but a padding feature

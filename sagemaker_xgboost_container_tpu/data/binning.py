@@ -312,10 +312,32 @@ def _float_block(block, device):
     return jax.device_put(np.asarray(block, np.float32), device)
 
 
-def _device_cut_points(features, w, max_cuts, blocks, block_columns, device=None):
+def _staged_block(block, device, phase, attributes):
+    """``_float_block`` for one block of a set-up phase, split where the work
+    changes hands: ``<phase>.stage`` is the host's own (the conversion where
+    the matrix is not float32 already; a float32 block stays the view it is:
+    handed a strided column block, jax gathers it into the transfer many
+    times faster than numpy copies it, PERF.md section 6, PR 36),
+    ``<phase>.transfer`` the link's (the put, until the block is on the
+    chip; ``bytes_up`` what it moved). The wait delays nothing: the kernel
+    cannot start before its block is there. What is on a device already is
+    staged by that device, inside the transfer span."""
+    with span(phase + ".stage", covering=True, attributes=attributes):
+        if isinstance(block, np.ndarray):
+            block = np.asarray(block, np.float32)
+    moved = dict(attributes, bytes_up=_host_bytes(block))
+    with span(phase + ".transfer", covering=True, attributes=moved):
+        return _float_block(block, device).block_until_ready()
+
+
+def _device_cut_points(features, w, max_cuts, blocks, block_columns, device=None, shard=0):
     """compute_cut_points's selection semantics as one vmapped XLA program,
     run on ``device`` over ``blocks`` blocks of ``block_columns`` columns
-    (``sketch_shards`` sizes them by ``DEVICE_BLOCK_BYTES``).
+    (``sketch_shards`` sizes them by ``DEVICE_BLOCK_BYTES``), one block after
+    another: each is staged, put, sketched and fetched under covering spans
+    ``setup.sketch.stage`` / ``.transfer`` / ``.kernel`` / ``.fetch``
+    (attributes ``shard``, ``block``), which say whether the host, the link
+    or the chip had the work.
 
     Mirrors the _select_cuts ALGORITHM step for step: stable sort, cumulative
     weight at each distinct value's run end, evenly spaced weighted-quantile
@@ -343,20 +365,27 @@ def _device_cut_points(features, w, max_cuts, blocks, block_columns, device=None
     # the dataset has fewer rows than max_cuts (n=100, max_bin=256)
     L = max(n, max_cuts)
     kernel = _cut_points_kernel(max_cuts, L)
-    wv = _float_block(w, device)
+    wv = _staged_block(w, device, "setup.sketch", {"shard": shard, "block": "weights"})
     cuts = []
     for b in range(blocks):
+        attributes = {"shard": shard, "block": b}
         lo = min(b * block_columns, d - block_columns)
-        mids, counts = kernel(
-            _float_block(features[:, lo : lo + block_columns], device), wv
+        block = _staged_block(
+            features[:, lo : lo + block_columns], device, "setup.sketch", attributes
         )
-        mids = np.asarray(mids, np.float32)
-        counts = np.asarray(counts)
-        # an overlapping last block repeats columns the one before gave
-        cuts += [
-            mids[f - lo, : int(counts[f - lo])].copy()
-            for f in range(len(cuts), lo + block_columns)
-        ]
+        # dispatch (and, the first time, the kernel's load) until the result is ready
+        with span("setup.sketch.kernel", covering=True, attributes=attributes):
+            mids, counts = kernel(block, wv)
+            del block
+            counts.block_until_ready()
+        with span("setup.sketch.fetch", covering=True, attributes=attributes):
+            mids = np.asarray(mids, np.float32)
+            counts = np.asarray(counts)
+            # an overlapping last block repeats columns the one before gave
+            cuts += [
+                mids[f - lo, : int(counts[f - lo])].copy()
+                for f in range(len(cuts), lo + block_columns)
+            ]
     return cuts
 
 
@@ -445,7 +474,7 @@ def sketch_shards(features, weights, max_bin, devices=(None,), merge=None):
         with span("setup.sketch.shard", covering=True, attributes=shard_attributes[s]):
             if on_device:
                 return _device_cut_points(
-                    features[s], weights[s], max_cuts, blocks, block_columns, devices[s]
+                    features[s], weights[s], max_cuts, blocks, block_columns, devices[s], s
                 )
             return _host_cut_points(features[s], weights[s], max_cuts)
 
@@ -556,7 +585,7 @@ def apply_shards(features, cut_points, max_bin, devices=(None,), name=None, to_h
         with span("setup.bin_apply.shard", covering=True, attributes=shard_attributes[s]):
             if not on_device:
                 return _host_apply(np.asarray(features[s]), cut_points, max_bin, dtype)
-            bins = _device_apply(features[s], padded, counts, max_bin, devices[s])
+            bins = _device_apply(features[s], padded, counts, max_bin, devices[s], s)
             return np.asarray(bins) if to_host else bins.block_until_ready()
 
     attributes = {
@@ -605,27 +634,39 @@ def _apply_kernel(max_bin):
     return kernel
 
 
-def _device_apply(features, padded, counts, max_bin, device=None):
+def _device_apply(features, padded, counts, max_bin, device=None, shard=0):
     """Binning as one vmapped on-device searchsorted (the binning stage's
     other host loop, ~5s for 1M x 28), on ``device``. Cuts pad to [d, L]
     with +inf (finite values never land in the pad; +inf values clip to the
     feature's true cut count, matching numpy searchsorted semantics). A
     matrix over ``DEVICE_BLOCK_BYTES`` goes through in equal blocks of rows,
-    joined on the device."""
+    joined on the device; each block under covering spans
+    ``setup.bin_apply.stage`` / ``.transfer`` / ``.kernel`` (attributes
+    ``shard``, ``block``), the join under the last block's kernel span."""
     import jax
     import jax.numpy as jnp
 
     n, d = features.shape
     kernel = _apply_kernel(max_bin)
-    cuts_dev, counts_dev = jax.device_put((padded, counts), device)
+    moved = {"shard": shard, "block": "cuts", "bytes_up": padded.nbytes + counts.nbytes}
+    with span("setup.bin_apply.transfer", covering=True, attributes=moved):
+        cuts_dev, counts_dev = jax.device_put((padded, counts), device)
+        counts_dev.block_until_ready()
     blocks, rows = _equal_blocks(n, DEVICE_BLOCK_BYTES // DEVICE_BYTES_PER_VALUE // d)
     parts = []
     for b in range(blocks):
+        attributes = {"shard": shard, "block": b}
         lo = min(b * rows, n - rows)
-        part = kernel(_float_block(features[lo : lo + rows], device), cuts_dev, counts_dev)
-        # an overlapping last block repeats rows the one before gave
-        parts.append(part[b * rows - lo :])
-    return parts[0] if blocks == 1 else jnp.concatenate(parts, axis=0)
+        block = _staged_block(features[lo : lo + rows], device, "setup.bin_apply", attributes)
+        with span("setup.bin_apply.kernel", covering=True, attributes=attributes):
+            part = kernel(block, cuts_dev, counts_dev)
+            del block
+            # an overlapping last block repeats rows the one before gave
+            parts.append(part[b * rows - lo :])
+            if b == blocks - 1 and blocks > 1:
+                parts = [jnp.concatenate(parts, axis=0)]
+            parts[-1].block_until_ready()
+    return parts[0]
 
 
 def resolve_max_bin(cut_points, max_bin, exact_cap=None):

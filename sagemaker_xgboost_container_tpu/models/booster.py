@@ -76,7 +76,14 @@ from ..telemetry.device import (
     STAGE_PACK,
     stage,
 )
-from ..telemetry.spans import active_recorder, begin_span, end_span, span
+from ..telemetry.spans import (
+    active_recorder,
+    begin_span,
+    end_span,
+    note_phase_memory,
+    record_startup,
+    span,
+)
 from ..toolkit import exceptions as exc
 from ..utils.faults import fault_point
 from . import eval_metrics
@@ -84,6 +91,10 @@ from . import objectives as objectives_mod
 from .forest import Forest, compact_padded_tree
 
 logger = logging.getLogger(__name__)
+
+# the one jax.monitoring listener, from the import on: what a caller loads
+# in front of its first session is counted too (under no phase)
+install_program_listener()
 
 # objective hyperparameters carried into the saved model / objective
 # construction (shared by train() and the fold-parallel CV path)
@@ -669,6 +680,7 @@ class _TrainingSession:
                     for shard in self._train_rows.shards
                 ]
             )
+            self._note_setup_memory("setup.sketch")
             max_bin = resolve_max_bin(cuts, config.max_bin, config.exact_bin_cap)
             shard_bins = apply_shards(
                 self._train_floats, cuts, max_bin, self._train_rows.devices, name="train"
@@ -721,6 +733,7 @@ class _TrainingSession:
             self.eval_sets.append((name, dm, binned))
             self._eval_rows.append(rows)
             staged.append(shard_bins)
+        self._note_setup_memory("setup.bin_apply")
         with self._upload_span("labels_weights_margins"):
             self.labels = _put(_layout_rows(labels, 0.0), P("data"))
             self.weights = _put(_layout_rows(dtrain.get_weight(), 0.0), P("data"))
@@ -772,6 +785,7 @@ class _TrainingSession:
                     )
                 else:
                     self.eval_margins.append(_put(np.full(eshape, base, np.float32), margin_spec))
+        self._note_setup_memory("setup.upload")
 
         self.rng = jax.random.PRNGKey(config.seed)
 
@@ -929,6 +943,7 @@ class _TrainingSession:
         with span("setup.program_build"):
             self._round_fn = self._make_round_fn()
             self._apply_fn = self._make_apply_fn()
+        self._note_setup_memory("setup.program_build")
         self._introspect_compiled_cost(self._register_round_program())
 
     def _put_layout(self, layout, specs=None):
@@ -1706,22 +1721,6 @@ class _TrainingSession:
             "collectives (ring formula, docs/DESIGN.md Communication)",
             labels,
         ).inc(self.hist_comm_bytes_per_round * k_rounds)
-        # trace the dispatch as a span under the open round span; the span
-        # duration is the calibrated isolated collective latency (0 until
-        # calibration lands) — an estimate, flagged as such in the attrs
-        from ..telemetry import tracing
-
-        if tracing.enabled():
-            tracing.record_span(
-                "collective.dispatch",
-                duration_s=(self._hist_comm_ms or 0.0) * k_rounds / 1000.0,
-                attributes={
-                    "impl": self.hist_comm,
-                    "bytes": self.hist_comm_bytes_per_round * k_rounds,
-                    "rounds": k_rounds,
-                    "calibrated": bool(self._hist_comm_ms),
-                },
-            )
 
     @contextlib.contextmanager
     def _upload_span(self, what):
@@ -1911,7 +1910,7 @@ class _TrainingSession:
         the `host_turnaround` that the previous dispatch's `device_sync`
         began: from there on the device has work again."""
         pre_compile = compile_stats()["seconds"]
-        with span("host_dispatch", attributes=attributes):
+        with self._first_dispatch_part("load"), span("host_dispatch", attributes=attributes):
             out = dispatch()
         # an XLA compile that completed inside THIS dispatch is wall time
         # the host_dispatch span already contains; RoundTimer reports it
@@ -2002,6 +2001,24 @@ class _TrainingSession:
         if self._first_dispatch is not None:
             end_span(self._first_dispatch)
             self._first_dispatch = None
+            self._note_setup_memory("setup.first_dispatch")
+
+    def _first_dispatch_part(self, part):
+        """Inside `setup.first_dispatch` its `host_dispatch` is the covering
+        span `setup.first_dispatch.load` too (trace, lower, compile or load
+        from the cache, up to the asynchronous call's return) and its
+        `device_sync` is `setup.first_dispatch.run` (K rounds running); in
+        every later dispatch, nothing."""
+        if self._first_dispatch is None:
+            return contextlib.nullcontext()
+        return span("setup.first_dispatch." + part, covering=True)
+
+    def _note_setup_memory(self, phase):
+        """The fullest chip's memory where a top-level set-up span has ended
+        (`setup.bin_apply` and `setup.upload`: the last of them): gauges
+        `setup_hbm_bytes{phase, what}`, five reads a session."""
+        devices = jax.local_devices()[:1] if self.mesh is None else self.mesh.local_devices
+        note_phase_memory(phase, devices)
 
     def _device_sync(self, packed, out, attributes, fenced):
         """The packed trees on the host, under a `device_sync` span: the
@@ -2010,7 +2027,7 @@ class _TrainingSession:
         K = 1 path's eval-apply programs are separate dispatches. Where the
         span ends the device has nothing queued, so `host_turnaround`
         begins (and `setup.first_dispatch` ends)."""
-        with span("device_sync", attributes=attributes):
+        with self._first_dispatch_part("run"), span("device_sync", attributes=attributes):
             if fenced:
                 jax.block_until_ready(out)
             packed_np = np.asarray(packed)
@@ -2405,6 +2422,7 @@ def train(
     snapshot so the rebuilt (smaller-mesh) session trains under identical
     kernel choices.
     """
+    record_startup(entering_train=True)  # what ran in front of the first train(), once
     config = TrainConfig(params)
     callbacks = list(callbacks or [])
 

@@ -46,7 +46,7 @@ from ..utils.envconfig import env_float, env_int, env_port
 from . import tracing
 from .emit import emit_metric
 from .registry import REGISTRY, percentile
-from .spans import current_phase
+from .spans import add_interval, current_phase, outermost_phase
 
 logger = logging.getLogger(__name__)
 
@@ -164,24 +164,52 @@ _PROGRAM_STAGE_OF_EVENT = {
 _CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 #: the last program loads, newest last: (stage, phase, fun_name, seconds)
 _program_events = collections.deque(maxlen=512)
+#: the newest intervals in which some thread was loading a program, disjoint
+#: and sorted (spans.add_interval), on time.perf_counter's clock. A trace's
+#: inner traces end before it does and it then takes them up: the tuple
+#: stays short, and the oldest go only when a program has thousands open
+_program_wall = ()
+_PROGRAM_WALL_KEPT = 4096
 
 
-def _count_program(stage, duration, fun_name):
-    phase = current_phase()
-    labels = {"stage": stage, "phase": phase}
+def _count_program_seconds(stage, phase, seconds):
     REGISTRY.counter(
         "xla_program_seconds_total",
         help="Seconds spent tracing, lowering, compiling and cache-loading "
         "XLA programs, by stage and by the span (phase) that was open",
-        labels=labels,
-    ).inc(float(duration))
+        labels={"stage": stage, "phase": phase},
+    ).inc(seconds)
+
+
+def _count_program(stage, duration, fun_name):
+    phase = current_phase()
+    _count_program_seconds(stage, phase, float(duration))
     REGISTRY.counter(
         "xla_programs_total",
         help="XLA program-load events, by stage and open span (phase)",
-        labels=labels,
+        labels={"stage": stage, "phase": phase},
     ).inc()
     if stage != "trace":  # one line a program: every inner jit traces too
         _program_events.append((stage, phase, fun_name or "", float(duration)))
+
+
+def _count_program_wall(duration):
+    """Program load as wall time: what the interval ``duration`` long that
+    ends now adds to the union of all such intervals on all threads (the
+    sketch's threads load side by side; a trace holds the traces, lowerings
+    and loads of the functions it calls), under the outermost span open on
+    this thread. ``xla_program_seconds_total`` sums thread-seconds."""
+    global _program_wall
+    with _runtime_lock:
+        end = time.perf_counter()
+        covered, added = add_interval(_program_wall, end - float(duration), end)
+        _program_wall = covered[-_PROGRAM_WALL_KEPT:]
+    REGISTRY.counter(
+        "xla_program_wall_seconds_total",
+        help="Wall seconds in which some thread was tracing, lowering, "
+        "compiling or cache-loading an XLA program, by the outermost open span",
+        labels={"phase": outermost_phase()},
+    ).inc(added)
 
 
 def program_events():
@@ -211,6 +239,7 @@ def _own_trace_seconds(duration):
 def _on_jax_duration_event(event, duration, **kwargs):
     stage = _PROGRAM_STAGE_OF_EVENT.get(event)
     if stage is not None:
+        _count_program_wall(duration)
         if stage == "trace":
             duration = _own_trace_seconds(float(duration))
         _count_program(stage, duration, kwargs.get("fun_name"))
@@ -226,6 +255,7 @@ def _on_jax_duration_event(event, duration, **kwargs):
         return
     cache_hit = getattr(_listener_tls, "cache_hit", False)
     _listener_tls.cache_hit = False
+    _count_program_wall(duration)
     _count_program(
         "cache_load" if cache_hit else "compile", duration, kwargs.get("fun_name")
     )
@@ -260,6 +290,11 @@ def install_program_listener():
             monitoring.register_event_duration_secs_listener(_on_jax_duration_event)
         except Exception:  # jax absent or monitoring API unavailable: no-op
             logger.debug("jax.monitoring unavailable; compile gauges disabled")
+            return
+        # a stage that has not happened yet reads 0, not nothing: a cold run
+        # loads no program from the cache, a warm one compiles none
+        for stage in ("trace", "lower", "compile", "cache_load"):
+            _count_program_seconds(stage, "", 0.0)
 
 
 def register_runtime_gauges():

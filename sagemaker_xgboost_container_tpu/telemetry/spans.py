@@ -29,7 +29,10 @@ while a profiler session runs the program's spans land in the same
 annotation is a flag test; jax is never imported from here.
 """
 
+import bisect
 import contextlib
+import os
+import sys
 import threading
 import time
 
@@ -93,9 +96,17 @@ def _open_phases():
 
 def current_phase():
     """The spans open on this thread, outermost first and joined by ``/``
-    (``setup.first_dispatch/host_dispatch``; "" outside any): the ``phase``
-    label of the program-load counters (telemetry/cluster.py)."""
+    (``setup.first_dispatch/setup.first_dispatch.load/host_dispatch``; ""
+    outside any): the ``phase`` label of ``xla_program_seconds_total``
+    (telemetry/cluster.py)."""
     return "/".join(getattr(_tls, "open_phases", ()))
+
+
+def outermost_phase():
+    """The outermost span open on this thread ("" outside any): the
+    ``phase`` label of ``xla_program_wall_seconds_total``."""
+    phases = getattr(_tls, "open_phases", ())
+    return phases[0] if phases else ""
 
 
 class OpenSpan:
@@ -140,6 +151,26 @@ def begin_span(name, covering=False, registry=None, attributes=None):
     return OpenSpan(name, covering, registry, attributes)
 
 
+def _observe_phase(registry, name, seconds):
+    (registry or REGISTRY).histogram(
+        PHASE_HISTOGRAM,
+        help="Wall time of named training phases",
+        labels={"phase": name},
+    ).observe(seconds)
+
+
+def record_past_span(name, seconds, ended_s_ago=0.0, attributes=None, registry=None):
+    """A span of an interval already past (``seconds`` long, over
+    ``ended_s_ago`` seconds ago), for work that ran before anything could
+    open one: it lands in the phase histogram and, when armed, in the tracer
+    (``tracing.record_span``). No annotation: the profiler takes no event
+    after the fact. Never in a ``PhaseRecorder``: it is no part of a round."""
+    seconds = max(float(seconds), 0.0)
+    _observe_phase(registry, name, seconds)
+    tracing.record_span(name, seconds, attributes=attributes, ended_s_ago=ended_s_ago)
+    return seconds
+
+
 def end_span(open_span, emit=False):
     """Close ``open_span``: the duration lands in the phase histogram, in
     this thread's ``PhaseRecorder`` unless the span is ``covering``, and
@@ -156,11 +187,7 @@ def end_span(open_span, emit=False):
     elif open_span.annotation is not None:
         open_span.annotation.__exit__(None, None, None)
     registry = open_span.registry
-    registry.histogram(
-        PHASE_HISTOGRAM,
-        help="Wall time of named training phases",
-        labels={"phase": name},
-    ).observe(elapsed)
+    _observe_phase(registry, name, elapsed)
     for direction, nbytes in open_span.bytes.items():
         if not nbytes:
             continue
@@ -198,3 +225,158 @@ def span(name, emit=False, registry=None, attributes=None, covering=False):
         yield open_span
     finally:
         end_span(open_span, emit=emit)
+
+
+# ------------------------------------------------------------------ start-up
+def add_interval(covered, start, end):
+    """``(start, end)`` merged into ``covered``, a sorted tuple of disjoint
+    intervals: the new tuple and the seconds that were not covered before.
+    Pure: the wall seconds of work on several threads are the sum of what
+    each of its intervals adds, in whatever order they come."""
+    if end <= start:
+        return covered, 0.0
+    # disjoint and sorted, so the ends are sorted as the starts are
+    first = bisect.bisect_left(covered, start, key=lambda interval: interval[1])
+    last = bisect.bisect_right(covered, end, key=lambda interval: interval[0])
+    added = end - start
+    for lo, hi in covered[first:last]:
+        added -= min(hi, end) - max(lo, start)
+    if first < last:
+        start, end = min(start, covered[first][0]), max(end, covered[last - 1][1])
+    return covered[:first] + ((start, end),) + covered[last:], max(added, 0.0)
+
+
+def union_seconds(intervals):
+    """Seconds covered by ``(start, end)`` intervals, overlaps counted once."""
+    covered, total = (), 0.0
+    for start, end in intervals:
+        covered, added = add_interval(covered, start, end)
+        total += added
+    return total
+
+
+def process_start_time(fallback):
+    """When this process began, seconds since the epoch: its start in
+    ``/proc/self/stat`` (clock ticks after boot) against the boot-time clock,
+    ``psutil`` where that cannot be read, else ``fallback`` (the package's
+    first line). Never later than ``fallback``, nor a week before it (a
+    clock namespace that ``/proc`` does not share)."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - ticks / os.sysconf("SC_CLK_TCK")
+        started = time.time() - age
+    except Exception:
+        try:
+            import psutil
+
+            started = float(psutil.Process().create_time())
+        except Exception:
+            return fallback
+    return started if 0.0 <= fallback - started < 7 * 86400.0 else fallback
+
+
+def _backend_is_up():
+    """Whether jax has brought its back end up; None where that cannot be
+    told (jax not imported, or without the accessor)."""
+    bridge = sys.modules.get("jax._src.xla_bridge")
+    check = getattr(bridge, "backends_are_initialized", None)
+    return None if check is None else bool(check())
+
+
+_startup_lock = threading.Lock()
+_startup_done = set()  # which of "process", "before_train" are on record
+
+
+def record_startup(entering_train=False, registry=None):
+    """What ran between the start of the process and the program's own
+    spans, as spans of intervals already past; each once a process, however
+    often this is called (``train()`` calls it at its entry with
+    ``entering_train``, ``sagemaker_train`` at its start):
+
+    * gauge ``process_start_time_seconds`` (:func:`process_start_time`);
+    * ``startup.package_import``: the seconds inside the package's own
+      import statements up to now (the union of the package's
+      ``IMPORT_INTERVALS``; attribute ``jax_inside``: one of them was the
+      first to import jax, whose seconds are then in it);
+    * ``startup.before_train`` (only when ``entering_train``): process start
+      to the entry of the first ``train()``. What ``startup.package_import``
+      leaves of it is the caller's: data ingest in a job (span
+      ``data_ingest``), and the back end where the caller brought it up;
+    * ``startup.backend_init`` (only when ``entering_train``: a job's
+      ``jax.distributed.initialize`` has to come before it): jax 0.9 reports
+      no event for its back end coming up, so the span is round the first
+      device enumeration, made here, at the entry of ``train()``, if nobody
+      has made it. Where the caller enumerated first there is no span.
+    """
+    now = time.time()
+    with _startup_lock:
+        todo = {"process"} | ({"before_train"} if entering_train else set())
+        todo -= _startup_done
+        _startup_done.update(todo)
+    if not todo:
+        return
+    import sagemaker_xgboost_container_tpu as package
+
+    intervals = list(package.IMPORT_INTERVALS)
+    first_line = intervals[0][0]
+    started = process_start_time(first_line)
+    if "process" in todo:
+        (registry or REGISTRY).gauge(
+            "process_start_time_seconds",
+            help="Start time of the process, seconds since the epoch",
+        ).set(started)
+        record_past_span(
+            "startup.package_import",
+            union_seconds((start, end) for start, end, _jax in intervals),
+            ended_s_ago=now - max(end for _start, end, _jax in intervals),
+            attributes={
+                "jax_inside": any(jax_inside for _start, _end, jax_inside in intervals),
+                "imports": len(intervals),
+            },
+            registry=registry,
+        )
+    if "before_train" in todo:
+        record_past_span(
+            "startup.before_train",
+            now - started,
+            ended_s_ago=time.time() - now,
+            attributes={"process_start": round(started, 3)},
+            registry=registry,
+        )
+        if _backend_is_up() is False:
+            with span("startup.backend_init", registry=registry, covering=True):
+                sys.modules["jax"].local_devices()
+
+
+def note_phase_memory(phase, devices, registry=None):
+    """The fullest chip's memory at the end of a set-up phase, in gauges
+    ``setup_hbm_bytes{phase, what}``: ``in_use``, ``peak`` (the allocator's
+    high-water mark so far) and ``reserved`` (what the runtime holds for
+    programs' scratch, which the v5e keeps out of ``peak``). One
+    ``memory_stats()`` a chip; fullest by ``in_use`` plus ``reserved``. No
+    gauge where the back end reports no stats, as the CPU's does not."""
+    fullest = None
+    for device in devices:
+        try:
+            stats = device.memory_stats()
+        except Exception:
+            stats = None
+        if not stats:
+            continue
+        read = {
+            "in_use": int(stats.get("bytes_in_use", 0)),
+            "peak": int(stats.get("peak_bytes_in_use", 0)),
+            "reserved": int(stats.get("bytes_reserved", 0)),
+        }
+        if fullest is None or read["in_use"] + read["reserved"] > (
+            fullest["in_use"] + fullest["reserved"]
+        ):
+            fullest = read
+    for what, value in (fullest or {}).items():
+        (registry or REGISTRY).gauge(
+            "setup_hbm_bytes",
+            help="Device memory of the fullest chip at the end of a set-up phase",
+            labels={"phase": phase, "what": what},
+        ).set(value)
+    return fullest

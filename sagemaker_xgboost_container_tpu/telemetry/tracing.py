@@ -283,16 +283,17 @@ def trace_span(name, attributes=None, parent=None, trace_id=None, root=False):
         finish_span(span)
 
 
-def record_span(name, duration_s=0.0, attributes=None, parent=None):
-    """Record an already-completed span ending *now* (for event-driven
-    durations: an XLA compile reported by ``jax.monitoring``, a calibrated
-    collective). Parented to the current thread context unless overridden."""
+def record_span(name, duration_s=0.0, attributes=None, parent=None, ended_s_ago=0.0):
+    """Record an already-completed span ending *now*, or ``ended_s_ago``
+    seconds ago (for event-driven durations: an XLA compile reported by
+    ``jax.monitoring``; the start-up intervals in front of ``train()``).
+    Parented to the current thread context unless overridden."""
     if not enabled():
         return None
     tid, parent_id = _resolve_parent(parent, None, False)
     span = Span(name, tid, parent_id, attributes)
     span.dur_us = max(float(duration_s), 0.0) * 1e6
-    span.start_us = max(span.start_us - span.dur_us, 0.0)
+    span.start_us = max(span.start_us - max(float(ended_s_ago), 0.0) * 1e6 - span.dur_us, 0.0)
     recorder = _get_recorder()
     global _seq
     with _state_lock:

@@ -31,6 +31,7 @@ from ..data.readers import (
 )
 from ..parallel import distributed
 from ..telemetry import register_runtime_gauges, span, start_cluster_telemetry
+from ..telemetry.spans import record_startup
 from ..toolkit import exceptions as exc
 from ..toolkit.channels import PIPE_MODE
 from ..models import booster
@@ -192,6 +193,9 @@ def sagemaker_train(
     checkpoint_config,
 ):
     """Validate config, load data, select execution mode, run train_job."""
+    # process start and the imports, as spans of what is past already
+    # (telemetry/spans.py; `train()` adds startup.before_train at its entry)
+    record_startup()
     # XLA compile / RSS / device-buffer gauges: registered before any jax
     # work so the first compile is counted (adds no threads; jax-absent and
     # CPU-only paths no-op)

@@ -256,7 +256,6 @@ def test_training_trace_tree_nests_round_children(
     rounds = [e for e in complete if e["name"] == "round"]
     assert len(rounds) >= 3
     for child_name in (
-        "collective.dispatch",
         "checkpoint.save",
         "checkpoint.manifest",
         "xla.compile",

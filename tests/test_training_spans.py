@@ -356,3 +356,284 @@ def test_a_training_session_installs_the_listener(monkeypatch):
     _train()
     _train()
     assert installed == [cluster._on_jax_duration_event]  # once, by the first session
+
+
+# ------------------------------------------------- the set-up timeline (PR 36)
+def _phases(registry):
+    """{phase: (count, seconds)} of the registry's phase histogram."""
+    return {
+        m.labels["phase"]: (m.count, m.sum)
+        for name, _k, _h, fam in registry.collect()
+        if name == spans.PHASE_HISTOGRAM
+        for m in fam
+    }
+
+
+def _gauges(registry, name):
+    return {
+        tuple(sorted(m.labels.items())): m.value
+        for n, _k, _h, fam in registry.collect()
+        if n == name
+        for m in fam
+    }
+
+
+def test_startup_spans_are_recorded_once_however_often_the_function_is_called(monkeypatch):
+    from sagemaker_xgboost_container_tpu.telemetry.registry import MetricsRegistry
+
+    import jax
+
+    jax.local_devices()  # the caller enumerates first, as the benchmark's harness does
+    registry = MetricsRegistry()
+    monkeypatch.setattr(spans, "_startup_done", set())
+    started = time.time()
+    spans.record_startup(registry=registry)  # a job's start: no train() yet
+    assert "startup.before_train" not in _phases(registry)
+    for _ in range(3):
+        spans.record_startup(entering_train=True, registry=registry)
+    spans.record_startup(registry=registry)
+    phases = _phases(registry)
+    assert phases["startup.package_import"][0] == phases["startup.before_train"][0] == 1
+    # the imports lie inside the process's life up to train()
+    assert phases["startup.before_train"][1] >= phases["startup.package_import"][1] > 0
+    began = _gauges(registry, "process_start_time_seconds")[()]
+    assert began <= started and phases["startup.before_train"][1] >= started - began
+    # the back end was up already: nothing to time, and no span
+    assert "startup.backend_init" not in phases
+
+
+def test_train_times_the_back_end_where_it_is_the_first_to_enumerate(monkeypatch):
+    from sagemaker_xgboost_container_tpu.telemetry.registry import MetricsRegistry
+
+    import jax  # noqa: F401  record_startup looks jax up, it never imports it
+
+    registry = MetricsRegistry()
+    monkeypatch.setattr(spans, "_startup_done", set())
+    monkeypatch.setattr(spans, "_backend_is_up", lambda: False)
+    spans.record_startup(registry=registry)  # a job's start: jax.distributed may still come
+    assert "startup.backend_init" not in _phases(registry)
+    spans.record_startup(entering_train=True, registry=registry)
+    spans.record_startup(entering_train=True, registry=registry)
+    assert _phases(registry)["startup.backend_init"][0] == 1
+
+
+def test_train_records_the_startup_spans_at_its_first_entry_only(monkeypatch):
+    monkeypatch.setattr(spans, "_startup_done", set())
+    before = _phases(REGISTRY).get("startup.before_train", (0, 0.0))[0]
+    _train()
+    _train()
+    assert _phases(REGISTRY)["startup.before_train"][0] == before + 1
+
+
+def test_process_start_is_never_later_than_the_packages_first_line():
+    import sagemaker_xgboost_container_tpu as package
+
+    first_line = package.IMPORT_INTERVALS[0][0]
+    assert spans.process_start_time(first_line) <= first_line
+    # a clock that /proc does not share: the package's first line stands in
+    assert spans.process_start_time(first_line - 30 * 86400.0) == first_line - 30 * 86400.0
+    assert all(end >= start for start, end, _jax in package.IMPORT_INTERVALS)
+    assert len(package.IMPORT_INTERVALS) >= 2  # the package's own, and `models`
+
+
+def test_a_past_span_lands_in_the_histogram_and_the_tracer_where_it_ended(monkeypatch):
+    from sagemaker_xgboost_container_tpu.telemetry.registry import MetricsRegistry
+
+    registry = MetricsRegistry()
+    recorded = []
+    monkeypatch.setattr(
+        tracing, "record_span",
+        lambda name, seconds, attributes=None, ended_s_ago=0.0: recorded.append(
+            (name, seconds, attributes, ended_s_ago)
+        ),
+    )
+    assert spans.record_past_span("startup.x", 2.5, 0.5, {"a": 1}, registry) == 2.5
+    assert spans.record_past_span("startup.x", -1.0, registry=registry) == 0.0  # a clock set back
+    assert _phases(registry)["startup.x"] == (2, 2.5)
+    assert recorded == [("startup.x", 2.5, {"a": 1}, 0.5), ("startup.x", 0.0, None, 0.0)]
+
+
+WALL_CASES = [
+    # intervals as (thread, start, end) in the order their events arrive; wall seconds
+    ("nothing", [], 0.0),
+    ("one", [("a", 1.0, 3.0)], 2.0),
+    ("empty_and_backwards", [("a", 2.0, 2.0), ("a", 5.0, 4.0)], 0.0),
+    ("apart", [("a", 1.0, 2.0), ("a", 5.0, 7.0)], 3.0),
+    ("touching", [("a", 1.0, 2.0), ("a", 2.0, 3.0)], 2.0),
+    ("two_threads_overlap", [("a", 0.0, 4.0), ("b", 2.0, 6.0)], 6.0),
+    ("two_threads_side_by_side", [("a", 0.0, 5.0), ("b", 0.0, 5.0)], 5.0),
+    ("inner_traces_end_first", [("a", 1.0, 2.0), ("a", 3.0, 4.0), ("a", 0.0, 5.0)], 5.0),
+    ("one_inside_another_later", [("a", 0.0, 10.0), ("b", 3.0, 4.0)], 10.0),
+    ("bridges_two", [("a", 0.0, 2.0), ("b", 4.0, 6.0), ("a", 1.0, 5.0)], 6.0),
+    ("out_of_order", [("b", 8.0, 9.0), ("a", 0.0, 1.0), ("b", 0.5, 8.5)], 9.0),
+]
+
+
+@pytest.mark.parametrize("case", WALL_CASES, ids=[c[0] for c in WALL_CASES])
+def test_wall_seconds_are_the_union_of_the_intervals_over_all_threads(case):
+    _name, arrivals, wall = case
+    covered, total = (), 0.0
+    for _thread, start, end in arrivals:
+        before = covered
+        covered, added = spans.add_interval(covered, start, end)
+        assert added >= 0.0 and before == before[:]  # pure: the old tuple stands
+        total += added
+        assert all(a[1] < b[0] for a, b in zip(covered, covered[1:])), covered  # disjoint, sorted
+    assert total == pytest.approx(wall)
+    assert sum(hi - lo for lo, hi in covered) == pytest.approx(wall)
+    assert spans.union_seconds((s, e) for _t, s, e in arrivals) == pytest.approx(wall)
+    # whatever the order the events come in
+    assert spans.union_seconds((s, e) for _t, s, e in reversed(arrivals)) == pytest.approx(wall)
+
+
+def test_listener_counts_program_wall_once_under_the_outermost_phase(monkeypatch):
+    import threading
+
+    def wall():
+        return {k: v for k, v in _gauges(REGISTRY, "xla_program_wall_seconds_total").items()}
+
+    monkeypatch.setattr(cluster, "_program_wall", ())
+    clock = [100.0]
+    monkeypatch.setattr(cluster.time, "perf_counter", lambda: clock[0])
+    before = wall()
+    key = (("phase", "wall-test"),)
+
+    def load(seconds, ends_at):
+        clock[0] = ends_at
+        cluster._on_jax_duration_event("/jax/core/compile/jaxpr_to_mlir_module_duration", seconds)
+
+    def on_another_thread():
+        with spans.span("wall-test"):
+            load(3.0, 104.0)  # 101 to 104, beside the first thread's 100 to 103
+
+    with spans.span("wall-test"):
+        with spans.span("inner"):
+            load(3.0, 103.0)
+        thread = threading.Thread(target=on_another_thread)
+        thread.start()
+        thread.join()
+        load(1.0, 102.0)  # inside both: adds nothing
+    assert wall()[key] - before.get(key, 0.0) == pytest.approx(4.0)
+    assert (("phase", "wall-test/inner"),) not in wall()  # the outermost span names it
+
+
+class _Chip:
+    def __init__(self, stats):
+        self.stats = stats
+
+    def memory_stats(self):
+        if isinstance(self.stats, Exception):
+            raise self.stats
+        return self.stats
+
+
+def test_memory_gauges_are_absent_where_the_back_end_reports_no_stats():
+    import jax
+    from sagemaker_xgboost_container_tpu.telemetry.registry import MetricsRegistry
+
+    registry = MetricsRegistry()
+    silent = [_Chip(None), _Chip({}), _Chip(RuntimeError("no stats"))]
+    assert spans.note_phase_memory("setup.sketch", silent, registry) is None
+    assert spans.note_phase_memory("setup.sketch", jax.local_devices(), registry) is None  # the CPU
+    assert _gauges(registry, "setup_hbm_bytes") == {}
+    chips = [
+        _Chip({"bytes_in_use": 5, "peak_bytes_in_use": 9, "bytes_reserved": 1}),
+        _Chip(None),
+        _Chip({"bytes_in_use": 4, "peak_bytes_in_use": 30, "bytes_reserved": 3}),
+    ]
+    fullest = spans.note_phase_memory("setup.sketch", chips, registry)
+    assert fullest == {"in_use": 4, "peak": 30, "reserved": 3}  # by what is taken now
+    assert _gauges(registry, "setup_hbm_bytes") == {
+        (("phase", "setup.sketch"), ("what", what)): value for what, value in fullest.items()
+    }
+
+
+def test_a_cpu_session_reads_memory_five_times_and_sets_no_gauge(monkeypatch):
+    from sagemaker_xgboost_container_tpu.models import booster
+
+    read = []
+    real = spans.note_phase_memory
+    monkeypatch.setattr(
+        booster, "note_phase_memory",
+        lambda phase, devices: read.append((phase, len(list(devices)))) or real(phase, devices),
+    )
+    _train(rounds=6, k=2)
+    assert read == [(phase, 1) for phase in SETUP_ORDER]  # once a phase, one chip
+    assert _gauges(REGISTRY, "setup_hbm_bytes") == {}
+
+
+@pytest.mark.parametrize("blocks", [1, 3])
+def test_child_spans_cover_a_shard_of_the_sketch_and_of_the_bin_apply(
+    blocks, finished_spans, monkeypatch
+):
+    from sagemaker_xgboost_container_tpu.data import binning
+
+    monkeypatch.setenv("GRAFT_SKETCH_IMPL", "device")
+    rows, columns = 42_000, 6
+    if blocks == 3:  # two columns a sketch block, a third of the rows a bin-apply block
+        monkeypatch.setattr(
+            binning, "DEVICE_BLOCK_BYTES", binning.DEVICE_BYTES_PER_VALUE * rows * 2
+        )
+    rng = np.random.RandomState(blocks)
+    x = rng.randn(rows, columns).astype(np.float32)
+    x[rng.rand(rows) < 0.1, 2] = np.nan
+    cuts = binning.sketch_shards([x], [None], 64)
+    binned = binning.apply_shards([x], cuts, 64)
+    assert np.asarray(binned[0]).shape == x.shape
+    monkeypatch.setenv("GRAFT_SKETCH_IMPL", "host")
+    assert np.asarray(binned[0]).tobytes() == binning.apply_cut_points(x, cuts, 64).tobytes()
+
+    def seconds(name):
+        return [s for n, s, _c in finished_spans if n == name]
+
+    for phase, parts in (
+        ("setup.sketch", ("stage", "transfer", "kernel", "fetch")),
+        ("setup.bin_apply", ("stage", "transfer", "kernel")),
+    ):
+        (shard,) = seconds(phase + ".shard")[:1]
+        counts = {part: len(seconds(phase + "." + part)) for part in parts}
+        # the sketch stages and puts the weights too, the bin-apply puts the cuts
+        extra = {"stage": phase == "setup.sketch", "transfer": True}
+        assert counts == {part: blocks + extra.get(part, 0) for part in parts}, counts
+        covered = sum(sum(seconds(phase + "." + part)) for part in parts)
+        assert 0.95 * shard <= covered <= shard, (phase, covered, shard)
+        assert all(c for n, _s, c in finished_spans if n.startswith(phase + "."))  # covering
+
+
+def test_nothing_new_runs_inside_a_dispatch_after_the_first(finished_spans):
+    import collections
+
+    _train(rounds=8, k=2)  # four dispatches: the first is set-up, three follow
+    names = [n for n, _s, _c in finished_spans]
+    first = names.index("setup.first_dispatch")
+    # the first dispatch's two halves, once each and inside it
+    assert names[first - 4 : first] == [
+        "host_dispatch", "setup.first_dispatch.load", "device_sync", "setup.first_dispatch.run",
+    ]
+    # from there on the spans of a dispatch and of a round are the parent's, by name and count
+    assert collections.Counter(names[first + 1 :]) == {
+        "host_dispatch": 3, "device_sync": 3, "host_turnaround": 4,
+        "commit": 8, "eval_log": 8, "callbacks": 8,
+    }
+    assert not any(n.startswith(("setup.", "startup.")) for n in names[first + 1 :])
+
+
+def test_the_collective_is_counted_and_no_span_states_its_estimate(monkeypatch, tmp_path):
+    """`collective.dispatch` recorded the calibrated latency of the psum alone
+    as a span's duration; the counters and the gauge stay."""
+    import jax
+    from jax.sharding import Mesh
+
+    recorded = []
+    real = tracing.record_span
+    monkeypatch.setattr(tracing, "enabled", lambda: True)
+    monkeypatch.setattr(
+        tracing, "record_span",
+        lambda name, *a, **k: recorded.append(name) or real(name, *a, **k),
+    )
+    _train(mesh=Mesh(np.array(jax.devices()[:2]), ("data",)))
+    assert "collective.dispatch" not in recorded
+    assert any(
+        name == "hist_comm_bytes_total" and any(m.value > 0 for m in fam)
+        for name, _k, _h, fam in REGISTRY.collect()
+    )

@@ -52,9 +52,11 @@ from ..ops.histogram import (
 )
 from ..ops.ranking import (
     GroupLayout,
+    SlotColumns,
     build_group_layout,
     lambdarank_grad_hess,
     pair_slots,
+    with_slot_columns,
 )
 from ..ops.tree_build import (
     build_tree,
@@ -738,10 +740,19 @@ class _TrainingSession:
             self.labels = _put(_layout_rows(labels, 0.0), P("data"))
             self.weights = _put(_layout_rows(dtrain.get_weight(), 0.0), P("data"))
             self.groups = dtrain.groups
+            # what a ranking round reads in slot order and never changes goes
+            # to the device with the index it belongs to (`_put_layout`): a
+            # round gathers the margins alone
+            from .device_metrics import ndcg_cutoffs
+
+            self._slot_cutoffs = tuple(sorted({0, *ndcg_cutoffs(metric_names or ())}))
             if self.rank_layout is None:
                 self.rank_index_dev = jnp.zeros((1, 1), jnp.int32)  # inert dummy
             else:
-                self.rank_index_dev = self._put_layout(self.rank_layout, self._rank_specs())
+                with span("setup.group_layout", attributes={"what": "train_slots"}):
+                    self.rank_index_dev = self._put_layout(
+                        self.rank_layout, self.labels, self.weights, self._rank_specs()
+                    )
 
             base = self.objective.base_margin(forest.base_score)
             shape = (n_pad,) if self.num_group == 1 else (n_pad, self.num_group)
@@ -835,10 +846,14 @@ class _TrainingSession:
                 if any(fn.needs_groups for fn in self.device_metric_fns):
                     with span("setup.group_layout", attributes={"what": "eval_sets"}):
                         self.eval_layouts = tuple(
-                            self._put_layout(build_group_layout(dm.groups))
-                            for _name, dm, binned in self.eval_sets
+                            self._put_layout(
+                                build_group_layout(dm.groups),
+                                self.eval_labels[i], self.eval_weights[i],
+                            )
+                            for i, (_name, dm, binned) in enumerate(self.eval_sets)
                             if binned is not self.train_binned
                         )
+        self._note_rank_gathers()
         # Metrics outside device_metrics.all_supported (feval, ranking
         # metrics, non-decomposable scalars) no longer force K -> 1: the
         # fused dispatch keeps K, the scan carries every eval set's margins
@@ -946,19 +961,72 @@ class _TrainingSession:
         self._note_setup_memory("setup.program_build")
         self._introspect_compiled_cost(self._register_round_program())
 
-    def _put_layout(self, layout, specs=None):
-        """A host ``GroupLayout`` as device arrays: whole on one device, by
-        ``specs`` (a ``GroupLayout`` of partition specs) on a mesh."""
+    def _put_layout(self, layout, labels, weights, specs=None):
+        """A host ``GroupLayout`` as device arrays, its slot columns gathered
+        there from the rows' ``labels`` and ``weights`` (device arrays, laid
+        out as the rows): whole on one device, by ``specs`` (the placed
+        layout's partition specs) on a mesh, a shard's columns from its rows."""
+        cutoffs = self._slot_cutoffs
         if specs is None:
-            specs = jax.tree_util.tree_map(lambda _: P(), layout)
-        return jax.tree_util.tree_map(self._put, layout, specs)
+            placed = jax.tree_util.tree_map(lambda a: self._put(a, P()), layout)
+            return with_slot_columns(placed, labels, weights, cutoffs)
+        bare = specs._replace(slots=())
+        placed = jax.tree_util.tree_map(self._put, layout, bare)
+        fill = jax.shard_map(
+            partial(with_slot_columns, cutoffs=cutoffs),
+            mesh=self.mesh,
+            in_specs=(bare, P("data"), P("data")),
+            out_specs=specs,
+            check_vma=False,
+        )
+        # graftlint: disable=trace-uncached-jit — session-scope construction: a mesh's train layout is filled once per training session
+        return jax.jit(fill)(placed, labels, weights)
 
     def _rank_specs(self):
         """How a mesh shards the train rows' ``GroupLayout`` (one index a
-        shard, its rows' slots with the rows); None on one device."""
+        shard, its slot columns with it, its rows' slots with the rows);
+        None on one device."""
         if self.rank_layout is None or self.mesh is None:
             return None
-        return GroupLayout((P("data", None, None),), P("data"), P())
+        by_slot = P("data", None, None)
+        columns = SlotColumns(
+            by_slot, by_slot, by_slot, by_slot,
+            {k: P("data", None) for k in self._slot_cutoffs},
+        )
+        return GroupLayout((by_slot,), P("data"), P(), (columns,))
+
+    def _note_rank_gathers(self):
+        """Gauges of what a ranking round fetches through a row index: set
+        once, from the layouts' shapes."""
+        if self.rank_layout is None:
+            return
+        from ..telemetry import REGISTRY
+
+        grouped = sum(fn.needs_groups for fn in self.device_metric_fns or ())
+        shared = sum(binned is self.train_binned for _n, _dm, binned in self.eval_sets)
+        train_buckets = len(self.rank_index_dev.indices)
+        REGISTRY.gauge(
+            "rank_row_gathers_per_round",
+            "Row-to-slot gathers one round issues over all buckets: the "
+            "gradient's over the train layout and each grouped device "
+            "metric's over every evaluation set's (one a bucket and caller: "
+            "the margins)",
+        ).set(
+            train_buckets * (1 + grouped * shared)
+            + grouped * sum(len(layout.indices) for layout in self.eval_layouts)
+        )
+        REGISTRY.gauge(
+            "rank_slot_constant_bytes",
+            "Bytes of the slot columns the group layouts carry on the device "
+            "(labels, weights, valid, gains, ideal DCG; train and evaluation "
+            "sets)",
+        ).set(
+            sum(
+                int(column.nbytes)
+                for layout in (self.rank_index_dev,) + self.eval_layouts
+                for column in jax.tree_util.tree_leaves(layout.slots)
+            )
+        )
 
     def _build_rank_layout(self, groups, num_row):
         """The train rows' query groups as the round program takes them: on
@@ -1175,7 +1243,7 @@ class _TrainingSession:
     def _grad_hess_fn(self):
         if not self.is_ranking:
             return None
-        # (margins, labels, weights, layout); a shard's slices under shard_map
+        # (margins, layout); a shard's slices under shard_map
         return partial(lambdarank_grad_hess, scheme=self.objective.scheme)
 
     def _make_round_fn(self):
@@ -1314,7 +1382,7 @@ class _TrainingSession:
                 shard_rng = rng
             with stage(STAGE_GRAD):
                 if ranking_grads is not None:
-                    g, h = ranking_grads(margins, labels, weights, rank_index)
+                    g, h = ranking_grads(margins, rank_index)
                 else:
                     g, h = grad_hess(margins, labels, weights)
 

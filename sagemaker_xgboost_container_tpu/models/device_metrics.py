@@ -89,27 +89,31 @@ def grouped_ndcg(name, k=None):
     by margin descending with ties broken by position (a stable argsort's
     order), a group without a relevant document counted as 1 — the semantics
     of ``eval_metrics.ndcg``, which ignores weights too. Padding slots carry
-    no gain and rank last, so they add nothing."""
+    no gain and rank last, so they add nothing.
+
+    A round gathers the margins alone: the gains and each group's ideal DCG
+    at ``k`` are the layout's (``ops/ranking.py::SlotColumns``, cutoff
+    ``k or 0``; ``ndcg_cutoffs`` names the cutoffs a layout has to carry)."""
     from ..ops import ranking
 
-    def per_group(S, Y, valid):
-        gains = ranking.dcg_gain(Y, valid)
+    def per_group(S, gains, valid, ideal):
         ranks = ranking.rank_descending(S, valid)
         terms = gains * ranking.dcg_discount(ranks)
         if k:
             terms = jnp.where(ranks <= k, terms, 0.0)
         dcg = terms.sum(axis=1)
-        ideal = ranking.ideal_dcg(Y, gains, valid, k)
         ndcg = jnp.where(ideal > 0, dcg / jnp.where(ideal > 0, ideal, 1.0), 1.0)
         held = valid.any(axis=1)  # groups added to fill a chunk hold nothing
         return jnp.where(held, ndcg, 0.0), held.astype(jnp.float32)
 
     def partial(m, y, w, layout):
         total = count = layout.empty_groups  # the host counts an empty group as 1
-        for index in layout.indices:
-            valid, S, Y = ranking.gather_groups(index, (m, y), (0.0, 0.0))
+        for index, slots in zip(layout.indices, layout.slots):
+            _valid, S = ranking.gather_groups(index, (m,), (0.0,))
             ndcg, held = ranking.map_group_chunks(
-                per_group, (S, Y, valid), fills=(0.0, 0.0, False)
+                per_group,
+                (S, slots.gains, slots.valid, slots.ideal_dcg[k or 0]),
+                fills=(0.0, 0.0, False, 0.0),
             )
             total = total + ndcg.sum()
             count = count + held.sum()
@@ -120,13 +124,24 @@ def grouped_ndcg(name, k=None):
     )
 
 
+def ndcg_cutoffs(names):
+    """The ``ideal_dcg`` cutoffs the grouped metrics among ``names`` read off
+    a layout (0: the whole group), ascending."""
+    cutoffs = set()
+    for name in names:
+        base, _, suffix = name.partition("@")
+        if base == "ndcg":
+            cutoffs.add(int(float(suffix)) if suffix else 0)
+    return tuple(sorted(cutoffs))
+
+
 def make_device_metric(name, objective_name, num_group=1, params=None):
     """-> DeviceMetric, or None if unsupported on device."""
     params = params or {}
     base, _, suffix = name.partition("@")
 
     if base == "ndcg":
-        return grouped_ndcg(name, int(float(suffix)) if suffix else None)
+        return grouped_ndcg(name, ndcg_cutoffs([name])[0])
 
     if num_group > 1:
         if base == "merror":

@@ -20,8 +20,20 @@ shard (``build_sharded_group_layout``).
 
 Every row lies in exactly one slot, so the way back from slots to rows is a
 gather through the inverse index (``row_slot``), not a scatter-add.
+
+Which columns are whose. A round changes the margins and nothing else, so a
+round gathers the margins and nothing else (``gather_groups`` under
+``rank_gather``, once a bucket and caller). Labels, weights and all that
+follows from them and the index alone (``valid``, the DCG gains, each group's
+ideal DCG at every cutoff a caller asks for) are the layout's: gathered and
+computed once, where the layout goes to the device (``with_slot_columns``,
+the same helper and the same expressions the round used to run), and carried
+beside the index they belong to as one ``SlotColumns`` a bucket. Both callers,
+``lambdarank_grad_hess`` and ``models/device_metrics.py::grouped_ndcg``, read
+them there.
 """
 
+from functools import partial
 from typing import NamedTuple
 
 import jax
@@ -58,11 +70,34 @@ class GroupLayout(NamedTuple):
       flattened ``indices``; -1 for a row in no group (mesh padding).
     empty_groups: float32 scalar, the groups that hold no row: they are in
       no bucket, and a per-group metric counts them as the host does.
+    slots: tuple of ``SlotColumns``, one a bucket, in the shapes of
+      ``indices``; empty on a layout as the host builds it, filled where it
+      goes to the device (``with_slot_columns``).
     """
 
     indices: tuple
     row_slot: object
     empty_groups: object
+    slots: tuple = ()
+
+
+class SlotColumns(NamedTuple):
+    """What one bucket's slots hold that no round changes, ``[G, M]`` each
+    (``[shards, G, M]`` on a mesh).
+
+    labels: float32, -inf in the padding (padding is never "preferred").
+    weights: float32, 0 in the padding.
+    valid: bool, the slots that hold a row.
+    gains: float32, ``dcg_gain(labels, valid)``.
+    ideal_dcg: ``{cutoff: float32 [G]}``, ``ideal_dcg`` at every cutoff asked
+      for; cutoff 0 is the whole group (the gradient's, and plain ``ndcg``'s).
+    """
+
+    labels: object
+    weights: object
+    valid: object
+    gains: object
+    ideal_dcg: dict
 
 
 def map_exchange_delta(S, Y, valid):
@@ -285,7 +320,11 @@ def map_group_chunks(fn, arrays, pair_slots_per_step=PAIR_SLOTS_PER_STEP, fills=
 
 def gather_groups(index, columns, fills):
     """Rows -> slots: each of ``columns`` ([n]) at ``index`` ([G, M]), the
-    padding slots holding the column's fill. Returns (valid, *gathered)."""
+    padding slots holding the column's fill. Returns (valid, *gathered).
+
+    The one way from rows to slots, for every caller. A round passes the
+    margins alone, the one column it changes; labels and weights pass through
+    here once, at set-up (``with_slot_columns``), and stay on the layout."""
     valid = index >= 0
     safe = jnp.where(valid, index, 0)
     return (valid,) + tuple(
@@ -319,44 +358,83 @@ def dcg_discount(ranks):
     return 1.0 / jnp.log2(1.0 + ranks.astype(jnp.float32))
 
 
-def ideal_dcg(labels, gains, valid, k=None):
-    """DCG (at ``k``) of each group's documents in the order of their labels."""
+def ideal_dcg(labels, gains, valid, cutoffs):
+    """DCG of each group's documents in the order of their labels, at each of
+    ``cutoffs`` (0: the whole group): a tuple of ``[G]``."""
     ideal_ranks = rank_descending(labels, valid)
     terms = gains * dcg_discount(ideal_ranks)
-    if k:
-        terms = jnp.where(ideal_ranks <= k, terms, 0.0)
-    return terms.sum(axis=1)
+    return tuple(
+        (jnp.where(ideal_ranks <= k, terms, 0.0) if k else terms).sum(axis=1)
+        for k in cutoffs
+    )
+
+
+def _slot_columns(index, labels, weights, cutoffs, pair_slots_per_step):
+    """One bucket's ``SlotColumns`` from its ``[G, M]`` index."""
+    # padding is never "preferred": its label is -inf
+    valid, Y, W = gather_groups(index, (labels, weights), (-jnp.inf, 0.0))
+    gains = dcg_gain(Y, valid)
+    ideal = map_group_chunks(
+        partial(ideal_dcg, cutoffs=cutoffs),
+        (Y, gains, valid),
+        pair_slots_per_step,
+        fills=(-jnp.inf, 0.0, False),
+    )
+    return SlotColumns(Y, W, valid, gains, dict(zip(cutoffs, ideal)))
+
+
+@partial(jax.jit, static_argnames=("cutoffs", "pair_slots_per_step"))
+def with_slot_columns(layout, labels, weights, cutoffs=(0,),
+                      pair_slots_per_step=PAIR_SLOTS_PER_STEP):
+    """``layout`` with its ``slots`` filled from the rows' ``labels`` and
+    ``weights`` ([n]): once, where the layout goes to the device. A shard's
+    ``[1, G, M]`` slice of a mesh's index (under ``shard_map``) gives columns
+    with the same leading axis. ``cutoffs``: the ``ideal_dcg`` entries."""
+    slots = []
+    for index in layout.indices:
+        lead = index.shape[:-2]
+        columns = _slot_columns(
+            index.reshape(index.shape[-2:]), labels, weights, cutoffs, pair_slots_per_step
+        )
+        slots.append(
+            jax.tree_util.tree_map(lambda a: a.reshape(lead + a.shape), columns)
+        )
+    return layout._replace(slots=tuple(slots))
 
 
 def lambdarank_grad_hess(
-    margins, labels, weights, layout, scheme="pairwise",
-    pair_slots_per_step=PAIR_SLOTS_PER_STEP,
+    margins, layout, scheme="pairwise", pair_slots_per_step=PAIR_SLOTS_PER_STEP,
 ):
     """Per-row (grad, hess) for LambdaMART.
 
-    margins/labels/weights: [n]; layout: a ``GroupLayout``;
+    margins: [n]; layout: a ``GroupLayout`` with its ``slots`` (labels,
+    weights and what follows from them; cutoff 0 among ``ideal_dcg`` for
+    "ndcg");
     scheme: "pairwise" (delta = 1) | "ndcg" (|delta NDCG|) | "map" (exact
     |delta AP| exchange weights, binary relevance = label > 0).
 
-    Three stages (``telemetry/device.py::STAGES``): ``rank_gather`` (rows to
-    slots), ``rank_pairs`` (ranks, the O(M^2) pair pass and its sums over
-    slots, per bucket, chunks of groups at a time) and ``rank_scatter``
-    (slots back to rows).
+    Three stages (``telemetry/device.py::STAGES``): ``rank_gather`` (the
+    margins from rows to slots: the one gather a bucket), ``rank_pairs``
+    (ranks, the O(M^2) pair pass and its sums over slots, per bucket, chunks
+    of groups at a time) and ``rank_scatter`` (slots back to rows).
     """
     grads, hesses = [], []
-    for index in layout.indices:
-        index = index.reshape(index.shape[-2:])  # a shard's [1, G, M] slice
+    for index, slots in zip(layout.indices, layout.slots):
+        if index.ndim == 3:  # a shard's [1, G, M] slice
+            index, slots = jax.tree_util.tree_map(lambda a: a[0], (index, slots))
         with stage(STAGE_RANK_GATHER):
-            # padding is never "preferred": its label is -inf
-            valid, S, Y, W = gather_groups(
-                index, (margins, labels, weights), (0.0, -jnp.inf, 0.0)
-            )
+            _valid, S = gather_groups(index, (margins,), (0.0,))
         with stage(STAGE_RANK_PAIRS):
+            columns = (S, slots.labels, slots.weights, slots.valid)
+            fills = (0.0, -jnp.inf, 0.0, False)
+            if scheme == "ndcg":
+                columns += (slots.gains, jnp.maximum(slots.ideal_dcg[0], 1e-12))
+                fills += (0.0, 1e-12)
             g_mat, h_mat = map_group_chunks(
-                lambda s, y, w, v: _lambdarank_block(s, y, w, v, scheme),
-                (S, Y, W, valid),
+                partial(_lambdarank_block, scheme=scheme),
+                columns,
                 pair_slots_per_step,
-                fills=(0.0, -jnp.inf, 0.0, False),
+                fills=fills,
             )
         grads.append(g_mat.reshape(-1))
         hesses.append(h_mat.reshape(-1))
@@ -368,17 +446,16 @@ def lambdarank_grad_hess(
         )
 
 
-def _lambdarank_block(S, Y, W, valid, scheme):
-    """(g, h) of every slot of ``[G, M]`` groups: all intra-group pairs."""
+def _lambdarank_block(S, Y, W, valid, gains=None, max_dcg=None, scheme="pairwise"):
+    """(g, h) of every slot of ``[G, M]`` groups: all intra-group pairs.
+    ``gains`` ([G, M]) and ``max_dcg`` ([G]) are the layout's, for "ndcg"."""
     s_diff = S[:, :, None] - S[:, None, :]             # [G, M, M]
     rho = 1.0 / (1.0 + jnp.exp(_SIGMA * s_diff))       # P(swap needed | i>j)
     prefer = (Y[:, :, None] > Y[:, None, :]) & valid[:, :, None] & valid[:, None, :]
 
     if scheme == "ndcg":
         ranks = rank_descending(S, valid)              # by score, 1-based
-        gains = dcg_gain(Y, valid)
         discount = dcg_discount(ranks)
-        max_dcg = jnp.maximum(ideal_dcg(Y, gains, valid), 1e-12)
         delta = (
             jnp.abs(gains[:, :, None] - gains[:, None, :])
             * jnp.abs(discount[:, :, None] - discount[:, None, :])

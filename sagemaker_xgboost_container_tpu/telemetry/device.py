@@ -64,8 +64,9 @@ DEFAULT_HBM_SAMPLE_EVERY = 8
 #: ops/tree_build.py, ops/histogram.py) and so the ``op_name`` metadata of
 #: every HLO instruction under it; ``hist_allreduce`` exists on a mesh only.
 STAGE_GRAD = "grad"
-#: inside ``grad`` for the ranking objectives (ops/ranking.py): rows to group
-#: slots, the pair pass with its ranks and sums, slots back to rows
+#: inside ``grad`` for the ranking objectives (ops/ranking.py): the margins
+#: from rows to group slots, the pair pass with its ranks and sums, slots back
+#: to rows
 STAGE_RANK_GATHER = "rank_gather"
 STAGE_RANK_PAIRS = "rank_pairs"
 STAGE_RANK_SCATTER = "rank_scatter"

@@ -28,15 +28,15 @@ def install():
 
     from sagemaker_xgboost_container_tpu.ops import ranking
 
-    def block_bf16(S, Y, W, valid, scheme):
+    def block_bf16(S, Y, W, valid, gains, max_dcg, scheme):
+        # gains and max_dcg are the layout's, made at set-up through the
+        # rounded `dcg_gain` and `dcg_discount` below
         assert scheme == "ndcg"
         low = jnp.bfloat16
         s = S.astype(low)
         rho = 1.0 / (1.0 + jnp.exp(s[:, :, None] - s[:, None, :]))
         prefer = (Y[:, :, None] > Y[:, None, :]) & valid[:, :, None] & valid[:, None, :]
-        gains = ranking.dcg_gain(Y, valid)
         discount = ranking.dcg_discount(ranking.rank_descending(S, valid))
-        max_dcg = jnp.maximum(ranking.ideal_dcg(Y, gains, valid), 1e-12)
         g_low, d_low = gains.astype(low), discount.astype(low)
         delta = (
             jnp.abs(g_low[:, :, None] - g_low[:, None, :])

@@ -118,3 +118,76 @@ def test_build_tree_holds_no_rows_by_width_buffer_and_no_row_gather(
         dims for op, _dtype, dims in held + _results(hlo, fused=True) if op == "gather"
     ]
     assert gathers and max(max(dims, default=1) for dims in gathers) <= 2**DEPTH, gathers
+
+
+# ------------------------------------------------- a ranking round's gathers
+def _computations(hlo):
+    """name -> text of every computation of an HLO module."""
+    out = {}
+    for comp in re.split(_COMPUTATION, hlo):
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(", comp)
+        if head:
+            out[head.group(1)] = comp
+    return out
+
+
+def _opcodes_under(comps, name, seen=None):
+    """Opcodes of computation ``name`` and of every computation it calls
+    (fusions, loop bodies and conditions, reducers)."""
+    seen = set() if seen is None else seen
+    if name in seen or name not in comps:
+        return []
+    seen.add(name)
+    body = comps[name]
+    ops = [m.group(2) for m in (re.match(_INSTRUCTION, line) for line in body.split("\n")) if m]
+    for called in re.findall(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", body):
+        ops += _opcodes_under(comps, called, seen)
+    return ops
+
+
+@pytest.mark.parametrize("scheme", ["pairwise", "ndcg"])
+def test_a_ranking_rounds_loop_gathers_the_margins_and_nothing_else(
+    one_chip, no_compile_cache, scheme
+):
+    """K rounds under ``lax.scan``: the loop's body holds one row-to-slot
+    gather a bucket (the margins) and the two of ``rank_scatter``; the
+    compiler lifted none of the three a bucket the round used to hold out of
+    the loop (PERF.md section 6, PR 38), so what is not in the body now is
+    not run a round."""
+    import numpy as np
+
+    from sagemaker_xgboost_container_tpu.ops import ranking
+
+    rng = np.random.default_rng(0)
+    sizes = np.concatenate([rng.integers(1, 33, 3000), rng.integers(33, 65, 1500),
+                            rng.integers(65, 129, 800)])
+    host = ranking.build_group_layout(sizes)
+    buckets = len(host.indices)
+    assert buckets == 3
+    n = int(sizes.sum())
+
+    def shape(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    rows = jax.ShapeDtypeStruct((n,), jnp.float32)
+    layout = jax.tree_util.tree_map(
+        shape, jax.eval_shape(lambda y, w: ranking.with_slot_columns(host, y, w), rows, rows)
+    )
+
+    def rounds(margins, layout):
+        def body(m, _):
+            g, h = ranking.lambdarank_grad_hess(m, layout, scheme)
+            return m - 0.1 * g / (h + 1.0), None
+
+        return jax.lax.scan(body, margins, None, length=8)[0]
+
+    hlo = jax.jit(rounds).lower(shape(rows), layout).compile().as_text()
+    comps = _computations(hlo)
+    entry = next(name for name, text in comps.items() if text.startswith("ENTRY"))
+    loops = re.findall(r" while\(.*?body=%?([\w.\-]+)", comps[entry])
+    assert len(loops) == 1, loops
+    in_loop = _opcodes_under(comps, loops[0])
+    assert in_loop.count("gather") == buckets + 2
+    # and none was moved in front of the loop
+    outside = _opcodes_under(comps, entry, seen={loops[0]})
+    assert outside.count("gather") == 0

@@ -458,6 +458,7 @@ def test_ranking_group_chunking_equivalence():
     from sagemaker_xgboost_container_tpu.ops.ranking import (
         build_group_layout,
         lambdarank_grad_hess,
+        with_slot_columns,
     )
 
     rng = np.random.RandomState(7)
@@ -465,13 +466,13 @@ def test_ranking_group_chunking_equivalence():
     margins = jnp.asarray(rng.randn(n_groups * m).astype(np.float32))
     labels = jnp.asarray(rng.randint(0, 3, n_groups * m).astype(np.float32))
     weights = jnp.asarray(np.ones(n_groups * m, np.float32))
-    idx = jax.tree_util.tree_map(jnp.asarray, build_group_layout(np.full(n_groups, m)))
+    idx = with_slot_columns(build_group_layout(np.full(n_groups, m)), labels, weights)
     width = idx.indices[0].shape[1]
     # a budget of four groups' pair tensors a step against one step for all
     g1, h1 = lambdarank_grad_hess(
-        margins, labels, weights, idx, "ndcg", pair_slots_per_step=4 * width * width
+        margins, idx, "ndcg", pair_slots_per_step=4 * width * width
     )
-    g2, h2 = lambdarank_grad_hess(margins, labels, weights, idx, "ndcg")
+    g2, h2 = lambdarank_grad_hess(margins, idx, "ndcg")
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), rtol=1e-5, atol=1e-6)
 

@@ -22,6 +22,7 @@ from sagemaker_xgboost_container_tpu.models import eval_metrics, train  # noqa: 
 from sagemaker_xgboost_container_tpu.models.device_metrics import (  # noqa: E402
     all_supported,
     make_device_metric,
+    ndcg_cutoffs,
 )
 from sagemaker_xgboost_container_tpu.ops import ranking  # noqa: E402
 
@@ -30,8 +31,11 @@ from sagemaker_xgboost_container_tpu.ops import ranking  # noqa: E402
 RAGGED = np.asarray([1, 2, 7, 33, 130, 600])
 
 
-def on_device(layout):
-    return jax.tree_util.tree_map(jnp.asarray, layout)
+def on_device(layout, labels, weights=None, cutoffs=(0,)):
+    """The layout as a session puts it on the device: with its slot columns."""
+    labels = jnp.asarray(labels)
+    weights = jnp.ones_like(labels) if weights is None else jnp.asarray(weights)
+    return ranking.with_slot_columns(layout, labels, weights, cutoffs=cutoffs)
 
 
 def documents(sizes, seed=0, tie_every=5):
@@ -165,8 +169,7 @@ def test_bucketed_gradient_matches_the_float64_reference(scheme):
     layout = ranking.build_group_layout(sizes)
     assert len(layout.indices) == len(sizes) - 2  # the groups of 1, 2 and 7 share a bucket
     g, h = ranking.lambdarank_grad_hess(
-        jnp.asarray(margins), jnp.asarray(labels), jnp.asarray(weights),
-        on_device(layout), scheme,
+        jnp.asarray(margins), on_device(layout, labels, weights), scheme
     )
     g_ref, h_ref = reference_grad_hess(scheme, margins, labels, sizes)
     # float32 sums over up to 600 pairs a document against float64 sums: a few
@@ -179,12 +182,15 @@ def test_bucketed_gradient_matches_the_float64_reference(scheme):
 @pytest.mark.parametrize("scheme", ["pairwise", "ndcg", "map"])
 def test_bucketed_gradient_equals_the_one_bucket_layout(scheme):
     margins, labels, weights = documents(RAGGED, seed=4)
-    args = (jnp.asarray(margins), jnp.asarray(labels), jnp.asarray(weights))
     bucketed = ranking.build_group_layout(RAGGED)
     one = ranking.build_group_layout(RAGGED, widths=[640])
     assert len(bucketed.indices) == 4 and len(one.indices) == 1
-    g_b, h_b = ranking.lambdarank_grad_hess(*args, on_device(bucketed), scheme)
-    g_1, h_1 = ranking.lambdarank_grad_hess(*args, on_device(one), scheme)
+    g_b, h_b = ranking.lambdarank_grad_hess(
+        jnp.asarray(margins), on_device(bucketed, labels, weights), scheme
+    )
+    g_1, h_1 = ranking.lambdarank_grad_hess(
+        jnp.asarray(margins), on_device(one, labels, weights), scheme
+    )
     # only padding differs, and padding adds exact zeros; but a sum over 640
     # slots and one over 32 need not add their terms in the same order, so
     # the two are held to float32 rounding and not to the bit
@@ -221,8 +227,8 @@ def test_device_ndcg_matches_the_host(name, case):
         labels[4:44] = 0.0  # the middle group has no relevant document: counted as 1
     fn = make_device_metric(name, "rank:ndcg")
     assert fn.needs_groups
-    layout = on_device(ranking.build_group_layout(sizes))
-    got = float(fn.finalize(fn.partial(jnp.asarray(margins), jnp.asarray(labels), None, layout)))
+    layout = on_device(ranking.build_group_layout(sizes), labels, cutoffs=ndcg_cutoffs([name]))
+    got = float(fn.finalize(fn.partial(jnp.asarray(margins), None, None, layout)))
     want = eval_metrics.evaluate(name, margins, labels, groups=sizes)
     assert got == pytest.approx(want, abs=2e-6)
 

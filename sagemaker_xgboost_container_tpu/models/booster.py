@@ -910,6 +910,12 @@ class _TrainingSession:
             "from the round program's shapes (ring formula, docs/DESIGN.md "
             "Communication; 0 on one device)",
         ).set(self.hist_comm_bytes_per_round)
+        REGISTRY.gauge(
+            "round_class_trees",
+            "Trees one boosting round grows: classes x num_parallel_tree "
+            "(1 for a binary, regression or ranking job); under the class "
+            "vmap every level's kernel call runs once a tree",
+        ).set(self._build_structure()[3])
         tiles, tiles_unfolded = self._onehot_tile_plan()
         REGISTRY.gauge(
             "hist_onehot_tiles_per_round",
@@ -1218,14 +1224,23 @@ class _TrainingSession:
     def _note_binned_shape(self, shard_bins):
         """What the binned training matrix holds, set once: how many of its
         cells sit in the missing bin (counted where the shards lie; padding
-        rows, which are all missing, taken off), and how many of the
+        rows, which are all missing, taken off), how many of the
         histogram's cut slots (``max_bin - 1`` a column, all of them built
-        every level) the sketch filled. This host's rows."""
+        every level) the sketch filled, and how many columns hold one value
+        in every row (all of a column's rows in bin 0 under its one cut, or
+        none with a value at all): no split can use them, and every level
+        histograms them all the same. This host's rows."""
         from ..telemetry import REGISTRY
 
         binned = self.train_binned
         counts = [(block == binned.max_bin).sum() for block in shard_bins]
         missing = sum(int(c) for c in counts) - (self._n_pad - self.n) * binned.num_col
+        # padding rows sit in the missing bin, never in bin 0
+        in_first_bin = sum(np.asarray((block == 0).sum(axis=0)) for block in shard_bins)
+        constant = sum(
+            len(cuts) == 0 or int(in_first_bin[f]) == binned.num_row
+            for f, cuts in enumerate(binned.cut_points)
+        )
         gauges = (
             ("train_cells_missing", "Cells of the binned training matrix in the missing bin",
              missing),
@@ -1235,6 +1250,9 @@ class _TrainingSession:
              sum(len(c) for c in binned.cut_points)),
             ("sketch_cut_slots", "Cut slots a level histogram carries: columns x (max_bin - 1)",
              binned.num_col * (binned.max_bin - 1)),
+            ("train_columns_constant", "Training columns that hold one value in every row",
+             constant),
+            ("train_columns_total", "Columns of the binned training matrix", binned.num_col),
         )
         for name, text, value in gauges:
             REGISTRY.gauge(name, text).set(float(value))
@@ -1421,12 +1439,14 @@ class _TrainingSession:
                 for k in range(num_parallel):
                     rng_k = jax.random.fold_in(rng, k)
                     gk, hk = sampled(jax.random.fold_in(shard_rng, k), g, h)
+                    with stage(STAGE_GRAD):
+                        g_by_class, h_by_class = gk.T, hk.T
                     tree, row_out = jax.vmap(
                         lambda gc, hc: builder(
                             bins, gc, hc, num_cuts,
                             feature_mask=feature_mask, monotone=mono, rng=rng_k,
                         )
-                    )(gk.T, hk.T)
+                    )(g_by_class, h_by_class)
                     trees.append(tree)
                     with stage(STAGE_LEAF_MARGIN):
                         total_out = total_out + row_out.T

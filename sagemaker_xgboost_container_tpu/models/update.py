@@ -17,10 +17,11 @@ TreeRefresher/TreePruner. Semantics mirrored here:
   eta * its base weight. Stats are ALWAYS recomputed from the update data
   first (leaf values only touched when refresh was requested): stored gains
   follow different conventions per model source (this builder stores
-  0.5*delta - gamma_train, ops/split.py:72-77; imported xgboost models
-  store raw loss_chg), so comparing them directly against the update job's
-  gamma would double-count gamma or over-prune — one recomputed convention
-  makes prune consistent for every model source.
+  0.5*delta, the split's own loss change, ops/tree_build.py; a model it
+  saved before it did so stores 0.5*delta - gamma_train; imported xgboost
+  models store raw loss_chg), so comparing them directly against the update
+  job's gamma would double-count gamma or over-prune — one recomputed
+  convention makes prune consistent for every model source.
 
 Runs host-side except row routing (the compiled forest kernel): update jobs
 are one pass over num_round trees, not a boosting loop — throughput is

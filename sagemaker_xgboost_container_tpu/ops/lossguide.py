@@ -324,7 +324,9 @@ def build_tree_lossguide(
         tree["is_leaf"] = tree["is_leaf"].at[l].set(
             jnp.where(can, False, tree["is_leaf"][l])
         )
-        tree["gain"] = tree["gain"].at[l].set(jnp.where(can, gains[l], tree["gain"][l]))
+        # stored is the split's own loss change (ops/tree_build.py::build_tree)
+        won = gains[l] + gamma if gamma else gains[l]
+        tree["gain"] = tree["gain"].at[l].set(jnp.where(can, won, tree["gain"][l]))
         tree["left"] = tree["left"].at[l].set(jnp.where(can, id_a, tree["left"][l]))
         tree["right"] = tree["right"].at[l].set(jnp.where(can, id_b, tree["right"][l]))
         # exhausted leaves can't be re-picked

@@ -274,7 +274,8 @@ def build_tree(
     Tree arrays (length ``max_nodes_for_depth(max_depth)``):
       feature, bin (i32), default_left (bool), is_leaf (bool),
       leaf_value (f32, eta already applied), base_weight (f32, pre-eta),
-      gain (f32), sum_hess (f32).
+      gain (f32: a split's loss change without ``gamma`` taken off, 0 at a
+      leaf), sum_hess (f32).
 
     feature_axis_name: optional second mesh axis carrying a *column* shard
     (the reference's vestigial dsplit=col, done properly): ``bins`` holds only
@@ -631,9 +632,11 @@ def build_tree(
                 jnp.where(becomes_leaf, eta * weight, 0.0)
             )
             tree["base_weight"] = tree["base_weight"].at[sl].set(weight)
-            tree["gain"] = tree["gain"].at[sl].set(
-                jnp.where(can_split, splits["gain"], 0.0)
-            )
+            # stored is the split's own loss change, as xgboost keeps
+            # loss_chg: gamma decides whether a split is kept and is no part
+            # of what it won (a static branch: no gamma, the same program)
+            won = splits["gain"] + gamma if gamma else splits["gain"]
+            tree["gain"] = tree["gain"].at[sl].set(jnp.where(can_split, won, 0.0))
             tree["sum_hess"] = tree["sum_hess"].at[sl].set(h_tot)
             row_out = jnp.where(row_leafed, eta * at_node(weight, local_safe), row_out)
 

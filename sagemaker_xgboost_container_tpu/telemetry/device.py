@@ -354,11 +354,20 @@ def stage(name):
     return jax.named_scope(name)
 
 
+# a scope traced under ``jax.vmap`` is spelled ``vmap(route_rows)``
+_VMAPPED_SCOPE = re.compile(r"^(?:vmap\()+([^()]*)\)+$")
+
+
 def stage_of_op_name(op_name):
     """The innermost stage among the ``/``-separated scopes of an HLO
-    ``op_name`` (``jit(multi_round)/while/body/route_rows/gather``), or
-    None where the instruction was traced under no stage."""
+    ``op_name`` (``jit(multi_round)/while/body/route_rows/gather``; under the
+    class ``vmap`` of a multi-class round
+    ``.../closed_call/vmap(route_rows)/gather``), or None where the
+    instruction was traced under no stage."""
     for part in reversed(op_name.split("/")):
+        vmapped = _VMAPPED_SCOPE.match(part)
+        if vmapped:
+            part = vmapped.group(1)
         if part in STAGES:
             return part
     return None

@@ -69,7 +69,7 @@ def _libtpu_version():
 
 def start_device_runtime(
     role, mesh=None, knobs=None, route_width=None, grow_policy=None, max_depth=None,
-    **facts
+    trees_per_round=None, **facts
 ):
     """Arm the compile cache and log the ``device runtime:`` line.
 
@@ -83,7 +83,9 @@ def start_device_runtime(
     builds no trees). ``max_depth``: the trainer's, for which the line names how
     the build's rows read their level's node tables (``build_table_impl``: the
     lowering at the widest level, ``2**max_depth`` entries; a loss-guided job
-    has lookups of its own). ``facts``: what else only the caller knows about
+    has lookups of its own). ``trees_per_round``: the trainer's classes x
+    ``num_parallel_tree``, the trees one boosting round grows (the gauge
+    ``round_class_trees``; a server grows none). ``facts``: what else only the caller knows about
     the path taken (the trainer's ingest mode). Returns the logged fields.
     """
     import jax
@@ -131,6 +133,7 @@ def start_device_runtime(
             if eval_traversal == "level" and max_depth is not None
             else None
         ),
+        trees_per_round=trees_per_round,
         sketch_impl=_sketch_impl(),
         pallas_interpret=pallas_interpret(),
         compile_cache_dir=cache_dir,

@@ -160,6 +160,24 @@ def test_stage_of_op_name_takes_the_innermost_scope():
     assert set(device.STAGES) >= {"grad", "hist", "node_totals", "split_scan", "pack"}
 
 
+@pytest.mark.parametrize(
+    "op_name, stage",
+    [
+        # a multi-class round traces its builder under the class vmap
+        ("jit(multi_round)/while/body/closed_call/vmap(route_rows)/jit(clip)/min", "route_rows"),
+        ("jit(multi_round)/while/body/closed_call/vmap(hist)/broadcast_in_dim", "hist"),
+        ("jit(f)/while/body/closed_call/vmap(vmap(split_scan))/add", "split_scan"),
+        # a vmapped function that is no stage leaves the scope outside it to decide
+        ("jit(f)/while/body/closed_call/eval_apply/vmap(jit(_where))/select_n", "eval_apply"),
+        ("jit(f)/while/body/closed_call/vmap()/sub", None),
+        # only vmap is looked through: a jitted function named like a stage is none
+        ("jit(f)/while/body/jit(hist)/add", None),
+    ],
+)
+def test_stage_of_op_name_looks_through_a_vmapped_scope(op_name, stage):
+    assert device.stage_of_op_name(op_name) == stage
+
+
 def test_stages_from_hlo_text_names_fusions_and_counts_what_runs():
     table, summary = device.stages_from_hlo_text(_HLO)
     # a fusion takes the stage of its own op_name; its body is named too

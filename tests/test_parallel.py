@@ -1561,6 +1561,63 @@ def test_four_row_shares_add_up_to_the_uncut_data_and_grow_its_tree(monkeypatch)
     np.testing.assert_allclose(np.asarray(row_out), np.asarray(ref_out), rtol=1e-5, atol=1e-6)
 
 
+def test_sixteen_row_shares_add_up_to_the_uncut_histograms_class_by_class():
+    """What `mnist8m-mc10` leaves out on one chip, at a small size: the
+    deployment divides the rows over a `data` mesh of 16 and all-reduces the
+    level histograms. Under one set of cuts, the root and level-1 histograms
+    and node totals of the 16 shares, built for the ten classes side by side
+    as the round builds them (`vmap` over the class axis of the softmax
+    gradients), sum to the uncut matrix's, class by class (to 1e-4 of a
+    class's largest entry: the shares sum in another order)."""
+    import jax.numpy as jnp
+
+    from benchmark.datagen import mnist8m_like
+    from benchmark.reference import gbt_reference
+    from sagemaker_xgboost_container_tpu.data.binning import (
+        apply_cut_points, compute_cut_points,
+    )
+    from sagemaker_xgboost_container_tpu.ops.histogram import level_histogram, node_totals
+
+    shares, classes, max_bin = 16, 10, 256
+    n = shares * 192
+    config = {"train_rows": n, "validation_rows": 8, "num_feature": 784,
+              "params": {"num_class": classes}}
+    x, y = mnist8m_like.make(config, 2**31 + 16)["train"]
+    cuts = compute_cut_points(x, None, max_bin)
+    bins = apply_cut_points(x, cuts, max_bin).astype(np.int32)
+    # margins of a later round, so that the classes' gradients differ by row
+    rng = np.random.RandomState(16)
+    margin = 0.5 + 0.8 * rng.randn(n, classes) + 1.5 * (y[:, None] == np.arange(classes))
+    g, h = (
+        a.astype(np.float32)
+        for a in gbt_reference.grad_hess("multi:softmax", margin, y.astype(np.float64))
+    )
+    lit = int(np.argmax((bins > 0).mean(axis=0) * (bins == 0).mean(axis=0)))
+    level1 = (bins[:, lit] > 0).astype(np.int32)  # a pixel lit or dark
+    assert 0.2 < level1.mean() < 0.8
+    parts = [slice(s * n // shares, (s + 1) * n // shares) for s in range(shares)]
+
+    def level(rows, node_local, width):
+        def one(gc, hc):
+            G, H = level_histogram(
+                jnp.asarray(bins[rows]), gc, hc, jnp.asarray(node_local[rows]), width, max_bin + 1
+            )
+            return (G, H) + tuple(node_totals(gc, hc, jnp.asarray(node_local[rows]), width))
+
+        out = jax.vmap(one)(jnp.asarray(g[rows].T), jnp.asarray(h[rows].T))
+        return [np.asarray(a, np.float64) for a in out]
+
+    for node_local, width in ((np.zeros(n, np.int32), 1), (level1, 2)):
+        want = level(slice(0, n), node_local, width)
+        got = [sum(a) for a in zip(*(level(part, node_local, width) for part in parts))]
+        assert want[0].shape == (classes, width, 784, max_bin + 1)
+        for a, w in zip(got, want):
+            for c in range(classes):
+                np.testing.assert_allclose(a[c], w[c], rtol=0, atol=1e-4 * np.abs(w[c]).max())
+        # every class has a histogram of its own: the hessians differ by class
+        assert len({round(float(want[1][c].sum()), 3) for c in range(classes)}) == classes
+
+
 # ------------------------------------------------------ set-up shard by shard
 def _mesh4():
     return Mesh(np.array(jax.devices()[:4]), axis_names=("data",))

@@ -566,3 +566,22 @@ def test_device_runtime_line_names_the_resolved_lowering(
             said["route_impl"], said["eval_traversal"], said["build_table_impl"]
         ) == want
         assert said["route_width"] == width
+
+
+@pytest.mark.parametrize(
+    "role, trees, want",
+    [("train", 10, 10), ("train", 1, 1), ("serve", None, None)],
+    ids=["ten_class_trainer", "one_tree_trainer", "server"],
+)
+def test_device_runtime_line_says_the_trees_a_round(monkeypatch, caplog, role, trees, want):
+    """`trees_per_round`: classes x `num_parallel_tree` as `train_job` reads
+    them from the job's hyper-parameters; a server grows none."""
+    from sagemaker_xgboost_container_tpu.utils import device_runtime
+
+    monkeypatch.setattr(device_runtime, "enable_compile_cache", lambda: None)
+    extra = {} if trees is None else {"trees_per_round": trees}
+    with caplog.at_level(logging.INFO, logger=device_runtime.__name__):
+        fields = device_runtime.start_device_runtime(role, **extra)
+    line = [r.getMessage() for r in caplog.records if "device runtime: " in r.getMessage()][-1]
+    logged = json.loads(line.split("device runtime: ", 1)[1])
+    assert fields["trees_per_round"] == logged["trees_per_round"] == want

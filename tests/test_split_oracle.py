@@ -207,3 +207,117 @@ def test_split_and_default_direction_on_missing_heavy_counts(missing_rate, missi
         _score(gl, hl) + _score(g64.sum() - gl, h64.sum() - hl) - _score(g64.sum(), h64.sum())
     ) - GAMMA
     assert other < 0.8 * gain
+
+
+# ------------------------------------- ten class trees, gamma and mcw live
+MC_GAMMA, MC_MINCW, MC_DEPTH, MC_BINS = 4.0, 6.0, 5, 16
+MC_BAND = 2e-3  # a float32 scan beside a float64 oracle: nodes this near a rule are not judged
+
+
+def _oracle_best(bins, g, h, num_cuts):
+    """Over every (feature, cut) of one node's rows, float64, nothing missing:
+    the gain ``0.5 * (GL^2/(HL+l) + GR^2/(HR+l) - G^2/(H+l))`` and the two
+    children's hessian sums, each ``[features, cuts]``."""
+    cuts = int(num_cuts.max())
+    left = np.stack([(bins <= b) for b in range(cuts)])            # [cuts, rows, features]
+    gl = np.einsum("brf,r->fb", left, g)
+    hl = np.einsum("brf,r->fb", left, h)
+    gr, hr = g.sum() - gl, h.sum() - hl
+    gain = 0.5 * (_score(gl, hl) + _score(gr, hr) - _score(g.sum(), h.sum()))
+    legal = np.arange(cuts)[None, :] < num_cuts[:, None]
+    return np.where(legal, gain, -np.inf), hl, hr
+
+
+def _judge_tree(tree, bins, cuts, g, h, gamma, mincw):
+    """Walk the program's tree by its own splits. Returns the names of the
+    rules a node breaks: a kept split whose gain is under gamma, or whose
+    child is under min_child_weight, or that is not the best of those both
+    rules allow; a leaf above the last level where such a split was there."""
+    broken = []
+    num_cuts = np.asarray([len(c) for c in cuts])
+    rows_of = {0: np.arange(len(g))}
+    depth_of = {0: 0}
+    stack = [0]
+    while stack:
+        node = stack.pop()
+        rows, depth = rows_of[node], depth_of[node]
+        gain, hl, hr = _oracle_best(bins[rows], g[rows], h[rows], num_cuts)
+        allowed = np.where((hl >= mincw) & (hr >= mincw), gain, -np.inf)
+        best = float(allowed.max()) if len(rows) else -np.inf
+        if tree["left"][node] < 0:
+            if depth < MC_DEPTH and best > gamma * (1 + MC_BAND):
+                broken.append("a leaf where a split reached gamma")
+            continue
+        f = int(tree["feature"][node])
+        b = int(np.flatnonzero(cuts[f] == tree["threshold"][node])[0])
+        if gain[f, b] < gamma * (1 - MC_BAND):
+            broken.append("gamma")
+        if min(hl[f, b], hr[f, b]) < mincw * (1 - MC_BAND):
+            broken.append("min_child_weight")
+        # the scan's own pick is the best of what both rules allow
+        if gain[f, b] < best - MC_BAND * abs(best) and "min_child_weight" not in broken:
+            broken.append("not the best split")
+        # stored is the split's own loss change, gamma not taken off
+        assert abs(tree["gain"][node] - gain[f, b]) < 2e-3 * abs(gain[f, b]) + 1e-3
+        go_left = bins[rows, f] <= b
+        for child, mask in ((tree["left"][node], go_left), (tree["right"][node], ~go_left)):
+            rows_of[int(child)], depth_of[int(child)] = rows[mask], depth + 1
+            stack.append(int(child))
+    return broken
+
+
+@pytest.mark.parametrize(
+    "ignored, want",
+    [
+        (None, set()),
+        ("gamma", {"gamma"}),
+        ("min_child_weight", {"min_child_weight"}),
+    ],
+    ids=["both_rules", "gamma_ignored", "min_child_weight_ignored"],
+)
+def test_ten_class_forest_keeps_a_split_exactly_where_gamma_and_child_weight_allow(ignored, want):
+    """`mnist8m-mc10`'s trees stop early by two rules the teacher-forced
+    reference cannot see: through `models.train()` (ten classes under the
+    class `vmap`, two rounds, the generator's pictures with seeded labels), a
+    split is kept exactly where its gain reaches `gamma` and both children's
+    hessian sums reach `min_child_weight`, and is the best such split; a
+    forest grown with either rule switched off breaks that rule and no other."""
+    from benchmark.datagen import mnist8m_like
+    from benchmark.kinds.train_window import plain_rounds
+    from benchmark.reference import gbt_reference
+    from sagemaker_xgboost_container_tpu import models
+    from sagemaker_xgboost_container_tpu.data.matrix import DataMatrix
+
+    config = {"train_rows": 3000, "validation_rows": 8, "num_feature": 784,
+              "params": {"num_class": 10}}
+    x, y = mnist8m_like.make(config, 2**31 + 5)["train"]
+    params = {
+        "objective": "multi:softmax", "num_class": 10, "max_depth": MC_DEPTH, "eta": 0.2,
+        "gamma": MC_GAMMA, "min_child_weight": MC_MINCW, "lambda": LAM, "max_bin": MC_BINS,
+        "_rounds_per_dispatch": 2,
+    }
+    if ignored == "gamma":
+        params["gamma"] = 0.0
+    if ignored == "min_child_weight":
+        params["min_child_weight"] = 0.0
+    forest = models.train(params, DataMatrix(x, labels=y), num_boost_round=2, verbose_eval=False)
+    cuts = compute_cut_points(x, None, MC_BINS)
+    bins = apply_cut_points(x, cuts, MC_BINS).astype(np.int64)
+    assert (bins < MC_BINS).all()  # nothing missing: every zero is a value
+
+    margin = np.full((len(x), 10), gbt_reference.base_margin("multi:softmax", 0.5))
+    broken, leaves_above_the_last_level, splits = set(), 0, 0
+    for rnd in plain_rounds(forest, 2):
+        g, h = gbt_reference.grad_hess("multi:softmax", margin, y.astype(np.float64))
+        for c, tree in rnd:
+            found = _judge_tree(tree, bins, cuts, g[:, c], h[:, c], MC_GAMMA, MC_MINCW)
+            broken |= set(found)
+            depth = gbt_reference.node_depths(tree)
+            leaves_above_the_last_level += int(((tree["left"] < 0) & (depth < MC_DEPTH)).sum())
+            splits += int((tree["left"] >= 0).sum())
+        for c, tree in rnd:
+            margin[:, c] += gbt_reference.tree_margin(tree, x)
+    assert broken == want, broken
+    if ignored is None:
+        # both rules stop trees here: most of the twenty stop short of depth 5
+        assert leaves_above_the_last_level >= 20 and splits >= 60

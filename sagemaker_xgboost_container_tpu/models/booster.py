@@ -913,17 +913,17 @@ class _TrainingSession:
         REGISTRY.gauge(
             "round_class_trees",
             "Trees one boosting round grows: classes x num_parallel_tree "
-            "(1 for a binary, regression or ranking job); under the class "
-            "vmap every level's kernel call runs once a tree",
+            "(1 for a binary, regression or ranking job); the class trees "
+            "of a depth-wise round share one kernel call a level",
         ).set(self._build_structure()[3])
         tiles, tiles_unfolded = self._onehot_tile_plan()
         REGISTRY.gauge(
             "hist_onehot_tiles_per_round",
             "One-hot tiles ([128 rows, 128 bin lanes]) a shard's level "
             "histogram kernel latches a round: over the build's histogram "
-            "levels, row tiles x features x bin tiles after the fold "
-            "(ops/histogram.py::_bin_fold; 0 where the builder is not the "
-            "kernel)",
+            "levels, row tiles x features x bin tiles after the fold, once a "
+            "group of class trees (ops/histogram.py::_bin_fold, "
+            "_class_groups; 0 where the builder is not the kernel)",
         ).set(tiles)
         REGISTRY.gauge(
             "hist_onehot_tiles_unfolded_per_round",
@@ -1342,6 +1342,12 @@ class _TrainingSession:
 
             grad_hess = cox_mesh_grad_hess
         num_group = self.num_group
+        # the class trees of a round read one bin matrix: mapped over the
+        # class axis, a depth-wise build's level histogram takes their
+        # gradients as one operand (ops/histogram.py::_class_groups)
+        class_builder = (
+            partial(builder, class_vmap=True) if self._class_operand_trees() > 1 else builder
+        )
         subsample = cfg.subsample
         num_parallel = cfg.num_parallel_tree
         use_monotone = self.has_monotone
@@ -1442,7 +1448,7 @@ class _TrainingSession:
                     with stage(STAGE_GRAD):
                         g_by_class, h_by_class = gk.T, hk.T
                     tree, row_out = jax.vmap(
-                        lambda gc, hc: builder(
+                        lambda gc, hc: class_builder(
                             bins, gc, hc, num_cuts,
                             feature_mask=feature_mask, monotone=mono, rng=rng_k,
                         )
@@ -1738,7 +1744,15 @@ class _TrainingSession:
             num_bins,
             self.hist_knobs.precision,
             trees_per_round=trees_per_round,
+            class_trees=self._class_operand_trees(),
         )
+
+    def _class_operand_trees(self):
+        """Trees of a round whose gradients are ONE operand of the level
+        histogram kernel: the class trees of a depth-wise round, mapped over
+        the class axis (``_make_round_fn``); 1 for a one-tree round and for a
+        loss-guided build, whose members each make their own call."""
+        return 1 if self.config.grow_policy == "lossguide" else max(self.num_group, 1)
 
     def _set_comm_round_fields(self):
         """Clear the comm keys from the per-round record at session start so

@@ -19,8 +19,10 @@ Two builders, one a backend (``choose_hist_impl``, as
   leaves free rows on a latched one-hot tile (W <= 8 at 256 bin lanes), the
   bin axis is folded into it (``_bin_fold``): the kernel latches the one-hot
   of a bin's low part alone, half the tiles, and the bin's high part picks
-  which copy of the operand a row's gradients ride. Interpreted on the CPU
-  backend (tests, rehearsals).
+  which copy of the operand a row's gradients ride. The class trees of a
+  round read one bin matrix, so they share a call and its latched tiles:
+  their gradients are rows of one operand (``_class_groups``). Interpreted
+  on the CPU backend (tests, rehearsals).
 * ``flat`` (everything else, and the tests' reference): one
   ``jax.ops.segment_sum`` over n*d flattened (node, feature, bin) ids. XLA
   lowers it to a sorted scatter-add — correct everywhere, fast on CPU,
@@ -348,6 +350,7 @@ def level_histogram(
     axis_size=1,
     knobs=None,
     impl=None,
+    class_vmap=False,
 ):
     """Build (G, H) histograms for one tree level.
 
@@ -368,6 +371,11 @@ def level_histogram(
         tests, probes): the process's backend and ``bf16x2``.
       impl: a builder by name (``flat`` | ``pallas``), for direct callers;
         None chooses from the backend through ``choose_hist_impl``.
+      class_vmap: static; True where the caller's build is mapped over a
+        round's class trees with ``jax.vmap`` (the class branch of the round
+        program): the kernel then takes the trees' gradients as one operand
+        (``_class_hist_fn``). A one-tree build leaves it False and traces
+        what it always traced.
 
     Returns:
       (G, H): f32 [num_nodes, d, num_bins] for psum / no axis;
@@ -376,10 +384,15 @@ def level_histogram(
     if impl is None:
         impl = choose_hist_impl(_backend(knobs))
     if impl == "pallas":
-        G, H = _hist_pallas(
-            bins, grad, hess, node_local, num_nodes, num_bins,
-            prec=knobs.precision if knobs is not None else HIST_PRECISIONS[0],
-        )
+        prec = knobs.precision if knobs is not None else HIST_PRECISIONS[0]
+        if class_vmap:
+            G, H = _class_hist_fn(num_nodes, num_bins, prec)(
+                bins, grad, hess, node_local
+            )
+        else:
+            G, H = _hist_pallas(
+                bins, grad, hess, node_local, num_nodes, num_bins, prec=prec,
+            )
     elif impl == "flat":
         G, H = _hist_flat(bins, grad, hess, node_local, num_nodes, num_bins)
     else:
@@ -540,11 +553,13 @@ PALLAS_ROW_BLOCK = 512
 HIST_ROW_CHUNKS = 32
 
 
-def _operand_rows(W):
+def _operand_rows(W, trees=1):
     """Rows of the kernel's gradient operand for a level of W nodes: g of
     node w in row w, h in row W + w, padded ONCE to the bf16 operand tile (16
     sublanes). The operand is the side the MXU streams against each latched
     [128, 128] tile of a bin one-hot, and a level issues the rows it holds.
+    With ``trees`` class trees in one call (``_class_groups``) tree t's 2W
+    rows follow tree t - 1's, and the ``2 * W * trees`` rows are padded once.
 
     ms a call by the rows streamed against one latched tile (hi and lo halves
     of the bf16x2 operand together), W = 1, padding features dotted
@@ -561,7 +576,7 @@ def _operand_rows(W):
     what W = 32 does), and no operand narrower than the bf16 tile: under 64
     rows nothing more is to be had from this side of the dot, so the rows a
     latch still carries free take a part of the bin axis (``_bin_fold``)."""
-    return _round_up(2 * W, 16)
+    return _round_up(2 * W * trees, 16)
 
 
 # rows of the streamed operand one latched one-hot tile carries at the
@@ -609,20 +624,66 @@ def _bin_fold(rows, bin_lanes, prec):
     return fold
 
 
-def round_onehot_tiles(levels, n, d, num_bins, prec, trees_per_round=1):
+# most rows of the streamed operand one call carries for the class trees of
+# a round: ten classes at W = 8 (160 rows, the widest call the probe beside
+# ``_class_groups`` ran in one piece). From 64 streamed rows a call costs
+# what its rows cost, so wider levels lose nothing by going in groups
+CLASS_OPERAND_MAX_ROWS = 160
+
+
+def _class_groups(W, trees):
+    """``(size, groups)``: how the ``trees`` class trees of a round ride a
+    level of W nodes. A latched one-hot tile of a row tile, feature and bin
+    tile is the same for every tree that reads the one bin matrix; only the
+    streamed gradient operand differs. So ``size`` trees share one operand
+    (``_operand_rows(W, size)`` rows, within CLASS_OPERAND_MAX_ROWS) and one
+    latch of every tile, and the level takes ``groups`` such operands, one a
+    step of the kernel's outermost grid axis (equal sizes: the last group is
+    filled up with trees of dead rows). From shapes alone.
+
+    ms a call of ten class trees (scripts/dissect.py --hist-levels --trees
+    10 re-reads it; 506,250 x 784, 257 bins in u16, bf16x2: 6,221,824 tiles a
+    group; one v5e, jax 0.9.0, PR 41; PR 40's probe read the same to 0.5
+    ms), beside ten times the one-tree call (45.2 ms folded: what the class
+    axis on the kernel's grid cost):
+
+        W                       1      2      4      8     16     32     64
+        operand rows           32     48     80    160    160    128    128
+        class groups            1      1      1      1      2      5     10
+        ms a call            83.0  117.3  186.9  359.9  702.8  1,397  2,787
+        ten one-tree calls    452    452    452    452    798  1,460  2,798
+        MXU share of peak    0.80   0.85   0.89   0.92   0.94   0.95   0.95
+
+    Ten trees at W = 1 are 64 stacked rows, what a latch carries free
+    (LATCH_FREE_ROWS), so nothing is left to fold; from W = 2 the call is
+    bound by the rows it streams, and by W = 64 (a group a tree) nothing is
+    shared and nothing lost."""
+    size = min(trees, max(1, CLASS_OPERAND_MAX_ROWS // (2 * W)))
+    groups = -(-trees // size)
+    return -(-trees // groups), groups
+
+
+def round_onehot_tiles(levels, n, d, num_bins, prec, trees_per_round=1,
+                       class_trees=1):
     """``(latched, unfolded)``: the [128, 128] one-hot tiles the Pallas kernel
     latches a round over ``n`` rows x ``d`` features (a shard's), summed over
     ``levels`` (``round_hist_levels``): row tiles x features x bin tiles
-    after the fold, and the same with ``fold`` 1. From shapes alone: what
-    the fold rule engages on, stated before a round runs."""
+    after the fold, and the same with ``fold`` 1. Of the round's
+    ``trees_per_round`` trees, ``class_trees`` at a time share their latches
+    (the class trees of one bagged step: ``_class_groups``), so a level
+    latches once a class group, not once a tree; ``unfolded`` counts every
+    tree. From shapes alone: what the fold and the class operand engage on,
+    stated before a round runs."""
     block = PALLAS_ROW_BLOCK
     row_tiles = _round_up(n, block * _chunk_cap(-(-n // block))) // 128
     lanes = _bin_lanes(num_bins)
     latched = unfolded = 0
     for W, count in levels:
-        tiles = count * trees_per_round * row_tiles * d * (lanes // 128)
-        unfolded += tiles
-        latched += tiles // _bin_fold(_operand_rows(W), lanes, prec)
+        tiles = count * row_tiles * d * (lanes // 128)
+        unfolded += tiles * trees_per_round
+        size, groups = _class_groups(W, class_trees)       # (1, 1) for one tree
+        calls = trees_per_round // class_trees * groups
+        latched += tiles * calls // _bin_fold(_operand_rows(W, size), lanes, prec)
     return latched, unfolded
 
 
@@ -673,7 +734,7 @@ def _pallas_feature_group(d, bins_dtype):
 
 @functools.lru_cache(maxsize=None)
 def _pallas_hist_fn(n, d, fg, W, B, block, prec, interpret, split_missing,
-                    rows, chunks, fold=1):
+                    rows, chunks, fold=1, class_groups=None):
     """Compiled pallas histogram over ROW-ON-LANES operands: (bins int
     [d_pad, n] — any integer storage dtype, widened per block in VMEM so
     u8/u16 bins move fewer HBM bytes — gh f32 [2, n], node i32 [1, n]) ->
@@ -697,6 +758,17 @@ def _pallas_hist_fn(n, d, fg, W, B, block, prec, interpret, split_missing,
     feature's slab. The missing bin (B - 1 = fold * L where it is split out)
     matches no copy, as it matches no lane of the unfolded one-hot. Output
     shapes and the kernel's name do not depend on ``fold``.
+
+    ``class_groups`` = (size, groups) (see _class_groups; None is the
+    one-tree kernel, traced as it always was): the class trees of a round
+    over the one bin matrix. gh is f32 [groups, 2 * size, n] (a group's g
+    rows, then its h rows), node i32 [groups, size, n] (the trees route
+    their rows apart), and tree t of a group holds rows [2W * t, 2W * (t +
+    1)) of the operand, g then h. One latched one-hot tile then serves the
+    ``size`` trees of a group; the groups are the outermost grid axis and
+    the outputs' leading one: (main f32 [groups, chunks, d_pad, rows, Bp],
+    miss f32 [groups, chunks, d_pad, rows or 2*rows]). The fold, the chunks,
+    the missing bin and the padding features as for one tree.
 
     Every operand keeps rows on the lane axis, so the kernel has no
     lane-sparse [block, 1] blocks, no in-kernel transposes and no strided
@@ -725,22 +797,43 @@ def _pallas_hist_fn(n, d, fg, W, B, block, prec, interpret, split_missing,
     if L * fold != Bp or L % 128:
         raise ValueError("fold {} does not cut {} bin lanes into whole tiles".format(fold, Bp))
 
+    # the class groups lead the grid, the gh, node and output blocks (one
+    # group a step) and the outputs' shapes; one tree has no such axis, and
+    # nothing is traced for it
+    size, tree_groups = class_groups or (1, None)
+    lead = () if class_groups is None else (0,)
+    axis0 = len(lead)
+
     def kernel(bins_ref, gh_ref, node_ref, out_ref, miss_ref):
-        @pl.when(pl.program_id(2) == 0)
+        @pl.when(pl.program_id(axis0 + 2) == 0)
         def _():
             out_ref[...] = jnp.zeros_like(out_ref)
             miss_ref[...] = jnp.zeros_like(miss_ref)
 
-        node = node_ref[...]                           # [1, blk]
-        dead = node >= W
-        # a dead row must stay out of BOTH halves, not land in h's first rows
-        g_row = jnp.where(dead, -1, node)
-        h_row = jnp.where(dead, -1, node + W)
-        row = jax.lax.broadcasted_iota(jnp.int32, (rows, block), 0)
-        A = jnp.where(
-            row == g_row, gh_ref[0:1, :],
-            jnp.where(row == h_row, gh_ref[1:2, :], 0.0),
-        )  # [rows, blk]
+        if class_groups is None:
+            node = node_ref[...]                       # [1, blk]
+            dead = node >= W
+            # a dead row must stay out of BOTH halves, not land in h's first
+            # rows
+            g_row = jnp.where(dead, -1, node)
+            h_row = jnp.where(dead, -1, node + W)
+            row = jax.lax.broadcasted_iota(jnp.int32, (rows, block), 0)
+            A = jnp.where(
+                row == g_row, gh_ref[0:1, :],
+                jnp.where(row == h_row, gh_ref[1:2, :], 0.0),
+            )  # [rows, blk]
+        else:
+            row = jax.lax.broadcasted_iota(jnp.int32, (rows, block), 0)
+            A = jnp.zeros((rows, block), jnp.float32)
+            for t in range(size):                      # tree t: rows 2W * t ..
+                node = node_ref[0, t:t + 1, :]         # [1, blk]
+                dead = node >= W
+                g_row = jnp.where(dead, -1, node + 2 * W * t)
+                h_row = jnp.where(dead, -1, node + 2 * W * t + W)
+                A = jnp.where(
+                    row == g_row, gh_ref[0, t:t + 1, :],
+                    jnp.where(row == h_row, gh_ref[0, size + t:size + t + 1, :], A),
+                )
         if stacked:
             A = jnp.concatenate(_split_bf16(A), axis=0)    # [2*rows, blk]
         else:  # "bf16": one rounded half, the failing control
@@ -770,21 +863,21 @@ def _pallas_hist_fn(n, d, fg, W, B, block, prec, interpret, split_missing,
             )
             for t in range(fold):
                 Pt = P[t * S:(t + 1) * S]
-                out_ref[0, f, :, t * L:(t + 1) * L] += (
+                out_ref[lead + (0, f, slice(None), slice(t * L, (t + 1) * L))] += (
                     (Pt[:rows] + Pt[rows:]) if stacked else Pt
                 )
 
         for f in range(real_in_last):                  # real in every group
             feature(f)
         if real_in_last < fg and groups > 1:
-            @pl.when(pl.program_id(0) < groups - 1)
+            @pl.when(pl.program_id(axis0) < groups - 1)
             def _():
                 for f in range(real_in_last, fg):
                     feature(f)
 
         if split_missing:
             miss = (bw == (B - 1)).astype(jnp.bfloat16)    # [fg, blk]
-            miss_ref[0] += jax.lax.dot_general(
+            miss_ref[lead + (0,)] += jax.lax.dot_general(
                 miss, A, lanes, preferred_element_type=jnp.float32
             )
 
@@ -793,24 +886,29 @@ def _pallas_hist_fn(n, d, fg, W, B, block, prec, interpret, split_missing,
     # one-hot temporaries are small next to them
     acc_bytes = fg * rows * (Bp + 128) * 4
     vmem_limit = min(2 * acc_bytes + 16 * 1024 * 1024, 100 * 1024 * 1024)
+    q, qs = (1,) * axis0, (tree_groups,) * axis0
+
+    def behind_group(index):
+        return lambda *ids: ids[:-3] + index(*ids[-3:])
+
     return pl.pallas_call(
         kernel,
-        grid=(groups, chunks, per),
+        grid=qs + (groups, chunks, per),
         in_specs=[
-            pl.BlockSpec((fg, block), lambda j, c, i: (j, c * per + i)),
-            pl.BlockSpec((2, block), lambda j, c, i: (0, c * per + i)),
-            pl.BlockSpec((1, block), lambda j, c, i: (0, c * per + i)),
+            pl.BlockSpec((fg, block), lambda *ids: (ids[-3], ids[-2] * per + ids[-1])),
+            pl.BlockSpec(q + (2 * size, block), behind_group(lambda j, c, i: (0, c * per + i))),
+            pl.BlockSpec(q + (size, block), behind_group(lambda j, c, i: (0, c * per + i))),
         ],
         out_specs=[
-            pl.BlockSpec((1, fg, rows, Bp), lambda j, c, i: (c, j, 0, 0)),
-            pl.BlockSpec((1, fg, miss_rows), lambda j, c, i: (c, j, 0)),
+            pl.BlockSpec(q + (1, fg, rows, Bp), behind_group(lambda j, c, i: (c, j, 0, 0))),
+            pl.BlockSpec(q + (1, fg, miss_rows), behind_group(lambda j, c, i: (c, j, 0))),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((chunks, d_pad, rows, Bp), jnp.float32),
-            jax.ShapeDtypeStruct((chunks, d_pad, miss_rows), jnp.float32),
+            jax.ShapeDtypeStruct(qs + (chunks, d_pad, rows, Bp), jnp.float32),
+            jax.ShapeDtypeStruct(qs + (chunks, d_pad, miss_rows), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel",) * (len(q) + 2) + ("arbitrary",),
             vmem_limit_bytes=vmem_limit,
         ),
         interpret=interpret,
@@ -820,15 +918,21 @@ def _pallas_hist_fn(n, d, fg, W, B, block, prec, interpret, split_missing,
 
 def _hist_pallas(bins, grad, hess, node_local, num_nodes, num_bins,
                  prec=HIST_PRECISIONS[0]):
+    """One tree (``grad``, ``hess``, ``node_local`` f32 / i32 [n]) ->
+    (G, H) f32 [W, d, B]; or the T class trees of a round over the one bin
+    matrix (each [T, n]: the gradients' rank decides) -> [T, W, d, B], the
+    trees in groups that share their latched one-hot tiles
+    (``_class_groups``)."""
     if prec not in HIST_PRECISIONS:
         raise ValueError("unknown histogram precision: {!r}".format(prec))
     n, d = bins.shape
     W = num_nodes
     B = num_bins
+    trees = grad.shape[:-1]                            # () or (T,)
     if n == 0:
         # grid would be (.., 0): the step-0 out_ref init never runs and the
         # kernel would return an uninitialized buffer
-        zeros = jnp.zeros((W, d, B), jnp.float32)
+        zeros = jnp.zeros(trees + (W, d, B), jnp.float32)
         return zeros, zeros
     block = PALLAS_ROW_BLOCK
 
@@ -844,23 +948,85 @@ def _hist_pallas(bins, grad, hess, node_local, num_nodes, num_bins,
     # rows onto the lane axis (see _pallas_hist_fn); the padding rows are
     # dead (node == W) and the padding features are sliced off below
     bins_t = jnp.pad(bins.T, [(0, d_pad - d), (0, n_pad - n)])
-    gh = jnp.pad(jnp.stack([g, h]), [(0, 0), (0, n_pad - n)])
-    node = jnp.pad(node, [(0, n_pad - n)], constant_values=W)
-
     split_missing = _mxu_split_missing(B)
-    rows = _operand_rows(W)
-    fn = _pallas_hist_fn(
-        n_pad, d, fg, W, B, block, prec, pallas_interpret(), split_missing,
-        rows, _row_chunks(W, cap), _bin_fold(rows, _bin_lanes(B), prec),
-    )
-    main, miss = fn(bins_t, gh, node[None, :].astype(jnp.int32))
+    interpret = pallas_interpret()
+    chunks = _row_chunks(W, cap)
+    lanes = _bin_lanes(B)
+    if not trees:
+        gh = jnp.pad(jnp.stack([g, h]), [(0, 0), (0, n_pad - n)])
+        node = jnp.pad(node, [(0, n_pad - n)], constant_values=W)
 
-    Bm = B - 1 if split_missing else B
-    GH = main.sum(axis=0)[:d, :, :Bm]                  # [d, rows, Bm]
+        rows = _operand_rows(W)
+        fn = _pallas_hist_fn(
+            n_pad, d, fg, W, B, block, prec, interpret, split_missing,
+            rows, chunks, _bin_fold(rows, lanes, prec),
+        )
+        GH = _sum_row_chunks(
+            *fn(bins_t, gh, node[None, :].astype(jnp.int32)), d, rows, B, split_missing
+        )
+        GH = GH.transpose(1, 0, 2)                         # [rows, d, B]
+        return GH[:W], GH[W:2 * W]
+
+    T = trees[0]
+    size, groups = _class_groups(W, T)
+    # whole groups: a tree that fills up the last one has dead rows alone
+    fill = [(0, size * groups - T), (0, n_pad - n)]
+    g, h = (jnp.pad(x, fill).reshape(groups, size, n_pad) for x in (g, h))
+    node = jnp.pad(node, fill, constant_values=W).reshape(groups, size, n_pad)
+    rows = _operand_rows(W, size)
+    fn = _pallas_hist_fn(
+        n_pad, d, fg, W, B, block, prec, interpret, split_missing,
+        rows, chunks, _bin_fold(rows, lanes, prec), class_groups=(size, groups),
+    )
+    GH = _sum_row_chunks(
+        *fn(bins_t, jnp.concatenate([g, h], axis=1), node.astype(jnp.int32)),
+        d, rows, B, split_missing,
+    )                                                      # [groups, d, rows, B]
+    # a group's rows are (tree, g | h, node): trees to the front
+    GH = GH[:, :, :size * 2 * W].reshape(groups, d, size, 2, W, B)
+    GH = GH.transpose(0, 2, 3, 4, 1, 5).reshape(groups * size, 2, W, d, B)[:T]
+    return GH[:, 0], GH[:, 1]
+
+
+def _sum_row_chunks(main, miss, d, rows, B, split_missing):
+    """The kernel's two results (``_pallas_hist_fn``: main [.., chunks,
+    d_pad, rows, Bp], miss [.., chunks, d_pad, rows or 2 * rows], a leading
+    class-group axis or none) -> [.., d, rows, B]: the row chunks' partial
+    sums added, the padding features and bin lanes cut off, and the missing
+    bin, where it was split out, put back as the last bin."""
+    GH = main.sum(axis=-4)[..., :d, :, :B - 1 if split_missing else B]
     if split_missing:
-        miss = miss.sum(axis=0)[:d]
-        if miss.shape[1] != rows:                      # hi and lo halves
-            miss = miss[:, :rows] + miss[:, rows:]
-        GH = jnp.concatenate([GH, miss[:, :, None]], axis=2)
-    GH = GH.transpose(1, 0, 2)                         # [rows, d, B]
-    return GH[:W], GH[W:2 * W]
+        miss = miss.sum(axis=-3)[..., :d, :]
+        if miss.shape[-1] != rows:                         # hi and lo halves
+            miss = miss[..., :rows] + miss[..., rows:]
+        GH = jnp.concatenate([GH, miss[..., None]], axis=-1)
+    return GH
+
+
+@functools.lru_cache(maxsize=None)
+def _class_hist_fn(num_nodes, num_bins, prec):
+    """``_hist_pallas`` for the builds of a round's class trees, which
+    ``models/booster.py`` maps over the class axis with ``jax.vmap``: its
+    batching rule hands the kernel the [T, n] gradients as ONE operand over
+    the one bin matrix, where Pallas's own rule would put the class axis on
+    the kernel's grid and latch every one-hot tile once a class. Only the
+    class branch of the round program asks for it (``level_histogram``'s
+    ``class_vmap``): a one-tree build calls ``_hist_pallas`` itself, with
+    nothing wrapped around it."""
+
+    def call(bins, grad, hess, node_local):  # one tree's [n], or [T, n]
+        return _hist_pallas(bins, grad, hess, node_local, num_nodes, num_bins, prec=prec)
+
+    hist = jax.custom_batching.custom_vmap(call)
+
+    @hist.def_vmap
+    def _(axis_size, in_batched, bins, grad, hess, node_local):
+        # the bins are the one matrix, never mapped; the root's node ids (all
+        # rows in node 0) are one row for every tree
+        grad, hess, node_local = (
+            x if batched else jnp.broadcast_to(x, (axis_size,) + x.shape)
+            for x, batched in zip((grad, hess, node_local), in_batched[1:])
+        )
+        return call(bins, grad, hess, node_local), (True, True)
+
+    return hist

@@ -181,6 +181,7 @@ def find_best_splits(
     feature_mask=None,
     monotone=None,
     totals=None,
+    gathers=True,
 ):
     """Best (feature, bin, default_dir, gain) per node at one level.
 
@@ -195,6 +196,14 @@ def find_best_splits(
         feature-0 derivation — required when G/H are a reduce_scattered
         feature slice (broadcast_node_totals), where local feature 0 is a
         different global feature on every shard.
+      gathers: static; how the winner's gain and default direction are read
+        at ``best_idx``. True: two ``take_along_axis`` gathers, a node long.
+        False, for a build mapped over the class trees of a round: a maximum
+        and a compare-select-reduce, the same values bit for bit. Mapped, the
+        gathers become one over a [T, W, d * nbins] operand that XLA's
+        memory-space assignment may keep in VMEM on the chip, and a v5e stops
+        for good in such a gather of the ``take_left`` mask at some indices
+        (the program it was found in: PERF.md section 6, PR 41).
 
     Returns dict of per-node arrays (length W): gain f32, feature i32,
     bin i32, default_left bool, plus node totals g_total/h_total f32.
@@ -255,12 +264,19 @@ def find_best_splits(
 
     flat = gain.reshape(W, d * nbins)
     best_idx = jnp.argmax(flat, axis=1)
-    best_gain = jnp.take_along_axis(flat, best_idx[:, None], axis=1)[:, 0]
     best_feature = (best_idx // nbins).astype(jnp.int32)
     best_bin = (best_idx % nbins).astype(jnp.int32)
-    best_default_left = jnp.take_along_axis(
-        take_left.reshape(W, d * nbins), best_idx[:, None], axis=1
-    )[:, 0]
+    if gathers:
+        best_gain = jnp.take_along_axis(flat, best_idx[:, None], axis=1)[:, 0]
+        best_default_left = jnp.take_along_axis(
+            take_left.reshape(W, d * nbins), best_idx[:, None], axis=1
+        )[:, 0]
+    else:
+        # argmax is the first maximum (a NaN counts as one), so the value
+        # there is the maximum itself
+        best_gain = flat.max(axis=1)
+        at_best = jnp.arange(d * nbins, dtype=best_idx.dtype)[None, :] == best_idx[:, None]
+        best_default_left = (take_left.reshape(W, d * nbins) & at_best).any(axis=1)
 
     return {
         "gain": jnp.where(jnp.isfinite(best_gain), best_gain, -jnp.inf),

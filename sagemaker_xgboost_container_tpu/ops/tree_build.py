@@ -268,6 +268,7 @@ def build_tree(
     hist_comm="psum",
     n_data_shards=1,
     knobs=None,
+    class_vmap=False,
 ):
     """Grow one tree. Returns (tree arrays dict, row_out f32 [n]).
 
@@ -303,6 +304,13 @@ def build_tree(
     knobs: the session's ``ops.histogram.HistKnobs`` snapshot (trace-safety:
     the traced build must not read env; None, for direct unit-test/probe
     callers, chooses every lowering from the process's backend).
+
+    class_vmap: static; True where the caller maps this build over the class
+    trees of a round with ``jax.vmap`` (``models/booster.py``, the class
+    branch): the level histogram kernel then takes the trees' gradients as
+    one operand over the one bin matrix (``ops.histogram._class_hist_fn``),
+    and the split scan reads its winners without a gather
+    (``ops.split.find_best_splits``, ``gathers``).
 
     Every per-row read of a level's per-node table is ``node_table_lookup`` in
     the lowering ``choose_table_impl(backend, 2**level)`` picks: the four
@@ -431,7 +439,7 @@ def build_tree(
                 left_local = jnp.where(active & is_left, node_local // 2, -1)
                 Gl_loc, Hl_loc = level_histogram(
                     bins, grad, hess, left_local, width // 2, num_bins,
-                    knobs=knobs,
+                    knobs=knobs, class_vmap=class_vmap,
                 )
                 keep = ~parent_leaf
 
@@ -463,6 +471,7 @@ def build_tree(
             else:
                 G_loc, H_loc = level_histogram(
                     bins, grad, hess, node_local, width, num_bins, knobs=knobs,
+                    class_vmap=class_vmap,
                 )
                 batch_hists = [
                     (nsl,)
@@ -552,6 +561,7 @@ def build_tree(
                     feature_mask=scan_mask,
                     monotone=scan_mono,
                     totals=scan_totals,
+                    gathers=not class_vmap,
                 )
                 if reduce_scatter:
                     # the data axis is a feature axis for the duration of the

@@ -27,7 +27,8 @@ every level's node count W under the operand-row rule
 (``ops/histogram._operand_rows``) and the bin fold (``_bin_fold``: each folded
 level also at ``fold`` 1, with the one-hot tiles a call latches), then at
 W = 1 with the operand padded to more rows: the tables the two rules were
-read from. Run under an external timeout,
+read from; with ``--trees T`` the call of T class trees in one operand
+(``_class_groups``) at every W. Run under an external timeout,
 like anything that holds a device.
 """
 
@@ -293,14 +294,17 @@ HIST_PROBE_LEVELS = (1, 2, 4, 8, 16, 32, 64)  # a depth-8 tree with subtraction
 HIST_PROBE_ROWS = (16, 32, 64, 128)           # operand rows at W = 1
 
 
-def hist_level_probe(shapes, num_bins=MAX_BIN + 1):
+def hist_level_probe(shapes, num_bins=MAX_BIN + 1, trees=1):
     """ms a call of the level histogram kernel by node count W under the
     shipped operand-row, chunk and fold rules (a folded level also at
     ``fold`` 1), then by operand rows at W = 1 in both precisions, with the
     rows the MXU streams against one latched one-hot tile (both halves of
     the split operand, every folded copy), the tiles a call latches and the
     share of the MXU's peak that the issued flops make. Bins and gradients
-    are made on the device."""
+    are made on the device. With ``trees`` > 1 the call is that of a round's
+    class trees in one operand (``ops/histogram._class_groups``: the trees
+    of a group share their latches, the groups are a grid axis), by node
+    count alone."""
     import jax
     import jax.numpy as jnp
 
@@ -329,24 +333,33 @@ def hist_level_probe(shapes, num_bins=MAX_BIN + 1):
         gh = jax.random.normal(k_gh, (2, n_pad), jnp.float32)
         jax.block_until_ready((bins, gh))
 
-        def call(W, rows, chunks, prec="bf16x2", fold=1):
-            node = jax.random.randint(k_node, (1, n_pad), 0, W, jnp.int32)
+        def call(W, rows, chunks, prec="bf16x2", fold=1, class_groups=None):
+            size, groups = class_groups or (1, 1)
+            node = jax.random.randint(k_node, (groups * size, n_pad), 0, W, jnp.int32)
+            operands = (gh, node)
+            if class_groups:  # [groups, 2 * size, n] and [groups, size, n]
+                operands = (
+                    jax.random.normal(k_gh, (groups, 2 * size, n_pad), jnp.float32),
+                    node.reshape(groups, size, n_pad),
+                )
             fn = jax.jit(H._pallas_hist_fn(
                 n_pad, d, fg, W, B, block, prec, H.pallas_interpret(),
-                split_missing, rows, chunks, fold,
+                split_missing, rows, chunks, fold, class_groups,
             ))
-            ms = _time(fn, bins, gh, node)
+            ms = _time(fn, bins, *operands)
             # what the MXU is handed: for every real feature ``fold`` copies
             # of the operand's rows (both halves of bf16x2 in one dot)
             # against a [block, bin_lanes / fold] one-hot, two flops a
-            # multiply-add; a tile is [128 rows, 128 bin lanes] of it
+            # multiply-add, once a class group; a tile is [128 rows, 128 bin
+            # lanes] of it
             streamed = fold * rows * (2 if prec == "bf16x2" else 1)
-            flops = 2.0 * n_pad * d * streamed * (bin_lanes // fold)
+            flops = 2.0 * groups * n_pad * d * streamed * (bin_lanes // fold)
             row = {
-                "shape": name, "W": W, "prec": prec, "operand_rows": rows,
+                "shape": name, "W": W, "prec": prec, "trees": trees,
+                "tree_groups": groups, "operand_rows": rows,
                 "chunks": chunks, "fold": fold,
                 "streamed_rows_a_tile": streamed,
-                "tiles_latched": (n_pad // 128) * d * (bin_lanes // 128 // fold),
+                "tiles_latched": groups * (n_pad // 128) * d * (bin_lanes // 128 // fold),
                 "ms": ms,
                 "mxu_share_of_peak": flops / (ms * 1e-3) / peak if peak else None,
             }
@@ -354,13 +367,15 @@ def hist_level_probe(shapes, num_bins=MAX_BIN + 1):
             out.append(row)
 
         for W in HIST_PROBE_LEVELS:
-            rows = H._operand_rows(W)
+            class_groups = H._class_groups(W, trees) if trees > 1 else None
+            rows = H._operand_rows(W, class_groups[0] if class_groups else 1)
             # the shipped fold, and the unfolded kernel beside it
             for fold in sorted({1, H._bin_fold(rows, bin_lanes, "bf16x2")}):
-                call(W, rows, H._row_chunks(W, cap), fold=fold)
+                call(W, rows, H._row_chunks(W, cap), fold=fold,
+                     class_groups=class_groups)
         # the rule's table: one latched one-hot tile costs what 64 streamed
         # rows cost; the one-pass control reaches 16 rows a tile
-        for prec in H.HIST_PRECISIONS:
+        for prec in H.HIST_PRECISIONS if trees == 1 else ():
             for rows in HIST_PROBE_ROWS:
                 for fold in sorted({1, H._bin_fold(rows, bin_lanes, prec)}):
                     call(1, rows, 1, prec, fold)
@@ -394,6 +409,10 @@ def main():
     ap.add_argument(
         "--hist-levels", action="store_true",
         help="run only the level-histogram probe (both cells' shapes)",
+    )
+    ap.add_argument(
+        "--trees", type=int, default=1, metavar="T",
+        help="with --hist-levels: the call of T class trees in one operand",
     )
     ap.add_argument("--out", default=None, help="also write the JSON summary here")
     args = ap.parse_args()
@@ -440,7 +459,7 @@ def main():
         summary = {
             "backend": jax.default_backend(),
             "device_kind": jax.devices()[0].device_kind,
-            "hist_level_probe": hist_level_probe(shapes),
+            "hist_level_probe": hist_level_probe(shapes, trees=args.trees),
         }
         _emit(summary, args.out)
         return

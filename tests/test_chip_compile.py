@@ -71,16 +71,23 @@ def _results(hlo, fused):
     return out
 
 
-@pytest.mark.parametrize("classes", [0, 3], ids=["one_tree", "class_vmap"])
+@pytest.mark.parametrize(
+    "classes, class_vmap",
+    [(0, False), (3, False), (3, True)],
+    # a mapped build under Pallas's own batching rule (DART, the CV folds, a
+    # loss-guided multi-class job), and the round program's class branch
+    ids=["one_tree", "class_vmap", "class_operand"],
+)
 def test_build_tree_holds_no_rows_by_width_buffer_and_no_row_gather(
-    one_chip, no_compile_cache, classes
+    one_chip, no_compile_cache, classes, class_vmap
 ):
     knobs = resolve_hist_knobs()._replace(backend="tpu")
 
     def build(bins, grad, hess, num_cuts):
         def one(g, h):
             tree, row_out = build_tree(
-                bins, g, h, num_cuts, DEPTH, NUM_BINS, eta=0.1, knobs=knobs
+                bins, g, h, num_cuts, DEPTH, NUM_BINS, eta=0.1, knobs=knobs,
+                class_vmap=class_vmap,
             )
             return pack_tree(tree), row_out
 
@@ -117,7 +124,41 @@ def test_build_tree_holds_no_rows_by_width_buffer_and_no_row_gather(
     gathers = [
         dims for op, _dtype, dims in held + _results(hlo, fused=True) if op == "gather"
     ]
-    assert gathers and max(max(dims, default=1) for dims in gathers) <= 2**DEPTH, gathers
+    if class_vmap:
+        # the round program's class branch has none: mapped, the split scan's
+        # reads of its winners were one gather over a [T, W, d * bins] mask
+        # that XLA kept in VMEM, where a v5e stopped in it (PERF.md section 6,
+        # PR 41); `find_best_splits(gathers=False)` reads them without one
+        assert not gathers, gathers
+    else:
+        assert gathers and max(max(dims, default=1) for dims in gathers) <= 2**DEPTH, gathers
+
+
+@pytest.mark.parametrize("W", [1, 8, 16, 64])
+def test_ten_class_trees_in_one_operand_compile_for_the_chip(
+    one_chip, no_compile_cache, monkeypatch, W
+):
+    """`mnist8m-mc10`'s level (506,250 x 784, 257 bins in u16, ten class
+    trees) through the chip's own kernel compiler, which the interpreter on
+    the CPU is not: 32 operand rows in one group at W = 1, 160 at W = 8, two
+    groups of 160 at W = 16, a group a tree at W = 64."""
+    from sagemaker_xgboost_container_tpu.ops import histogram as hist_mod
+
+    monkeypatch.setattr(hist_mod, "pallas_interpret", lambda: False)
+    n, d, T = 506_250, 784, 10
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = (
+        jax.jit(lambda b, g, h, node: hist_mod._hist_pallas(b, g, h, node, W, NUM_BINS))
+        .lower(
+            shape((n, d), jnp.uint16), shape((T, n), jnp.float32),
+            shape((T, n), jnp.float32), shape((T, n), jnp.int32),
+        )
+        .compile()
+    )
+    assert compiled.as_text().count("tpu_custom_call") >= 1
 
 
 # ------------------------------------------------- a ranking round's gathers

@@ -910,12 +910,12 @@ class _TrainingSession:
             "from the round program's shapes (ring formula, docs/DESIGN.md "
             "Communication; 0 on one device)",
         ).set(self.hist_comm_bytes_per_round)
-        REGISTRY.gauge(
-            "round_class_trees",
-            "Trees one boosting round grows: classes x num_parallel_tree "
-            "(1 for a binary, regression or ranking job); the class trees "
-            "of a depth-wise round share one kernel call a level",
-        ).set(self._build_structure()[3])
+        # the round's shape as gauges (``round_class_trees``: the trees a
+        # round grows; ``round_split_steps``: a loss-guided build's split
+        # steps), in a method below the round program: a line moved above
+        # the traced code moves the compile cache's key (PERF.md section 5)
+        # and costs every job one cold compile
+        _note_round_shape(self)
         tiles, tiles_unfolded = self._onehot_tile_plan()
         REGISTRY.gauge(
             "hist_onehot_tiles_per_round",
@@ -2307,6 +2307,54 @@ class _TrainingSession:
         )
 
 
+def _note_round_shape(session):
+    """The gauges that say what one boosting round of ``session`` builds,
+    set once at session build (``_TrainingSession.__init__``)."""
+    from ..telemetry import REGISTRY
+    from ..utils.device_runtime import split_steps_per_round
+
+    cfg = session.config
+    trees = session._build_structure()[3]
+    REGISTRY.gauge(
+        "round_class_trees",
+        "Trees one boosting round grows: classes x num_parallel_tree "
+        "(1 for a binary, regression or ranking job); the class trees "
+        "of a depth-wise round share one kernel call a level",
+    ).set(trees)
+    REGISTRY.gauge(
+        "round_split_steps",
+        "Split steps one boosting round runs: (max_leaves - 1) x the trees "
+        "a round grows for a loss-guided job, whose steps are one rolled "
+        "loop (ops/lossguide.py); 0 for a depth-wise job",
+    ).set(split_steps_per_round(cfg.grow_policy, cfg.max_leaves, trees))
+    _tree_depth_gauge().set(0)  # the deepest leaf of THIS session's trees
+
+
+def _tree_depth_gauge():
+    from ..telemetry import REGISTRY
+
+    return REGISTRY.gauge(
+        "tree_depth_max",
+        "Depth of the deepest leaf over the trees the training session has "
+        "committed (root = 0): the levels the evaluation walk of such a tree "
+        "makes",
+    )
+
+
+def note_committed_trees(trees):
+    """Counts the trees a round committed, from the compact trees the host
+    holds anyway (no device work): the leaves grown, and the deepest leaf of
+    the session so far, which is the number of levels the pointer walk of a
+    loss-guided tree makes (``ops/tree_build.py::predict_binned``)."""
+    from ..telemetry import REGISTRY
+
+    REGISTRY.counter(
+        "tree_leaves_total", "Leaves of the trees committed by training rounds"
+    ).inc(sum(int(np.count_nonzero(t.is_leaf)) for t in trees))
+    deepest = _tree_depth_gauge()
+    deepest.set(max([deepest.value] + [t.depth() for t in trees]))
+
+
 def evaluate_host_lines(
     entries,
     metric_names,
@@ -2682,6 +2730,7 @@ def train(
                 with span("commit", attributes={"round": rnd}):
                     trees, info = _trees_for_round(tree_np)
                     forest.append_round(trees, info)
+                    note_committed_trees(trees)
 
                 if j < len(session.last_learning_stats):
                     # model-quality plane: device reductions + committed-tree

@@ -95,16 +95,19 @@ class Tree:
         return self.left < 0
 
     def depth(self):
-        """Max root->leaf depth (host-side, for kernel iteration count)."""
+        """Max root->leaf depth (host-side, for kernel iteration count). A
+        level at a time, in numpy: the training loop asks it of every tree it
+        commits (``models/booster.py::note_committed_trees``), inside the
+        host's turnaround between two dispatches."""
         depth = 0
-        frontier = [(0, 0)]
-        while frontier:
-            node, d = frontier.pop()
-            depth = max(depth, d)
-            if self.left[node] >= 0:
-                frontier.append((int(self.left[node]), d + 1))
-                frontier.append((int(self.right[node]), d + 1))
-        return depth
+        nodes = np.zeros(1, np.int64)
+        while True:
+            left = self.left[nodes]
+            internal = left >= 0
+            if not internal.any():
+                return depth
+            nodes = np.concatenate([left[internal], self.right[nodes][internal]])
+            depth += 1
 
 
 def _parents_from_children(left, right):
@@ -119,8 +122,14 @@ def _parents_from_children(left, right):
 def compact_padded_tree(padded, cut_points):
     """Trainer's padded arrays (numpy) -> compact Tree.
 
-    Keeps only reachable nodes (BFS from root through explicit child indices);
-    split bin indices become float thresholds via the feature's cut array.
+    Keeps only the nodes reachable from the root through the explicit child
+    indices and numbers them in increasing padded slot: a parent's slot is
+    below its children's in both builders' layouts, so parents come first.
+    For ``build_tree``'s heap that is breadth-first order; for a loss-guided
+    tree (``ops/lossguide.py``: split step t makes slots 2t+1 and 2t+2) it is
+    the order of expansion, as xgboost's own loss-guided updater numbers its
+    nodes. Split bin indices become float thresholds via the feature's cut
+    array.
     """
     is_leaf = np.asarray(padded["is_leaf"])
     feature = np.asarray(padded["feature"])
@@ -137,14 +146,13 @@ def compact_padded_tree(padded, cut_points):
         ids = np.arange(len(is_leaf), dtype=np.int32)
         child_left, child_right = 2 * ids + 1, 2 * ids + 2
 
-    # BFS in padded numbering, assigning compact ids in visit order
+    # reachable slots, then compact ids in increasing slot order
     order = [0]
-    compact_id = {0: 0}
     for node in order:
         if not is_leaf[node]:
-            for child in (int(child_left[node]), int(child_right[node])):
-                compact_id[child] = len(order)
-                order.append(child)
+            order += [int(child_left[node]), int(child_right[node])]
+    order.sort()
+    compact_id = {node: cid for cid, node in enumerate(order)}
 
     k = len(order)
     out = {

@@ -6,11 +6,20 @@ lossguide updater (LightGBM-style growth). Static-shape XLA formulation:
 
 * node slots are allocated sequentially (root=0; split t creates 2t+1, 2t+2),
   explicit child indices — the shared tree layout of ops/tree_build;
-* ``max_leaves - 1`` split steps are unrolled; each step picks the global
-  best-gain leaf (argmax over the candidate store), routes its rows, and
-  histograms only the two fresh children (W=2 level histogram);
+* the ``max_leaves - 1`` split steps are ONE rolled ``lax.fori_loop`` over
+  the build's state (tree arrays, candidate store, node sums and depths, each
+  row's node, the per-node histogram cache, the alive constraint sets): the
+  program holds one kernel call site, one split scan and one routing step
+  whatever ``max_leaves`` (95,708 equations unrolled at 255 leaves, where a
+  depth-8 ``build_tree`` has 1,892). Each step picks the global best-gain
+  leaf (argmax over the candidate store), routes its rows, and histograms
+  the fresh children (the left one alone with sibling subtraction);
 * every leaf keeps a precomputed best-split candidate, so step selection is
-  O(nodes), not O(n).
+  O(nodes), not O(n);
+* every instruction lies under a stage of the round program
+  (``telemetry/device.py::STAGES``): ``hist``, ``split_scan``, ``route_rows``,
+  ``leaf_margin``, and ``step_pick`` for the pick, the tree and store
+  updates, the cache's slot writes and the loop itself.
 
 Cost note: each step rescans all n rows for the 2-child histogram, so a tree
 costs O(max_leaves * n * d) versus depthwise's O(max_depth * n * d); this is
@@ -27,6 +36,14 @@ from .histogram import (
     overlap_node_batches,
     padded_feature_width,
     subtraction_enabled,
+)
+from ..telemetry.device import (
+    STAGE_HIST,
+    STAGE_LEAF_MARGIN,
+    STAGE_ROUTE_ROWS,
+    STAGE_SPLIT_SCAN,
+    STAGE_STEP_PICK,
+    stage,
 )
 from .split import (
     broadcast_node_totals,
@@ -237,77 +254,70 @@ def build_tree_lossguide(
         # step's argmax) must be identical on all shards, with GLOBAL ids
         return _combine(s)
 
-    def _score_children(parent_rows_mask_nodes, id_a, id_b, depth_ab, mask=None, GH=None):
-        """Histogram the two fresh children and return their candidates.
-
-        parent_rows_mask_nodes: node_local [n] mapping rows to {0,1,-1}.
-        GH: optional precomputed ([2, d, B], [2, d, B]) histograms (the
-        sibling-subtraction path — already reduced, one batch).
-        """
-        mask = mask if mask is not None else feature_mask
-        if GH is not None:
-            batches = [(slice(0, 2),) + GH]
-        else:
-            G_loc, H_loc = level_histogram(
-                bins, grad, hess, parent_rows_mask_nodes, 2, num_bins,
-                knobs=knobs,
+    def _child_splits(batches, mask, depth_ab):
+        """Candidates of the two fresh children from their (reduced)
+        histograms, a node batch at a time; the depth cap folded into the
+        gains (children at ``depth_cap`` can never split)."""
+        with stage(STAGE_SPLIT_SCAN):
+            splits = concat_node_splits(
+                [
+                    _scan_nodes(
+                        Gb, Hb,
+                        mask[nsl] if mask is not None and mask.ndim == 2 else mask,
+                    )
+                    for nsl, Gb, Hb in batches
+                ]
             )
-            batches = [
-                (nsl,)
-                + apply_hist_collective(
-                    G_loc[nsl], H_loc[nsl], axis_name, hist_comm,
-                    n_data_shards,
-                )
-                for nsl in overlap_node_batches(2, overlap)
-            ]
-        splits = concat_node_splits(
-            [
-                _scan_nodes(
-                    Gb, Hb,
-                    mask[nsl] if mask is not None and mask.ndim == 2 else mask,
-                )
-                for nsl, Gb, Hb in batches
-            ]
-        )
-        # depth cap: children at depth_cap can never split
-        can_deepen = depth_ab < depth_cap
-        gains = jnp.where(can_deepen, splits["gain"], -jnp.inf)
+            gains = jnp.where(depth_ab < depth_cap, splits["gain"], -jnp.inf)
         return splits, gains
 
     # full-width gate under both lowerings (bit-identity: same build path)
     subtract = _subtraction_enabled(max_leaves, d, num_bins)
+    hist_cache = None
     if subtract:
         # per-node histogram cache (filled as leaves are created); stores
         # only this shard's feature slice under reduce_scatter
-        hist_G = jnp.zeros((max_nodes, d_scan, num_bins), jnp.float32)
-        hist_H = jnp.zeros((max_nodes, d_scan, num_bins), jnp.float32)
+        hist_cache = (
+            jnp.zeros((max_nodes, d_scan, num_bins), jnp.float32),
+            jnp.zeros((max_nodes, d_scan, num_bins), jnp.float32),
+        )
 
     # root candidate
-    root_local = jnp.zeros(n, jnp.int32)
-    G, H = level_histogram(
-        bins, grad, hess, root_local, 1, num_bins,
-        axis_name=axis_name, comm=hist_comm, axis_size=n_data_shards,
-        knobs=knobs,
-    )
-    if subtract:
-        hist_G = hist_G.at[0].set(G[0])
-        hist_H = hist_H.at[0].set(H[0])
-    root_mask = _with_level_mask(feature_mask, jnp.int32(0))
-    if alive_sets is not None:
-        allowed0 = _allowed_cols(alive_sets[0])
-        root_mask = allowed0 if root_mask is None else root_mask * allowed0
-    root_splits = _scan_nodes(G, H, root_mask)
-    cand["gain"] = cand["gain"].at[0].set(root_splits["gain"][0])
-    cand["feature"] = cand["feature"].at[0].set(root_splits["feature"][0])
-    cand["bin"] = cand["bin"].at[0].set(root_splits["bin"][0])
-    cand["default_left"] = cand["default_left"].at[0].set(root_splits["default_left"][0])
-    node_g = node_g.at[0].set(root_splits["g_total"][0])
-    node_h = node_h.at[0].set(root_splits["h_total"][0])
+    with stage(STAGE_HIST):
+        G, H = level_histogram(
+            bins, grad, hess, jnp.zeros(n, jnp.int32), 1, num_bins,
+            axis_name=axis_name, comm=hist_comm, axis_size=n_data_shards,
+            knobs=knobs,
+        )
+        if subtract:
+            hist_cache = (hist_cache[0].at[0].set(G[0]), hist_cache[1].at[0].set(H[0]))
+    with stage(STAGE_SPLIT_SCAN):
+        root_mask = _with_level_mask(feature_mask, jnp.int32(0))
+        if alive_sets is not None:
+            allowed0 = _allowed_cols(alive_sets[0])
+            root_mask = allowed0 if root_mask is None else root_mask * allowed0
+        root_splits = _scan_nodes(G, H, root_mask)
+    with stage(STAGE_STEP_PICK):
+        for field in cand:
+            cand[field] = cand[field].at[0].set(root_splits[field][0])
+        node_g = node_g.at[0].set(root_splits["g_total"][0])
+        node_h = node_h.at[0].set(root_splits["h_total"][0])
 
-    for t in range(max_leaves - 1):
+    def _pair(table, values, id_a):
+        """``values`` [2, ...] into the fresh children's slots ``id_a`` and
+        ``id_a + 1`` of a per-node ``table``."""
+        return jax.lax.dynamic_update_slice(
+            table, values.astype(table.dtype), (id_a,) + (0,) * (table.ndim - 1)
+        )
+
+    def split_step(t, state):
+        """One split step: pick the best leaf, route its rows, score its two
+        fresh children (slots ``2t + 1``, ``2t + 2``). What lies under no
+        stage of its own here is ``step_pick``'s (the scope round the loop)."""
+        tree, cand, node_g, node_h, node_depth, node_of_row, hist_cache, alive_sets = state
+        tree, cand = dict(tree), dict(cand)
         id_a, id_b = 2 * t + 1, 2 * t + 2
-        leaf_mask = tree["is_leaf"]
-        gains = jnp.where(leaf_mask, cand["gain"], -jnp.inf)
+        gains = jnp.where(tree["is_leaf"], cand["gain"], -jnp.inf)
         l = jnp.argmax(gains).astype(jnp.int32)
         can = gains[l] > MIN_SPLIT_LOSS
 
@@ -315,125 +325,134 @@ def build_tree_lossguide(
         b_l = cand["bin"][l]
         dl_l = cand["default_left"][l]
 
-        # mark split
-        tree["feature"] = tree["feature"].at[l].set(jnp.where(can, f_l, tree["feature"][l]))
-        tree["bin"] = tree["bin"].at[l].set(jnp.where(can, b_l, tree["bin"][l]))
-        tree["default_left"] = tree["default_left"].at[l].set(
-            jnp.where(can, dl_l, tree["default_left"][l])
-        )
-        tree["is_leaf"] = tree["is_leaf"].at[l].set(
-            jnp.where(can, False, tree["is_leaf"][l])
-        )
-        # stored is the split's own loss change (ops/tree_build.py::build_tree)
+        # mark split; stored is the split's own loss change
+        # (ops/tree_build.py::build_tree)
         won = gains[l] + gamma if gamma else gains[l]
-        tree["gain"] = tree["gain"].at[l].set(jnp.where(can, won, tree["gain"][l]))
-        tree["left"] = tree["left"].at[l].set(jnp.where(can, id_a, tree["left"][l]))
-        tree["right"] = tree["right"].at[l].set(jnp.where(can, id_b, tree["right"][l]))
+        for field, value in (
+            ("feature", f_l), ("bin", b_l), ("default_left", dl_l), ("is_leaf", False),
+            ("gain", won), ("left", id_a), ("right", id_b),
+        ):
+            tree[field] = tree[field].at[l].set(jnp.where(can, value, tree[field][l]))
         # exhausted leaves can't be re-picked
         cand["gain"] = cand["gain"].at[l].set(-jnp.inf)
 
-        # route rows of l
-        in_l = node_of_row == l
-        # one scalar feature for every row: a dynamic column slice, not a
-        # per-row gather
-        if feature_axis_name is None:
-            row_bin = jax.lax.dynamic_slice(bins, (0, f_l), (n, 1))[:, 0]
-            is_missing = row_bin == (num_bins - 1)
-            go_right = jnp.where(is_missing, ~dl_l, row_bin > b_l)
-        else:
-            # only the shard owning the winning (global) feature can decide
-            # the rows; decisions psum-broadcast along the feature axis —
-            # same convention as tree_build's level routing
-            owner = (f_l // d) == feat_shard
-            f_local = jnp.clip(f_l - feat_shard * d, 0, d - 1)
-            row_bin = jax.lax.dynamic_slice(bins, (0, f_local), (n, 1))[:, 0]
-            is_missing = row_bin == (num_bins - 1)
-            decision = jnp.where(is_missing, ~dl_l, row_bin > b_l)
-            go_right = (
-                jax.lax.psum(
-                    jnp.where(owner, decision, False).astype(jnp.int32),
-                    feature_axis_name,
+        with stage(STAGE_ROUTE_ROWS):
+            # route rows of l: one scalar feature for every row, so a dynamic
+            # column slice, not a per-row gather
+            in_l = node_of_row == l
+            if feature_axis_name is None:
+                row_bin = jax.lax.dynamic_slice(bins, (0, f_l), (n, 1))[:, 0]
+                is_missing = row_bin == (num_bins - 1)
+                go_right = jnp.where(is_missing, ~dl_l, row_bin > b_l)
+            else:
+                # only the shard owning the winning (global) feature can decide
+                # the rows; decisions psum-broadcast along the feature axis —
+                # same convention as tree_build's level routing
+                owner = (f_l // d) == feat_shard
+                f_local = jnp.clip(f_l - feat_shard * d, 0, d - 1)
+                row_bin = jax.lax.dynamic_slice(bins, (0, f_local), (n, 1))[:, 0]
+                is_missing = row_bin == (num_bins - 1)
+                decision = jnp.where(is_missing, ~dl_l, row_bin > b_l)
+                go_right = (
+                    jax.lax.psum(
+                        jnp.where(owner, decision, False).astype(jnp.int32),
+                        feature_axis_name,
+                    )
+                    > 0
                 )
-                > 0
-            )
-        new_node = jnp.where(go_right, id_b, id_a)
-        node_of_row = jnp.where(in_l & can, new_node, node_of_row)
+            new_node = jnp.where(go_right, id_b, id_a)
+            node_of_row = jnp.where(in_l & can, new_node, node_of_row)
 
         # children depth + candidates
         depth_ab = node_depth[l] + 1
-        node_depth = node_depth.at[id_a].set(depth_ab)
-        node_depth = node_depth.at[id_b].set(depth_ab)
-        child_local = jnp.where(
-            can & (node_of_row == id_a),
-            0,
-            jnp.where(can & (node_of_row == id_b), 1, -1),
-        )
-        node_mask = feature_mask
-        if colsample_bynode < 1.0 and rng is not None:
-            # drawn over GLOBAL columns (identical stream to single-device),
-            # each shard slicing its own segment — see the bylevel comment
-            draw = jax.random.uniform(jax.random.fold_in(rng, 7919 + t), (2, d_draw))
-            sampled = _local_cols(
-                _pad_cols((draw < colsample_bynode).astype(jnp.float32))
-            )
-            node_mask = sampled if node_mask is None else sampled * node_mask[None, :]
-        # the children being scored sit at depth_ab: their candidate splits
-        # (executed at that depth) draw that depth's bylevel subset
-        node_mask = _with_level_mask(node_mask, depth_ab)
-        if alive_sets is not None:
-            # both fresh children inherit alive-sets = parent's ∩ {sets
-            # containing the split feature}; inert when the step can't split
-            # (their candidate gains are forced to -inf below)
-            child_alive = alive_sets[l] & interaction_sets[:, f_l]
-            alive_sets = alive_sets.at[id_a].set(child_alive).at[id_b].set(child_alive)
-            allowed = _allowed_cols(child_alive)
-            if node_mask is None:
-                node_mask = allowed
-            elif node_mask.ndim == 1:
-                node_mask = node_mask * allowed
+        node_depth = _pair(node_depth, jnp.stack([depth_ab, depth_ab]), id_a)
+        with stage(STAGE_SPLIT_SCAN):
+            node_mask = feature_mask
+            if colsample_bynode < 1.0 and rng is not None:
+                # drawn over GLOBAL columns (identical stream to single-device),
+                # each shard slicing its own segment — see the bylevel comment
+                draw = jax.random.uniform(jax.random.fold_in(rng, 7919 + t), (2, d_draw))
+                sampled = _local_cols(
+                    _pad_cols((draw < colsample_bynode).astype(jnp.float32))
+                )
+                node_mask = sampled if node_mask is None else sampled * node_mask[None, :]
+            # the children being scored sit at depth_ab: their candidate splits
+            # (executed at that depth) draw that depth's bylevel subset
+            node_mask = _with_level_mask(node_mask, depth_ab)
+            if alive_sets is not None:
+                # both fresh children inherit alive-sets = parent's ∩ {sets
+                # containing the split feature}; inert when the step can't split
+                # (their candidate gains are forced to -inf below)
+                child_alive = alive_sets[l] & interaction_sets[:, f_l]
+                alive_sets = _pair(alive_sets, jnp.stack([child_alive, child_alive]), id_a)
+                allowed = _allowed_cols(child_alive)
+                if node_mask is None:
+                    node_mask = allowed
+                elif node_mask.ndim == 1:
+                    node_mask = node_mask * allowed
+                else:
+                    node_mask = node_mask * allowed[None, :]
+        with stage(STAGE_HIST):
+            if subtract:
+                # histogram only the LEFT child; right = cached parent - left.
+                # When the step can't split, no rows were routed: left is all
+                # zeros and the right side is forced to zero too.
+                left_local = jnp.where(can & (node_of_row == id_a), 0, -1)
+                Ga, Ha = level_histogram(
+                    bins, grad, hess, left_local, 1, num_bins,
+                    axis_name=axis_name, comm=hist_comm, axis_size=n_data_shards,
+                    knobs=knobs,
+                )
+                Gb = jnp.where(can, hist_cache[0][l] - Ga[0], 0.0)
+                Hb = jnp.where(can, hist_cache[1][l] - Ha[0], 0.0)
+                # already reduced, one batch
+                batches = [(slice(0, 2), jnp.stack([Ga[0], Gb]), jnp.stack([Ha[0], Hb]))]
             else:
-                node_mask = node_mask * allowed[None, :]
-        GH = None
+                child_local = jnp.where(
+                    can & (node_of_row == id_a),
+                    0,
+                    jnp.where(can & (node_of_row == id_b), 1, -1),
+                )
+                G_loc, H_loc = level_histogram(
+                    bins, grad, hess, child_local, 2, num_bins, knobs=knobs,
+                )
+                batches = [
+                    (nsl,)
+                    + apply_hist_collective(
+                        G_loc[nsl], H_loc[nsl], axis_name, hist_comm, n_data_shards,
+                    )
+                    for nsl in overlap_node_batches(2, overlap)
+                ]
         if subtract:
-            # histogram only the LEFT child; right = cached parent - left.
-            # When the step can't split, no rows were routed: left is all
-            # zeros and the right side is forced to zero too.
-            left_local = jnp.where(can & (node_of_row == id_a), 0, -1)
-            Ga, Ha = level_histogram(
-                bins, grad, hess, left_local, 1, num_bins,
-                axis_name=axis_name, comm=hist_comm, axis_size=n_data_shards,
-                knobs=knobs,
+            hist_cache = (
+                _pair(hist_cache[0], batches[0][1], id_a),
+                _pair(hist_cache[1], batches[0][2], id_a),
             )
-            Gb = jnp.where(can, hist_G[l] - Ga[0], 0.0)
-            Hb = jnp.where(can, hist_H[l] - Ha[0], 0.0)
-            GH = (jnp.stack([Ga[0], Gb]), jnp.stack([Ha[0], Hb]))
-            hist_G = hist_G.at[id_a].set(Ga[0]).at[id_b].set(Gb)
-            hist_H = hist_H.at[id_a].set(Ha[0]).at[id_b].set(Hb)
-        splits, child_gains = _score_children(
-            child_local, id_a, id_b, jnp.stack([depth_ab, depth_ab]), node_mask, GH=GH
+        splits, child_gains = _child_splits(
+            batches, node_mask, jnp.stack([depth_ab, depth_ab])
         )
-        valid = can
-        cand["gain"] = cand["gain"].at[id_a].set(jnp.where(valid, child_gains[0], -jnp.inf))
-        cand["gain"] = cand["gain"].at[id_b].set(jnp.where(valid, child_gains[1], -jnp.inf))
-        cand["feature"] = cand["feature"].at[id_a].set(splits["feature"][0])
-        cand["feature"] = cand["feature"].at[id_b].set(splits["feature"][1])
-        cand["bin"] = cand["bin"].at[id_a].set(splits["bin"][0])
-        cand["bin"] = cand["bin"].at[id_b].set(splits["bin"][1])
-        cand["default_left"] = cand["default_left"].at[id_a].set(splits["default_left"][0])
-        cand["default_left"] = cand["default_left"].at[id_b].set(splits["default_left"][1])
-        node_g = node_g.at[id_a].set(splits["g_total"][0])
-        node_g = node_g.at[id_b].set(splits["g_total"][1])
-        node_h = node_h.at[id_a].set(splits["h_total"][0])
-        node_h = node_h.at[id_b].set(splits["h_total"][1])
         # children of a non-split never get rows, so their -inf gains + zero
         # totals are inert
+        cand["gain"] = _pair(cand["gain"], jnp.where(can, child_gains, -jnp.inf), id_a)
+        for field in ("feature", "bin", "default_left"):
+            cand[field] = _pair(cand[field], splits[field], id_a)
+        node_g = _pair(node_g, splits["g_total"], id_a)
+        node_h = _pair(node_h, splits["h_total"], id_a)
+        return tree, cand, node_g, node_h, node_depth, node_of_row, hist_cache, alive_sets
+
+    # ONE rolled loop over the split steps: the program holds one kernel call
+    # site, one split scan and one routing step whatever ``max_leaves``
+    state = (tree, cand, node_g, node_h, node_depth, node_of_row, hist_cache, alive_sets)
+    with stage(STAGE_STEP_PICK):
+        state = jax.lax.fori_loop(0, max_leaves - 1, split_step, state)
+    tree, _cand, node_g, node_h, _depth, node_of_row, _cache, _alive = state
 
     # finalize leaf values for every (reachable) leaf slot
-    weight = leaf_weight(node_g, node_h, reg_lambda=reg_lambda, alpha=alpha,
-                         max_delta_step=max_delta_step)
-    tree["base_weight"] = weight
-    tree["sum_hess"] = node_h
-    tree["leaf_value"] = jnp.where(tree["is_leaf"], eta * weight, 0.0)
-
-    row_out = tree["leaf_value"][node_of_row]
+    with stage(STAGE_LEAF_MARGIN):
+        weight = leaf_weight(node_g, node_h, reg_lambda=reg_lambda, alpha=alpha,
+                             max_delta_step=max_delta_step)
+        tree["base_weight"] = weight
+        tree["sum_hess"] = node_h
+        tree["leaf_value"] = jnp.where(tree["is_leaf"], eta * weight, 0.0)
+        row_out = tree["leaf_value"][node_of_row]
     return tree, row_out

@@ -74,6 +74,11 @@ STAGE_HIST = "hist"
 STAGE_HIST_ALLREDUCE = "hist_allreduce"
 STAGE_NODE_TOTALS = "node_totals"
 STAGE_SPLIT_SCAN = "split_scan"
+#: a loss-guided build's split-step loop (ops/lossguide.py): the argmax over
+#: the candidate store, the tree and store updates, the histogram cache's slot
+#: writes and the loop itself; the step's kernel, scan and routing have their
+#: own stages inside it
+STAGE_STEP_PICK = "step_pick"
 STAGE_ROUTE_ROWS = "route_rows"
 STAGE_LEAF_MARGIN = "leaf_margin"
 STAGE_EVAL_APPLY = "eval_apply"
@@ -88,6 +93,7 @@ STAGES = (
     STAGE_HIST_ALLREDUCE,
     STAGE_NODE_TOTALS,
     STAGE_SPLIT_SCAN,
+    STAGE_STEP_PICK,
     STAGE_ROUTE_ROWS,
     STAGE_LEAF_MARGIN,
     STAGE_EVAL_APPLY,

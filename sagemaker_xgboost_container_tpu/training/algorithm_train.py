@@ -527,6 +527,7 @@ def train_job(
         "train", mesh=mesh, knobs=hist_knobs, route_width=train_dmatrix.num_col,
         grow_policy=train_cfg.get("grow_policy", "depthwise"),
         max_depth=int(train_cfg.get("max_depth") or 6),  # TrainConfig's default
+        max_leaves=int(train_cfg.get("max_leaves") or 0),
         trees_per_round=(
             int(train_cfg.get("num_class") or 1)
             if str(train_cfg.get("objective", "")).startswith("multi:")

@@ -67,9 +67,20 @@ def _libtpu_version():
         return None
 
 
+def split_steps_per_round(grow_policy, max_leaves, trees_per_round):
+    """Split steps one boosting round runs: a loss-guided build's
+    ``max_leaves - 1`` a tree; 0 for a depth-wise job, None where no trees
+    are built (a server)."""
+    if grow_policy is None:
+        return None
+    if grow_policy != "lossguide":
+        return 0
+    return max(int(max_leaves or 0) - 1, 0) * int(trees_per_round or 1)
+
+
 def start_device_runtime(
     role, mesh=None, knobs=None, route_width=None, grow_policy=None, max_depth=None,
-    trees_per_round=None, **facts
+    trees_per_round=None, max_leaves=None, **facts
 ):
     """Arm the compile cache and log the ``device runtime:`` line.
 
@@ -82,10 +93,15 @@ def start_device_runtime(
     names how evaluation rows walk a new tree (``eval_traversal``; a server
     builds no trees). ``max_depth``: the trainer's, for which the line names how
     the build's rows read their level's node tables (``build_table_impl``: the
-    lowering at the widest level, ``2**max_depth`` entries; a loss-guided job
-    has lookups of its own). ``trees_per_round``: the trainer's classes x
+    lowering at the widest level, ``2**max_depth`` entries; a loss-guided
+    build reads no node table: a split step routes one node's rows by a
+    dynamic column slice, and its ``max_leaves - 1`` steps are one rolled
+    loop, ``ops/lossguide.py``). ``trees_per_round``: the trainer's classes x
     ``num_parallel_tree``, the trees one boosting round grows (the gauge
-    ``round_class_trees``; a server grows none). ``facts``: what else only the caller knows about
+    ``round_class_trees``; a server grows none). ``max_leaves``: the
+    trainer's, from which the line says the split steps a round
+    (``split_steps_per_round``, the gauge ``round_split_steps``: 0 for a
+    depth-wise job, null for a server) beside ``grow_policy``. ``facts``: what else only the caller knows about
     the path taken (the trainer's ingest mode). Returns the logged fields.
     """
     import jax
@@ -134,6 +150,10 @@ def start_device_runtime(
             else None
         ),
         trees_per_round=trees_per_round,
+        grow_policy=grow_policy,
+        split_steps_per_round=split_steps_per_round(
+            grow_policy, max_leaves, trees_per_round
+        ),
         sketch_impl=_sketch_impl(),
         pallas_interpret=pallas_interpret(),
         compile_cache_dir=cache_dir,

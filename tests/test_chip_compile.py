@@ -28,14 +28,19 @@ ROWS, FEATURES, NUM_BINS, DEPTH = 2_000_000, 39, 257, 8
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def described_chips():
     from jax.experimental import topologies
 
     try:
         topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler in this installation
         pytest.skip("no v5e:2x2 topology can be described here: {}".format(e))
-    return SingleDeviceSharding(topo.devices[0])
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def one_chip(described_chips):
+    return SingleDeviceSharding(described_chips[0])
 
 
 @pytest.fixture()
@@ -232,3 +237,78 @@ def test_a_ranking_rounds_loop_gathers_the_margins_and_nothing_else(
     # and none was moved in front of the loop
     outside = _opcodes_under(comps, entry, seen={loops[0]})
     assert outside.count("gather") == 0
+
+
+# --------------------------------------------- a loss-guided round, rolled
+LEAFWISE_ROWS, LEAFWISE_VALIDATION, LEAFWISE_FEATURES, LEAVES = 10_500_000, 500_000, 28, 255
+
+
+@pytest.mark.parametrize("chips", [1, 4], ids=["one_chip", "data_mesh_of_4"])
+def test_rolled_loss_guided_round_compiles_for_the_chip(
+    described_chips, one_chip, no_compile_cache, monkeypatch, chips
+):
+    """`higgs-leafwise-l255`'s round (10.5M x 28 in u8, 255 leaves, the pointer
+    walk over 500,000 validation rows) through the chip's own compilers, the
+    kernel's too: two kernel call sites whatever `max_leaves` (the root's and
+    the step body's) where the unrolled loop held 255, the step loop one
+    `while` with the kernel inside it, and on a `data` mesh the histogram's
+    all-reduce inside that body too."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from sagemaker_xgboost_container_tpu.ops import histogram as hist_mod
+    from sagemaker_xgboost_container_tpu.ops.lossguide import build_tree_lossguide
+    from sagemaker_xgboost_container_tpu.ops.tree_build import predict_binned, tree_from_packed
+
+    monkeypatch.setattr(hist_mod, "pallas_interpret", lambda: False)
+    knobs = resolve_hist_knobs()._replace(backend="tpu")
+    axis = "data" if chips > 1 else None
+
+    def one_round(bins, grad, hess, num_cuts, validation_bins):
+        tree, row_out = build_tree_lossguide(
+            bins, grad, hess, num_cuts, max_leaves=LEAVES, num_bins=NUM_BINS,
+            min_child_weight=100.0, eta=0.1, knobs=knobs, axis_name=axis,
+            n_data_shards=chips,
+        )
+        packed = pack_tree(tree)
+        walked = predict_binned(
+            tree_from_packed(packed), validation_bins, LEAVES - 1, NUM_BINS, route_impl="dense"
+        )
+        return packed, row_out, walked
+
+    if chips == 1:
+        fn, rows, whole = one_round, one_chip, one_chip
+    else:
+        mesh = Mesh(np.array(described_chips[:chips]), ("data",))
+        fn = jax.shard_map(
+            one_round, mesh=mesh,
+            in_specs=(P("data", None), P("data"), P("data"), P(), P("data", None)),
+            out_specs=(P(), P("data"), P("data")), check_vma=False,
+        )
+        rows, whole = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+
+    def shape(dims, dtype, sharding):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    n, v = LEAFWISE_ROWS, LEAFWISE_VALIDATION
+    hlo = (
+        jax.jit(fn)
+        .lower(
+            shape((n, LEAFWISE_FEATURES), jnp.uint8, rows), shape((n,), jnp.float32, rows),
+            shape((n,), jnp.float32, rows), shape((LEAFWISE_FEATURES,), jnp.int32, whole),
+            shape((v, LEAFWISE_FEATURES), jnp.uint8, rows),
+        )
+        .compile()
+        .as_text()
+    )
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    comps = _computations(hlo)
+    step_loops = [
+        body for name, text in comps.items()
+        for body in re.findall(r" while\([^\n]*?body=%?([\w.\-]+)[^\n]*step_pick", text)
+    ]
+    assert len(step_loops) == 1, step_loops
+    in_loop = _opcodes_under(comps, step_loops[0])
+    assert in_loop.count("custom-call") >= 1
+    if chips > 1:
+        assert "all-reduce" in in_loop or "all-reduce-start" in in_loop

@@ -13,6 +13,7 @@ endpoint (bounded capture when armed, 404 when not).
 
 import json
 import os
+import re
 import threading
 import urllib.error
 import urllib.request
@@ -203,6 +204,60 @@ def test_round_program_stages_is_lazy_and_cached(device_env):
     assert calls == [1]  # compiled once, when somebody asked
     device.register_round_program(lambda: calls.append(2) or Compiled())
     assert device._stage_table is None  # a new session drops the old table
+
+
+def _loss_guided_round_program(mesh=None):
+    """A traced loss-guided round on the CPU; returns (table, summary, HLO)."""
+    from sagemaker_xgboost_container_tpu.models import train
+
+    rng = np.random.RandomState(7)
+    X = rng.rand(600, 5).astype(np.float32)
+    y = (X[:, 0] + X[:, 1] * X[:, 2] > 0.9).astype(np.float32)
+    dtrain = DataMatrix(X, labels=y)
+    device._reset_for_tests()
+    train(
+        {"objective": "binary:logistic", "grow_policy": "lossguide", "max_depth": 0,
+         "max_leaves": 9, "eval_metric": "logloss", "_rounds_per_dispatch": 2},
+        dtrain, num_boost_round=2, verbose_eval=False, mesh=mesh,
+        evals=[(dtrain, "train"), (DataMatrix(X[:200], labels=y[:200]), "validation")],
+    )
+    compiled = device._round_program()
+    table, summary = device.note_stage_table(compiled)
+    return table, summary, compiled.as_text()
+
+
+@pytest.mark.parametrize("shards", [1, 4], ids=["one_device", "data_mesh_of_4"])
+def test_loss_guided_round_leaves_no_instruction_of_its_body_without_a_stage(shards):
+    """PR 42: ``ops/lossguide.py`` had no stage scope at all, so a loss-guided
+    round's device time would have read as unnamed. Now every instruction the
+    build traces lies under a stage, ``step_pick`` where no other."""
+    import jax
+    from jax.sharding import Mesh
+
+    mesh = None
+    if shards > 1:
+        mesh = Mesh(np.array(jax.devices()[:shards]), axis_names=("data",))
+    table, summary, hlo = _loss_guided_round_program(mesh)
+    want = {"grad", "hist", "split_scan", "step_pick", "route_rows", "leaf_margin",
+            "eval_apply", "eval_metric", "pack"}
+    if shards > 1:
+        want |= {"hist_allreduce"}
+    assert set(table.values()) == want  # and no node_totals: no such stage here
+    assert summary["step_pick"]["instructions"] > 0
+    # every instruction that carries the build's own frames is named
+    unnamed = []
+    for line in hlo.splitlines():
+        m = re.search(r'op_name="([^"]*)"', line)
+        if m and ("split_step" in m.group(1) or "/step_pick/" in m.group(1)):
+            if device.stage_of_op_name(m.group(1)) is None:
+                unnamed.append(m.group(1))
+    assert not unnamed, unnamed[:5]
+    # the step loop itself is step_pick's, what is inside it the inner stage's
+    assert device.stage_of_op_name("jit(multi_round)/while/body/step_pick/while") == "step_pick"
+    assert (
+        device.stage_of_op_name("jit(f)/while/body/step_pick/while/body/closed_call/hist/pad")
+        == "hist"
+    )
 
 
 # ------------------------------------------------------------ HBM watermarks

@@ -1,0 +1,352 @@
+"""The loss-guided build's split steps as ONE rolled loop (PR 42).
+
+* The forest is what the unrolled loop built, bit for bit: the digests below
+  were read off the commit before the change (``tests/lossguide_cases.py``
+  run against it) at every shape the issue names.
+* The program does not grow with ``max_leaves``.
+* The program's tree is the plain float64 grower's
+  (``benchmark/reference/leafwise_reference.py::grow``).
+* Compact node ids follow the padded slots: breadth-first for a heap, the
+  order of expansion for a loss-guided tree.
+* The gauges that say what a round builds, and what it built.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from sagemaker_xgboost_container_tpu.data.matrix import DataMatrix
+from sagemaker_xgboost_container_tpu.models import train
+from sagemaker_xgboost_container_tpu.models.forest import compact_padded_tree
+from sagemaker_xgboost_container_tpu.ops import lossguide as lossguide_mod
+from sagemaker_xgboost_container_tpu.ops.lossguide import build_tree_lossguide
+from sagemaker_xgboost_container_tpu.ops.tree_build import build_tree
+from sagemaker_xgboost_container_tpu.telemetry import REGISTRY
+
+from tests import lossguide_cases
+
+# sha256[:16] of the padded tree arrays and row_out, off the parent commit
+# (4707354: the Python loop ``for t in range(max_leaves - 1)``)
+PARENT_DIGESTS = {
+    "l2.sub.plain": "c5991d5ab201c881", "l2.sub.bynode": "c5991d5ab201c881",
+    "l2.sub.sets": "c5991d5ab201c881", "l2.sub.mcw": "c5991d5ab201c881",
+    "l2.sub.depth3": "c5991d5ab201c881", "l2.nosub.plain": "c5991d5ab201c881",
+    "l2.nosub.bynode": "c5991d5ab201c881", "l2.nosub.sets": "c5991d5ab201c881",
+    "l2.nosub.mcw": "c5991d5ab201c881", "l2.nosub.depth3": "c5991d5ab201c881",
+    "l8.sub.plain": "64cc327ad7361a2e", "l8.sub.bynode": "5f942f4341c77d46",
+    "l8.sub.bylevel": "36134b3ebd08e85c", "l8.sub.sets": "08eb4fc3792e9940",
+    "l8.sub.mcw": "8e26a51f51c10f44", "l8.sub.depth3": "fd4134e8391c2d09",
+    "l8.sub.gamma": "d5b7193fa8a87772", "l8.nosub.plain": "bf0b8af0387a01d5",
+    "l8.nosub.bynode": "1948cfacc988f809", "l8.nosub.bylevel": "45f225e8a7292c02",
+    "l8.nosub.sets": "1fbce81dc1d7c8dc", "l8.nosub.mcw": "ece1ebff12879155",
+    "l8.nosub.depth3": "fa7eb0ac0dd15ee1", "l8.nosub.gamma": "68b74f9590c07b01",
+    "l31.sub.plain": "a2eef49292589551", "l31.sub.bynode": "5546077c7bc8d54a",
+    "l31.sub.sets": "351704746211e94e", "l31.sub.mcw": "c3974e24c311ed78",
+    "l31.sub.depth3": "f38a3c188dd7d4e2", "l31.nosub.plain": "adf79e63b8be076c",
+    "l31.nosub.bynode": "e4fdab139d0597b5", "l31.nosub.sets": "230fa04817c76741",
+    "l31.nosub.mcw": "5dc17ff1d7a85333", "l31.nosub.depth3": "ac3d826bbf5117b1",
+    "l8.sub.kernel": "ffb099084397fd89",
+    "data4.psum.sub.plain": "78b06c578600a5bb", "data4.psum.sub.bynode": "b695d426f07d3c00",
+    "data4.psum.sub.sets": "c882670ecbdd024a", "data4.psum.nosub.plain": "33dcf62293882a70",
+    "data4.psum.nosub.bynode": "81a3955bb32c2d7f", "data4.psum.nosub.sets": "df6e5817dc8f8723",
+    "data4.reduce_scatter.sub.plain": "78b06c578600a5bb",
+    "data4.reduce_scatter.sub.bynode": "b695d426f07d3c00",
+    "data4.reduce_scatter.sub.sets": "c882670ecbdd024a",
+    "data4.reduce_scatter.nosub.plain": "33dcf62293882a70",
+    "data4.reduce_scatter.nosub.bynode": "81a3955bb32c2d7f",
+    "data4.reduce_scatter.nosub.sets": "df6e5817dc8f8723",
+    "data2xfeature2.psum.plain": "7e43e387a133fa78",
+    "data2xfeature2.psum.bynode": "4a2eef0993bcceef",
+    "data2xfeature2.psum.sets": "31eb61af8bcf24de",
+    "data2xfeature2.reduce_scatter.plain": "7e43e387a133fa78",
+    "data2xfeature2.reduce_scatter.bynode": "4a2eef0993bcceef",
+    "data2xfeature2.reduce_scatter.sets": "31eb61af8bcf24de",
+}
+CASES = lossguide_cases.cases()
+
+
+def test_every_case_has_its_digest():
+    assert set(CASES) == set(PARENT_DIGESTS)
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_DIGESTS))
+def test_rolled_build_is_the_unrolled_builds_forest_bit_for_bit(name):
+    tree, row_out = lossguide_cases.run_case(*CASES[name])
+    assert lossguide_cases.digest(tree, row_out) == PARENT_DIGESTS[name]
+
+
+# ------------------------------------------------------- the program's size
+def _build_jaxpr(max_leaves, counter=None, subtract=True):
+    from sagemaker_xgboost_container_tpu.ops import histogram as hist_mod
+
+    rows = jax.ShapeDtypeStruct((64,), jnp.float32)
+    cap = hist_mod.SUBTRACT_CACHE_MAX_BYTES
+    hist_mod.SUBTRACT_CACHE_MAX_BYTES = cap if subtract else 0
+    real = lossguide_mod.level_histogram
+
+    def counted(*args, **kwargs):
+        counter.append(args[4])  # the nodes built in the call
+        return real(*args, **kwargs)
+
+    if counter is not None:
+        lossguide_mod.level_histogram = counted
+    try:
+        return jax.make_jaxpr(
+            lambda b, g, h, c: build_tree_lossguide(
+                b, g, h, c, max_leaves=max_leaves, num_bins=9, colsample_bynode=0.5,
+                rng=jax.random.PRNGKey(0),
+            )
+        )(
+            jax.ShapeDtypeStruct((64, 4), jnp.uint8), rows, rows,
+            jax.ShapeDtypeStruct((4,), jnp.int32),
+        )
+    finally:
+        hist_mod.SUBTRACT_CACHE_MAX_BYTES = cap
+        lossguide_mod.level_histogram = real
+
+
+def _count_equations(jaxpr):
+    total = 0
+    for eqn in jaxpr.eqns:
+        total += 1
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                total += _count_equations(inner)
+    return total
+
+
+@pytest.mark.parametrize("subtract", [True, False], ids=["subtraction", "both_children"])
+def test_build_program_does_not_grow_with_max_leaves(subtract):
+    """The equation count, every nested body included, at 16 and 255 leaves;
+    and one ``level_histogram`` call site for the root and one for the step
+    body (W = 1 with sibling subtraction, W = 2 without)."""
+    calls_16, calls_255 = [], []
+    small = _build_jaxpr(16, calls_16, subtract)
+    large = _build_jaxpr(255, calls_255, subtract)
+    assert _count_equations(small.jaxpr) == _count_equations(large.jaxpr)
+    assert len(small.jaxpr.eqns) == len(large.jaxpr.eqns)
+    assert calls_16 == calls_255 == [1, 1 if subtract else 2]
+
+
+def test_benchmark_probe_reads_the_rolled_loop():
+    from benchmark.kinds import train_window_leafwise
+
+    assert train_window_leafwise.build_equations(16) == train_window_leafwise.build_equations(32)
+    train_window_leafwise.require_rolled_steps()  # does not leave
+
+
+# ------------------------------------------------ against the plain grower
+def _grower_inputs(seed, n=900, d=6, num_bins=13):
+    """Seeded rows whose candidate gains do not tie: continuous gradients,
+    hessians of their own, a few missing."""
+    rng = np.random.RandomState(seed)
+    bins = rng.randint(0, num_bins - 1, size=(n, d)).astype(np.uint8)
+    bins[rng.rand(n, d) < 0.04] = num_bins - 1
+    signal = (bins[:, 1] > 5) * 1.0 - 0.5 * (bins[:, 4] > 8) + 0.07 * bins[:, 2]
+    grad = (rng.randn(n) * 0.7 + signal - signal.mean()).astype(np.float32)
+    hess = (0.1 + rng.rand(n)).astype(np.float32)
+    return bins, grad, hess, np.full(d, num_bins - 1, np.int32), num_bins
+
+
+@pytest.mark.parametrize(
+    "extra",
+    [{}, {"min_child_weight": 25.0}, {"max_depth": 3}, {"max_depth": 4, "min_child_weight": 8.0}],
+    ids=["plain", "min_child_weight", "max_depth_3", "both"],
+)
+@pytest.mark.parametrize("seed", [3, 2**31 + 5])
+def test_program_tree_is_the_plain_growers(seed, extra):
+    from benchmark.reference import leafwise_reference
+
+    bins, grad, hess, num_cuts, num_bins = _grower_inputs(seed)
+    max_leaves = 12
+    tree, _row_out = jax.jit(
+        lambda b, g, h, c: build_tree_lossguide(
+            b, g, h, c, max_leaves=max_leaves, num_bins=num_bins, reg_lambda=1.0, eta=0.1,
+            **extra
+        )
+    )(bins, grad, hess, num_cuts)
+    tree = {k: np.asarray(v) for k, v in tree.items()}
+    want = leafwise_reference.grow(
+        bins, num_cuts, grad, hess, max_leaves, lam=1.0, eta=0.1,
+        min_child_weight=extra.get("min_child_weight", 1.0),
+        max_depth=extra.get("max_depth", 0),
+    )
+    n = len(want["left"])
+    internal = want["left"] >= 0
+    # the padded slots ARE the order of expansion: step t made 2t+1, 2t+2
+    assert np.array_equal(~tree["is_leaf"][:n], internal)
+    assert tree["is_leaf"][n:].all()
+    assert np.array_equal(tree["left"][:n][internal], want["left"][internal])
+    assert np.array_equal(tree["right"][:n][internal], want["right"][internal])
+    assert np.array_equal(tree["feature"][:n][internal], want["feature"][internal])
+    assert np.array_equal(tree["bin"][:n][internal], want["bin"][internal])
+    assert np.array_equal(tree["default_left"][:n][internal], want["default_left"][internal])
+    np.testing.assert_allclose(tree["leaf_value"][:n][~internal], want["value"][~internal],
+                               rtol=0, atol=1e-5)
+    np.testing.assert_allclose(tree["gain"][:n][internal], want["gain"][internal], rtol=1e-4)
+    np.testing.assert_allclose(tree["sum_hess"][:n], want["sum_hess"], rtol=1e-5)
+    if "max_depth" in extra:
+        from benchmark.reference import gbt_reference
+
+        assert gbt_reference.node_depths(want).max() <= extra["max_depth"]
+
+
+# ------------------------------------------------------ compact node ids
+def _bfs_compact_ids(padded):
+    """The numbering ``compact_padded_tree`` handed out before PR 42."""
+    order = [0]
+    for node in order:
+        if not padded["is_leaf"][node]:
+            order += [int(padded["left"][node]), int(padded["right"][node])]
+    return order
+
+
+# the five cells' shapes at small size: (features, bins, depth, missing share)
+CELL_SHAPES = {
+    "higgs-d8": (28, 257, 8, 0.0), "mslr-ndcg": (136, 257, 8, 0.0),
+    "criteo-tb-d8": (39, 257, 8, 0.14), "criteo-tb-d8-host4": (39, 257, 8, 0.14),
+    "mnist8m-mc10": (784, 257, 5, 0.0),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_depthwise_forest_is_numbered_as_before(cell):
+    """A heap's slots are breadth-first order, so increasing-slot numbering
+    is the breadth-first numbering, pruned subtrees and all: the same bytes."""
+    d, num_bins, depth, missing = CELL_SHAPES[cell]
+    rng = np.random.RandomState(len(cell))
+    n = 700
+    bins = rng.randint(0, num_bins - 1, size=(n, d)).astype(np.uint16)
+    bins[rng.rand(n, d) < missing] = num_bins - 1
+    grad = rng.randn(n).astype(np.float32)
+    hess = (0.05 + rng.rand(n)).astype(np.float32)
+    tree, _ = jax.jit(
+        lambda b, g, h, c: build_tree(
+            b, g, h, c, depth, num_bins, min_child_weight=4.0, gamma=0.05
+        )
+    )(bins, grad, hess, np.full(d, num_bins - 1, np.int32))
+    padded = {k: np.asarray(v) for k, v in tree.items()}
+    order = _bfs_compact_ids(padded)
+    assert len(order) < len(padded["is_leaf"])  # something was pruned
+    assert order == sorted(order)
+    cuts = [np.arange(num_bins - 1, dtype=np.float32) for _ in range(d)]
+    compact = compact_padded_tree(padded, cuts)
+    want_left = [order.index(int(padded["left"][s])) if not padded["is_leaf"][s] else -1
+                 for s in order]
+    assert compact.left.tolist() == want_left
+    assert np.array_equal(compact.sum_hess, padded["sum_hess"][order])
+    assert np.array_equal(compact.value, padded["leaf_value"][order])
+
+
+def test_lossguide_forest_is_numbered_in_expansion_order_and_round_trips(tmp_path):
+    from benchmark.reference import gbt_reference, leafwise_reference
+
+    from sagemaker_xgboost_container_tpu.models.forest import Forest
+
+    rng = np.random.RandomState(9)
+    X = rng.randn(1500, 7).astype(np.float32)
+    y = (X[:, 0] * X[:, 1] + 0.5 * X[:, 2] > 0).astype(np.float32)
+    forest = train(
+        {"objective": "binary:logistic", "grow_policy": "lossguide", "max_depth": 0,
+         "max_leaves": 20, "eta": 0.3, "max_bin": 32},
+        DataMatrix(X, labels=y), num_boost_round=3,
+    )
+    for tree in forest.trees:
+        internal = np.flatnonzero(tree.left >= 0)
+        # children after parents, and each split's children the next two ids
+        assert (tree.left[internal] > internal).all()
+        steps = np.argsort(tree.left[internal])
+        assert tree.left[internal][steps].tolist() == list(range(1, 2 * len(internal), 2))
+        assert (tree.right[internal] == tree.left[internal] + 1).all()
+        plain = {"left": tree.left.astype(np.int64), "right": tree.right.astype(np.int64),
+                 "gain": tree.gain}
+        assert leafwise_reference.best_first_violations(plain) == 0
+        assert tree.depth() == gbt_reference.node_depths(plain).max()
+        # the breadth-first numbering of before reads as out of order
+        assert len(internal) == 19
+    path = str(tmp_path / "model.json")
+    forest.save_model(path)
+    again = Forest.load_model(path)
+    assert np.array_equal(
+        np.asarray(forest.predict(X), np.float32), np.asarray(again.predict(X), np.float32)
+    )
+    for a, b in zip(forest.trees, again.trees):
+        assert np.array_equal(a.left, b.left) and np.array_equal(a.right, b.right)
+
+
+def test_breadth_first_ids_read_as_best_first_violations():
+    """What the judge's exact check is for: hand it a best-first tree
+    renumbered breadth-first (the compact ids of before PR 42)."""
+    from benchmark.reference import leafwise_reference
+
+    bins, grad, hess, num_cuts, num_bins = _grower_inputs(4)
+    tree = leafwise_reference.grow(bins, num_cuts, grad, hess, 16, lam=1.0, eta=0.1)
+    assert leafwise_reference.best_first_violations(tree) == 0
+    order = [0]
+    for node in order:
+        if tree["left"][node] >= 0:
+            order += [int(tree["left"][node]), int(tree["right"][node])]
+    assert order != sorted(order)  # not a heap: the two numberings differ
+    new_id = {old: new for new, old in enumerate(order)}
+    renumbered = {
+        "left": np.array([new_id.get(int(tree["left"][o]), -1) for o in order]),
+        "right": np.array([new_id.get(int(tree["right"][o]), -1) for o in order]),
+        "gain": tree["gain"][order],
+    }
+    assert leafwise_reference.best_first_violations(renumbered) > 0
+
+
+# ----------------------------------------------------------------- gauges
+def _gauge(name):
+    for metric, _kind, _help, family in REGISTRY.collect():
+        if metric == name:
+            return [s.value for s in family]
+    return None
+
+
+@pytest.mark.parametrize(
+    "params, steps, trees",
+    [
+        ({"objective": "binary:logistic", "grow_policy": "lossguide", "max_depth": 0,
+          "max_leaves": 9}, 8, 1),
+        ({"objective": "binary:logistic", "max_depth": 3}, 0, 1),
+        ({"objective": "multi:softmax", "num_class": 10, "max_depth": 2}, 0, 10),
+        ({"objective": "multi:softmax", "num_class": 3, "grow_policy": "lossguide",
+          "max_depth": 0, "max_leaves": 5}, 12, 3),
+    ],
+    ids=["loss_guided", "depth_wise", "ten_class", "loss_guided_three_class"],
+)
+def test_round_shape_gauges(params, steps, trees):
+    rng = np.random.RandomState(2)
+    X = rng.randn(400, 5).astype(np.float32)
+    classes = int(params.get("num_class", 2))
+    y = (np.abs(X[:, 0] * 3).astype(int) % classes).astype(np.float32)
+    leaves_before = (_gauge("tree_leaves_total") or [0.0])[0]
+    forest = train(dict(params, max_bin=16), DataMatrix(X, labels=y), num_boost_round=2)
+    assert _gauge("round_split_steps") == [float(steps)]
+    assert _gauge("round_class_trees") == [float(trees)]
+    grown = sum(int((t.left < 0).sum()) for t in forest.trees)
+    assert _gauge("tree_leaves_total")[0] - leaves_before == grown
+    assert _gauge("tree_depth_max") == [float(max(t.depth() for t in forest.trees))]
+
+
+def test_front_door_takes_the_settings_as_strings():
+    """``grow_policy=lossguide``, ``max_depth=0``, ``max_leaves=255`` as the
+    strings of a ``hyperparameters.json`` pass the validation and arrive at
+    ``TrainConfig`` as what the build needs."""
+    from sagemaker_xgboost_container_tpu.algorithm import hyperparameters as hpv
+    from sagemaker_xgboost_container_tpu.algorithm import metrics as metrics_mod
+    from sagemaker_xgboost_container_tpu.models.booster import TrainConfig
+
+    schema = hpv.initialize(metrics_mod.initialize())
+    validated = schema.validate({
+        "num_round": "500", "objective": "binary:logistic", "tree_method": "hist",
+        "grow_policy": "lossguide", "max_depth": "0", "max_leaves": "255", "eta": "0.1",
+        "min_child_weight": "100", "lambda": "1.0", "max_bin": "256", "eval_metric": "logloss",
+    })
+    config = TrainConfig(validated)
+    assert (config.grow_policy, config.max_depth, config.max_leaves) == ("lossguide", 0, 255)
+    assert config.eval_traversal == "pointer" and config.predict_depth == 254
+    assert config.min_child_weight == 100.0

@@ -62,11 +62,11 @@ from ..ops.tree_build import (
     build_tree,
     choose_eval_traversal,
     choose_route_impl,
-    pack_tree,
+    pack_round_trees,
     predict_binned,
     predict_binned_levels,
     tree_from_packed,
-    unpack_tree,
+    unpack_round_trees,
 )
 from ..telemetry import device as device_telemetry
 from ..telemetry.cluster import compile_stats, install_program_listener
@@ -1463,7 +1463,7 @@ class _TrainingSession:
                 stacked = jax.tree_util.tree_map(
                     lambda *leaves: jnp.stack(leaves), *trees
                 ) if num_parallel > 1 else trees[0]
-                packed = pack_tree(stacked)
+                packed = pack_round_trees(stacked)
             if not collect_stats:
                 return packed, margins
             return packed, margins, _learning_stats(g, h, margins)
@@ -1698,7 +1698,18 @@ class _TrainingSession:
             self.hist_comm,
             subtract,
             trees_per_round=trees_per_round,
+            pass_slots=self._pass_slots(subtract),
         )
+
+    def _pass_slots(self, subtract):
+        """Node slots of a loss-guided build's pass over the rows (the W of
+        its kernel call and of its collective; ops/lossguide.py); 1 for a
+        depth-wise job, which has none."""
+        if self.config.grow_policy != "lossguide":
+            return 1
+        from ..ops.lossguide import pass_nodes
+
+        return pass_nodes(self.config.max_leaves, subtract)
 
     def _build_structure(self):
         """(columns a shard histograms, bins, subtraction, trees a round):
@@ -1737,7 +1748,8 @@ class _TrainingSession:
         d_local, num_bins, subtract, trees_per_round = self._build_structure()
         return round_onehot_tiles(
             round_hist_levels(
-                cfg.grow_policy, cfg.max_depth, cfg.max_leaves, subtract
+                cfg.grow_policy, cfg.max_depth, cfg.max_leaves, subtract,
+                self._pass_slots(subtract),
             ),
             self.bins.shape[0] // self.n_data_shards,
             d_local,
@@ -1820,7 +1832,9 @@ class _TrainingSession:
         REGISTRY.counter(
             "hist_comm_bytes_total",
             "Estimated cross-shard wire bytes moved by histogram "
-            "collectives (ring formula, docs/DESIGN.md Communication)",
+            "collectives (ring formula, docs/DESIGN.md Communication; a "
+            "loss-guided build's passes counted one a split step, the most "
+            "they can be: tree_hist_passes_total counts them)",
             labels,
         ).inc(self.hist_comm_bytes_per_round * k_rounds)
 
@@ -2198,7 +2212,7 @@ class _TrainingSession:
             packed_np = self._device_sync(packed, out, attributes, fenced)
             self._note_comm_dispatch(1)
             self._stash_learning_stats(lstats)
-            return [unpack_tree(packed_np)], None
+            return [unpack_round_trees(packed_np)], None
         eval_m = tuple(m for m in self.eval_margins if m is not None)
         eval_blw = tuple(
             (self.eval_bins[i], self.eval_labels[i], self.eval_weights[i])
@@ -2225,7 +2239,7 @@ class _TrainingSession:
         self._stash_learning_stats(lstats)
         metrics_np = np.asarray(metrics) if self.device_metric_fns else None
         return (
-            [unpack_tree(packed_np[j]) for j in range(packed_np.shape[0])],
+            [unpack_round_trees(packed_np[j]) for j in range(packed_np.shape[0])],
             metrics_np,
         )
 
@@ -2328,6 +2342,18 @@ def _note_round_shape(session):
         "loop (ops/lossguide.py); 0 for a depth-wise job",
     ).set(split_steps_per_round(cfg.grow_policy, cfg.max_leaves, trees))
     _tree_depth_gauge().set(0)  # the deepest leaf of THIS session's trees
+    _round_hist_passes_gauge().set(0)
+
+
+def _round_hist_passes_gauge():
+    from ..telemetry import REGISTRY
+
+    return REGISTRY.gauge(
+        "round_hist_passes",
+        "Passes over the rows in the split-step loops of the last committed "
+        "round's loss-guided trees (round_split_steps would be a pass a step); "
+        "0 for a depth-wise job",
+    )
 
 
 def _tree_depth_gauge():
@@ -2341,11 +2367,16 @@ def _tree_depth_gauge():
     )
 
 
-def note_committed_trees(trees):
+def note_committed_trees(trees, padded=None):
     """Counts the trees a round committed, from the compact trees the host
     holds anyway (no device work): the leaves grown, and the deepest leaf of
     the session so far, which is the number of levels the pointer walk of a
-    loss-guided tree makes (``ops/tree_build.py::predict_binned``)."""
+    loss-guided tree makes (``ops/tree_build.py::predict_binned``).
+    ``padded``: the round's padded tree arrays as the dispatch's one array
+    brought them; a loss-guided round's hold its ``[..., 3]`` pass counters
+    (``ops/lossguide.py``): passes over the rows, node slots the passes
+    filled, and filled slots a split step then used."""
+    from ..ops.tree_build import PASS_COUNTS_FIELD
     from ..telemetry import REGISTRY
 
     REGISTRY.counter(
@@ -2353,6 +2384,26 @@ def note_committed_trees(trees):
     ).inc(sum(int(np.count_nonzero(t.is_leaf)) for t in trees))
     deepest = _tree_depth_gauge()
     deepest.set(max([deepest.value] + [t.depth() for t in trees]))
+    if padded is None or PASS_COUNTS_FIELD not in padded:
+        return
+    passes, filled, used = (
+        int(v) for v in np.reshape(padded[PASS_COUNTS_FIELD], (-1, 3)).sum(axis=0)
+    )
+    REGISTRY.counter(
+        "tree_hist_passes_total",
+        "Passes over the rows the split-step loops of the committed loss-guided "
+        "trees ran: one level histogram kernel call each, the root's call apart",
+    ).inc(passes)
+    REGISTRY.counter(
+        "tree_hist_pass_slots_total",
+        "Node slots those passes filled with a leaf's child (a pass holds "
+        "ops/lossguide.py::PASS_SLOTS)",
+    ).inc(filled)
+    REGISTRY.counter(
+        "tree_hist_pass_slots_used_total",
+        "Filled node slots whose leaf a split step then committed",
+    ).inc(used)
+    _round_hist_passes_gauge().set(passes)
 
 
 def evaluate_host_lines(
@@ -2730,7 +2781,7 @@ def train(
                 with span("commit", attributes={"round": rnd}):
                     trees, info = _trees_for_round(tree_np)
                     forest.append_round(trees, info)
-                    note_committed_trees(trees)
+                    note_committed_trees(trees, tree_np)
 
                 if j < len(session.last_learning_stats):
                     # model-quality plane: device reductions + committed-tree

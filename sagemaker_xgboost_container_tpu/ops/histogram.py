@@ -239,15 +239,15 @@ def _wire_ratio(comm, axis_size):
 MERGE_COLLECTIVES_PER_SCAN = 7
 
 
-def round_hist_levels(grow_policy, max_depth, max_leaves, subtract):
+def round_hist_levels(grow_policy, max_depth, max_leaves, subtract, pass_slots=1):
     """``(W, count)`` of the ``level_histogram`` calls one tree build issues:
-    W nodes built a call, ``count`` such calls. With sibling subtraction a
-    depth-wise level builds its left children alone and a loss-guided split
-    step one child."""
+    W nodes built a call, ``count`` such calls; for a loss-guided build's passes
+    (``pass_slots`` nodes each, ops/lossguide.py) AT MOST: one a split step. With
+    sibling subtraction a depth-wise level builds its left children alone."""
     if grow_policy == "lossguide":
         levels = [(1, 1)]                                # root
         if max_leaves > 1:
-            levels.append((1 if subtract else 2, max_leaves - 1))  # per split step
+            levels.append((pass_slots, max_leaves - 1))  # a pass: the data's count
         return levels
     return [(1, 1)] + [                                  # level 0, then 1 ..
         (2 ** (level - 1) if subtract else 2**level, 1)
@@ -264,7 +264,7 @@ def round_comm_plan(
     axis_size,
     comm,
     subtract,
-    trees_per_round=1,
+    trees_per_round=1, pass_slots=1,
 ):
     """Static per-round collective plan for the data axis.
 
@@ -289,7 +289,7 @@ def round_comm_plan(
     d_eff = padded_feature_width(d, axis_size) if comm == "reduce_scatter" else d
     ratio = _wire_ratio(comm, axis_size)
     psum_ratio = _wire_ratio("psum", axis_size)
-    hist_widths = round_hist_levels(grow_policy, max_depth, max_leaves, subtract)
+    hist_widths = round_hist_levels(grow_policy, max_depth, max_leaves, subtract, pass_slots)
     totals = []
     if grow_policy == "lossguide":
         # winner-merge scan widths (reduce_scatter only): the root, then

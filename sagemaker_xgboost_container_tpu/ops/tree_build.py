@@ -796,3 +796,36 @@ def predict_binned_levels(tree, bins, max_depth, num_bins, route_impl=None,
     return node_table_lookup(
         leaves, node, impl=choose_table_impl(table_backend, leaves.shape[0])
     )
+
+
+# What follows stands at the end of the file because the lines of the traced
+# code above are part of the compile cache's key (PERF.md section 6, PR 30).
+
+#: a loss-guided build's pass counters (ops/lossguide.py: passes over the
+#: rows, node slots filled, slots used), the one field a tree dict may hold
+#: beside _TREE_FIELDS
+PASS_COUNTS_FIELD = "hist_passes"
+
+
+def pack_round_trees(tree):
+    """``pack_tree`` for the round program: a loss-guided tree's pass
+    counters ride the one array as the first entries of an eleventh row, so
+    they cost no transfer of their own; a depth-wise tree packs as ever."""
+    packed = pack_tree(tree)
+    if PASS_COUNTS_FIELD not in tree:
+        return packed
+    counts = tree[PASS_COUNTS_FIELD].astype(jnp.float32)
+    spare = packed.shape[-1] - counts.shape[-1]
+    row = jnp.pad(counts, [(0, 0)] * (counts.ndim - 1) + [(0, spare)])
+    return jnp.concatenate([packed, row[None]])
+
+
+def unpack_round_trees(packed):
+    """``unpack_tree``, and the pass counters where the array carries them."""
+    import numpy as np
+
+    out = unpack_tree(packed)
+    if len(packed) > len(_TREE_FIELDS):
+        counts = np.asarray(packed[len(_TREE_FIELDS)])
+        out[PASS_COUNTS_FIELD] = counts[..., :3].astype(np.int64)
+    return out

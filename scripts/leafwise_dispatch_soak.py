@@ -96,6 +96,17 @@ def main(argv=None):
             )
 
     booster._TrainingSession._device_sync = sync
+    # [passes, slots filled, slots used] of every committed tree (PR 43: a
+    # pass histograms the best open leaves at once, ops/lossguide.py)
+    pass_counts = []
+    real_note = booster.note_committed_trees
+
+    def note(trees, padded=None):
+        if padded is not None and "hist_passes" in padded:
+            pass_counts.append([int(v) for v in padded["hist_passes"].reshape(-1, 3).sum(axis=0)])
+        return real_note(trees, padded)
+
+    booster.note_committed_trees = note
     sessions = []
     for seed in [int(s) for s in args.seeds.split(",")]:
         data = higgs_like.make(
@@ -103,7 +114,7 @@ def main(argv=None):
              "num_feature": args.cols},
             seed,
         )
-        before = len(seconds)
+        before, counted = len(seconds), len(pass_counts)
         sets = {name: DataMatrix(x, labels=y) for name, (x, y) in data.items()}
         forest = train(
             dict(params, seed=seed % (1 << 31)),
@@ -118,6 +129,7 @@ def main(argv=None):
             "sync_s": [round(s, 3) for s in seconds[before:]],
             "leaves": [int((t.left < 0).sum()) for t in forest.trees],
             "depth": [t.depth() for t in forest.trees],
+            "hist_passes": pass_counts[counted:],
         })
         print("SESSION {}".format(json.dumps(sessions[-1])), flush=True)
         del data, sets, forest

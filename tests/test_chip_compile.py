@@ -179,16 +179,39 @@ def _computations(hlo):
 
 def _opcodes_under(comps, name, seen=None):
     """Opcodes of computation ``name`` and of every computation it calls
-    (fusions, loop bodies and conditions, reducers)."""
+    (fusions, loop bodies and conditions, a conditional's branches,
+    reducers)."""
     seen = set() if seen is None else seen
     if name in seen or name not in comps:
         return []
     seen.add(name)
     body = comps[name]
     ops = [m.group(2) for m in (re.match(_INSTRUCTION, line) for line in body.split("\n")) if m]
-    for called in re.findall(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", body):
-        ops += _opcodes_under(comps, called, seen)
+    called = re.findall(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", body)
+    for name in called + _conditional_branches(body):
+        ops += _opcodes_under(comps, name, seen)
     return ops
+
+
+def _conditional_branches(body):
+    """Names of the branch computations of every conditional in ``body``
+    (HLO spells two branches ``true_computation=`` / ``false_computation=``
+    and more ``branch_computations={...}``)."""
+    out = []
+    for line in body.split("\n"):
+        if " conditional(" not in line:
+            continue
+        out += re.findall(r"(?:true|false)_computation=%?([\w.\-]+)", line)
+        for branches in re.findall(r"branch_computations=\{([^}]*)\}", line):
+            out += [b.strip().lstrip("%") for b in branches.split(",")]
+    return out
+
+
+def _computations_under(comps, name):
+    """``name`` and every computation it calls."""
+    seen = set()
+    _opcodes_under(comps, name, seen)
+    return sorted(seen)
 
 
 @pytest.mark.parametrize("scheme", ["pairwise", "ndcg"])
@@ -250,9 +273,10 @@ def test_rolled_loss_guided_round_compiles_for_the_chip(
     """`higgs-leafwise-l255`'s round (10.5M x 28 in u8, 255 leaves, the pointer
     walk over 500,000 validation rows) through the chip's own compilers, the
     kernel's too: two kernel call sites whatever `max_leaves` (the root's and
-    the step body's) where the unrolled loop held 255, the step loop one
-    `while` with the kernel inside it, and on a `data` mesh the histogram's
-    all-reduce inside that body too."""
+    a pass's) where the unrolled loop held 255, the step loop one `while`
+    whose body holds a conditional with the kernel inside it (PR 43: a pass
+    runs only for a pick without a histogram), and on a `data` mesh the
+    histogram's all-reduce inside that branch too."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -310,5 +334,13 @@ def test_rolled_loss_guided_round_compiles_for_the_chip(
     assert len(step_loops) == 1, step_loops
     in_loop = _opcodes_under(comps, step_loops[0])
     assert in_loop.count("custom-call") >= 1
+    # the kernel is in a branch of the step's conditional, not in the step
+    passes = [
+        _opcodes_under(comps, branch)
+        for name in _computations_under(comps, step_loops[0])
+        for branch in _conditional_branches(comps[name])
+    ]
+    with_kernel = [ops for ops in passes if "custom-call" in ops]
+    assert len(with_kernel) == 1 and len(passes) >= 2
     if chips > 1:
-        assert "all-reduce" in in_loop or "all-reduce-start" in in_loop
+        assert "all-reduce" in with_kernel[0] or "all-reduce-start" in with_kernel[0]

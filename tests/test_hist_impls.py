@@ -436,13 +436,21 @@ def test_a_fold_that_cuts_no_whole_tiles_is_refused():
         ("depthwise", 8, 0, True, [(1, 1), (1, 1), (2, 1), (4, 1), (8, 1), (16, 1), (32, 1), (64, 1)]),
         ("depthwise", 3, 0, False, [(1, 1), (2, 1), (4, 1)]),
         ("depthwise", 1, 0, False, [(1, 1)]),
-        ("lossguide", 0, 31, True, [(1, 1), (1, 30)]),
-        ("lossguide", 0, 31, False, [(1, 1), (2, 30)]),
+        # PR 43: a loss-guided build's passes hold PASS_SLOTS node slots (eight
+        # leaves' left children, or four leaves' two children) and run at most
+        # once a split step; fewer slots where the tree has fewer open leaves
+        ("lossguide", 0, 31, True, [(1, 1), (8, 30)]),
+        ("lossguide", 0, 31, False, [(1, 1), (8, 30)]),
+        ("lossguide", 0, 4, True, [(1, 1), (2, 3)]),
+        ("lossguide", 0, 2, False, [(1, 1), (2, 1)]),
         ("lossguide", 0, 1, True, [(1, 1)]),
     ],
 )
 def test_round_hist_levels_are_the_calls_a_build_issues(policy, depth, leaves, subtract, levels):
-    assert hist_mod.round_hist_levels(policy, depth, leaves, subtract) == levels
+    from sagemaker_xgboost_container_tpu.ops.lossguide import pass_nodes
+
+    slots = pass_nodes(leaves, subtract) if policy == "lossguide" else 1
+    assert hist_mod.round_hist_levels(policy, depth, leaves, subtract, slots) == levels
 
 
 @pytest.mark.parametrize(

@@ -1,8 +1,8 @@
 """The loss-guided build's split steps as ONE rolled loop (PR 42).
 
-* The forest is what the unrolled loop built, bit for bit: the digests below
-  were read off the commit before the change (``tests/lossguide_cases.py``
-  run against it) at every shape the issue names.
+* The forest is pinned bit for bit at every shape PR 42's issue names
+  (``tests/lossguide_cases.py``): the unrolled loop's digests up to PR 43,
+  which moved the sums' last bits where a tree splits more than once.
 * The program does not grow with ``max_leaves``.
 * The program's tree is the plain float64 grower's
   (``benchmark/reference/leafwise_reference.py::grow``).
@@ -26,42 +26,50 @@ from sagemaker_xgboost_container_tpu.telemetry import REGISTRY
 
 from tests import lossguide_cases
 
-# sha256[:16] of the padded tree arrays and row_out, off the parent commit
-# (4707354: the Python loop ``for t in range(max_leaves - 1)``)
+# sha256[:16] of the padded tree arrays and row_out. PR 42 read all 53 off its
+# parent commit (4707354: the Python loop ``for t in range(max_leaves - 1)``)
+# and PR 43's passes first gave the same 53 (the flat builder sums a node's
+# rows in row order whatever the node slots beside it). Then a pass dealt a
+# large leaf's rows round several slots (``_leaf_slots``: a leaf over an
+# eighth of the root's hessian sum, so every root's child), whose sums are
+# added up afterwards: another order of additions, other last bits. The 43
+# cases that split more than once were read anew off that program (the ten
+# ``l2`` ones are the parent's still); what holds them to the mathematics is
+# the plain grower (below and in ``tests/test_lossguide_passes.py``).
 PARENT_DIGESTS = {
     "l2.sub.plain": "c5991d5ab201c881", "l2.sub.bynode": "c5991d5ab201c881",
     "l2.sub.sets": "c5991d5ab201c881", "l2.sub.mcw": "c5991d5ab201c881",
     "l2.sub.depth3": "c5991d5ab201c881", "l2.nosub.plain": "c5991d5ab201c881",
     "l2.nosub.bynode": "c5991d5ab201c881", "l2.nosub.sets": "c5991d5ab201c881",
     "l2.nosub.mcw": "c5991d5ab201c881", "l2.nosub.depth3": "c5991d5ab201c881",
-    "l8.sub.plain": "64cc327ad7361a2e", "l8.sub.bynode": "5f942f4341c77d46",
-    "l8.sub.bylevel": "36134b3ebd08e85c", "l8.sub.sets": "08eb4fc3792e9940",
-    "l8.sub.mcw": "8e26a51f51c10f44", "l8.sub.depth3": "fd4134e8391c2d09",
-    "l8.sub.gamma": "d5b7193fa8a87772", "l8.nosub.plain": "bf0b8af0387a01d5",
-    "l8.nosub.bynode": "1948cfacc988f809", "l8.nosub.bylevel": "45f225e8a7292c02",
-    "l8.nosub.sets": "1fbce81dc1d7c8dc", "l8.nosub.mcw": "ece1ebff12879155",
-    "l8.nosub.depth3": "fa7eb0ac0dd15ee1", "l8.nosub.gamma": "68b74f9590c07b01",
-    "l31.sub.plain": "a2eef49292589551", "l31.sub.bynode": "5546077c7bc8d54a",
-    "l31.sub.sets": "351704746211e94e", "l31.sub.mcw": "c3974e24c311ed78",
-    "l31.sub.depth3": "f38a3c188dd7d4e2", "l31.nosub.plain": "adf79e63b8be076c",
-    "l31.nosub.bynode": "e4fdab139d0597b5", "l31.nosub.sets": "230fa04817c76741",
-    "l31.nosub.mcw": "5dc17ff1d7a85333", "l31.nosub.depth3": "ac3d826bbf5117b1",
-    "l8.sub.kernel": "ffb099084397fd89",
-    "data4.psum.sub.plain": "78b06c578600a5bb", "data4.psum.sub.bynode": "b695d426f07d3c00",
-    "data4.psum.sub.sets": "c882670ecbdd024a", "data4.psum.nosub.plain": "33dcf62293882a70",
-    "data4.psum.nosub.bynode": "81a3955bb32c2d7f", "data4.psum.nosub.sets": "df6e5817dc8f8723",
-    "data4.reduce_scatter.sub.plain": "78b06c578600a5bb",
-    "data4.reduce_scatter.sub.bynode": "b695d426f07d3c00",
-    "data4.reduce_scatter.sub.sets": "c882670ecbdd024a",
-    "data4.reduce_scatter.nosub.plain": "33dcf62293882a70",
-    "data4.reduce_scatter.nosub.bynode": "81a3955bb32c2d7f",
-    "data4.reduce_scatter.nosub.sets": "df6e5817dc8f8723",
-    "data2xfeature2.psum.plain": "7e43e387a133fa78",
-    "data2xfeature2.psum.bynode": "4a2eef0993bcceef",
-    "data2xfeature2.psum.sets": "31eb61af8bcf24de",
-    "data2xfeature2.reduce_scatter.plain": "7e43e387a133fa78",
-    "data2xfeature2.reduce_scatter.bynode": "4a2eef0993bcceef",
-    "data2xfeature2.reduce_scatter.sets": "31eb61af8bcf24de",
+    "l8.sub.plain": "673b5032fd4adde2", "l8.sub.bynode": "8074bf421aa8a4ad",
+    "l8.sub.bylevel": "5dd80eccd3e4beaa", "l8.sub.sets": "e12e7c35e2820947",
+    "l8.sub.mcw": "994e0798e7b59c7a", "l8.sub.depth3": "3b219e8a35e0d208",
+    "l8.sub.gamma": "a4e89fc2e1a30eee", "l8.nosub.plain": "abdbad2aca44cb18",
+    "l8.nosub.bynode": "c0be7293512bef17", "l8.nosub.bylevel": "3462b671240e958b",
+    "l8.nosub.sets": "9aa54f5be8013d87", "l8.nosub.mcw": "fd7275719dc69b25",
+    "l8.nosub.depth3": "b87385fb22972b96", "l8.nosub.gamma": "ab8324a6d77b28a5",
+    "l31.sub.plain": "79d371dec5a1797d", "l31.sub.bynode": "2ed1e41c59dd2712",
+    "l31.sub.sets": "c618baca4ff06f0b", "l31.sub.mcw": "36cf8db4c14131f4",
+    "l31.sub.depth3": "7d2db9cf7ab0be4f", "l31.nosub.plain": "03b73b4bdc5f0632",
+    "l31.nosub.bynode": "6b9b93ac9858e2c3", "l31.nosub.sets": "f7ed1b2623dbc3df",
+    "l31.nosub.mcw": "543330921d34eaf5", "l31.nosub.depth3": "90f1bd9485fc5944",
+    "l8.sub.kernel": "222f5c984a70442d",
+    "data4.psum.sub.plain": "0052e36868abc57d", "data4.psum.sub.bynode": "5a07cad657fe96e6",
+    "data4.psum.sub.sets": "eacf476b11f65e01", "data4.psum.nosub.plain": "c222f41ec387ed76",
+    "data4.psum.nosub.bynode": "575f966b9e6b822b", "data4.psum.nosub.sets": "7451bba2019f803b",
+    "data4.reduce_scatter.sub.plain": "0052e36868abc57d",
+    "data4.reduce_scatter.sub.bynode": "5a07cad657fe96e6",
+    "data4.reduce_scatter.sub.sets": "eacf476b11f65e01",
+    "data4.reduce_scatter.nosub.plain": "c222f41ec387ed76",
+    "data4.reduce_scatter.nosub.bynode": "575f966b9e6b822b",
+    "data4.reduce_scatter.nosub.sets": "7451bba2019f803b",
+    "data2xfeature2.psum.plain": "5de9d21f6363ed9a",
+    "data2xfeature2.psum.bynode": "137dc296c006e3dc",
+    "data2xfeature2.psum.sets": "ba31962fcd94f857",
+    "data2xfeature2.reduce_scatter.plain": "5de9d21f6363ed9a",
+    "data2xfeature2.reduce_scatter.bynode": "137dc296c006e3dc",
+    "data2xfeature2.reduce_scatter.sets": "ba31962fcd94f857",
 }
 CASES = lossguide_cases.cases()
 
@@ -74,6 +82,11 @@ def test_every_case_has_its_digest():
 def test_rolled_build_is_the_unrolled_builds_forest_bit_for_bit(name):
     tree, row_out = lossguide_cases.run_case(*CASES[name])
     assert lossguide_cases.digest(tree, row_out) == PARENT_DIGESTS[name]
+    # PR 43: the same forest from fewer passes over the rows, in every case
+    passes, filled, used = (int(v) for v in tree["hist_passes"])
+    kids = 1 if CASES[name][2] else 2  # node slots a leaf takes in a pass
+    steps = int((~tree["is_leaf"]).sum())
+    assert passes <= steps and kids * steps <= used <= filled <= lossguide_mod.PASS_SLOTS * passes
 
 
 # ------------------------------------------------------- the program's size
@@ -111,9 +124,11 @@ def _count_equations(jaxpr):
     for eqn in jaxpr.eqns:
         total += 1
         for value in eqn.params.values():
-            inner = getattr(value, "jaxpr", value)
-            if hasattr(inner, "eqns"):
-                total += _count_equations(inner)
+            # one nested jaxpr (a loop's body) or several (a cond's branches)
+            for inner in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    total += _count_equations(inner)
     return total
 
 
@@ -121,13 +136,14 @@ def _count_equations(jaxpr):
 def test_build_program_does_not_grow_with_max_leaves(subtract):
     """The equation count, every nested body included, at 16 and 255 leaves;
     and one ``level_histogram`` call site for the root and one for the step
-    body (W = 1 with sibling subtraction, W = 2 without)."""
+    body's pass (PR 43: ``PASS_SLOTS`` = 8 node slots, eight leaves' left
+    children with sibling subtraction, four leaves' two children without)."""
     calls_16, calls_255 = [], []
     small = _build_jaxpr(16, calls_16, subtract)
     large = _build_jaxpr(255, calls_255, subtract)
     assert _count_equations(small.jaxpr) == _count_equations(large.jaxpr)
     assert len(small.jaxpr.eqns) == len(large.jaxpr.eqns)
-    assert calls_16 == calls_255 == [1, 1 if subtract else 2]
+    assert calls_16 == calls_255 == [1, 8]
 
 
 def test_benchmark_probe_reads_the_rolled_loop():

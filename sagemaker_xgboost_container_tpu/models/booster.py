@@ -63,8 +63,8 @@ from ..ops.tree_build import (
     choose_eval_traversal,
     choose_route_impl,
     pack_round_trees,
-    predict_binned,
     predict_binned_levels,
+    predict_binned_steps,
     tree_from_packed,
     unpack_round_trees,
 )
@@ -281,8 +281,8 @@ def _apply_packed_tree(packed, bins, margins, num_group, num_parallel, depth,
     ``hist_knobs.backend`` snapshot, never a trace-time read.
     ``traversal``: the layout of the trees this session's builder makes
     (``TrainConfig.eval_traversal``): ``level`` walks the depth-wise heap
-    level by level, ``pointer`` chases a loss-guided tree's ``left`` /
-    ``right``.
+    level by level, ``replay`` takes the rows through a loss-guided tree's
+    splits in the order they were made (``depth`` is the level walk's alone).
     """
     route_impl = choose_route_impl(backend, bins.shape[1])
 
@@ -292,7 +292,7 @@ def _apply_packed_tree(packed, bins, margins, num_group, num_parallel, depth,
                 t, bins, depth, num_bins, route_impl=route_impl,
                 table_backend=backend,
             )
-        return predict_binned(t, bins, depth, num_bins, route_impl=route_impl)
+        return predict_binned_steps(t, bins, num_bins, table_backend=backend)
 
     with stage(STAGE_EVAL_APPLY):
         tree = tree_from_packed(packed)
@@ -2335,12 +2335,20 @@ def _note_round_shape(session):
         "(1 for a binary, regression or ranking job); the class trees "
         "of a depth-wise round share one kernel call a level",
     ).set(trees)
+    steps = split_steps_per_round(cfg.grow_policy, cfg.max_leaves, trees)
     REGISTRY.gauge(
         "round_split_steps",
         "Split steps one boosting round runs: (max_leaves - 1) x the trees "
         "a round grows for a loss-guided job, whose steps are one rolled "
         "loop (ops/lossguide.py); 0 for a depth-wise job",
-    ).set(split_steps_per_round(cfg.grow_policy, cfg.max_leaves, trees))
+    ).set(steps)
+    REGISTRY.gauge(
+        "round_eval_replay_steps",
+        "Split steps one boosting round replays over evaluation rows: "
+        "round_split_steps x the evaluation sets that do not share the "
+        "training rows (ops/tree_build.py::predict_binned_steps); 0 for a "
+        "depth-wise job, whose rows walk the heap level by level",
+    ).set(steps * sum(b is not None for b in session.eval_bins))
     _tree_depth_gauge().set(0)  # the deepest leaf of THIS session's trees
     _round_hist_passes_gauge().set(0)
 
@@ -2362,16 +2370,15 @@ def _tree_depth_gauge():
     return REGISTRY.gauge(
         "tree_depth_max",
         "Depth of the deepest leaf over the trees the training session has "
-        "committed (root = 0): the levels the evaluation walk of such a tree "
-        "makes",
+        "committed (root = 0)",
     )
 
 
 def note_committed_trees(trees, padded=None):
     """Counts the trees a round committed, from the compact trees the host
     holds anyway (no device work): the leaves grown, and the deepest leaf of
-    the session so far, which is the number of levels the pointer walk of a
-    loss-guided tree makes (``ops/tree_build.py::predict_binned``).
+    the session so far (the levels a pointer walk would make of a loss-guided
+    tree; its rows replay the split steps, ``round_eval_replay_steps``).
     ``padded``: the round's padded tree arrays as the dispatch's one array
     brought them; a loss-guided round's hold its ``[..., 3]`` pass counters
     (``ops/lossguide.py``): passes over the rows, node slots the passes

@@ -218,9 +218,9 @@ def unpack_split_word(word, bin_bits):
 def choose_eval_traversal(grow_policy):
     """How an evaluation row finds its leaf in a tree this session's builder
     made: ``level`` (``predict_binned_levels``) for the heap ``build_tree``
-    lays out, ``pointer`` (``predict_binned``) for loss-guided trees, whose
-    nodes are numbered in the order they were split."""
-    return "pointer" if grow_policy == "lossguide" else "level"
+    lays out, ``replay`` (``predict_binned_steps``) for loss-guided trees,
+    whose nodes are numbered in the order they were split."""
+    return "replay" if grow_policy == "lossguide" else "level"
 
 
 def max_nodes_for_depth(max_depth):
@@ -829,3 +829,60 @@ def unpack_round_trees(packed):
         counts = np.asarray(packed[len(_TREE_FIELDS)])
         out[PASS_COUNTS_FIELD] = counts[..., :3].astype(np.int64)
     return out
+
+
+def predict_binned_steps(tree, bins, num_bins, table_backend=None):
+    """``predict_binned`` for a tree ``build_tree_lossguide`` made, bit for bit.
+
+    Such a tree numbers its nodes in the order they were split (split step t
+    made nodes ``2t + 1`` and ``2t + 2``; ``ops/lossguide.py``), so the tree
+    is the list of its builder's steps and rows replay them the way the build
+    routed its own: ONE rolled ``fori_loop`` over the ``(nodes - 1) // 2``
+    steps, a step's node and split read as scalars, that one column sliced
+    out of ``bins`` and the rows that sit on the node moved by one compare
+    and one select. No ``while_loop``, no reduction over the rows and no
+    per-row gather inside the loop (on the chip the pointer walk's five
+    row-length gathers a level cost 37.6 ns a row and level, a step here
+    30 us over 500,000 rows: PERF.md section 6, PR 44); under ``vmap`` over
+    a stack of trees the scalar column is one column a tree.
+    The per-step table is derived here from ``left`` and ``is_leaf`` by a
+    ``[steps, nodes]`` compare: a step that could not split has no node
+    (-1, which no row sits on), and nothing is added to the packed tree. The
+    leaf value is one ``node_table_lookup`` at the end, in the lowering
+    ``choose_table_impl(table_backend, nodes)`` picks (traced callers pass
+    the session's ``HistKnobs.backend``; None reads the process's backend,
+    for direct callers only).
+    """
+    if table_backend is None:
+        table_backend = jax.default_backend()
+    n = bins.shape[0]
+    nodes = tree["left"].shape[0]
+    steps = (nodes - 1) // 2
+    node_ids = jnp.arange(nodes, dtype=jnp.int32)
+    first_child = 2 * jnp.arange(steps, dtype=jnp.int32) + 1
+    # [steps, nodes]: the internal node whose children step t made; a step
+    # that could not split reads -1 in every field, and no row sits on node -1
+    made_by = ~tree["is_leaf"][None, :] & (tree["left"][None, :] == first_child[:, None])
+    table = jnp.stack(
+        [
+            jnp.max(jnp.where(made_by, values.astype(jnp.int32)[None, :], -1), axis=1)
+            for values in (node_ids, tree["feature"], tree["bin"], tree["default_left"])
+        ],
+        axis=1,
+    )
+
+    def step(t, node):
+        parent, feature, split_bin, default_left = jax.lax.dynamic_index_in_dim(
+            table, t, keepdims=False
+        )
+        row_bin = jax.lax.dynamic_slice(bins, (0, feature), (n, 1))[:, 0]
+        go_right = jnp.where(
+            row_bin == (num_bins - 1), default_left == 0, row_bin > split_bin
+        )
+        child = 2 * t + 1 + go_right.astype(jnp.int32)
+        return jnp.where(node == parent, child, node)
+
+    node = jax.lax.fori_loop(0, steps, step, jnp.zeros(n, jnp.int32))
+    return node_table_lookup(
+        tree["leaf_value"], node, impl=choose_table_impl(table_backend, nodes)
+    )

@@ -90,8 +90,10 @@ def start_device_runtime(
     process would pick). ``route_width``: the feature width of the train bins,
     for which the line names the bin fetch's lowering (the trainer; a server
     routes no binned rows). ``grow_policy``: the trainer's, for which the line
-    names how evaluation rows walk a new tree (``eval_traversal``; a server
-    builds no trees). ``max_depth``: the trainer's, for which the line names how
+    names how evaluation rows walk a new tree (``eval_traversal``: ``level``
+    for a depth-wise job, ``replay`` for a loss-guided one, whose rows are
+    taken through the tree's splits in the order they were made,
+    ``ops/tree_build.py::predict_binned_steps``; a server builds no trees). ``max_depth``: the trainer's, for which the line names how
     the build's rows read their level's node tables (``build_table_impl``: the
     lowering at the widest level, ``2**max_depth`` entries; a loss-guided
     build reads no node table: a split step routes one node's rows by a

@@ -5,11 +5,13 @@ whether every dispatch came back.
     python scripts/leafwise_dispatch_soak.py --seeds 1,2,3,4,5 --dispatches 6
 
 A loss-guided round (``ops/lossguide.py``: ``max_leaves - 1`` split steps
-under one rolled loop) is the only caller of the pointer walk
-(``ops/tree_build.py::predict_binned``: a ``while_loop`` whose every step
-gathers two ``bool`` tables over the evaluation rows), and PR 41 found a v5e
-stopping for good in a gather of a ``pred`` operand that XLA had placed in
-VMEM. This drives the path a job takes (``models.train()``, the device
+under one rolled loop) takes its evaluation rows through the new tree by
+the step replay since PR 44 (``ops/tree_build.py::predict_binned_steps``: a
+second rolled loop, one column slice a split step and no gather; up to PR 43
+the pointer walk, ``predict_binned``, whose every step gathered two ``bool``
+tables over the evaluation rows), and PR 41 found a v5e stopping for good in
+a gather of a ``pred`` operand that XLA had placed in VMEM: a new round
+program runs here first. This drives the path a job takes (``models.train()``, the device
 sketch, K rounds a dispatch, ``logloss`` of both sets from the device) on
 seeded rows at ``higgs-leafwise-l255``'s shape by default, one session a
 seed, times every dispatch, and leaves at once, exit 3, when one is not back
@@ -138,6 +140,7 @@ def main(argv=None):
         "ok": ok, "rows": args.rows, "validation_rows": args.validation_rows,
         "cols": args.cols, "leaves": args.leaves, "rounds_per_dispatch": k,
         "dispatches": len(seconds), "sessions": sessions,
+        "eval_traversal": booster.TrainConfig(params).eval_traversal,
         "device": {"platform": platform, "kind": jax.devices()[0].device_kind,
                    "count": len(jax.devices())},
     }))

@@ -270,19 +270,25 @@ LEAFWISE_ROWS, LEAFWISE_VALIDATION, LEAFWISE_FEATURES, LEAVES = 10_500_000, 500_
 def test_rolled_loss_guided_round_compiles_for_the_chip(
     described_chips, one_chip, no_compile_cache, monkeypatch, chips
 ):
-    """`higgs-leafwise-l255`'s round (10.5M x 28 in u8, 255 leaves, the pointer
-    walk over 500,000 validation rows) through the chip's own compilers, the
+    """`higgs-leafwise-l255`'s round (10.5M x 28 in u8, 255 leaves, the step
+    replay over 500,000 validation rows) through the chip's own compilers, the
     kernel's too: two kernel call sites whatever `max_leaves` (the root's and
     a pass's) where the unrolled loop held 255, the step loop one `while`
     whose body holds a conditional with the kernel inside it (PR 43: a pass
     runs only for a pick without a histogram), and on a `data` mesh the
-    histogram's all-reduce inside that branch too."""
+    histogram's all-reduce inside that branch too. The replay (PR 44) is a
+    second `while`, a column slice a step: no gather and no collective in its
+    body, and no row-length gather anywhere in the walk."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from sagemaker_xgboost_container_tpu.ops import histogram as hist_mod
     from sagemaker_xgboost_container_tpu.ops.lossguide import build_tree_lossguide
-    from sagemaker_xgboost_container_tpu.ops.tree_build import predict_binned, tree_from_packed
+    from sagemaker_xgboost_container_tpu.ops.tree_build import (
+        predict_binned_steps,
+        tree_from_packed,
+    )
+    from sagemaker_xgboost_container_tpu.telemetry.device import STAGE_EVAL_APPLY, stage
 
     monkeypatch.setattr(hist_mod, "pallas_interpret", lambda: False)
     knobs = resolve_hist_knobs()._replace(backend="tpu")
@@ -295,9 +301,10 @@ def test_rolled_loss_guided_round_compiles_for_the_chip(
             n_data_shards=chips,
         )
         packed = pack_tree(tree)
-        walked = predict_binned(
-            tree_from_packed(packed), validation_bins, LEAVES - 1, NUM_BINS, route_impl="dense"
-        )
+        with stage(STAGE_EVAL_APPLY):
+            walked = predict_binned_steps(
+                tree_from_packed(packed), validation_bins, NUM_BINS, table_backend="tpu"
+            )
         return packed, row_out, walked
 
     if chips == 1:
@@ -344,3 +351,13 @@ def test_rolled_loss_guided_round_compiles_for_the_chip(
     assert len(with_kernel) == 1 and len(passes) >= 2
     if chips > 1:
         assert "all-reduce" in with_kernel[0] or "all-reduce-start" in with_kernel[0]
+    replays = [
+        body for name, text in comps.items()
+        for body in re.findall(r" while\([^\n]*?body=%?([\w.\-]+)[^\n]*eval_apply", text)
+    ]
+    assert len(replays) == 1, replays
+    in_replay = _opcodes_under(comps, replays[0])
+    assert "dynamic-slice" in in_replay
+    assert not {"gather", "while", "conditional", "all-reduce", "all-reduce-start"} & set(in_replay)
+    walk = [line for line in hlo.split("\n") if "eval_apply" in line and " gather(" in line]
+    assert not walk, walk

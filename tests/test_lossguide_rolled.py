@@ -348,6 +348,41 @@ def test_round_shape_gauges(params, steps, trees):
     assert _gauge("tree_depth_max") == [float(max(t.depth() for t in forest.trees))]
 
 
+@pytest.mark.parametrize(
+    "params, watchlist, replayed",
+    [
+        ({"grow_policy": "lossguide", "max_depth": 0, "max_leaves": 9}, ("validation",), 8),
+        ({"grow_policy": "lossguide", "max_depth": 0, "max_leaves": 9}, ("train", "validation"), 8),
+        ({"grow_policy": "lossguide", "max_depth": 0, "max_leaves": 9}, ("validation", "test"), 16),
+        ({"grow_policy": "lossguide", "max_depth": 0, "max_leaves": 9}, (), 0),
+        ({"grow_policy": "lossguide", "max_depth": 0, "max_leaves": 5, "num_class": 3,
+          "objective": "multi:softmax"}, ("validation",), 12),
+        ({"max_depth": 3}, ("train", "validation"), 0),
+    ],
+    ids=["one_set", "the_training_rows_share_leaf_margin", "two_sets", "no_watchlist",
+         "three_class", "depth_wise"],
+)
+def test_eval_replay_steps_gauge(params, watchlist, replayed):
+    """``round_eval_replay_steps``: the split steps a round replays over
+    evaluation rows, ``round_split_steps`` x the evaluation sets that do not
+    share the training rows; 0 where rows walk a heap level by level."""
+    rng = np.random.RandomState(4)
+    X = rng.randn(500, 5).astype(np.float32)
+    classes = int(params.get("num_class", 2))
+    y = (np.abs(X[:, 0] * 3).astype(int) % classes).astype(np.float32)
+    dtrain = DataMatrix(X[:300], labels=y[:300])
+    sets = {
+        "train": dtrain,
+        "validation": DataMatrix(X[300:400], labels=y[300:400]),
+        "test": DataMatrix(X[400:], labels=y[400:]),
+    }
+    train(
+        dict({"objective": "binary:logistic"}, **params, max_bin=16), dtrain, num_boost_round=1,
+        evals=[(sets[name], name) for name in watchlist], verbose_eval=False,
+    )
+    assert _gauge("round_eval_replay_steps") == [float(replayed)]
+
+
 def test_front_door_takes_the_settings_as_strings():
     """``grow_policy=lossguide``, ``max_depth=0``, ``max_leaves=255`` as the
     strings of a ``hyperparameters.json`` pass the validation and arrive at
@@ -364,5 +399,5 @@ def test_front_door_takes_the_settings_as_strings():
     })
     config = TrainConfig(validated)
     assert (config.grow_policy, config.max_depth, config.max_leaves) == ("lossguide", 0, 255)
-    assert config.eval_traversal == "pointer" and config.predict_depth == 254
+    assert config.eval_traversal == "replay" and config.predict_depth == 254
     assert config.min_child_weight == 100.0

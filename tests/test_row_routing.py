@@ -10,7 +10,8 @@ The evaluation walk: ``predict_binned_levels`` (depth-wise trees, level by
 level over the level's own node tables), ``predict_binned`` (the pointer
 traversal) and the build's own ``row_out`` agree bit for bit under every
 lowering of the bin fetch and of the node-table lookup, and a session takes
-the walk its ``grow_policy`` names.
+the walk its ``grow_policy`` names (a loss-guided one the step replay,
+``predict_binned_steps``: ``tests/test_eval_step_replay.py``).
 """
 
 import functools
@@ -422,21 +423,23 @@ def test_table_chooser_reads_backend_and_width_only(monkeypatch, backend, width,
 
 
 def test_session_walks_as_its_grow_policy_says(monkeypatch):
-    """A loss-guided session keeps the pointer traversal, a depth-wise one
-    takes the level walk; the choice reads the policy and nothing else."""
+    """A loss-guided session replays the tree's split steps, a depth-wise one
+    takes the level walk; the choice reads the policy and nothing else, and
+    no session traces the pointer traversal."""
     from sagemaker_xgboost_container_tpu.data.matrix import DataMatrix
     from sagemaker_xgboost_container_tpu.models import booster, train
 
     with monkeypatch.context() as during_the_call:
         during_the_call.setattr(os, "environ", NoEnviron())
-        assert choose_eval_traversal("lossguide") == "pointer"
+        assert choose_eval_traversal("lossguide") == "replay"
         assert choose_eval_traversal("depthwise") == "level"
     lossguide = {"grow_policy": "lossguide", "max_leaves": 8, "max_depth": 0}
-    assert booster.TrainConfig(lossguide).eval_traversal == "pointer"
+    assert booster.TrainConfig(lossguide).eval_traversal == "replay"
     assert booster.TrainConfig({"max_depth": 3}).eval_traversal == "level"
 
     taken = []
-    for name in ("predict_binned", "predict_binned_levels"):
+    assert not hasattr(booster, "predict_binned")
+    for name in ("predict_binned_steps", "predict_binned_levels"):
         def spy(*args, _name=name, _fn=getattr(booster, name), **kwargs):
             taken.append(_name)
             return _fn(*args, **kwargs)
@@ -446,7 +449,9 @@ def test_session_walks_as_its_grow_policy_says(monkeypatch):
     X = rng.rand(500, 4).astype(np.float32)
     y = (X[:, 0] + X[:, 1] > 1).astype(np.float32)
     dtrain, dval = DataMatrix(X[:400], labels=y[:400]), DataMatrix(X[400:], labels=y[400:])
-    for params, want in ((lossguide, "predict_binned"), ({"max_depth": 3}, "predict_binned_levels")):
+    for params, want in (
+        (lossguide, "predict_binned_steps"), ({"max_depth": 3}, "predict_binned_levels")
+    ):
         del taken[:]
         train(
             dict(params, objective="binary:logistic"), dtrain, num_boost_round=2,
@@ -455,10 +460,18 @@ def test_session_walks_as_its_grow_policy_says(monkeypatch):
         assert set(taken) == {want}
 
 
-def test_train_logs_the_pointer_traversals_validation_metric(monkeypatch):
+@pytest.mark.parametrize(
+    "growth, walk",
+    [
+        ({"max_depth": 5}, "predict_binned_levels"),
+        ({"grow_policy": "lossguide", "max_depth": 0, "max_leaves": 24}, "predict_binned_steps"),
+    ],
+    ids=["depthwise_level_walk", "lossguide_step_replay"],
+)
+def test_train_logs_the_pointer_traversals_validation_metric(monkeypatch, growth, walk):
     """End to end: ``train()`` with a validation set logs, every round, the
     metric that the same forest gives those rows under the pointer traversal,
-    to the bit."""
+    to the bit, whichever walk the session's growth policy names."""
     from sagemaker_xgboost_container_tpu.data.matrix import DataMatrix
     from sagemaker_xgboost_container_tpu.models import booster, train
 
@@ -468,10 +481,9 @@ def test_train_logs_the_pointer_traversals_validation_metric(monkeypatch):
     y = (np.nan_to_num(X[:, 0]) + np.nan_to_num(X[:, 2]) > 1).astype(np.float32)
     dtrain = DataMatrix(X[:2400], labels=y[:2400])
     dval = DataMatrix(X[2400:], labels=y[2400:])
-    params = {
-        "objective": "binary:logistic", "max_depth": 5, "gamma": 0.5,
-        "_rounds_per_dispatch": 2,
-    }
+    params = dict(
+        growth, objective="binary:logistic", gamma=0.5, _rounds_per_dispatch=2
+    )
 
     def run():
         log = {}
@@ -490,12 +502,15 @@ def test_train_logs_the_pointer_traversals_validation_metric(monkeypatch):
     forest, logged = run()
     assert len(logged) == 4
     walked = []
-    monkeypatch.setattr(
-        booster, "predict_binned_levels",
-        lambda t, b, depth, num_bins, route_impl=None, table_backend=None: (
-            walked.append("pointer") or predict_binned(t, b, depth, num_bins, route_impl=route_impl)
-        ),
-    )
+
+    def pointer(t, b, *depth, **_lowerings):
+        # the level walk is called (t, b, depth, num_bins), the replay (t, b, num_bins)
+        *depth, num_bins = depth
+        walked.append("pointer")
+        steps = depth[0] if depth else (t["left"].shape[-1] - 1) // 2
+        return predict_binned(t, b, steps, num_bins)
+
+    monkeypatch.setattr(booster, walk, pointer)
     forest_p, logged_p = run()
     assert walked
     assert [float(v).hex() for v in logged] == [float(v).hex() for v in logged_p]
@@ -539,7 +554,7 @@ def test_session_snapshot_holds_the_backend():
     [
         ("tpu", 28, "depthwise", 8, ("dense", "level", "select")),
         ("cpu", 28, "depthwise", 8, ("gather", "level", "gather")),
-        ("tpu", 28, "lossguide", 0, ("dense", "pointer", None)),
+        ("tpu", 28, "lossguide", 0, ("dense", "replay", None)),
         # a server: no binned rows, no trees built
         ("tpu", None, None, None, (None, None, None)),
         # the widest level a select still reads, and one level past it

@@ -43,7 +43,6 @@ from ..data.binning import (
 )
 from ..ops.histogram import (
     choose_hist_impl,
-    hist_comm_impl,
     padded_feature_width,
     resolve_hist_knobs,
     round_comm_plan,
@@ -311,22 +310,22 @@ def _apply_packed_tree(packed, bins, margins, num_group, num_parallel, depth,
 
 
 @functools.lru_cache(maxsize=None)
-def _calibrated_comm_ms(mesh, hist_comm, plan_key):
+def _calibrated_comm_ms(mesh, plan_key):
     """Standalone timing of one round's data-axis collectives (ms).
 
-    lru_cached module factory: one calibration per (mesh, lowering, plan
-    shapes) per PROCESS, not per session — a CV fold rebuild or an elastic
+    lru_cached module factory: one calibration per (mesh, plan shapes)
+    per PROCESS, not per session — a CV fold rebuild or an elastic
     reform that lands on an identical plan skips the compile + timing
     dispatches entirely (jax Meshes hash by device assignment + axis
     names, so a genuinely different topology still re-calibrates).
 
     Each DISTINCT payload shape in ``plan_key`` (tuples of
     ``(kind, shape, count)`` from ``round_comm_plan``) is timed as a
-    standalone jitted collective on zeros (min of 3 reps after a warmup)
-    and the per-round estimate is the count-weighted sum. An
-    isolated-latency estimate: real rounds overlap collectives with
-    compute (GRAFT_HIST_OVERLAP pipelines them on purpose), so this is an
-    upper bound on the comm share. Raises on failure — lru_cache does NOT
+    standalone jitted ``psum`` on zeros (min of 3 reps after a warmup)
+    and the per-round estimate is the count-weighted sum: what a host pays
+    to dispatch the round's collectives one by one, an upper bound on the
+    comm share and not the time they take inside the round program (the
+    ``hist_allreduce`` stage reads that). Raises on failure — lru_cache does NOT
     memoize raising calls, so a transient failure (device momentarily
     busy) is retried by the next session rebuild instead of pinning the
     gauge to a cached 0.0 for the rest of the process; the caller
@@ -337,31 +336,18 @@ def _calibrated_comm_ms(mesh, hist_comm, plan_key):
     def psum_fn(x):
         return jax.lax.psum(x, "data")
 
-    def scatter_fn(x):
-        return jax.lax.psum_scatter(
-            x, "data", scatter_dimension=1, tiled=True
-        )
-
-    from ..ops.histogram import MERGE_COLLECTIVES_PER_SCAN
-
     total_s = 0.0
     timed = {}
     for kind, shape, count in plan_key:
         key = (kind, shape)
         if key not in timed:
-            if kind == "hist" and hist_comm == "reduce_scatter":
-                fn, out_spec = scatter_fn, P(None, "data", None)
-            else:
-                # totals and winner-merge entries are psum-class [W]
-                # collectives under both lowerings
-                fn, out_spec = psum_fn, P()
-            # graftlint: disable=trace-uncached-jit — calibration-scope: lru_cached module factory, one standalone collective timing per distinct (mesh, plan shape, impl) per process, off the round path
+            # graftlint: disable=trace-uncached-jit — calibration-scope: lru_cached module factory, one standalone collective timing per distinct (mesh, plan shape) per process, off the round path
             mapped = jax.jit(
                 jax.shard_map(
-                    fn,
+                    psum_fn,
                     mesh=mesh,
                     in_specs=(P(),),
-                    out_specs=out_spec,
+                    out_specs=P(),
                     check_vma=False,
                 )
             )
@@ -373,11 +359,8 @@ def _calibrated_comm_ms(mesh, hist_comm, plan_key):
                 jax.block_until_ready(mapped(x))
                 best = min(best, time.perf_counter() - t0)
             timed[key] = best
-        # one timing covers one tensor: hist/totals move G and H (2 per
-        # count); a winner-merge scan issues MERGE_COLLECTIVES_PER_SCAN
-        # [W]-shaped collectives per count
-        per_count = MERGE_COLLECTIVES_PER_SCAN if kind == "merge" else 2
-        total_s += timed[key] * per_count * count
+        # one timing covers one tensor: hist and totals move G and H
+        total_s += timed[key] * 2 * count
     return total_s * 1000.0
 
 
@@ -470,26 +453,15 @@ class _TrainingSession:
         self.n_data_shards = (
             int(mesh.shape["data"]) if mesh is not None else 1
         )
-        # data-axis histogram collective (GRAFT_HIST_COMM): resolved ONCE per
-        # session — the round program is traced against it, so flipping the
-        # env mid-job cannot desynchronize shards; a new train() call (new
-        # session, new round-fn closure, hence its own jit cache entry)
-        # picks up the new value.
-        self.hist_comm = hist_comm_impl() if mesh is not None else "psum"
-        # the backend every kernel chooser reads, the histogram's operand
-        # precision and the collective overlap, snapshotted host-side for
-        # the same reason (trace-safety: graftlint trace-env-read forbids
-        # env reads in the traced build path) and threaded into the builders.
+        # the backend every kernel chooser reads and the histogram's operand
+        # precision, snapshotted host-side ONCE per session (trace-safety:
+        # graftlint trace-env-read forbids env reads in the traced build
+        # path; flipping the env mid-job cannot desynchronize shards) and
+        # threaded into the builders.
         # Callers may inject a snapshot: an elastic membership reform rebuilds
         # the session on a smaller mesh but MUST train under the same knobs
         # as the generation it resumes (no mid-job env drift).
         self.hist_knobs = hist_knobs if hist_knobs is not None else resolve_hist_knobs()
-        # reduce_scatter composes with a 'feature' mesh axis: each feature
-        # shard's local histograms psum_scatter along the DATA axis, every
-        # device gain-scans only its doubly-sharded d_local/n_data_shards
-        # column block, and winners merge hierarchically (data-axis
-        # sub-slice merge, then the feature-axis merge) — bit-identical to
-        # the psum lowering on the same mesh (ops/tree_build.build_tree).
         # multi-host: every process holds its own row shard; device arrays are
         # assembled into global arrays over the whole mesh
         self.is_multiprocess = mesh is not None and jax.process_count() > 1
@@ -1301,8 +1273,6 @@ class _TrainingSession:
             feature_axis_name=feature_axis,
             n_feature_shards=self.n_feature_shards,
             d_global=self.train_binned.num_col,
-            hist_comm=self.hist_comm,
-            n_data_shards=self.n_data_shards,
             knobs=self.hist_knobs,
         )
         if cfg.grow_policy == "lossguide":
@@ -1695,7 +1665,6 @@ class _TrainingSession:
             d_local,
             num_bins,
             self.n_data_shards,
-            self.hist_comm,
             subtract,
             trees_per_round=trees_per_round,
             pass_slots=self._pass_slots(subtract),
@@ -1716,15 +1685,10 @@ class _TrainingSession:
         the static structure of a round's tree builds, as they trace it."""
         cfg = self.config
         # columns each data shard histograms: the whole width, unless a
-        # feature axis splits them — under the 2-D reduce_scatter lowering
-        # round_comm_plan further pads/scatters this local width to
-        # d_local/n_data_shards per device and adds the winner-merge
-        # entries of the hierarchical two-axis merge
+        # feature axis splits them
         d_local = self.d_pad // self.n_feature_shards
         num_bins = self.train_binned.num_bins
-        # the builders gate subtraction on the FULL feature width under both
-        # comm lowerings (bit-identity contract) — mirror that here so the
-        # plan matches what actually traces
+        # the builders' own gate, so the plan matches what actually traces
         if cfg.grow_policy == "lossguide":
             from ..ops.lossguide import _subtraction_enabled
 
@@ -1781,7 +1745,7 @@ class _TrainingSession:
         """Isolated latency of one round's data-axis collectives, in ms.
 
         Delegates to the module-level lru_cached factory keyed by
-        (mesh, lowering, plan shapes): a session rebuilt on the same mesh
+        (mesh, plan shapes): a session rebuilt on the same mesh
         with the same static plan — every sequential CV fold, an elastic
         generation that kept its topology, a dart staging rebuild — reuses
         the measured number instead of re-paying the standalone collective
@@ -1797,7 +1761,7 @@ class _TrainingSession:
             for entry in self.hist_comm_plan
         )
         try:
-            return _calibrated_comm_ms(self.mesh, self.hist_comm, plan_key)
+            return _calibrated_comm_ms(self.mesh, plan_key)
         except Exception as e:  # calibration must never break training
             # degrade THIS session to 0.0 only: a raising call is not
             # memoized by lru_cache, so the next session rebuild retries
@@ -1813,9 +1777,9 @@ class _TrainingSession:
             return
         from ..telemetry import REGISTRY, set_round_fields
 
-        labels = {"impl": self.hist_comm}
+        labels = {"impl": "psum"}
         set_round_fields(
-            hist_comm=self.hist_comm,
+            hist_comm="psum",
             hist_comm_bytes=self.hist_comm_bytes_per_round,
         )
         if self._hist_comm_ms is None:

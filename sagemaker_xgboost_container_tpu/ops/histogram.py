@@ -52,7 +52,6 @@ HistKnobs = collections.namedtuple(
     [
         "backend",       # jax.default_backend(): what the four choosers read
         "precision",     # GRAFT_HIST_MM_PREC
-        "comm_overlap",  # GRAFT_HIST_OVERLAP
     ],
 )
 
@@ -77,7 +76,6 @@ def resolve_hist_knobs():
     return HistKnobs(
         backend=jax.default_backend(),
         precision=precision,
-        comm_overlap=_comm_overlap(),
     )
 
 
@@ -116,127 +114,25 @@ def _balanced_chunks(n, chunk_rows):
     return chunk, -(-n // chunk)
 
 
-def _comm_overlap():
-    """GRAFT_HIST_OVERLAP: pipeline the per-level histogram collectives.
-
-    When enabled (default), a tree level's node axis is split into two
-    independent collective -> split-scan batches (overlap_node_batches), so
-    the collective for the second node batch is in flight while the first
-    batch's gain scan runs — XLA's latency-hiding scheduler can overlap
-    the wire time with compute. Values are bit-identical either way: each
-    node's histogram is reduced whole by exactly one collective in the
-    same shard order. ``0`` restores the single fused per-level collective
-    (A/B lever; also the fallback if a backend's scheduler serializes the
-    split collectives poorly).
-    """
-    # graftlint: disable=trace-env-read — direct-caller fallback only;
-    # sessions snapshot this via resolve_hist_knobs() at build time
-    return os.environ.get("GRAFT_HIST_OVERLAP", "1") == "1"
-
-
-def overlap_node_batches(num_nodes, enabled):
-    """Node-axis batching schedule for the pipelined level collective.
-
-    Returns the list of contiguous node slices whose histograms are
-    reduced (and gain-scanned) as independent collective -> scan chains.
-    With overlap disabled, or fewer than 2 nodes, the whole level is one
-    batch — the exact dataflow of the unpipelined path.
-    """
-    if not enabled or num_nodes < 2:
-        return [slice(0, num_nodes)]
-    half = num_nodes // 2
-    return [slice(0, half), slice(half, num_nodes)]
-
-
-def apply_hist_collective(G, H, axis_name, comm, axis_size):
-    """Reduce (G, H) level histograms across the data axis.
-
-    The collective tail of :func:`level_histogram`, split out so the
-    builders can issue it per node batch (overlap_node_batches): ``psum``
-    allreduces the full payload, ``reduce_scatter`` psum_scatters along the
-    feature dim (scatter_histograms). No-op when ``axis_name`` is None.
-    Reducing a node-axis slice is bit-identical to reducing the whole
-    level: both collectives sum the same per-node payloads in the same
-    shard order.
+def apply_hist_collective(G, H, axis_name):
+    """Reduce (G, H) level histograms across the data axis: a ``psum``,
+    the one collective the level histograms have, once a level (or a
+    loss-guided pass). The collective tail of :func:`level_histogram`, and
+    what a builder that holds local histograms calls itself. No-op when
+    ``axis_name`` is None.
     """
     if axis_name is None:
         return G, H
     with stage(STAGE_HIST_ALLREDUCE):
-        if comm == "reduce_scatter":
-            return scatter_histograms(G, H, axis_name, axis_size)
         return jax.lax.psum(G, axis_name), jax.lax.psum(H, axis_name)
 
 
-def hist_comm_impl():
-    """Cross-shard histogram collective for the data axis (GRAFT_HIST_COMM).
-
-    * ``psum`` (default): allreduce the full [W, d, B] grad+hess histograms
-      to every device; every device then runs the identical split scan.
-    * ``reduce_scatter``: ``lax.psum_scatter`` along the data axis — each
-      device receives the globally summed histograms for only its
-      d/axis_size feature slice and scans just that slice; winners merge
-      across shards afterwards (LightGBM's reduce-scatter histogram
-      aggregation, Ke et al. 2017, transplanted onto the SPMD round).
-      Roughly halves collective wire bytes (ring allreduce moves
-      2(p-1)/p x payload, reduce-scatter (p-1)/p) and divides split-scan
-      FLOPs by the axis size.
-    """
-    v = os.environ.get("GRAFT_HIST_COMM", "psum")
-    if v not in ("psum", "reduce_scatter"):
-        raise ValueError(
-            "Unknown GRAFT_HIST_COMM=%r; expected psum|reduce_scatter" % v
-        )
-    return v
-
-
 def padded_feature_width(d, axis_size):
-    """Features padded up to a multiple of the data-axis size so the
-    reduce-scatter slice boundary is static and every shard owns an equal
-    contiguous column slice. The padded columns carry all-zero histograms
-    and zero cut counts, so they can never win a split."""
+    """Features padded up to a multiple of the `feature` mesh axis's size,
+    so that every column shard owns an equal contiguous slice. The padded
+    columns carry all-zero histograms and zero cut counts, so they can
+    never win a split."""
     return -(-d // axis_size) * axis_size
-
-
-def scatter_histograms(G, H, axis_name, axis_size):
-    """psum_scatter (G, H) [W, d, B] along the feature dim of the data axis.
-
-    Returns ([W, d_pad/axis_size, B], same) — the globally summed histograms
-    for this shard's contiguous feature slice. Values are the same sums the
-    full psum would produce for those columns (XLA reduces both collectives
-    in rank order), so split decisions downstream stay bit-identical.
-    ``d`` is whatever column width the caller histograms — the full matrix
-    on a 1-D mesh, or a feature shard's d_local slice on a 2-D (data x
-    feature) mesh, where the per-shard padding of d_local keeps the
-    doubly-sharded slice boundary static.
-    """
-    d = G.shape[1]
-    d_pad = padded_feature_width(d, axis_size)
-    if d_pad != d:
-        pad = [(0, 0), (0, d_pad - d), (0, 0)]
-        G = jnp.pad(G, pad)
-        H = jnp.pad(H, pad)
-    G = jax.lax.psum_scatter(G, axis_name, scatter_dimension=1, tiled=True)
-    H = jax.lax.psum_scatter(H, axis_name, scatter_dimension=1, tiled=True)
-    return G, H
-
-
-def _wire_ratio(comm, axis_size):
-    """Per-device wire bytes per logical payload byte for a ring collective:
-    allreduce = reduce-scatter + all-gather = 2(p-1)/p; reduce-scatter alone
-    = (p-1)/p. The bytes-per-round formula in docs/DESIGN.md §Communication
-    is this ratio times the payload size."""
-    p = axis_size
-    if p <= 1:
-        return 0.0
-    frac = (p - 1) / p
-    return 2.0 * frac if comm == "psum" else frac
-
-
-# data-axis collectives per winner-merge scan batch under reduce_scatter:
-# broadcast_node_totals psums g and h (2), combine_splits_across_shards
-# runs pmax(gain), pmin(tie-break candidate) and 3 selection psums
-# (feature, bin, default_left) — 7 [W]-shaped collectives in total
-MERGE_COLLECTIVES_PER_SCAN = 7
 
 
 def round_hist_levels(grow_policy, max_depth, max_leaves, subtract, pass_slots=1):
@@ -262,68 +158,41 @@ def round_comm_plan(
     d,
     num_bins,
     axis_size,
-    comm,
     subtract,
     trees_per_round=1, pass_slots=1,
 ):
     """Static per-round collective plan for the data axis.
 
     Returns ``(entries, bytes_per_round)`` where each entry is
-    ``{"kind": "hist"|"totals"|"merge", "shape": local payload shape,
+    ``{"kind": "hist"|"totals", "shape": local payload shape,
     "count": n, "bytes": wire bytes for all n collectives}``.
     ``bytes_per_round`` feeds the ``hist_comm_bytes_total`` counter; the
     entry list feeds the latency calibration (one timing per distinct
-    shape). ``hist`` entries carry the G and H f32 histogram pair (wire
-    bytes = payload x ring ratio, _wire_ratio); ``d`` is the width each
-    data shard histograms — the feature-shard-LOCAL width on a 2-D mesh,
-    which reduce_scatter pads and scatters to d/axis_size per device.
-    Under reduce_scatter the plan also carries the ``merge`` entries of
-    the winner merge (MERGE_COLLECTIVES_PER_SCAN [W]-shaped psum-class
-    collectives per gain-scan: the node-totals broadcast plus the
-    cross-shard split combine), so ``hist_comm_bytes_total`` and the
-    latency calibration stay truthful for the scattered lowering — 1-D
-    and the 2-D (data x feature) composition alike.
+    shape). ``hist`` entries carry the G and H f32 histogram pair; wire
+    bytes are the payload times a ring allreduce's 2(p-1)/p (reduce-scatter
+    + all-gather; docs/DESIGN.md §Communication). ``d`` is the width each
+    data shard histograms: the feature-shard-LOCAL width on a 2-D mesh.
     """
     if axis_size <= 1:
         return [], 0
-    d_eff = padded_feature_width(d, axis_size) if comm == "reduce_scatter" else d
-    ratio = _wire_ratio(comm, axis_size)
-    psum_ratio = _wire_ratio("psum", axis_size)
-    hist_widths = round_hist_levels(grow_policy, max_depth, max_leaves, subtract, pass_slots)
-    totals = []
-    if grow_policy == "lossguide":
-        # winner-merge scan widths (reduce_scatter only): the root, then
-        # both fresh children a split step
-        merge_widths = [(1, 1)] + ([(2, max_leaves - 1)] if max_leaves > 1 else [])
-    else:
-        merge_widths = [(2**level, 1) for level in range(max_depth)]  # full level scans
-        totals.append((2**max_depth, 1))                 # last-level node totals
+    ratio = 2.0 * (axis_size - 1) / axis_size
     entries = []
     total_bytes = 0.0
-    for W, count in hist_widths:
+    for W, count in round_hist_levels(grow_policy, max_depth, max_leaves, subtract, pass_slots):
         count *= trees_per_round
-        payload = 2 * W * d_eff * num_bins * 4           # G + H, f32
-        b = payload * ratio * count
+        b = 2 * W * d * num_bins * 4 * ratio * count     # G + H, f32
         entries.append(
-            {"kind": "hist", "shape": (W, d_eff, num_bins), "count": count,
+            {"kind": "hist", "shape": (W, d, num_bins), "count": count,
              "bytes": b}
         )
         total_bytes += b
-    for W, count in totals:
-        count *= trees_per_round
-        b = 2 * W * 4 * psum_ratio * count               # totals always psum
+    if grow_policy != "lossguide":
+        W = 2**max_depth                                 # last-level node totals
+        b = 2 * W * 4 * ratio * trees_per_round
         entries.append(
-            {"kind": "totals", "shape": (W,), "count": count, "bytes": b}
+            {"kind": "totals", "shape": (W,), "count": trees_per_round, "bytes": b}
         )
         total_bytes += b
-    if comm == "reduce_scatter":
-        for W, count in merge_widths:
-            count *= trees_per_round
-            b = MERGE_COLLECTIVES_PER_SCAN * W * 4 * psum_ratio * count
-            entries.append(
-                {"kind": "merge", "shape": (W,), "count": count, "bytes": b}
-            )
-            total_bytes += b
     return entries, int(total_bytes)
 
 
@@ -346,8 +215,6 @@ def level_histogram(
     num_nodes,
     num_bins,
     axis_name=None,
-    comm="psum",
-    axis_size=1,
     knobs=None,
     impl=None,
     class_vmap=False,
@@ -362,10 +229,6 @@ def level_histogram(
       num_nodes: static int — number of nodes at this level (2**level).
       num_bins: static int — histogram width per feature (max_bin + 1).
       axis_name: mesh axis to psum over, or None on a single device.
-      comm: cross-shard lowering (hist_comm_impl): "psum" allreduces the
-        full histograms; "reduce_scatter" psum_scatters them along the
-        feature dim so each shard gets only its d/axis_size column slice.
-      axis_size: static size of ``axis_name`` (required for reduce_scatter).
       knobs: the session's :class:`HistKnobs` snapshot; traced production
         code must thread it (trace-safety). None, for direct callers (unit
         tests, probes): the process's backend and ``bf16x2``.
@@ -378,8 +241,7 @@ def level_histogram(
         what it always traced.
 
     Returns:
-      (G, H): f32 [num_nodes, d, num_bins] for psum / no axis;
-      f32 [num_nodes, padded_d/axis_size, num_bins] for reduce_scatter.
+      (G, H): f32 [num_nodes, d, num_bins].
     """
     if impl is None:
         impl = choose_hist_impl(_backend(knobs))
@@ -399,7 +261,7 @@ def level_histogram(
         raise ValueError(
             "unknown level_histogram builder: {!r}; expected flat|pallas".format(impl)
         )
-    return apply_hist_collective(G, H, axis_name, comm, axis_size)
+    return apply_hist_collective(G, H, axis_name)
 
 
 def node_totals(grad, hess, node_local, num_nodes, axis_name=None, knobs=None,

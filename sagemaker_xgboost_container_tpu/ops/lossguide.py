@@ -61,7 +61,6 @@ from .histogram import (
     _operand_rows,
     apply_hist_collective,
     level_histogram,
-    padded_feature_width,
     subtraction_enabled,
 )
 from ..telemetry.device import (
@@ -73,12 +72,10 @@ from ..telemetry.device import (
     stage,
 )
 from .split import (
-    broadcast_node_totals,
     column_shard_helpers,
     combine_splits_across_shards,
     find_best_splits,
     leaf_weight,
-    shard_feature_slice,
 )
 from .tree_build import choose_route_impl, row_bin_lookup
 
@@ -125,10 +122,7 @@ def _subtraction_enabled(max_leaves, d_hist, num_bins):
     from the parent's cached histogram, so a pass holds twice the leaves.
     Needs a [2*max_leaves-1, d_hist, B] f32 cache x2 and, beside it, the
     store of left children in the same shape, so gated by the shared cap
-    on both. Callers pass the FULL feature width regardless of the
-    GRAFT_HIST_COMM lowering (same-decision-both-lowerings bit-identity
-    contract — see ops.tree_build._subtraction_enabled); under
-    reduce_scatter the resident cache is only the d/axis_size slice."""
+    on both."""
     return subtraction_enabled(2 * 2 * (2 * max_leaves - 1) * d_hist * num_bins * 4)
 
 
@@ -156,8 +150,6 @@ def build_tree_lossguide(
     feature_axis_name=None,
     n_feature_shards=1,
     d_global=None,
-    hist_comm="psum",
-    n_data_shards=1,
     knobs=None,
 ):
     """Grow one leaf-wise tree. Returns (tree arrays dict, row_out [n]).
@@ -166,30 +158,13 @@ def build_tree_lossguide(
     ``hist_passes``: int32 ``[passes, slots filled, slots used]`` of the
     step loop (the root's call apart), which ``pack_tree`` carries to the
     host in the round's one array; max_depth=0 means
-    unbounded depth (bounded by max_leaves - 1). ``hist_comm`` selects the
-    data-axis collective (see ops.tree_build.build_tree): reduce_scatter
-    scans only this shard's feature slice per step and merges winners into
-    the candidate store with bit-identical tie-breaking. ``knobs``: the
+    unbounded depth (bounded by max_leaves - 1). ``knobs``: the
     session's ``ops.histogram.HistKnobs`` snapshot (trace-safety; None,
     for direct unit-test/probe callers, chooses from the process's backend).
     """
     n, d = bins.shape
     max_nodes = 2 * max_leaves - 1
     depth_cap = max_depth if max_depth > 0 else max_leaves
-    reduce_scatter = hist_comm == "reduce_scatter" and axis_name is not None
-    # ``d`` is the feature-shard-LOCAL width on a 2-D (data x feature)
-    # mesh, so the reduce_scatter slicing composes with the feature axis —
-    # see ops.tree_build.build_tree: each device scans a doubly-sharded
-    # d_local/n_data_shards block and winners merge hierarchically.
-    d_scan = padded_feature_width(d, n_data_shards) // n_data_shards if reduce_scatter else d
-    data_shard = jax.lax.axis_index(axis_name) if reduce_scatter else None
-
-    def _scan_slice(arr):
-        """Per-feature scan input -> this shard's slice (reduce_scatter)."""
-        if not reduce_scatter or arr is None:
-            return arr
-        return shard_feature_slice(arr, data_shard, d_scan, n_data_shards)
-
     # feature-axis sharding: this shard holds columns [feat_shard*d,
     # (feat_shard+1)*d) of the global matrix; candidate splits are combined
     # across shards (combine_splits_across_shards) so the candidate store —
@@ -203,26 +178,6 @@ def build_tree_lossguide(
     d_draw, _pad_cols, _local_cols = column_shard_helpers(
         feat_shard, d, n_feature_shards, d_global
     )
-
-    def _combine(splits):
-        if reduce_scatter:
-            # data-axis winner merge (shared with the feature-axis path);
-            # totals were broadcast from data-shard 0 before the scan. On a
-            # 2-D mesh this yields feature-shard-local ids, globalized by
-            # the feature-axis merge below (hierarchical two-axis merge).
-            splits = combine_splits_across_shards(
-                splits, data_shard, d_scan, axis_name
-            )
-        if feature_axis_name is None:
-            return splits
-        return combine_splits_across_shards(splits, feat_shard, d, feature_axis_name)
-
-    def _scan_totals(G, H):
-        """Pre-scan node totals under reduce_scatter (bit-identical to the
-        psum lowering's feature-0 derivation); None otherwise."""
-        if not reduce_scatter:
-            return None
-        return broadcast_node_totals(G, H, data_shard, axis_name)
 
     # colsample_bylevel: one Bernoulli feature mask per DEPTH, shared by all
     # nodes at that depth (the leaf-wise analog of tree_build's per-level
@@ -295,18 +250,19 @@ def build_tree_lossguide(
         s = find_best_splits(
             Gb,
             Hb,
-            _scan_slice(num_cuts),
+            num_cuts,
             reg_lambda=reg_lambda,
             alpha=alpha,
             gamma=gamma,
             min_child_weight=min_child_weight,
-            feature_mask=_scan_slice(mask_b),
-            monotone=_scan_slice(monotone),
-            totals=_scan_totals(Gb, Hb),
+            feature_mask=mask_b,
+            monotone=monotone,
         )
         # cross-shard combine: the candidate store (and therefore every
         # step's argmax) must be identical on all shards, with GLOBAL ids
-        return _combine(s)
+        if feature_axis_name is None:
+            return s
+        return combine_splits_across_shards(s, feat_shard, d, feature_axis_name)
 
     def _child_splits(G_ab, H_ab, mask, depth_ab):
         """Candidates of the two fresh children from their (reduced)
@@ -317,15 +273,13 @@ def build_tree_lossguide(
             gains = jnp.where(depth_ab < depth_cap, splits["gain"], -jnp.inf)
         return splits, gains
 
-    # full-width gate under both lowerings (bit-identity: same build path)
     subtract = _subtraction_enabled(max_leaves, d, num_bins)
     hist_cache = None
     if subtract:
-        # per-node histogram cache (filled as leaves are created); stores
-        # only this shard's feature slice under reduce_scatter
+        # per-node histogram cache (filled as leaves are created)
         hist_cache = (
-            jnp.zeros((max_nodes, d_scan, num_bins), jnp.float32),
-            jnp.zeros((max_nodes, d_scan, num_bins), jnp.float32),
+            jnp.zeros((max_nodes, d, num_bins), jnp.float32),
+            jnp.zeros((max_nodes, d, num_bins), jnp.float32),
         )
     # the store of children's histograms a pass fills and the split steps
     # read. With subtraction a leaf takes one node slot of a pass (its left
@@ -338,8 +292,8 @@ def build_tree_lossguide(
     in_pass = pass_leaves(max_leaves, subtract)
     store_leaves = max_nodes if subtract else in_pass
     store = (
-        jnp.zeros((store_leaves * kids, d_scan, num_bins), jnp.float32),
-        jnp.zeros((store_leaves * kids, d_scan, num_bins), jnp.float32),
+        jnp.zeros((store_leaves * kids, d, num_bins), jnp.float32),
+        jnp.zeros((store_leaves * kids, d, num_bins), jnp.float32),
     )
     # the store entry that holds a leaf's children, -1 where none does
     entry = jnp.full(max_nodes, -1, jnp.int32)
@@ -350,8 +304,7 @@ def build_tree_lossguide(
     with stage(STAGE_HIST):
         G, H = level_histogram(
             bins, grad, hess, jnp.zeros(n, jnp.int32), 1, num_bins,
-            axis_name=axis_name, comm=hist_comm, axis_size=n_data_shards,
-            knobs=knobs,
+            axis_name=axis_name, knobs=knobs,
         )
         if subtract:
             hist_cache = (hist_cache[0].at[0].set(G[0]), hist_cache[1].at[0].set(H[0]))
@@ -479,7 +432,7 @@ def build_tree_lossguide(
                 *level_histogram(
                     bins, grad, hess, slot_of_row, in_pass * kids, num_bins, knobs=knobs
                 ),
-                axis_name, hist_comm, n_data_shards,
+                axis_name,
             )
             held = leaves if subtract else jnp.arange(in_pass, dtype=jnp.int32)
             by_slot = [built.reshape((in_pass, kids) + built.shape[1:]) for built in (G, H)]

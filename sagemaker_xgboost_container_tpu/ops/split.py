@@ -28,21 +28,12 @@ def combine_splits_across_shards(splits, feat_shard, d_local, feature_axis_name)
     default_left are psum-broadcast so every shard ends with identical
     global split decisions.
 
-    Two callers share this merge:
-
-    * the *feature* mesh axis (column-sharded data — the reference's
-      vestigial dsplit=col done as SPMD). ``g_total``/``h_total`` are
-      already identical on every shard (every row lands in exactly one bin
-      of every feature), so they pass through (``select_totals=False``).
-    * the *data* axis under ``GRAFT_HIST_COMM=reduce_scatter``
-      (ops/histogram.scatter_histograms): every shard holds all columns but
-      scanned only its psum_scattered feature slice. Its node totals must
-      come through ``broadcast_node_totals`` BEFORE the scan (every shard's
-      gains then use the identical totals), after which the passthrough
-      here is exact on every shard.
-
-    Used by both the depthwise (ops/tree_build.py) and leaf-wise
-    (ops/lossguide.py) builders.
+    The *feature* mesh axis (column-sharded data — the reference's
+    vestigial dsplit=col done as SPMD) is this merge's only caller, from
+    both the depthwise (ops/tree_build.py) and leaf-wise (ops/lossguide.py)
+    builders. ``g_total``/``h_total`` are already identical on every shard
+    (every row lands in exactly one bin of every feature), so they pass
+    through.
     """
     global_feat = splits["feature"] + feat_shard * d_local
     gain = splits["gain"]
@@ -65,66 +56,6 @@ def combine_splits_across_shards(splits, feat_shard, d_local, feature_axis_name)
         "g_total": splits["g_total"],
         "h_total": splits["h_total"],
     }
-
-
-def concat_node_splits(parts):
-    """Concatenate per-node-batch :func:`find_best_splits` results.
-
-    The gain scan is per-node independent, so scanning a level in node
-    batches (ops/histogram.overlap_node_batches — the pipelined-collective
-    schedule) and concatenating along the node axis is bit-identical to one
-    whole-level scan. A single batch passes through untouched.
-    """
-    if len(parts) == 1:
-        return parts[0]
-    return {
-        k: jnp.concatenate([p[k] for p in parts], axis=0) for k in parts[0]
-    }
-
-
-def broadcast_node_totals(G, H, shard, axis_name):
-    """Per-node (sum g, sum h) for the reduce_scatter lowering.
-
-    The psum lowering derives node totals inside the scan as "sum over the
-    bins of feature 0" — every row lands in exactly one bin of every
-    feature, so any feature's bins sum to the node total *mathematically*,
-    but NOT bitwise (different values, different accumulation). Under
-    reduce_scatter each shard's slice starts at a different global feature,
-    so totals must come from the shard owning global feature 0 and
-    psum-broadcast (adding exact zeros) BEFORE the gain scan; every shard's
-    gains then use totals bit-identical to the psum lowering's.
-
-    On a 2-D (data x feature) mesh ``shard``/``axis_name`` are the DATA
-    shard/axis and the broadcast runs within each feature shard: its
-    data-shard 0 holds the feature shard's local column 0 after the
-    scatter — exactly the column the psum lowering's scan derives totals
-    from on that feature shard — so the composed lowering's gains stay
-    bit-identical to psum on the same mesh.
-    """
-    own0 = shard == 0
-    g = jnp.where(own0, G[:, 0, :].sum(axis=-1), 0.0)
-    h = jnp.where(own0, H[:, 0, :].sum(axis=-1), 0.0)
-    return jax.lax.psum(g, axis_name), jax.lax.psum(h, axis_name)
-
-
-def shard_feature_slice(arr, shard, d_local, axis_size):
-    """This shard's contiguous feature slice of a per-feature array.
-
-    ``arr`` is [..., d] over the real feature width; it zero-pads to
-    ``d_local * axis_size`` (ops/histogram.padded_feature_width) and slices
-    ``[shard * d_local, (shard + 1) * d_local)``. Zero padding is inert for
-    every consumer: num_cuts 0 = no legal split bins, feature_mask 0 =
-    masked, monotone 0 = unconstrained. Companion of scatter_histograms —
-    the scan inputs must slice exactly like the scattered histograms.
-    """
-    d = arr.shape[-1]
-    d_pad = d_local * axis_size
-    if d_pad != d:
-        pad = [(0, 0)] * (arr.ndim - 1) + [(0, d_pad - d)]
-        arr = jnp.pad(arr, pad)
-    start = (0,) * (arr.ndim - 1) + (shard * d_local,)
-    sizes = arr.shape[:-1] + (d_local,)
-    return jax.lax.dynamic_slice(arr, start, sizes)
 
 
 def column_shard_helpers(feat_shard, d_local, n_feature_shards, d_global):
@@ -180,7 +111,6 @@ def find_best_splits(
     min_child_weight=1.0,
     feature_mask=None,
     monotone=None,
-    totals=None,
     gathers=True,
 ):
     """Best (feature, bin, default_dir, gain) per node at one level.
@@ -192,10 +122,6 @@ def find_best_splits(
       feature_mask: optional f32/bool [d] colsample mask, or [W, d] per-node
         mask (interaction constraints); 1 = usable.
       monotone: optional i32 [d] in {-1, 0, 1} monotone constraints.
-      totals: optional (g_total, h_total) f32 [W] pair overriding the
-        feature-0 derivation — required when G/H are a reduce_scattered
-        feature slice (broadcast_node_totals), where local feature 0 is a
-        different global feature on every shard.
       gathers: static; how the winner's gain and default direction are read
         at ``best_idx``. True: two ``take_along_axis`` gathers, a node long.
         False, for a build mapped over the class trees of a round: a maximum
@@ -210,12 +136,9 @@ def find_best_splits(
     """
     W, d, B = G.shape
     nbins = B - 1  # data bins
-    if totals is None:
-        # node totals: every row lands in exactly one bin of feature 0
-        g_total = G[:, 0, :].sum(axis=-1)
-        h_total = H[:, 0, :].sum(axis=-1)
-    else:
-        g_total, h_total = totals
+    # node totals: every row lands in exactly one bin of feature 0
+    g_total = G[:, 0, :].sum(axis=-1)
+    h_total = H[:, 0, :].sum(axis=-1)
 
     g_miss = G[:, :, nbins]  # [W, d]
     h_miss = H[:, :, nbins]

@@ -17,12 +17,9 @@ import jax
 import jax.numpy as jnp
 
 from .histogram import (
-    _comm_overlap,
     apply_hist_collective,
     level_histogram,
     node_totals,
-    overlap_node_batches,
-    padded_feature_width,
     subtraction_enabled,
 )
 from ..telemetry.device import (
@@ -35,13 +32,10 @@ from ..telemetry.device import (
     stage,
 )
 from .split import (
-    broadcast_node_totals,
     column_shard_helpers,
     combine_splits_across_shards,
-    concat_node_splits,
     find_best_splits,
     leaf_weight,
-    shard_feature_slice,
 )
 
 MIN_SPLIT_LOSS = 1e-6  # xgboost kRtEps
@@ -231,12 +225,7 @@ def _subtraction_enabled(max_depth, d_hist, num_bins):
     """Histogram subtraction: build only left children, derive right ones as
     parent - left (libxgboost's standard sibling trick) — halves histogram
     work per level. Needs the previous level's histograms cached
-    ([2**(L-1), d_hist, B] f32 x2); gated by the shared memory cap.
-    Callers pass the FULL feature width for ``d_hist`` regardless of the
-    GRAFT_HIST_COMM lowering, so psum and reduce_scatter always make the
-    same subtraction decision and commit bit-identical trees; under
-    reduce_scatter the cache actually resident is only the d/axis_size
-    slice (1/axis_size of this estimate)."""
+    ([2**(L-1), d_hist, B] f32 x2); gated by the shared memory cap."""
     if max_depth < 2:
         return False
     return subtraction_enabled(2 * (2 ** (max_depth - 1)) * d_hist * num_bins * 4)
@@ -265,8 +254,6 @@ def build_tree(
     feature_axis_name=None,
     n_feature_shards=1,
     d_global=None,
-    hist_comm="psum",
-    n_data_shards=1,
     knobs=None,
     class_vmap=False,
 ):
@@ -284,22 +271,6 @@ def build_tree(
     max-gain, and row routing decisions (which need the winning feature's
     bins) are computed by the owning shard and psum-broadcast. Emitted
     feature ids are global.
-
-    hist_comm: data-axis collective lowering (ops/histogram.hist_comm_impl).
-    Under ``reduce_scatter`` each shard receives the globally summed
-    histograms for only its d/n_data_shards feature slice, scans that slice,
-    and the per-shard winners merge through the same
-    combine_splits_across_shards machinery the feature axis uses (the data
-    axis IS a feature axis for the duration of the split scan). On a 2-D
-    (data x feature) mesh the two compose: ``bins`` already holds only this
-    feature shard's d_local columns, the psum_scatter slices those again
-    along the data axis (each device scans d_local/n_data_shards columns),
-    and winners merge hierarchically — the data-axis merge produces
-    feature-shard-local ids (offset ``data_shard * d_scan``), which the
-    existing feature-axis merge then globalizes (offset
-    ``feat_shard * d_local``). Tie-breaking (max gain, lowest global
-    feature id) and node totals are bit-identical to the psum lowering on
-    the same mesh, so committed trees match bitwise.
 
     knobs: the session's ``ops.histogram.HistKnobs`` snapshot (trace-safety:
     the traced build must not read env; None, for direct unit-test/probe
@@ -320,14 +291,6 @@ def build_tree(
     same bits under either lowering, packed or not.
     """
     n, d = bins.shape
-    reduce_scatter = hist_comm == "reduce_scatter" and axis_name is not None
-    # reduce_scatter: the scan runs on this shard's feature slice only.
-    # ``d`` is already the feature-shard-LOCAL width on a 2-D (data x
-    # feature) mesh, so the two slicings compose: each device scans a
-    # doubly-sharded d_local/n_data_shards block and the winners merge
-    # hierarchically (data-axis sub-slice merge, then the feature axis).
-    d_scan = padded_feature_width(d, n_data_shards) // n_data_shards if reduce_scatter else d
-    data_shard = jax.lax.axis_index(axis_name) if reduce_scatter else None
     max_nodes = max_nodes_for_depth(max_depth)
     route_impl = (
         choose_route_impl(knobs.backend, d) if knobs is not None else None
@@ -340,9 +303,9 @@ def build_tree(
             table, local_safe, impl=choose_table_impl(table_backend, table.shape[0])
         )
 
-    # every id a merged split's feature field can hold: the scanned width
-    # (padded under reduce_scatter) times the feature shards
-    feature_ids = d_scan * n_data_shards if reduce_scatter else d
+    # every id a merged split's feature field can hold: the local width
+    # times the feature shards
+    feature_ids = d
     if feature_axis_name is not None:
         feature_ids *= jax.lax.axis_size(feature_axis_name)
     word_bin_bits = split_word_bin_bits(feature_ids, num_bins)
@@ -379,25 +342,9 @@ def build_tree(
         jax.lax.axis_index(feature_axis_name) if feature_axis_name is not None else None
     )
 
-    # the subtraction DECISION is gated on the full feature width under both
-    # lowerings so psum and reduce_scatter always take the same build path —
-    # a split gate (slice width under reduce_scatter) would let the two
-    # commit bitwise-divergent trees in the (cap/p, cap] window, breaking
-    # the bit-identity contract. The resident cache under reduce_scatter is
-    # still only the [W/2, d_scan, B] slice (1/p of the gate's estimate).
     subtract = _subtraction_enabled(max_depth, d, num_bins)
-    G_cache = H_cache = None      # previous level's [W/2, d_scan, B] histograms
+    G_cache = H_cache = None      # previous level's [W/2, d, B] histograms
     parent_leaf = None            # previous level's becomes_leaf [W/2]
-
-    # pipelined level collectives (GRAFT_HIST_OVERLAP): the node axis of a
-    # level splits into independent collective -> gain-scan batches, so the
-    # second batch's psum/psum_scatter is issued before the first batch's
-    # scan consumes its result — XLA can overlap wire time with compute.
-    # Per-node payloads reduce whole either way: bit-identical trees.
-    overlap = (
-        (knobs.comm_overlap if knobs is not None else _comm_overlap())
-        and axis_name is not None
-    )
 
     for level in range(max_depth + 1):
         first = 2**level - 1
@@ -431,62 +378,35 @@ def build_tree(
             if subtract and level > 0:
                 # histogram only the LEFT child of each sibling pair; the right
                 # one is parent - left. Parents that leafed routed no rows to
-                # their children, so their pair contribution is zeroed. The
-                # local accumulation runs ONCE over the rows; the collective is
-                # issued per node batch (overlap schedule) on slices of it.
+                # their children, so their pair contribution is zeroed.
                 active = node_local >= 0
                 is_left = (node_local % 2) == 0
                 left_local = jnp.where(active & is_left, node_local // 2, -1)
-                Gl_loc, Hl_loc = level_histogram(
+                Gl, Hl = level_histogram(
                     bins, grad, hess, left_local, width // 2, num_bins,
                     knobs=knobs, class_vmap=class_vmap,
                 )
+                # (after the kernel call, before the collective: the order the
+                # pinned one-tree program and the mesh program trace)
                 keep = ~parent_leaf
-
-                def _batch_hists(psl):
-                    # parent slice [a, b) -> level nodes [2a, 2b), interleaved
-                    # (left child 2i, right child 2i+1) from the reduced left
-                    # histograms + the cached (already reduced) parent slice
-                    Gl, Hl = apply_hist_collective(
-                        Gl_loc[psl], Hl_loc[psl], axis_name, hist_comm,
-                        n_data_shards,
-                    )
-                    kp = keep[psl]
-                    Gp = jnp.where(kp[:, None, None], G_cache[psl], 0.0)
-                    Hp = jnp.where(kp[:, None, None], H_cache[psl], 0.0)
-                    Gr = Gp - Gl
-                    Hr = Hp - Hl
-                    Gb = jnp.stack([Gl, Gr], axis=1).reshape(
-                        2 * Gl.shape[0], Gl.shape[1], -1
-                    )
-                    Hb = jnp.stack([Hl, Hr], axis=1).reshape(
-                        2 * Hl.shape[0], Hl.shape[1], -1
-                    )
-                    return Gb, Hb
-
-                batch_hists = [
-                    (slice(psl.start * 2, psl.stop * 2),) + _batch_hists(psl)
-                    for psl in overlap_node_batches(width // 2, overlap)
-                ]
+                Gl, Hl = apply_hist_collective(Gl, Hl, axis_name)
+                Gp = jnp.where(keep[:, None, None], G_cache, 0.0)
+                Hp = jnp.where(keep[:, None, None], H_cache, 0.0)
+                Gr = Gp - Gl
+                Hr = Hp - Hl
+                # level nodes interleaved: left child 2i, right child 2i + 1
+                G = jnp.stack([Gl, Gr], axis=1).reshape(width, Gl.shape[1], -1)
+                H = jnp.stack([Hl, Hr], axis=1).reshape(width, Hl.shape[1], -1)
             else:
-                G_loc, H_loc = level_histogram(
-                    bins, grad, hess, node_local, width, num_bins, knobs=knobs,
-                    class_vmap=class_vmap,
+                G, H = apply_hist_collective(
+                    *level_histogram(
+                        bins, grad, hess, node_local, width, num_bins, knobs=knobs,
+                        class_vmap=class_vmap,
+                    ),
+                    axis_name,
                 )
-                batch_hists = [
-                    (nsl,)
-                    + apply_hist_collective(
-                        G_loc[nsl], H_loc[nsl], axis_name, hist_comm,
-                        n_data_shards,
-                    )
-                    for nsl in overlap_node_batches(width, overlap)
-                ]
             if subtract:
-                if len(batch_hists) == 1:
-                    G_cache, H_cache = batch_hists[0][1], batch_hists[0][2]
-                else:
-                    G_cache = jnp.concatenate([b[1] for b in batch_hists], axis=0)
-                    H_cache = jnp.concatenate([b[2] for b in batch_hists], axis=0)
+                G_cache, H_cache = G, H
         with stage(STAGE_SPLIT_SCAN):
             # shared column-draw convention (ops/split.py): draws over the REAL
             # global feature count, padded then sliced per shard
@@ -523,62 +443,22 @@ def build_tree(
                 ) > 0
                 per_node = _local_cols(node_allowed.astype(jnp.float32))
                 level_mask = per_node if level_mask is None else per_node * level_mask[None, :]
-            def _scan_batch(nsl, Gb, Hb):
-                """Gain-scan one node batch of the level (per-node independent,
-                so batches concatenate bit-identically — concat_node_splits)."""
-                scan_cuts, scan_mask, scan_mono, scan_totals = (
-                    num_cuts, level_mask, monotone, None,
-                )
-                if scan_mask is not None and scan_mask.ndim == 2:
-                    scan_mask = scan_mask[nsl]  # per-node mask rows
-                if reduce_scatter:
-                    # the scan sees only this shard's globally-summed feature
-                    # slice; its per-feature inputs must slice exactly like the
-                    # histograms, and node totals broadcast from shard 0 BEFORE
-                    # the scan so every shard's gains use bit-identical totals
-                    scan_cuts = shard_feature_slice(
-                        num_cuts, data_shard, d_scan, n_data_shards
-                    )
-                    if scan_mask is not None:
-                        scan_mask = shard_feature_slice(
-                            scan_mask, data_shard, d_scan, n_data_shards
-                        )
-                    if scan_mono is not None:
-                        scan_mono = shard_feature_slice(
-                            scan_mono, data_shard, d_scan, n_data_shards
-                        )
-                    scan_totals = broadcast_node_totals(
-                        Gb, Hb, data_shard, axis_name
-                    )
-                s = find_best_splits(
-                    Gb,
-                    Hb,
-                    scan_cuts,
-                    reg_lambda=reg_lambda,
-                    alpha=alpha,
-                    gamma=gamma,
-                    min_child_weight=min_child_weight,
-                    feature_mask=scan_mask,
-                    monotone=scan_mono,
-                    totals=scan_totals,
-                    gathers=not class_vmap,
-                )
-                if reduce_scatter:
-                    # the data axis is a feature axis for the duration of the
-                    # scan: the same winner merge (totals pass through —
-                    # already broadcast)
-                    s = combine_splits_across_shards(
-                        s, data_shard, d_scan, axis_name
-                    )
-                if feature_axis_name is not None:
-                    s = combine_splits_across_shards(
-                        s, feat_shard, d, feature_axis_name
-                    )
-                return s
-
-            splits = concat_node_splits(
-                [_scan_batch(nsl, Gb, Hb) for nsl, Gb, Hb in batch_hists]
+            splits = find_best_splits(
+                G,
+                H,
+                num_cuts,
+                reg_lambda=reg_lambda,
+                alpha=alpha,
+                gamma=gamma,
+                min_child_weight=min_child_weight,
+                feature_mask=level_mask,
+                monotone=monotone,
+                gathers=not class_vmap,
             )
+            if feature_axis_name is not None:
+                splits = combine_splits_across_shards(
+                    splits, feat_shard, d, feature_axis_name
+                )
 
             g_tot, h_tot = splits["g_total"], splits["h_total"]
             weight = leaf_weight(

@@ -23,9 +23,9 @@ _write_lock = threading.Lock()
 
 # Extra fields merged into every ``training.round`` record (see
 # profiling.RoundTimer). Set by the training session for facts only it
-# knows (e.g. the histogram-collective lowering and its per-round wire
-# bytes — GRAFT_HIST_COMM); process-wide like ROUND_STATE, last writer
-# wins, which matches sequential training sessions.
+# knows (e.g. the histogram collective and its per-round wire bytes);
+# process-wide like ROUND_STATE, last writer wins, which matches
+# sequential training sessions.
 _round_fields = {}
 _round_fields_lock = threading.Lock()
 

@@ -7,7 +7,7 @@ catalogues every rule):
 
 * **trace-safety** — functions reachable from ``jax.jit``/``shard_map``
   closures must not read env knobs (resolve at session build time, the
-  PR-4 ``GRAFT_HIST_COMM`` pattern), must not construct un-cached jit
+  ``resolve_hist_knobs`` pattern), must not construct un-cached jit
   wrappers (the per-round re-sketch recompile class), and must not sync to
   host (``.item()``, ``np.asarray`` on device values, ``print``).
 * **concurrency & I/O discipline** — sockets read/accept/connect under a

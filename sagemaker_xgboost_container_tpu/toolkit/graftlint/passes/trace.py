@@ -4,8 +4,9 @@ The jitted round path is the product (PAPER.md: >=5 rounds/sec needs a
 round program that never silently recompiles or syncs to host), and both
 bug classes have shipped before: the PR-4 per-round re-sketch recompile
 (``jax.jit`` constructed per call) and assorted trace-time knob reads that
-PR 4 had to hoist to session build (``GRAFT_HIST_COMM``). This pass makes
-the policy mechanical.
+PR 4 had to hoist to session build (the histogram collective's name, gone
+since PR 45; ``ops/histogram.resolve_hist_knobs`` is the pattern). This
+pass makes the policy mechanical.
 
 **Reachability.** Roots are functions handed to ``jax.jit``/``pjit``/
 ``shard_map`` (as arguments, through ``functools.partial``, through simple
@@ -22,7 +23,7 @@ Rules:
 
 * ``trace-env-read`` — ``os.environ``/``os.getenv``/``env_int``-family
   reads inside a reachable function. Knobs are resolved once at session
-  build time and threaded in (the ``GRAFT_HIST_COMM`` pattern): a
+  build time and threaded in (the ``resolve_hist_knobs`` pattern): a
   trace-time read bakes whatever the env said at first trace into the
   compiled program, so mid-job changes silently do nothing and two shards
   tracing at different times can disagree. The ``env_int``-family helper
@@ -416,7 +417,7 @@ class TraceSafetyPass(object):
                     n.lineno,
                     "env read ({}) inside jit-reachable '{}' — resolve the "
                     "knob at session build time and thread it in (the "
-                    "GRAFT_HIST_COMM pattern, docs/static-analysis.md)".format(
+                    "resolve_hist_knobs pattern, docs/static-analysis.md)".format(
                         hit, info.qual
                     ),
                 )

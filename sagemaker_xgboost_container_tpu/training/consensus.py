@@ -1,7 +1,7 @@
 """Cross-rank consensus guard: prove the mesh agrees on the committed trees.
 
 The distributed contract says every rank commits bit-identical trees (the
-histogram psum/reduce_scatter lowerings are proven equivalent at test time)
+mesh build is proven equal to the one-device build at test time)
 — but nothing *enforced* it at runtime. A diverged rank (flaky HBM bit
 flips, a non-deterministic collective on a misbehaving fabric, version skew
 after a partial restart) silently trains a forked ensemble: rank 0 saves

@@ -155,8 +155,8 @@ class RoundTimer:
                     "round_ms": round(elapsed * 1000, 3),
                     "phases_ms": phases_ms,
                 }
-                # session-owned extras (hist_comm lowering + per-round
-                # collective bytes/ms on a mesh — see booster.py)
+                # session-owned extras (hist_comm + per-round collective
+                # bytes/ms on a mesh — see booster.py)
                 fields.update(get_round_fields())
                 if self.fold is not None:
                     fields["fold"] = self.fold

@@ -32,11 +32,11 @@ def install():
 
     collective = histogram.apply_hist_collective
 
-    def without_last_shard(G, H, axis_name, comm, axis_size):
+    def without_last_shard(G, H, axis_name):
         if axis_name is not None:
-            keep = jax.lax.axis_index(axis_name) != axis_size - 1
+            keep = jax.lax.axis_index(axis_name) != jax.lax.axis_size(axis_name) - 1
             G, H = jnp.where(keep, G, 0.0), jnp.where(keep, H, 0.0)
-        return collective(G, H, axis_name, comm, axis_size)
+        return collective(G, H, axis_name)
 
     for module in (histogram, tree_build, lossguide):
         module.apply_hist_collective = without_last_shard

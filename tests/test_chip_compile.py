@@ -298,7 +298,6 @@ def test_rolled_loss_guided_round_compiles_for_the_chip(
         tree, row_out = build_tree_lossguide(
             bins, grad, hess, num_cuts, max_leaves=LEAVES, num_bins=NUM_BINS,
             min_child_weight=100.0, eta=0.1, knobs=knobs, axis_name=axis,
-            n_data_shards=chips,
         )
         packed = pack_tree(tree)
         with stage(STAGE_EVAL_APPLY):

@@ -49,7 +49,7 @@ def _tree(name):
 
     case, internal = TREES[name]
     if isinstance(case, dict):
-        case = (None, "psum", True, case)
+        case = (None, True, case)
     else:
         case = lossguide_cases.cases()[case]
     tree, row_out = lossguide_cases.run_case(*case)

@@ -306,7 +306,13 @@ class TestSigquitDump:
         try:
             os.kill(os.getpid(), signal.SIGQUIT)
             status_path = tmp_path / "fleet-status-rank0.json"
-            assert _wait_for(status_path.is_file, timeout=10)
+            assert _wait_for(status_path.is_file, timeout=60)
+            # the file exists from the moment the dump thread opens it; the
+            # document and the record are there when that thread has ended
+            for thread in threading.enumerate():
+                if thread.name == "sigquit-dump":
+                    thread.join(timeout=60)
+                    assert not thread.is_alive()
             with open(str(status_path)) as f:
                 doc = json.load(f)
             assert "round" in doc and "uptime_s" in doc

@@ -98,12 +98,11 @@ def test_trace_env_read_follows_call_graph_and_spares_unreachable(tmp_path):
 
 
 def test_trace_pass_reaches_double_buffered_builder_helpers(tmp_path):
-    """The fused round pipeline (ops/tree_build, ops/lossguide) routes
-    histograms through helpers invoked from comprehensions and nested
-    per-batch closures — apply_hist_collective per node batch, a _scan_batch
-    closure per slice. The name-based call graph must keep treating that
-    shape as jit-reachable so trace-env-read / trace-host-sync still cover
-    the hot path."""
+    """The tree builders (ops/tree_build, ops/lossguide) call helpers from
+    comprehensions and nested closures (a pass's store writes, the per-table
+    reads of a split step). The name-based call graph must keep treating
+    that shape as jit-reachable so trace-env-read / trace-host-sync still
+    cover the hot path."""
     root = make_tree(tmp_path, {"mod.py": """\
         import os
         import jax

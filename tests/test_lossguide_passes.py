@@ -227,18 +227,17 @@ def test_the_judge_passes_the_benchmarks_grower_and_no_other_tree():
 
 
 # ------------------------------------------------------------------ meshes
-@pytest.mark.parametrize("comm", ["psum", "reduce_scatter"])
 @pytest.mark.parametrize("mesh_shape", [(4,), (2, 2)], ids=["data4", "data2xfeature2"])
 @pytest.mark.parametrize("variant", ["plain", "bynode", "sets"])
-def test_mesh_forest_is_the_one_device_forest(mesh_shape, comm, variant):
+def test_mesh_forest_is_the_one_device_forest(mesh_shape, variant):
     """Every shard holds the same candidate store, so every shard runs the
     same passes; under feature sharding the owners' go-left decisions for a
     pass's leaves go through one ``psum``."""
     # 24 leaves: at 31 the sets case meets a near-tie of two cuts that a
     # shard's partial sums resolve otherwise (on the parent commit too)
-    kw = dict(lossguide_cases.cases()["l31.sub." + variant][3], max_leaves=24)
-    one, out_one = lossguide_cases.run_case(None, "psum", True, kw)
-    mesh, out_mesh = lossguide_cases.run_case(mesh_shape, comm, True, kw)
+    kw = dict(lossguide_cases.cases()["l31.sub." + variant][2], max_leaves=24)
+    one, out_one = lossguide_cases.run_case(None, True, kw)
+    mesh, out_mesh = lossguide_cases.run_case(mesh_shape, True, kw)
     assert (~one["is_leaf"]).sum() == 23
     # (not ``default_left``: where a node's rows all have a value both ways
     # gain the same, and the last bit of a sum decides)

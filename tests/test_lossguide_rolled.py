@@ -35,7 +35,10 @@ from tests import lossguide_cases
 # added up afterwards: another order of additions, other last bits. The 43
 # cases that split more than once were read anew off that program (the ten
 # ``l2`` ones are the parent's still); what holds them to the mathematics is
-# the plain grower (below and in ``tests/test_lossguide_passes.py``).
+# the plain grower (below and in ``tests/test_lossguide_passes.py``). Nine of
+# the 53 were the mesh cases again under a second collective lowering, equal
+# by construction; they went with it in PR 45 and the 44 that stay are as
+# they were.
 PARENT_DIGESTS = {
     "l2.sub.plain": "c5991d5ab201c881", "l2.sub.bynode": "c5991d5ab201c881",
     "l2.sub.sets": "c5991d5ab201c881", "l2.sub.mcw": "c5991d5ab201c881",
@@ -58,18 +61,9 @@ PARENT_DIGESTS = {
     "data4.psum.sub.plain": "0052e36868abc57d", "data4.psum.sub.bynode": "5a07cad657fe96e6",
     "data4.psum.sub.sets": "eacf476b11f65e01", "data4.psum.nosub.plain": "c222f41ec387ed76",
     "data4.psum.nosub.bynode": "575f966b9e6b822b", "data4.psum.nosub.sets": "7451bba2019f803b",
-    "data4.reduce_scatter.sub.plain": "0052e36868abc57d",
-    "data4.reduce_scatter.sub.bynode": "5a07cad657fe96e6",
-    "data4.reduce_scatter.sub.sets": "eacf476b11f65e01",
-    "data4.reduce_scatter.nosub.plain": "c222f41ec387ed76",
-    "data4.reduce_scatter.nosub.bynode": "575f966b9e6b822b",
-    "data4.reduce_scatter.nosub.sets": "7451bba2019f803b",
     "data2xfeature2.psum.plain": "5de9d21f6363ed9a",
     "data2xfeature2.psum.bynode": "137dc296c006e3dc",
     "data2xfeature2.psum.sets": "ba31962fcd94f857",
-    "data2xfeature2.reduce_scatter.plain": "5de9d21f6363ed9a",
-    "data2xfeature2.reduce_scatter.bynode": "137dc296c006e3dc",
-    "data2xfeature2.reduce_scatter.sets": "ba31962fcd94f857",
 }
 CASES = lossguide_cases.cases()
 
@@ -84,7 +78,7 @@ def test_rolled_build_is_the_unrolled_builds_forest_bit_for_bit(name):
     assert lossguide_cases.digest(tree, row_out) == PARENT_DIGESTS[name]
     # PR 43: the same forest from fewer passes over the rows, in every case
     passes, filled, used = (int(v) for v in tree["hist_passes"])
-    kids = 1 if CASES[name][2] else 2  # node slots a leaf takes in a pass
+    kids = 1 if CASES[name][1] else 2  # node slots a leaf takes in a pass
     steps = int((~tree["is_leaf"]).sum())
     assert passes <= steps and kids * steps <= used <= filled <= lossguide_mod.PASS_SLOTS * passes
 

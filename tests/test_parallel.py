@@ -1544,7 +1544,7 @@ def test_four_row_shares_add_up_to_the_uncut_data_and_grow_its_tree(monkeypatch)
     mesh = Mesh(np.array(jax.devices()[:shares]), axis_names=("data",))
 
     def build(bn, g, h, nc):
-        tree, row_out = build_tree(bn, g, h, nc, axis_name="data", n_data_shards=shares, **kwargs)
+        tree, row_out = build_tree(bn, g, h, nc, axis_name="data", **kwargs)
         return pack_tree(tree), row_out
 
     packed, row_out = jax.jit(shard_map(

@@ -247,15 +247,25 @@ def test_sigterm_saves_intermediate_model(tmp_path):
         stderr=subprocess.STDOUT,
         text=True,
     )
-    # wait until at least one round has been logged, then SIGTERM
-    deadline = time.time() + 300
-    saw_round = False
-    while time.time() < deadline and not saw_round:
-        line = proc.stdout.readline()
-        if line.startswith("["):
-            saw_round = True
-    assert saw_round, "training never produced a round line"
-    time.sleep(2)
+    # SIGTERM once a round has been logged (the handler is installed before
+    # the first round runs) and its model is on disk. The job's INFO lines
+    # start with "[" too: a round line is "[<round>]<tab>...". A thread
+    # drains the pipe so the child never blocks on a full one.
+    import threading
+
+    saw_round = threading.Event()
+
+    def drain():
+        for line in proc.stdout:
+            if re.match(r"\[\d+\]\s", line):
+                saw_round.set()
+
+    threading.Thread(target=drain, daemon=True).start()
+    assert saw_round.wait(timeout=600), "training never produced a round line"
+    deadline = time.time() + 120
+    while not (model_dir / "xgboost-model").exists() and time.time() < deadline:
+        time.sleep(0.05)
+    assert (model_dir / "xgboost-model").exists(), "no intermediate model after a round"
     proc.send_signal(signal.SIGTERM)
     rc = proc.wait(timeout=60)
     assert rc == 0

@@ -4,9 +4,11 @@ Replaces the reference's ``xgb.DMatrix`` (a handle into libxgboost's C++
 memory). Here the dataset is plain numpy on the host — dense float32 with NaN
 as the missing marker — and moves to TPU HBM only after binning (see
 ``binning.py``), as a compact uint8/uint16 bin-index matrix sharded over the
-mesh. Sparse inputs (libsvm/recordio CSR) densify with NaN fill so that
-"absent entry" keeps XGBoost's missing-value semantics (default split
-direction) rather than silently becoming 0.
+mesh. Sparse inputs (libsvm/recordio CSR) stay CSR: an absent entry is
+*missing* (XGBoost's default split direction), never 0. A training session
+that takes the bundled layout (``data/bundling.py``) reads the CSR itself;
+whoever needs floats reads ``float_block`` a block of rows at a time, and
+``features`` densifies the whole matrix (NaN fill) only when asked for.
 """
 
 import numpy as np
@@ -20,13 +22,15 @@ class DataMatrix:
 
     def __init__(self, features, labels=None, weights=None, groups=None, feature_names=None):
         if sp.issparse(features):
-            features = _densify_with_nan(features.tocsr())
-        features = np.asarray(features, dtype=np.float32)
-        if features.ndim != 2:
+            self.csr = features.tocsr().astype(np.float32, copy=False)
+            self._dense = None
+        else:
+            self.csr = None
+            self._dense = np.asarray(features, dtype=np.float32)
+        if len(self.shape) != 2:
             raise exc.AlgorithmError(
-                "DataMatrix features must be 2-D, got shape {}".format(features.shape)
+                "DataMatrix features must be 2-D, got shape {}".format(self.shape)
             )
-        self.features = features
         self.labels = None if labels is None else np.asarray(labels, dtype=np.float32).reshape(-1)
         self.weights = None if weights is None else np.asarray(weights, dtype=np.float32).reshape(-1)
         self.groups = None if groups is None else np.asarray(groups, dtype=np.int32).reshape(-1)
@@ -50,12 +54,35 @@ class DataMatrix:
             )
 
     @property
+    def is_sparse(self):
+        return self.csr is not None
+
+    @property
+    def shape(self):
+        return self.csr.shape if self.csr is not None else self._dense.shape
+
+    @property
+    def features(self):
+        """Dense float32 [n, d], NaN = missing. A sparse matrix is densified
+        whole on first use and kept: for the paths that read every float."""
+        if self._dense is None:
+            self._dense = _densify_with_nan(self.csr)
+        return self._dense
+
+    def float_block(self, start, end):
+        """Rows ``[start, end)`` as dense float32, NaN = missing; of a sparse
+        matrix only that block is ever dense."""
+        if self._dense is not None:
+            return self._dense[start:end]
+        return _densify_with_nan(self.csr[start:end])
+
+    @property
     def num_row(self):
-        return self.features.shape[0]
+        return self.shape[0]
 
     @property
     def num_col(self):
-        return self.features.shape[1]
+        return self.shape[1]
 
     def get_label(self):
         return self.labels if self.labels is not None else np.empty(0, dtype=np.float32)
@@ -69,7 +96,7 @@ class DataMatrix:
         """Row subset (used by k-fold CV), preserving labels/weights."""
         row_indices = np.asarray(row_indices)
         return DataMatrix(
-            self.features[row_indices],
+            self.csr[row_indices] if self.is_sparse else self._dense[row_indices],
             labels=None if self.labels is None else self.labels[row_indices],
             weights=None if self.weights is None else self.weights[row_indices],
             feature_names=self.feature_names,
@@ -79,9 +106,16 @@ class DataMatrix:
         """Widen with all-missing columns (serving: model trained on more cols)."""
         if num_col <= self.num_col:
             return self
-        pad = np.full((self.num_row, num_col - self.num_col), np.nan, dtype=np.float32)
+        if self.is_sparse:  # the new columns hold no entry: all missing
+            wide = sp.csr_matrix(
+                (self.csr.data, self.csr.indices, self.csr.indptr),
+                shape=(self.num_row, num_col),
+            )
+        else:
+            pad = np.full((self.num_row, num_col - self.num_col), np.nan, dtype=np.float32)
+            wide = np.concatenate([self._dense, pad], axis=1)
         return DataMatrix(
-            np.concatenate([self.features, pad], axis=1),
+            wide,
             labels=self.labels,
             weights=self.weights,
             groups=self.groups,
@@ -102,8 +136,12 @@ class DataMatrix:
                 y = np.zeros(b.num_row, dtype=x.dtype)
             return np.concatenate([x, y])
 
+        if a.is_sparse and b.is_sparse:
+            rows = sp.vstack([a.csr, b.csr], format="csr")
+        else:
+            rows = np.concatenate([a.features, b.features], axis=0)
         return DataMatrix(
-            np.concatenate([a.features, b.features], axis=0),
+            rows,
             labels=_cat(a.labels, b.labels),
             weights=_cat(a.weights, b.weights),
             feature_names=self.feature_names,

@@ -228,13 +228,15 @@ def _predict_margin_rows(forest, dm, block_rows=1 << 16):
     bit-identical to predicting from the original floats, at O(block) peak
     memory instead of O(dataset).
     """
-    if not isinstance(dm, BinnedMatrix):
+    if not isinstance(dm, BinnedMatrix) and not dm.is_sparse:
         return np.asarray(forest.predict_margin(dm.features), np.float32)
     if dm.num_row == 0:
         return np.zeros((0,), np.float32)
+    # a sparse matrix gives its floats a block at a time (NaN = absent)
+    block = dm.rep_block if isinstance(dm, BinnedMatrix) else dm.float_block
     parts = [
         np.asarray(
-            forest.predict_margin(dm.rep_block(s, min(s + block_rows, dm.num_row))),
+            forest.predict_margin(block(s, min(s + block_rows, dm.num_row))),
             np.float32,
         )
         for s in range(0, dm.num_row, block_rows)
@@ -271,7 +273,7 @@ def _merge_cuts_across_processes(local_sets, max_bin):
 
 
 def _apply_packed_tree(packed, bins, margins, num_group, num_parallel, depth,
-                       num_bins, backend, traversal):
+                       num_bins, backend, traversal, bundle=None):
     """margins += the packed tree's (or tree stack's) outputs on ``bins``.
 
     Runs under trace (the round fn and the session apply fn), so the
@@ -282,6 +284,8 @@ def _apply_packed_tree(packed, bins, margins, num_group, num_parallel, depth,
     (``TrainConfig.eval_traversal``): ``level`` walks the depth-wise heap
     level by level, ``replay`` takes the rows through a loss-guided tree's
     splits in the order they were made (``depth`` is the level walk's alone).
+    ``bundle``: a bundled session's ``ops.bundle.BundleTables`` (the level
+    walk's range test), None for every other.
     """
     route_impl = choose_route_impl(backend, bins.shape[1])
 
@@ -289,7 +293,7 @@ def _apply_packed_tree(packed, bins, margins, num_group, num_parallel, depth,
         if traversal == "level":
             return predict_binned_levels(
                 t, bins, depth, num_bins, route_impl=route_impl,
-                table_backend=backend,
+                table_backend=backend, bundle=bundle, gathers=num_group == 1,
             )
         return predict_binned_steps(t, bins, num_bins, table_backend=backend)
 
@@ -435,6 +439,7 @@ class _TrainingSession:
         metric_names=None,
         has_feval=False,
         hist_knobs=None,
+        bundles=False,
     ):
         # the one jax.monitoring listener: program loads (trace, lower,
         # compile, cache load) count under the span that is open, whoever
@@ -635,9 +640,23 @@ class _TrainingSession:
         # input (chunked streaming ingest) already agreed its cuts cross-rank
         # through the ingest sketch allgather and is only dealt out.
         self._dtrain = dtrain
-        self._train_rows = self._row_shards(n_pad, d_pad, self.bins_spec)
         self._train_floats = None    # the train rows' float blocks, a shard each
-        if pre_binned:
+        # a sparse input the shape rule picks is bundled (data/bundling.py):
+        # its bin matrix is `bundled.plan.num_bundles` wide, not d_pad
+        self.bundle = None
+        bundled = (
+            self._bundle_inputs(dtrain, evals)
+            if bundles and self._takes_bundles(dtrain, evals)
+            else None
+        )
+        self._train_rows = self._row_shards(
+            n_pad, d_pad if bundled is None else bundled.plan.num_bundles, self.bins_spec
+        )
+        if bundled is not None:
+            self.bundle = bundled.plan
+            cuts, max_bin = bundled.plan.cut_points, bundled.plan.max_bin
+            shard_bins = [bundled.bins[0]]
+        elif pre_binned:
             cuts, max_bin = dtrain.cut_points, dtrain.max_bin
             shard_bins = [
                 self._shard_rows(dtrain.bins, shard, max_bin, self.rank_perm)
@@ -660,7 +679,10 @@ class _TrainingSession:
                 self._train_floats, cuts, max_bin, self._train_rows.devices, name="train"
             )
         self._stage_train_bins(shard_bins, cuts, max_bin)
-        self._note_binned_shape(shard_bins)
+        if bundled is None:
+            self._note_binned_shape(shard_bins)
+        else:
+            self._note_bundled_shape(bundled)
         del shard_bins
         if not self.approx_resketch:
             self._train_floats = None  # only the re-sketch reads them again
@@ -674,7 +696,11 @@ class _TrainingSession:
                 self._eval_rows.append(None)
                 staged.append(None)
                 continue
-            rows = self._row_shards(_agreed_pad(dm.num_row), d_real, P("data", None))
+            rows = self._row_shards(
+                _agreed_pad(dm.num_row),
+                d_real if bundled is None else bundled.plan.num_bundles,
+                P("data", None),
+            )
             if isinstance(dm, BinnedMatrix):
                 # pre-binned eval set: must carry the training channel's
                 # bin edges (streaming ingest bins validation with the
@@ -703,7 +729,10 @@ class _TrainingSession:
                     labels=dm.labels, weights=dm.weights, groups=dm.groups,
                     shape=(dm.num_row, d_real),
                 )
-                shard_bins = self._bin_eval_rows(i, name, dm, rows, cuts, max_bin)
+                if bundled is None:
+                    shard_bins = self._bin_eval_rows(i, name, dm, rows, cuts, max_bin)
+                else:
+                    shard_bins = [bundled.bins[bundled.index_of[id(dm)]]]
             self.eval_sets.append((name, dm, binned))
             self._eval_rows.append(rows)
             staged.append(shard_bins)
@@ -930,7 +959,8 @@ class _TrainingSession:
 
         self.learning_stats = model_telemetry.enabled()
         self.last_learning_stats = []
-        if self.learning_stats:
+        if self.learning_stats and self.bundle is None:
+            # (a bundled session holds no per-column bins to count)
             model_telemetry.capture_drift_baseline(self.train_binned)
 
         with span("setup.program_build"):
@@ -1145,11 +1175,13 @@ class _TrainingSession:
     def _train_bins_to_host(self, bins):
         """A placed train bin matrix's real rows and columns on the host, in
         original row order (this process's rows)."""
+        self._refuse_bundled_bins()
         if self.rank_pos is not None:
             return self._to_host(bins, None)[:, : self._d_real][self.rank_pos]
         return self._to_host(bins, self.n)[:, : self._d_real]
 
     def _eval_bins_to_host(self, index):
+        self._refuse_bundled_bins()
         dm = self.eval_sets[index][1]
         return self._to_host(self.eval_bins[index], dm.num_row)[:, : self._d_real]
 
@@ -1286,6 +1318,8 @@ class _TrainingSession:
             )
         else:
             builder = partial(build_tree, max_depth=cfg.max_depth, **common)
+            if self.bundle is not None:
+                builder = partial(builder, bundle=self.bundle.tables)
         ranking_grads = self._grad_hess_fn()
         grad_hess = self.objective.grad_hess
         if self.objective.name == "survival:cox" and axis_name is not None:
@@ -1452,6 +1486,7 @@ class _TrainingSession:
         d_pad = self.d_pad
         n_fs = self.n_feature_shards
         backend = self.hist_knobs.backend
+        bundle = self.bundle.tables if self.bundle is not None else None
 
         def multi_round(
             bins, margins, labels, weights, num_cuts, rng, feature_mask, monotone,
@@ -1513,7 +1548,7 @@ class _TrainingSession:
                         m_e = _apply_packed_tree(
                             packed, b_e, extra[ei],
                             num_group, num_parallel, predict_depth, num_bins,
-                            backend=backend, traversal=eval_traversal,
+                            backend=backend, traversal=eval_traversal, bundle=bundle,
                         )
                         new_extra.append(m_e)
                         ei += 1
@@ -1626,12 +1661,13 @@ class _TrainingSession:
         num_parallel = cfg.num_parallel_tree
 
         backend = self.hist_knobs.backend
+        bundle = self.bundle.tables if self.bundle is not None else None
 
         def apply_tree(packed, bins, margins):
             return _apply_packed_tree(
                 packed, bins, margins, num_group, num_parallel,
                 cfg.predict_depth, num_bins, backend=backend,
-                traversal=cfg.eval_traversal,
+                traversal=cfg.eval_traversal, bundle=bundle,
             )
 
         if self.mesh is None:
@@ -1686,7 +1722,7 @@ class _TrainingSession:
         cfg = self.config
         # columns each data shard histograms: the whole width, unless a
         # feature axis splits them
-        d_local = self.d_pad // self.n_feature_shards
+        d_local = self.bins.shape[1] // self.n_feature_shards
         num_bins = self.train_binned.num_bins
         # the builders' own gate, so the plan matches what actually traces
         if cfg.grow_policy == "lossguide":
@@ -2124,7 +2160,7 @@ class _TrainingSession:
             # pipeline (the learning-telemetry guard must catch it there)
             self.margins = self.margins * jnp.float32(np.nan)
         self.rng, sub, colrng = jax.random.split(self.rng, 3)
-        d_pad = self.bins.shape[1]
+        d_pad = self.d_pad  # the bins' width, but for a bundled session's
         if self.config.colsample_bytree < 1.0:
             # draw k of the REAL columns (padded always-missing columns are
             # never legal splits, but counting them would shrink k)
@@ -2283,6 +2319,110 @@ class _TrainingSession:
             self.is_multiprocess,
             global_rows_cache=self._global_rows_cache,
         )
+
+
+    # ------------------------------------------------------ bundled layout
+    # (below the round program: a line moved above the traced code moves the
+    # compile cache's key, PERF.md section 5)
+    def _takes_bundles(self, dtrain, evals):
+        """Whether this session's matrices take the bundled layout
+        (``data/bundling.py``): the input's shape decides (sparse matrices,
+        the training one at most a quarter full), within what the bundled
+        scan and range test cover: a depth-wise ``hist`` build in one process
+        on one device, no monotone or interaction constraints, no column
+        draw below the tree's. Everything else keeps the densified path."""
+        from ..data.bundling import takes_bundled_layout
+        from ..ops.bundle import RANGE_BITS
+
+        cfg = self.config
+        return (
+            self.mesh is None
+            and not self.is_multiprocess
+            and cfg.grow_policy != "lossguide"
+            and cfg.tree_method != "approx"
+            and cfg.max_bin is not None
+            and cfg.max_bin < 1 << RANGE_BITS
+            and not cfg.monotone_constraints
+            and not cfg.interaction_constraints
+            and cfg.colsample_bylevel >= 1.0
+            and cfg.colsample_bynode >= 1.0
+            and takes_bundled_layout([dtrain] + [dm for dm, _name in evals])
+        )
+
+    def _bundle_inputs(self, dtrain, evals):
+        """The session's matrices bundled: the plan over the training matrix
+        and every evaluation set, and the bundled bins of each. The columns
+        filled in most rows go, as one dense block, through the sketch and
+        the bin-apply every dense matrix takes."""
+        from ..data.bundling import bundle_matrices
+
+        matrices, names, index_of = [dtrain], ["train"], {id(dtrain): 0}
+        for dm, name in evals:
+            if id(dm) not in index_of:
+                index_of[id(dm)] = len(matrices)
+                matrices.append(dm)
+                names.append(name)
+        max_bin = self.config.max_bin
+
+        def sketch_dense(block):
+            cuts = sketch_shards([block], [dtrain.weights], max_bin)
+            self._note_setup_memory("setup.sketch")
+            return cuts
+
+        bundled = bundle_matrices(
+            [m.csr for m in matrices],
+            dtrain.weights,
+            max_bin,
+            sketch_dense,
+            lambda block, cuts, name: apply_shards([block], cuts, max_bin, name=name)[0],
+            names,
+        )
+        bundled.index_of = index_of
+        plan = bundled.plan
+        logger.info(
+            "bundled layout: %d sparse columns (%d of them in most rows) in %d bin "
+            "columns, %d of %d bin positions used, %d conflict rows",
+            plan.num_col, len(plan.dense_columns), plan.num_bundles, plan.bins_used,
+            plan.num_bundles * plan.max_bin, bundled.conflict_rows,
+        )
+        return bundled
+
+    def _note_bundled_shape(self, bundled):
+        """``_note_binned_shape`` for a bundled session: what the input held
+        and what the bundled matrix holds of it, set once."""
+        from ..telemetry import REGISTRY
+
+        plan = bundled.plan
+        cells = self.n * plan.num_col
+        cuts = sum(len(c) for c in plan.cut_points)
+        gauges = (
+            ("train_cells_present", "Cells of the training matrix that hold a value",
+             bundled.cells_present),
+            ("train_cells_missing", "Cells of the binned training matrix in the missing bin",
+             cells - bundled.cells_present),
+            ("train_cells_total", "Cells of the binned training matrix (rows x columns)", cells),
+            ("sketch_cuts_selected", "Cut points the sketch selected, summed over columns", cuts),
+            ("sketch_cut_slots", "Cut slots a level histogram carries: columns x (max_bin - 1)",
+             plan.num_bundles * (plan.max_bin - 1)),
+            ("train_columns_total", "Columns of the binned training matrix", plan.num_col),
+            ("train_bundle_columns", "Bin columns of a bundled training matrix "
+             "(data/bundling.py): what the level histogram reads a row", plan.num_bundles),
+            ("bundle_bins_used", "Bin positions the bundles' members take", plan.bins_used),
+            ("bundle_bin_slots", "Bin positions the bundles carry: bundles x max_bin",
+             plan.num_bundles * plan.max_bin),
+            ("bundle_conflict_rows", "Rows of the session's matrices that hold two members "
+             "of one bundle, counted from the bundled matrices: 0, the plan excludes them",
+             bundled.conflict_rows),
+        )
+        for name, text, value in gauges:
+            REGISTRY.gauge(name, text).set(float(value))
+
+    def _refuse_bundled_bins(self):
+        if self.bundle is not None:
+            raise exc.AlgorithmError(
+                "a bundled session holds bundle positions, not per-column bins: "
+                "nothing on the host may read them as a BinnedMatrix"
+            )
 
 
 def _note_round_shape(session):
@@ -2694,6 +2834,7 @@ def train(
         metric_names=metric_names,
         has_feval=feval is not None,
         hist_knobs=hist_knobs,
+        bundles=True,
     )
 
     for cb in callbacks:
@@ -2701,6 +2842,9 @@ def train(
             forest = cb.before_training(forest) or forest
 
     def _trees_for_round(arrs):
+        if session.bundle is not None:
+            # a bundled build's splits name a bundle and a position
+            arrs = session.bundle.original_splits(arrs)
         if session.num_group > 1 and config.num_parallel_tree > 1:
             # stacked [P, C, ...]: commit class-major (class 0's P trees,
             # then class 1's, ...) matching xgboost's per-group layout

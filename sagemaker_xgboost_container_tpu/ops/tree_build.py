@@ -256,6 +256,7 @@ def build_tree(
     d_global=None,
     knobs=None,
     class_vmap=False,
+    bundle=None,
 ):
     """Grow one tree. Returns (tree arrays dict, row_out f32 [n]).
 
@@ -282,6 +283,14 @@ def build_tree(
     one operand over the one bin matrix (``ops.histogram._class_hist_fn``),
     and the split scan reads its winners without a gather
     (``ops.split.find_best_splits``, ``gathers``).
+
+    bundle: static; the session's ``ops.bundle.BundleTables`` where ``bins``
+    is a bundled matrix (``data/bundling.py``: a bin column holds several
+    mutually exclusive original columns, each in its own range of positions).
+    The scan then judges original columns over positions and a row absent
+    from its node's split column is told by a range test, a second word read
+    of the node's table; ``feature`` and ``bin`` of the tree are then a bundle
+    and a position. None, a dense session, traces none of it.
 
     Every per-row read of a level's per-node table is ``node_table_lookup`` in
     the lowering ``choose_table_impl(backend, 2**level)`` picks: the four
@@ -443,7 +452,8 @@ def build_tree(
                 ) > 0
                 per_node = _local_cols(node_allowed.astype(jnp.float32))
                 level_mask = per_node if level_mask is None else per_node * level_mask[None, :]
-            splits = find_best_splits(
+            scan = find_best_splits if bundle is None else bundle.find_best_splits
+            splits = scan(
                 G,
                 H,
                 num_cuts,
@@ -490,8 +500,13 @@ def build_tree(
             row_leafed = at_level & row_at_leaf
             if feature_axis_name is None:
                 row_bin = row_bin_lookup(bins, split_feat, impl=route_impl)
-                is_missing = row_bin == (num_bins - 1)
-                go_right = jnp.where(is_missing, ~default_left, row_bin > split_bin)
+                if bundle is None:
+                    is_missing = row_bin == (num_bins - 1)
+                    go_right = jnp.where(is_missing, ~default_left, row_bin > split_bin)
+                else:  # absent: outside the split column's range
+                    go_right = bundle.go_right(
+                        row_bin, at_node(splits["range"], local_safe), split_bin, default_left
+                    )
             else:
                 # only the shard owning a node's split feature can decide its
                 # rows; decisions psum-broadcast along the feature axis
@@ -633,7 +648,7 @@ def predict_binned(tree, bins, max_depth, num_bins, route_impl=None):
 
 
 def predict_binned_levels(tree, bins, max_depth, num_bins, route_impl=None,
-                          table_backend=None):
+                          table_backend=None, bundle=None, gathers=True):
     """``predict_binned`` for a tree ``build_tree`` made, bit for bit.
 
     Such a tree is a heap (children of i at 2i+1 / 2i+2, level L the static
@@ -649,10 +664,15 @@ def predict_binned_levels(tree, bins, max_depth, num_bins, route_impl=None,
     ``table_backend`` chooses each ``node_table_lookup``'s lowering through
     ``choose_table_impl`` (traced callers pass the session's
     ``HistKnobs.backend``; None reads the process's backend, for direct
-    callers only).
+    callers only). ``bundle``: the session's ``ops.bundle.BundleTables`` where
+    tree and bins are bundled: a row absent from a split's column is told by
+    the range test, as the build tells it (``gathers``: how the nodes' range
+    words are read, ``BundleTables.node_ranges``).
     """
     if table_backend is None:
         table_backend = jax.default_backend()
+    if bundle is not None:
+        tree = dict(tree, range=bundle.node_ranges(tree["feature"], tree["bin"], gathers))
     node = jnp.zeros(bins.shape[0], jnp.int32)
     for level in range(max_depth):
         first = 2**level - 1
@@ -667,9 +687,14 @@ def predict_binned_levels(tree, bins, max_depth, num_bins, route_impl=None,
             )
 
         row_bin = row_bin_lookup(bins, at_node("feature"), impl=route_impl)
-        go_right = jnp.where(
-            row_bin == (num_bins - 1), ~at_node("default_left"), row_bin > at_node("bin")
-        )
+        if bundle is None:
+            go_right = jnp.where(
+                row_bin == (num_bins - 1), ~at_node("default_left"), row_bin > at_node("bin")
+            )
+        else:
+            go_right = bundle.go_right(
+                row_bin, at_node("range"), at_node("bin"), at_node("default_left")
+            )
         child = node * 2 + 1 + go_right.astype(jnp.int32)
         node = jnp.where((node_local >= 0) & ~at_node("is_leaf"), child, node)
     leaves = tree["leaf_value"]

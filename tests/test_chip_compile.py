@@ -360,3 +360,88 @@ def test_rolled_loss_guided_round_compiles_for_the_chip(
     assert not {"gather", "while", "conditional", "all-reduce", "all-reduce-start"} & set(in_replay)
     walk = [line for line in hlo.split("\n") if "eval_apply" in line and " gather(" in line]
     assert not walk, walk
+
+
+# ------------------------------------------- a bundled round (sparse input)
+SPARSE_ROWS, SPARSE_VALIDATION, SPARSE_BUNDLES = 12_184_290, 1_000_000, 47
+
+
+def _seeded_tables(bundles, max_bin, seed=0):
+    """Bundle tables of ``bundles`` bin columns: three bundles of one (a dense
+    column of 200 cuts each), the rest filled with one-position members."""
+    import numpy as np
+
+    from sagemaker_xgboost_container_tpu.ops.bundle import BundleTables
+
+    lo = np.zeros((bundles, max_bin), np.int32)
+    hi = np.zeros((bundles, max_bin), np.int32)
+    legal = np.zeros((bundles, max_bin), bool)
+    column = np.full((bundles, max_bin), -1, np.int32)
+    rng = np.random.default_rng(seed)
+    next_column = 0
+    for b in range(bundles):
+        if b < 3:
+            hi[b, :201], legal[b, :200], column[b, :201] = 201, True, next_column
+            next_column += 1
+            continue
+        used = int(rng.integers(max_bin // 2, max_bin + 1))
+        lo[b, :used] = np.arange(used)
+        hi[b, :used] = np.arange(used) + 1
+        legal[b, :used] = True
+        column[b, :used] = next_column + np.arange(used)
+        next_column += used
+    return BundleTables(lo, hi, legal, column), next_column
+
+
+def test_bundled_round_compiles_for_the_chip(one_chip, no_compile_cache, monkeypatch):
+    """`allstate-onehot-d8`'s round (12,184,290 rows x 47 bundle columns in
+    u16, depth 8, the level walk's range test over 1,000,000 validation rows)
+    through the chip's own compilers, the kernel's too: the same eight kernel
+    call sites as a dense depth-8 build, the bundled scan's masked sums as
+    dots, and no row-length gather in the build or in the walk."""
+    from sagemaker_xgboost_container_tpu.ops import histogram as hist_mod
+    from sagemaker_xgboost_container_tpu.ops.tree_build import (
+        choose_route_impl,
+        predict_binned_levels,
+        tree_from_packed,
+    )
+
+    monkeypatch.setattr(hist_mod, "pallas_interpret", lambda: False)
+    knobs = resolve_hist_knobs()._replace(backend="tpu")
+    tables, columns = _seeded_tables(SPARSE_BUNDLES, NUM_BINS - 1)
+
+    def one_round(bins, grad, hess, num_cuts, validation_bins):
+        tree, row_out = build_tree(
+            bins, grad, hess, num_cuts, max_depth=DEPTH, num_bins=NUM_BINS,
+            min_child_weight=100.0, eta=0.1, knobs=knobs, bundle=tables,
+            feature_mask=jnp.ones(columns, jnp.float32),
+        )
+        packed = pack_tree(tree)
+        walked = predict_binned_levels(
+            tree_from_packed(packed), validation_bins, DEPTH, NUM_BINS,
+            route_impl=choose_route_impl("tpu", SPARSE_BUNDLES), table_backend="tpu",
+            bundle=tables,
+        )
+        return packed, row_out, walked
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    n, v = SPARSE_ROWS, SPARSE_VALIDATION
+    compiled = (
+        jax.jit(one_round)
+        .lower(
+            shape((n, SPARSE_BUNDLES), jnp.uint16), shape((n,), jnp.float32),
+            shape((n,), jnp.float32), shape((columns,), jnp.int32),
+            shape((v, SPARSE_BUNDLES), jnp.uint16),
+        )
+        .compile()
+    )
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == DEPTH
+    # no per-row gather: a gather's result is never a row long
+    for dims in re.findall(r" = \S+\[([0-9,]*)\][^\n]* gather\(", hlo):
+        assert str(n) not in dims.split(",") and str(v) not in dims.split(","), dims
+    # the program's own arguments and scratch fit the chip beside the bins
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 6 << 30, memory

@@ -321,3 +321,56 @@ def test_ten_class_forest_keeps_a_split_exactly_where_gamma_and_child_weight_all
     if ignored is None:
         # both rules stop trees here: most of the twenty stop short of depth 5
         assert leaves_above_the_last_level >= 20 and splits >= 60
+
+
+# ------------------------------------------- the bundled scan (sparse input)
+@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+def test_bundled_split_scan_matches_bruteforce_per_original_column(seed):
+    """The scan over bundle positions against a brute force over the
+    ORIGINAL columns of the densified matrix: every cut of every column, the
+    absent rows sent to either side."""
+    from sagemaker_xgboost_container_tpu.data import bundling
+    from sagemaker_xgboost_container_tpu.ops.histogram import level_histogram
+    from tests.sparse_cases import densified, one_hot_csr
+
+    max_bin = 256
+    x, _y = one_hot_csr(500, seed, groups=(3, 4, 7, 30), numeric=2, unknown=0.15, absent=0.2)
+    out = bundling.bundle_matrices(
+        [x], None, max_bin,
+        lambda block: compute_cut_points(block, None, max_bin),
+        lambda block, cuts, name: apply_cut_points(block, cuts, max_bin, name=name),
+        ["train"],
+    )
+    plan = out.plan
+    rng = np.random.RandomState(seed)
+    grad = rng.randn(500).astype(np.float32)
+    hess = rng.rand(500).astype(np.float32) + 0.1
+    G, H = level_histogram(
+        jnp.asarray(out.bins[0]), jnp.asarray(grad), jnp.asarray(hess),
+        jnp.zeros(500, jnp.int32), 1, max_bin + 1,
+    )
+    splits = plan.tables.find_best_splits(
+        G, H, None, reg_lambda=LAM, gamma=GAMMA, min_child_weight=MINCW
+    )
+    got = plan.original_splits({"feature": np.asarray(splits["feature"]),
+                                "bin": np.asarray(splits["bin"])})
+    column, own_bin = int(got["feature"][0]), int(got["bin"][0])
+    dense_bins = apply_cut_points(densified(x), plan.cut_points, max_bin)
+    best = (-np.inf, -1, -1, False)
+    for f in range(x.shape[1]):
+        gain, b, ml = _brute_best_split(
+            dense_bins[:, f].astype(np.int32), grad, hess, len(plan.cut_points[f]), max_bin
+        )
+        if gain > best[0]:
+            best = (gain, f, b, ml)
+    assert abs(float(splits["gain"][0]) - best[0]) < 1e-3, (float(splits["gain"][0]), best)
+    chosen_gain, chosen_bin, chosen_left = _brute_best_split(
+        dense_bins[:, column].astype(np.int32), grad, hess,
+        len(plan.cut_points[column]), max_bin,
+    )
+    assert abs(chosen_gain - best[0]) < 1e-3
+    # the node's range word is the chosen member's range
+    word = int(splits["range"][0])
+    b, p = int(splits["feature"][0]), int(splits["bin"][0])
+    assert (word >> 9, word & 511) == (plan.tables.lo[b, p], plan.tables.hi[b, p])
+    assert plan.tables.column[b, p] == column and p - plan.tables.lo[b, p] == own_bin

@@ -922,9 +922,9 @@ class _TrainingSession:
             "hist_onehot_tiles_per_round",
             "One-hot tiles ([128 rows, 128 bin lanes]) a shard's level "
             "histogram kernel latches a round: over the build's histogram "
-            "levels, row tiles x features x bin tiles after the fold, once a "
-            "group of class trees (ops/histogram.py::_bin_fold, "
-            "_class_groups; 0 where the builder is not the kernel)",
+            "levels, row tiles x features x bin tiles after the fold (x tiles of "
+            "features where they share one), once a group of class trees (ops/"
+            "histogram.py::_bin_fold, _tile_pack, _class_groups; 0 off the kernel)",
         ).set(tiles)
         REGISTRY.gauge(
             "hist_onehot_tiles_unfolded_per_round",

@@ -19,8 +19,11 @@ Two builders, one a backend (``choose_hist_impl``, as
   leaves free rows on a latched one-hot tile (W <= 8 at 256 bin lanes), the
   bin axis is folded into it (``_bin_fold``): the kernel latches the one-hot
   of a bin's low part alone, half the tiles, and the bin's high part picks
-  which copy of the operand a row's gradients ride. The class trees of a
-  round read one bin matrix, so they share a call and its latched tiles:
+  which copy of the operand a row's gradients ride. At the narrowest levels
+  (W <= 2) the rows that really hold a gradient are so few that two features
+  share every latched tile (``_tile_pack``): half the tiles again. The class
+  trees of a round read one bin matrix, so they share a call and its latched
+  tiles:
   their gradients are rows of one operand (``_class_groups``). Interpreted
   on the CPU backend (tests, rehearsals).
 * ``flat`` (everything else, and the tests' reference): one
@@ -486,6 +489,72 @@ def _bin_fold(rows, bin_lanes, prec):
     return fold
 
 
+# least rows of a packed tile's slot: g and h of up to two nodes, so that the
+# W = 1 and W = 2 calls of a tree are one shape of the packed body
+PACK_SLOT_ROWS = 4
+
+
+def _slot_rows(W, bin_lanes, pack):
+    """Rows of a slot of the packed body's operand: the 2W of the level that
+    hold a gradient, in a power of two (g of node w in row w, h in row W +
+    w), not padded to the bf16 tile as ``_operand_rows`` pads the unpacked
+    operand; and no fewer than make a feature's ``fold`` = bin_lanes / (128 /
+    pack) slots whole bf16 tiles of 16 rows, which the body masks as 32-bit
+    words."""
+    fold = bin_lanes * pack // 128
+    return max(PACK_SLOT_ROWS, 1 << (2 * W - 1).bit_length(), 16 // fold)
+
+
+def _tile_pack(W, bin_lanes, prec):
+    """Features that share one latched one-hot tile: the largest power of two
+    ``pack`` whose ``fold`` = bin_lanes / (128 / pack) masked copies of every
+    one of the ``pack`` features' stacked REAL rows (``_slot_rows``, twice
+    for bf16x2) stay within LATCH_FREE_ROWS; 1 is the body ``_bin_fold``
+    rules. From shapes alone. With 256 bin lanes and bf16x2 that is 2 at
+    W <= 2 (4 copies x 2 features x 8 rows = 64); the one-pass control
+    streams half the rows and packs W = 4 too.
+
+    ``_bin_fold`` stops where ``fold`` x the stacked operand reaches 64 rows,
+    but ``_operand_rows`` has padded 2W to the 16 sublanes of a bf16 tile
+    first: at W = 1 only 8 of those 64 rows hold a gradient, at W = 2 only
+    16. The packed body builds its operand from the real rows, so the bin
+    axis folds down to L = 128 / pack lanes a feature, and a latched tile
+    holds the one-hots of ``pack`` features side by side: half the tiles
+    again (``_pallas_hist_packed_fn``).
+
+    ms a call (scripts/dissect.py --hist-levels re-reads it; 257 bins in u16:
+    256 bin lanes and the missing bin's own dot; one v5e, jax 0.9.0, PR 47),
+    by the level's node count, the shipped pack beside ``pack`` 1 at the
+    shipped fold (``_bin_fold``'s second rows, read again):
+
+        W (slot rows)                  1 (4)   2 (4)   4 (16)  8 (16)
+        8.8M x 28, pack 1, fold 2      25.7    25.7    25.6    25.5
+        8.8M x 28, pack 2, fold 4      15.0    15.1
+        2.27M x 136, pack 1, fold 2    37.5    37.3    37.3    37.3
+        2.27M x 136, pack 2, fold 4    23.9    23.9
+
+    and the one-pass control at W = 1 (32 streamed rows a tile): 24.5 -> 14.2
+    and 35.7 -> 22.6; inside `higgs-d8`'s round a call went 24.2 -> 13.85 ms
+    (PERF.md section 5). Half the tiles again (1.93M -> 0.96M a call at 8.8M x
+    28) cost 58 to 64 % of the folded call, as the fold's halving cost 56 to
+    61 % of the unfolded one: 62 ns a row block and tile of two features,
+    where the folded body takes 53 a feature. Not so with the folded body's
+    way of building its operands: selects over
+    the whole [64, blk] bf16 operand and a [128, blk] compare converted to
+    bf16 ran 21.5 / 32.0 ms a call (0.85 of the folded call: at a quarter of
+    the tiles the VPU's work a tile binds, not the latch), the operand
+    masked as 32-bit words 17.6 / 26.6, the one-hot built as words too 15.0 /
+    23.9 (``_pallas_hist_packed_fn``). What a grid step costs whatever it
+    latches (``_bin_fold``'s 2.4 to 5.7 ms a call) is still there."""
+    halves = 2 if prec == "bf16x2" else 1
+    pack = 1
+    while (bin_lanes // 128) * (2 * pack) ** 2 * halves * _slot_rows(
+        W, bin_lanes, 2 * pack
+    ) <= LATCH_FREE_ROWS:
+        pack *= 2
+    return pack
+
+
 # most rows of the streamed operand one call carries for the class trees of
 # a round: ten classes at W = 8 (160 rows, the widest call the probe beside
 # ``_class_groups`` ran in one piece). From 64 streamed rows a call costs
@@ -530,12 +599,14 @@ def round_onehot_tiles(levels, n, d, num_bins, prec, trees_per_round=1,
     """``(latched, unfolded)``: the [128, 128] one-hot tiles the Pallas kernel
     latches a round over ``n`` rows x ``d`` features (a shard's), summed over
     ``levels`` (``round_hist_levels``): row tiles x features x bin tiles
-    after the fold, and the same with ``fold`` 1. Of the round's
+    after the fold, or row tiles x tiles of ``pack`` features where a
+    one-tree level packs (``_tile_pack``; a tile with one real feature is a
+    whole tile), and the same with ``fold`` and ``pack`` 1. Of the round's
     ``trees_per_round`` trees, ``class_trees`` at a time share their latches
     (the class trees of one bagged step: ``_class_groups``), so a level
     latches once a class group, not once a tree; ``unfolded`` counts every
-    tree. From shapes alone: what the fold and the class operand engage on,
-    stated before a round runs."""
+    tree. From shapes alone: what the fold, the pack and the class operand
+    engage on, stated before a round runs."""
     block = PALLAS_ROW_BLOCK
     row_tiles = _round_up(n, block * _chunk_cap(-(-n // block))) // 128
     lanes = _bin_lanes(num_bins)
@@ -545,7 +616,11 @@ def round_onehot_tiles(levels, n, d, num_bins, prec, trees_per_round=1,
         unfolded += tiles * trees_per_round
         size, groups = _class_groups(W, class_trees)       # (1, 1) for one tree
         calls = trees_per_round // class_trees * groups
-        latched += tiles * calls // _bin_fold(_operand_rows(W, size), lanes, prec)
+        pack = _tile_pack(W, lanes, prec) if class_trees == 1 else 1
+        if pack > 1:
+            latched += count * row_tiles * -(-d // pack) * calls
+        else:
+            latched += tiles * calls // _bin_fold(_operand_rows(W, size), lanes, prec)
     return latched, unfolded
 
 
@@ -592,6 +667,20 @@ def _pallas_feature_group(d, bins_dtype):
     narrow data is not padded to 32 features of MXU work."""
     tile = max(16, 32 // jnp.dtype(bins_dtype).itemsize)
     return min(_PALLAS_FEATURE_GROUP, _round_up(d, tile))
+
+
+def _level_rows(node, gh_ref, W, row):
+    """A block of the one-tree kernels' f32 operand: g of the row's node
+    where ``row`` (i32 [rows, blk]) is the node's id (``node`` i32 [1, blk]),
+    h where it is W + that, else 0. A dead row (node >= W) must stay out of
+    BOTH halves, not land in h's first rows."""
+    dead = node >= W
+    g_row = jnp.where(dead, -1, node)
+    h_row = jnp.where(dead, -1, node + W)
+    return jnp.where(
+        row == g_row, gh_ref[0:1, :],
+        jnp.where(row == h_row, gh_ref[1:2, :], 0.0),
+    )
 
 
 @functools.lru_cache(maxsize=None)
@@ -673,17 +762,8 @@ def _pallas_hist_fn(n, d, fg, W, B, block, prec, interpret, split_missing,
             miss_ref[...] = jnp.zeros_like(miss_ref)
 
         if class_groups is None:
-            node = node_ref[...]                       # [1, blk]
-            dead = node >= W
-            # a dead row must stay out of BOTH halves, not land in h's first
-            # rows
-            g_row = jnp.where(dead, -1, node)
-            h_row = jnp.where(dead, -1, node + W)
             row = jax.lax.broadcasted_iota(jnp.int32, (rows, block), 0)
-            A = jnp.where(
-                row == g_row, gh_ref[0:1, :],
-                jnp.where(row == h_row, gh_ref[1:2, :], 0.0),
-            )  # [rows, blk]
+            A = _level_rows(node_ref[...], gh_ref, W, row)     # [rows, blk]
         else:
             row = jax.lax.broadcasted_iota(jnp.int32, (rows, block), 0)
             A = jnp.zeros((rows, block), jnp.float32)
@@ -778,6 +858,161 @@ def _pallas_hist_fn(n, d, fg, W, B, block, prec, interpret, split_missing,
     )
 
 
+@functools.lru_cache(maxsize=None)
+def _pallas_hist_packed_fn(n, d, fg, W, B, block, prec, interpret, split_missing,
+                           chunks, pack):
+    """The one-tree kernel of a level narrow enough that ``pack`` features
+    share every latched one-hot tile (see _tile_pack): same operands as
+    ``_pallas_hist_fn`` (bins [d_pad, n], gh f32 [2, n], node i32 [1, n]),
+    same results with ``rows`` = ``_slot_rows`` (main f32 [chunks, d_pad,
+    rows, Bp], miss f32 [chunks, d_pad, rows or 2 * rows]), the same kernel
+    name, grid, row chunks, missing-bin dot and padding-feature rule.
+
+    A bin is ``L * top + low`` with L = 128 / pack lanes and ``fold`` = Bp /
+    L values of ``top``. A tile of ``pack`` adjacent features latches ONE
+    [128, blk] one-hot: lanes [j * L, (j + 1) * L) hold that of feature j's
+    ``low``. The streamed operand holds, for each half of the bf16x2 split,
+    a slot of the level's ``rows`` real rows for every (feature j of the
+    tile, copy t): row (j, t, k) is row k of the level's operand where
+    feature j's ``top`` is t, else 0. So the [S, 128] product a tile and
+    block (S = pack * fold * rows, halves added as in the unpacked body)
+    holds feature j's histogram in rows (j, t, k) x lanes [j * L, (j + 1) *
+    L), bin L * t + lane, and the products of feature j's rows with another
+    feature's lanes in the other blocks, which ``untangle`` drops after the
+    kernel. Every product that is kept reaches its cell at the same position
+    of the same 512-row contraction as in the unpacked body, beside zeros:
+    the same bits. A tile with a padding feature beside a real one is a
+    whole tile; a tile of padding features alone gets no dot.
+
+    With half the tiles again the VPU's work a tile shows (``_tile_pack``'s
+    table), so operand and one-hot are built as 32-bit words, two bf16 rows
+    a word (``pltpu.bitcast``: word r of a column holds rows 2r and 2r + 1).
+    A feature's ``fold`` slots are whole bf16 tiles (``_slot_rows``): one
+    compare and one select a half over [fold * rows / 2, blk] words mask
+    them, and a feature's one-hot is one compare and one select over [L / 2,
+    blk] words (lanes 2r and 2r + 1 the halves of word r); the features'
+    pieces sit side by side without a copy."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    Bp = _round_up(B - 1 if split_missing else B, 128)
+    d_pad = _round_up(d, fg)
+    groups = d_pad // fg
+    tiles_in_last = -(-(d - (groups - 1) * fg) // pack)   # with a real feature
+    per = n // (block * chunks)            # row blocks a chunk
+    stacked = prec == "bf16x2"
+    rows = _slot_rows(W, Bp, pack)
+    L = 128 // pack                        # lanes a feature of the latched tile
+    fold = Bp // L
+    F = fold * rows                        # rows of a feature's slots: (copy, k)
+    S = pack * F                           # rows of a half: (feature, copy, k)
+    halves = 2 if stacked else 1
+    shift_w, shift_l = (rows // 2).bit_length() - 1, L.bit_length() - 1
+
+    def kernel(bins_ref, gh_ref, node_ref, out_ref, miss_ref):
+        @pl.when(pl.program_id(2) == 0)
+        def _():
+            out_ref[...] = jnp.zeros_like(out_ref)
+            miss_ref[...] = jnp.zeros_like(miss_ref)
+
+        # every copy's slot holds the level's real rows
+        k = jax.lax.broadcasted_iota(jnp.int32, (F, block), 0) & (rows - 1)
+        A = _level_rows(node_ref[...], gh_ref, W, k)   # [F, blk]
+        # "bf16": one rounded half, the failing control
+        A = _split_bf16(A) if stacked else (A.astype(jnp.bfloat16),)
+        # two rows of a slot a 32-bit word: [F / 2, blk], whole sublane tiles
+        words = [pltpu.bitcast(half, jnp.uint32) for half in A]
+        copy_of = jax.lax.broadcasted_iota(jnp.int32, (F // 2, block), 0) >> shift_w
+        word_of = jax.lax.broadcasted_iota(jnp.int32, (L // 2, block), 0)
+        lanes = (((1,), (1,)), ((), ()))               # contract rows
+
+        bw = bins_ref[...].astype(jnp.int32)           # widen in VMEM
+
+        def tile(p):
+            b = [bw[pack * p + j:pack * p + j + 1, :] for j in range(pack)]  # [1, blk]
+            # the missing bin (fold * L where it is split out) is copy
+            # ``fold``: it rides no slot
+            rides = [(bj >> shift_l) == copy_of for bj in b]
+            Af = pltpu.bitcast(
+                jnp.concatenate(
+                    [jnp.where(m, half, jnp.uint32(0)) for half in words for m in rides], axis=0
+                ),
+                jnp.bfloat16,
+            )                                          # [halves * S, blk]
+            # the one-hot as words too: lanes 2r and 2r + 1 of a feature are
+            # the halves of its word r, and 0x3F80 is bf16's 1.0
+            ob = pltpu.bitcast(
+                jnp.concatenate(
+                    [
+                        jnp.where(
+                            ((bj & (L - 1)) >> 1) == word_of,
+                            jnp.uint32(0x3F80) << ((bj & 1) << 4).astype(jnp.uint32),
+                            jnp.uint32(0),
+                        )
+                        for bj in b
+                    ],
+                    axis=0,
+                ),
+                jnp.bfloat16,
+            )                                          # [128, blk]
+            P = jax.lax.dot_general(
+                Af, ob, lanes, preferred_element_type=jnp.float32
+            )
+            out_ref[0, p] += (P[:S] + P[S:]) if stacked else P
+
+        for p in range(tiles_in_last):                 # real in every group
+            tile(p)
+        if tiles_in_last < fg // pack and groups > 1:
+            @pl.when(pl.program_id(0) < groups - 1)
+            def _():
+                for p in range(tiles_in_last, fg // pack):
+                    tile(p)
+
+        if split_missing:
+            miss = (bw == (B - 1)).astype(jnp.bfloat16)    # [fg, blk]
+            miss_ref[0] += jax.lax.dot_general(
+                miss, jnp.concatenate(A, axis=0), lanes,
+                preferred_element_type=jnp.float32,
+            )
+
+    call = pl.pallas_call(
+        kernel,
+        grid=(groups, chunks, per),
+        in_specs=[
+            pl.BlockSpec((fg, block), lambda j, c, i: (j, c * per + i)),
+            pl.BlockSpec((2, block), lambda j, c, i: (0, c * per + i)),
+            pl.BlockSpec((1, block), lambda j, c, i: (0, c * per + i)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, fg // pack, S, 128), lambda j, c, i: (c, j, 0, 0)),
+            pl.BlockSpec((1, fg, halves * F), lambda j, c, i: (c, j, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((chunks, d_pad // pack, S, 128), jnp.float32),
+            jax.ShapeDtypeStruct((chunks, d_pad, halves * F), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+        name="graft_level_histogram",
+    )
+
+    def untangle(main, miss):
+        # a tile's slab is (feature, copy, k) x (feature', low): keep feature
+        # == feature', bins back to L * copy + low
+        main = main.reshape(chunks, d_pad // pack, pack, fold, rows, pack, L)
+        main = jnp.stack([main[:, :, j, :, :, j] for j in range(pack)], axis=2)
+        main = main.transpose(0, 1, 2, 4, 3, 5).reshape(chunks, d_pad, rows, Bp)
+        # the missing bin's dot met every copy's slot: the first has it
+        miss = jnp.concatenate(
+            [miss[..., h * F:h * F + rows] for h in range(halves)], axis=-1
+        )
+        return main, miss
+
+    return lambda bins_t, gh, node: untangle(*call(bins_t, gh, node))
+
+
 def _hist_pallas(bins, grad, hess, node_local, num_nodes, num_bins,
                  prec=HIST_PRECISIONS[0]):
     """One tree (``grad``, ``hess``, ``node_local`` f32 / i32 [n]) ->
@@ -818,11 +1053,19 @@ def _hist_pallas(bins, grad, hess, node_local, num_nodes, num_bins,
         gh = jnp.pad(jnp.stack([g, h]), [(0, 0), (0, n_pad - n)])
         node = jnp.pad(node, [(0, n_pad - n)], constant_values=W)
 
-        rows = _operand_rows(W)
-        fn = _pallas_hist_fn(
-            n_pad, d, fg, W, B, block, prec, interpret, split_missing,
-            rows, chunks, _bin_fold(rows, lanes, prec),
-        )
+        pack = _tile_pack(W, lanes, prec)
+        if pack > 1:
+            rows = _slot_rows(W, lanes, pack)
+            fn = _pallas_hist_packed_fn(
+                n_pad, d, fg, W, B, block, prec, interpret, split_missing,
+                chunks, pack,
+            )
+        else:
+            rows = _operand_rows(W)
+            fn = _pallas_hist_fn(
+                n_pad, d, fg, W, B, block, prec, interpret, split_missing,
+                rows, chunks, _bin_fold(rows, lanes, prec),
+            )
         GH = _sum_row_chunks(
             *fn(bins_t, gh, node[None, :].astype(jnp.int32)), d, rows, B, split_missing
         )

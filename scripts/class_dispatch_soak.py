@@ -4,6 +4,7 @@ whether every dispatch came back.
 
     python scripts/class_dispatch_soak.py --classes 10 --dispatches 6
     python scripts/class_dispatch_soak.py --classes 3 --chips 4
+    python scripts/class_dispatch_soak.py --classes 1 --rows 8800000 --cols 28 --depth 8 --dispatches 10
 
 The class trees of a depth-wise round share one call of the level histogram
 kernel a level (``ops/histogram.py::_class_groups``). PR 41's first build of
@@ -17,7 +18,10 @@ back after ``--hang-after`` seconds: a stopped program never returns, and a
 run that waits for it is charged to its time limit. Exit 0 with one JSON line
 last. Needs the chip (exit 2 without one); ``--cpu-rehearsal`` runs the same
 code on 2,000 rows through the interpreter, which proves the script and
-nothing about the chip.
+nothing about the chip. ``--classes 1`` dispatches a one-tree round program
+(``binary:logistic``, `logloss`) instead: the soak of PR 47's packed kernel
+body (``ops/histogram.py::_tile_pack``) at `higgs-d8`'s and `criteo-tb-d8`'s
+shapes.
 """
 
 import argparse
@@ -77,6 +81,10 @@ def main(argv=None):
         "objective": "multi:softmax", "num_class": args.classes, "max_depth": args.depth,
         "eta": 0.2, "max_bin": 256, "eval_metric": "mlogloss", "_rounds_per_dispatch": 8,
     }
+    if args.classes == 1:                           # a one-tree round program
+        y = (X[:, : min(8, args.cols)].sum(axis=1) % 2).astype(np.float32)
+        del params["num_class"]
+        params.update(objective="binary:logistic", eval_metric="logloss")
 
     seconds = []
     real_sync = booster._TrainingSession._device_sync

@@ -24,8 +24,9 @@ over the same rows, then the build's own read set at its two widest levels
 counts. ``--hist-levels`` runs the level-histogram probe: the
 Pallas kernel builder called directly at the two cells' shapes, ms a call at
 every level's node count W under the operand-row rule
-(``ops/histogram._operand_rows``) and the bin fold (``_bin_fold``: each folded
-level also at ``fold`` 1, with the one-hot tiles a call latches), then at
+(``ops/histogram._operand_rows``), the bin fold (``_bin_fold``: each folded
+level also at ``fold`` 1, with the one-hot tiles a call latches) and the tile
+pack (``_tile_pack``: each packed level beside the shipped fold), then at
 W = 1 with the operand padded to more rows: the tables the two rules were
 read from; with ``--trees T`` the call of T class trees in one operand
 (``_class_groups``) at every W. Run under an external timeout,
@@ -296,8 +297,9 @@ HIST_PROBE_ROWS = (16, 32, 64, 128)           # operand rows at W = 1
 
 def hist_level_probe(shapes, num_bins=MAX_BIN + 1, trees=1):
     """ms a call of the level histogram kernel by node count W under the
-    shipped operand-row, chunk and fold rules (a folded level also at
-    ``fold`` 1), then by operand rows at W = 1 in both precisions, with the
+    shipped operand-row, chunk, fold and pack rules (a folded level also at
+    ``fold`` 1, a packed one also unpacked at the shipped fold), then by
+    operand rows at W = 1 in both precisions, with the
     rows the MXU streams against one latched one-hot tile (both halves of
     the split operand, every folded copy), the tiles a call latches and the
     share of the MXU's peak that the issued flops make. Bins and gradients
@@ -333,7 +335,7 @@ def hist_level_probe(shapes, num_bins=MAX_BIN + 1, trees=1):
         gh = jax.random.normal(k_gh, (2, n_pad), jnp.float32)
         jax.block_until_ready((bins, gh))
 
-        def call(W, rows, chunks, prec="bf16x2", fold=1, class_groups=None):
+        def call(W, rows, chunks, prec="bf16x2", fold=1, class_groups=None, pack=1):
             size, groups = class_groups or (1, 1)
             node = jax.random.randint(k_node, (groups * size, n_pad), 0, W, jnp.int32)
             operands = (gh, node)
@@ -342,24 +344,32 @@ def hist_level_probe(shapes, num_bins=MAX_BIN + 1, trees=1):
                     jax.random.normal(k_gh, (groups, 2 * size, n_pad), jnp.float32),
                     node.reshape(groups, size, n_pad),
                 )
-            fn = jax.jit(H._pallas_hist_fn(
-                n_pad, d, fg, W, B, block, prec, H.pallas_interpret(),
-                split_missing, rows, chunks, fold, class_groups,
-            ))
+            if pack > 1:  # ``pack`` features a latched tile, the level's real rows
+                rows, fold = H._slot_rows(W, bin_lanes, pack), bin_lanes * pack // 128
+                fn = jax.jit(H._pallas_hist_packed_fn(
+                    n_pad, d, fg, W, B, block, prec, H.pallas_interpret(),
+                    split_missing, chunks, pack,
+                ))
+            else:
+                fn = jax.jit(H._pallas_hist_fn(
+                    n_pad, d, fg, W, B, block, prec, H.pallas_interpret(),
+                    split_missing, rows, chunks, fold, class_groups,
+                ))
             ms = _time(fn, bins, *operands)
-            # what the MXU is handed: for every real feature ``fold`` copies
-            # of the operand's rows (both halves of bf16x2 in one dot)
-            # against a [block, bin_lanes / fold] one-hot, two flops a
-            # multiply-add, once a class group; a tile is [128 rows, 128 bin
-            # lanes] of it
-            streamed = fold * rows * (2 if prec == "bf16x2" else 1)
-            flops = 2.0 * groups * n_pad * d * streamed * (bin_lanes // fold)
+            # what the MXU is handed: for every tile of ``pack`` features
+            # ``fold`` copies of each one's operand rows (both halves of
+            # bf16x2 in one dot) against a [block, pack * bin_lanes / fold]
+            # one-hot, two flops a multiply-add, once a class group; a tile is
+            # [128 rows, 128 lanes] of it, a tile with one real feature whole
+            streamed = pack * fold * rows * (2 if prec == "bf16x2" else 1)
+            lane_tiles = -(-d // pack) * (pack * bin_lanes // fold // 128)
+            flops = 2.0 * groups * n_pad * streamed * lane_tiles * 128
             row = {
                 "shape": name, "W": W, "prec": prec, "trees": trees,
                 "tree_groups": groups, "operand_rows": rows,
-                "chunks": chunks, "fold": fold,
+                "chunks": chunks, "fold": fold, "pack": pack,
                 "streamed_rows_a_tile": streamed,
-                "tiles_latched": groups * (n_pad // 128) * d * (bin_lanes // 128 // fold),
+                "tiles_latched": groups * (n_pad // 128) * lane_tiles,
                 "ms": ms,
                 "mxu_share_of_peak": flops / (ms * 1e-3) / peak if peak else None,
             }
@@ -373,12 +383,20 @@ def hist_level_probe(shapes, num_bins=MAX_BIN + 1, trees=1):
             for fold in sorted({1, H._bin_fold(rows, bin_lanes, "bf16x2")}):
                 call(W, rows, H._row_chunks(W, cap), fold=fold,
                      class_groups=class_groups)
+            # the shipped pack (one tree), beside the shipped fold above
+            pack = H._tile_pack(W, bin_lanes, "bf16x2") if trees == 1 else 1
+            if pack > 1:
+                call(W, rows, H._row_chunks(W, cap), pack=pack)
         # the rule's table: one latched one-hot tile costs what 64 streamed
         # rows cost; the one-pass control reaches 16 rows a tile
         for prec in H.HIST_PRECISIONS if trees == 1 else ():
             for rows in HIST_PROBE_ROWS:
                 for fold in sorted({1, H._bin_fold(rows, bin_lanes, prec)}):
                     call(1, rows, 1, prec, fold)
+            # and the control's packed call: half the streamed rows a tile
+            pack = H._tile_pack(1, bin_lanes, prec)
+            if prec != "bf16x2" and pack > 1:
+                call(1, None, H._row_chunks(1, cap), prec, pack=pack)
         del bins, gh
     return out
 

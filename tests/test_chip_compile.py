@@ -166,6 +166,50 @@ def test_ten_class_trees_in_one_operand_compile_for_the_chip(
     assert compiled.as_text().count("tpu_custom_call") >= 1
 
 
+@pytest.mark.parametrize(
+    "n, d, B, dtype, W, prec, members",
+    [
+        (8_800_000, 28, 257, jnp.uint16, 1, "bf16x2", 0),      # higgs-d8's root
+        (16_387_491, 39, 257, jnp.uint16, 2, "bf16x2", 0),     # criteo-tb-d8: an odd width
+        (2_270_296, 136, 256, jnp.uint8, 2, "bf16x2", 0),      # u8 bins, five feature groups
+        (8_800_000, 28, 257, jnp.uint16, 4, "bf16", 0),        # the one-pass control packs W = 4
+        (1_000_000, 28, 128, jnp.uint8, 1, "bf16", 0),         # and four features at one bin tile
+        (1_000_000, 28, 257, jnp.uint16, 2, "bf16x2", 3),      # DART, the CV folds: Pallas's own rule
+    ],
+)
+def test_packed_level_kernel_compiles_for_the_chip(
+    one_chip, no_compile_cache, monkeypatch, n, d, B, dtype, W, prec, members
+):
+    """The narrow levels' packed body (``ops/histogram.py::_tile_pack``, PR
+    47: two features a latched tile, operand and one-hot built as 32-bit
+    words and bitcast to bf16) through the chip's own kernel compiler, which
+    refuses what the interpreter takes: a slice off the tiling, a bitcast it
+    cannot lay out."""
+    from sagemaker_xgboost_container_tpu.ops import histogram as hist_mod
+
+    monkeypatch.setattr(hist_mod, "pallas_interpret", lambda: False)
+    assert hist_mod._tile_pack(W, hist_mod._bin_lanes(B), prec) > 1
+    lead = (members,) if members else ()
+
+    def shape(dims, kind):
+        return jax.ShapeDtypeStruct(dims, kind, sharding=one_chip)
+
+    def level(b, g, h, node):
+        return hist_mod._hist_pallas(b, g, h, node, W, B, prec=prec)
+
+    if members:  # mapped over the members' gradients, the bins the one matrix
+        level = jax.vmap(level, in_axes=(None, 0, 0, 0))
+    compiled = (
+        jax.jit(level)
+        .lower(
+            shape((n, d), dtype), shape(lead + (n,), jnp.float32),
+            shape(lead + (n,), jnp.float32), shape(lead + (n,), jnp.int32),
+        )
+        .compile()
+    )
+    assert compiled.as_text().count("tpu_custom_call") >= 1
+
+
 # ------------------------------------------------- a ranking round's gathers
 def _computations(hlo):
     """name -> text of every computation of an HLO module."""

@@ -49,14 +49,20 @@ def _find(jaxpr, primitive):
 PIN_ROWS, PIN_FEATURES, PIN_BINS = 32768, 28, 257   # 64 row blocks: 2 chunks
 
 # W -> (equations of the kernel body, grid, operand rows), read off 06c84c3;
-# the same for u8 and u16 bins (the widening is one convert either way)
+# the same for u8 and u16 bins (the widening is one convert either way).
+# Since PR 47 the levels W <= 2 take the packed body (``_tile_pack``): 16
+# tiles of two features where the folded one unrolls 32 features, a slab of
+# (feature, copy, k) x (feature', low) a tile, untangled after the call
 ONE_TREE_KERNELS = {
-    1: (970, (1, 2, 32), 16),      # folded: two masked copies a feature
+    1: (765, (1, 2, 32), 4),       # packed: two features a latched tile
+    2: (765, (1, 1, 64), 4),
+    4: (970, (1, 1, 64), 16),      # folded: two masked copies a feature
     8: (970, (1, 1, 64), 16),
     16: (326, (1, 1, 64), 32),     # unfolded
     64: (326, (1, 1, 64), 128),
 }
 ONE_TREE_WRAPPER_EQNS = 39         # _hist_pallas around the call
+PACKED_WRAPPER_EQNS = 52           # and the slabs' untangling
 
 
 @pytest.mark.parametrize("dtype", [np.uint8, np.uint16], ids=["u8", "u16"])
@@ -64,6 +70,8 @@ ONE_TREE_WRAPPER_EQNS = 39         # _hist_pallas around the call
 def test_one_tree_level_traces_the_kernel_it_always_traced(W, dtype):
     eqns, grid, rows = ONE_TREE_KERNELS[W]
     n, d, B = PIN_ROWS, PIN_FEATURES, PIN_BINS
+    packed = hist_mod._tile_pack(W, 256, "bf16x2") > 1
+    assert packed == (W <= 2)
     closed = jax.make_jaxpr(
         lambda b, g, h, node: hist_mod._hist_pallas(b, g, h, node, W, B)
     )(jnp.zeros((n, d), dtype), jnp.zeros(n), jnp.zeros(n), jnp.zeros(n, jnp.int32))
@@ -71,18 +79,22 @@ def test_one_tree_level_traces_the_kernel_it_always_traced(W, dtype):
     kernel = call.params["jaxpr"]
     mapping = call.params["grid_mapping"]
     assert _count_eqns(kernel) == eqns
-    assert _count_eqns(closed.jaxpr) - eqns == ONE_TREE_WRAPPER_EQNS
+    assert _count_eqns(closed.jaxpr) - eqns == (
+        PACKED_WRAPPER_EQNS if packed else ONE_TREE_WRAPPER_EQNS
+    )
     assert tuple(mapping.grid) == grid
     blocks = [
         tuple(getattr(x, "block_size", x) for x in bm.block_shape)
         for bm in mapping.block_mappings
     ]
-    assert blocks == [
-        (32, 512), (2, 512), (1, 512), (1, 32, rows, 256), (1, 32, 2 * rows),
-    ]
+    # packed: 16 tiles of [2 features x 4 copies x 4 rows, 128 lanes]; the
+    # missing bin's dot meets the four slots of both halves
+    slab = (16, 32, 128) if packed else (32, rows, 256)
+    miss = (32, 32) if packed else (32, 2 * rows)
+    assert blocks == [(32, 512), (2, 512), (1, 512), (1,) + slab, (1,) + miss]
     chunks = grid[1]
     assert [tuple(a.shape) for a in call.params["out_avals"]] == [
-        (chunks, 32, rows, 256), (chunks, 32, 2 * rows),
+        (chunks,) + slab, (chunks,) + miss,
     ]
     # nothing wrapped round the call: no custom_vmap, no inner jit
     assert not _find(closed.jaxpr, "custom_vmap_call") and not _find(closed.jaxpr, "pjit")
@@ -104,9 +116,11 @@ def _program_counts():
 def test_one_tree_round_program_fires_the_trace_and_lower_events_it_always_fired():
     """``xla_programs_total{stage}`` under the first dispatch of a small
     one-tree job on the chip's program, with jax's caches dropped first so
-    that no earlier test's traces are found again: 1,306 ``trace`` events
-    (every inner jit of the round program, the interpreter's included) and
-    one ``lower``, as on 06c84c3. A count, so it cannot wander with the
+    that no earlier test's traces are found again: 1,018 ``trace`` events
+    (every inner jit of the round program, the interpreter's included; 1,306
+    on 06c84c3 and up to PR 46: the three levels of this depth-3 tree take
+    the packed body since PR 47, two tiles where the folded one unrolled
+    four features) and one ``lower``. A count, so it cannot wander with the
     host; a wrapper that traces the build or the level again moves it."""
     from sagemaker_xgboost_container_tpu.data.matrix import DataMatrix
     from sagemaker_xgboost_container_tpu.models import train
@@ -120,7 +134,7 @@ def test_one_tree_round_program_fires_the_trace_and_lower_events_it_always_fired
     )
     after = _program_counts()
     fired = {stage: after[stage] - before.get(stage, 0) for stage in after}
-    assert fired.get("trace") == 1306
+    assert fired.get("trace") == 1018
     assert fired.get("lower") == 1
 
 
@@ -311,7 +325,8 @@ def test_mnist8m_round_latches_31_million_tiles():
         trees_per_round=10,
     )
     assert tiles(class_trees=10) == (31_109_120, 311_091_200)
-    assert tiles() == (155_545_600, 311_091_200)
+    # ten one-tree calls a level: half, and a quarter where two features share a tile (W <= 2, PR 47)
+    assert tiles() == (108_881_920, 311_091_200)
     # bagged classes: the class trees of each bagged step share, the steps do not
     assert hist_mod.round_onehot_tiles(
         levels, 506_250, 784, 257, "bf16x2", trees_per_round=20, class_trees=10

@@ -396,12 +396,14 @@ def test_round_hist_levels_are_the_calls_a_build_issues(policy, depth, leaves, s
 @pytest.mark.parametrize(
     "n, d, B, prec, depth, pct",
     [
-        (8_800_000, 28, 257, "bf16x2", 8, 68.75),     # higgs-d8: five of eight levels at half
-        (2_270_296, 136, 257, "bf16x2", 8, 68.75),    # mslr-ndcg
-        (16_387_491, 39, 257, "bf16x2", 8, 68.75),    # both Criteo cells, a chip
-        (8_800_000, 28, 257, "bf16", 8, 62.5),        # the one-pass control: six of eight
-        (8_800_000, 28, 128, "bf16x2", 8, 100.0),     # one bin tile: nothing to fold
-        (8_800_000, 28, 257, "bf16x2", 4, 50.0),      # a depth-4 tree: every level
+        # higgs-d8: W = 1, 1, 2 at a quarter (two features a tile, PR 47), W = 4, 8 at half
+        (8_800_000, 28, 257, "bf16x2", 8, 59.375),
+        (2_270_296, 136, 257, "bf16x2", 8, 59.375),   # mslr-ndcg
+        # both Criteo cells, a chip: the twentieth tile holds one real feature and is whole
+        (16_387_491, 39, 257, "bf16x2", 8, 100.0 * (3 * 20 + 2 * 39 + 3 * 78) / (8 * 78)),
+        (8_800_000, 28, 257, "bf16", 8, 50.0),        # the one-pass control: W = 4 packs too
+        (8_800_000, 28, 128, "bf16x2", 8, 75.0),      # one bin tile: nothing folds, W <= 4 packs
+        (8_800_000, 28, 257, "bf16x2", 4, 31.25),     # a depth-4 tree: every level
     ],
 )
 def test_onehot_tile_plan_counts_what_the_fold_saves(n, d, B, prec, depth, pct):
@@ -413,6 +415,23 @@ def test_onehot_tile_plan_counts_what_the_fold_saves(n, d, B, prec, depth, pct):
     twice = hist_mod.round_onehot_tiles(levels, n, d, B, prec, trees_per_round=2)
     assert twice == (2 * latched, 2 * unfolded)
     assert hist_mod.round_onehot_tiles(levels, 0, d, B, prec) == (0, 0)
+
+
+def test_tile_plan_packs_the_one_tree_levels_alone():
+    """A tile of two features with one real one is a whole tile (39 columns
+    are 20 tiles at W <= 2), and the class trees' operand is full at W = 1:
+    `mnist8m-mc10`'s ten depth-5 class trees latch the 31,109,120 tiles they
+    latched (the number ``tests/benchmark/test_multiclass_cell.py`` and the
+    cell hold)."""
+    row_tiles = -(-16_387_491 // (512 * 32)) * 512 * 32 // 128
+    root = hist_mod.round_onehot_tiles([(1, 1)], 16_387_491, 39, 257, "bf16x2")
+    assert root == (20 * row_tiles, 2 * 39 * row_tiles)
+    level2 = hist_mod.round_onehot_tiles([(4, 1)], 16_387_491, 39, 257, "bf16x2")
+    assert level2 == (39 * row_tiles, 2 * 39 * row_tiles)
+    levels = hist_mod.round_hist_levels("depthwise", 5, 0, True)
+    assert hist_mod.round_onehot_tiles(
+        levels, 506_250, 784, 257, "bf16x2", trees_per_round=10, class_trees=10
+    ) == (31_109_120, 311_091_200)
 
 
 @pytest.mark.parametrize("chip", [True, False])
@@ -444,8 +463,9 @@ def test_session_states_its_tile_plan_where_the_kernel_builds(chip):
         return
     # 600 rows pad to 1,024: 8 row tiles x 4 features x 2 bin tiles, 5 levels
     assert under == 5 * 8 * 4 * 2
-    # W = 1, 1, 2, 4, 8: every level of a depth-5 tree folds
-    assert over == under // 2
+    # W = 1, 1, 2, 4, 8: every level of a depth-5 tree folds, and at the first
+    # three the four features are two tiles (PR 47)
+    assert over == (3 * 8 * 2 + 2 * 8 * 4)
 
 
 @pytest.mark.parametrize("d, dtype", [(28, np.uint16), (40, np.uint8), (70, np.uint8)])

@@ -23,8 +23,10 @@ FOLD_BINS = [
 
 
 def _unfolded(monkeypatch):
-    """The kernel with the whole bin axis in its one-hot: the parent's."""
+    """The kernel with the whole bin axis in its one-hot, a feature a tile:
+    PR 35's parent's."""
     monkeypatch.setattr(hist_mod, "_bin_fold", lambda rows, lanes, prec: 1)
+    monkeypatch.setattr(hist_mod, "_tile_pack", lambda W, lanes, prec: 1)
 
 
 @pytest.fixture
@@ -36,6 +38,7 @@ def drop_compiled_kernels():
     import jax
 
     hist_mod._pallas_hist_fn.cache_clear()
+    hist_mod._pallas_hist_packed_fn.cache_clear()
     jax.clear_caches()
 
 
@@ -49,12 +52,18 @@ def test_folded_kernel_equals_the_unfolded_one_to_the_bit(
     bin's low part alone and the high part picks the operand's copy: every
     product lands where it landed, so both histograms keep every bit, over
     rows in the missing bin (B - 1), dead rows, a row count that pads (1,100
-    to three blocks), one bin tile (no fold), two and four."""
+    to three blocks), one bin tile (no fold), two and four. Where two
+    features share a tile (W <= 2 at two bin tiles, W <= 4 at one) the
+    shipped kernel is the packed one."""
     bins, grad, hess, node = _level_problem(41 + W, 1100, d, B, W, dtype)
     assert (np.asarray(node) < 0).any() and (np.asarray(bins) == B - 1).any()
     lanes = hist_mod._bin_lanes(B)
     fold = hist_mod._bin_fold(hist_mod._operand_rows(W), lanes, "bf16x2")
     assert fold == (2 if W <= 8 and lanes >= 256 else 1)
+    # since PR 47 the narrowest levels take the packed body (``_tile_pack``),
+    # held to the same unfolded kernel
+    pack = hist_mod._tile_pack(W, lanes, "bf16x2")
+    assert pack == (2 if lanes <= 256 and W * lanes <= 512 else 1)
     G1, H1 = hist_mod._hist_pallas(bins, grad, hess, node, W, B)
     _unfolded(monkeypatch)
     G0, H0 = hist_mod._hist_pallas(bins, grad, hess, node, W, B)

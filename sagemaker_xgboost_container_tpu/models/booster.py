@@ -43,11 +43,13 @@ from ..data.binning import (
 )
 from ..ops.histogram import (
     choose_hist_impl,
+    dead_bin_tiles,
     padded_feature_width,
     resolve_hist_knobs,
     round_comm_plan,
     round_hist_levels,
     round_onehot_tiles,
+    skipped_tiles_pct,
 )
 from ..ops.ranking import (
     GroupLayout,
@@ -917,20 +919,30 @@ class _TrainingSession:
         # the traced code moves the compile cache's key (PERF.md section 5)
         # and costs every job one cold compile
         _note_round_shape(self)
-        tiles, tiles_unfolded = self._onehot_tile_plan()
+        tiles, tiles_unfolded, skipped_pct = self._onehot_tile_plan()
         REGISTRY.gauge(
             "hist_onehot_tiles_per_round",
             "One-hot tiles ([128 rows, 128 bin lanes]) a shard's level "
             "histogram kernel latches a round: over the build's histogram "
             "levels, row tiles x features x bin tiles after the fold (x tiles of "
-            "features where they share one), once a group of class trees (ops/"
-            "histogram.py::_bin_fold, _tile_pack, _class_groups; 0 off the kernel)",
+            "features where they share one), once a group of class trees, less "
+            "the tiles above a column's highest bin in the calls that do not "
+            "fold (ops/histogram.py::_bin_fold, _tile_pack, _class_groups, "
+            "_live_tiles; 0 off the kernel)",
         ).set(tiles)
         REGISTRY.gauge(
             "hist_onehot_tiles_unfolded_per_round",
             "The same count with every level's fold at 1: what the kernel "
             "would latch with the whole bin axis in the one-hot",
         ).set(tiles_unfolded)
+        REGISTRY.gauge(
+            "hist_tiles_skipped_pct",
+            "Share (%) of the one-hot tiles of the round's calls that do not "
+            "fold which the kernel does not build because no bin of their "
+            "column can land on them, by the cuts the session was built with "
+            "(the kernel's lists are made from num_cuts on the chip; 0 where "
+            "every call folds)",
+        ).set(skipped_pct)
 
         # every dispatch records a `host_dispatch` span (python + XLA
         # dispatch until the async call returns) and a `device_sync` span
@@ -1739,14 +1751,20 @@ class _TrainingSession:
         )
 
     def _onehot_tile_plan(self):
-        """(latched, unfolded) one-hot tiles a round of this session's level
-        histogram kernel — ops.histogram.round_onehot_tiles over a shard's
-        rows and columns; (0, 0) where the builder is not the kernel."""
+        """(latched, unfolded, skipped %) one-hot tiles a round of this
+        session's level histogram kernel: ops.histogram.round_onehot_tiles
+        and skipped_tiles_pct over a shard's rows and columns, the dead tiles
+        counted from the host's copy of the cuts (a feature shard's: the
+        mean); zeros where the builder is not the kernel."""
         if choose_hist_impl(self.hist_knobs.backend) != "pallas":
-            return 0, 0
+            return 0, 0, 0.0
         cfg = self.config
         d_local, num_bins, subtract, trees_per_round = self._build_structure()
-        return round_onehot_tiles(
+        reach = np.asarray(
+            [len(c) for c in self.cuts] if self.bundle is None
+            else self.bundle.tables.reach
+        ).reshape(self.n_feature_shards, d_local)
+        plan = (
             round_hist_levels(
                 cfg.grow_policy, cfg.max_depth, cfg.max_leaves, subtract,
                 self._pass_slots(subtract),
@@ -1755,9 +1773,14 @@ class _TrainingSession:
             d_local,
             num_bins,
             self.hist_knobs.precision,
+        )
+        shape = dict(
             trees_per_round=trees_per_round,
             class_trees=self._class_operand_trees(),
+            dead_tiles=sum(dead_bin_tiles(shard, num_bins, self.bins.dtype) for shard in reach)
+            // self.n_feature_shards,
         )
+        return round_onehot_tiles(*plan, **shape) + (skipped_tiles_pct(*plan, **shape),)
 
     def _class_operand_trees(self):
         """Trees of a round whose gradients are ONE operand of the level

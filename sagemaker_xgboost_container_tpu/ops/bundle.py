@@ -52,6 +52,13 @@ class BundleTables:
             raise ValueError("a bundle's positions do not fit the range word")
 
     @property
+    def reach(self):
+        """[bundles] int32: a bundle's highest position, what a dense
+        column's count of cuts is to the level histogram (the bins its rows
+        can sit in, the missing slot apart)."""
+        return self.hi.max(axis=1, initial=1) - 1
+
+    @property
     def range_words(self):
         """[bundles, positions] int32: ``lo`` and ``hi`` of a position's
         member as one word, what a row reads of its node beside the split."""

@@ -21,11 +21,14 @@ Two builders, one a backend (``choose_hist_impl``, as
   of a bin's low part alone, half the tiles, and the bin's high part picks
   which copy of the operand a row's gradients ride. At the narrowest levels
   (W <= 2) the rows that really hold a gradient are so few that two features
-  share every latched tile (``_tile_pack``): half the tiles again. The class
-  trees of a round read one bin matrix, so they share a call and its latched
-  tiles:
-  their gradients are rows of one operand (``_class_groups``). Interpreted
-  on the CPU backend (tests, rehearsals).
+  share every latched tile (``_tile_pack``): half the tiles again. The calls
+  that do not fold (W >= 16, the class trees') take a dot a 128-lane bin
+  tile, and a tile no bin of its column can land on is not built: the tiles
+  above a feature's first come off a list made on the chip from the
+  columns' cut counts (``_live_tiles``, ``_pallas_hist_tiles_fn``).
+  The class trees of a round read one bin matrix, so they share a call and
+  its latched tiles: their gradients are rows of one operand
+  (``_class_groups``). Interpreted on the CPU backend (tests, rehearsals).
 * ``flat`` (everything else, and the tests' reference): one
   ``jax.ops.segment_sum`` over n*d flattened (node, feature, bin) ids. XLA
   lowers it to a sorted scatter-add — correct everywhere, fast on CPU,
@@ -38,6 +41,7 @@ and two node-total lowerings (``choose_totals_impl``): ``onehot`` on the TPU,
 
 import collections
 import functools
+import math
 import os
 
 import jax
@@ -221,6 +225,7 @@ def level_histogram(
     knobs=None,
     impl=None,
     class_vmap=False,
+    reach=None,
 ):
     """Build (G, H) histograms for one tree level.
 
@@ -242,6 +247,11 @@ def level_histogram(
         program): the kernel then takes the trees' gradients as one operand
         (``_class_hist_fn``). A one-tree build leaves it False and traces
         what it always traced.
+      reach: i32 [d], traced: the highest bin a row of each column can sit
+        in, the missing bin apart (a column's count of cuts; a bundle's
+        highest position). The kernel's unfolded calls skip, feature by
+        feature, the one-hot tiles above it (``_live_tiles``); None, for
+        direct callers, builds every tile. The same bits either way.
 
     Returns:
       (G, H): f32 [num_nodes, d, num_bins].
@@ -252,11 +262,12 @@ def level_histogram(
         prec = knobs.precision if knobs is not None else HIST_PRECISIONS[0]
         if class_vmap:
             G, H = _class_hist_fn(num_nodes, num_bins, prec)(
-                bins, grad, hess, node_local
+                bins, grad, hess, node_local, reach
             )
         else:
             G, H = _hist_pallas(
                 bins, grad, hess, node_local, num_nodes, num_bins, prec=prec,
+                reach=reach,
             )
     elif impl == "flat":
         G, H = _hist_flat(bins, grad, hess, node_local, num_nodes, num_bins)
@@ -473,6 +484,22 @@ def _bin_fold(rows, bin_lanes, prec):
         2.27M x 136, fold 1    61.7    61.4    61.6    61.3    63.0    114.7    219.0
         2.27M x 136, fold 2    37.2    37.3    37.2    37.3
 
+    Since PR 49 the ``fold`` 1 rows are ``_pallas_hist_tiles_fn``'s (a dot a
+    bin tile and row block, four row blocks a grid step, the tiles above a
+    feature's first off a list of the live ones). ``scripts/dissect.py
+    --hist-levels`` and ``--narrow-every 3`` (every third column at 127
+    cuts, one bin tile; 8 of 56 and 32 of 272 tiles left out) re-read it,
+    one v5e, jax 0.9.0, PR 49; the body it replaced beside it from a scratch
+    script of the same calls, the same bits:
+
+        W (operand rows)                16 (32)  32 (64)  64 (128)
+        8.8M x 28, as it was             46.0     86.8    169.3
+        8.8M x 28, all at 255 cuts       48.6     89.6    172.9
+        8.8M x 28, every third at 127    41.5     76.7    148.1
+        2.27M x 136, as it was           63.2    114.5    218.9
+        2.27M x 136, all at 255 cuts     62.0    113.5    217.9
+        2.27M x 136, every third         54.4     99.9    192.1
+
     and the one-pass control at W = 1 (its operand is one half: 16 streamed
     rows a copy): 44.8 -> 24.4 and 60.7 -> 35.5. Half the tiles at 64
     streamed rows cost 56 to 61 % of all of them at 32 (3.86M -> 1.93M tiles
@@ -595,7 +622,7 @@ def _class_groups(W, trees):
 
 
 def round_onehot_tiles(levels, n, d, num_bins, prec, trees_per_round=1,
-                       class_trees=1):
+                       class_trees=1, dead_tiles=0):
     """``(latched, unfolded)``: the [128, 128] one-hot tiles the Pallas kernel
     latches a round over ``n`` rows x ``d`` features (a shard's), summed over
     ``levels`` (``round_hist_levels``): row tiles x features x bin tiles
@@ -605,23 +632,87 @@ def round_onehot_tiles(levels, n, d, num_bins, prec, trees_per_round=1,
     ``trees_per_round`` trees, ``class_trees`` at a time share their latches
     (the class trees of one bagged step: ``_class_groups``), so a level
     latches once a class group, not once a tree; ``unfolded`` counts every
-    tree. From shapes alone: what the fold, the pack and the class operand
-    engage on, stated before a round runs."""
+    tree. ``dead_tiles``: the bin tiles, summed over the ``d`` features, that
+    a call which neither folds nor packs does not build because no bin of
+    their column can land on them (``dead_bin_tiles``). From shapes and the
+    columns' cut counts alone: what the fold, the pack, the class operand
+    and the live-tile rule engage on, stated before a round runs."""
+    latched, unfolded, _skipped, _plain = _round_tiles(
+        levels, n, d, num_bins, prec, trees_per_round, class_trees, dead_tiles
+    )
+    return latched, unfolded
+
+
+def skipped_tiles_pct(levels, n, d, num_bins, prec, trees_per_round=1,
+                      class_trees=1, dead_tiles=0):
+    """Share (%) of the one-hot tiles of a round's calls that neither fold
+    nor pack which the live-tile rule leaves unbuilt (``round_onehot_tiles``'s
+    arguments); 0 where every call folds."""
+    _latched, _unfolded, skipped, plain = _round_tiles(
+        levels, n, d, num_bins, prec, trees_per_round, class_trees, dead_tiles
+    )
+    return 100.0 * skipped / plain if plain else 0.0
+
+
+def _round_tiles(levels, n, d, num_bins, prec, trees_per_round, class_trees,
+                 dead_tiles):
+    """``(latched, unfolded, skipped, plain)``: ``round_onehot_tiles``'s two
+    counts, the tiles the live-tile rule skips, and all the tiles of the
+    calls it reads (``fold`` and ``pack`` 1)."""
     block = PALLAS_ROW_BLOCK
     row_tiles = _round_up(n, block * _chunk_cap(-(-n // block))) // 128
     lanes = _bin_lanes(num_bins)
-    latched = unfolded = 0
+    latched = unfolded = skipped = plain = 0
     for W, count in levels:
         tiles = count * row_tiles * d * (lanes // 128)
         unfolded += tiles * trees_per_round
         size, groups = _class_groups(W, class_trees)       # (1, 1) for one tree
         calls = trees_per_round // class_trees * groups
         pack = _tile_pack(W, lanes, prec) if class_trees == 1 else 1
+        fold = _bin_fold(_operand_rows(W, size), lanes, prec)
         if pack > 1:
             latched += count * row_tiles * -(-d // pack) * calls
+        elif fold > 1:
+            latched += tiles * calls // fold
         else:
-            latched += tiles * calls // _bin_fold(_operand_rows(W, size), lanes, prec)
-    return latched, unfolded
+            dead = count * row_tiles * dead_tiles * calls
+            latched += tiles * calls - dead
+            skipped += dead
+            plain += tiles * calls
+    return latched, unfolded, skipped, plain
+
+
+def dead_bin_tiles(reach, num_bins, bins_dtype):
+    """Bin tiles the unfolded kernel does not build over the columns of
+    ``reach`` (host-side: a numpy int array, a column's count of cuts or a
+    bundle's highest position; bins stored as ``bins_dtype``, which sets the
+    kernel's feature groups), summed: ``round_onehot_tiles``'s
+    ``dead_tiles``. Of a group's tiles t > 0 the kernel builds the live ones
+    in whole blocks of LIVE_CHUNK_SLOTS (``_pallas_hist_tiles_fn``); the
+    last block's fill may be a padding feature's, which counts against."""
+    import numpy as np
+
+    reached = _tile_reached(np.asarray(reach), num_bins, np)[:, 1:]
+    fg = _pallas_feature_group(len(reached), bins_dtype)
+    dead = 0
+    for first in range(0, len(reached), fg):
+        live = reached[first:first + fg].sum(axis=0)               # a tile
+        built = np.minimum(fg, -(-live // LIVE_CHUNK_SLOTS) * LIVE_CHUNK_SLOTS)
+        dead += int((len(reached[first:first + fg]) - built).sum())
+    return dead
+
+
+def _tile_reached(reach, B, xp):
+    """bool [d, tiles]: a bin of column f can land on the 128-lane bin tile t
+    of the unfolded kernel's one-hot: ``128 * t <= reach[f]`` (a column of k
+    cuts holds bins 0 .. k), or the missing bin lives on it (where it is not
+    split out, ``_mxu_split_missing``). ``xp``: numpy on the host, jax.numpy
+    under trace."""
+    t = xp.arange(_bin_lanes(B) // 128, dtype=xp.int32)
+    on = 128 * t[None, :] <= reach[:, None].astype(xp.int32)
+    if not _mxu_split_missing(B):
+        on = on | (t == (B - 1) // 128)[None, :]
+    return on
 
 
 def _floor_pow2(x):
@@ -701,7 +792,8 @@ def _pallas_hist_fn(n, d, fg, W, B, block, prec, interpret, split_missing,
     halves of the product are added; the missing-bin product keeps both
     halves on its lane axis for the caller to add.
 
-    ``fold`` > 1 (see _bin_fold; 1 is the unfolded kernel): the latched
+    ``fold`` > 1 (see _bin_fold; 1 is the unfolded kernel, which
+    ``_pallas_hist_tiles_fn`` builds and this function hands out): the latched
     one-hot is that of ``bin % L``, L = Bp / fold lanes, and the streamed
     operand is ``fold`` copies of the stacked one, copy t zeroed (a select on
     the block's bf16 operand) in the rows whose ``bin // L`` is not t; copy
@@ -737,6 +829,11 @@ def _pallas_hist_fn(n, d, fg, W, B, block, prec, interpret, split_missing,
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if fold == 1:
+        return _pallas_hist_tiles_fn(
+            n, d, fg, W, B, block, prec, interpret, split_missing, rows, chunks,
+            class_groups,
+        )
     Bp = _round_up(B - 1 if split_missing else B, 128)
     d_pad = _round_up(d, fg)
     groups = d_pad // fg
@@ -788,17 +885,14 @@ def _pallas_hist_fn(n, d, fg, W, B, block, prec, interpret, split_missing,
 
         def feature(f):
             b = bw[f:f + 1, :]                         # [1, blk]
-            if fold == 1:
-                low, Af = b, A
-            else:
-                # bin = L * top + low: the one-hot of ``low`` is latched,
-                # ``top`` picks the copy of the operand a row's g and h ride
-                top = sum((b >= t * L).astype(jnp.int32) for t in range(1, fold))
-                low = b - L * top
-                zero = jnp.zeros_like(A)
-                Af = jnp.concatenate(
-                    [jnp.where(top == t, A, zero) for t in range(fold)], axis=0
-                )                                      # [fold * S, blk]
+            # bin = L * top + low: the one-hot of ``low`` is latched,
+            # ``top`` picks the copy of the operand a row's g and h ride
+            top = sum((b >= t * L).astype(jnp.int32) for t in range(1, fold))
+            low = b - L * top
+            zero = jnp.zeros_like(A)
+            Af = jnp.concatenate(
+                [jnp.where(top == t, A, zero) for t in range(fold)], axis=0
+            )                                          # [fold * S, blk]
             ob = (iota_b == low).astype(jnp.bfloat16)  # [L, blk]
             P = jax.lax.dot_general(
                 Af, ob, lanes, preferred_element_type=jnp.float32
@@ -849,6 +943,198 @@ def _pallas_hist_fn(n, d, fg, W, B, block, prec, interpret, split_missing,
             jax.ShapeDtypeStruct(qs + (chunks, d_pad, rows, Bp), jnp.float32),
             jax.ShapeDtypeStruct(qs + (chunks, d_pad, miss_rows), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * (len(q) + 2) + ("arbitrary",),
+            vmem_limit_bytes=vmem_limit,
+        ),
+        interpret=interpret,
+        name="graft_level_histogram",
+    )
+
+
+# second-tile slots of one conditional block of the unfolded kernel, and the
+# 512-row blocks one grid step of it covers (``_pallas_hist_tiles_fn``)
+LIVE_CHUNK_SLOTS = 4
+TILE_STEP_BLOCKS = 4
+
+
+def _pallas_hist_tiles_fn(n, d, fg, W, B, block, prec, interpret, split_missing,
+                          rows, chunks, class_groups):
+    """``_pallas_hist_fn`` at ``fold`` 1, the kernel of the calls that do not
+    fold (one tree at W >= 16, the class trees'): same operands and one more,
+    ``live`` i32 [feature groups, lists] whole in scalar memory
+    (``_live_tiles``), same results, same name.
+
+    A feature's dot over the Bp bin lanes is one dot a 128-lane bin tile.
+    Tile 0 of every feature is straight-line code, as the whole dot was.
+    The tiles t > 0 are taken from the group's list of the features whose
+    column can reach tile t (live ones first), LIVE_CHUNK_SLOTS list entries
+    a conditional block, a block run only where its first entry is live: so
+    a tile no bin of its column can land on is not built, but for the up to
+    LIVE_CHUNK_SLOTS - 1 that fill the last block of a list (their one-hot
+    is all zeros: the column holds no bin there). A tile that is skipped
+    leaves its lanes of the slab at the zeros the first grid step wrote,
+    which is what its dot against an all-zero one-hot added: the same bits.
+
+    Why blocks and not a branch a feature: a conditional block is scheduled
+    alone, so the latency of its first latch and its last accumulate is not
+    hidden behind another feature's work; one v5e, ms a call at 8.8M x 28,
+    all 28 columns at 255 cuts, W = 16 / 32 / 64 (PR 49's probe; one
+    256-lane dot a feature: 46.2 / 86.8 / 169.5, two 128-lane dots the
+    same): a branch round every second tile 112.9 / 153.9 / 236.9 (138 ns a
+    taken branch and row block, whatever W; 17 ns one not taken), a branch
+    between every two tiles 175.4 / 216.7 / 299.9. So a block holds several
+    tiles, and a grid step TILE_STEP_BLOCKS row blocks of 512 (each a dot of
+    its own, accumulated in row order: the same bits), where the rows divide
+    so.
+    """
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    Bp = _round_up(B - 1 if split_missing else B, 128)
+    tiles = Bp // 128
+    d_pad = _round_up(d, fg)
+    groups = d_pad // fg
+    real_in_last = d - (groups - 1) * fg   # features of the last group
+    blocks = n // (block * chunks)         # 512-row blocks a chunk
+    sub = math.gcd(blocks, TILE_STEP_BLOCKS)
+    per = blocks // sub                    # grid steps a chunk
+    step = sub * block                     # rows a grid step
+    stacked = prec == "bf16x2"
+    miss_rows = 2 * rows if stacked else rows
+    U = LIVE_CHUNK_SLOTS
+
+    size, tree_groups = class_groups or (1, None)
+    lead = () if class_groups is None else (0,)
+    axis0 = len(lead)
+    lanes = (((1,), (1,)), ((), ()))                   # contract rows
+
+    # The kernel's two pieces of arithmetic as jitted helpers: each is traced
+    # once and bound a row block, or a tile and a feature; the body itself is
+    # loads, stores and those calls, so what a job waits for in front of its
+    # first dispatch is little more than their lowering
+
+    @jax.jit
+    def operand_of(node, gh):
+        """The streamed operand of a row block ([S, blk] bf16) from its node
+        ids (i32 [size, blk]) and gradients (f32 [2 * size, blk])."""
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, block), 0)
+        A = jnp.zeros((rows, block), jnp.float32)
+        for t in range(size):                      # tree t: rows 2W * t ..
+            at = node[t:t + 1]                     # [1, blk]
+            dead = at >= W                         # out of BOTH halves
+            g_row = jnp.where(dead, -1, at + 2 * W * t)
+            h_row = jnp.where(dead, -1, at + 2 * W * t + W)
+            A = jnp.where(
+                row == g_row, gh[t:t + 1], jnp.where(row == h_row, gh[size + t:size + t + 1], A)
+            )
+        if stacked:
+            return jnp.concatenate(_split_bf16(A), axis=0)     # [2*rows, blk]
+        return A.astype(jnp.bfloat16)  # "bf16": one rounded half, the failing control
+
+    cut = [slice(k * block, (k + 1) * block) for k in range(sub)]  # a step's row blocks
+
+    @functools.partial(jax.jit, static_argnums=3)
+    def tile_of(slab, operand, b, t):
+        """A feature's slab lanes of bin tile t ([1, rows, 128]) with a grid
+        step's rows added: a dot a row block, the operand ([S, blk]) against
+        the one-hot of tile t of the feature's bins (b i32 [1, step]), the
+        halves of bf16x2 added, the row blocks' products in row order."""
+        lane_bin = jax.lax.broadcasted_iota(jnp.int32, (128, block), 0)
+        one = jnp.ones((128, block), jnp.float32)
+        zero = jnp.zeros((128, block), jnp.float32)
+        for k in range(sub):
+            at = jax.lax.slice_in_dim(b, cut[k].start, cut[k].stop, axis=1) - 128 * t
+            # (a 32-bit select, then f32 -> bf16: Mosaic lowers bool -> bf16
+            # through a helper traced at every use, a third of this body's
+            # lowering time, into bool -> i32 -> f32 -> bf16)
+            ob = jax.lax.select(
+                jax.lax.eq(lane_bin, jax.lax.broadcast_in_dim(at, (128, block), (0, 1))),
+                one, zero,
+            ).astype(jnp.bfloat16)                     # [128, blk]
+            P = jax.lax.dot_general(operand[k], ob, lanes, preferred_element_type=jnp.float32)
+            if stacked:
+                P = jax.lax.slice_in_dim(P, 0, rows) + jax.lax.slice_in_dim(P, rows, 2 * rows)
+            slab = slab + P[None]
+        return slab
+
+    def kernel(bins_ref, gh_ref, node_ref, live_ref, out_ref, miss_ref, bw_ref):
+        @pl.when(pl.program_id(axis0 + 2) == 0)
+        def _():
+            out_ref[...] = jnp.zeros_like(out_ref)
+            miss_ref[...] = jnp.zeros_like(miss_ref)
+
+        group = pl.program_id(axis0)
+        bw = bins_ref[...].astype(jnp.int32)           # widen in VMEM
+        if tiles > 1:
+            bw_ref[...] = bw                           # rows read by a traced index
+        operand = tuple(
+            operand_of(node_ref[lead + (slice(None), at)], gh_ref[lead + (slice(None), at)])
+            for at in cut
+        )
+
+        def tile(f, t, b):
+            # bin tile t of feature f (an int, or a traced index off a list)
+            at = lead + (0, pl.ds(f, 1), slice(None), slice(128 * t, 128 * (t + 1)))
+            out_ref[at] = tile_of(out_ref[at], operand, b, t)
+
+        def first_tiles(features):
+            for f in features:
+                tile(f, 0, jax.lax.slice_in_dim(bw, f, f + 1, axis=0))
+
+        first_tiles(range(real_in_last))               # real in every group
+        if real_in_last < fg and groups > 1:
+            @pl.when(group < groups - 1)
+            def _():
+                first_tiles(range(real_in_last, fg))
+
+        # the tiles above: LIVE_CHUNK_SLOTS entries of the group's list a
+        # block (a padding feature is on no list's live part)
+        for t in range(1, tiles):
+            base = (t - 1) * (fg + 1)
+            count = live_ref[group, base + fg]
+            for c in range(fg // U):
+                @pl.when(count > c * U)
+                def _(t=t, base=base, c=c):
+                    for u in range(U):
+                        f = live_ref[group, base + c * U + u]
+                        tile(f, t, bw_ref[pl.ds(f, 1), :])
+
+        if split_missing:
+            miss = (bw == (B - 1)).astype(jnp.bfloat16)    # [fg, step]
+            for k in range(sub):
+                miss_ref[lead + (0,)] += jax.lax.dot_general(
+                    miss[:, cut[k]], operand[k], lanes, preferred_element_type=jnp.float32
+                )
+
+    # accumulator blocks (main + the lane-padded missing-bin block) are
+    # double-buffered by the pipeline; operand blocks and the per-feature
+    # one-hot temporaries are small next to them
+    acc_bytes = fg * rows * (Bp + 128) * 4
+    vmem_limit = min(2 * acc_bytes + 16 * 1024 * 1024, 100 * 1024 * 1024)
+    q, qs = (1,) * axis0, (tree_groups,) * axis0
+
+    def behind_group(index):
+        return lambda *ids: ids[:-3] + index(*ids[-3:])
+
+    return pl.pallas_call(
+        kernel,
+        grid=qs + (groups, chunks, per),
+        in_specs=[
+            pl.BlockSpec((fg, step), lambda *ids: (ids[-3], ids[-2] * per + ids[-1])),
+            pl.BlockSpec(q + (2 * size, step), behind_group(lambda j, c, i: (0, c * per + i))),
+            pl.BlockSpec(q + (size, step), behind_group(lambda j, c, i: (0, c * per + i))),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+        ],
+        out_specs=[
+            pl.BlockSpec(q + (1, fg, rows, Bp), behind_group(lambda j, c, i: (c, j, 0, 0))),
+            pl.BlockSpec(q + (1, fg, miss_rows), behind_group(lambda j, c, i: (c, j, 0))),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(qs + (chunks, d_pad, rows, Bp), jnp.float32),
+            jax.ShapeDtypeStruct(qs + (chunks, d_pad, miss_rows), jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((fg, step), jnp.int32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * (len(q) + 2) + ("arbitrary",),
             vmem_limit_bytes=vmem_limit,
@@ -1013,8 +1299,39 @@ def _pallas_hist_packed_fn(n, d, fg, W, B, block, prec, interpret, split_missing
     return lambda bins_t, gh, node: untangle(*call(bins_t, gh, node))
 
 
+def _live_tiles(reach, d, fg, B):
+    """i32 [feature groups, (tiles - 1) * (fg + 1)], the unfolded kernel's
+    scalar operand: for every bin tile t > 0, the group's fg features in the
+    order the kernel takes them, those whose column can reach tile t first
+    (``_tile_reached``; ``reach`` i32 [d], traced; in their own order), then
+    how many those are. ``reach`` None: every real feature reaches every
+    tile. A padding feature reaches none. No sort and no gather: a feature's
+    place is a running count, and the list a compare-select-reduce over
+    [fg, fg]."""
+    tiles = _bin_lanes(B) // 128
+    d_pad = _round_up(d, fg)
+    if tiles == 1:                       # nothing above the one tile: no list
+        return jnp.zeros((d_pad // fg, 1), jnp.int32)
+    if reach is None:
+        on = jnp.ones((d, tiles), jnp.bool_)
+    else:
+        on = _tile_reached(reach, B, jnp)
+    on = jnp.pad(on[:, 1:], [(0, d_pad - d), (0, 0)])      # [d_pad, tiles - 1]
+    on = on.reshape(d_pad // fg, fg, tiles - 1).transpose(0, 2, 1).astype(jnp.int32)
+    count = on.sum(axis=-1, keepdims=True)                  # [groups, tiles - 1, 1]
+    place = jnp.where(
+        on > 0, jnp.cumsum(on, axis=-1) - 1, count + jnp.cumsum(1 - on, axis=-1) - 1
+    )
+    slot = jnp.arange(fg, dtype=jnp.int32)
+    listed = jnp.sum(
+        jnp.where(place[..., None, :] == slot[:, None], slot, 0), axis=-1
+    )                                                       # [groups, tiles - 1, fg]
+    lists = jnp.concatenate([listed, count], axis=-1).astype(jnp.int32)
+    return lists.reshape(d_pad // fg, (tiles - 1) * (fg + 1))
+
+
 def _hist_pallas(bins, grad, hess, node_local, num_nodes, num_bins,
-                 prec=HIST_PRECISIONS[0]):
+                 prec=HIST_PRECISIONS[0], reach=None):
     """One tree (``grad``, ``hess``, ``node_local`` f32 / i32 [n]) ->
     (G, H) f32 [W, d, B]; or the T class trees of a round over the one bin
     matrix (each [T, n]: the gradients' rank decides) -> [T, W, d, B], the
@@ -1053,6 +1370,7 @@ def _hist_pallas(bins, grad, hess, node_local, num_nodes, num_bins,
         gh = jnp.pad(jnp.stack([g, h]), [(0, 0), (0, n_pad - n)])
         node = jnp.pad(node, [(0, n_pad - n)], constant_values=W)
 
+        live = ()                  # the unfolded body's operand alone
         pack = _tile_pack(W, lanes, prec)
         if pack > 1:
             rows = _slot_rows(W, lanes, pack)
@@ -1062,12 +1380,16 @@ def _hist_pallas(bins, grad, hess, node_local, num_nodes, num_bins,
             )
         else:
             rows = _operand_rows(W)
+            fold = _bin_fold(rows, lanes, prec)
             fn = _pallas_hist_fn(
                 n_pad, d, fg, W, B, block, prec, interpret, split_missing,
-                rows, chunks, _bin_fold(rows, lanes, prec),
+                rows, chunks, fold,
             )
+            if fold == 1:
+                live = (_live_tiles(reach, d, fg, B),)
         GH = _sum_row_chunks(
-            *fn(bins_t, gh, node[None, :].astype(jnp.int32)), d, rows, B, split_missing
+            *fn(bins_t, gh, node[None, :].astype(jnp.int32), *live),
+            d, rows, B, split_missing,
         )
         GH = GH.transpose(1, 0, 2)                         # [rows, d, B]
         return GH[:W], GH[W:2 * W]
@@ -1079,12 +1401,14 @@ def _hist_pallas(bins, grad, hess, node_local, num_nodes, num_bins,
     g, h = (jnp.pad(x, fill).reshape(groups, size, n_pad) for x in (g, h))
     node = jnp.pad(node, fill, constant_values=W).reshape(groups, size, n_pad)
     rows = _operand_rows(W, size)
+    fold = _bin_fold(rows, lanes, prec)
     fn = _pallas_hist_fn(
         n_pad, d, fg, W, B, block, prec, interpret, split_missing,
-        rows, chunks, _bin_fold(rows, lanes, prec), class_groups=(size, groups),
+        rows, chunks, fold, class_groups=(size, groups),
     )
+    live = (_live_tiles(reach, d, fg, B),) if fold == 1 else ()
     GH = _sum_row_chunks(
-        *fn(bins_t, jnp.concatenate([g, h], axis=1), node.astype(jnp.int32)),
+        *fn(bins_t, jnp.concatenate([g, h], axis=1), node.astype(jnp.int32), *live),
         d, rows, B, split_missing,
     )                                                      # [groups, d, rows, B]
     # a group's rows are (tree, g | h, node): trees to the front
@@ -1119,19 +1443,22 @@ def _class_hist_fn(num_nodes, num_bins, prec):
     ``class_vmap``): a one-tree build calls ``_hist_pallas`` itself, with
     nothing wrapped around it."""
 
-    def call(bins, grad, hess, node_local):  # one tree's [n], or [T, n]
-        return _hist_pallas(bins, grad, hess, node_local, num_nodes, num_bins, prec=prec)
+    def call(bins, grad, hess, node_local, reach):  # one tree's [n], or [T, n]
+        return _hist_pallas(
+            bins, grad, hess, node_local, num_nodes, num_bins, prec=prec, reach=reach
+        )
 
     hist = jax.custom_batching.custom_vmap(call)
 
     @hist.def_vmap
-    def _(axis_size, in_batched, bins, grad, hess, node_local):
-        # the bins are the one matrix, never mapped; the root's node ids (all
-        # rows in node 0) are one row for every tree
+    def _(axis_size, in_batched, bins, grad, hess, node_local, reach):
+        # the bins are the one matrix and the columns' reach theirs, never
+        # mapped; the root's node ids (all rows in node 0) are one row for
+        # every tree
         grad, hess, node_local = (
             x if batched else jnp.broadcast_to(x, (axis_size,) + x.shape)
-            for x, batched in zip((grad, hess, node_local), in_batched[1:])
+            for x, batched in zip((grad, hess, node_local), in_batched[1:4])
         )
-        return call(bins, grad, hess, node_local), (True, True)
+        return call(bins, grad, hess, node_local, reach), (True, True)
 
     return hist

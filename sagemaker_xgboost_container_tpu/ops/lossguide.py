@@ -304,7 +304,7 @@ def build_tree_lossguide(
     with stage(STAGE_HIST):
         G, H = level_histogram(
             bins, grad, hess, jnp.zeros(n, jnp.int32), 1, num_bins,
-            axis_name=axis_name, knobs=knobs,
+            axis_name=axis_name, knobs=knobs, reach=num_cuts,
         )
         if subtract:
             hist_cache = (hist_cache[0].at[0].set(G[0]), hist_cache[1].at[0].set(H[0]))
@@ -430,7 +430,8 @@ def build_tree_lossguide(
         with stage(STAGE_HIST):
             G, H = apply_hist_collective(
                 *level_histogram(
-                    bins, grad, hess, slot_of_row, in_pass * kids, num_bins, knobs=knobs
+                    bins, grad, hess, slot_of_row, in_pass * kids, num_bins, knobs=knobs,
+                    reach=num_cuts,
                 ),
                 axis_name,
             )

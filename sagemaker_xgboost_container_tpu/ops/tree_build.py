@@ -351,6 +351,9 @@ def build_tree(
         jax.lax.axis_index(feature_axis_name) if feature_axis_name is not None else None
     )
 
+    # the highest bin a row of each column can sit in: the level histogram
+    # builds no one-hot tile above it (ops.histogram._live_tiles)
+    reach = num_cuts if bundle is None else jnp.asarray(bundle.reach)
     subtract = _subtraction_enabled(max_depth, d, num_bins)
     G_cache = H_cache = None      # previous level's [W/2, d, B] histograms
     parent_leaf = None            # previous level's becomes_leaf [W/2]
@@ -393,7 +396,7 @@ def build_tree(
                 left_local = jnp.where(active & is_left, node_local // 2, -1)
                 Gl, Hl = level_histogram(
                     bins, grad, hess, left_local, width // 2, num_bins,
-                    knobs=knobs, class_vmap=class_vmap,
+                    knobs=knobs, class_vmap=class_vmap, reach=reach,
                 )
                 # (after the kernel call, before the collective: the order the
                 # pinned one-tree program and the mesh program trace)
@@ -410,7 +413,7 @@ def build_tree(
                 G, H = apply_hist_collective(
                     *level_histogram(
                         bins, grad, hess, node_local, width, num_bins, knobs=knobs,
-                        class_vmap=class_vmap,
+                        class_vmap=class_vmap, reach=reach,
                     ),
                     axis_name,
                 )

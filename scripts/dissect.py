@@ -29,7 +29,9 @@ level also at ``fold`` 1, with the one-hot tiles a call latches) and the tile
 pack (``_tile_pack``: each packed level beside the shipped fold), then at
 W = 1 with the operand padded to more rows: the tables the two rules were
 read from; with ``--trees T`` the call of T class trees in one operand
-(``_class_groups``) at every W. Run under an external timeout,
+(``_class_groups``) at every W, and with ``--narrow-every K`` every K-th
+column at 127 cuts, whose second one-hot tile the calls that do not fold
+leave out (``_live_tiles``). Run under an external timeout,
 like anything that holds a device.
 """
 
@@ -295,7 +297,7 @@ HIST_PROBE_LEVELS = (1, 2, 4, 8, 16, 32, 64)  # a depth-8 tree with subtraction
 HIST_PROBE_ROWS = (16, 32, 64, 128)           # operand rows at W = 1
 
 
-def hist_level_probe(shapes, num_bins=MAX_BIN + 1, trees=1):
+def hist_level_probe(shapes, num_bins=MAX_BIN + 1, trees=1, narrow_every=0):
     """ms a call of the level histogram kernel by node count W under the
     shipped operand-row, chunk, fold and pack rules (a folded level also at
     ``fold`` 1, a packed one also unpacked at the shipped fold), then by
@@ -306,7 +308,11 @@ def hist_level_probe(shapes, num_bins=MAX_BIN + 1, trees=1):
     are made on the device. With ``trees`` > 1 the call is that of a round's
     class trees in one operand (``ops/histogram._class_groups``: the trees
     of a group share their latches, the groups are a grid axis), by node
-    count alone."""
+    count alone. With ``narrow_every`` = K every K-th column holds 127 cuts
+    (bins 0 to 127 and the missing bin), so the calls that do not fold leave
+    its second one-hot tile out (``ops/histogram._live_tiles``; but for what
+    fills a list's last block: ``dead_bin_tiles``); the other columns hold
+    255, and at K = 0 all do."""
     import jax
     import jax.numpy as jnp
 
@@ -326,14 +332,19 @@ def hist_level_probe(shapes, num_bins=MAX_BIN + 1, trees=1):
         cap = H._chunk_cap(-(-n // block))
         n_pad = -(-n // (block * cap)) * block * cap
         k_bins, k_gh, k_node = jax.random.split(jax.random.PRNGKey(31), 3)
+        narrow = jnp.arange(d_pad) % max(narrow_every, 1) == narrow_every - 1
         bins = jax.jit(
             lambda k: jnp.where(
                 (jnp.arange(d_pad) < d)[:, None],
-                jax.random.bits(k, (d_pad, n_pad), jnp.uint16) % B, 0,
+                _probe_bins(jax.random.bits(k, (d_pad, n_pad), jnp.uint16), B, narrow), 0,
             ).astype(dtype)
         )(k_bins)
         gh = jax.random.normal(k_gh, (2, n_pad), jnp.float32)
-        jax.block_until_ready((bins, gh))
+        # the unfolded body's operand: a column's live one-hot tiles
+        reach = jnp.where(narrow[:d], 127, B - 2)
+        live = H._live_tiles(reach, d, fg, B)
+        dead_tiles = H.dead_bin_tiles(np.asarray(reach), B, dtype)
+        jax.block_until_ready((bins, gh, live))
 
         def call(W, rows, chunks, prec="bf16x2", fold=1, class_groups=None, pack=1):
             size, groups = class_groups or (1, 1)
@@ -355,6 +366,10 @@ def hist_level_probe(shapes, num_bins=MAX_BIN + 1, trees=1):
                     n_pad, d, fg, W, B, block, prec, H.pallas_interpret(),
                     split_missing, rows, chunks, fold, class_groups,
                 ))
+            skipped = 0
+            if pack == 1 and fold == 1:
+                operands += (live,)
+                skipped = dead_tiles
             ms = _time(fn, bins, *operands)
             # what the MXU is handed: for every tile of ``pack`` features
             # ``fold`` copies of each one's operand rows (both halves of
@@ -362,7 +377,7 @@ def hist_level_probe(shapes, num_bins=MAX_BIN + 1, trees=1):
             # one-hot, two flops a multiply-add, once a class group; a tile is
             # [128 rows, 128 lanes] of it, a tile with one real feature whole
             streamed = pack * fold * rows * (2 if prec == "bf16x2" else 1)
-            lane_tiles = -(-d // pack) * (pack * bin_lanes // fold // 128)
+            lane_tiles = -(-d // pack) * (pack * bin_lanes // fold // 128) - skipped
             flops = 2.0 * groups * n_pad * streamed * lane_tiles * 128
             row = {
                 "shape": name, "W": W, "prec": prec, "trees": trees,
@@ -370,6 +385,7 @@ def hist_level_probe(shapes, num_bins=MAX_BIN + 1, trees=1):
                 "chunks": chunks, "fold": fold, "pack": pack,
                 "streamed_rows_a_tile": streamed,
                 "tiles_latched": groups * (n_pad // 128) * lane_tiles,
+                "tiles_skipped_a_row_tile": skipped,
                 "ms": ms,
                 "mxu_share_of_peak": flops / (ms * 1e-3) / peak if peak else None,
             }
@@ -399,6 +415,16 @@ def hist_level_probe(shapes, num_bins=MAX_BIN + 1, trees=1):
                 call(1, None, H._row_chunks(1, cap), prec, pack=pack)
         del bins, gh
     return out
+
+
+def _probe_bins(raw, B, narrow):
+    """Bins of the level-histogram probe from random 16-bit words [d, n]:
+    uniform over the B bins, the last one missing; a ``narrow`` column (bool
+    [d]) keeps its missing share and spreads the rest over bins 0 to 127."""
+    import jax.numpy as jnp
+
+    wide = raw % B
+    return jnp.where(narrow[:, None] & (wide != B - 1), raw % 128, wide)
 
 
 def _emit(summary, out_path):
@@ -431,6 +457,10 @@ def main():
     ap.add_argument(
         "--trees", type=int, default=1, metavar="T",
         help="with --hist-levels: the call of T class trees in one operand",
+    )
+    ap.add_argument(
+        "--narrow-every", type=int, default=0, metavar="K",
+        help="with --hist-levels: every K-th column holds 127 cuts, one bin tile",
     )
     ap.add_argument("--out", default=None, help="also write the JSON summary here")
     args = ap.parse_args()
@@ -477,7 +507,9 @@ def main():
         summary = {
             "backend": jax.default_backend(),
             "device_kind": jax.devices()[0].device_kind,
-            "hist_level_probe": hist_level_probe(shapes, trees=args.trees),
+            "hist_level_probe": hist_level_probe(
+                shapes, trees=args.trees, narrow_every=args.narrow_every
+            ),
         }
         _emit(summary, args.out)
         return

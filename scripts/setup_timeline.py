@@ -11,7 +11,8 @@ The line ``timeline {...}`` carries what the per-layer metrics of the set-up
 read, for every cell and without a trace (``higgs-d8.train-fused`` is on none
 of their lists yet): the start-up and set-up spans of
 ``training_phase_seconds`` (count and seconds), the program-load counters by
-stage and as wall, the ``setup_hbm_bytes`` gauges, each ``setup.*`` span as it
+stage and as wall, the ``setup_hbm_bytes`` gauges, the round's shape as the
+session stated it (``ROUND_SHAPE_GAUGES``), each ``setup.*`` span as it
 ended (seconds after ``train()`` was entered, its length, its thread), and
 what the caller itself did in front of ``train()`` that the program cannot
 see: the import of jax and the back end coming up, timed here because the
@@ -32,6 +33,15 @@ import threading  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
+
+
+# what the session says of its round and its columns at build: the one-hot
+# tiles the level histogram latches, the share the live-tile rule skips
+ROUND_SHAPE_GAUGES = (
+    "hist_onehot_tiles_per_round", "hist_onehot_tiles_unfolded_per_round",
+    "hist_tiles_skipped_pct", "train_columns_constant", "train_columns_total",
+    "sketch_cuts_selected",
+)
 
 
 def seconds_of(what):
@@ -59,7 +69,8 @@ def record_span_ends(ended):
 def timeline(caller, ended):
     from sagemaker_xgboost_container_tpu.telemetry import REGISTRY
 
-    out = {"caller": caller, "phases": {}, "program_seconds": {}, "program_wall": {}, "hbm": {}}
+    out = {"caller": caller, "phases": {}, "program_seconds": {}, "program_wall": {}, "hbm": {},
+           "round_shape": {}}
     for name, _kind, _help, family in REGISTRY.collect():
         for s in family:
             labels = s.labels or {}
@@ -74,6 +85,8 @@ def timeline(caller, ended):
                 out["hbm"][labels["phase"] + " " + labels["what"]] = s.value
             elif name == "process_start_time_seconds":
                 out["process_start_time_seconds"] = s.value
+            elif name in ROUND_SHAPE_GAUGES:
+                out["round_shape"][name] = s.value
     threads = {}
     first = min((start for _n, start, _s, _t in ended), default=0.0)
     out["spans"] = [

@@ -58,11 +58,19 @@ ONE_TREE_KERNELS = {
     2: (765, (1, 1, 64), 4),
     4: (970, (1, 1, 64), 16),      # folded: two masked copies a feature
     8: (970, (1, 1, 64), 16),
-    16: (326, (1, 1, 64), 32),     # unfolded
-    64: (326, (1, 1, 64), 128),
+    # unfolded. Since PR 49 a dot a 128-lane bin tile and a row block, four
+    # row blocks of 512 a grid step (``_pallas_hist_tiles_fn``): 28 first
+    # tiles straight-line, the tiles above them off a list of the group's
+    # live ones, four list entries a conditional block (eight blocks a group
+    # of 32), and the missing bin's dot a row block: 244 dots. The body is
+    # 179 equations, loads and stores round two jitted helpers (326 with one
+    # 256-lane dot a feature and row block, up to PR 47)
+    16: (3519, (1, 1, 16), 32),
+    64: (3519, (1, 1, 16), 128),
 }
 ONE_TREE_WRAPPER_EQNS = 39         # _hist_pallas around the call
 PACKED_WRAPPER_EQNS = 52           # and the slabs' untangling
+UNFOLDED_WRAPPER_EQNS = 73         # and the live-tile lists of a call with no reach
 
 
 @pytest.mark.parametrize("dtype", [np.uint8, np.uint16], ids=["u8", "u16"])
@@ -78,9 +86,11 @@ def test_one_tree_level_traces_the_kernel_it_always_traced(W, dtype):
     (call,) = _find(closed.jaxpr, "pallas_call")
     kernel = call.params["jaxpr"]
     mapping = call.params["grid_mapping"]
+    unfolded = W >= 16
     assert _count_eqns(kernel) == eqns
     assert _count_eqns(closed.jaxpr) - eqns == (
-        PACKED_WRAPPER_EQNS if packed else ONE_TREE_WRAPPER_EQNS
+        PACKED_WRAPPER_EQNS if packed
+        else UNFOLDED_WRAPPER_EQNS if unfolded else ONE_TREE_WRAPPER_EQNS
     )
     assert tuple(mapping.grid) == grid
     blocks = [
@@ -91,7 +101,16 @@ def test_one_tree_level_traces_the_kernel_it_always_traced(W, dtype):
     # missing bin's dot meets the four slots of both halves
     slab = (16, 32, 128) if packed else (32, rows, 256)
     miss = (32, 32) if packed else (32, 2 * rows)
-    assert blocks == [(32, 512), (2, 512), (1, 512), (1,) + slab, (1,) + miss]
+    # the unfolded body alone takes a fourth operand, the group's list of
+    # live second tiles and their count, whole in scalar memory, and four
+    # row blocks a step; the folded and the packed call are handed three
+    live = [(1, 33)] if unfolded else []
+    step = 2048 if unfolded else 512
+    assert blocks == [(32, step), (2, step), (1, step)] + live + [(1,) + slab, (1,) + miss]
+    if unfolded:
+        assert len(kernel.eqns) == 179
+        assert len(_find(kernel, "cond")) == 1 + 8
+        assert len(_find(kernel, "dot_general")) == (28 + 32) * 4 + 4
     chunks = grid[1]
     assert [tuple(a.shape) for a in call.params["out_avals"]] == [
         (chunks,) + slab, (chunks,) + miss,
@@ -253,8 +272,8 @@ def _plain_class_vmap(monkeypatch):
     """The parent's program: the class axis left to Pallas's batching rule."""
     monkeypatch.setattr(
         hist_mod, "_class_hist_fn",
-        lambda W, B, prec: functools.partial(
-            hist_mod._hist_pallas, num_nodes=W, num_bins=B, prec=prec
+        lambda W, B, prec: lambda bins, grad, hess, node, reach: hist_mod._hist_pallas(
+            bins, grad, hess, node, W, B, prec=prec, reach=reach
         ),
     )
 

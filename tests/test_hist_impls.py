@@ -487,6 +487,7 @@ def test_padding_features_get_no_dot(d, dtype):
         jnp.pad(bins.T, [(0, d_pad - d), (0, 0)]),
         jnp.stack([grad, hess]),
         jnp.where(node >= 0, node, W)[None, :],
+        hist_mod._live_tiles(None, d, fg, B),   # the unfolded body's flags: every tile
     )
     assert main.shape == (1, d_pad, rows, 128) and miss.shape == (1, d_pad, 2 * rows)
     assert np.asarray(main[0, d - 1, : 2 * W, 0]).any(), "the last real feature's bin 0 is hit"

@@ -189,6 +189,14 @@ def initialize(metrics):
                 "to either 'exact' or 'hist'."
             )
 
+    @dependencies_validator(["enable_categorical"])
+    def check_feature_types(value, deps):
+        if value is not None and "c" in value and deps.get("enable_categorical") != "true":
+            raise exc.UserError(
+                "feature_types names a column as categories ('c'): set enable_categorical "
+                "to 'true' to train it as categories."
+            )
+
     @dependencies_validator(["tree_method"])
     def check_interaction(value, deps):
         if value is not None and deps.get("tree_method") not in ("exact", "hist", "approx"):
@@ -344,6 +352,23 @@ def initialize(metrics):
         ),
         IntegerHyperparameter(name="max_leaves", range=Interval(min_closed=0), required=False),
         IntegerHyperparameter(name="max_bin", range=Interval(min_closed=0), required=False),
+        # columns given as categories (xgboost doc/tutorials/categorical.rst):
+        # which they are, and the partition scan's two bounds
+        CategoricalHyperparameter(
+            name="enable_categorical", range=["true", "false"], required=False
+        ),
+        TupleHyperparameter(
+            name="feature_types",
+            range=["q", "c", "float", "int", "i"],
+            required=False,
+            dependencies=check_feature_types,
+        ),
+        IntegerHyperparameter(
+            name="max_cat_to_onehot", range=Interval(min_closed=1), required=False
+        ),
+        IntegerHyperparameter(
+            name="max_cat_threshold", range=Interval(min_closed=1), required=False
+        ),
         CategoricalHyperparameter(name="predictor", range=predictor_range, required=False),
         TupleHyperparameter(
             name="monotone_constraints",

@@ -17,10 +17,37 @@ import scipy.sparse as sp
 from ..toolkit import exceptions as exc
 
 
+QUANTITATIVE, CATEGORICAL = "q", "c"
+# xgboost's spellings of a column's type: ``c`` a category's code, the rest a number
+_NUMERIC_TYPES = (QUANTITATIVE, "float", "int", "i")
+
+
+def normalize_feature_types(feature_types, num_col):
+    """``feature_types`` as a list of ``q`` / ``c`` a column (xgboost's
+    spelling; ``float``, ``int`` and ``i`` read as ``q``), None where none
+    was given."""
+    if feature_types is None:
+        return None
+    types = [str(t) for t in feature_types]
+    if len(types) != num_col:
+        raise exc.UserError(
+            "feature_types names {} columns but the data has {}".format(len(types), num_col)
+        )
+    unknown = sorted({t for t in types if t != CATEGORICAL and t not in _NUMERIC_TYPES})
+    if unknown:
+        raise exc.UserError(
+            "feature_types takes 'q' (a number) or 'c' (a category's code), got {}".format(
+                unknown
+            )
+        )
+    return [CATEGORICAL if t == CATEGORICAL else QUANTITATIVE for t in types]
+
+
 class DataMatrix:
     """Features + labels + optional per-row weights and ranking groups."""
 
-    def __init__(self, features, labels=None, weights=None, groups=None, feature_names=None):
+    def __init__(self, features, labels=None, weights=None, groups=None, feature_names=None,
+                 feature_types=None):
         if sp.issparse(features):
             self.csr = features.tocsr().astype(np.float32, copy=False)
             self._dense = None
@@ -35,6 +62,7 @@ class DataMatrix:
         self.weights = None if weights is None else np.asarray(weights, dtype=np.float32).reshape(-1)
         self.groups = None if groups is None else np.asarray(groups, dtype=np.int32).reshape(-1)
         self.feature_names = list(feature_names) if feature_names is not None else None
+        self.feature_types = normalize_feature_types(feature_types, self.num_col)
 
         if self.labels is not None and len(self.labels) != self.num_row:
             raise exc.UserError(
@@ -56,6 +84,11 @@ class DataMatrix:
     @property
     def is_sparse(self):
         return self.csr is not None
+
+    @property
+    def has_categorical(self):
+        """Whether a column is given as categories (``feature_types`` ``c``)."""
+        return self.feature_types is not None and CATEGORICAL in self.feature_types
 
     @property
     def shape(self):
@@ -100,6 +133,7 @@ class DataMatrix:
             labels=None if self.labels is None else self.labels[row_indices],
             weights=None if self.weights is None else self.weights[row_indices],
             feature_names=self.feature_names,
+            feature_types=self.feature_types,
         )
 
     def pad_features(self, num_col):
@@ -120,6 +154,10 @@ class DataMatrix:
             weights=self.weights,
             groups=self.groups,
             feature_names=self.feature_names,
+            feature_types=(
+                None if self.feature_types is None
+                else self.feature_types + [QUANTITATIVE] * (num_col - self.num_col)
+            ),
         )
 
     def concat(self, other):
@@ -145,6 +183,7 @@ class DataMatrix:
             labels=_cat(a.labels, b.labels),
             weights=_cat(a.weights, b.weights),
             feature_names=self.feature_names,
+            feature_types=a.feature_types,
         )
 
 

@@ -199,8 +199,9 @@ def supports_streaming(train_cfg):
     validates the pre-binned matrix against its own parse, so drift fails
     loudly, not silently). Chunked ingest needs the binned training path:
     gblinear fits the raw floats, ``process_type=update`` revisits committed
-    trees, ``tree_method=exact`` is unbounded-bin by design, and the approx
-    per-round re-sketch needs the float channel resident.
+    trees, ``tree_method=exact`` is unbounded-bin by design, the approx
+    per-round re-sketch needs the float channel resident, and columns given
+    as categories are laid out from their float codes.
     """
     p = train_cfg or {}
     booster = p.get("booster", "gbtree")
@@ -213,6 +214,12 @@ def supports_streaming(train_cfg):
         return False, "tree_method=exact is unbounded-bin", None
     if tree_method == "approx":
         return False, "tree_method=approx re-sketches from float features", None
+    if p.get("enable_categorical", "false") == "true" and "c" in (p.get("feature_types") or ()):
+        # the channels carry no types, the hyperparameters name them; chunked
+        # ingest sketches every column as a number, so a category's code would
+        # train as an ordered value (data/categorical.py lays it out from the
+        # floats)
+        return False, "feature_types 'c' lays out categories from float features", None
     if p.get("max_bin") is not None:
         max_bin = int(p["max_bin"])
     elif p.get("sketch_eps"):

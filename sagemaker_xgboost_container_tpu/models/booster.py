@@ -60,6 +60,7 @@ from ..ops.ranking import (
     with_slot_columns,
 )
 from ..ops.tree_build import (
+    round_tree_from_packed,
     build_tree,
     choose_eval_traversal,
     choose_route_impl,
@@ -184,6 +185,17 @@ class TrainConfig:
         self.eval_metric = p.get("eval_metric")
         self.num_parallel_tree = int(p.get("num_parallel_tree", 1) or 1)
         self.booster = p.get("booster", "gbtree")
+        # the partition scan of a column given as categories (xgboost's names
+        # and defaults; ops/categorical.py): fewer categories than the first,
+        # one against the rest; at most the second in a scanned set
+        to_onehot, threshold = p.get("max_cat_to_onehot"), p.get("max_cat_threshold")
+        self.max_cat_to_onehot = 4 if to_onehot is None else int(to_onehot)
+        self.max_cat_threshold = 64 if threshold is None else int(threshold)
+        if self.max_cat_to_onehot < 1 or self.max_cat_threshold < 1:
+            raise exc.UserError(
+                "max_cat_to_onehot and max_cat_threshold must be at least 1, got {} and "
+                "{}".format(self.max_cat_to_onehot, self.max_cat_threshold)
+            )
         # internal: build K trees per device dispatch (with eval sets the
         # per-round metrics ride back as device-computed stats inside the
         # scan; falls back to 1 when a metric can't — see _TrainingSession)
@@ -275,7 +287,7 @@ def _merge_cuts_across_processes(local_sets, max_bin):
 
 
 def _apply_packed_tree(packed, bins, margins, num_group, num_parallel, depth,
-                       num_bins, backend, traversal, bundle=None):
+                       num_bins, backend, traversal, bundle=None, cat=None):
     """margins += the packed tree's (or tree stack's) outputs on ``bins``.
 
     Runs under trace (the round fn and the session apply fn), so the
@@ -287,7 +299,9 @@ def _apply_packed_tree(packed, bins, margins, num_group, num_parallel, depth,
     level by level, ``replay`` takes the rows through a loss-guided tree's
     splits in the order they were made (``depth`` is the level walk's alone).
     ``bundle``: a bundled session's ``ops.bundle.BundleTables`` (the level
-    walk's range test), None for every other.
+    walk's range test), None for every other. ``cat``: a categorical
+    session's ``ops.categorical.CatTables`` (the level walk's set test; the
+    packed trees then carry their sets), None for every other.
     """
     route_impl = choose_route_impl(backend, bins.shape[1])
 
@@ -295,12 +309,15 @@ def _apply_packed_tree(packed, bins, margins, num_group, num_parallel, depth,
         if traversal == "level":
             return predict_binned_levels(
                 t, bins, depth, num_bins, route_impl=route_impl,
-                table_backend=backend, bundle=bundle, gathers=num_group == 1,
+                table_backend=backend, bundle=bundle, gathers=num_group == 1, cat=cat,
             )
         return predict_binned_steps(t, bins, num_bins, table_backend=backend)
 
     with stage(STAGE_EVAL_APPLY):
-        tree = tree_from_packed(packed)
+        tree = (
+            tree_from_packed(packed) if cat is None
+            else round_tree_from_packed(packed, cat.words)
+        )
         if num_group == 1:
             if num_parallel > 1:
                 delta = jax.vmap(one)(tree).sum(axis=0)
@@ -524,6 +541,27 @@ class _TrainingSession:
                 self._build_rank_layout(groups, dtrain.num_row)
 
         pre_binned = isinstance(dtrain, BinnedMatrix)
+        # columns given as categories (data/categorical.py): the bin matrix
+        # holds a categorical column's codes as positions of its own bin
+        # columns, so it is `cat.num_bin_columns` wide; None traces none of it
+        self.cat = None
+        if getattr(dtrain, "has_categorical", False):
+            from ..data.categorical import CatLayout
+
+            from ..ops.categorical import SET_TABLE_SELECT_MAX_ENTRIES, set_table_fits
+
+            with span("setup.cat_encode", attributes={"what": "layout"}):
+                self.cat = CatLayout.of(dtrain, config.max_bin)
+            if not set_table_fits(self.cat.set_words, config.max_depth, self.hist_knobs.backend):
+                raise exc.UserError(
+                    "Categorical columns (feature_types 'c'): a column of {} categories at "
+                    "max_depth={} needs a set table of {} x {} words a level, over the {} "
+                    "the device reads; lower max_depth or group the rare categories.".format(
+                        max(self.cat.cardinalities), config.max_depth,
+                        1 << (config.max_depth - 1), self.cat.set_words,
+                        SET_TABLE_SELECT_MAX_ENTRIES,
+                    )
+                )
         if self.is_multiprocess and config.max_bin is None:
             # libxgboost's exact updater is likewise single-machine only
             raise exc.UserError(
@@ -581,7 +619,7 @@ class _TrainingSession:
 
         # column padding: features pad to a multiple of the feature shards
         # with always-missing columns (zero cuts -> never split on)
-        d_real = dtrain.num_col
+        d_real = dtrain.num_col if self.cat is None else self.cat.num_bin_columns
         d_pad = padded_feature_width(d_real, self.n_feature_shards)
         self.d_pad = d_pad
 
@@ -666,7 +704,7 @@ class _TrainingSession:
             ]
         else:
             self._train_floats = self._float_rows(
-                dtrain.features, self._train_rows, self.rank_perm
+                self._bin_column_floats(dtrain, "train"), self._train_rows, self.rank_perm
             )
             weights = dtrain.weights
             cuts = self._sketch(
@@ -681,7 +719,9 @@ class _TrainingSession:
                 self._train_floats, cuts, max_bin, self._train_rows.devices, name="train"
             )
         self._stage_train_bins(shard_bins, cuts, max_bin)
-        if bundled is None:
+        if self.cat is not None:
+            self._note_categorical_shape(shard_bins)
+        elif bundled is None:
             self._note_binned_shape(shard_bins)
         else:
             self._note_bundled_shape(bundled)
@@ -971,8 +1011,8 @@ class _TrainingSession:
 
         self.learning_stats = model_telemetry.enabled()
         self.last_learning_stats = []
-        if self.learning_stats and self.bundle is None:
-            # (a bundled session holds no per-column bins to count)
+        if self.learning_stats and self.bundle is None and self.cat is None:
+            # (a bundled or categorical session holds no per-column bins to count)
             model_telemetry.capture_drift_baseline(self.train_binned)
 
         with span("setup.program_build"):
@@ -1150,6 +1190,20 @@ class _TrainingSession:
         max_bin = self.config.max_bin
         if max_bin is None:
             return sketch_shards([self._dtrain.features], [self._dtrain.weights], None)
+        if self.cat is not None:
+            # a categorical column is not sketched: its chunks' cuts follow
+            # its cardinality (one device: `train()` refuses a mesh)
+            numeric = self.cat.numeric_columns
+            cuts = []
+            if len(numeric):
+                cuts = sketch_shards(
+                    [block[:, numeric] for block in self._train_floats],
+                    weights or [None] * len(self._train_floats),
+                    max_bin,
+                    self._train_rows.devices,
+                )
+            with span("setup.cat_encode", attributes={"what": "cuts"}):
+                return self.cat.cuts(cuts)
         merge = (
             partial(_merge_cuts_across_processes, max_bin=max_bin)
             if self.is_multiprocess
@@ -1232,10 +1286,59 @@ class _TrainingSession:
         under the training cuts."""
         floats = self._eval_floats.get(index)
         if floats is None:
-            floats = self._float_rows(dm.features, layout)
+            floats = self._float_rows(self._bin_column_floats(dm, name), layout)
             if self.approx_resketch:
                 self._eval_floats[index] = floats
         return apply_shards(floats, cuts, max_bin, layout.devices, name=name)
+
+    def _bin_column_floats(self, dm, name):
+        """A matrix's floats as the bin-apply takes them: its own, or where
+        columns are given as categories one column a bin column, a chunk's
+        holding the position of the row's category (``CatLayout.expand``)."""
+        if self.cat is None:
+            return dm.features
+        if not self.cat.same_types(dm):
+            raise exc.UserError(
+                "Evaluation set {!r} does not name the training data's feature_types: "
+                "a column is a category in both or in neither.".format(name)
+            )
+        with span("setup.cat_encode", attributes={"what": name}):
+            return self.cat.expand(dm.features)
+
+    def _note_categorical_shape(self, shard_bins):
+        """``_note_binned_shape`` for a session with categorical columns, in
+        *original* columns: a categorical column's cell is missing where the
+        row sits in the missing slot of every chunk (a row that holds a value
+        sits in all but one); the sketch's counts are the numeric columns'."""
+        from ..telemetry import REGISTRY
+
+        cat, binned = self.cat, self.train_binned
+        missing_bin = sum(
+            int((block == binned.max_bin).sum()) for block in shard_bins
+        ) - (self._n_pad - self.n) * cat.num_bin_columns
+        extra_chunks = cat.num_bin_columns - cat.num_col
+        numeric = cat.numeric_columns
+        gauges = (
+            ("train_cells_missing", "Cells of the binned training matrix in the missing bin",
+             missing_bin - extra_chunks * self.n),
+            ("train_cells_total", "Cells of the binned training matrix (rows x columns)",
+             self.n * cat.num_col),
+            ("sketch_cuts_selected", "Cut points the sketch selected, summed over columns",
+             sum(len(binned.cut_points[c]) for c in numeric)),
+            ("sketch_cut_slots", "Cut slots a level histogram carries: columns x (max_bin - 1)",
+             len(numeric) * (binned.max_bin - 1)),
+            ("train_columns_total", "Columns of the binned training matrix", cat.num_col),
+            ("train_columns_categorical", "Training columns given as categories "
+             "(feature_types 'c'): their bins are their codes", cat.num_col - len(numeric)),
+            ("train_bin_columns", "Bin columns of a training matrix with categorical columns: "
+             "a numeric column's one and a categorical column's chunks "
+             "(data/categorical.py)", cat.num_bin_columns),
+            ("cat_set_words", "32-bit words of a node's category set in the round program's "
+             "level tables: the widest categorical column's, 32 categories a word",
+             cat.set_words),
+        )
+        for name, text, value in gauges:
+            REGISTRY.gauge(name, text).set(float(value))
 
     def _note_binned_shape(self, shard_bins):
         """What the binned training matrix holds, set once: how many of its
@@ -1332,6 +1435,8 @@ class _TrainingSession:
             builder = partial(build_tree, max_depth=cfg.max_depth, **common)
             if self.bundle is not None:
                 builder = partial(builder, bundle=self.bundle.tables)
+            if self.cat is not None:
+                builder = partial(builder, cat=self.cat_tables)
         ranking_grads = self._grad_hess_fn()
         grad_hess = self.objective.grad_hess
         if self.objective.name == "survival:cox" and axis_name is not None:
@@ -1499,6 +1604,7 @@ class _TrainingSession:
         n_fs = self.n_feature_shards
         backend = self.hist_knobs.backend
         bundle = self.bundle.tables if self.bundle is not None else None
+        cat = self.cat_tables
 
         def multi_round(
             bins, margins, labels, weights, num_cuts, rng, feature_mask, monotone,
@@ -1561,6 +1667,7 @@ class _TrainingSession:
                             packed, b_e, extra[ei],
                             num_group, num_parallel, predict_depth, num_bins,
                             backend=backend, traversal=eval_traversal, bundle=bundle,
+                            cat=cat,
                         )
                         new_extra.append(m_e)
                         ei += 1
@@ -1674,12 +1781,13 @@ class _TrainingSession:
 
         backend = self.hist_knobs.backend
         bundle = self.bundle.tables if self.bundle is not None else None
+        cat = self.cat_tables
 
         def apply_tree(packed, bins, margins):
             return _apply_packed_tree(
                 packed, bins, margins, num_group, num_parallel,
                 cfg.predict_depth, num_bins, backend=backend,
-                traversal=cfg.eval_traversal, bundle=bundle,
+                traversal=cfg.eval_traversal, bundle=bundle, cat=cat,
             )
 
         if self.mesh is None:
@@ -1763,7 +1871,10 @@ class _TrainingSession:
         reach = np.asarray(
             [len(c) for c in self.cuts] if self.bundle is None
             else self.bundle.tables.reach
-        ).reshape(self.n_feature_shards, d_local)
+        )
+        if self.cat is not None:
+            reach = self.cat.reach(reach)
+        reach = reach.reshape(self.n_feature_shards, d_local)
         plan = (
             round_hist_levels(
                 cfg.grow_policy, cfg.max_depth, cfg.max_leaves, subtract,
@@ -1888,9 +1999,10 @@ class _TrainingSession:
         ]
         with self._upload_span("train_bins"):
             self.cuts = cuts
-            self.num_cuts = self._put(
-                np.array([len(c) for c in cuts], np.int32), self.feat_spec
-            )
+            cut_counts = np.array([len(c) for c in cuts], np.int32)
+            if self.cat is not None:  # no threshold split on a categorical column
+                cut_counts = self.cat.numeric_cut_counts(cut_counts)
+            self.num_cuts = self._put(cut_counts, self.feat_spec)
             self.bins = self._place_rows(shard_bins, self._train_rows, fill=max_bin)
         dtrain = self._dtrain
         if isinstance(dtrain, BinnedMatrix):
@@ -2235,7 +2347,7 @@ class _TrainingSession:
             packed_np = self._device_sync(packed, out, attributes, fenced)
             self._note_comm_dispatch(1)
             self._stash_learning_stats(lstats)
-            return [unpack_round_trees(packed_np)], None
+            return [unpack_round_trees(packed_np, self._set_words)], None
         eval_m = tuple(m for m in self.eval_margins if m is not None)
         eval_blw = tuple(
             (self.eval_bins[i], self.eval_labels[i], self.eval_weights[i])
@@ -2262,7 +2374,10 @@ class _TrainingSession:
         self._stash_learning_stats(lstats)
         metrics_np = np.asarray(metrics) if self.device_metric_fns else None
         return (
-            [unpack_round_trees(packed_np[j]) for j in range(packed_np.shape[0])],
+            [
+                unpack_round_trees(packed_np[j], self._set_words)
+                for j in range(packed_np.shape[0])
+            ],
             metrics_np,
         )
 
@@ -2343,6 +2458,29 @@ class _TrainingSession:
             global_rows_cache=self._global_rows_cache,
         )
 
+
+    # -------------------------------------------------- categorical columns
+    @functools.cached_property
+    def cat_tables(self):
+        """The session's ``ops.categorical.CatTables``, None where no column
+        is given as categories."""
+        if self.cat is None:
+            return None
+        from ..ops.categorical import CatTables
+
+        return CatTables(
+            self.cat, self.config.max_cat_to_onehot, self.config.max_cat_threshold
+        )
+
+    @property
+    def _set_words(self):
+        return 0 if self.cat is None else self.cat.set_words
+
+    @property
+    def tree_cuts(self):
+        """Cuts by the column a committed tree's split names: the bin
+        columns' own, or by original column where some are categories."""
+        return self.cuts if self.cat is None else self.cat.feature_cuts(self.cuts)
 
     # ------------------------------------------------------ bundled layout
     # (below the round program: a line moved above the traced code moves the
@@ -2510,7 +2648,7 @@ def note_committed_trees(trees, padded=None):
     brought them; a loss-guided round's hold its ``[..., 3]`` pass counters
     (``ops/lossguide.py``): passes over the rows, node slots the passes
     filled, and filled slots a split step then used."""
-    from ..ops.tree_build import PASS_COUNTS_FIELD
+    from ..ops.tree_build import PASS_COUNTS_FIELD, SET_WORDS_FIELD
     from ..telemetry import REGISTRY
 
     REGISTRY.counter(
@@ -2518,6 +2656,17 @@ def note_committed_trees(trees, padded=None):
     ).inc(sum(int(np.count_nonzero(t.is_leaf)) for t in trees))
     deepest = _tree_depth_gauge()
     deepest.set(max([deepest.value] + [t.depth() for t in trees]))
+    if padded is not None and SET_WORDS_FIELD in padded:
+        # (counted for a categorical session alone: every other's trees are
+        # told from their leaves)
+        REGISTRY.counter(
+            "tree_splits_total", "Splits of the trees committed by a session with "
+            "categorical columns"
+        ).inc(sum(int(np.count_nonzero(~t.is_leaf)) for t in trees))
+        REGISTRY.counter(
+            "tree_cat_splits_total", "Set-membership splits among them: a node that "
+            "sends right the categories of its set (ops/categorical.py)"
+        ).inc(sum(len(t.categories) for t in trees))
     if padded is None or PASS_COUNTS_FIELD not in padded:
         return
     passes, filled, used = (
@@ -2735,6 +2884,44 @@ def _abort_numeric_poison(round_index):
     )
 
 
+def _refuse_categorical_combinations(config, dtrain, evals, mesh):
+    """A job with columns given as categories (``feature_types`` ``c``) trains
+    set-membership splits depth-wise, under ``hist``, on one device
+    (``ops/categorical.py``). Every other combination is refused by name: a
+    category's code is never trained as a number."""
+    matrices = [dtrain] + [dm for dm, _name in evals]
+    if not any(getattr(dm, "has_categorical", False) for dm in matrices):
+        return
+    if not getattr(dtrain, "has_categorical", False):
+        raise exc.UserError(
+            "An evaluation set names categorical columns (feature_types 'c') and the "
+            "training data names none: a column is a category in both or in neither."
+        )
+    refused = [
+        (dtrain.is_sparse or any(dm.is_sparse for dm in matrices),
+         "sparse (CSR) input"),
+        (config.booster != "gbtree", "booster={!r}".format(config.booster)),
+        (config.process_type != "default", "process_type={!r}".format(config.process_type)),
+        (config.grow_policy == "lossguide", "grow_policy='lossguide'"),
+        (config.tree_method in ("approx", "exact"),
+         "tree_method={!r}".format(config.tree_method)),
+        (mesh is not None, "a device mesh (more than one chip or process)"),
+        (bool(config.monotone_constraints), "monotone_constraints"),
+        (bool(config.interaction_constraints), "interaction_constraints"),
+        (min(config.colsample_bytree, config.colsample_bylevel, config.colsample_bynode) < 1.0,
+         "colsample_bytree / colsample_bylevel / colsample_bynode below 1"),
+    ]
+    named = [what for hit, what in refused if hit]
+    if named:
+        raise exc.UserError(
+            "Categorical columns (feature_types 'c') train as categories depth-wise under "
+            "tree_method='hist' on one device; not yet with {}. Drop the feature_types "
+            "to train the codes as numbers, or one-hot encode the columns.".format(
+                ", ".join(named)
+            )
+        )
+
+
 def train(
     params,
     dtrain,
@@ -2760,6 +2947,7 @@ def train(
     record_startup(entering_train=True)  # what ran in front of the first train(), once
     config = TrainConfig(params)
     callbacks = list(callbacks or [])
+    _refuse_categorical_combinations(config, dtrain, evals, mesh)
 
     if isinstance(dtrain, BinnedMatrix) and (
         config.booster != "gbtree" or config.process_type != "default"
@@ -2822,6 +3010,7 @@ def train(
             num_feature=dtrain.num_col,
             num_class=config.num_class,
             feature_names=dtrain.feature_names,
+            feature_types=getattr(dtrain, "feature_types", None),
         )
     elif isinstance(xgb_model, Forest):
         forest = xgb_model
@@ -2874,7 +3063,7 @@ def train(
             return (
                 [
                     compact_padded_tree(
-                        {k: v[t, c] for k, v in arrs.items()}, session.cuts
+                        {k: v[t, c] for k, v in arrs.items()}, session.tree_cuts
                     )
                     for c in range(session.num_group)
                     for t in range(config.num_parallel_tree)
@@ -2888,7 +3077,7 @@ def train(
         if session.num_group > 1:
             return (
                 [
-                    compact_padded_tree({k: v[c] for k, v in arrs.items()}, session.cuts)
+                    compact_padded_tree({k: v[c] for k, v in arrs.items()}, session.tree_cuts)
                     for c in range(session.num_group)
                 ],
                 list(range(session.num_group)),
@@ -2896,12 +3085,12 @@ def train(
         if config.num_parallel_tree > 1:
             return (
                 [
-                    compact_padded_tree({k: v[t] for k, v in arrs.items()}, session.cuts)
+                    compact_padded_tree({k: v[t] for k, v in arrs.items()}, session.tree_cuts)
                     for t in range(config.num_parallel_tree)
                 ],
                 [0] * config.num_parallel_tree,
             )
-        return [compact_padded_tree(arrs, session.cuts)], [0]
+        return [compact_padded_tree(arrs, session.tree_cuts)], [0]
 
     evals_log = {}
     start_round = forest.num_boosted_rounds

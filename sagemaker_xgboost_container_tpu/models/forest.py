@@ -49,9 +49,11 @@ class Tree:
     are the set that routes to the RIGHT child (xgboost
     common::Decision semantics: category in set -> not default-left branch
     decision -> right); invalid/missing categories follow ``default_left``.
-    Our trainer never produces these — they exist for BYO xgboost models
-    loaded for serving (reference serve_utils.py:171-197 loads any customer
-    model through libxgboost, which handles categorical nodes natively).
+    The trainer makes them for columns given as categories
+    (``DataMatrix(feature_types=...)``, ``ops/categorical.py``), and BYO
+    xgboost models loaded for serving carry them (reference
+    serve_utils.py:171-197 loads any customer model through libxgboost, which
+    handles categorical nodes natively).
     """
 
     def __init__(self, feature, threshold, default_left, left, right, value,
@@ -129,7 +131,10 @@ def compact_padded_tree(padded, cut_points):
     tree (``ops/lossguide.py``: split step t makes slots 2t+1 and 2t+2) it is
     the order of expansion, as xgboost's own loss-guided updater numbers its
     nodes. Split bin indices become float thresholds via the feature's cut
-    array.
+    array. A categorical build's arrays (``cat_words``: i32 ``[nodes, words]``)
+    hold a set split where ``bin`` is past every cut of any column (the
+    missing bin's index, ``ops/categorical.py``): such a node keeps the codes
+    of its set's bits as its ``categories`` and no threshold.
     """
     is_leaf = np.asarray(padded["is_leaf"])
     feature = np.asarray(padded["feature"])
@@ -139,6 +144,10 @@ def compact_padded_tree(padded, cut_points):
     base_weight = np.asarray(padded["base_weight"])
     gain = np.asarray(padded["gain"])
     sum_hess = np.asarray(padded["sum_hess"])
+    set_words = padded.get("cat_words")
+    categories = {}
+    if set_words is not None:
+        from ..data.categorical import words_to_categories
     if "left" in padded:
         child_left = np.asarray(padded["left"])
         child_right = np.asarray(padded["right"])
@@ -175,12 +184,17 @@ def compact_padded_tree(padded, cut_points):
         else:
             f = int(feature[node])
             out["feature"][cid] = f
-            out["threshold"][cid] = cut_points[f][int(bin_idx[node])]
+            if set_words is not None and (
+                cut_points[f] is None or int(bin_idx[node]) >= len(cut_points[f])
+            ):
+                categories[cid] = words_to_categories(set_words[node])
+            else:
+                out["threshold"][cid] = cut_points[f][int(bin_idx[node])]
             out["default_left"][cid] = default_left[node]
             out["left"][cid] = compact_id[int(child_left[node])]
             out["right"][cid] = compact_id[int(child_right[node])]
             out["gain"][cid] = gain[node]
-    return Tree(**out)
+    return Tree(categories=categories, **out)
 
 
 def _parse_base_score(value):
@@ -196,7 +210,8 @@ class Forest:
     """The model: trees + objective metadata + prediction entry points."""
 
     def __init__(self, objective_name="reg:squarederror", objective_params=None,
-                 base_score=0.5, num_feature=0, num_class=0, feature_names=None):
+                 base_score=0.5, num_feature=0, num_class=0, feature_names=None,
+                 feature_types=None):
         self.trees = []
         self.tree_info = []  # class id per tree (0 for single-output)
         self.iteration_indptr = [0]
@@ -206,6 +221,8 @@ class Forest:
         self.num_feature = int(num_feature)
         self.num_class = int(num_class)  # 0 = not multiclass (xgboost convention)
         self.feature_names = feature_names
+        # xgboost's spelling a column ("c": a category's code), None: all numbers
+        self.feature_types = list(feature_types) if feature_types else None
         self.attributes = {}
         self._stacked_cache = None
 
@@ -576,7 +593,9 @@ class Forest:
             "learner": {
                 "attributes": self.attributes,
                 "feature_names": self.feature_names or [],
-                "feature_types": [],
+                "feature_types": [
+                    "c" if t == "c" else "float" for t in self.feature_types or []
+                ],
                 "gradient_booster": {
                     "model": {
                         "gbtree_model_param": {
@@ -638,6 +657,7 @@ class Forest:
             num_feature=int(lmp.get("num_feature", 0)),
             num_class=int(lmp.get("num_class", 0)),
             feature_names=learner.get("feature_names") or None,
+            feature_types=learner.get("feature_types") or None,
         )
         forest.attributes = learner.get("attributes", {})
         forest.trees = [cls._tree_from_json(t) for t in model["trees"]]

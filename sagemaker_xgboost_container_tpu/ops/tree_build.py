@@ -257,6 +257,7 @@ def build_tree(
     knobs=None,
     class_vmap=False,
     bundle=None,
+    cat=None,
 ):
     """Grow one tree. Returns (tree arrays dict, row_out f32 [n]).
 
@@ -291,6 +292,15 @@ def build_tree(
     from its node's split column is told by a range test, a second word read
     of the node's table; ``feature`` and ``bin`` of the tree are then a bundle
     and a position. None, a dense session, traces none of it.
+
+    cat: static; the session's ``ops.categorical.CatTables`` where some
+    columns are given as categories (``data/categorical.py``: a categorical
+    column's codes are positions of its own bin columns). The scan then also
+    tries set-membership splits, a row reads its value of the split column
+    over the column's chunks and, at a set split, its bit of the node's set;
+    the tree holds the original column in ``feature``, ``num_bins - 1`` in
+    ``bin`` at a set split, and the sets in ``cat_words`` (i32 ``[nodes,
+    words]``). None traces none of it.
 
     Every per-row read of a level's per-node table is ``node_table_lookup`` in
     the lowering ``choose_table_impl(backend, 2**level)`` picks: the four
@@ -332,6 +342,8 @@ def build_tree(
         "gain": jnp.zeros(max_nodes, jnp.float32),
         "sum_hess": jnp.zeros(max_nodes, jnp.float32),
     }
+    if cat is not None:
+        tree["cat_words"] = jnp.zeros((max_nodes, cat.words), jnp.int32)
 
     node_of_row = jnp.zeros(n, jnp.int32)
     row_out = jnp.zeros(n, jnp.float32)
@@ -354,6 +366,8 @@ def build_tree(
     # the highest bin a row of each column can sit in: the level histogram
     # builds no one-hot tile above it (ops.histogram._live_tiles)
     reach = num_cuts if bundle is None else jnp.asarray(bundle.reach)
+    if cat is not None:  # a chunk's reach is its last category, whatever the data
+        reach = num_cuts + jnp.asarray(cat.chunk_reach)
     subtract = _subtraction_enabled(max_depth, d, num_bins)
     G_cache = H_cache = None      # previous level's [W/2, d, B] histograms
     parent_leaf = None            # previous level's becomes_leaf [W/2]
@@ -456,6 +470,8 @@ def build_tree(
                 per_node = _local_cols(node_allowed.astype(jnp.float32))
                 level_mask = per_node if level_mask is None else per_node * level_mask[None, :]
             scan = find_best_splits if bundle is None else bundle.find_best_splits
+            if cat is not None:
+                scan = cat.find_best_splits
             splits = scan(
                 G,
                 H,
@@ -501,7 +517,13 @@ def build_tree(
                     word_bin_bits,
                 )
             row_leafed = at_level & row_at_leaf
-            if feature_axis_name is None:
+            if cat is not None:  # the row's value over the column's chunks, then its set's bit
+                value = cat.row_value(bins, split_feat)
+                go_right = cat.go_right(
+                    value, split_bin, default_left,
+                    cat.set_word(splits["cat_words"], local_safe, value, table_backend),
+                )
+            elif feature_axis_name is None:
                 row_bin = row_bin_lookup(bins, split_feat, impl=route_impl)
                 if bundle is None:
                     is_missing = row_bin == (num_bins - 1)
@@ -546,6 +568,8 @@ def build_tree(
             won = splits["gain"] + gamma if gamma else splits["gain"]
             tree["gain"] = tree["gain"].at[sl].set(jnp.where(can_split, won, 0.0))
             tree["sum_hess"] = tree["sum_hess"].at[sl].set(h_tot)
+            if cat is not None:
+                tree["cat_words"] = tree["cat_words"].at[sl].set(splits["cat_words"])
             row_out = jnp.where(row_leafed, eta * at_node(weight, local_safe), row_out)
 
         if alive_sets is not None and level < max_depth:
@@ -651,7 +675,7 @@ def predict_binned(tree, bins, max_depth, num_bins, route_impl=None):
 
 
 def predict_binned_levels(tree, bins, max_depth, num_bins, route_impl=None,
-                          table_backend=None, bundle=None, gathers=True):
+                          table_backend=None, bundle=None, gathers=True, cat=None):
     """``predict_binned`` for a tree ``build_tree`` made, bit for bit.
 
     Such a tree is a heap (children of i at 2i+1 / 2i+2, level L the static
@@ -670,7 +694,10 @@ def predict_binned_levels(tree, bins, max_depth, num_bins, route_impl=None,
     callers only). ``bundle``: the session's ``ops.bundle.BundleTables`` where
     tree and bins are bundled: a row absent from a split's column is told by
     the range test, as the build tells it (``gathers``: how the nodes' range
-    words are read, ``BundleTables.node_ranges``).
+    words are read, ``BundleTables.node_ranges``). ``cat``: the session's
+    ``ops.categorical.CatTables`` where the tree holds set splits: a row reads
+    its value over the split column's chunks and its bit of the node's set
+    (``tree["cat_words"]``), as the build routes its own.
     """
     if table_backend is None:
         table_backend = jax.default_backend()
@@ -689,15 +716,24 @@ def predict_binned_levels(tree, bins, max_depth, num_bins, route_impl=None,
                 tree[field][first:first + width], local_safe, impl=impl
             )
 
-        row_bin = row_bin_lookup(bins, at_node("feature"), impl=route_impl)
-        if bundle is None:
-            go_right = jnp.where(
-                row_bin == (num_bins - 1), ~at_node("default_left"), row_bin > at_node("bin")
+        if cat is not None:
+            value = cat.row_value(bins, at_node("feature"))
+            go_right = cat.go_right(
+                value, at_node("bin"), at_node("default_left"),
+                cat.set_word(
+                    tree["cat_words"][first:first + width], local_safe, value, table_backend
+                ),
             )
         else:
-            go_right = bundle.go_right(
-                row_bin, at_node("range"), at_node("bin"), at_node("default_left")
-            )
+            row_bin = row_bin_lookup(bins, at_node("feature"), impl=route_impl)
+            if bundle is None:
+                go_right = jnp.where(
+                    row_bin == (num_bins - 1), ~at_node("default_left"), row_bin > at_node("bin")
+                )
+            else:
+                go_right = bundle.go_right(
+                    row_bin, at_node("range"), at_node("bin"), at_node("default_left")
+                )
         child = node * 2 + 1 + go_right.astype(jnp.int32)
         node = jnp.where((node_local >= 0) & ~at_node("is_leaf"), child, node)
     leaves = tree["leaf_value"]
@@ -715,11 +751,22 @@ def predict_binned_levels(tree, bins, max_depth, num_bins, route_impl=None,
 PASS_COUNTS_FIELD = "hist_passes"
 
 
+#: a categorical build's sets (ops/categorical.py: i32 [nodes, words]), the
+#: other field a tree dict may hold beside _TREE_FIELDS
+SET_WORDS_FIELD = "cat_words"
+
+
 def pack_round_trees(tree):
     """``pack_tree`` for the round program: a loss-guided tree's pass
     counters ride the one array as the first entries of an eleventh row, so
-    they cost no transfer of their own; a depth-wise tree packs as ever."""
+    they cost no transfer of their own, and a categorical build's sets as
+    ``2 * words`` rows more (``ops.categorical.pack_set_words``); a
+    depth-wise tree without sets packs as ever."""
     packed = pack_tree(tree)
+    if SET_WORDS_FIELD in tree:
+        from .categorical import pack_set_words
+
+        return jnp.concatenate([packed, pack_set_words(tree[SET_WORDS_FIELD])])
     if PASS_COUNTS_FIELD not in tree:
         return packed
     counts = tree[PASS_COUNTS_FIELD].astype(jnp.float32)
@@ -728,12 +775,28 @@ def pack_round_trees(tree):
     return jnp.concatenate([packed, row[None]])
 
 
-def unpack_round_trees(packed):
-    """``unpack_tree``, and the pass counters where the array carries them."""
+def round_tree_from_packed(packed, set_words=0):
+    """``tree_from_packed``, and the sets where the session's trees carry
+    ``set_words`` words of them a node."""
+    tree = tree_from_packed(packed)
+    if set_words:
+        from .categorical import unpack_set_words
+
+        tree[SET_WORDS_FIELD] = unpack_set_words(packed[len(_TREE_FIELDS):])
+    return tree
+
+
+def unpack_round_trees(packed, set_words=0):
+    """``unpack_tree``, and the pass counters or the sets (``set_words``
+    words a node: a categorical session's) where the array carries them."""
     import numpy as np
 
     out = unpack_tree(packed)
-    if len(packed) > len(_TREE_FIELDS):
+    if set_words:
+        from .categorical import unpack_set_words
+
+        out[SET_WORDS_FIELD] = unpack_set_words(np.asarray(packed[len(_TREE_FIELDS):]), np)
+    elif len(packed) > len(_TREE_FIELDS):
         counts = np.asarray(packed[len(_TREE_FIELDS)])
         out[PASS_COUNTS_FIELD] = counts[..., :3].astype(np.int64)
     return out

@@ -74,6 +74,7 @@ STAGE_HIST = "hist"
 STAGE_HIST_ALLREDUCE = "hist_allreduce"
 STAGE_NODE_TOTALS = "node_totals"
 STAGE_SPLIT_SCAN = "split_scan"
+STAGE_CAT_SCAN = "cat_scan"
 #: a loss-guided build's split-step loop (ops/lossguide.py): the argmax over
 #: the candidate store, the tree and store updates, the histogram cache's slot
 #: writes and the loop itself; the step's kernel, scan and routing have their
@@ -93,6 +94,7 @@ STAGES = (
     STAGE_HIST_ALLREDUCE,
     STAGE_NODE_TOTALS,
     STAGE_SPLIT_SCAN,
+    STAGE_CAT_SCAN,
     STAGE_STEP_PICK,
     STAGE_ROUTE_ROWS,
     STAGE_LEAF_MARGIN,

@@ -510,10 +510,28 @@ def train_job(
     legacy jax heartbeat timeout applies) and is documented as such.
     """
     from ..data.binning import BinnedMatrix
+    from ..data.matrix import normalize_feature_types
     from ..ops.histogram import resolve_hist_knobs
     from ..utils.device_runtime import start_device_runtime
 
     train_cfg = dict(train_cfg)
+    # columns given as categories: the channels carry no types, the
+    # hyperparameters name them (`feature_types`, under `enable_categorical`)
+    feature_types = train_cfg.pop("feature_types", None)
+    if train_cfg.pop("enable_categorical", "false") == "true" and feature_types is not None:
+        for matrix in (train_dmatrix, val_dmatrix, train_val_dmatrix):
+            if matrix is None:
+                continue
+            if isinstance(matrix, BinnedMatrix) and "c" in feature_types:
+                # `_streaming_plan` keeps such a job on the whole-file readers;
+                # a pre-binned matrix has sketched the codes as numbers already
+                raise exc.UserError(
+                    "Categorical columns (feature_types 'c') need the whole-file "
+                    "readers: chunked ingest bins a category's code as a number. "
+                    "Use SM_INGEST_MODE=whole."
+                )
+            if not isinstance(matrix, BinnedMatrix):
+                matrix.feature_types = normalize_feature_types(feature_types, matrix.num_col)
     num_devices_cap = train_cfg.pop("_num_devices", None)
     mesh = training_mesh(num_devices_cap)
     # one knob snapshot for the whole job: every generation the reform loop

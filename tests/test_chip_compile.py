@@ -489,3 +489,69 @@ def test_bundled_round_compiles_for_the_chip(one_chip, no_compile_cache, monkeyp
     # the program's own arguments and scratch fit the chip beside the bins
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 6 << 30, memory
+
+
+# ------------------------------ a categorical round (columns as categories)
+CAT_SIZES = (2, 2, 3, 3, 4, 5, 6, 8, 10, 12, 14, 18, 23, 28, 75, 1300, 2700)
+
+
+def test_categorical_round_compiles_for_the_chip(one_chip, no_compile_cache, monkeypatch):
+    """`allstate-cat-d8`'s round (12,184,290 rows x 47 bin columns in u16: 15
+    numeric columns and the chunks of 17 categorical ones; depth 8; the level
+    walk's set test over 1,000,000 validation rows) through the chip's own
+    compilers: the eight kernel call sites of a dense depth-8 build, the
+    partition scan without a sort, and no row-length gather in the build or
+    in the walk (the level tables hold at most 128 x 85 words: the select
+    pass)."""
+    from sagemaker_xgboost_container_tpu.data.categorical import CatLayout
+    from sagemaker_xgboost_container_tpu.ops import histogram as hist_mod
+    from sagemaker_xgboost_container_tpu.ops.categorical import CatTables
+    from sagemaker_xgboost_container_tpu.ops.tree_build import (
+        choose_route_impl,
+        pack_round_trees,
+        predict_binned_levels,
+        round_tree_from_packed,
+    )
+
+    monkeypatch.setattr(hist_mod, "pallas_interpret", lambda: False)
+    knobs = resolve_hist_knobs()._replace(backend="tpu")
+    layout = CatLayout(["q"] * 15 + ["c"] * 17, [0] * 15 + list(CAT_SIZES), NUM_BINS - 1)
+    tables = CatTables(layout, 4, 64)
+    columns = layout.num_bin_columns
+    assert (columns, tables.words) == (47, 85)
+
+    def one_round(bins, grad, hess, num_cuts, validation_bins):
+        tree, row_out = build_tree(
+            bins, grad, hess, num_cuts, max_depth=DEPTH, num_bins=NUM_BINS,
+            min_child_weight=100.0, eta=0.1, knobs=knobs, cat=tables,
+            feature_mask=jnp.ones(columns, jnp.float32),
+        )
+        packed = pack_round_trees(tree)
+        walked = predict_binned_levels(
+            round_tree_from_packed(packed, tables.words), validation_bins, DEPTH, NUM_BINS,
+            route_impl=choose_route_impl("tpu", columns), table_backend="tpu", cat=tables,
+        )
+        return packed, row_out, walked
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    n, v = SPARSE_ROWS, SPARSE_VALIDATION
+    compiled = (
+        jax.jit(one_round)
+        .lower(
+            shape((n, columns), jnp.uint16), shape((n,), jnp.float32),
+            shape((n,), jnp.float32), shape((columns,), jnp.int32),
+            shape((v, columns), jnp.uint16),
+        )
+        .compile()
+    )
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == DEPTH
+    assert not re.findall(r" sort\(", hlo)
+    # the packed tree: ten rows and the sets' two halves a word
+    assert "f32[{},511]".format(10 + 2 * tables.words) in hlo
+    for dims in re.findall(r" = \S+\[([0-9,]*)\][^\n]* gather\(", hlo):
+        assert str(n) not in dims.split(",") and str(v) not in dims.split(","), dims
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 6 << 30, memory

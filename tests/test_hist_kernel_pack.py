@@ -28,13 +28,18 @@ def _unpacked(monkeypatch):
 def drop_compiled_kernels():
     """As ``tests/test_hist_kernel_fold.py``'s: an interpreted kernel with its
     features unrolled is hundreds of memory mappings, kept for the process's
-    life."""
-    yield
+    life. Dropped in front of the test too: it counts the kernels it
+    builds, and another file's tests on this worker may have left theirs."""
     import jax
 
-    hist_mod._pallas_hist_fn.cache_clear()
-    hist_mod._pallas_hist_packed_fn.cache_clear()
-    jax.clear_caches()
+    def drop():
+        hist_mod._pallas_hist_fn.cache_clear()
+        hist_mod._pallas_hist_packed_fn.cache_clear()
+        jax.clear_caches()
+
+    drop()
+    yield
+    drop()
 
 
 def _both(monkeypatch, bins, grad, hess, node, W, B, prec="bf16x2"):

@@ -27,6 +27,10 @@ what a split is:
   word of the node's set that holds its code from the level's
   ``[2**level, words]`` table, and goes right iff its bit is set; a missing
   value follows ``default_left``, a value that is no category goes left.
+  The key of that read is (node, word): on the TPU it is a matrix product,
+  the one-hot of the row's node times the table as bytes, then a pick of the
+  row's own word (``set_table_lookup``, the kernel ``graft_cat_set_read``),
+  where a select pass over the flattened table pays for every entry.
 
 A tree such a build makes holds the *original* column in ``feature``, the
 marker ``num_bins - 1`` in ``bin`` where the split is a set, and the set's
@@ -35,20 +39,57 @@ any of this: ``ops/tree_build.py`` takes these branches only where it is
 handed a ``CatTables``.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..telemetry.device import STAGE_CAT_SCAN, stage
+from . import histogram
 from .split import _score, find_best_splits
 
-# Widest set table (a level's nodes x words) the TPU reads, by the select pass
-# (`node_table_lookup`; this cell's widest is 128 x 85 = 10,880 entries, 313 ms
-# a round). A wider one would need a row-length gather of the table, which no
-# chip run has measured or soaked for this table, so the session refuses the
-# job (`set_table_fits`) where the backend is the TPU. The CPU reads any
-# width by the gather.
+# Widest set table (a level's nodes x words) the TPU reads (this cell's widest
+# is 128 x 85 = 10,880 entries). The product below would hold a wider one, but
+# no chip run has measured or soaked a wider table, so the session refuses the
+# job (`set_table_fits`) where the backend is the TPU. The CPU reads any width
+# by the gather.
 SET_TABLE_SELECT_MAX_ENTRIES = 1 << 14
+
+# Widest set table the TPU still reads by the select pass over the flat table
+# (`node_table_lookup`); a wider one is read as a matrix product
+# (`_set_read_fn`). ns a row and level on one v5e (scripts/dissect.py
+# --cat-set-read: `allstate-cat-d8`'s 12,184,290 train rows and 1,000,000
+# evaluation rows, 85 words a node, every pair bit-equal; PR 51, PERF.md
+# section 6):
+#
+#   level  entries    gather         select           product
+#     0        85    8.24 / 8.32    0.12 / 0.19    0.55 / 0.68
+#     1       170    8.59 / 8.67    0.19 / 0.29    0.55 / 0.68
+#     2       340    8.58 / 8.67    0.32 / 0.35    0.55 / 0.68
+#     3       680    5.62 / 5.71    0.68 / 0.64    0.55 / 0.68   <- the product from here
+#     4     1,360    7.50 / 7.58    1.35 / 1.18    0.55 / 0.68
+#     5     2,720    7.50 / 7.57    2.08 / 2.04    0.55 / 0.68
+#     6     5,440    7.50 / 7.58    6.56 / 3.99    0.55 / 0.68
+#     7    10,880    7.50 / 7.57   11.63 / 14.39   0.55 / 0.68
+#
+# The select pass costs per entry (22.9 ns a row over the eight levels, 279 ms
+# a round of 12.18M rows); the product costs the same at every level, because
+# a narrower table comes padded to the 128 nodes the MXU contracts anyway.
+SET_READ_SELECT_MAX_ENTRIES = 512
+
+# Rows a grid step of the product takes (lanes of its blocks) where a node's
+# set holds at most 88 words, fewer in proportion for a wider set (the step's
+# f32 product is ``[4 x words, rows]``: 11.5 MB of VMEM here); nodes a product
+# contracts (the MXU's depth). ms a level over 12,184,290 rows by rows a grid
+# step and rows a product inside the step (the same probe's tables, levels 0
+# and 7 alike; my chip runs, PR 51): (512, 512) 11.29, (2,048, 512) 10.12,
+# (8,192, 512) 9.72, (8,192, 1,024) 8.18, (2,048, 2,048) 7.76, (8,192, 2,048)
+# 7.34, (4,096, 4,096) 6.93, (8,192, 4,096) 6.87, **(8,192, 8,192) 6.67**,
+# (16,384, 8,192) 6.63, (16,384, 16,384) 6.60: one product a step, no loop
+# inside it.
+SET_READ_ROW_BLOCK = 8192
+SET_READ_NODE_TILE = 128
 
 
 def set_table_fits(words, max_depth, backend):
@@ -256,15 +297,12 @@ class CatTables:
 
     def set_word(self, words, local_safe, value, backend):
         """The word of each row's node's set that holds the row's code:
-        ``words`` i32 ``[W, words]`` read flat by ``node * words + code // 32``
-        (a row without a code reads word 0, which ``go_right`` never asks)."""
-        from .tree_build import node_table_lookup
-
-        at = jnp.minimum(jnp.maximum(value - 1, 0) >> 5, self.words - 1)
-        # one lowering a backend: the session has refused a table the TPU's
-        # select pass does not hold (`set_table_fits`)
-        impl = "select" if backend == "tpu" else "gather"
-        return node_table_lookup(words.reshape(-1), local_safe * self.words + at, impl=impl)
+        ``words`` i32 ``[W, words]`` at ``[node, code // 32]`` (a row without
+        a code reads word 0, which ``go_right`` never asks), in the lowering
+        ``choose_set_read_impl`` picks from the backend and the table's
+        static shape."""
+        impl = choose_set_read_impl(backend, words.shape[0] * words.shape[1])
+        return set_table_lookup(words, local_safe, value, impl)
 
     def go_right(self, value, split_bin, default_left, word):
         """Where a row goes: missing, where ``default_left`` says; at a set
@@ -274,6 +312,145 @@ class CatTables:
         in_set = (code >= 0) & (((word >> (code & 31)) & 1) == 1)
         by_split = jnp.where(split_bin == self.num_bins - 1, in_set, code > split_bin)
         return jnp.where(value == 0, ~default_left, by_split)
+
+
+def choose_set_read_impl(backend, entries):
+    """The lowering ``CatTables.set_word`` takes for a level's set table of
+    ``entries`` = nodes x words entries (static at trace time) on
+    ``backend``: the select pass costs rows x entries and the product rows x
+    128 x words whatever the level, and only the TPU serializes gathers."""
+    if backend != "tpu":
+        return "gather"
+    return "select" if entries <= SET_READ_SELECT_MAX_ENTRIES else "product"
+
+
+def _word_of(value, words):
+    """The word of a set that holds a row's code (``value`` - 1); a row
+    without a code reads word 0."""
+    return jnp.minimum(jnp.maximum(value - 1, 0) >> 5, words - 1)
+
+
+def _byte_planes(words):
+    """A level's set table i32 ``[W, words]`` as the product's left operand,
+    bf16 ``[4 * plane, W padded to whole node tiles]``, ``plane`` = ``words``
+    padded to whole sublane tiles: row ``k * plane + w`` holds byte ``k`` of
+    word ``w`` of every node's set, 0..255, which bf16 holds exactly."""
+    W, count = words.shape
+    shifts = jnp.arange(0, 32, 8, dtype=jnp.int32)[:, None, None]
+    planes = (words.T[None, :, :] >> shifts) & 0xFF            # [4, words, W]
+    plane = histogram._round_up(count, 8)
+    nodes = histogram._round_up(W, SET_READ_NODE_TILE)
+    planes = jnp.pad(planes, ((0, 0), (0, plane - count), (0, nodes - W)))
+    return planes.reshape(4 * plane, nodes).astype(jnp.bfloat16)
+
+
+@functools.lru_cache(maxsize=None)
+def _set_read_fn(n, words, nodes, block, interpret):
+    """Compiled read of a set table as a matrix product, rows on the lanes:
+    (table bf16 ``[4 * plane, nodes]`` (``_byte_planes``), node i32 ``[1,
+    n]``, value i32 ``[1, n]``) -> i32 ``[1, n]``, each row's ``words[node,
+    (value - 1) // 32]``.
+
+    A grid step takes ``block`` rows: the one-hot of the rows' nodes
+    (``[128, block]``, 0/1 in bf16) times the table gives every byte of the
+    node's set (``[4 * plane, block]`` in f32: one non-zero term a sum, so
+    exact), the row's own word is picked off each byte plane by a
+    compare-select-reduce over ``plane`` sublanes, and the four bytes are
+    put back at their places. ``nodes`` over 128 (a level deeper than 7)
+    adds the products of its node tiles. Nothing of the body follows the
+    level: a narrower table comes padded to one node tile, so the levels of
+    a build share one Mosaic body and the evaluation rows' count makes a
+    second. The last step's rows past ``n`` read what lies there and are
+    not written back."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    plane = histogram._round_up(words, 8)
+    tile = SET_READ_NODE_TILE
+    node_tiles = nodes // tile
+
+    def kernel(table_ref, node_ref, value_ref, out_ref):
+        node = node_ref[...]                                   # [1, block]
+        node_iota = jax.lax.broadcasted_iota(jnp.int32, (tile, block), 0)
+
+        def product(table, first):          # a node tile of the table, its first node
+            onehot = (node_iota == node - first).astype(jnp.bfloat16)
+            return jnp.dot(table, onehot, preferred_element_type=jnp.float32)
+
+        if node_tiles == 1:
+            held = product(table_ref[...], 0)                  # [4 * plane, block]
+        else:
+            held = jax.lax.fori_loop(
+                0, node_tiles,
+                lambda k, acc: acc + product(
+                    table_ref[:, pl.ds(pl.multiple_of(k * tile, tile), tile)], k * tile
+                ),
+                jnp.zeros((4 * plane, block), jnp.float32),
+            )
+        at = _word_of(value_ref[...], words)
+        mine = jax.lax.broadcasted_iota(jnp.int32, (plane, block), 0) == at
+        word = jnp.zeros((1, block), jnp.int32)
+        for k in range(4):
+            byte = jnp.sum(
+                jnp.where(mine, held[k * plane:(k + 1) * plane], 0.0), axis=0, keepdims=True
+            )
+            word = word | (byte.astype(jnp.int32) << (8 * k))
+        out_ref[...] = word
+
+    rows = pl.BlockSpec((1, block), lambda i: (0, i))
+    return pl.pallas_call(
+        kernel,
+        grid=(pl.cdiv(n, block),),
+        in_specs=[pl.BlockSpec((4 * plane, nodes), lambda i: (0, 0)), rows, rows],
+        out_specs=rows,
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            # a step's one-hot and its product, in f32 and with room to spare
+            vmem_limit_bytes=min(
+                16 * (tile + 4 * plane) * block + 16 * 1024 * 1024, 100 * 1024 * 1024
+            ),
+        ),
+        interpret=interpret,
+        name="graft_cat_set_read",
+    )
+
+
+def _set_read_rows(n, words):
+    """Rows a grid step of ``_set_read_fn`` takes: ``SET_READ_ROW_BLOCK``
+    up to 88 words a node, fewer in proportion beyond, whole lane tiles and
+    no more than the rows there are."""
+    plane = histogram._round_up(words, 8)
+    fit = SET_READ_ROW_BLOCK * min(plane, 88) // plane
+    return min(max(fit // 128, 1) * 128, histogram._round_up(n, 128))
+
+
+def set_table_lookup(words, node, value, impl):
+    """``words[node[i], (value[i] - 1) // 32]`` of a level's set table i32
+    ``[W, words]``, ``node`` inside the table. Three lowerings, the same 32
+    bits (a word whose bit 31 is set stays negative):
+
+    * ``gather``: the indexed gather of the flat table.
+    * ``select``: ``node_table_lookup``'s select pass over the flat table,
+      rows x W x words compare-select-adds.
+    * ``product``: ``_set_read_fn``, rows x 128 x 4 x words multiply-adds on
+      the MXU and a pick over ``words``, whatever the level.
+
+    ``impl``: a lowering by name, as ``choose_set_read_impl`` gives it."""
+    count = words.shape[1]
+    if impl == "product":
+        n = node.shape[0]
+        if n == 0:  # a grid of no step would hand back an unwritten buffer
+            return jnp.zeros((0,), jnp.int32)
+        table = _byte_planes(words)
+        fn = _set_read_fn(
+            n, count, table.shape[1], _set_read_rows(n, count), histogram.pallas_interpret()
+        )
+        return fn(table, node[None, :], value[None, :])[0]
+    from .tree_build import node_table_lookup
+
+    at = _word_of(value, count)
+    return node_table_lookup(words.reshape(-1), node * count + at, impl=impl)
 
 
 def _rank(key):

@@ -31,7 +31,10 @@ W = 1 with the operand padded to more rows: the tables the two rules were
 read from; with ``--trees T`` the call of T class trees in one operand
 (``_class_groups``) at every W, and with ``--narrow-every K`` every K-th
 column at 127 cuts, whose second one-hot tile the calls that do not fold
-leave out (``_live_tiles``). Run under an external timeout,
+leave out (``_live_tiles``). ``--cat-set-read`` runs the set-table read probe:
+the three lowerings of ``ops.categorical.set_table_lookup`` at every level's
+table of a depth-8 build, ``allstate-cat-d8``'s rows and 85 words a node, the
+table beside ``SET_READ_SELECT_MAX_ENTRIES``. Run under an external timeout,
 like anything that holds a device.
 """
 
@@ -291,6 +294,48 @@ def eval_walk_probe(n_rows, d=N_FEATURES, depth=MAX_DEPTH, max_bin=MAX_BIN):
     return out
 
 
+# allstate-cat-d8's train rows and evaluation rows, and the words of a node's
+# set there (2,700 categories)
+CAT_SET_READ_ROWS = (12_184_290, 1_000_000)
+CAT_SET_READ_WORDS = 85
+
+
+def cat_set_read_probe(row_counts, words, depth=MAX_DEPTH):
+    """ns a row and level of ``ops.categorical.set_table_lookup`` under each
+    lowering, at every level of a depth-wise build (a ``[2**level, words]``
+    set table whose words hold every bit, bit 31 too; per-row nodes and
+    values, values that are missing or no category among them), every
+    result bit-equal to the gather's."""
+    import jax
+    import jax.numpy as jnp
+
+    from sagemaker_xgboost_container_tpu.ops import categorical as C
+
+    rows = []
+    for n in row_counts:
+        for level in range(depth):
+            W = 1 << level
+            k_tab, k_node, k_val = jax.random.split(jax.random.PRNGKey(n % 1000 + level), 3)
+            table = jax.lax.bitcast_convert_type(
+                jax.random.bits(k_tab, (W, words), jnp.uint32), jnp.int32
+            )
+            node = jax.random.randint(k_node, (n,), 0, W, jnp.int32)
+            value = jax.random.randint(k_val, (n,), -1, 32 * words + 1, jnp.int32)
+            jax.block_until_ready((table, node, value))
+            row = {"rows": n, "level": level, "nodes": W, "words": words, "entries": W * words}
+            want = None
+            for impl in ("gather", "select", "product"):
+                fn = jax.jit(lambda t, i, v, impl=impl: C.set_table_lookup(t, i, v, impl))
+                row[impl + "_ns_per_row"] = _time_a_call(fn, table, node, value) * 1e6 / n
+                got = fn(table, node, value)
+                want = got if want is None else want
+                row[impl + "_equal"] = bool(jnp.array_equal(got, want))
+            row["chosen"] = C.choose_set_read_impl(jax.default_backend(), W * words)
+            print(json.dumps(row), flush=True)
+            rows.append(row)
+    return rows
+
+
 # the cells' train matrices (benchmark/configs): rows, features; 257 bins, u16
 HIST_PROBE_SHAPES = {"higgs-d8": (8_800_000, 28), "mslr-ndcg": (2_270_296, 136)}
 HIST_PROBE_LEVELS = (1, 2, 4, 8, 16, 32, 64)  # a depth-8 tree with subtraction
@@ -462,6 +507,10 @@ def main():
         "--narrow-every", type=int, default=0, metavar="K",
         help="with --hist-levels: every K-th column holds 127 cuts, one bin tile",
     )
+    ap.add_argument(
+        "--cat-set-read", action="store_true",
+        help="run only the set-table read probe (allstate-cat-d8's rows and words)",
+    )
     ap.add_argument("--out", default=None, help="also write the JSON summary here")
     args = ap.parse_args()
 
@@ -496,6 +545,20 @@ def main():
             "eval_walk_probe": eval_walk_probe(NODE_TABLE_PROBE_ROWS),
             "build_read_probe": build_read_probe(
                 (N_ROWS,) if os.getenv("DISSECT_ROWS") else BUILD_READ_PROBE_ROWS
+            ),
+        }
+        _emit(summary, args.out)
+        return
+    if args.cat_set_read:
+        from sagemaker_xgboost_container_tpu.ops import categorical as C
+
+        summary = {
+            "backend": jax.default_backend(),
+            "device_kind": jax.devices()[0].device_kind,
+            "set_read_select_max_entries": C.SET_READ_SELECT_MAX_ENTRIES,
+            "cat_set_read_probe": cat_set_read_probe(
+                (N_ROWS,) if os.getenv("DISSECT_ROWS") else CAT_SET_READ_ROWS,
+                CAT_SET_READ_WORDS,
             ),
         }
         _emit(summary, args.out)

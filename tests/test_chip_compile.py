@@ -501,11 +501,13 @@ def test_categorical_round_compiles_for_the_chip(one_chip, no_compile_cache, mon
     walk's set test over 1,000,000 validation rows) through the chip's own
     compilers: the eight kernel call sites of a dense depth-8 build, the
     partition scan without a sort, and no row-length gather in the build or
-    in the walk (the level tables hold at most 128 x 85 words: the select
-    pass)."""
+    in the walk: the level tables (at most 128 x 85 words) are read by the
+    select pass or, from ``SET_READ_SELECT_MAX_ENTRIES`` up, by the product
+    kernel ``graft_cat_set_read`` (PR 51), one call a level of the build and
+    of the walk, and no ``[rows, 4 * words]`` product is ever written out."""
     from sagemaker_xgboost_container_tpu.data.categorical import CatLayout
     from sagemaker_xgboost_container_tpu.ops import histogram as hist_mod
-    from sagemaker_xgboost_container_tpu.ops.categorical import CatTables
+    from sagemaker_xgboost_container_tpu.ops.categorical import CatTables, choose_set_read_impl
     from sagemaker_xgboost_container_tpu.ops.tree_build import (
         choose_route_impl,
         pack_round_trees,
@@ -547,7 +549,12 @@ def test_categorical_round_compiles_for_the_chip(one_chip, no_compile_cache, mon
         .compile()
     )
     hlo = compiled.as_text()
-    assert hlo.count('custom_call_target="tpu_custom_call"') == DEPTH
+    products = sum(
+        choose_set_read_impl("tpu", tables.words << level) == "product" for level in range(DEPTH)
+    )
+    assert products >= 4  # the wide levels, whose select pass cost 150 ms a round
+    assert hlo.count('custom_call_target="tpu_custom_call"') == DEPTH + 2 * products
+    assert len(re.findall(r"custom-call\([^\n]*graft_cat_set_read", hlo)) == 2 * products
     assert not re.findall(r" sort\(", hlo)
     # the packed tree: ten rows and the sets' two halves a word
     assert "f32[{},511]".format(10 + 2 * tables.words) in hlo
